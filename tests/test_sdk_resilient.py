@@ -120,11 +120,15 @@ def test_http_client_stalled_peer_times_out_transient():
     participant forever."""
 
     async def run():
+        release = asyncio.Event()
+
         async def handler(reader, writer):
             await reader.readline()
             writer.write(b"HTTP/1.1 200 OK\r\nContent-Length: 10\r\n\r\n")
             await writer.drain()
-            await asyncio.sleep(10)  # the body never arrives
+            await release.wait()  # the body never arrives
+            # Python 3.12: wait_closed() below waits for this connection
+            writer.close()
 
         server = await asyncio.start_server(handler, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -134,7 +138,8 @@ def test_http_client_stalled_peer_times_out_transient():
         t0 = time.monotonic()
         with pytest.raises(ClientTransientError):
             await client.get_model()
-        assert time.monotonic() - t0 < 5.0  # idle timeout, not the 10s stall
+        assert time.monotonic() - t0 < 5.0  # idle timeout, not the stall
+        release.set()
         server.close()
         await server.wait_closed()
 

@@ -244,8 +244,10 @@ def test_streaming_pipeline_leases_and_releases_pool_pages():
         tenant="tenant-x", pool=pool, scheduler=sched,
     )
     rng = np.random.default_rng(0)
+    # group elements (< order, top limb < 4656): the kernel=auto race
+    # compares its candidates, and they only agree on valid input
     stack = rng.integers(
-        0, 2**16, size=(3, 64, agg.n_limbs), dtype=np.uint32
+        0, 2**12, size=(3, 64, agg.n_limbs), dtype=np.uint32
     )
     stream.submit_batch(stack)
     stream.drain()

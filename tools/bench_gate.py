@@ -162,6 +162,8 @@ def load_series(
 ) -> list[tuple[float, str, float, str]]:
     """Chronological (ts, metric, value, config) for one headline family."""
     series = []
+    if not os.path.exists(path):
+        return series  # no history yet: an empty one
     with open(path) as f:
         for line in f:
             line = line.strip()

@@ -81,12 +81,9 @@ def main() -> None:
         BoundType, DataType, GroupType, MaskConfig, ModelType,
     )
     from xaynet_tpu.ops import limbs as host_limbs
-    from xaynet_tpu.utils.jaxcache import silence_cpu_cache
+    from xaynet_tpu.utils.jaxcache import enable_compile_cache
 
-    import jax
-
-    if jax.devices()[0].platform == "cpu":
-        silence_cpu_cache(jax)
+    enable_compile_cache()
     config = MaskConfig(
         GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M6
     ).pair()

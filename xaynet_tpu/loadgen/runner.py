@@ -173,7 +173,15 @@ async def _shard_main(shard: int, cfg: dict) -> dict:
 
 def _shard_entry(shard: int, cfg: dict, queue) -> None:
     """Spawned-process entry (top level so the spawn context can pickle
-    it); ships a result or an error marker — the parent never hangs."""
+    it); ships a result or an error marker — the parent never hangs.
+
+    Drivers forge on the CPU backend: they stand for edge devices, and an
+    accelerator belongs to ONE process — the coordinator. Sibling drivers
+    initialising JAX's default backend on a chip host would take it, or
+    hang on one another."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     try:
         queue.put((shard, _run_shard(shard, cfg), None))
     except BaseException as exc:  # noqa: BLE001 - report, don't swallow
@@ -187,9 +195,9 @@ def _run_shard(shard: int, cfg: dict) -> dict:
 def run(cfg: dict) -> dict:
     """Run the whole driver tier; returns the merged stats dict.
 
-    Always process-sharded (spawn context): each driver owns its own JAX
-    runtime and socket pool, so forging scales across cores and a driver
-    crash cannot take the parent down.
+    Always process-sharded (spawn context): each driver owns its own
+    (CPU) JAX runtime and socket pool, so forging scales across cores and
+    a driver crash cannot take the parent down.
     """
     ctx = mp.get_context("spawn")
     queue = ctx.Queue()

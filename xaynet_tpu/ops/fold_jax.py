@@ -4,9 +4,9 @@ The naive way to aggregate K masked updates is a pairwise tree of modular
 adds — ``log2 K`` full passes over HBM. This kernel does it in ONE pass over
 the staged batch:
 
-1. split each uint32 limb into its 16-bit halves *inside the reduction* (XLA
-   fuses the elementwise split into the reduce input, so the batch is read
-   exactly once);
+1. split each uint32 limb into its 16-bit halves *inside the reduction*
+   (mask and shift; XLA fuses the elementwise split into the reduce input,
+   so the batch is read exactly once);
 2. plain-sum the halves over K — sums of 16-bit values stay below 2^32 for
    K <= 65535, so no carries are needed during the reduction;
 3. carry-propagate the 16-bit column sums into an (L+1)-limb value
@@ -120,25 +120,11 @@ def p_mod_sub(a, b, order: int):
 # --- the fold -------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("order",), donate_argnums=(0,))
-def fold_planar_batch(acc, stack_planar, order: int):
-    """Fold planar ``uint32[K, L, n]`` updates into the planar ``[L, n]`` acc.
-
-    Single full pass over the batch: the uint32 limbs are bitcast to uint16
-    halves (free) and summed over K with ONE widening reduction whose minor
-    dimension is the model axis — full VPU lane utilization, no relayout.
-    """
-    k, n_limb, n = stack_planar.shape
-    if k > MAX_LAZY_BATCH:
-        raise ValueError(f"batch of {k} exceeds lazy-carry headroom {MAX_LAZY_BATCH}")
-    halves = jax.lax.bitcast_convert_type(stack_planar, jnp.uint16)  # [K, L, n, 2]
-    # merge the u16 pair axis into the model axis BEFORE the reduction: a
-    # materialized tensor with a minor dimension of 2 tiles catastrophically
-    # on TPU (lane padding), while [.., 2n] keeps lanes full. The reshape is
-    # free (contiguous dims merge) and the batch is read exactly once.
-    sums = jnp.sum(halves.reshape(k, n_limb, n * 2), axis=0, dtype=_U32)  # [L, 2n]
-    lo = sums[:, 0::2]
-    hi = sums[:, 1::2]
+def _reduce_and_add(acc, lo, hi, k: int, order: int):
+    """Shared tail of the lazy-carry folds: 16-bit column sums ``lo``/``hi``
+    (planar ``uint32[L, n]``, each < ``k * 2^16``) -> carry-propagate ->
+    reduce modulo the order -> modular add into ``acc``."""
+    n_limb, n = acc.shape
     carry = jnp.zeros(n, dtype=_U32)
     limbs32 = []
     for j in range(n_limb):
@@ -154,17 +140,59 @@ def fold_planar_batch(acc, stack_planar, order: int):
     return p_mod_add(acc, value[:n_limb], order)
 
 
+def _check_lazy_batch(k: int) -> None:
+    if k > MAX_LAZY_BATCH:
+        raise ValueError(f"batch of {k} exceeds lazy-carry headroom {MAX_LAZY_BATCH}")
+
+
+@partial(jax.jit, static_argnames=("order",), donate_argnums=(0,))
+def fold_planar_batch(acc, stack_planar, order: int):
+    """Fold planar ``uint32[K, L, n]`` updates into the planar ``[L, n]`` acc.
+
+    Single full pass over the batch: the 16-bit halves are split with a mask
+    and a shift INSIDE the two K-reductions (XLA fuses both into one read of
+    the batch), so every tensor keeps the model axis minor. A u16 bitcast
+    would be the obvious split, but it materializes ``[..., n, 2]`` — a
+    minor dimension of 2 that the TPU's (8,128) tiling pads 64x (25.6 GB at
+    n = 25M; the v5e compiler refuses it).
+    """
+    k = stack_planar.shape[0]
+    _check_lazy_batch(k)
+    lo = jnp.sum(stack_planar & _U32(0xFFFF), axis=0, dtype=_U32)
+    hi = jnp.sum(stack_planar >> _U32(16), axis=0, dtype=_U32)
+    return _reduce_and_add(acc, lo, hi, k, order)
+
+
 @partial(jax.jit, static_argnames=("n_limbs", "order"), donate_argnums=(0,))
 def fold_packed_batch(acc, packed, n_limbs: int, order: int):
     """Fold PACKED byte-planar ``uint8[K, bpn, n]`` updates into the planar
-    ``[L, n]`` accumulator: in-graph unpack (``limbs_jax.packed_planar_to_limbs``)
-    fused with the lazy-carry fold in ONE jit, so the 4L-byte planar tensor
-    never crosses host->device — only the ``bpn``-byte packed planes do
-    (the EQuARX insight applied to the staging transfer)."""
-    from .limbs_jax import packed_planar_to_limbs
+    ``[L, n]`` accumulator, so only the ``bpn``-byte packed planes cross
+    host->device (the EQuARX insight applied to the staging transfer).
 
-    planar = packed_planar_to_limbs(packed, n_limbs)
-    return fold_planar_batch(acc, planar, order)
+    The byte planes are summed over K FIRST (one widening reduction, the
+    batch read once) and limbs are assembled from the ``[bpn, n]`` plane
+    sums: limb j's 16-bit column sums are ``S[4j] + (S[4j+1] << 8)`` and
+    ``S[4j+2] + (S[4j+3] << 8)`` — exactly the sums the planar fold takes,
+    each < ``K * 2^16``. Unpacking before the reduction would materialize
+    the widened ``uint32[K, bpn, n]`` (10.4 GB at K=16, n=25M).
+    """
+    k, bpn, n = packed.shape
+    _check_lazy_batch(k)
+    if 4 * n_limbs < bpn:
+        raise ValueError("limb width too small for the packed width")
+    sums = jnp.sum(packed, axis=0, dtype=_U32)  # [bpn, n]
+
+    def half(b: int):
+        # byte planes b, b+1 as one 16-bit column sum (planes past bpn are 0)
+        if b >= bpn:
+            return jnp.zeros(n, dtype=_U32)
+        if b + 1 >= bpn:
+            return sums[b]
+        return sums[b] + (sums[b + 1] << _U32(8))
+
+    lo = jnp.stack([half(4 * j) for j in range(n_limbs)])
+    hi = jnp.stack([half(4 * j + 2) for j in range(n_limbs)])
+    return _reduce_and_add(acc, lo, hi, k, order)
 
 
 def wire_to_planar(stack: np.ndarray) -> np.ndarray:

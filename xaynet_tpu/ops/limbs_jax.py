@@ -127,27 +127,45 @@ def aggregate_batch(acc: jax.Array, stack: jax.Array, order_limbs: np.ndarray) -
     return _aggregate_batch_kernel(acc, stack, tuple(int(x) for x in _as_order(order_limbs)))
 
 
+def _assemble_limbs(byte_plane, bpn: int, n_limbs: int):
+    """Little-endian limb assembly shared by the two byte unpackers: limb j
+    is ``byte_plane(4j) | byte_plane(4j+1) << 8 | ...`` over the byte planes
+    that exist (``< bpn``). ``byte_plane(i)`` returns byte i of every
+    element as ``uint8[..., n]``; each plane is widened AFTER it is sliced,
+    so no widened copy of the whole input is ever materialized."""
+    limbs = []
+    for j in range(n_limbs):
+        if 4 * j >= bpn:
+            limbs.append(jnp.zeros(byte_plane(0).shape, dtype=_U32))
+            continue
+        w = byte_plane(4 * j).astype(_U32)
+        for i in range(1, min(4, bpn - 4 * j)):
+            w = w | (byte_plane(4 * j + i).astype(_U32) << _U32(8 * i))
+        limbs.append(w)
+    return jnp.stack(limbs, axis=-2)
+
+
 def wire_bytes_to_planar(data: jax.Array, count: int, bpn: int) -> jax.Array:
     """Wire element block ``uint8[..., count*bpn]`` -> planar ``uint32[..., L, count]``.
 
     The wire layout is ``count`` fixed-width little-endian integers of
     ``bpn`` bytes each (serialization.py / reference vect.rs:24-80). Pure
-    byte shuffling — reshape + shifts — so the coordinator can ship RAW
-    wire bytes to the device (``bpn/(4L)`` of the limb-tensor size, e.g.
-    6/8 for the f32/B0/M3 configs, 7/8 for M6) and never pay a host-side
-    parse. Designed to run inside a jitted caller.
+    byte shuffling, so the coordinator can ship RAW wire bytes to the
+    device (``bpn/(4L)`` of the limb-tensor size, e.g. 6/8 for the
+    f32/B0/M3 configs, 7/8 for M6) and never pay a host-side parse.
+    Byte i of every element is the stride-``bpn`` slice ``data[..., i::bpn]``
+    — a reshape to ``[..., count, bpn]`` would put a minor dimension of
+    ``bpn`` under the TPU's 128-lane tiling (18x padding at bpn = 7; the
+    v5e compiler refuses it at n = 25M). Designed to run inside a jitted
+    caller.
     """
     from . import limbs as host_limbs
 
-    out_limbs = host_limbs.n_limbs_for_bytes(bpn)
-    b = data.reshape(*data.shape[:-1], count, bpn).astype(_U32)
-    limbs = []
-    for j in range(out_limbs):
-        w = b[..., 4 * j]
-        for i in range(1, min(4, bpn - 4 * j)):
-            w = w | (b[..., 4 * j + i] << _U32(8 * i))
-        limbs.append(w)
-    return jnp.stack(limbs, axis=-2)
+    if data.shape[-1] != count * bpn:
+        raise ValueError("wire block length must be count * bytes_per_number")
+    return _assemble_limbs(
+        lambda i: data[..., i::bpn], bpn, host_limbs.n_limbs_for_bytes(bpn)
+    )
 
 
 def packed_planar_to_limbs(packed: jax.Array, n_limbs: int) -> jax.Array:
@@ -165,17 +183,7 @@ def packed_planar_to_limbs(packed: jax.Array, n_limbs: int) -> jax.Array:
     bpn = packed.shape[-2]
     if n_limbs < host_limbs.n_limbs_for_bytes(bpn):
         raise ValueError("limb width too small for the packed width")
-    b = packed.astype(_U32)
-    limbs = []
-    for j in range(n_limbs):
-        if 4 * j >= bpn:
-            limbs.append(jnp.zeros(packed.shape[:-2] + packed.shape[-1:], dtype=_U32))
-            continue
-        w = b[..., 4 * j, :]
-        for i in range(1, min(4, bpn - 4 * j)):
-            w = w | (b[..., 4 * j + i, :] << _U32(8 * i))
-        limbs.append(w)
-    return jnp.stack(limbs, axis=-2)
+    return _assemble_limbs(lambda i: packed[..., i, :], bpn, n_limbs)
 
 
 # standalone jitted entry for callers that unpack OUTSIDE their own jit
@@ -191,12 +199,14 @@ def planar_all_lt_const(planar: jax.Array, order: int) -> jax.Array:
     bool per leading index (per update for a ``[K, L, n]`` batch; a scalar
     for a single ``[L, n]`` tensor). Owns the ``order == 2^(32 L)``
     boundary case (every bit pattern valid) exactly like the host
-    ``limbs.elements_lt_order`` — callers never special-case it.
+    ``limbs.elements_lt_order`` — callers never special-case it. The
+    compare walks the limb PLANES (model axis stays minor): moving the
+    limb axis last would tile a minor dimension of L on TPU.
     """
-    from . import limbs as host_limbs
+    from .fold_jax import _int_to_limbs_list, p_lt_const
 
     n_limb = planar.shape[-2]
     if order == 1 << (32 * n_limb):
         return jnp.ones(planar.shape[:-2], dtype=bool)
-    order_limbs = host_limbs.int_to_limbs(order, n_limb)
-    return jnp.all(lt_const(jnp.moveaxis(planar, -2, -1), order_limbs), axis=-1)
+    lt = p_lt_const(jnp.moveaxis(planar, -2, 0), _int_to_limbs_list(order, n_limb))
+    return jnp.all(lt, axis=-1)

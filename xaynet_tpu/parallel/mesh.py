@@ -19,27 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MODEL_AXIS = "model"
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at top level with ``check_vma``; 0.4.x ships it in
-    ``jax.experimental.shard_map`` with the equivalent ``check_rep`` knob
-    (pallas_call's out_shape carries no vma/rep either way, so the check is
-    disabled in both). The ONE shim for every shard_map call site
-    (parallel/aggregator.py, sim/round.py) — the API moved once already,
-    and the next move must be absorbed in one place.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    return _exp_shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def make_mesh(devices=None) -> Mesh:
     """A 1-D mesh over all (or the given) devices, named for the model axis."""
     if devices is None:

@@ -151,6 +151,9 @@ class RestServer:
             ("method", "path", "status", "tenant"),
         )
         self._server: Optional[asyncio.AbstractServer] = None
+        # live connections: stop() closes them — an idle keep-alive peer
+        # would otherwise hold the process for read_timeout seconds
+        self._writers: set[asyncio.StreamWriter] = set()
 
     async def start(
         self, host: str = "127.0.0.1", port: int = 8081, tls: Optional[ssl.SSLContext] = None
@@ -161,13 +164,22 @@ class RestServer:
         return addr[0], addr[1]
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+        """Stop listening AND close every live connection. Since Python 3.12
+        ``Server.wait_closed()`` waits for all connections to finish; an idle
+        keep-alive handler sits in ``readline()`` for ``read_timeout``, so
+        without closing them a SIGTERM'd coordinator outlives its signal by
+        minutes — and still owns its accelerator."""
+        if self._server is None:
+            return
+        self._server.close()
+        for writer in list(self._writers):
+            writer.close()
+        await self._server.wait_closed()
 
     # --- request handling -------------------------------------------------
 
     async def _handle_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        self._writers.add(writer)
         try:
             while True:
                 request_line = await asyncio.wait_for(reader.readline(), self.read_timeout)
@@ -202,6 +214,7 @@ class RestServer:
         except (asyncio.IncompleteReadError, ConnectionResetError, asyncio.TimeoutError):
             pass
         finally:
+            self._writers.discard(writer)
             writer.close()
             try:
                 await writer.wait_closed()

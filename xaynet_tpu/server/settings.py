@@ -11,11 +11,7 @@ settings/mod.rs:307-376).
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: env/default settings still work
-    tomllib = None
+import tomllib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -524,7 +520,7 @@ class TenancySettings:
 
     def tenant_weights(self) -> dict:
         """Parsed ``weights``: ``{tenant: weight}`` (same string form as
-        ``slo.tenant_round_wall_s`` — env-overridable, mini-TOML-safe)."""
+        ``slo.tenant_round_wall_s`` — env-overridable)."""
         return {
             t: float(v) for t, v in _parse_tenant_pairs(self.weights)
         }
@@ -603,8 +599,8 @@ class SloSettings:
 
     ``round_wall_s`` is the round-wall target every tenant inherits;
     ``tenant_round_wall_s`` overrides it per tenant as a comma-separated
-    ``tenant=seconds`` string (strings keep the section env-overridable
-    and mini-TOML-parseable, like ``tenancy.tenants``). The three budgets
+    ``tenant=seconds`` string (strings keep the section env-overridable,
+    like ``tenancy.tenants``). The three budgets
     are the allowed BAD fractions (slow rounds / degraded rounds / shed
     ingress); burn rate 1.0 means spending exactly that budget. An alert
     needs BOTH the fast and the slow window burning — ``warn`` at
@@ -782,12 +778,8 @@ class Settings:
         """Load from TOML (optional) with ``XAYNET__SECTION__KEY`` env overrides."""
         raw: dict[str, Any] = {}
         if path is not None:
-            if tomllib is not None:
-                with open(path, "rb") as f:
-                    raw = tomllib.load(f)
-            else:
-                with open(path, "r", encoding="utf-8") as f:
-                    raw = _mini_toml(f.read())
+            with open(path, "rb") as f:
+                raw = tomllib.load(f)
         env = dict(os.environ if env is None else env)
         for key, value in env.items():
             if not key.startswith("XAYNET__"):
@@ -1005,7 +997,7 @@ class Settings:
             tenancy=TenancySettings(
                 enabled=bool(ten_raw.get("enabled", ten_base.enabled)),
                 # a TOML array, or a comma-separated string (env overrides
-                # and the mini-TOML fallback deliver strings)
+                # deliver strings)
                 tenants=(
                     [t.strip() for t in ten_raw["tenants"].split(",") if t.strip()]
                     if isinstance(ten_raw.get("tenants"), str)
@@ -1091,56 +1083,6 @@ class Settings:
                 spec_group=int(ov_raw.get("spec_group", ov_base.spec_group)),
             ),
         )
-
-
-def _mini_toml(text: str) -> dict:
-    """TOML-subset parser for Python < 3.11 (no ``tomllib``).
-
-    Covers exactly what the coordinator configs use: ``[dotted.section]``
-    headers, ``key = value`` with string/bool/int/float scalars, comments
-    and blank lines. Anything fancier (arrays, inline tables, multi-line
-    strings) raises — better a loud error than silently dropped settings.
-    """
-    root: dict[str, Any] = {}
-    node = root
-    for lineno, line in enumerate(text.splitlines(), 1):
-        stripped = line.strip()
-        if not stripped or stripped.startswith("#"):
-            continue
-        if stripped.startswith("[") and stripped.endswith("]"):
-            header = stripped[1:-1].strip()
-            if header.startswith("[") or header.endswith("]"):
-                raise SettingsError(
-                    f"config line {lineno}: arrays of tables ({stripped!r}) are "
-                    "not supported by the tomllib fallback parser"
-                )
-            node = root
-            for part in header.split("."):
-                node = node.setdefault(part.strip(), {})
-            continue
-        key, eq, value = stripped.partition("=")
-        if not eq:
-            raise SettingsError(f"config line {lineno}: expected 'key = value'")
-        value = value.strip()
-        # strip a trailing comment (quote-aware for string values)
-        if value.startswith('"'):
-            end = value.find('"', 1)
-            if end < 0:
-                raise SettingsError(f"config line {lineno}: unterminated string")
-            trailing = value[end + 1 :].split("#", 1)[0].strip()
-            if trailing:
-                raise SettingsError(
-                    f"config line {lineno}: unexpected content after string: {trailing!r}"
-                )
-            node[key.strip()] = value[1:end]
-            continue
-        value = value.split("#", 1)[0].strip()
-        coerced = _coerce(value)  # same bool/int/float ladder as env overrides
-        if isinstance(coerced, str):
-            # unquoted non-scalar (array, inline table, bareword): loud error
-            raise SettingsError(f"config line {lineno}: unsupported value {value!r}")
-        node[key.strip()] = coerced
-    return root
 
 
 def _coerce(value: str):

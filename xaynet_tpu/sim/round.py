@@ -64,7 +64,7 @@ from ..ops.masking_jax import (
     encode_models_batch,
     seed_words,
 )
-from ..parallel.mesh import MODEL_AXIS, shard_map_compat
+from ..parallel.mesh import MODEL_AXIS
 from ..telemetry import profiling
 
 
@@ -204,11 +204,12 @@ class SimRound:
             else:
                 from jax.sharding import PartitionSpec as P
 
-                sharded = shard_map_compat(
+                sharded = jax.shard_map(
                     _prog_shard,
-                    mesh,
+                    mesh=mesh,
                     in_specs=(P(MODEL_AXIS), P(MODEL_AXIS), P(), P(MODEL_AXIS)),
                     out_specs=(P(MODEL_AXIS), P(MODEL_AXIS), P(MODEL_AXIS), P(MODEL_AXIS)),
+                    check_vma=False,
                 )
                 pmv, pmu, pkv, pku = sharded(kw, enc, unit_enc, valid)
                 # cross-device combine: modular sums are associative and

@@ -4,8 +4,8 @@ One structured JSON object per completed round, appended to a JSONL file:
 round id, per-phase durations, message accepted/rejected/discarded counts
 per phase, unique-mask total, aggregation kernel stats (calls, device-synced
 seconds, elements, derived elements/sec) and any phase events. Consumers
-(``tools/tpu_watch.py``, ``bench.py``, dashboards) read one artifact instead
-of scraping coordinator logs.
+(``bench.py``, dashboards) read one artifact instead of scraping coordinator
+logs.
 
 Fed by ``telemetry.bridge.BridgedMetrics``: a report window opens when Idle
 records ``round_total`` for a new round and flushes when the next round
