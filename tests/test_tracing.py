@@ -436,3 +436,147 @@ def test_calibrate_mask_kernel_records_auditable_verdict():
     # memoized second resolution records nothing new
     assert masking_jax.calibrate_mask_kernel(seeds, length, cfg, seed_batch=3) == winner
     assert [e for e in drain_mask_calibrations() if e["length"] == length] == []
+
+
+# --- the mirror sink (the same spans on the profiler's clock) ----------------
+
+S_MIRRORED = tracing.declare_span("test.mirrored", mirror=True)
+S_MIRRORED_B = tracing.declare_span("test.mirrored_b", mirror=True)
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: a context manager
+    built from ``(name, **attrs)`` that logs its enter and exit."""
+
+    log: list = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.attrs.get("rid")))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.attrs.get("rid")))
+
+
+@pytest.fixture
+def mirror_log():
+    _FakeAnnotation.log = []
+    return _FakeAnnotation.log
+
+
+def test_mirror_receives_only_spans_declared_for_it(tracer, mirror_log):
+    tracer.set_mirror(_FakeAnnotation)
+    assert tracer.mirrored
+    with tracer.span(S_A):  # declared without mirror=True
+        with tracer.span(S_MIRRORED, rid="r1", bytes=3):
+            pass
+    tracer.record_span(S_MIRRORED, time.monotonic() - 0.1, 0.1, rid="retro")
+    assert mirror_log == [("enter", S_MIRRORED, "r1"), ("exit", S_MIRRORED, "r1")]
+    tracer.set_mirror(None)
+    with tracer.span(S_MIRRORED, rid="r2"):
+        pass
+    assert len(mirror_log) == 2 and not tracer.mirrored
+    assert len([s for s in tracer.ring_spans() if s.name == S_MIRRORED]) == 3
+
+
+@pytest.mark.parametrize(
+    "name", ["round", "rest.request", "phase.update", "phase.sum2", "pipeline.pool_wait",
+             "pipeline.resume_wait", "update.request_wait", "update.verdict_wait"])
+def test_covering_and_retroactive_spans_are_never_mirrored(name):
+    import importlib
+
+    for module in ("rest", "stages", "phases.base"):  # the modules that declare them
+        importlib.import_module(f"xaynet_tpu.server.{module}")
+    assert name in tracing.declared_span_names()
+    assert name not in tracing.mirrored_span_names()
+
+
+def test_mirror_is_never_called_with_tracing_off(mirror_log):
+    tracer = tracing.Tracer(mode="off", trace_dir="")
+    tracer.set_mirror(_FakeAnnotation)
+    with tracer.span(S_MIRRORED, rid="x") as span:
+        assert span.ctx is None
+    assert mirror_log == []
+
+
+def test_a_failing_mirror_never_fails_the_span(tracer):
+    def broken(name, **attrs):
+        raise RuntimeError("sink down")
+
+    tracer.set_mirror(broken)
+    with tracer.span(S_MIRRORED):
+        pass
+    assert tracer.ring_spans()[-1].name == S_MIRRORED
+
+
+def test_interleaved_coroutine_spans_pair_on_the_mirror(tracer, mirror_log):
+    """Two coroutines on one loop thread: B opens and closes inside A's
+    await. Each annotation is entered and exited once, by its own span."""
+    import asyncio
+
+    tracer.set_mirror(_FakeAnnotation)
+
+    async def co(name, rid, delay, dur):
+        await asyncio.sleep(delay)
+        with tracer.span(name, rid=rid):
+            await asyncio.sleep(dur)
+
+    async def main():
+        await asyncio.gather(co(S_MIRRORED, "a", 0.0, 0.06), co(S_MIRRORED_B, "b", 0.02, 0.08))
+
+    asyncio.run(main())
+    assert mirror_log == [
+        ("enter", S_MIRRORED, "a"), ("enter", S_MIRRORED_B, "b"),
+        ("exit", S_MIRRORED, "a"), ("exit", S_MIRRORED_B, "b"),
+    ]
+    spans = {s.attrs["rid"]: s for s in tracer.ring_spans()}
+    assert spans["a"].parent_id is None and spans["b"].parent_id is None  # siblings, not nested
+
+
+def test_overlapping_coroutine_annotations_keep_their_own_ends_in_a_profile(tmp_path, tracer):
+    """What the mirror relies on, shown under the CPU profiler: a
+    ``TraceAnnotation`` is recorded whole when it exits, so two spans that
+    overlap on the loop thread come out with their own starts and ends."""
+    import asyncio
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tracer.set_mirror(jax.profiler.TraceAnnotation)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+
+    async def co(name, rid, delay, dur):
+        await asyncio.sleep(delay)
+        with tracer.span(name, rid=rid):
+            await asyncio.sleep(dur)
+
+    async def main():
+        await asyncio.gather(co(S_MIRRORED, "a", 0.0, 0.20), co(S_MIRRORED_B, "b", 0.05, 0.30))
+
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        asyncio.run(main())
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    assert files
+    found = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name in (S_MIRRORED, S_MIRRORED_B):
+                    found[event.name] = (event.start_ns * 1e-9, event.duration_ns * 1e-9,
+                                         dict(event.stats))
+    assert set(found) == {S_MIRRORED, S_MIRRORED_B}
+    (a_lo, a_dur, a_stats), (b_lo, b_dur, b_stats) = found[S_MIRRORED], found[S_MIRRORED_B]
+    assert a_stats["rid"] == "a" and b_stats["rid"] == "b"
+    assert 0.18 < a_dur < 0.5 and 0.28 < b_dur < 0.6
+    assert a_lo < b_lo < a_lo + a_dur < b_lo + b_dur  # they overlap, neither is cut
+    by_rid = {s.attrs["rid"]: s for s in tracer.ring_spans()}
+    assert abs(by_rid["a"].duration - a_dur) < 0.02 and abs(by_rid["b"].duration - b_dur) < 0.02

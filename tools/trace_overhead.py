@@ -18,7 +18,8 @@ Usage:
   JAX_PLATFORMS=cpu python tools/trace_overhead.py [--model-len N]
                     [--k K] [--batches B] [--reps R]
 Prints one JSON line: {updates_per_s_on, updates_per_s_off, overhead_pct,
-timeline_fold_us, timeline_fold_pct_of_window, ...}.
+span_cost_us, span_cost_mirrored_us (the mirror sink set, no profiler
+session), timeline_fold_us, timeline_fold_pct_of_window, ...}.
 """
 
 from __future__ import annotations
@@ -139,6 +140,24 @@ def main() -> None:
             pass
     span_cost_us = (time.perf_counter() - t0) / n_probe * 1e6
 
+    # the same span with the mirror sink set as the runner sets it
+    # (jax.profiler.TraceAnnotation) and no profiler session open: what a
+    # mirror=True span costs a coordinator that nobody is profiling
+    import jax
+
+    mirrored_probe = "trace.overhead_probe_mirrored"
+    if mirrored_probe not in name:
+        tracing.declare_span(mirrored_probe, mirror=True)
+    tracer.set_mirror(jax.profiler.TraceAnnotation)
+    try:
+        t0 = time.perf_counter()
+        for _ in range(n_probe):
+            with tracer.span(mirrored_probe, batch=1):
+                pass
+        span_cost_mirrored_us = (time.perf_counter() - t0) / n_probe * 1e6
+    finally:
+        tracer.set_mirror(None)
+
     # the always-on timeline fold (DESIGN §20): one O(n) pass per round
     # over the span buffer. Time it on a synthetic buffer shaped like a
     # real round (phase spans + streaming children, half the 8192 cap) and
@@ -190,6 +209,7 @@ def main() -> None:
                 "overhead_pct": round(overhead, 2),
                 "pair_ratios": [round(r, 4) for r in ratios],
                 "span_cost_us": round(span_cost_us, 2),
+                "span_cost_mirrored_us": round(span_cost_mirrored_us, 2),
                 "timeline_fold_us": round(fold_cost_us, 2),
                 "timeline_fold_spans": len(buffer),
                 "timeline_fold_pct_of_window": round(fold_pct_of_window, 4),

@@ -93,14 +93,10 @@ class Message:
         ``lazy_update_vect``: device-ingest coordinators defer the Update
         payload's element parse/validity to the accelerator (see
         ``parse_mask_vect``); all other payloads parse eagerly."""
-        if len(data) < HEADER_LENGTH:
-            raise DecodeError("message shorter than header")
+        length = cls._declared_length(data)
         signature = data[:SIGNATURE_LENGTH]
         participant_pk = data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH]
         coordinator_pk = data[SIGNATURE_LENGTH + PK_LENGTH : SIGNATURE_LENGTH + 2 * PK_LENGTH]
-        (length,) = struct.unpack_from(">I", data, SIGNATURE_LENGTH + 2 * PK_LENGTH)
-        if length < HEADER_LENGTH or length > len(data):
-            raise DecodeError("invalid message length field")
         tag_raw = data[SIGNATURE_LENGTH + 2 * PK_LENGTH + 4]
         flags_raw = data[SIGNATURE_LENGTH + 2 * PK_LENGTH + 5]
         try:
@@ -108,10 +104,8 @@ class Message:
         except ValueError as e:
             raise DecodeError(f"invalid tag {tag_raw}") from e
         is_multipart = bool(flags_raw & Flags.MULTIPART)
-        if verify and not crypto_sign.verify_detached(
-            participant_pk, signature, memoryview(data)[SIGNATURE_LENGTH:length]
-        ):
-            raise DecodeError("invalid message signature")
+        if verify:
+            cls.verify_bytes(data)
         payload = parse_payload(
             tag, is_multipart, data[HEADER_LENGTH:length], lazy_update_vect=lazy_update_vect
         )
@@ -124,13 +118,27 @@ class Message:
             signature=signature,
         )
 
-    def verify_signature(self, data: bytes) -> bool:
+    @staticmethod
+    def _declared_length(data: bytes) -> int:
+        if len(data) < HEADER_LENGTH:
+            raise DecodeError("message shorter than header")
         (length,) = struct.unpack_from(">I", data, SIGNATURE_LENGTH + 2 * PK_LENGTH)
-        return crypto_sign.verify_detached(
+        if length < HEADER_LENGTH or length > len(data):
+            raise DecodeError("invalid message length field")
+        return length
+
+    @classmethod
+    def verify_bytes(cls, data: bytes) -> None:
+        """The signature check of :meth:`from_bytes` on its own (one Ed25519
+        pass over the signed bytes, no copy of them), for callers that time
+        it apart from the parse and then parse with ``verify=False``."""
+        length = cls._declared_length(data)
+        if not crypto_sign.verify_detached(
             data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH],
             data[:SIGNATURE_LENGTH],
-            data[SIGNATURE_LENGTH:length],
-        )
+            memoryview(data)[SIGNATURE_LENGTH:length],
+        ):
+            raise DecodeError("invalid message signature")
 
 
 def _payload_tag(payload: Payload) -> Tag:

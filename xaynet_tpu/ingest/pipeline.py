@@ -43,7 +43,7 @@ logger = logging.getLogger("xaynet.ingest")
 
 SPAN_ADMISSION = trace.declare_span("ingest.admission")
 SPAN_QUEUE_WAIT = trace.declare_span("ingest.queue_wait")
-SPAN_DECRYPT_BATCH = trace.declare_span("ingest.decrypt_batch")
+SPAN_DECRYPT_BATCH = trace.declare_span("ingest.decrypt_batch", mirror=True)
 
 WORKER_RESTARTS = get_registry().counter(
     "xaynet_ingest_worker_restarts_total",
@@ -216,8 +216,9 @@ class IngestPipeline:
     async def submit(self, encrypted: bytes) -> Admission:
         """Admit, shed, or drop one encrypted message (REST entry point).
 
-        The REST request id is assigned HERE and rides with the ciphertext
-        through the intake queue, so the decrypt worker and the coalescer
+        The REST request id (assigned before the body is read, or here for a
+        caller that skips the socket) rides with the ciphertext through the
+        intake queue, so the decrypt worker and the coalescer
         log under the same id the request logs carry — the id no longer
         dies at the pipeline boundary.
         """
@@ -225,7 +226,7 @@ class IngestPipeline:
             # cheap pre-decrypt rejection: structurally impossible, or no
             # phase is accepting messages at all
             return self.admission.dropped("pre-filter")
-        request_id = tracing.new_request_id()
+        request_id = tracing.request_id_or_fresh()
         with trace.get_tracer().span(
             SPAN_ADMISSION, rid=request_id, tenant=self.tenant
         ) as span:

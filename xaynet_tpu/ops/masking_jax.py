@@ -46,7 +46,7 @@ from . import chacha_jax, limbs as host_limbs, limbs_jax
 logger = logging.getLogger(__name__)
 
 SPAN_MASK_CALIBRATE = trace.declare_span("mask.calibrate")
-SPAN_MASK_SUM = trace.declare_span("mask.sum")
+SPAN_MASK_SUM = trace.declare_span("mask.sum", mirror=True)
 
 # Compiled-program cache bound for the pow2-lane batched derive (and the
 # other jitted mask-pipeline builders below). Each entry retains a full XLA

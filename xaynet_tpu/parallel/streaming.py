@@ -73,6 +73,7 @@ import queue as queue_mod
 import threading
 import time
 import weakref
+from contextlib import contextmanager, nullcontext
 
 import numpy as np
 
@@ -91,10 +92,15 @@ from .aggregator import BYTES_REDUCED, BYTES_STAGED, ShardedAggregator
 
 logger = logging.getLogger(__name__)
 
-SPAN_STAGE = trace.declare_span("stream.stage")
-SPAN_FOLD = trace.declare_span("stream.fold")
+# mirror=True: also written into the profiler's trace when the runner has
+# installed its sink. stream.commit and overlap.eager_unmask are recorded
+# after the fact (record_span), which no mirror can carry; so are the
+# per-shard stream.stage spans of the shard-parallel submit paths.
+SPAN_STAGE = trace.declare_span("stream.stage", mirror=True)
+SPAN_H2D = trace.declare_span("stream.h2d", mirror=True)
+SPAN_FOLD = trace.declare_span("stream.fold", mirror=True)
 SPAN_COMMIT = trace.declare_span("stream.commit")
-SPAN_DRAIN = trace.declare_span("stream.drain")
+SPAN_DRAIN = trace.declare_span("stream.drain", mirror=True)
 SPAN_EAGER_UNMASK = trace.declare_span("overlap.eager_unmask")
 
 _registry = get_registry()
@@ -144,7 +150,32 @@ SHARD_OVERLAP = _registry.gauge(
     "that ran concurrently with the other leg during the last drain window.",
     ("shard",),
 )
+H2D_SECONDS = _registry.histogram(
+    "xaynet_streaming_h2d_seconds",
+    "One staged batch's host-to-device copy: device_put until the staged "
+    "array is ready, the fold's dispatch in between (and, while "
+    "XAYNET_KERNEL_PROFILE keeps its per-fold sync, that fold's device time).",
+    buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+             1.0, 2.5, 5.0, 10.0, 30.0),
+)
+H2D_BYTES = _registry.counter(
+    "xaynet_streaming_h2d_bytes_total",
+    "Bytes of staged batches copied host to device by the fold worker.",
+)
 _SHUTDOWN = object()
+
+
+@contextmanager
+def _h2d(kind: str, nbytes: int):
+    """One staged batch's host-to-device copy in ``_fold_payload``: from
+    ``device_put`` to the wait that frees the ring buffer. The fold's
+    dispatch lies between the two (the order is the hot path's, and this
+    adds no sync), so the time is an upper bound of the copy alone."""
+    t0 = time.monotonic()
+    with trace.get_tracer().span(SPAN_H2D, kind=kind, bytes=nbytes):
+        yield
+    H2D_SECONDS.observe(time.monotonic() - t0)
+    H2D_BYTES.inc(nbytes)
 
 
 class StreamingError(RuntimeError):
@@ -655,32 +686,33 @@ class StreamingAggregator:
         from ..ops import limbs as host_limbs
 
         kind = "packed" if self._packed else "planar"
-        t0 = time.monotonic()
-        buf = self._ring(kind).acquire()
-        view = buf[:k]
-        if self._packed:
-            # pack straight into the byte-planar ring buffer: one strided
-            # transpose of the first bpn wire bytes per element — the same
-            # copy class as the planar transpose below, writing bpn/(4L)
-            # of the bytes
-            host_limbs.pack_wire(stack, self.agg.packed_width, out=view[:, :, : self.agg.model_length])
-            if self.agg.padded_length != self.agg.model_length:
-                view[:, :, self.agg.model_length :] = 0
-        else:
-            # transpose+pad straight into the ring buffer (numpy strided
-            # copy, no wire_to_planar intermediate): per-batch host
-            # allocation in the steady state is zero
-            view[:, :, : self.agg.model_length] = stack.transpose(0, 2, 1)
-            if self.agg.padded_length != self.agg.model_length:
-                view[:, :, self.agg.model_length :] = 0
-        BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
-        ticket = StreamTicket(k)
-        self._stage_seconds += time.monotonic() - t0
-        self._batch_seq += 1
-        trace.get_tracer().record_span(
-            SPAN_STAGE, start=t0, duration=time.monotonic() - t0,
-            batch=self._batch_seq, kind=kind, k=k,
-        )
+        with trace.get_tracer().span(
+            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
+        ):
+            t0 = time.monotonic()
+            buf = self._ring(kind).acquire()
+            view = buf[:k]
+            if self._packed:
+                # pack straight into the byte-planar ring buffer: one strided
+                # transpose of the first bpn wire bytes per element — the same
+                # copy class as the planar transpose below, writing bpn/(4L)
+                # of the bytes
+                host_limbs.pack_wire(
+                    stack, self.agg.packed_width, out=view[:, :, : self.agg.model_length]
+                )
+                if self.agg.padded_length != self.agg.model_length:
+                    view[:, :, self.agg.model_length :] = 0
+            else:
+                # transpose+pad straight into the ring buffer (numpy strided
+                # copy, no wire_to_planar intermediate): per-batch host
+                # allocation in the steady state is zero
+                view[:, :, : self.agg.model_length] = stack.transpose(0, 2, 1)
+                if self.agg.padded_length != self.agg.model_length:
+                    view[:, :, self.agg.model_length :] = 0
+            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
+            ticket = StreamTicket(k)
+            self._stage_seconds += time.monotonic() - t0
+            self._batch_seq += 1
         self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
         return ticket
 
@@ -832,22 +864,21 @@ class StreamingAggregator:
         from ..ops import limbs as host_limbs
 
         kind = "packed" if self._packed else "planar"
-        t0 = time.monotonic()
-        buf = self._ring(kind).acquire()
-        view = buf[:k]
-        for i, row in enumerate(rows):
-            if self._packed:
-                host_limbs.pack_planar(row, self.agg.packed_width, out=view[i])
-            else:
-                np.copyto(view[i], row)
-        BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
-        ticket = StreamTicket(k)
-        self._stage_seconds += time.monotonic() - t0
-        self._batch_seq += 1
-        trace.get_tracer().record_span(
-            SPAN_STAGE, start=t0, duration=time.monotonic() - t0,
-            batch=self._batch_seq, kind=kind, k=k,
-        )
+        with trace.get_tracer().span(
+            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
+        ):
+            t0 = time.monotonic()
+            buf = self._ring(kind).acquire()
+            view = buf[:k]
+            for i, row in enumerate(rows):
+                if self._packed:
+                    host_limbs.pack_planar(row, self.agg.packed_width, out=view[i])
+                else:
+                    np.copyto(view[i], row)
+            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
+            ticket = StreamTicket(k)
+            self._stage_seconds += time.monotonic() - t0
+            self._batch_seq += 1
         self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
         return ticket
 
@@ -863,20 +894,19 @@ class StreamingAggregator:
             raise ValueError("expected uint8[K, model_len * bytes_per_number]")
         k = raw.shape[0]
         self._check(k)
-        t0 = time.monotonic()
-        ring = self._ring("wire")
-        buf = ring.acquire()
-        view = buf[:k]
-        view[:, : raw.shape[1]] = raw
-        if agg.padded_length != agg.model_length:
-            view[:, raw.shape[1] :] = 0  # zero bytes decode to zero elements
-        BYTES_STAGED.labels(layout="wire").inc(view.nbytes)
-        ticket = StreamTicket(k)
-        self._stage_seconds += time.monotonic() - t0
-        trace.get_tracer().record_span(
-            SPAN_STAGE, start=t0, duration=time.monotonic() - t0,
-            batch=self._batch_seq + 1, kind="wire", k=k,
-        )
+        with trace.get_tracer().span(
+            SPAN_STAGE, batch=self._batch_seq + 1, kind="wire", k=k
+        ):
+            t0 = time.monotonic()
+            ring = self._ring("wire")
+            buf = ring.acquire()
+            view = buf[:k]
+            view[:, : raw.shape[1]] = raw
+            if agg.padded_length != agg.model_length:
+                view[:, raw.shape[1] :] = 0  # zero bytes decode to zero elements
+            BYTES_STAGED.labels(layout="wire").inc(view.nbytes)
+            ticket = StreamTicket(k)
+            self._stage_seconds += time.monotonic() - t0
         if self._sharded:
             return self._dispatch_sharded_wire(ring, buf, view, k, ticket)
         self._batch_seq += 1
@@ -914,24 +944,27 @@ class StreamingAggregator:
 
         agg = self.agg
         if kind == "wire":
-            staged = jax.device_put(payload, agg._batch_bytes_sharding)
-            ok = agg.dispatch_staged_bytes(staged)
-            # -- acc now references this batch: no retry beyond this line --
-            if defer_ok:
-                ticket._ok = ok
-                with self._lock:
-                    self._pending.append(ticket)
-                try:
-                    # the transfer out of the ring buffer must complete
-                    # before reuse; the fold itself stays in flight behind it
-                    jax.block_until_ready(staged)  # lint: sync-ok
-                except BaseException as e:
+            # the copy has a wait of its own only on the worker path; the
+            # degraded path's one sync is the acceptance fetch below
+            with _h2d(kind, payload.nbytes) if defer_ok else nullcontext():
+                staged = jax.device_put(payload, agg._batch_bytes_sharding)
+                ok = agg.dispatch_staged_bytes(staged)
+                # -- acc now references this batch: no retry beyond this line --
+                if defer_ok:
+                    ticket._ok = ok
                     with self._lock:
-                        if ticket in self._pending:
-                            self._pending.remove(ticket)
-                    ticket._ok = None
-                    raise _UnsafeFoldError() from e
-                return
+                        self._pending.append(ticket)
+                    try:
+                        # the transfer out of the ring buffer must complete
+                        # before reuse; the fold itself stays in flight behind it
+                        jax.block_until_ready(staged)  # lint: sync-ok
+                    except BaseException as e:
+                        with self._lock:
+                            if ticket in self._pending:
+                                self._pending.remove(ticket)
+                        ticket._ok = None
+                        raise _UnsafeFoldError() from e
+                    return
             try:
                 ok_host = np.asarray(ok)  # acceptance sync (and fold barrier)  # lint: sync-ok
             except BaseException as e:
@@ -960,15 +993,16 @@ class StreamingAggregator:
             # in place through the native packed kernel)
             self._credit(payload, k, packed=packed)
         else:
-            staged = jax.device_put(
-                payload, agg._batch_packed_sharding if packed else agg._batch_sharding
-            )
-            self._credit(staged, k, packed=packed)
-            try:
-                jax.block_until_ready(staged)  # host buffer free to reuse  # lint: sync-ok
-            except BaseException as e:
-                # _credit already handed the count off: settled
-                raise _UnsafeFoldError(settled=True) from e
+            with _h2d(kind, payload.nbytes):
+                staged = jax.device_put(
+                    payload, agg._batch_packed_sharding if packed else agg._batch_sharding
+                )
+                self._credit(staged, k, packed=packed)
+                try:
+                    jax.block_until_ready(staged)  # host buffer free to reuse  # lint: sync-ok
+                except BaseException as e:
+                    # _credit already handed the count off: settled
+                    raise _UnsafeFoldError(settled=True) from e
         ticket.accepted = np.ones(k, dtype=bool)
 
     def _degrade_and_retry(self, payload, kind: str, k: int, ticket, seq: int,

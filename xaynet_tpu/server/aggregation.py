@@ -30,6 +30,9 @@ from ..core.mask.object import LazyWireMaskVect, MaskObject, MaskUnit, MaskVect
 from ..ops import limbs as limb_ops
 from ..resilience.checkpoint import AggSnapshot
 from ..telemetry import profiling
+from ..telemetry import tracing as trace
+from ..utils.tracing import current_request_id
+from . import stages
 
 
 def build_staged_aggregator(shared) -> "StagedAggregator":
@@ -444,12 +447,17 @@ class StagedAggregator:
                 from ..ops.fold_jax import wire_to_planar
 
                 padded = self._device.padded_length
+                # the relayout outlives this call (and may outlive the
+                # request that staged it), so its span LINKS the caller's
+                # span instead of parenting to it
+                caller, rid = trace.current_ctx(), current_request_id()
 
                 def to_planar(data=obj.vect.data):
-                    planar = wire_to_planar(data)
-                    if planar.shape[1] != padded:
-                        planar = np.pad(planar, ((0, 0), (0, padded - planar.shape[1])))
-                    return planar
+                    with stages.stage("to_planar", link=caller, rid=rid, bytes=data.nbytes):
+                        planar = wire_to_planar(data)
+                        if planar.shape[1] != padded:
+                            planar = np.pad(planar, ((0, 0), (0, padded - planar.shape[1])))
+                        return planar
 
                 self._staged_vect.append(self._ingest_pool.submit(to_planar))
         else:

@@ -37,6 +37,7 @@ from ...telemetry import tracing as trace
 from ...telemetry.recorder import flight_dump
 from ...telemetry.registry import get_registry
 from ...utils import tracing
+from .. import stages
 from ..events import EventPublisher, PhaseName
 from ..requests import (
     ChannelClosed,
@@ -45,6 +46,7 @@ from ..requests import (
     PartialAggregate,
     RequestError,
     RequestReceiver,
+    UPDATE_REQUESTS,
     StateMachineRequest,
 )
 from ..settings import PhaseSettings, Settings, Sum2Settings
@@ -74,6 +76,8 @@ _PHASE_SPANS: dict[str, str] = {
     "shutdown": trace.declare_span("phase.shutdown"),
 }
 SPAN_PARTIAL = trace.declare_span("edge.upstream_fold")
+# the longest single `update.await_request` span (see _next_request)
+_AWAIT_SLICE_S = 1.0
 
 
 class PhaseError(Exception):
@@ -416,11 +420,33 @@ class PhaseState:
             remaining = deadline - time_mod.monotonic()
             if remaining <= 0:
                 return
-            try:
-                env = await asyncio.wait_for(self.shared.request_rx.next_request(), remaining)
-            except asyncio.TimeoutError:
+            env = await self._next_request(remaining)
+            if env is None:
                 return
             await self._process_single(env, counter)
+
+    async def _next_request(self, timeout: float):
+        """The next envelope, or None when ``timeout`` passes first. The
+        span says what the state machine is doing meanwhile — nothing: in a
+        device trace it is the honest name for most of a window's idle gap.
+
+        The wait is taken in slices of at most ``_AWAIT_SLICE_S``, one span
+        each: a profiler session records no annotation that began before it
+        did, and a wait is the one span that can be arbitrarily long, so a
+        session that opens mid-wait sees it from the next slice on. The cost
+        is one wakeup per slice of an idle state machine."""
+        deadline = time_mod.monotonic() + timeout
+        while True:
+            left = deadline - time_mod.monotonic()
+            if left <= 0:
+                return None
+            with trace.get_tracer().span(stages.SPAN_AWAIT_REQUEST, phase=self.NAME.value):
+                try:
+                    return await asyncio.wait_for(
+                        self.shared.request_rx.next_request(), min(left, _AWAIT_SLICE_S)
+                    )
+                except asyncio.TimeoutError:
+                    pass
 
     async def _process_until_enough(self, counter: _Counter, deadline: float) -> None:
         """Accept until ``count.min`` — or until the ``time.max`` deadline
@@ -460,11 +486,8 @@ class PhaseState:
                 wait = time_left
                 if at_quorum:
                     wait = min(wait, stall_grace - (now - last_accept))
-                try:
-                    env = await asyncio.wait_for(
-                        self.shared.request_rx.next_request(), wait
-                    )
-                except asyncio.TimeoutError:
+                env = await self._next_request(wait)
+                if env is None:
                     continue  # re-evaluate the deadline / stall clock
             accepted_before = counter.accepted
             await self._process_single(env, counter)
@@ -472,6 +495,8 @@ class PhaseState:
                 last_accept = time_mod.monotonic()
 
     async def _process_single(self, env, counter: _Counter) -> None:
+        if env.enqueued and isinstance(env.request, UPDATE_REQUESTS):
+            stages.waited("request_wait", env.enqueued, ctx=env.ctx, rid=env.request_id)
         if isinstance(env.request, CoalescedUpdates):
             # unpack the micro-batch: every member is counted, handled and
             # answered exactly as if it had arrived alone (count.min/max
@@ -509,9 +534,10 @@ class PhaseState:
             return
         t0 = time_mod.monotonic()
         try:
-            with tracing.use_request_id(env.request_id), tracing.span(
-                "handle_request", phase=self.NAME.value
-            ):
+            # the sender's request id and trace context, re-entered on this
+            # side of the channel: the phase's per-message spans are
+            # children of the message's own request span
+            with tracing.use_request_id(env.request_id), trace.use_ctx(env.ctx):
                 await self.handle_request(env.request)
         except RequestError as err:
             counter.rejected += 1
@@ -551,9 +577,7 @@ class PhaseState:
         k = len(env.request)
         t0 = time_mod.monotonic()
         try:
-            with tracing.use_request_id(env.request_id), tracing.span(
-                "handle_partial", phase=self.NAME.value
-            ), trace.get_tracer().span(
+            with tracing.use_request_id(env.request_id), trace.get_tracer().span(
                 SPAN_PARTIAL,
                 link=trace.parse_header(getattr(env.request, "trace", None)),
                 edge_id=getattr(env.request, "edge_id", ""),
@@ -614,6 +638,7 @@ class PhaseState:
             env.request.reject_members(error)
         if env.response.done():
             return
+        env.resolved = time_mod.monotonic()
         if error is None:
             env.response.set_result(None)
         else:

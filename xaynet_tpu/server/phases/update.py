@@ -31,6 +31,7 @@ from ...core.mask.masking import AggregationError
 from ...resilience.chaos import maybe_kill
 from ...resilience.checkpoint import CheckpointManager, RoundCheckpoint, entry, write_entry
 from ...telemetry.registry import get_registry
+from .. import stages
 from ..aggregation import StagedAggregator, build_staged_aggregator
 from ..events import DictionaryUpdate, PhaseName
 from ..requests import (
@@ -155,21 +156,26 @@ class UpdatePhase(PhaseState):
             # kernel + sync — neither may stall the loop serving the API
             # (ordering is preserved: the await completes before the
             # seed-dict insert below)
-            await asyncio.get_running_loop().run_in_executor(
-                None, self.aggregator.validate_aggregation, req.masked_model
-            )
+            with stages.stage("validate"):
+                await asyncio.get_running_loop().run_in_executor(
+                    None, self.aggregator.validate_aggregation, req.masked_model
+                )
         except AggregationError as err:
             raise RequestError(RequestError.Kind.MESSAGE_REJECTED, err.kind) from err
-        store_err = await self.shared.store.coordinator.add_local_seed_dict(
-            req.participant_pk, req.local_seed_dict
-        )
+        with stages.stage("seed_dict"):
+            store_err = await self.shared.store.coordinator.add_local_seed_dict(
+                req.participant_pk, req.local_seed_dict
+            )
         if store_err is not None:
             raise RequestError(RequestError.Kind.MESSAGE_REJECTED, store_err.value)
-        self.aggregator.stage(req.masked_model)
+        with stages.stage("stage"):
+            self.aggregator.stage(req.masked_model)
         if self.aggregator.pending >= self.aggregator.batch_size:
             # fold off the event loop so the API stays responsive during
-            # large folds; handle_request awaits it, so folds serialize
-            await asyncio.get_running_loop().run_in_executor(None, self.aggregator.flush)
+            # large folds; handle_request awaits it, so folds serialize.
+            # The message that fills the batch pays for its flush.
+            with stages.stage("flush", k=self.aggregator.pending):
+                await asyncio.get_running_loop().run_in_executor(None, self.aggregator.flush)
             if self._ckpt is not None:
                 await self._ckpt.maybe_save()
         # chaos hook (kill-matrix harness): dies BEFORE the ack leaves, so

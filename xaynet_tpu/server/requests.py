@@ -10,16 +10,18 @@ phases are purged with a rejection at phase end.
 from __future__ import annotations
 
 import asyncio
+import time
 from dataclasses import dataclass
 from enum import Enum
 from typing import Optional, Union
 
-from ..utils import tracing
-
 from ..core.common import LocalSeedDict
 from ..core.mask.object import MaskObject
 from ..core.message import Message, Sum, Sum2, Update
+from ..telemetry import tracing as trace
 from ..telemetry.registry import get_registry
+from ..utils import tracing
+from . import stages
 
 # depth of the services -> state-machine queue: the leading indicator of a
 # phase falling behind its ingest (scraped via GET /metrics). Labelled per
@@ -140,6 +142,9 @@ class EnvelopeReplay(Exception):
     folded envelope as rejected data loss."""
 
 
+# what travels the per-message stage chain of server/stages.py
+UPDATE_REQUESTS = (UpdateRequest, CoalescedUpdates)
+
 StateMachineRequest = Union[
     SumRequest, UpdateRequest, Sum2Request, CoalescedUpdates, PartialAggregate
 ]
@@ -167,6 +172,15 @@ class _Envelope:
     request: StateMachineRequest
     response: asyncio.Future
     request_id: str = "-"
+    # when it entered the channel (``time.monotonic()``) and the sender's
+    # trace context: the phase takes the channel wait from the first and
+    # parents its per-message spans to the second (server/stages.py).
+    # Member envelopes of a coalesced batch carry neither: the batch waited.
+    enqueued: float = 0.0
+    ctx: Optional[trace.TraceContext] = None
+    # when the phase resolved ``response``: the sender takes from it how
+    # long its coroutine then waited for the event loop
+    resolved: float = 0.0
 
 
 class RequestReceiver:
@@ -284,5 +298,12 @@ class RequestSender:
         Raises ``RequestError`` when the request is rejected/discarded.
         """
         fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        self._receiver._enqueue(_Envelope(req, fut, tracing.current_request_id()))
-        await fut
+        env = _Envelope(
+            req, fut, tracing.current_request_id(), time.monotonic(), trace.current_ctx()
+        )
+        self._receiver._enqueue(env)
+        try:
+            await fut
+        finally:
+            if env.resolved and isinstance(req, UPDATE_REQUESTS):
+                stages.waited("verdict_wait", env.resolved)

@@ -35,6 +35,8 @@ import numpy as np
 
 from ..telemetry import tracing as trace
 from ..telemetry.registry import MetricsRegistry, get_registry
+from ..utils import tracing
+from . import stages
 from .requests import RequestError
 from .services import Fetcher, PetMessageHandler, ServiceError
 
@@ -150,6 +152,15 @@ class RestServer:
             "('' = the bare single-tenant routes).",
             ("method", "path", "status", "tenant"),
         )
+        self._loop_lag = self.registry.histogram(
+            "xaynet_event_loop_lag_seconds",
+            "How late a 100 ms sleep on the loop that serves the API woke: "
+            "the time a ready task waits for the loop (bodies being read, "
+            "handlers between awaits) as opposed to for a worker or a queue.",
+            buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                     0.5, 1.0, 2.5, 5.0, 10.0),
+        )
+        self._lag_task: Optional[asyncio.Task] = None
         self._server: Optional[asyncio.AbstractServer] = None
         # live connections: stop() closes them — an idle keep-alive peer
         # would otherwise hold the process for read_timeout seconds
@@ -159,6 +170,7 @@ class RestServer:
         self, host: str = "127.0.0.1", port: int = 8081, tls: Optional[ssl.SSLContext] = None
     ) -> tuple[str, int]:
         self._server = await asyncio.start_server(self._handle_conn, host, port, ssl=tls)
+        self._lag_task = asyncio.create_task(self._watch_loop_lag(), name="rest-loop-lag")
         addr = self._server.sockets[0].getsockname()
         logger.info("REST API listening on %s:%d", addr[0], addr[1])
         return addr[0], addr[1]
@@ -171,10 +183,24 @@ class RestServer:
         minutes — and still owns its accelerator."""
         if self._server is None:
             return
+        if self._lag_task is not None:
+            self._lag_task.cancel()
+            await asyncio.gather(self._lag_task, return_exceptions=True)
+            self._lag_task = None
         self._server.close()
         for writer in list(self._writers):
             writer.close()
         await self._server.wait_closed()
+
+    async def _watch_loop_lag(self, period: float = 0.1) -> None:
+        """Observe, every ``period`` seconds, how late this loop ran a task
+        that was due: what tells "many bodies on one loop" from "the loop is
+        idle and the workers are the queue"."""
+        loop = asyncio.get_running_loop()
+        while True:
+            due = loop.time() + period
+            await asyncio.sleep(period)
+            self._loop_lag.observe(max(0.0, loop.time() - due))
 
     # --- request handling -------------------------------------------------
 
@@ -201,13 +227,16 @@ class RestServer:
                 if length > MAX_BODY:
                     await self._respond(writer, 413, b"body too large")
                     break
-                body = (
-                    await asyncio.wait_for(reader.readexactly(length), self.read_timeout)
-                    if length
-                    else b""
-                )
+
+                async def read_body(length=length) -> bytes:
+                    if not length:
+                        return b""
+                    return await asyncio.wait_for(reader.readexactly(length), self.read_timeout)
+
                 keep_alive = headers.get("connection", "keep-alive").lower() != "close"
-                status, payload, ctype, extra = await self._route(method, target, body, headers)
+                status, payload, ctype, extra = await self._route(
+                    method, target, read_body, headers
+                )
                 await self._respond(writer, status, payload, ctype, keep_alive, extra)
                 if not keep_alive:
                     break
@@ -233,10 +262,22 @@ class RestServer:
         sub = "/" + (parts[3] if len(parts) > 3 else "")
         return tid, sub, routes
 
-    async def _route(self, method: str, target: str, body: bytes, headers=None):
+    async def _route(self, method: str, target: str, body, headers=None):
+        """``body`` is the request's bytes, or (from ``_handle_conn``) the
+        coroutine function that reads them off the socket. Only a traced
+        POST of a message defers the read, into its ``rest.request`` span,
+        so that the read is that span's first stage; every other request's
+        body is read here, before anything is decided, as it always was."""
         url = urlparse(target)
         headers = headers or {}
-        if url.path == "/admin/tenants" or url.path.startswith("/admin/tenants/"):
+        admin = url.path == "/admin/tenants" or url.path.startswith("/admin/tenants/")
+        read_body = None
+        if callable(body):
+            if method == "POST" and url.path.endswith("/message") and not admin:
+                read_body = body
+            else:
+                body = await body()
+        if admin:
             status, payload, ctype, extra = await self._admin_route(
                 method, url.path, body, headers
             )
@@ -248,6 +289,8 @@ class RestServer:
             ).inc()
             return status, payload, ctype, extra
         tenant, path, routes = self._resolve_tenant(url.path)
+        if read_body is not None and (routes is None or path in _UNTRACED_PATHS):
+            body, read_body = await read_body(), None
         if routes is None:
             # unknown tenant: closed-cardinality labels (the id is
             # attacker-controlled), no dispatch
@@ -271,6 +314,8 @@ class RestServer:
                 tenant or self.default_tenant
             )
             if not admitted:
+                if read_body is not None:
+                    await read_body()  # shed, but the connection stays in step
                 extra = (
                     {"Retry-After": str(max(1, math.ceil(retry_after)))}
                     if retry_after
@@ -291,9 +336,16 @@ class RestServer:
             # SDK / edge hop) and sets the ambient context, so the ingest
             # admission span below lands in the same trace
             remote = trace.parse_header(headers.get(trace.TRACE_HEADER.lower()))
-            with trace.get_tracer().span(
+            # a message is named here, before its body is read: every stage
+            # span from the socket to the fold carries this id as `rid`
+            with tracing.use_request_id(tracing.make_request_id()), trace.get_tracer().span(
                 SPAN_REQUEST, link=remote, method=method, path=path, tenant=tenant
             ) as span:
+                if read_body is not None:
+                    with stages.stage(
+                        "read_body", bytes=int(headers.get("content-length", "0"))
+                    ):
+                        body = await read_body()
                 result = await self._dispatch(method, path, url.query, body, headers, routes)
                 span.set(status=result[0])
         status, payload, ctype = result[:3]
@@ -390,6 +442,15 @@ class RestServer:
                 tenancy = self._tenancy_health()
                 if tenancy is not None:
                     payload["tenancy"] = tenancy
+                # which spans also go to the mirror sink (the profiler's
+                # clock): a trace reader tells the program's spans from the
+                # runtime's own events by this closed set
+                tracer = trace.get_tracer()
+                payload["trace"] = {
+                    "mode": tracer.mode,
+                    "mirror": tracer.mirrored,
+                    "mirrored_spans": trace.mirrored_span_names(),
+                }
                 if routes.health_extra is not None:
                     # role-specific sections (the edge runner reports its
                     # upstream link + envelope backlog here); an extra

@@ -582,6 +582,12 @@ def init_device_backend(settings: Settings):
             "(no accelerator found, or it is held by another process); set "
             "JAX_PLATFORMS=cpu to aggregate on XLA:CPU on purpose"
         )
+    # the program's spans on the profiler's clock: with a profiler session
+    # open they land in the device trace beside the device's operations;
+    # with none an annotation costs an atomic load (docs/DESIGN.md §16)
+    from ..telemetry import tracing as trace
+
+    trace.get_tracer().set_mirror(jax.profiler.TraceAnnotation)
     devices = jax.devices()
     logger.info(
         "device aggregation on backend=%s device_kind=%s devices=%d; "

@@ -17,6 +17,7 @@ offload with a concurrency limit.
 from __future__ import annotations
 
 import asyncio
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
@@ -26,8 +27,10 @@ from ..core.crypto.sign import is_eligible, verify_detached
 from ..core.mask.serialization import DecodeError
 from ..core.message import Chunk, Message, Sum, Sum2, Tag, Update, peek_header
 from ..core.message.encoder import MessageBuilder
+from ..telemetry import tracing as trace
 from ..telemetry.registry import get_registry
 from ..utils import tracing
+from . import stages
 from .events import EventSubscriber, PhaseName
 from .requests import RequestSender, request_from_message
 
@@ -37,15 +40,6 @@ _PHASE_TAGS = {
     PhaseName.SUM2: Tag.SUM2,
 }
 
-# ms-scale crypto stages; the 'total' series includes the state-machine wait
-_PIPELINE_SECONDS = get_registry().histogram(
-    "xaynet_message_pipeline_seconds",
-    "Message-pipeline stage wall time (decrypt_parse = sealed-box open + "
-    "signature verify on the thread pool; total = end-to-end handling).",
-    ("stage",),
-    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
-             0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
-)
 _MULTIPART_BUFFERS = get_registry().gauge(
     "xaynet_multipart_buffers",
     "Multipart reassembly buffers currently held (bounded, oldest-evicted).",
@@ -89,34 +83,40 @@ class PetMessageHandler:
         Raises ``ServiceError`` (pipeline drop) or ``RequestError`` (state
         machine rejection).
         """
-        tracing.new_request_id()
-        with tracing.span("handle_message", size=len(encrypted)):
-            with _PIPELINE_SECONDS.labels(stage="total").time():
-                with _PIPELINE_SECONDS.labels(stage="decrypt_parse").time():
+        with tracing.use_request_id(tracing.request_id_or_fresh()):
+            with stages.SECONDS.labels(stage="total").time():
+                with stages.SECONDS.labels(stage="decrypt_parse").time():
                     message = await self._parse_message(encrypted)
                 if message is None:
                     return  # multipart message still incomplete
-                with tracing.span("task_validator"):
-                    self._validate_task(message)
+                self._validate_task(message)
                 await self.request_tx.request(request_from_message(message))
 
     # --- pipeline stages --------------------------------------------------
 
     def _decrypt_parse_one(
-        self, encrypted: bytes, keys: EncryptKeyPair, phase: PhaseName
+        self,
+        encrypted: bytes,
+        keys: EncryptKeyPair,
+        phase: PhaseName,
+        ctx: Optional[trace.TraceContext] = None,
+        rid: str = "-",
     ) -> Message:
         """Sealed-box open + phase filter + signature verify + parse.
 
         Synchronous CPU body shared by the per-message path and the batched
-        ingest workers; always runs on a worker thread.
+        ingest workers; always runs on a worker thread, so the caller hands
+        over what does not cross the hop: the parent span's ``ctx`` and the
+        request id.
         """
         # sealed-box open (CPU) — reference: decryptor.rs:48-69. Passing our
         # public key skips a per-message X25519 recompute of it (milliseconds
         # per message on the pure-python fallback)
-        try:
-            raw = keys.secret.decrypt(encrypted, keys.public)
-        except (DecryptError, ValueError) as e:
-            raise ServiceError("decrypt", str(e)) from e
+        with stages.stage("open", ctx=ctx, rid=rid, bytes=len(encrypted)):
+            try:
+                raw = keys.secret.decrypt(encrypted, keys.public)
+            except (DecryptError, ValueError) as e:
+                raise ServiceError("decrypt", str(e)) from e
         # phase filter before the expensive signature check
         # (reference: message_parser.rs:88-141)
         try:
@@ -131,9 +131,15 @@ class PetMessageHandler:
             raise ServiceError(  # lint: taint-ok: one-byte message-type tag, not key bytes
                 "phase-filter", f"{tag.name} message during {phase.value}"
             )
-        # signature verification + full parse
+        # signature verification, then the full parse: one pass each over
+        # the body, timed apart
         try:
-            return Message.from_bytes(raw, verify=True, lazy_update_vect=self.wire_ingest)
+            with stages.stage("verify", ctx=ctx, rid=rid, bytes=len(raw)):
+                Message.verify_bytes(raw)
+            with stages.stage("parse", ctx=ctx, rid=rid, bytes=len(raw)):
+                return Message.from_bytes(
+                    raw, verify=False, lazy_update_vect=self.wire_ingest
+                )
         except DecodeError as e:
             raise ServiceError("parse", str(e)) from e
 
@@ -141,9 +147,16 @@ class PetMessageHandler:
         loop = asyncio.get_running_loop()
         keys: EncryptKeyPair = self.events.keys.get_latest().event
         phase: PhaseName = self.events.phase.get_latest().event
-        message = await loop.run_in_executor(
-            self._pool, self._decrypt_parse_one, encrypted, keys, phase
-        )
+        ctx, rid, submitted = trace.current_ctx(), tracing.current_request_id(), time.monotonic()
+
+        def on_worker() -> tuple[Message, float]:
+            stages.waited("pool_wait", submitted, ctx=ctx, rid=rid)
+            message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid)
+            return message, time.monotonic()
+
+        message, returned = await loop.run_in_executor(self._pool, on_worker)
+        # the worker is done; this coroutine waited for the loop since then
+        stages.waited("resume_wait", returned)
         if message.is_multipart:
             return self._handle_chunk(message)
         return message
@@ -162,12 +175,13 @@ class PetMessageHandler:
         keys: EncryptKeyPair = self.events.keys.get_latest().event
         phase: PhaseName = self.events.phase.get_latest().event
         params: RoundParameters = self.events.params.get_latest().event
+        ctx = trace.current_ctx()  # the ingest.decrypt_batch span
 
         def run() -> list:
             out = []
             for encrypted in batch:
                 try:
-                    message = self._decrypt_parse_one(encrypted, keys, phase)
+                    message = self._decrypt_parse_one(encrypted, keys, phase, ctx)
                     if not message.is_multipart:
                         self._validate_task_with(message, params)
                     out.append(message)
@@ -175,7 +189,7 @@ class PetMessageHandler:
                     out.append(e)
             return out
 
-        with _PIPELINE_SECONDS.labels(stage="decrypt_parse_batch").time():
+        with stages.SECONDS.labels(stage="decrypt_parse_batch").time():
             results = await loop.run_in_executor(self._pool, run)
         final = []
         for res in results:

@@ -88,13 +88,19 @@ class SpanNameError(ValueError):
 # duplicate-declaration diagnostic). The analysis `span` pass mirrors this
 # statically and cross-checks it against the DESIGN §16 table.
 _SPAN_NAMES: dict[str, str] = {}
+# the subset a mirror sink also receives (``Tracer.set_mirror``): chosen at
+# the declaration, never at a call site. Spans that would cover every idle
+# gap whole (``round``, ``phase.*``, ``rest.request``) stay out of it.
+_MIRRORED: set[str] = set()
 _names_lock = threading.Lock()
 
 
-def declare_span(name: str) -> str:
+def declare_span(name: str, mirror: bool = False) -> str:
     """Register one span name exactly once (module import time).
 
     Returns the name so modules can bind it: ``SPAN_X = declare_span("x.y")``.
+    ``mirror=True`` also hands the span, when opened with ``with``, to the
+    tracer's mirror sink (the profiler's clock; docs/DESIGN.md §16).
     """
     if not name or any(c.isspace() for c in name):
         raise SpanNameError(f"bad span name {name!r}")
@@ -112,6 +118,8 @@ def declare_span(name: str) -> str:
                 "one module owns a span name — import its constant instead"
             )
         _SPAN_NAMES[name] = module
+        if mirror:
+            _MIRRORED.add(name)
     return name
 
 
@@ -119,6 +127,14 @@ def declared_span_names() -> dict[str, str]:
     """Snapshot of the declared span names (tests, the analysis pass)."""
     with _names_lock:
         return dict(_SPAN_NAMES)
+
+
+def mirrored_span_names() -> list[str]:
+    """The declared names a mirror sink receives, sorted (``/healthz``
+    publishes them so a trace reader can tell program spans from the
+    runtime's own events without a copied list)."""
+    with _names_lock:
+        return sorted(_MIRRORED)
 
 
 # the root span every phase span parents to; declared here because the
@@ -196,6 +212,26 @@ def current_ctx() -> Optional[TraceContext]:
     return _ctx.get()
 
 
+class use_ctx:
+    """Re-enter a context carried across a queue (the request envelope):
+    spans opened inside parent to ``ctx`` as if opened where it was taken.
+    ``None`` leaves the ambient context as it is."""
+
+    __slots__ = ("_ctx", "_token")
+
+    def __init__(self, ctx: Optional[TraceContext]):
+        self._ctx = ctx
+        self._token = None
+
+    def __enter__(self) -> None:
+        if self._ctx is not None:
+            self._token = _ctx.set(self._ctx)
+
+    def __exit__(self, *exc) -> None:
+        if self._token is not None:
+            _ctx.reset(self._token)
+
+
 class Span:
     """One finished (or in-flight) span. Walls are monotonic."""
 
@@ -244,12 +280,13 @@ class _SpanHandle:
     exception path by construction (the analysis ``span`` pass rejects
     non-``with`` uses)."""
 
-    __slots__ = ("_tracer", "_span", "_token")
+    __slots__ = ("_tracer", "_span", "_token", "_mirror")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
         self._token = None
+        self._mirror = None
 
     @property
     def ctx(self) -> TraceContext:
@@ -261,9 +298,22 @@ class _SpanHandle:
 
     def __enter__(self) -> "_SpanHandle":
         self._token = _ctx.set(self.ctx)
+        factory = self._tracer._mirror
+        if factory is not None and self._span.name in _MIRRORED:
+            try:
+                self._mirror = factory(self._span.name, **self._span.attrs)
+                self._mirror.__enter__()
+            except Exception:  # a telemetry consumer must never fail the work
+                self._mirror = None
+                logger.exception("trace mirror failed to open %s", self._span.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._mirror is not None:
+            try:
+                self._mirror.__exit__(exc_type, exc, tb)
+            except Exception:
+                logger.exception("trace mirror failed to close %s", self._span.name)
         _ctx.reset(self._token)
         self._span.duration = time.monotonic() - self._span.start
         if exc is not None:
@@ -331,6 +381,8 @@ class Tracer:
         # window closes (the timeline fold consumes the span buffer here);
         # fail-soft by contract
         self._flush_hooks: list = []
+        # optional second sink for mirror=True spans (set_mirror)
+        self._mirror = None
 
     # -- configuration -----------------------------------------------------
 
@@ -346,6 +398,21 @@ class Tracer:
         if ring_size is not None:
             with self._lock:
                 self._ring = deque(self._ring, maxlen=ring_size)
+
+    def set_mirror(self, factory) -> None:
+        """Install (or with ``None`` remove) the mirror sink: spans declared
+        ``mirror=True`` and opened with ``with`` also enter and exit
+        ``factory(name, **attrs)``, a context manager on another clock. The
+        runner installs ``jax.profiler.TraceAnnotation`` so that the
+        program's stages lie on the device trace's timeline; this module
+        stays stdlib-only. Retroactive ``record_span``s are not mirrored:
+        the profiler has no call for an interval that is already over."""
+        self._mirror = factory
+
+    @property
+    def mirrored(self) -> bool:
+        """Whether a mirror sink is installed."""
+        return self._mirror is not None
 
     def add_round_hook(self, hook) -> None:
         if hook not in self._round_hooks:
