@@ -142,6 +142,42 @@ def test_stream_parse_peak_memory_bounded():
     assert np.array_equal(parsed.model_mask.vect.data, obj.vect.data)
 
 
+def test_stream_parsed_update_is_staged_into_its_ring_slot_as_it_arrives():
+    """The reassembled (multipart) update takes the same route as a
+    one-part message: its wire rows go straight into the open batch's
+    ring slot on the ingest pool, and the flush relays nothing out."""
+    import jax
+
+    from xaynet_tpu.core.mask.masking import Aggregation
+    from xaynet_tpu.parallel.mesh import make_mesh
+    from xaynet_tpu.parallel.streaming import ROWS_STAGED
+    from xaynet_tpu.server.aggregation import StagedAggregator
+
+    obj = _masked(300)
+    payload = Update(
+        sum_signature=b"\x0a" * 64,
+        update_signature=b"\x0b" * 64,
+        masked_model=obj,
+        local_seed_dict={},
+    )
+    got = _roundtrip_stream(payload, Tag.UPDATE).masked_model
+    dev = StagedAggregator(
+        CFG.pair(), 300, device=True, batch_size=2, kernel="xla",
+        mesh=make_mesh(jax.devices()[:1]),
+    )
+    arrival, flush = (ROWS_STAGED.labels(route=r) for r in ("arrival", "flush"))
+    before = arrival.value, flush.value
+    for update in (got, obj):
+        dev.validate_aggregation(update)
+        dev.aggregate(update)  # the second fills the batch: flush
+    dev.drain()
+    assert (arrival.value, flush.value) == (before[0] + 2, before[1])
+    host = Aggregation(CFG.pair(), 300)
+    host.aggregate(obj)
+    host.aggregate(obj)
+    assert dev.finalize().object == host.object
+
+
 # --- chunk-level send retry -------------------------------------------------
 
 

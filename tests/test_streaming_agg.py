@@ -9,6 +9,7 @@ ahead of late-completing folds, plus the batch-prevalidation single-
 dispatch contract and the settings/metrics surface.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -323,3 +324,322 @@ def test_prevalidate_skips_count_mismatched_member():
     with pytest.raises(AggregationError):  # ModelMismatch for THAT member only
         dev.validate_aggregation(short)
     assert dev.nb_models == 1
+
+
+# -- staging at arrival (ISSUE 25) ------------------------------------------
+#
+# A single-device pipeline lends an open batch its ring buffer and every
+# accepted update is written into its own slot by its ``xn-ingest`` task;
+# ``flush()`` only waits for the writes and submits the buffer.
+
+
+def _mesh2():
+    return make_mesh(jax.devices()[:2])
+
+
+def _masked_updates(n, total, seed):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(total):
+        w = rng.uniform(-1, 1, size=n).astype(np.float32)
+        out.append(Masker(CFG.pair()).mask(Scalar(1, total), w)[1])
+    return out
+
+
+def _oracle(n, objs):
+    host = Aggregation(CFG.pair(), n)
+    for obj in objs:
+        host.aggregate(obj)
+    return host
+
+
+def _staged(n, batch_size=4, **kw):
+    """A device StagedAggregator whose pipeline is NOT shard-parallel on a
+    2-device mesh, so ``padded_length`` (104 at n = 103) exceeds the model
+    length and the slots have pad columns."""
+    from xaynet_tpu.server.aggregation import StagedAggregator
+
+    kw.setdefault("mesh", _mesh2())
+    kw.setdefault("shard_parallel", False)
+    kw.setdefault("kernel", "xla")
+    return StagedAggregator(CFG.pair(), n, device=True, batch_size=batch_size, **kw)
+
+
+def _rows_staged():
+    from xaynet_tpu.parallel.streaming import ROWS_STAGED
+
+    return (
+        ROWS_STAGED.labels(route="arrival").value,
+        ROWS_STAGED.labels(route="flush").value,
+    )
+
+
+def _await_writes(dev):
+    for batch in dev._open:
+        for write in batch.writes:
+            write.exception(timeout=30)
+
+
+@pytest.mark.parametrize("shape", ["full", "part", "reused", "overrun"])
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
+def test_arrival_staging_bit_equal_to_host_oracle(packed, shape):
+    """full: one batch closed by its last update; part: a batch of 3 of 4
+    closed by drain(); reused: three batches through two DIRTY ring
+    buffers; overrun: 11 updates staged before the one flush, so the third
+    open batch waits for a ring buffer that the flush has to free. Model
+    length 103 on a padded length of 104."""
+    n = 103
+    total = {"full": 4, "part": 3, "reused": 12, "overrun": 11}[shape]
+    objs = _masked_updates(n, total, seed=21)
+    dev = _staged(n, packed_staging=packed, staging_buffers=2)
+    stream = dev._stream
+    assert stream._packed is packed and stream.stages_rows
+    assert stream.agg.padded_length == 104
+    if shape == "reused":
+        ring = stream._ring(stream._host_kind)
+        bufs = [ring.acquire(), ring.acquire()]
+        for buf in bufs:
+            buf.fill(0xFF)
+            ring.release(buf)
+    arrival0, flush0 = _rows_staged()
+    depth0 = STAGING_DEPTH.value
+    for obj in objs:
+        dev.validate_aggregation(obj)
+        if shape == "overrun":
+            dev.stage(obj)
+        else:
+            dev.aggregate(obj)
+    drain = threading.Thread(target=dev.drain, daemon=True)
+    drain.start()
+    drain.join(timeout=60)
+    assert not drain.is_alive()
+    assert _rows_staged() == (arrival0 + total, flush0)
+    assert STAGING_DEPTH.value == depth0
+    got, want = dev.finalize(), _oracle(n, objs)
+    assert got.nb_models == want.nb_models == total
+    assert got.object == want.object
+
+
+def test_rows_land_in_arrival_order_whatever_order_the_pool_finishes_in():
+    from xaynet_tpu.ops import limbs as host_limbs
+
+    n, k = 103, 4
+    objs = _masked_updates(n, k, seed=22)
+    dev = _staged(n, staging_buffers=2)
+    stream = dev._stream
+    ring = stream._ring(stream._host_kind)
+    dirty = ring.acquire()
+    dirty.fill(0xFF)
+    ring.release(dirty)
+    real, finished = stream.stage_row, []
+
+    def late_first(buf, i, wire):
+        time.sleep(0.05 * (k - i))  # slot 0 lands last
+        real(buf, i, wire)
+        finished.append(i)
+
+    stream.stage_row = late_first
+    for obj in objs:
+        dev.stage(obj)
+    _await_writes(dev)
+    assert finished != sorted(finished)
+    buf = dev._open[0].buf
+    for i, obj in enumerate(objs):
+        want = host_limbs.pack_wire(obj.vect.data[None], stream.agg.packed_width)[0]
+        assert np.array_equal(buf[i, :, :n], want)
+        assert not buf[i, :, n:].any()  # the dirty buffer's pad columns
+    dev.drain()
+    assert dev.finalize().object == _oracle(n, objs).object
+
+
+@pytest.mark.parametrize("route", ["fold_partial", "wire_ingest", "mesh2"])
+def test_other_routes_stage_as_before(route):
+    """What does not take the slot route: an edge partial (one row through
+    ``submit_host_planar_rows``), device-resident wire-ingest parts (never
+    staged on the host) and a shard-parallel mesh (per-shard rings, filled
+    at submit)."""
+    from xaynet_tpu.core.mask.object import LazyWireMaskVect, MaskObject
+    from xaynet_tpu.server.aggregation import StagedAggregator
+
+    n, k = 103, 3
+    objs = _masked_updates(n, k, seed=23)
+    host = StagedAggregator(CFG.pair(), n, device=False, batch_size=4)
+    arrival0, flush0 = _rows_staged()
+    if route == "fold_partial":
+        dev = _staged(n)
+        for s in (host, dev):
+            s.fold_partial(objs[0], 2)
+        assert _rows_staged() == (arrival0, flush0 + 1)
+        want_models = 2
+    elif route == "wire_ingest":
+        dev = _staged(n)
+        for obj in objs:
+            host.aggregate(obj)
+            raw = np.array(vect_element_block(serialize_mask_vect(obj.vect)))
+            lazy = MaskObject(LazyWireMaskVect(CFG, raw, n), obj.unit)
+            dev.validate_aggregation(lazy)
+            dev.aggregate(lazy)
+        assert not dev._open
+        dev.drain()
+        assert _rows_staged() == (arrival0, flush0)
+        want_models = k
+    else:
+        dev = _staged(n, shard_parallel=True)
+        assert not dev._stream.stages_rows
+        for obj in objs:
+            host.aggregate(obj)
+            dev.aggregate(obj)
+        assert not dev._open
+        dev.drain()
+        assert _rows_staged() == (arrival0, flush0 + k)
+        want_models = k
+    a, b = host.finalize(), dev.finalize()
+    assert a.nb_models == b.nb_models == want_models
+    assert a.object == b.object
+
+
+def test_stage_returns_without_waiting_when_every_ring_buffer_is_busy():
+    n = 64
+    objs = _masked_updates(n, 2, seed=24)
+    # the host fold that reads the ring buffer in place
+    dev = _staged(n, mesh=_mesh1(), staging_buffers=2, kernel="native-u64")
+    ring = dev._stream._ring(dev._stream._host_kind)
+    busy = [ring.acquire(), ring.acquire()]
+    t0 = time.monotonic()
+    for obj in objs:
+        dev.stage(obj)
+    assert time.monotonic() - t0 < 1.0
+    time.sleep(0.2)
+    writes = dev._open[0].writes
+    assert len(writes) == 2 and not any(w.done() for w in writes)
+    ring.release(busy.pop())  # the first write of the batch takes it
+    dev.drain()
+    ring.release(busy.pop())
+    assert dev.finalize().object == _oracle(n, objs).object
+
+
+def test_failed_slot_write_raises_at_flush_and_returns_the_buffer():
+    n = 64
+    objs = _masked_updates(n, 5, seed=25)
+    dev = _staged(n, mesh=_mesh1())
+    stream = dev._stream
+    depth0 = STAGING_DEPTH.value
+    boom = RuntimeError("slot write died (stand-in)")
+    real = stream.stage_row
+
+    def failing(buf, i, wire):
+        if i == 1:
+            raise boom
+        real(buf, i, wire)
+
+    stream.stage_row = failing
+    for obj in objs[:3]:
+        dev.stage(obj)
+    with pytest.raises(RuntimeError) as err:
+        dev.flush()
+    assert err.value is boom
+    assert STAGING_DEPTH.value == depth0
+    assert dev.pending == 0 and dev.nb_models == 0
+    # the buffer went back clean enough: what is staged next folds correctly
+    stream.stage_row = real
+    for obj in objs[3:]:
+        dev.aggregate(obj)
+    dev.drain()
+    assert STAGING_DEPTH.value == depth0
+    assert dev.finalize().object == _oracle(n, objs[3:]).object
+
+
+@pytest.mark.parametrize("exit_", ["close", "abandon"])
+def test_open_batch_lease_is_released_on(exit_):
+    import gc
+
+    from xaynet_tpu.tenancy.pool import get_pool
+
+    n = 64
+    tenant = f"open-batch-{exit_}"
+    dev = _staged(n, mesh=_mesh1(), tenant=tenant)
+    depth0 = STAGING_DEPTH.value
+    for obj in _masked_updates(n, 2, seed=26):
+        dev.stage(obj)
+    _await_writes(dev)
+    assert STAGING_DEPTH.value == depth0 + 1
+    assert not get_pool().balanced(tenant)
+    if exit_ == "close":
+        dev._stream.close()
+    else:
+        del dev
+        gc.collect()
+    assert STAGING_DEPTH.value == depth0
+    assert get_pool().balanced(tenant)
+
+
+def test_staging_ring_grows_on_demand_up_to_size_then_blocks():
+    import queue
+
+    from xaynet_tpu.parallel.streaming import _StagingRing
+    from xaynet_tpu.tenancy.pool import get_pool
+
+    pool, tenant = get_pool(), "lazy-ring"
+    ring = _StagingRing(2, (4, 2, 64), np.uint32, pool=pool, tenant=tenant)
+    assert len(pool.outstanding(tenant)) == 0  # nothing leased up front
+    a = ring.acquire()
+    assert len(pool.outstanding(tenant)) == 1
+    ring.release(a)
+    a = ring.acquire()  # a free buffer is reused before the ring grows
+    assert len(pool.outstanding(tenant)) == 1
+    b = ring.acquire()
+    assert len(pool.outstanding(tenant)) == 2
+    with pytest.raises(queue.Empty):
+        ring.acquire(timeout=0.05)  # at size: blocks, as the eager ring did
+    assert len(pool.outstanding(tenant)) == 2
+    ring.release(b)
+    assert ring.acquire(timeout=1.0) is b
+    ring.close()
+    assert pool.balanced(tenant)
+    del a
+
+
+def test_staging_ring_stays_within_size_under_concurrent_acquires():
+    """More threads than cores take and return buffers of a ring of 3 under
+    a short switch interval: never more than 3 leases, every buffer has
+    one holder at a time, and the depth gauge comes back."""
+    import sys
+
+    from xaynet_tpu.parallel.streaming import _StagingRing
+    from xaynet_tpu.tenancy.pool import get_pool
+
+    pool, tenant = get_pool(), "ring-stress"
+    ring = _StagingRing(3, (2, 2, 32), np.uint32, pool=pool, tenant=tenant)
+    depth0 = STAGING_DEPTH.value
+    held, most, errors = set(), [0], []
+    guard = threading.Lock()
+
+    def churn():
+        try:
+            for _ in range(150):
+                buf = ring.acquire(timeout=30)
+                with guard:
+                    assert id(buf) not in held
+                    held.add(id(buf))
+                    most[0] = max(most[0], len(pool.outstanding(tenant)))
+                with guard:
+                    held.discard(id(buf))
+                ring.release(buf)
+        except BaseException as e:  # surfaced through the assertion below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=churn, daemon=True) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and not errors
+    assert most[0] <= 3 and len(pool.outstanding(tenant)) <= 3
+    assert STAGING_DEPTH.value == depth0
+    ring.close()
+    assert pool.balanced(tenant)

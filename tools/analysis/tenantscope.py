@@ -65,9 +65,10 @@ _LEASE_CALLS = frozenset({"lease_host", "lease_device"})
 
 # (file, function qualname) -> rationale naming the paired release.
 LEASE_SITES: dict[tuple[str, str], str] = {
-    ("xaynet_tpu/parallel/streaming.py", "_StagingRing.__init__"):
-        "staging ring buffers; released by ring.close() from the "
-        "pipeline's close(), GC finalizer as the crash backstop",
+    ("xaynet_tpu/parallel/streaming.py", "_StagingRing._grow"):
+        "staging ring buffers, leased as acquire() needs them up to the "
+        "ring's size; released by ring.close() from the pipeline's "
+        "close(), GC finalizer as the crash backstop",
     ("xaynet_tpu/parallel/shards.py", "ShardPlan._alloc"):
         "per-shard accumulator/spare buffers; released by "
         "release_pages() from the round's unmask tail, GC finalizer + "
@@ -97,9 +98,6 @@ _LOCK_NAME_RE = re.compile(r"(_lock|_cond)$")
 
 # (file, function qualname) -> rationale proving the quiescence protocol.
 MIGRATION_SITES: dict[tuple[str, str], str] = {
-    ("xaynet_tpu/parallel/streaming.py", "_StagingRing.__init__"):
-        "free ring buffers opt in at construction, before any is handed "
-        "out; acquire() pins before the first access",
     ("xaynet_tpu/parallel/streaming.py", "_StagingRing.acquire"):
         "clears the migrator THROUGH the pool lock before reading "
         "lease.array — an in-flight buffer is an immovable barrier",

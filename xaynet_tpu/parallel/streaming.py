@@ -5,13 +5,17 @@ fold — host staging (pad + transpose + ``device_put``), the fold dispatch,
 and (on the wire path) a blocking acceptance-vector fetch — so the host and
 the device take turns idling. This module turns that into a pipeline:
 
-- **staging buffer ring** — a small set of pre-allocated host buffers;
-  batch N+1 is padded/copied into a ring buffer while batch N folds, and
-  the per-batch ``np.pad``/``np.stack`` allocations (plus their page-fault
-  tax, ~0.15 s per 200 MB at 25M params) disappear entirely. A buffer is
-  reused only after the fold that consumed it has finished reading host
-  memory (for device kernels: after the ``device_put`` transfer is
-  complete; for the native host kernel: after the fold call returns).
+- **staging buffer ring** — a small, bounded set of host buffers, leased
+  as they are first needed; batch N+1 is padded/copied into a ring buffer
+  while batch N folds, and the per-batch ``np.pad``/``np.stack``
+  allocations (plus their page-fault tax, ~0.15 s per 200 MB at 25M
+  params) disappear entirely. A buffer is reused only after the fold that
+  consumed it has finished reading host memory (for device kernels: after
+  the ``device_put`` transfer is complete; for the native host kernel:
+  after the fold call returns). A single-device pipeline lends the buffer
+  to a batch that is still filling (``open_batch``), so its rows are
+  written into their slots as they arrive (``stage_row``) and submitting
+  the batch (``submit_staged``) relays nothing out.
 - **dispatch-ahead depth** — up to ``dispatch_ahead`` batches are queued to
   a single fold worker thread, so XLA's asynchronous dispatch keeps
   multiple folds in flight behind one another while the producer stages
@@ -162,6 +166,13 @@ H2D_BYTES = _registry.counter(
     "xaynet_streaming_h2d_bytes_total",
     "Bytes of staged batches copied host to device by the fold worker.",
 )
+ROWS_STAGED = _registry.counter(
+    "xaynet_streaming_rows_staged_total",
+    "Host rows relaid out into a staging ring buffer, by where the relayout "
+    "ran (arrival = into the open batch's slot as the update arrived, "
+    "flush = inside the submit call that closed the batch).",
+    ("route",),
+)
 _SHUTDOWN = object()
 
 
@@ -262,8 +273,14 @@ class _UnmaskJob:
         self.done = threading.Event()
 
 
-def _release_ring_leases(pool, leases: list) -> None:
-    """Module-level so a ring's GC finalizer holds no ring reference."""
+def _release_ring(pool, leases: list, inflight: dict, gauge) -> None:
+    """Give a ring's pages back and settle the depth gauges for buffers
+    still checked out (an open batch that was never submitted). Idempotent;
+    module-level so a ring's GC finalizer holds no ring reference."""
+    STAGING_DEPTH.dec(len(inflight))
+    if gauge is not None:
+        gauge.dec(len(inflight))
+    inflight.clear()
     for lease in leases:
         pool.release(lease)
 
@@ -278,11 +295,14 @@ def _ring_migrator(view) -> None:
 
 
 class _StagingRing:
-    """Fixed pool of pre-allocated host staging buffers.
+    """Bounded pool of host staging buffers, grown on demand up to ``size``.
 
-    ``acquire`` blocks while every buffer is owned by an in-flight batch —
-    this is the pipeline's memory bound (the producer can run at most
-    ``size`` batches ahead of the fold worker).
+    ``acquire`` hands out a free buffer, leases a new one while fewer than
+    ``size`` exist, and otherwise blocks until an in-flight batch returns
+    one — this is the pipeline's memory bound (the producer can run at
+    most ``size`` batches ahead of the fold worker). A round that never
+    has more than one batch in flight leases (and zero-fills) one buffer,
+    not ``size``.
 
     Buffers are page runs LEASED from the shared accumulator pool
     (``tenancy.pool``) under the ring's tenant — staging planes (packed
@@ -302,26 +322,54 @@ class _StagingRing:
                  pool=None, tenant: str = "default"):
         self._free: queue_mod.Queue = queue_mod.Queue()
         self.size = size
+        self._shape = shape
+        self._dtype = dtype
+        self._tenant = tenant
         # per-shard rings report on the shard-labelled gauge; the global
         # depth gauge keeps counting every owned buffer either way
         self._gauge = gauge
         self._pool = pool if pool is not None else get_pool()
-        self._leases = [self._pool.lease_host(tenant, shape, dtype) for _ in range(size)]
+        self._grow_lock = threading.Lock()
+        self._granted = 0  # leases taken or being taken  # guarded-by: _grow_lock
+        self._leases: list = []
         self._inflight: dict[int, object] = {}  # id(view) -> lease, checked-out buffers
-        for lease in self._leases:
-            self._pool.set_migrator(lease, _ring_migrator)
-            self._free.put(lease)
         # abandoned pipelines (dropped without close()) give their pages
         # back when the ring is collected — by then nothing can alias them
-        weakref.finalize(self, _release_ring_leases, self._pool, self._leases)
+        weakref.finalize(
+            self, _release_ring, self._pool, self._leases, self._inflight, gauge
+        )
 
     def close(self) -> None:
-        """Release the ring's page leases (idempotent; the buffers must no
-        longer be in flight — the pipeline drains before closing)."""
-        _release_ring_leases(self._pool, self._leases)
+        """Release the ring's page leases (idempotent). Submitted batches
+        must no longer be in flight — the pipeline drains before closing;
+        a buffer lent to a batch that was never submitted leaves the depth
+        gauges here."""
+        _release_ring(self._pool, self._leases, self._inflight, self._gauge)
+
+    def _grow(self):
+        """A new lease while the ring is under ``size``, else None. The
+        slot is reserved under the lock and the pages are leased (and
+        zero-filled) outside it."""
+        with self._grow_lock:
+            if self._granted >= self.size:
+                return None
+            self._granted += 1
+        try:
+            lease = self._pool.lease_host(self._tenant, self._shape, self._dtype)
+        except BaseException:
+            with self._grow_lock:
+                self._granted -= 1
+            raise
+        self._leases.append(lease)
+        return lease
 
     def acquire(self, timeout: float | None = None) -> np.ndarray:
-        lease = self._free.get(timeout=timeout)
+        try:
+            lease = self._free.get_nowait()
+        except queue_mod.Empty:
+            lease = self._grow()
+            if lease is None:
+                lease = self._free.get(timeout=timeout)
         # pin first, read second: set_migrator takes the pool lock, so a
         # compaction mid-flight either finished (lease.array is the new
         # view) or will now skip this lease entirely
@@ -334,12 +382,12 @@ class _StagingRing:
         return buf
 
     def release(self, buf: np.ndarray) -> None:
-        STAGING_DEPTH.dec()
-        if self._gauge is not None:
-            self._gauge.dec()
         lease = self._inflight.pop(id(buf), None)
         if lease is None:
             return  # close() raced a late release; the lease is gone
+        STAGING_DEPTH.dec()
+        if self._gauge is not None:
+            self._gauge.dec()
         self._pool.set_migrator(lease, _ring_migrator)
         self._free.put(lease)
 
@@ -376,7 +424,9 @@ class StreamingAggregator:
 
     NOT thread-safe for concurrent producers: submits must come from one
     thread at a time (the coordinator's executor serializes them; tests and
-    the bench are single-producer by construction).
+    the bench are single-producer by construction). ``open_batch`` and
+    ``stage_row`` are the exception: the rows of an open batch are written
+    by pool threads, one writer a slot.
     """
 
     def __init__(
@@ -609,16 +659,19 @@ class StreamingAggregator:
         with self._lock:
             return self._error
 
-    def _check(self, k: int) -> None:
+    def _check_usable(self) -> None:
         if self._closed:
             raise StreamingError("pipeline is closed")
         err = self._poisoned()
         if err is not None:
             raise self._poison_error() from err
-        if k > self.max_batch:
-            raise ValueError(f"batch of {k} exceeds max_batch={self.max_batch}")
         if self._window_start is None:
             self._window_start = time.monotonic()
+
+    def _check(self, k: int) -> None:
+        self._check_usable()
+        if k > self.max_batch:
+            raise ValueError(f"batch of {k} exceeds max_batch={self.max_batch}")
 
     def _dispatch(self, item: tuple) -> None:
         """Queue to the fold worker — or, once degraded, fold synchronously
@@ -671,6 +724,109 @@ class StreamingAggregator:
                 self._fold_seconds += time.monotonic() - t0
         BATCHES_TOTAL.labels(stage="folded").inc()
 
+    # -- host batches: open, fill slot by slot, submit ----------------------
+    #
+    # A single-device pipeline lends a batch its ring buffer when the
+    # batch OPENS. Rows are written into their slots while the batch is
+    # still filling (by the caller's own threads: the update phase writes
+    # each accepted update as it arrives) and submitting is bookkeeping.
+    # The shard-parallel pipeline slices a finished batch across its
+    # per-shard rings instead (``_submit_sharded_*``).
+
+    @property
+    def stages_rows(self) -> bool:
+        """Whether ``open_batch``/``stage_row``/``submit_staged`` apply:
+        one ring of whole-width buffers, i.e. no shard-parallel mode."""
+        return not self._sharded
+
+    @property
+    def _host_kind(self) -> str:
+        return "packed" if self._packed else "planar"
+
+    def open_batch(self) -> np.ndarray:
+        """Take the ring buffer the next host batch is staged into: row
+        ``i`` of the batch belongs in ``buf[i]``. Blocks while every ring
+        buffer is owned by a batch in flight (the first call of a round
+        leases the first buffer). The buffer goes back through
+        ``submit_staged`` or ``release_batch``."""
+        if self._sharded:
+            raise StreamingError("shard-parallel pipelines stage per shard at submit")
+        self._check_usable()
+        return self._ring(self._host_kind).acquire()
+
+    def _relay_wire_rows(self, view: np.ndarray, stack: np.ndarray) -> None:
+        """Wire-layout ``uint32[k, model_len, L]`` rows into ``k`` slots of
+        a ring buffer, in the ring's layout, pad columns included (a reused
+        buffer is dirty)."""
+        from ..ops import limbs as host_limbs
+
+        n = self.agg.model_length
+        if self._packed:
+            # pack straight into the byte-planar slots: one strided transpose
+            # of the first bpn wire bytes per element — the same copy class
+            # as the planar transpose below, writing bpn/(4L) of the bytes
+            host_limbs.pack_wire(stack, self.agg.packed_width, out=view[:, :, :n])
+        else:
+            # transpose+pad straight into the slots (numpy strided copy, no
+            # wire_to_planar intermediate)
+            view[:, :, :n] = stack.transpose(0, 2, 1)
+        if self.agg.padded_length != n:
+            view[:, :, n:] = 0
+
+    def stage_row(self, buf: np.ndarray, i: int, wire: np.ndarray) -> None:
+        """Write one wire-layout ``uint32[model_len, L]`` update into slot
+        ``i`` of an open batch's buffer. Any thread; a slot has one
+        writer."""
+        if wire.shape != (self.agg.model_length, self.agg.n_limbs):
+            raise ValueError("expected uint32[model_len, L]")
+        t0 = time.monotonic()
+        self._relay_wire_rows(buf[i : i + 1], wire[None])
+        ROWS_STAGED.labels(route="arrival").inc()
+        with self._lock:
+            self._stage_seconds += time.monotonic() - t0
+
+    def release_batch(self, buf: np.ndarray) -> None:
+        """Return an open batch's buffer unfolded (a failed slot write)."""
+        self._ring(self._host_kind).release(buf)
+
+    def submit_staged(self, buf: np.ndarray, k: int) -> StreamTicket:
+        """Stream-fold the first ``k`` slots of an open batch's buffer,
+        already in the ring's layout. Owns the buffer from here on, an
+        error included."""
+        kind = self._host_kind
+        try:
+            self._check(k)
+        except BaseException:
+            self.release_batch(buf)
+            raise
+        with trace.get_tracer().span(
+            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
+        ):
+            view = buf[:k]
+            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
+            ticket = StreamTicket(k)
+            self._batch_seq += 1
+        self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
+        return ticket
+
+    def _submit_filled(self, k: int, fill) -> StreamTicket:
+        """Open a batch, let ``fill(view)`` relay ``k`` rows out into it
+        inside the ``stream.stage`` span, submit."""
+        with trace.get_tracer().span(
+            SPAN_STAGE, batch=self._batch_seq + 1, kind=self._host_kind, k=k, route="flush"
+        ):
+            t0 = time.monotonic()
+            buf = self.open_batch()
+            try:
+                fill(buf[:k])
+            except BaseException:
+                self.release_batch(buf)
+                raise
+            ROWS_STAGED.labels(route="flush").inc(k)
+            with self._lock:
+                self._stage_seconds += time.monotonic() - t0
+        return self.submit_staged(buf, k)
+
     def submit_batch(self, stack: np.ndarray) -> StreamTicket:
         """Stage + stream-fold wire-layout ``uint32[K, model_len, L]``
         updates (the pre-validated path: all members count immediately)."""
@@ -683,38 +839,27 @@ class StreamingAggregator:
         self._check(k)
         if self._sharded:
             return self._submit_sharded_planar_stack(stack, k)
+        return self._submit_filled(k, lambda view: self._relay_wire_rows(view, stack))
+
+    def submit_host_planar_rows(self, rows: list) -> StreamTicket:
+        """Stream-fold host planar ``[L, padded_len]`` rows (numpy), copied
+        into a ring buffer here so the caller can recycle its arrays."""
+        k = len(rows)
+        if k == 0:
+            raise ValueError("empty planar batch")
+        self._check(k)
+        if self._sharded:
+            return self._submit_sharded_planar_rows(rows, k)
         from ..ops import limbs as host_limbs
 
-        kind = "packed" if self._packed else "planar"
-        with trace.get_tracer().span(
-            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
-        ):
-            t0 = time.monotonic()
-            buf = self._ring(kind).acquire()
-            view = buf[:k]
-            if self._packed:
-                # pack straight into the byte-planar ring buffer: one strided
-                # transpose of the first bpn wire bytes per element — the same
-                # copy class as the planar transpose below, writing bpn/(4L)
-                # of the bytes
-                host_limbs.pack_wire(
-                    stack, self.agg.packed_width, out=view[:, :, : self.agg.model_length]
-                )
-                if self.agg.padded_length != self.agg.model_length:
-                    view[:, :, self.agg.model_length :] = 0
-            else:
-                # transpose+pad straight into the ring buffer (numpy strided
-                # copy, no wire_to_planar intermediate): per-batch host
-                # allocation in the steady state is zero
-                view[:, :, : self.agg.model_length] = stack.transpose(0, 2, 1)
-                if self.agg.padded_length != self.agg.model_length:
-                    view[:, :, self.agg.model_length :] = 0
-            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
-            ticket = StreamTicket(k)
-            self._stage_seconds += time.monotonic() - t0
-            self._batch_seq += 1
-        self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
-        return ticket
+        def fill(view):
+            for i, row in enumerate(rows):
+                if self._packed:
+                    host_limbs.pack_planar(row, self.agg.packed_width, out=view[i])
+                else:
+                    np.copyto(view[i], row)
+
+        return self._submit_filled(k, fill)
 
     def fold_planar_rows_now(self, rows: list) -> None:
         """Fold already device-resident, validity-checked planar
@@ -852,36 +997,6 @@ class StreamingAggregator:
             agg.acc = new_acc
             agg.nb_models += k
 
-    def submit_host_planar_rows(self, rows: list) -> StreamTicket:
-        """Stream-fold host planar ``[L, padded_len]`` rows (numpy), copied
-        into a ring buffer here so the caller can recycle its arrays."""
-        k = len(rows)
-        if k == 0:
-            raise ValueError("empty planar batch")
-        self._check(k)
-        if self._sharded:
-            return self._submit_sharded_planar_rows(rows, k)
-        from ..ops import limbs as host_limbs
-
-        kind = "packed" if self._packed else "planar"
-        with trace.get_tracer().span(
-            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
-        ):
-            t0 = time.monotonic()
-            buf = self._ring(kind).acquire()
-            view = buf[:k]
-            for i, row in enumerate(rows):
-                if self._packed:
-                    host_limbs.pack_planar(row, self.agg.packed_width, out=view[i])
-                else:
-                    np.copyto(view[i], row)
-            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
-            ticket = StreamTicket(k)
-            self._stage_seconds += time.monotonic() - t0
-            self._batch_seq += 1
-        self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
-        return ticket
-
     def submit_wire_batch(self, raw: np.ndarray) -> StreamTicket:
         """Stage + stream-fold RAW wire element blocks
         ``uint8[K, model_len * bpn]``. Acceptance is DEFERRED: the per-member
@@ -905,8 +1020,10 @@ class StreamingAggregator:
             if agg.padded_length != agg.model_length:
                 view[:, raw.shape[1] :] = 0  # zero bytes decode to zero elements
             BYTES_STAGED.labels(layout="wire").inc(view.nbytes)
+            ROWS_STAGED.labels(route="flush").inc(k)
             ticket = StreamTicket(k)
-            self._stage_seconds += time.monotonic() - t0
+            with self._lock:
+                self._stage_seconds += time.monotonic() - t0
         if self._sharded:
             return self._dispatch_sharded_wire(ring, buf, view, k, ticket)
         self._batch_seq += 1
@@ -1348,6 +1465,7 @@ class StreamingAggregator:
                 SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
             )
             items.append((job, d, view, ring, buf))
+        ROWS_STAGED.labels(route="flush").inc(k)
         self._dispatch_sharded(job, items)
         return ticket
 
@@ -1385,6 +1503,7 @@ class StreamingAggregator:
                 SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
             )
             items.append((job, d, view, ring, buf))
+        ROWS_STAGED.labels(route="flush").inc(k)
         self._dispatch_sharded(job, items)
         return ticket
 

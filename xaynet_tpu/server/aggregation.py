@@ -22,6 +22,8 @@ batches.
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from ..core.mask.config import MaskConfigPair
@@ -202,6 +204,26 @@ class DeviceAggregation(Aggregation):
         self._device.release_plan_pages()
 
 
+class _OpenBatch:
+    """One fold batch still filling: a ring buffer of the streaming
+    pipeline and the slot writes submitted into it, in arrival order. The
+    first write to run borrows the buffer (which may wait for a free
+    one); the other writes of the batch wait for it on the lock."""
+
+    __slots__ = ("writes", "buf", "_lock")
+
+    def __init__(self):
+        self.writes: list = []  # futures; write i fills slot i
+        self.buf = None  # guarded-by: _lock
+        self._lock = threading.Lock()
+
+    def buffer(self, stream) -> np.ndarray:
+        with self._lock:
+            if self.buf is None:
+                self.buf = stream.open_batch()
+            return self.buf
+
+
 class StagedAggregator:
     """Stages validated masked updates and folds them in batches."""
 
@@ -225,7 +247,10 @@ class StagedAggregator:
         self.object_size = object_size
         self.tenant = tenant
         self.batch_size = max(1, batch_size)
-        self._staged_vect: list = []  # device: futures of planar arrays
+        # device: device-resident planars (wire ingest) and, on a
+        # shard-parallel pipeline, futures of host planar arrays
+        self._staged_vect: list = []
+        self._open: list[_OpenBatch] = []  # device: batches filling in the ring
         self._staged_unit: list[np.ndarray] = []
         self._count = 0
         self._host = Aggregation(config, object_size)
@@ -434,23 +459,45 @@ class StagedAggregator:
         return self._count
 
     def stage(self, obj: MaskObject) -> None:
-        """Stage an update without folding (caller controls flush timing)."""
+        """Stage an update without folding (caller controls flush timing).
+
+        Never waits: on the device path the relayout (and, for the first
+        row of a batch, the wait for a free ring buffer) runs on the
+        ``xn-ingest`` pool."""
         if self._ingest_pool is not None:
             planar_dev = (
                 obj.vect._staged_planar if isinstance(obj.vect, LazyWireMaskVect) else None
             )
+            # the relayout outlives this call (and may outlive the request
+            # that staged it), so its span LINKS the caller's span instead
+            # of parenting to it
+            caller, rid = trace.current_ctx(), current_request_id()
             if planar_dev is not None:
                 # wire ingest: validate_aggregation already unpacked this
                 # update on device — stage the device-resident planar
                 self._staged_vect.append(planar_dev)
+            elif self._stream.stages_rows:
+                # straight from the wire layout into this update's slot of
+                # the open batch's ring buffer (slot = arrival order), so
+                # the flush that closes the batch relays nothing out
+                batch = self._open[-1] if self._open else None
+                if batch is None or len(batch.writes) >= self._stream.max_batch:
+                    batch = _OpenBatch()
+                    self._open.append(batch)
+                stream, slot = self._stream, len(batch.writes)
+
+                def write_slot(data=obj.vect.data):
+                    buf = batch.buffer(stream)
+                    with stages.stage("to_planar", link=caller, rid=rid, bytes=data.nbytes):
+                        stream.stage_row(buf, slot, data)
+
+                batch.writes.append(self._ingest_pool.submit(write_slot))
             else:
+                # shard-parallel pipeline: rows are sliced across the
+                # per-shard rings when the batch is submitted
                 from ..ops.fold_jax import wire_to_planar
 
                 padded = self._device.padded_length
-                # the relayout outlives this call (and may outlive the
-                # request that staged it), so its span LINKS the caller's
-                # span instead of parenting to it
-                caller, rid = trace.current_ctx(), current_request_id()
 
                 def to_planar(data=obj.vect.data):
                     with stages.stage("to_planar", link=caller, rid=rid, bytes=data.nbytes):
@@ -487,6 +534,7 @@ class StagedAggregator:
 
             from ..ops import limbs as limb_ops
 
+            self._submit_open_batches()
             parts = [p.result() if hasattr(p, "result") else p for p in self._staged_vect]
             self._staged_vect.clear()  # consume destructively: free as we fold
             # wire-v2 members stay PACKED uint8[bpn, padded] through staging
@@ -515,9 +563,10 @@ class StagedAggregator:
                 # bound.
                 self._stream.fold_planar_rows_now(parts)
             else:
-                # host planars: copied into the pipeline's staging ring
-                # (no np.stack allocation) and folded by the worker while
-                # this thread returns to staging the next micro-batch
+                # host planars of a shard-parallel pipeline: sliced into
+                # the per-shard staging rings (no np.stack allocation) and
+                # folded by the workers while this thread returns to
+                # staging the next micro-batch
                 host_rows = [np.asarray(p) for p in parts]
                 for start in range(0, len(host_rows), self._stream.max_batch):
                     self._stream.submit_host_planar_rows(
@@ -540,6 +589,31 @@ class StagedAggregator:
         self._staged_vect.clear()
         self._staged_unit.clear()
         self._count = 0
+
+    def _submit_open_batches(self) -> None:
+        """Hand each open batch to the pipeline once its slot writes have
+        landed, oldest first (a later batch may be waiting for the ring
+        buffer an earlier one gives back). After a failed write nothing
+        more is submitted: every buffer goes back to the ring and the
+        first error is raised."""
+        batches, self._open = self._open, []
+        failed = None
+        for batch in batches:
+            for write in batch.writes:
+                error = write.exception()  # waits for the write to end
+                failed = failed or error
+            if batch.buf is None:
+                continue  # no write got as far as borrowing a buffer
+            if failed is None:
+                self._stream.submit_staged(batch.buf, len(batch.writes))
+            else:
+                self._stream.release_batch(batch.buf)
+        if failed is not None:
+            # what was staged since the last flush is lost with it
+            self._staged_vect.clear()
+            self._staged_unit.clear()
+            self._count = 0
+            raise failed
 
     def drain(self) -> None:
         """Flush, then block until every in-flight fold has completed (the

@@ -201,9 +201,9 @@ class AggregationSettings:
     # streaming pipeline (device=True): how many submitted fold batches may
     # be in flight behind the fold worker before flush() backpressures
     dispatch_ahead: int = 2
-    # pre-allocated host staging buffers (each batch_size x model-sized);
-    # batch N+1 stages into one while batch N folds — >= dispatch_ahead + 1
-    # for full overlap, minimum 2
+    # host staging buffers at most (each batch_size x model-sized, leased
+    # when first needed); batch N+1 stages into one while batch N folds —
+    # >= dispatch_ahead + 1 for full overlap, minimum 2
     staging_buffers: int = 3
     # shard-parallel streaming fold (device=True on a multi-device mesh):
     # one fold worker per mesh device with per-shard staging rings and

@@ -216,9 +216,8 @@ class PagePool:
                 slab_idx = len(self._slabs) - 1
                 start = slab.take(pages)
             lease = self._grant_locked(tenant, "host", pages, slab_idx, start)
-        raw = self._slabs[slab_idx].buf[
-            start * self.page_bytes : start * self.page_bytes + nbytes
-        ]
+            slab_buf = self._slabs[slab_idx].buf
+        raw = slab_buf[start * self.page_bytes : start * self.page_bytes + nbytes]
         view = raw.view(dtype).reshape(shape)
         view.fill(0)  # cross-tenant hygiene: never hand over another
         # tenant's masked bytes
@@ -242,17 +241,17 @@ class PagePool:
     def _grant_locked(
         self, tenant: str, arena: str, pages: int, slab: int, offset: int
     ) -> PageLease:
-        self._next_id += 1
+        self._next_id += 1  # lint: guarded-ok: _locked suffix — every caller holds _lock
         lease = PageLease(
             tenant=tenant,
             arena=arena,
-            lease_id=self._next_id,
+            lease_id=self._next_id,  # lint: guarded-ok: _locked suffix
             pages=pages,
             slab=slab,
             offset=offset if offset is not None else -1,
         )
-        self._leases[lease.lease_id] = lease
-        self._in_use[arena] += pages
+        self._leases[lease.lease_id] = lease  # lint: guarded-ok: _locked suffix
+        self._in_use[arena] += pages  # lint: guarded-ok: _locked suffix
         POOL_PAGES.labels(arena=arena, tenant=tenant).inc(pages)
         POOL_LEASES.labels(arena=arena, tenant=tenant).inc()
         return lease
