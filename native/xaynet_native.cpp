@@ -1149,7 +1149,9 @@ XN_EXPORT int xn_decode_exact(const uint32_t* limbs, uint64_t n, uint32_t n_limb
 // per element, draw the next uniform mask value from the seed's keystream
 // (rejection sampling, byte-stream compatible with the other samplers),
 // fixed-point-encode the weight in double-double (bit-identical to the
-// numpy fast path), add modulo the order, and emit the wire-layout element.
+// numpy fast path, and to encode_vect_exact for every bounded-f32 config
+// B0-B6 when the scalar is dyadic), add modulo the order, and emit the
+// wire-layout element.
 // Returns the new keystream byte offset, or 0 on unsupported parameters.
 XN_EXPORT uint64_t xn_mask_f32(const uint8_t key_bytes[32], uint64_t byte_offset,
                                const float* weights, uint64_t n,
@@ -1218,9 +1220,12 @@ XN_EXPORT uint64_t xn_mask_f32(const uint8_t key_bytes[32], uint64_t byte_offset
     two_prod(hi, e, p, perr);
     perr += lo * e;
     quick_two_sum(p, perr, hi, lo);
+    // floor of (hi, lo) in integers: at B6 the value reaches 2e16 > 2^53,
+    // where hi is an integer already and lo carries the units one double
+    // cannot hold (dd.floor_i64; tests/test_encode_exact.py holds both
+    // routes to encode_vect_exact)
     double f = __builtin_floor(hi);
-    f += __builtin_floor((hi - f) + lo);
-    long long shifted = (long long)f;
+    long long shifted = (long long)f + (long long)__builtin_floor((hi - f) + lo);
     if (shifted < 0) shifted = 0;
 
     // 3. modular add + wire emit (little-endian fixed width)

@@ -33,6 +33,9 @@ _SMOKE = chip_smoke.sizes(cpu=False)
 N = _SMOKE.model_length  # 25M
 K = _SMOKE.batch_size  # the fold batch the smoke runs on the chip
 L, BPN = 2, 7
+# the wide end of the bounded-f32 catalogue (75-bit order, 3 limbs, 10 wire
+# bytes): what the benchmark's resnet50-f32b6m6 cell folds, at its batch of 8
+CFG_WIDE = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B6, ModelType.M6)
 HBM = 16 * 2**30
 
 # temp bytes allowed per argument byte. Measured at this shape, K = 4..8:
@@ -67,17 +70,20 @@ def _keep_fold_fn_cache_clean():
     agg_mod._FOLD_FN_CACHE.update(before)
 
 
-def _builder(devices) -> ShardedAggregator:
+def _builder(devices, cfg=CFG) -> ShardedAggregator:
     """A ShardedAggregator shell over topology devices: the real builder
     methods, none of the constructor's device allocations."""
+    from xaynet_tpu.ops import limbs as host_limbs
+
     agg = object.__new__(ShardedAggregator)
-    agg.config, agg.order, agg.n_limbs = CFG, CFG.order, L
+    agg.config, agg.order = cfg, cfg.order
+    agg.n_limbs = host_limbs.n_limbs_for_order(cfg.order)
     agg.mesh = Mesh(np.asarray(devices), (MODEL_AXIS,))
-    agg.packed_width = BPN
+    agg.packed_width = cfg.bytes_per_number
     return agg
 
 
-def _specs(devices):
+def _specs(devices, L=L, BPN=BPN, K=K):
     """(acc, planar batch, packed batch, wire batch) argument specs."""
     if len(devices) == 1:
         acc_s = batch_s = wire_s = SingleDeviceSharding(devices[0])
@@ -108,11 +114,15 @@ def _compile(fn, *args):
     return mem
 
 
-@pytest.mark.parametrize("n_dev", [1, 4])
-def test_default_device_path_compiles_for_v5e_at_25m(v5e, n_dev):
+@pytest.mark.parametrize("n_dev,cfg,k", [
+    pytest.param(1, CFG, K, id="1-2limb-7B"),
+    pytest.param(4, CFG, K, id="4-2limb-7B"),
+    pytest.param(1, CFG_WIDE, 8, id="1-3limb-10B"),
+])
+def test_default_device_path_compiles_for_v5e_at_25m(v5e, n_dev, cfg, k):
     devices = v5e[:n_dev]
-    agg = _builder(devices)
-    acc, planar, packed, _wire = _specs(devices)
+    agg = _builder(devices, cfg)
+    acc, planar, packed, _wire = _specs(devices, agg.n_limbs, agg.packed_width, k)
     # the race's two candidates fold the planar batch...
     _compile(agg._make_fold_fn("xla"), acc, planar)
     _compile(agg._make_fold_fn("pallas"), acc, planar)
@@ -121,7 +131,7 @@ def test_default_device_path_compiles_for_v5e_at_25m(v5e, n_dev):
     _compile(agg._make_packed_fold_fn("pallas"), acc, packed)
     # wire-v2 validity, and the unmask subtract
     _compile(agg._make_planar_ok_fn(), packed)
-    _compile(lambda a, m: agg_mod._unmask_kernel(a, m, CFG.order), acc, acc)
+    _compile(lambda a, m: agg_mod._unmask_kernel(a, m, cfg.order), acc, acc)
 
 
 def test_shard_parallel_folds_compile_per_device(v5e):

@@ -39,6 +39,10 @@ from xaynet_tpu.parallel.streaming import (
 )
 
 CFG = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M6)
+# the wide end of the bounded-f32 catalogue: 75-bit order, 3 limbs, 10 wire
+# bytes; weights up to 1e6 put the encodings on both sides of 2^53
+CFG3 = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B6, ModelType.M6)
+WIDTHS = pytest.mark.parametrize("cfg", [CFG, CFG3], ids=["2limb-7B", "3limb-10B"])
 
 # these tests pin device 0 explicitly (the conftest forces 8 virtual CPU
 # devices) to exercise the SINGLE-WORKER pipeline; the shard-parallel
@@ -50,13 +54,18 @@ def _mesh1():
     return make_mesh(jax.devices()[:1])
 
 
-def _updates(n, total, seed=0):
+def _weights(rng, n, cfg):
+    bound = float(cfg.add_shift)
+    return rng.uniform(-bound, bound, size=n).astype(np.float32)
+
+
+def _updates(n, total, seed=0, cfg=CFG):
     rng = np.random.default_rng(seed)
-    host = Aggregation(CFG.pair(), n)
+    host = Aggregation(cfg.pair(), n)
     stacks, raws = [], []
     for _ in range(total):
-        w = rng.uniform(-1, 1, size=n).astype(np.float32)
-        _, masked = Masker(CFG.pair()).mask(Scalar(1, total), w)
+        w = _weights(rng, n, cfg)
+        _, masked = Masker(cfg.pair()).mask(Scalar(1, total), w)
         host.aggregate(masked)
         stacks.append(masked.vect.data)
         raws.append(
@@ -67,15 +76,28 @@ def _updates(n, total, seed=0):
     return stacks, raws, host
 
 
-@pytest.mark.parametrize("kernel", KERNELS)
-def test_streaming_planar_byte_identical_to_sequential(kernel):
+def _kernel_cases():
+    """Every fold kernel at both widths; the native u64 fold exists up to
+    2 limbs only, the Pallas fold (interpreted here) stands in its place."""
+    return [
+        pytest.param(cfg, kernel, id=f"{width}-{kernel}")
+        for cfg, width, kernels in (
+            (CFG, "2limb-7B", KERNELS),
+            (CFG3, "3limb-10B", ("xla", "pallas-interpret", "auto")),
+        )
+        for kernel in kernels
+    ]
+
+
+@pytest.mark.parametrize("cfg,kernel", _kernel_cases())
+def test_streaming_planar_byte_identical_to_sequential(cfg, kernel):
     n, total, bs = 103, 13, 4
-    stacks, _, host = _updates(n, total)
-    seq = ShardedAggregator(CFG, n, mesh=_mesh1(), kernel=kernel)
+    stacks, _, host = _updates(n, total, cfg=cfg)
+    seq = ShardedAggregator(cfg, n, mesh=_mesh1(), kernel=kernel)
     for i in range(0, total, bs):
         seq.add_batch(np.stack(stacks[i : i + bs]))
 
-    agg = ShardedAggregator(CFG, n, mesh=_mesh1(), kernel=kernel)
+    agg = ShardedAggregator(cfg, n, mesh=_mesh1(), kernel=kernel)
     stream = StreamingAggregator(agg, staging_buffers=3, dispatch_ahead=2, max_batch=bs)
     for i in range(0, total, bs):
         stream.submit_batch(np.stack(stacks[i : i + bs]))
@@ -89,23 +111,23 @@ def test_streaming_planar_byte_identical_to_sequential(kernel):
     stream.close()
 
 
-@pytest.mark.parametrize("kernel", KERNELS)
-def test_streaming_wire_deferred_acceptance_matches_sequential(kernel):
+@pytest.mark.parametrize("cfg,kernel", _kernel_cases())
+def test_streaming_wire_deferred_acceptance_matches_sequential(cfg, kernel):
     """Raw-wire streaming: accumulator, nb_models AND the per-member
     acceptance vectors (fetched in one deferred sync at drain) must equal
     the sequential add_wire_batch path, invalid members included."""
     n, total, bs = 57, 11, 4
-    _, raws, _ = _updates(n, total, seed=3)
+    _, raws, _ = _updates(n, total, seed=3, cfg=cfg)
     bad = raws[5].copy()
-    bad[: CFG.bytes_per_number] = 0xFF  # element >= order -> member rejected
+    bad[: cfg.bytes_per_number] = 0xFF  # element >= order -> member rejected
     wires = raws[:5] + [bad] + raws[6:]
 
-    seq = ShardedAggregator(CFG, n, mesh=_mesh1(), kernel=kernel)
+    seq = ShardedAggregator(cfg, n, mesh=_mesh1(), kernel=kernel)
     seq_oks = [
         seq.add_wire_batch(np.stack(wires[i : i + bs])) for i in range(0, total, bs)
     ]
 
-    agg = ShardedAggregator(CFG, n, mesh=_mesh1(), kernel=kernel)
+    agg = ShardedAggregator(cfg, n, mesh=_mesh1(), kernel=kernel)
     stream = StreamingAggregator(agg, staging_buffers=3, dispatch_ahead=2, max_batch=bs)
     tickets = [
         stream.submit_wire_batch(np.stack(wires[i : i + bs]))
@@ -337,23 +359,22 @@ def _mesh2():
     return make_mesh(jax.devices()[:2])
 
 
-def _masked_updates(n, total, seed):
+def _masked_updates(n, total, seed, cfg=CFG):
     rng = np.random.default_rng(seed)
     out = []
     for _ in range(total):
-        w = rng.uniform(-1, 1, size=n).astype(np.float32)
-        out.append(Masker(CFG.pair()).mask(Scalar(1, total), w)[1])
+        out.append(Masker(cfg.pair()).mask(Scalar(1, total), _weights(rng, n, cfg))[1])
     return out
 
 
-def _oracle(n, objs):
-    host = Aggregation(CFG.pair(), n)
+def _oracle(n, objs, cfg=CFG):
+    host = Aggregation(cfg.pair(), n)
     for obj in objs:
         host.aggregate(obj)
     return host
 
 
-def _staged(n, batch_size=4, **kw):
+def _staged(n, batch_size=4, cfg=CFG, **kw):
     """A device StagedAggregator whose pipeline is NOT shard-parallel on a
     2-device mesh, so ``padded_length`` (104 at n = 103) exceeds the model
     length and the slots have pad columns."""
@@ -362,7 +383,7 @@ def _staged(n, batch_size=4, **kw):
     kw.setdefault("mesh", _mesh2())
     kw.setdefault("shard_parallel", False)
     kw.setdefault("kernel", "xla")
-    return StagedAggregator(CFG.pair(), n, device=True, batch_size=batch_size, **kw)
+    return StagedAggregator(cfg.pair(), n, device=True, batch_size=batch_size, **kw)
 
 
 def _rows_staged():
@@ -380,9 +401,10 @@ def _await_writes(dev):
             write.exception(timeout=30)
 
 
+@WIDTHS
 @pytest.mark.parametrize("shape", ["full", "part", "reused", "overrun"])
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
-def test_arrival_staging_bit_equal_to_host_oracle(packed, shape):
+def test_arrival_staging_bit_equal_to_host_oracle(packed, shape, cfg):
     """full: one batch closed by its last update; part: a batch of 3 of 4
     closed by drain(); reused: three batches through two DIRTY ring
     buffers; overrun: 11 updates staged before the one flush, so the third
@@ -390,8 +412,8 @@ def test_arrival_staging_bit_equal_to_host_oracle(packed, shape):
     length 103 on a padded length of 104."""
     n = 103
     total = {"full": 4, "part": 3, "reused": 12, "overrun": 11}[shape]
-    objs = _masked_updates(n, total, seed=21)
-    dev = _staged(n, packed_staging=packed, staging_buffers=2)
+    objs = _masked_updates(n, total, seed=21, cfg=cfg)
+    dev = _staged(n, cfg=cfg, packed_staging=packed, staging_buffers=2)
     stream = dev._stream
     assert stream._packed is packed and stream.stages_rows
     assert stream.agg.padded_length == 104
@@ -415,7 +437,25 @@ def test_arrival_staging_bit_equal_to_host_oracle(packed, shape):
     assert not drain.is_alive()
     assert _rows_staged() == (arrival0 + total, flush0)
     assert STAGING_DEPTH.value == depth0
-    got, want = dev.finalize(), _oracle(n, objs)
+    got, want = dev.finalize(), _oracle(n, objs, cfg)
+    assert got.nb_models == want.nb_models == total
+    assert got.object == want.object
+
+
+@WIDTHS
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
+def test_arrival_staging_through_the_pallas_fold(packed, cfg):
+    """The slot route into the Pallas fold (interpreted): a full batch, then
+    a batch of 2 of 4 closed by drain(), at both element widths."""
+    n, total = 103, 6
+    objs = _masked_updates(n, total, seed=24, cfg=cfg)
+    dev = _staged(n, cfg=cfg, packed_staging=packed, kernel="pallas-interpret")
+    for obj in objs:
+        dev.validate_aggregation(obj)
+        dev.aggregate(obj)
+    dev.drain()
+    assert dev.kernel_used == "pallas-interpret"
+    got, want = dev.finalize(), _oracle(n, objs, cfg)
     assert got.nb_models == want.nb_models == total
     assert got.object == want.object
 
@@ -452,8 +492,9 @@ def test_rows_land_in_arrival_order_whatever_order_the_pool_finishes_in():
     assert dev.finalize().object == _oracle(n, objs).object
 
 
+@WIDTHS
 @pytest.mark.parametrize("route", ["fold_partial", "wire_ingest", "mesh2"])
-def test_other_routes_stage_as_before(route):
+def test_other_routes_stage_as_before(route, cfg):
     """What does not take the slot route: an edge partial (one row through
     ``submit_host_planar_rows``), device-resident wire-ingest parts (never
     staged on the host) and a shard-parallel mesh (per-shard rings, filled
@@ -462,21 +503,21 @@ def test_other_routes_stage_as_before(route):
     from xaynet_tpu.server.aggregation import StagedAggregator
 
     n, k = 103, 3
-    objs = _masked_updates(n, k, seed=23)
-    host = StagedAggregator(CFG.pair(), n, device=False, batch_size=4)
+    objs = _masked_updates(n, k, seed=23, cfg=cfg)
+    host = StagedAggregator(cfg.pair(), n, device=False, batch_size=4)
     arrival0, flush0 = _rows_staged()
     if route == "fold_partial":
-        dev = _staged(n)
+        dev = _staged(n, cfg=cfg)
         for s in (host, dev):
             s.fold_partial(objs[0], 2)
         assert _rows_staged() == (arrival0, flush0 + 1)
         want_models = 2
     elif route == "wire_ingest":
-        dev = _staged(n)
+        dev = _staged(n, cfg=cfg)
         for obj in objs:
             host.aggregate(obj)
             raw = np.array(vect_element_block(serialize_mask_vect(obj.vect)))
-            lazy = MaskObject(LazyWireMaskVect(CFG, raw, n), obj.unit)
+            lazy = MaskObject(LazyWireMaskVect(cfg, raw, n), obj.unit)
             dev.validate_aggregation(lazy)
             dev.aggregate(lazy)
         assert not dev._open
@@ -484,7 +525,7 @@ def test_other_routes_stage_as_before(route):
         assert _rows_staged() == (arrival0, flush0)
         want_models = k
     else:
-        dev = _staged(n, shard_parallel=True)
+        dev = _staged(n, cfg=cfg, shard_parallel=True)
         assert not dev._stream.stages_rows
         for obj in objs:
             host.aggregate(obj)

@@ -22,6 +22,7 @@ from fractions import Fraction
 import numpy as np
 
 from ...ops import limbs as limb_ops
+from ...telemetry import codec
 from .chacha import BLOCK_BYTES, ChaChaStream, keystream_blocks
 
 
@@ -88,6 +89,7 @@ class StreamSampler:
         from ...utils import native
 
         lib = native.load()
+        codec.count("derive", lib is not None, count)
         if lib is not None:
             return self._draw_limbs_native(lib, count, order, out_limbs)
         # Draw width is the byte length of the *order itself* (the reference
@@ -111,7 +113,7 @@ class StreamSampler:
             else:
                 buf = self._more_keystream(target)
             n_cand = len(buf) // bpn
-            cand = limb_ops.bytes_le_to_limbs(buf[: n_cand * bpn], n_cand, bpn)
+            cand = limb_ops.bytes_le_to_limbs(buf[: n_cand * bpn], n_cand, bpn, op=None)
             keep_mask = limb_ops.lt_const(cand, order_cl)
             n_keep = int(keep_mask.sum())
             if n_keep >= need:
@@ -153,7 +155,7 @@ class StreamSampler:
             self._block += 1
             blk = keystream_blocks(self._seed, self._block - 1, 1)
             self._leftover = blk[intra:]
-        return limb_ops.bytes_le_to_limbs(out, count, bpn)[:, :out_limbs]
+        return limb_ops.bytes_le_to_limbs(out, count, bpn, op=None)[:, :out_limbs]
 
     def draw_int(self, order: int) -> int:
         return limb_ops.limbs_to_ints(self.draw_limbs(1, order))[0]

@@ -12,10 +12,17 @@ with ``A = add_shift`` and ``E = exp_shift``; unmasking inverts it
 
 The reference computes this in exact big-rational arithmetic per weight. Here:
 
-- **fast path** (f32 data, bounded B0-B6 — every practical config): vectorized
-  numpy double-double arithmetic (error ~1e-23 ≪ the 1e-10 protocol
-  tolerance), producing int64 fixed-point values that convert straight into
-  limb tensors;
+- **fast path** (f32 data, bounded B0-B6): vectorized numpy double-double
+  arithmetic producing int64 fixed-point values that convert straight into
+  limb tensors. The value reaches ``2 * 1e6 * 1e10 = 2e16`` at B6, past the
+  2^53 one float64 holds, so the final floor is taken on both words in
+  int64 (``dd.floor_i64``). With a dyadic scalar (1, 1/8, ...) every step is
+  exact and the result equals the exact path bit for bit at every bound;
+  with any other scalar its double-double (2^-106 relative) can land a value
+  that is an exact integer one unit low (about one element in 2,000,000 at
+  scalar 1/12: PERF.md §6, PR 23), inside the protocol's ``1/exp_shift``
+  tolerance. ``tests/test_encode_exact.py`` holds this route, its limb form
+  and the native masker to the exact path at B0, B2, B4 and B6;
 - **exact path** (f64 / integer data types, Bmax): python-int / Fraction math,
   bit-identical to the reference semantics.
 """
@@ -28,6 +35,7 @@ import numpy as np
 
 from ...ops import dd
 from ...ops import limbs as limb_ops
+from ...telemetry import codec
 from .config import BoundType, DataType, MaskConfig
 
 # ---------------------------------------------------------------------------
@@ -82,8 +90,8 @@ def encode_vect_fast(weights: np.ndarray, scalar_clamped: Fraction, config: Mask
     # (c + a) * e, floored
     hi, lo = dd.add_f(hi, lo, a)
     hi, lo = dd.mul_f(hi, lo, e)
-    shifted = dd.floor(hi, lo)  # integer-valued f64, <= 2*1e6*1e10 < 2^53
-    return np.maximum(shifted, 0.0).astype(np.int64)
+    # up to 2 * 1e6 * 1e10 = 2e16 at B6, above 2^53: floored in int64
+    return np.maximum(dd.floor_i64(hi, lo), 0)
 
 
 def encode_vect_limbs(weights, scalar_clamped: Fraction, config: MaskConfig) -> np.ndarray:
@@ -198,9 +206,11 @@ def decode_vect_any(
             out.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         )
         if rc == 0:
+            codec.count("decode", True, n)
             return out
 
     # numpy fallback: exact vectorized limb subtract, then top-96-bit decode
+    codec.count("decode", False, n)
     ell = max(n_limb, c_nlimbs) + 1
     c_ext = limb_ops.int_to_limbs(c_int, ell)
     d = np.zeros((n, ell), dtype=np.uint32)
@@ -252,6 +262,7 @@ def decode_vect_fast(
     c_int = nb_models * int(config.add_shift) * config.exp_shift
     recip = Fraction(1, 1) / (config.exp_shift * scalar_sum)
     native_out = _decode_native(limbs, c_int, recip)
+    codec.count("decode", native_out is not None, n)
     if native_out is not None:
         return native_out
     # limbs -> double-double value (high to low; power-of-two scaling exact)

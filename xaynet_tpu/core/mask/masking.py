@@ -97,7 +97,7 @@ def _mask_native(seed: bytes, sampler: StreamSampler, weights: np.ndarray,
     if new_offset == 0:
         return None
     sampler.skip_bytes(new_offset - sampler.consumed_bytes)
-    return limb_ops.bytes_le_to_limbs(out, n, elem_nbytes)
+    return limb_ops.bytes_le_to_limbs(out, n, elem_nbytes, op=None)
 
 
 class Masker:

@@ -123,11 +123,16 @@ def div(a_hi, a_lo, b_hi, b_lo):
     return add_f(q_hi, q_lo, q3)
 
 
-def floor(a_hi, a_lo):
-    """Elementwise floor of a double-double, returned as f64 (exact integer)."""
+def floor_i64(a_hi, a_lo):
+    """Elementwise floor of a double-double as int64, exact for |value| < 2^63.
+
+    Both words are floored and the two integers are added in int64, so the
+    low word is not rounded into the high one: above 2^53 ``a_hi`` is an
+    integer already and ``a_lo`` (up to ulp(a_hi)/2 >= 1) carries the units
+    that one float64 cannot hold."""
     f = np.floor(a_hi)
-    frac = (a_hi - f) + a_lo  # a_hi - f is exact
-    return f + np.floor(frac)
+    frac = (a_hi - f) + a_lo  # a_hi - f is exact; frac is small
+    return f.astype(np.int64) + np.floor(frac).astype(np.int64)
 
 
 def to_float(a_hi, a_lo):

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..telemetry import codec
+
 _U32 = np.uint32
 _U64 = np.uint64
 _MASK32 = np.uint64(0xFFFFFFFF)
@@ -82,6 +84,7 @@ def all_lt_order(data: np.ndarray, order: int) -> bool:
     from ..utils import native
 
     lib = native.load()
+    codec.count("validate", lib is not None, flat.shape[0])
     if lib is not None:
         ol = np.ascontiguousarray(int_to_limbs(order, n_limb))
         bad = lib.xn_count_ge(
@@ -138,18 +141,25 @@ def limbs_to_ints(arr: np.ndarray) -> list[int]:
     return out
 
 
-def bytes_le_to_limbs(buf: bytes | np.ndarray, count: int, bytes_per_number: int) -> np.ndarray:
+def bytes_le_to_limbs(
+    buf: bytes | np.ndarray, count: int, bytes_per_number: int, op: str | None = "parse"
+) -> np.ndarray:
     """Parse ``count`` fixed-width little-endian integers into ``uint32[count, L]``.
 
     Native single-pass codec when available (~memory bandwidth; the numpy
     pad/slice path measures ~370 MB/s and parse sits on the coordinator's
-    per-update critical path — one 25M-param update is a 150 MB payload).
+    per-update critical path — one 25M-param update is a 150 MB payload):
+    one 8-byte load an element up to 8 wire bytes, a per-byte loop above.
+    ``op`` names the operation the route is counted under (the sampler
+    converts its own draws and counts them as ``derive``).
     """
     n_limb = n_limbs_for_bytes(bytes_per_number)
     raw = np.frombuffer(buf, dtype=np.uint8, count=count * bytes_per_number)
     from ..utils import native
 
     lib = native.load()
+    if op is not None:
+        codec.count(op, lib is not None, count)
     if lib is not None and count > 0:
         raw_c = np.ascontiguousarray(raw)
         out = np.empty((count, n_limb), dtype=_U32)
@@ -478,7 +488,9 @@ def pack_planar(planar: np.ndarray, bpn: int, out: np.ndarray | None = None) -> 
         and out.strides[-1] == 1
         and _native_pack_planar(planar, bpn, out)
     ):
+        codec.count("stage", True, n)
         return out
+    codec.count("stage", False, planar.size // n_limb)
     if planar.flags.c_contiguous:
         # little-endian u32 planes viewed as bytes: element i's byte b lives
         # at [..., b // 4, 4 * i + (b % 4)] — one strided plane copy per
@@ -550,7 +562,9 @@ def pack_planar_slice(
             out.strides[0],
             max(0, int(n_threads)),
         )
+        codec.count("stage", True, width)
         return view
+    codec.count("stage", False, width)
     for b in range(bpn):
         view[b, :] = (
             (planar[b // 4, lo:hi] >> _U32(8 * (b % 4))) & _U32(0xFF)
@@ -598,7 +612,9 @@ def pack_wire_slice(
                 out.strides[1],
                 max(0, int(n_threads)),
             )
+        codec.count("stage", True, k * width)
         return view
+    codec.count("stage", False, k * width)
     raw = stack.view(np.uint8)  # [K, n, 4L]
     view[...] = np.moveaxis(raw[:, lo:hi, :bpn], -1, -2)
     return view
@@ -618,6 +634,7 @@ def pack_wire(stack: np.ndarray, bpn: int, out: np.ndarray | None = None) -> np.
         out = np.empty((*stack.shape[:-2], bpn, stack.shape[-2]), dtype=np.uint8)
     if stack.ndim == 3 and out.ndim == 3:
         return pack_wire_slice(stack, 0, stack.shape[1], bpn, out)
+    codec.count("stage", False, stack.size // n_limb)
     raw = stack.view(np.uint8)  # [..., n, 4L]
     out[...] = np.moveaxis(raw[..., :bpn], -1, -2)
     return out

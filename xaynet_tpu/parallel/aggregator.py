@@ -98,8 +98,9 @@ _FOLD_FN_CACHE: dict[tuple, object] = {}
 
 def fold_kernel_report() -> dict:
     """The last fold-kernel resolution: ``kernel``, ``source`` (configured |
-    race | only-candidate | cached | persisted), the mesh decomposition it
-    ran on (``acc_slices``: one ``[lo, hi)`` model-axis slice per device),
+    race | only-candidate | cached | persisted), the vector and element it
+    folded (``model_length``, ``n_limbs``, ``bytes_per_number``), the mesh
+    decomposition it ran on (``acc_slices``: one ``[lo, hi)`` slice per device),
     and for a race ``race`` (per candidate ``status`` = ``ok`` or
     ``failed: <ExceptionType>``, first-call and steady ``seconds``) plus
     ``results_equal``. Empty before the first fold."""
@@ -939,6 +940,8 @@ class ShardedAggregator:
             "kernel": self.kernel_used,
             "source": source,
             "model_length": self.model_length,
+            "n_limbs": self.n_limbs,
+            "bytes_per_number": self.config.bytes_per_number,
             "acc_slices": shard_slices(self.padded_length, self.mesh.devices.size),
             **extra,
         }
