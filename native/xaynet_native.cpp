@@ -13,11 +13,16 @@
 //
 // Built as a plain shared library; loaded via ctypes (no pybind11).
 
+#include <cerrno>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <ctime>
 #include <thread>
 #include <vector>
+
+#include <poll.h>
+#include <sys/socket.h>
 
 #ifdef __AVX2__
 #include <immintrin.h>
@@ -1001,7 +1006,38 @@ XN_EXPORT uint64_t xn_count_ge(const uint32_t* limbs, uint64_t count, uint32_t n
   return bad;
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 8; }
+XN_EXPORT uint32_t xn_abi_version(void) { return 9; }
+
+// Fill buf[start, len) from the non-blocking stream socket `fd` within
+// `timeout_s` seconds and return how far buf is filled (ABI 9; the REST
+// server's read of a large request body, server/rest.py). Short when the
+// peer closed or reset, when the time ran out, or when the socket was shut
+// down under the call to abort it. Never reads past `len`. ctypes releases
+// the interpreter lock once for the whole body: a 179 MB upload is about a
+// thousand recv() calls, none of which waits for the lock.
+XN_EXPORT uint64_t xn_recv_exactly(int fd, uint8_t* buf, uint64_t start, uint64_t len,
+                                   double timeout_s) {
+  auto now = [] {
+    struct timespec t;
+    clock_gettime(CLOCK_MONOTONIC, &t);
+    return (double)t.tv_sec + 1e-9 * (double)t.tv_nsec;
+  };
+  const double deadline = now() + timeout_s;
+  struct pollfd p = {fd, POLLIN, 0};
+  uint64_t got = start;
+  while (got < len) {
+    ssize_t n = recv(fd, buf + got, (size_t)(len - got), 0);
+    if (n > 0) { got += (uint64_t)n; continue; }
+    if (n == 0) break;
+    if (errno == EINTR) continue;
+    if (errno != EAGAIN && errno != EWOULDBLOCK) break;
+    double left = deadline - now();
+    if (left <= 0) break;
+    if (left > 3600.0) left = 3600.0;
+    if (poll(&p, 1, (int)(left * 1000.0) + 1) < 0 && errno != EINTR) break;
+  }
+  return got;
+}
 
 // Fixed-point decode: out[i] = ((value_i - C) ) * inv, computed in
 // double-double, where value_i is the unmasked group element (wire-layout

@@ -100,16 +100,20 @@ class SecretEncryptKey:
             return PublicEncryptKey(sk.public_key().public_bytes_raw())
         return PublicEncryptKey(_purecrypto.x25519_public(self.bytes_))
 
-    def decrypt(self, sealed: bytes, pk: "PublicEncryptKey | None" = None) -> bytes:
+    def decrypt(
+        self, sealed: "bytes | bytearray | memoryview", pk: "PublicEncryptKey | None" = None
+    ) -> bytes:
         """Open a sealed box addressed to this key.
 
         ``pk`` (our own public key) is accepted for reference API parity; it
-        is recomputed when omitted.
+        is recomputed when omitted. ``sealed`` is read through the buffer
+        protocol: the ciphertext of a 179 MB upload is not copied first.
         """
         if len(sealed) < SEALBYTES:
             raise DecryptError("sealed box too short")
         my_pk = pk.as_bytes() if pk is not None else self.public_key().as_bytes()
-        eph_pk, ct = sealed[:32], sealed[32:]
+        view = memoryview(sealed)
+        eph_pk, ct = bytes(view[:32]), view[32:]
         if _HAVE_CRYPTO:
             sk = X25519PrivateKey.from_private_bytes(self.bytes_)
             shared = sk.exchange(X25519PublicKey.from_public_bytes(eph_pk))
@@ -121,7 +125,7 @@ class SecretEncryptKey:
         shared = _purecrypto.x25519(self.bytes_, eph_pk)
         key = _derive_key(shared, eph_pk, my_pk)
         try:
-            return _purecrypto.chacha20poly1305_decrypt(key, _ZERO_NONCE, ct)
+            return _purecrypto.chacha20poly1305_decrypt(key, _ZERO_NONCE, bytes(ct))
         except _purecrypto.AeadTagError as e:
             raise DecryptError("sealed box authentication failed") from e
 

@@ -22,11 +22,16 @@ HTTP dependency); optional TLS via ``ssl.SSLContext``.
 from __future__ import annotations
 
 import asyncio
+import ctypes
 import json
 import logging
 import math
+import os
+import select
+import socket
 import ssl
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Optional
 from urllib.parse import parse_qs, urlparse
@@ -35,7 +40,7 @@ import numpy as np
 
 from ..telemetry import tracing as trace
 from ..telemetry.registry import MetricsRegistry, get_registry
-from ..utils import tracing
+from ..utils import native, tracing
 from . import stages
 from .requests import RequestError
 from .services import Fetcher, PetMessageHandler, ServiceError
@@ -59,6 +64,22 @@ class TenantRoutes:
     health_extra: object = None  # zero-arg callable merged into /healthz
 
 MAX_BODY = 1 << 32  # u32 length field ceiling, as in the reference
+# A body of at least this many bytes, on a plain-TCP connection, is received
+# by a `rest-body` thread straight into one buffer of its Content-Length
+# (docs/DESIGN.md §16); below it the thread hop costs more than the
+# StreamReader's copies (PERF.md §6, PR 27: where the two cross).
+DIRECT_BODY_MIN = 1 << 20
+# Readers that may block on a socket at once. A large body that finds them
+# all busy is read through the StreamReader: a connection never waits for a
+# reader while its peer is sending.
+BODY_READERS = 16
+
+# ``bytearray(n)`` zero-fills its n bytes under the interpreter lock: 110 ms
+# of the event loop for a 179 MB body that recv() is about to overwrite. The
+# C API's constructor, given no source, allocates and touches nothing.
+_uninitialised_bytearray = ctypes.PYFUNCTYPE(
+    ctypes.py_object, ctypes.c_char_p, ctypes.c_ssize_t
+)(("PyByteArray_FromStringAndSize", ctypes.pythonapi))
 
 SPAN_REQUEST = trace.declare_span("rest.request")
 
@@ -81,6 +102,54 @@ _KNOWN_PATHS = {"/message", "/params", "/sums", "/seeds", "/model",
                 "/health", "/healthz", "/metrics", "/statusz", "/alerts",
                 "/edge/round", "/edge/envelope", "/admin/tenants"}
 _KNOWN_METHODS = {"GET", "POST", "HEAD", "PUT", "DELETE", "OPTIONS", "PATCH"}
+
+
+def _recv_exactly(sock: socket.socket, body: bytearray, start: int, deadline: float) -> int:
+    """Fill ``body[start:]`` from ``sock`` (non-blocking: it shares the
+    transport's open file) and return how far ``body`` is filled: short
+    when the peer closed or reset, when ``deadline`` (``time.monotonic()``)
+    passed, or when the socket was shut down under it to abort the read.
+    Runs on a ``rest-body`` thread, which owns ``sock`` and closes it. The
+    native library loops ``recv`` and ``poll`` with the interpreter lock
+    released once for the whole body; without it the same loop runs here and
+    takes the lock back after each of a body's several hundred calls."""
+    try:
+        lib = native.load()
+        if lib is not None:
+            first = ctypes.c_uint8.from_buffer(body)  # pins the buffer for the call
+            return lib.xn_recv_exactly(
+                sock.fileno(), ctypes.byref(first), start, len(body),
+                deadline - time.monotonic(),
+            )
+        view = memoryview(body)
+        poller = select.poll()
+        poller.register(sock, select.POLLIN)
+        got = start
+        while got < len(body):
+            try:
+                n = sock.recv_into(view[got:])
+            except (BlockingIOError, InterruptedError):
+                left = deadline - time.monotonic()
+                if left <= 0 or not poller.poll(left * 1000.0):
+                    break
+                continue
+            except OSError:
+                break  # reset by the peer
+            if n == 0:
+                break
+            got += n
+        return got
+    finally:
+        sock.close()
+
+
+def _abort_read(sock: socket.socket) -> None:
+    """Wake the thread that reads ``sock``: its ``recv_into`` returns 0. The
+    connection is shut down with it, so only for a connection being dropped."""
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass  # the reader finished and closed it first
 
 
 class RestServer:
@@ -160,8 +229,20 @@ class RestServer:
             buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                      0.5, 1.0, 2.5, 5.0, 10.0),
         )
+        self._body_bytes = self.registry.counter(
+            "xaynet_rest_body_bytes_total",
+            "Request body bytes read in full, by route: direct = received by "
+            "a rest-body thread into one buffer of the Content-Length, "
+            "stream = gathered by the event loop's StreamReader (small "
+            "bodies, TLS, no free reader).",
+            ("route",),
+        )
         self._lag_task: Optional[asyncio.Task] = None
         self._server: Optional[asyncio.AbstractServer] = None
+        # the bounded rest-body pool (made when a body first needs it) and
+        # the sockets its threads are reading: stop() shuts those down
+        self._body_pool: Optional[ThreadPoolExecutor] = None
+        self._direct_reads: set[socket.socket] = set()
         # live connections: stop() closes them — an idle keep-alive peer
         # would otherwise hold the process for read_timeout seconds
         self._writers: set[asyncio.StreamWriter] = set()
@@ -188,9 +269,14 @@ class RestServer:
             await asyncio.gather(self._lag_task, return_exceptions=True)
             self._lag_task = None
         self._server.close()
+        for sock in list(self._direct_reads):
+            _abort_read(sock)  # a body in mid-read: its thread returns short
         for writer in list(self._writers):
             writer.close()
         await self._server.wait_closed()
+        if self._body_pool is not None:
+            self._body_pool.shutdown(wait=False)
+            self._body_pool = None
 
     async def _watch_loop_lag(self, period: float = 0.1) -> None:
         """Observe, every ``period`` seconds, how late this loop ran a task
@@ -228,10 +314,8 @@ class RestServer:
                     await self._respond(writer, 413, b"body too large")
                     break
 
-                async def read_body(length=length) -> bytes:
-                    if not length:
-                        return b""
-                    return await asyncio.wait_for(reader.readexactly(length), self.read_timeout)
+                async def read_body(length=length) -> bytes | bytearray:
+                    return await self._read_body(reader, writer, length)
 
                 keep_alive = headers.get("connection", "keep-alive").lower() != "close"
                 status, payload, ctype, extra = await self._route(
@@ -249,6 +333,73 @@ class RestServer:
                 await writer.wait_closed()
             except Exception:  # lint: swallow-ok (best-effort socket teardown)
                 pass
+
+    async def _read_body(
+        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int
+    ) -> bytes | bytearray:
+        """The request's body, whole within ``read_timeout`` or an exception
+        that drops the connection unanswered. One algorithm (receive
+        ``length`` bytes) on the carrier the request calls for: a large body
+        on plain TCP with a reader free goes straight from the socket into
+        one buffer on a ``rest-body`` thread, everything else through the
+        StreamReader on the loop. Never reads past the body."""
+        if not length:
+            return b""
+        sock = self._direct_socket(reader, writer, length)
+        if sock is None:
+            body = await asyncio.wait_for(reader.readexactly(length), self.read_timeout)
+            self._body_bytes.labels(route="stream").inc(length)
+            return body
+        deadline = time.monotonic() + self.read_timeout
+        transport = writer.transport
+        # nothing below suspends until the thread has the socket, so no byte
+        # reaches the StreamReader between here and resume_reading()
+        transport.pause_reading()
+        body = _uninitialised_bytearray(None, length)
+        buffered = len(reader._buffer)
+        if buffered:
+            # the segment that carried the headers carried these; the buffer
+            # holds them, so read() returns at once. It resumes a transport
+            # the StreamReader had paused itself: pause again
+            body[:buffered] = await reader.read(buffered)  # lint: wirecopy-ok (a store)
+            transport.pause_reading()
+        if self._body_pool is None:
+            self._body_pool = ThreadPoolExecutor(BODY_READERS, thread_name_prefix="rest-body")
+        self._direct_reads.add(sock)
+        try:
+            got = await asyncio.get_running_loop().run_in_executor(
+                self._body_pool, _recv_exactly, sock, body, buffered, deadline
+            )
+        except BaseException:
+            _abort_read(sock)  # cancelled: the thread must not outlive the request
+            raise
+        finally:
+            self._direct_reads.discard(sock)
+        if got < length:  # closed, reset, aborted or out of time: all drop the connection
+            raise asyncio.IncompleteReadError(b"", length)
+        transport.resume_reading()
+        self._body_bytes.labels(route="direct").inc(length)
+        return body
+
+    def _direct_socket(
+        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int
+    ) -> Optional[socket.socket]:
+        """A duplicate of the connection's descriptor if this body is to be
+        read directly, else ``None``. A duplicate, so that a transport closed
+        in mid-body (``stop()``) cannot hand the reader thread a recycled
+        descriptor; it shares the transport's non-blocking mode."""
+        if length < DIRECT_BODY_MIN or len(self._direct_reads) >= BODY_READERS:
+            return None
+        if writer.get_extra_info("ssl_object") is not None:
+            return None
+        trsock = writer.get_extra_info("socket")
+        buffer = getattr(reader, "_buffer", None)
+        if trsock is None or not isinstance(buffer, bytearray) or len(buffer) >= length:
+            return None
+        try:
+            return socket.socket(fileno=os.dup(trsock.fileno()))
+        except OSError:
+            return None  # the transport is already closed: the stream path says how
 
     def _resolve_tenant(self, path: str):
         """Split a ``/t/<tenant>/<sub>`` target into (tenant id, sub path,

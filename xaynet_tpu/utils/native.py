@@ -18,7 +18,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 8
+_ABI_VERSION = 9
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -223,6 +223,16 @@ def load() -> Optional[ctypes.CDLL]:
         lib.xn_count_ge.restype = ctypes.c_uint64
         lib.xn_fold_wire_nlimb.argtypes = list(lib.xn_fold_wire_u64.argtypes)
         lib.xn_fold_wire_nlimb.restype = ctypes.c_int
+        # the REST server's direct body read (ABI 9): poll + recv in C, one
+        # release of the interpreter lock for the whole body
+        lib.xn_recv_exactly.argtypes = [
+            ctypes.c_int,  # fd of a non-blocking stream socket
+            u8p,
+            ctypes.c_uint64,  # start: bytes of buf already filled
+            ctypes.c_uint64,  # len of buf
+            ctypes.c_double,  # seconds allowed
+        ]
+        lib.xn_recv_exactly.restype = ctypes.c_uint64
         _lib = lib
     except (OSError, AttributeError) as e:
         # AttributeError: a stale prebuilt .so missing newer symbols when the
