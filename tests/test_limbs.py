@@ -78,68 +78,53 @@ def test_edge_values():
     assert limb_ops.limbs_to_ints(limb_ops.mod_sub(b, a, ol)) == [0, 0, 2 % order]
 
 
-def test_fold_planar_batch_host_matches_bigint_oracle():
-    """Native single-pass u64 fold == python big-int result (1 and 2 limb
-    orders, prime / integer / power2-boundary, elements at order-1)."""
-    import numpy as np
+def _wire_fold_against_oracle(order, k, n, seed):
+    """Fold k wire rows into an accumulator row with the host aggregator's
+    native single-pass kernel (``fold_wire_batch_host``) and with the numpy
+    reference (``batch_mod_sum`` + ``mod_add``); both must equal the python
+    big-int sum. A row of maximal elements (order - 1) is among them."""
+    from xaynet_tpu.utils import native
 
-    from xaynet_tpu.ops import limbs as L
+    nl, ol = limb_ops.n_limbs_for_order(order), limb_ops.order_limbs_for(order)
+    rng = np.random.default_rng(seed)
 
-    cases = [
+    def row():
+        return [int(rng.integers(0, min(order, 2**63))) % order for _ in range(n)]
+
+    vals = [row(), [order - 1] * n] + [row() for _ in range(k - 1)]
+    acc = limb_ops.ints_to_limbs(vals[0], nl)
+    stack = np.stack([limb_ops.ints_to_limbs(v, nl) for v in vals[1:]])
+    want = [sum(v[i] for v in vals) % order for i in range(n)]
+    out = limb_ops.fold_wire_batch_host(acc, stack, ol)
+    if native.load() is not None:
+        assert out is not None  # the library serves every width
+    if out is not None:
+        assert limb_ops.limbs_to_ints(out) == want
+    ref = limb_ops.mod_add(acc, limb_ops.batch_mod_sum(stack, ol), ol)
+    assert limb_ops.limbs_to_ints(ref) == want
+
+
+@pytest.mark.parametrize(
+    "order,k",
+    [
         (2**48 - 59, 9),          # prime-ish, 2 limbs
         ((1 << 45) * 10**3, 16),  # integer-style composite, 2 limbs
         (1 << 64, 5),             # power2 boundary: natural u64 wrap
         (1 << 32, 7),             # power2 boundary: one limb
         (2**31 - 1, 12),          # one limb, odd order
-    ]
-    rng = np.random.default_rng(3)
-    for order, k in cases:
-        nl = L.n_limbs_for_order(order)
-        ol = L.order_limbs_for(order)
-        n = 257
-        vals = [[int(rng.integers(0, min(order, 2**63))) % order for _ in range(n)]]
-        vals += [[order - 1] * n]  # a row of maximal elements
-        vals += [[int(rng.integers(0, min(order, 2**63))) % order for _ in range(n)]
-                 for _ in range(k - 1)]
-        acc_planar = np.ascontiguousarray(L.ints_to_limbs(vals[0], nl).T)
-        stack_planar = np.stack([np.ascontiguousarray(L.ints_to_limbs(v, nl).T) for v in vals[1:]])
-        out = L.fold_planar_batch_host(acc_planar, stack_planar, ol)
-        want = [sum(v[i] for v in vals) % order for i in range(n)]
-        got = [L.limbs_to_int(np.ascontiguousarray(out[:, i])) for i in range(n)]
-        assert got == want, (order, k)
-
-        # wire-layout variant agrees (or declines when unsupported)
-        acc_wire = np.ascontiguousarray(acc_planar.T)
-        stack_wire = np.ascontiguousarray(stack_planar.transpose(0, 2, 1))
-        wire_out = L.fold_wire_batch_host(acc_wire, stack_wire, ol)
-        if wire_out is not None:
-            got_w = [L.limbs_to_int(wire_out[i]) for i in range(n)]
-            assert got_w == want, (order, k, "wire")
+    ],
+    ids=["prime-2limb", "integer-2limb", "pow2-64", "pow2-32", "odd-1limb"],
+)
+def test_fold_wire_batch_host_matches_bigint_oracle(order, k):
+    """Native single-pass u64 fold == python big-int result (1 and 2 limb
+    orders, prime / integer / power2-boundary, elements at order-1)."""
+    _wire_fold_against_oracle(order, k, n=257, seed=3)
 
 
 def test_fold_host_oversized_batch_uses_generic_kernel():
     """(K+1) * order over the u64 bound routes to the generic n-limb
-    kernel (round 3) and stays exact."""
-    import numpy as np
-
-    from xaynet_tpu.ops import limbs as L
-
-    order = 1 << 62
-    nl, ol = L.n_limbs_for_order(order), L.order_limbs_for(order)
-    n, k = 33, 8  # (8+1) * 2^62 > 2^64 -> no u64 fast path
-    rng = np.random.default_rng(4)
-    vals = [[int(rng.integers(0, 2**62)) for _ in range(n)] for _ in range(k + 1)]
-    acc = np.ascontiguousarray(L.ints_to_limbs(vals[0], nl).T)
-    stack = np.stack([np.ascontiguousarray(L.ints_to_limbs(v, nl).T) for v in vals[1:]])
-    out = L.fold_planar_batch_host(acc, stack, ol)
-    want = [sum(v[i] for v in vals) % order for i in range(n)]
-    got = [L.limbs_to_int(np.ascontiguousarray(out[:, i])) for i in range(n)]
-    assert got == want
-    wire_out = L.fold_wire_batch_host(
-        np.ascontiguousarray(acc.T), np.ascontiguousarray(stack.transpose(0, 2, 1)), ol
-    )
-    if wire_out is not None:  # native present: the generic kernel must agree
-        assert L.limbs_to_ints(wire_out) == want
+    kernel and stays exact: (8+1) * 2^62 > 2^64 -> no u64 fast path."""
+    _wire_fold_against_oracle(1 << 62, 8, n=33, seed=4)
 
 
 def test_fold_host_nlimb_matches_bigint_oracle():

@@ -232,7 +232,7 @@ def _random_mask_vect(n, seed=7):
     return vect
 
 
-@pytest.mark.parametrize("kernel", ["xla", "native-u64"])
+@pytest.mark.parametrize("kernel", ["xla", "pallas-interpret"])
 def test_eager_unmask_byte_identical_sharded(kernel):
     stacks, host = _updates(LEN, 9)
     mask_vect = _random_mask_vect(LEN)
@@ -393,11 +393,11 @@ def test_calibcache_cold_warm_roundtrip(calib_path):
     calibcache.configure(calib_path)
     key = ("cpu", 123, "cfg", 8, None)
     assert calibcache.get("fold", key) is None  # cold
-    calibcache.put("fold", key, "native-u64")
+    calibcache.put("fold", key, "xla")
     calibcache.put("mask", key, "host-threaded")
     # a fresh "process": reload from disk
     calibcache.configure(calib_path)
-    assert calibcache.get("fold", key) == "native-u64"
+    assert calibcache.get("fold", key) == "xla"
     assert calibcache.get("mask", key) == "host-threaded"
     raw = json.loads(Path(calib_path).read_text())
     assert raw["fingerprint"] == calibcache.fingerprint()

@@ -8,8 +8,9 @@ The properties everything rests on:
   group elements (``element < order <= 2^(8*bpn)``) across every group
   family, including non-byte-aligned and non-limb-aligned orders;
 - a packed-staging round is **byte-identical** to the unpacked control
-  across mesh={1,2,8} × kernel={xla, native-u64, auto} — the fold is the
-  same exact modular sum, only the staged representation changes;
+  across mesh={1,2,8} × kernel={xla, pallas-interpret, auto} × element
+  width (2 limbs / 7 bytes, 3 limbs / 10 bytes) — the fold is the same
+  exact modular sum, only the staged representation changes;
 - the reduce-scatter plan persists across drain windows and the per-shard
   unmask produces the exact gathered-subtract result;
 - quantized configs derive protocol-consistent orders (the catalogue's
@@ -41,6 +42,9 @@ from xaynet_tpu.parallel.mesh import make_mesh
 from xaynet_tpu.parallel.streaming import BYTES_STAGED, StreamingAggregator
 
 CFG = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M6)
+# the wide end of the bounded-f32 catalogue: 75-bit order, 3 limbs, 10 wire bytes
+CFG3 = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B6, ModelType.M6)
+WIDTHS = pytest.mark.parametrize("cfg", [CFG, CFG3], ids=["2limb-7B", "3limb-10B"])
 
 # one config per group family, deliberately covering non-limb-aligned
 # (bpn=7: M6) and byte-boundary (Power2) widths, plus quantized orders
@@ -108,59 +112,28 @@ def test_pack_roundtrip_synthetic_widths():
         assert np.array_equal(host_limbs.unpack_planar(packed, n_limb), planar)
 
 
-@pytest.mark.parametrize("cfg", FAMILY_CONFIGS, ids=lambda c: f"{c.group_type.name}-q{c.quant}")
-def test_packed_host_fold_matches_planar(cfg):
+@pytest.mark.parametrize(
+    "cfg",
+    FAMILY_CONFIGS + [CFG3],
+    ids=lambda c: f"{c.group_type.name}-{c.bound_type.name}-q{c.quant}",
+)
+def test_packed_device_fold_matches_planar(cfg):
+    """The fused in-graph unpack + fold over byte planes equals the planar
+    fold and the host reference sum, at every packed width."""
+    from xaynet_tpu.ops.fold_jax import fold_packed_batch, fold_planar_batch
+
     order = cfg.order
     ol = host_limbs.order_limbs_for(order)
     bpn = host_limbs.wire_width_for(order)
     n_limb = host_limbs.n_limbs_for_order(order)
-    rng = np.random.default_rng(3)
-    k, n = 6, 1031
-    planar, _ = _rand_limbs(rng, order, k, n)
-    acc0 = np.zeros((n_limb, n), dtype=np.uint32)
-    ref = host_limbs.fold_planar_batch_host(acc0.copy(), planar, ol)
-    packed = host_limbs.pack_planar(planar, bpn)
-    out = host_limbs.fold_packed_batch_host(acc0.copy(), packed, ol)
-    assert np.array_equal(out, ref)
-
-
-def test_packed_device_fold_matches_planar():
-    from xaynet_tpu.ops.fold_jax import fold_packed_batch, fold_planar_batch
-
-    order = CFG.order
-    bpn = host_limbs.wire_width_for(order)
-    n_limb = host_limbs.n_limbs_for_order(order)
     rng = np.random.default_rng(5)
-    planar, _ = _rand_limbs(rng, order, 4, 515)
+    planar, wire = _rand_limbs(rng, order, 4, 515)
     packed = host_limbs.pack_planar(planar, bpn)
     acc = np.zeros((n_limb, 515), dtype=np.uint32)
     ref = np.asarray(fold_planar_batch(acc.copy(), planar, order))
     out = np.asarray(fold_packed_batch(acc.copy(), packed, n_limb, order))
     assert np.array_equal(out, ref)
-
-
-def test_packed_slice_fold_matches_full():
-    order = CFG.order
-    ol = host_limbs.order_limbs_for(order)
-    bpn = host_limbs.wire_width_for(order)
-    n_limb = host_limbs.n_limbs_for_order(order)
-    rng = np.random.default_rng(11)
-    k, n = 4, 2048
-    planar, _ = _rand_limbs(rng, order, k, n)
-    packed = host_limbs.pack_planar(planar, bpn)
-    ref = host_limbs.fold_planar_batch_host(
-        np.zeros((n_limb, n), np.uint32), planar, ol
-    )
-    # per-shard contiguous accumulator addressing (acc_cols), mid-batch slice
-    lo, hi = 512, 1536
-    acc = np.zeros((n_limb, hi - lo), np.uint32)
-    spare = np.empty_like(acc)
-    if host_limbs.fold_packed_slice_host(
-        acc, packed, spare, lo, hi, ol, acc_cols=hi - lo
-    ):
-        assert np.array_equal(spare, ref[:, lo:hi])
-    else:
-        pytest.skip("native packed kernel unavailable")
+    assert np.array_equal(out.T, host_limbs.batch_mod_sum(wire, ol))
 
 
 # --- packed staging byte-identity across mesh x kernel ---------------------
@@ -176,14 +149,15 @@ def _wire_updates(cfg, n, k, seed):
     return np.ascontiguousarray(wire.transpose(0, 2, 1))  # [K, n, L]
 
 
+@WIDTHS
 @pytest.mark.parametrize("mesh_n", (1, 2, 8))
-@pytest.mark.parametrize("kernel", ("xla", "native-u64", "auto"))
-def test_packed_round_byte_identical_to_unpacked_control(mesh_n, kernel):
+@pytest.mark.parametrize("kernel", ("xla", "pallas-interpret", "auto"))
+def test_packed_round_byte_identical_to_unpacked_control(mesh_n, kernel, cfg):
     n, k, batches = 515, 4, 2
-    stack = _wire_updates(CFG, n, k, seed=mesh_n * 31 + len(kernel))
+    stack = _wire_updates(cfg, n, k, seed=mesh_n * 31 + len(kernel))
 
     def run(packed):
-        agg = ShardedAggregator(CFG, n, mesh=_mesh(mesh_n), kernel=kernel)
+        agg = ShardedAggregator(cfg, n, mesh=_mesh(mesh_n), kernel=kernel)
         st = StreamingAggregator(
             agg, staging_buffers=2, dispatch_ahead=2, max_batch=k, packed=packed
         )
@@ -242,7 +216,7 @@ def test_packed_staging_auto_skips_boundary_orders():
 # --- reduce-scatter accumulator --------------------------------------------
 
 
-@pytest.mark.parametrize("kernel", ("xla", "native-u64"))
+@pytest.mark.parametrize("kernel", ("xla", "pallas-interpret"))
 def test_plan_persists_across_drain_windows(kernel):
     n, k = 1031, 3
     stack = _wire_updates(CFG, n, k, seed=17)
@@ -273,7 +247,7 @@ def test_plan_unmask_matches_gathered_subtract():
     rng = np.random.default_rng(23)
     _, mask_wire = _rand_limbs(rng, CFG.order, 1, n)
     mask = mask_wire[0]
-    for kernel in ("xla", "native-u64"):
+    for kernel in ("xla", "pallas-interpret"):
         agg = ShardedAggregator(CFG, n, mesh=make_mesh(), kernel=kernel)
         st = StreamingAggregator(agg, max_batch=k)
         st.submit_batch(stack)

@@ -3,8 +3,10 @@
 The property everything rests on: the shard-parallel pipeline — one fold
 worker per mesh device, per-shard staging rings, donated per-shard
 accumulators, drain() as the cross-shard barrier — is **byte-identical to
-the sequential single-device path** across kernels (xla, native-u64, auto)
-× mesh sizes (1, 2, 8) × planar/wire submit paths, including
+the sequential single-device path** across kernels (xla, the Pallas kernel
+through its interpreter, auto) × mesh sizes (1, 2, 8) × element widths
+(2 limbs / 7 wire bytes, 3 limbs / 10 wire bytes) × planar/wire submit
+paths, including
 dispatch-ahead out-of-order schedules, and its per-shard degradation
 ladder (fold failure → per-shard sync retry → pipeline-wide sync mode →
 sticky poison) keeps the shards consistent: a batch commits only when
@@ -29,10 +31,9 @@ from xaynet_tpu.core.mask import (
     Scalar,
 )
 from xaynet_tpu.core.mask.serialization import serialize_mask_vect, vect_element_block
-from xaynet_tpu.ops import limbs as host_limbs
 from xaynet_tpu.parallel.aggregator import ShardedAggregator
-from xaynet_tpu.parallel.mesh import make_mesh, shard_slices
-from xaynet_tpu.parallel.shards import ShardPlan, shard_thread_budget
+from xaynet_tpu.parallel.mesh import make_mesh
+from xaynet_tpu.parallel.shards import ShardPlan
 from xaynet_tpu.parallel.streaming import (
     SHARD_INFLIGHT,
     SHARD_STAGING_DEPTH,
@@ -41,8 +42,14 @@ from xaynet_tpu.parallel.streaming import (
 )
 
 CFG = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M6)
+# the wide end of the bounded-f32 catalogue: 75-bit order, 3 limbs, 10 wire
+# bytes; weights up to 1e6 put the encodings on both sides of 2^53
+CFG3 = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B6, ModelType.M6)
+WIDTHS = pytest.mark.parametrize("cfg", [CFG, CFG3], ids=["2limb-7B", "3limb-10B"])
 
-KERNELS = ("xla", "native-u64", "auto")
+# pallas-interpret is the kernel the chip's race picks, run through the
+# Pallas interpreter; "auto" on this CPU backend resolves to xla
+KERNELS = ("xla", "pallas-interpret", "auto")
 MESH_SIZES = (1, 2, 8)
 
 
@@ -50,13 +57,14 @@ def _mesh(n):
     return make_mesh(jax.devices()[:n])
 
 
-def _updates(n, total, seed=0):
+def _updates(n, total, seed=0, cfg=CFG):
     rng = np.random.default_rng(seed)
-    host = Aggregation(CFG.pair(), n)
+    host = Aggregation(cfg.pair(), n)
+    bound = float(cfg.add_shift)
     stacks, raws = [], []
     for _ in range(total):
-        w = rng.uniform(-1, 1, size=n).astype(np.float32)
-        _, masked = Masker(CFG.pair()).mask(Scalar(1, total), w)
+        w = rng.uniform(-bound, bound, size=n).astype(np.float32)
+        _, masked = Masker(cfg.pair()).mask(Scalar(1, total), w)
         host.aggregate(masked)
         stacks.append(masked.vect.data)
         raws.append(
@@ -67,24 +75,25 @@ def _updates(n, total, seed=0):
     return stacks, raws, host
 
 
-def _sequential_oracle(n, stacks, bs):
-    seq = ShardedAggregator(CFG, n, mesh=_mesh(1), kernel="xla")
+def _sequential_oracle(n, stacks, bs, cfg=CFG):
+    seq = ShardedAggregator(cfg, n, mesh=_mesh(1), kernel="xla")
     for i in range(0, len(stacks), bs):
         seq.add_batch(np.stack(stacks[i : i + bs]))
     return seq
 
 
-# --- the core property: kernels x mesh sizes x planar/wire ---------------
+# --- the core property: kernels x mesh sizes x widths x planar/wire --------
 
 
+@WIDTHS
 @pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("mesh_size", MESH_SIZES)
-def test_sharded_planar_byte_identical_to_sequential(kernel, mesh_size):
+def test_sharded_planar_byte_identical_to_sequential(kernel, mesh_size, cfg):
     n, total, bs = 103, 13, 4  # n not divisible by 8: padding columns in play
-    stacks, _, host = _updates(n, total)
-    seq = _sequential_oracle(n, stacks, bs)
+    stacks, _, host = _updates(n, total, cfg=cfg)
+    seq = _sequential_oracle(n, stacks, bs, cfg)
 
-    agg = ShardedAggregator(CFG, n, mesh=_mesh(mesh_size), kernel=kernel)
+    agg = ShardedAggregator(cfg, n, mesh=_mesh(mesh_size), kernel=kernel)
     stream = StreamingAggregator(agg, staging_buffers=3, dispatch_ahead=2, max_batch=bs)
     assert stream._sharded == (mesh_size > 1)
     for i in range(0, total, bs):
@@ -97,25 +106,26 @@ def test_sharded_planar_byte_identical_to_sequential(kernel, mesh_size):
     stream.close()
 
 
+@WIDTHS
 @pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("mesh_size", MESH_SIZES)
-def test_sharded_wire_byte_identical_with_deferred_acceptance(kernel, mesh_size):
+def test_sharded_wire_byte_identical_with_deferred_acceptance(kernel, mesh_size, cfg):
     """Wire path: the per-shard fold must preserve the psum-consistent
     validity semantics (an update invalid anywhere is excluded everywhere),
     the acceptance vectors stay deferred until drain, and the aggregate +
     nb_models equal the sequential path."""
     n, total, bs = 57, 11, 4
-    _, raws, _ = _updates(n, total, seed=3)
+    _, raws, _ = _updates(n, total, seed=3, cfg=cfg)
     bad = raws[5].copy()
-    bad[: CFG.bytes_per_number] = 0xFF  # element >= order -> member rejected
+    bad[: cfg.bytes_per_number] = 0xFF  # element >= order -> member rejected
     wires = raws[:5] + [bad] + raws[6:]
 
-    seq = ShardedAggregator(CFG, n, mesh=_mesh(1), kernel="xla")
+    seq = ShardedAggregator(cfg, n, mesh=_mesh(1), kernel="xla")
     seq_oks = [
         seq.add_wire_batch(np.stack(wires[i : i + bs])) for i in range(0, total, bs)
     ]
 
-    agg = ShardedAggregator(CFG, n, mesh=_mesh(mesh_size), kernel=kernel)
+    agg = ShardedAggregator(cfg, n, mesh=_mesh(mesh_size), kernel=kernel)
     stream = StreamingAggregator(agg, staging_buffers=3, dispatch_ahead=2, max_batch=bs)
     tickets = [
         stream.submit_wire_batch(np.stack(wires[i : i + bs]))
@@ -134,7 +144,7 @@ def test_sharded_wire_byte_identical_with_deferred_acceptance(kernel, mesh_size)
     stream.close()
 
 
-@pytest.mark.parametrize("kernel", ("xla", "native-u64"))
+@pytest.mark.parametrize("kernel", ("xla", "pallas-interpret"))
 def test_sharded_mixed_paths_across_drain_cycles(kernel):
     """Planar and wire batches interleaved over several drain cycles: the
     plan decomposes/reassembles per cycle and the result stays pinned to
@@ -287,68 +297,30 @@ def test_shard_failure_twice_poisons_with_batch_diagnostics():
     stream.close()
 
 
-# --- sequential multi-device native fold ----------------------------------
+# --- sequential multi-device fold ------------------------------------------
 
 
-def test_sequential_multidevice_native_fold_and_unmask():
-    """add_batch with kernel=native-u64 on an 8-device mesh: the per-shard
-    strided host fold must equal the mesh XLA fold, and unmask_limbs must
-    handle the host-resident accumulator."""
+@WIDTHS
+@pytest.mark.parametrize("kernel", ("xla", "pallas-interpret"))
+def test_sequential_multidevice_fold_and_unmask(kernel, cfg):
+    """add_batch on an 8-device mesh (one program over the mesh, the Pallas
+    kernel under shard_map): the aggregate and the unmasked result must
+    equal the single-device XLA fold's."""
     n, total, bs = 103, 8, 4
-    stacks, _, _ = _updates(n, total, seed=9)
-    ref = _sequential_oracle(n, stacks, bs)
+    stacks, _, _ = _updates(n, total, seed=9, cfg=cfg)
+    ref = _sequential_oracle(n, stacks, bs, cfg)
 
-    agg = ShardedAggregator(CFG, n, mesh=_mesh(8), kernel="native-u64")
+    agg = ShardedAggregator(cfg, n, mesh=_mesh(8), kernel=kernel)
     for i in range(0, total, bs):
         agg.add_batch(np.stack(stacks[i : i + bs]))
-    assert agg.kernel_used == "native-u64"
+    assert agg.kernel_used == kernel
     assert np.array_equal(agg.snapshot(), ref.snapshot())
 
-    mask = _updates(n, 1, seed=13)[0][0]
+    mask = _updates(n, 1, seed=13, cfg=cfg)[0][0]
     assert np.array_equal(agg.unmask_limbs(mask), ref.unmask_limbs(mask))
 
 
-# --- ShardPlan / slice-fold units ------------------------------------------
-
-
-def test_fold_planar_slice_host_matches_full_fold():
-    order = CFG.order
-    ol = host_limbs.order_limbs_for(order)
-    rng = np.random.default_rng(2)
-    k, L, n = 6, 2, 1024
-    stack = rng.integers(0, 2**32, size=(k, L, n), dtype=np.uint32)
-    stack[:, L - 1, :] &= np.uint32((1 << 13) - 1)
-    ref = host_limbs.fold_planar_batch_host(np.zeros((L, n), np.uint32), stack, ol)
-
-    # full-width buffers, strided per-slice folds
-    acc = np.zeros((L, n), np.uint32)
-    out = np.empty_like(acc)
-    for lo, hi in shard_slices(n, 8):
-        assert host_limbs.fold_planar_slice_host(acc, stack, out, lo, hi, ol, n_threads=1)
-    assert np.array_equal(out, ref)
-
-    # contiguous per-shard buffers (the streaming accumulators)
-    pieces = []
-    for lo, hi in shard_slices(n, 4):
-        a = np.zeros((L, hi - lo), np.uint32)
-        o = np.empty_like(a)
-        assert host_limbs.fold_planar_slice_host(
-            a, stack, o, lo, hi, ol, n_threads=2, acc_cols=hi - lo
-        )
-        pieces.append(o)
-    assert np.array_equal(np.concatenate(pieces, axis=1), ref)
-
-
-def test_shard_thread_budget_resolution(monkeypatch):
-    monkeypatch.delenv("XAYNET_NATIVE_SHARD_THREADS", raising=False)
-    assert shard_thread_budget(4, explicit=3) == 3
-    monkeypatch.setenv("XAYNET_NATIVE_SHARD_THREADS", "5")
-    assert shard_thread_budget(4) == 5
-    monkeypatch.setenv("XAYNET_NATIVE_SHARD_THREADS", "junk")
-    total = host_limbs.native_fold_threads()
-    assert shard_thread_budget(4) == max(1, total // 4)
-    monkeypatch.delenv("XAYNET_NATIVE_SHARD_THREADS", raising=False)
-    assert shard_thread_budget(10_000) == 1  # never below one thread
+# --- ShardPlan units ---------------------------------------------------------
 
 
 def test_shard_plan_requires_resolved_kernel():
@@ -359,12 +331,12 @@ def test_shard_plan_requires_resolved_kernel():
 
 def test_shard_plan_reassemble_roundtrip():
     """decompose -> per-shard folds -> reassemble equals the sequential
-    fold, for both backends, starting from a non-zero accumulator."""
+    fold, for both kernels, starting from a non-zero accumulator."""
     n, total, bs = 96, 4, 4
     stacks, _, _ = _updates(n, total, seed=17)
     base = _updates(n, 2, seed=18)[0]
 
-    for kernel in ("xla", "native-u64"):
+    for kernel in ("xla", "pallas-interpret"):
         ref = ShardedAggregator(CFG, n, mesh=_mesh(1), kernel="xla")
         ref.add_batch(np.stack(base))
         ref.add_batch(np.stack(stacks))
@@ -376,17 +348,13 @@ def test_shard_plan_reassemble_roundtrip():
         from xaynet_tpu.ops.fold_jax import wire_to_planar
 
         planar[:, :, :n] = wire_to_planar(np.stack(stacks))
-        if plan.native:
-            plan.fold_full(planar)
-        else:
-            for d, (lo, hi) in enumerate(plan.slices):
-                piece = jax.device_put(
-                    np.ascontiguousarray(planar[:, :, lo:hi]), plan.devices[d]
-                )
-                plan.fold_shard(d, piece)
-            plan.block_until_ready()
+        for d, (lo, hi) in enumerate(plan.slices):
+            piece = jax.device_put(
+                np.ascontiguousarray(planar[:, :, lo:hi]), plan.devices[d]
+            )
+            plan.fold_shard(d, piece)
+        plan.block_until_ready()
         agg.acc = plan.reassemble()
-        plan.close()
         assert np.array_equal(agg.snapshot(), ref.snapshot()), kernel
 
 
@@ -394,14 +362,11 @@ def test_shard_plan_reassemble_roundtrip():
 
 
 def test_shard_parallel_settings_surface():
-    from xaynet_tpu.server.settings import SettingsError, Settings
+    from xaynet_tpu.server.settings import Settings
 
     s = Settings.default()
     assert s.aggregation.shard_parallel is True
-    assert s.aggregation.shard_threads == 0
-    s.aggregation.shard_threads = -1
-    with pytest.raises(SettingsError, match="shard_threads"):
-        s.validate()
+    s.validate()
 
 
 def test_shard_parallel_opt_out_forces_single_worker():
@@ -447,7 +412,7 @@ def test_healthz_pipeline_section_reports_shards():
         assert shard["inflight_folds"] == 0
 
 
-@pytest.mark.parametrize("kernel", ("xla", "native-u64"))
+@pytest.mark.parametrize("kernel", ("xla", "pallas-interpret"))
 def test_sharded_fold_planar_rows_now_device_resident(kernel):
     """The server wire-ingest flush path: device-resident planars cached by
     validate_wire_updates fold synchronously per shard (the stacked chunk

@@ -47,7 +47,7 @@ WIDTHS = pytest.mark.parametrize("cfg", [CFG, CFG3], ids=["2limb-7B", "3limb-10B
 # these tests pin device 0 explicitly (the conftest forces 8 virtual CPU
 # devices) to exercise the SINGLE-WORKER pipeline; the shard-parallel
 # multi-device mode has its own suite in tests/test_shard_parallel.py
-KERNELS = ("xla", "native-u64", "auto")
+KERNELS = ("xla", "pallas-interpret", "auto")
 
 
 def _mesh1():
@@ -77,15 +77,11 @@ def _updates(n, total, seed=0, cfg=CFG):
 
 
 def _kernel_cases():
-    """Every fold kernel at both widths; the native u64 fold exists up to
-    2 limbs only, the Pallas fold (interpreted here) stands in its place."""
+    """Every fold kernel at both widths (the Pallas fold interpreted)."""
     return [
         pytest.param(cfg, kernel, id=f"{width}-{kernel}")
-        for cfg, width, kernels in (
-            (CFG, "2limb-7B", KERNELS),
-            (CFG3, "3limb-10B", ("xla", "pallas-interpret", "auto")),
-        )
-        for kernel in kernels
+        for cfg, width in ((CFG, "2limb-7B"), (CFG3, "3limb-10B"))
+        for kernel in KERNELS
     ]
 
 
@@ -295,10 +291,10 @@ def test_streaming_settings_surface():
 
     s = Settings.load(env={"XAYNET__AGGREGATION__DISPATCH_AHEAD": "4",
                            "XAYNET__AGGREGATION__STAGING_BUFFERS": "5",
-                           "XAYNET__AGGREGATION__KERNEL": "native-u64"})
+                           "XAYNET__AGGREGATION__KERNEL": "pallas"})
     assert s.aggregation.dispatch_ahead == 4
     assert s.aggregation.staging_buffers == 5
-    assert s.aggregation.kernel == "native-u64"
+    assert s.aggregation.kernel == "pallas"
     with pytest.raises(SettingsError):
         Settings.load(env={"XAYNET__AGGREGATION__DISPATCH_AHEAD": "0"})
     with pytest.raises(SettingsError):
@@ -542,8 +538,7 @@ def test_other_routes_stage_as_before(route, cfg):
 def test_stage_returns_without_waiting_when_every_ring_buffer_is_busy():
     n = 64
     objs = _masked_updates(n, 2, seed=24)
-    # the host fold that reads the ring buffer in place
-    dev = _staged(n, mesh=_mesh1(), staging_buffers=2, kernel="native-u64")
+    dev = _staged(n, mesh=_mesh1(), staging_buffers=2)
     ring = dev._stream._ring(dev._stream._host_kind)
     busy = [ring.acquire(), ring.acquire()]
     t0 = time.monotonic()

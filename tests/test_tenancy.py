@@ -252,9 +252,8 @@ def test_streaming_pipeline_leases_and_releases_pool_pages():
     stream.submit_batch(stack)
     stream.drain()
     assert agg.nb_models == 3
-    assert not pool.balanced("tenant-x")  # rings (+ plan) hold leases
+    assert not pool.balanced("tenant-x")  # the rings hold leases
     stream.close()
-    agg.release_plan_pages()  # the unmask-tail release
     assert pool.balanced("tenant-x")  # leases == releases at round end
     assert sched.split().get("tenant-x", 0) >= 1
 

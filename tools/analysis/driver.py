@@ -41,7 +41,6 @@ DEFAULT_TARGETS = [
     "tests",
     "tools",
     "examples",
-    "bench.py",
     "__graft_entry__.py",
     "conftest.py",
 ]

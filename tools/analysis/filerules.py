@@ -186,7 +186,6 @@ _FOLD_CALLEES = frozenset(
         "mod_add",
         "batch_mod_sum",
         "fold_wire_batch_host",
-        "fold_planar_batch_host",
         "masked_add",
     }
 )

@@ -1,4 +1,4 @@
-"""Host microbenchmark of the REST body read: StreamReader against direct.
+"""Host benchmark of the REST body read: StreamReader against direct.
 
 One process serves ``RestServer`` with a handler that drops every message;
 a second process sends ``--conns`` bodies of ``--size`` bytes at once, each

@@ -23,8 +23,8 @@ Legs (one JSON result each, combined into one line on stdout):
   and replays N updates, to pin the bytes-per-accepted-update comparison:
   the packed path must move STRICTLY fewer bytes.
 
-``--append-history`` appends the gated records to BENCH_HISTORY.jsonl
-(family: ``ingress accepted updates`` — tools/bench_gate.py).
+``--append-history`` appends the records to BENCH_HISTORY.jsonl (family:
+``ingress accepted updates``); nothing reads that file any more (ROADMAP D4).
 
 Usage (CI smoke):
   python tools/loadgen_soak.py --participants 2000 --drivers 2 --tenants 2 \

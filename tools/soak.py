@@ -576,7 +576,7 @@ def run_kill_matrix_soak(args) -> None:
     - zero pool pages stay leased after the round (no leak across a kill);
     - the restart-to-serving wall (``xaynet_recovery_seconds``) is
       recorded — with ``--append-history`` it lands in BENCH_HISTORY.jsonl
-      as the lower-is-better "restart recovery wall" bench-gate family.
+      as the lower-is-better "restart recovery wall" family.
     """
     import signal
     import socket
@@ -1369,8 +1369,8 @@ def main() -> None:
         "--append-history",
         action="store_true",
         help="with --kill-matrix: append one 'restart recovery wall' record "
-        "per kill coordinate to BENCH_HISTORY.jsonl (the lower-is-better "
-        "bench-gate family)",
+        "per kill coordinate to BENCH_HISTORY.jsonl (lower is better; "
+        "nothing reads that file any more)",
     )
     ap.add_argument(
         "--faults",
@@ -1583,7 +1583,7 @@ def main() -> None:
             # warmup block first: the first rounds pay one-time costs (JIT
             # compiles, XLA buffer pools, import side-effects) that are not
             # per-round growth; the steady-state rate is what a leak looks
-            # like (same split the bench_round RSS gate uses)
+            # like
             warmup_rounds = min(20, max(1, args.rounds // 10))
 
             def run_block(n_rounds: int) -> dict:

@@ -129,8 +129,7 @@ def fold_planar_batch_pallas(
 
     Model lengths that don't divide the tile are zero-padded internally
     (zeros are valid group elements) and sliced back afterwards.
-    ``tile_size`` overrides the default tile (bench.py sweeps it on real
-    hardware to pick the fastest VMEM blocking for the chip).
+    ``tile_size`` overrides the default tile (``TILE``).
     """
     k, n_limb, n = stack_planar.shape
     if k > MAX_LAZY_BATCH:

@@ -303,171 +303,6 @@ def batch_mod_sum(stack: np.ndarray, order_limbs: np.ndarray) -> np.ndarray:
     return stack[0]
 
 
-def native_fold_threads() -> int:
-    """The native library's process-wide fold worker budget
-    (``XAYNET_NATIVE_THREADS`` or its 2x-cores default), or 1 when the
-    library is unavailable. The shard planner divides this into per-shard
-    budgets instead of re-implementing the policy in Python."""
-    from ..utils import native
-
-    lib = native.load()
-    return int(lib.xn_fold_threads()) if lib is not None else 1
-
-
-def u64_fold_applicable(k: int, n_limb: int, order_limbs: np.ndarray) -> bool:
-    """Whether the native single-pass u64 fold is exact for this shape: a
-    <= 2-limb order whose K+1-term running sum fits u64 (pow2-boundary
-    orders — all-zero limbs — wrap exactly for any K)."""
-    if n_limb > 2:
-        return False
-    if not np.any(order_limbs):
-        return True
-    order = limbs_to_int(order_limbs)
-    return (k + 1) <= ((1 << 64) // order)
-
-
-def fold_planar_slice_host(
-    acc: np.ndarray,
-    stack: np.ndarray,
-    out: np.ndarray,
-    col0: int,
-    col1: int,
-    order_limbs: np.ndarray,
-    n_threads: int = 0,
-    acc_cols: int | None = None,
-) -> bool:
-    """Fold the model-axis column slice ``[col0, col1)`` of the planar
-    ``uint32[K, L, n]`` batch into the same slice of ``acc``, writing
-    ``out`` — reading the batch IN PLACE through its strides, so one
-    shard's fold touches zero bytes outside its slice and the staged batch
-    is never copied per shard.
-
-    ``acc``/``out`` are either full-width ``[L, n]`` buffers (the slice is
-    addressed at ``col0``) or contiguous per-shard ``[L, col1-col0]``
-    buffers (pass ``acc_cols=col1-col0``; the slice starts at column 0 —
-    the donated per-shard accumulators of the sharded streaming fold).
-    ``n_threads`` > 0 pins this call's native worker count (the per-shard
-    budget when shard folds run concurrently); 0 keeps the process default.
-
-    Returns False when no native path applies (caller falls back to a
-    copy + :func:`fold_planar_batch_host`); requirements otherwise match
-    the u64 kernel (use :func:`u64_fold_applicable`).
-    """
-    k, n_limb, n = stack.shape
-    width = col1 - col0
-    a_cols = acc_cols if acc_cols is not None else n
-    if acc.shape != (n_limb, a_cols) or out.shape != acc.shape:
-        raise ValueError("accumulator/out shape mismatch")
-    if not (acc.flags.c_contiguous and out.flags.c_contiguous and stack.flags.c_contiguous):
-        raise ValueError("slice fold requires C-contiguous buffers")
-    if out is acc:
-        raise ValueError("out must not alias acc")
-    if not u64_fold_applicable(k, n_limb, order_limbs):
-        return False
-    from ..utils import native
-
-    lib = native.load()
-    if lib is None:
-        return False
-    off = 0 if acc_cols is not None else col0
-    lib.xn_fold_planar_u64_strided(
-        native.np_u32p_at(acc, off),
-        native.np_u32p_at(stack, col0),
-        native.np_u32p_at(out, off),
-        width,
-        a_cols,  # acc/out plane stride
-        n,  # stack row (limb-plane) stride
-        n_limb * n,  # stack batch (update) stride
-        n_limb,
-        k,
-        native.np_u32p(np.ascontiguousarray(order_limbs, dtype=_U32)),
-        max(0, int(n_threads)),
-    )
-    return True
-
-
-def fold_planar_batch_host(
-    acc: np.ndarray, stack: np.ndarray, order_limbs: np.ndarray,
-    out: np.ndarray | None = None, n_threads: int = 0,
-) -> np.ndarray:
-    """Single-pass host fold of planar ``uint32[K, L, n]`` updates into the
-    planar ``uint32[L, n]`` accumulator (host analogue of
-    ``ops.fold_jax.fold_planar_batch``; reference hot loop:
-    rust/xaynet-core/src/mask/masking.rs:292-316).
-
-    Native fast path for orders that fit 64 bits (every 1-2 limb config) —
-    reads the batch once instead of XLA-CPU's strided half-word reduction
-    or the ``ceil(log2 K)``-pass pairwise tree. Falls back to the pairwise
-    numpy tree otherwise.
-
-    ``out`` optionally receives the result (contiguous, same shape/dtype as
-    ``acc``, not aliasing ``acc``): at 25M params a fresh 200 MB result
-    buffer costs ~0.15 s of page faults per fold, so steady-state callers
-    (the aggregator's native kernel) ping-pong two buffers instead. Only
-    the native path honors it; callers must use the RETURNED array either
-    way. ``n_threads`` > 0 pins the native worker count for this call (the
-    per-shard budget of the sharded streaming fold); 0 keeps the process
-    default.
-    """
-    k, n_limb, n = stack.shape
-    if acc.shape != (n_limb, n):
-        raise ValueError("accumulator/batch shape mismatch")
-    if u64_fold_applicable(k, n_limb, order_limbs):
-        from ..utils import native
-
-        lib = native.load()
-        if lib is not None:
-            acc_c = np.ascontiguousarray(acc, dtype=_U32)
-            stack_c = np.ascontiguousarray(stack, dtype=_U32)
-            if (
-                out is not None
-                and out.shape == acc_c.shape
-                and out.dtype == _U32
-                and out.flags.c_contiguous
-                and out is not acc_c
-            ):
-                pass  # reuse the caller's spare buffer
-            else:
-                out = np.empty_like(acc_c)
-            lib.xn_fold_planar_u64_strided(
-                native.np_u32p(acc_c),
-                native.np_u32p(stack_c),
-                native.np_u32p(out),
-                n,
-                n,  # acc/out plane stride (full width)
-                n,  # stack row stride
-                n_limb * n,  # stack batch stride
-                n_limb,
-                k,
-                native.np_u32p(np.ascontiguousarray(order_limbs, dtype=_U32)),
-                max(0, int(n_threads)),
-            )
-            return out
-    # fallback: wire layout pairwise tree (exact for any limb count)
-    wire = np.ascontiguousarray(stack.transpose(0, 2, 1))
-    folded = batch_mod_sum(wire, order_limbs)
-    acc_wire = np.ascontiguousarray(acc.T)
-    return np.ascontiguousarray(mod_add(acc_wire, folded, order_limbs).T)
-
-
-# ---------------------------------------------------------------------------
-# packed planar codec
-#
-# Masked limb CONTENTS are uniform-random and incompressible, but the
-# REPRESENTATION is not: group orders rarely fill their uint32 limbs, so a
-# planar ``uint32[..., L, n]`` tensor packs losslessly to the wire width
-# ``bpn = wire_width_for(order)`` bytes per element (6 instead of 8 for the
-# standard 2-limb f32 configs — a 25% cut in staged/transferred bytes).
-# The packed layout is BYTE-PLANAR ``uint8[..., bpn, n]``: byte-plane b
-# holds byte b of every element, so pack/unpack are strided plane copies
-# (no per-element gather), the device unpack is the same shift-or chain as
-# the wire unpack but over contiguous planes, and the native packed fold
-# streams bpn unit-stride byte planes exactly like the planar u64 fold
-# streams its limb planes. Lossless iff every element < 2^(8*bpn) — true
-# for every validated group element (element < order <= 2^(8*bpn)).
-# ---------------------------------------------------------------------------
-
-
 def pack_planar(planar: np.ndarray, bpn: int, out: np.ndarray | None = None) -> np.ndarray:
     """Planar ``uint32[..., L, n]`` -> packed byte-planar ``uint8[..., bpn, n]``.
 
@@ -654,103 +489,6 @@ def unpack_planar(packed: np.ndarray, n_limbs: int, out: np.ndarray | None = Non
     for b in range(bpn):
         raw[..., b // 4, b % 4 :: 4] = packed[..., b, :]
     return out
-
-
-def fold_packed_slice_host(
-    acc: np.ndarray,
-    packed: np.ndarray,
-    out: np.ndarray,
-    col0: int,
-    col1: int,
-    order_limbs: np.ndarray,
-    n_threads: int = 0,
-    acc_cols: int | None = None,
-) -> bool:
-    """Fold the column slice ``[col0, col1)`` of a PACKED byte-planar
-    ``uint8[K, bpn, n]`` batch into the planar ``uint32[L, *]`` accumulator
-    slice — the native single-pass u64 fold reading the packed bytes in
-    place (25% less batch traffic at bpn=6 vs the unpacked planar fold).
-
-    Buffer addressing matches :func:`fold_planar_slice_host`; returns False
-    when no native path applies (caller unpacks and takes the planar fold).
-    Requirements: u64-applicable order (<= 2 limbs, K+1 headroom) and
-    ``bpn <= 8``.
-    """
-    k, bpn, n = packed.shape
-    width = col1 - col0
-    n_limb = acc.shape[0]
-    a_cols = acc_cols if acc_cols is not None else n
-    if acc.shape != (n_limb, a_cols) or out.shape != acc.shape:
-        raise ValueError("accumulator/out shape mismatch")
-    if not (acc.flags.c_contiguous and out.flags.c_contiguous and packed.flags.c_contiguous):
-        raise ValueError("packed slice fold requires C-contiguous buffers")
-    if out is acc:
-        raise ValueError("out must not alias acc")
-    if bpn > 8 or not u64_fold_applicable(k, n_limb, order_limbs):
-        return False
-    from ..utils import native
-
-    lib = native.load()
-    if lib is None or not hasattr(lib, "xn_fold_packed_u64_strided"):
-        return False
-    off = 0 if acc_cols is not None else col0
-    lib.xn_fold_packed_u64_strided(
-        native.np_u32p_at(acc, off),
-        native.np_u8p_at(packed, col0),
-        native.np_u32p_at(out, off),
-        width,
-        a_cols,  # acc/out plane stride (elements)
-        n,  # packed byte-plane stride (bytes)
-        bpn * n,  # packed batch (update) stride (bytes)
-        n_limb,
-        bpn,
-        k,
-        native.np_u32p(np.ascontiguousarray(order_limbs, dtype=_U32)),
-        max(0, int(n_threads)),
-    )
-    return True
-
-
-def fold_packed_batch_host(
-    acc: np.ndarray,
-    packed: np.ndarray,
-    order_limbs: np.ndarray,
-    out: np.ndarray | None = None,
-    n_threads: int = 0,
-) -> np.ndarray:
-    """Single-pass host fold of PACKED byte-planar ``uint8[K, bpn, n]``
-    updates into the planar ``uint32[L, n]`` accumulator.
-
-    Native fast path reads the packed bytes directly (the fold's dominant
-    cost is the one mandatory read of the batch, and packed planes are
-    ``bpn / 4L`` of the unpacked bytes); without it the batch unpacks once
-    on the host and takes :func:`fold_planar_batch_host`. ``out``/
-    ``n_threads`` behave exactly like the planar fold's.
-    """
-    k, bpn, n = packed.shape
-    n_limb = acc.shape[0]
-    if acc.shape != (n_limb, n):
-        raise ValueError("accumulator/batch shape mismatch")
-    acc_c = np.ascontiguousarray(acc, dtype=_U32)
-    packed_c = np.ascontiguousarray(packed, dtype=np.uint8)
-    if (
-        out is not None
-        and out.shape == acc_c.shape
-        and out.dtype == _U32
-        and out.flags.c_contiguous
-        and out is not acc_c
-    ):
-        pass  # reuse the caller's spare buffer
-    else:
-        out = np.empty_like(acc_c)
-    if fold_packed_slice_host(
-        acc_c, packed_c, out, 0, n, order_limbs, n_threads=n_threads
-    ):
-        return out
-    # no native packed path: one host unpack, then the planar fold (which
-    # may still take its own native or pairwise route)
-    planar = unpack_planar(packed_c, n_limb)
-    return fold_planar_batch_host(acc_c, planar, order_limbs, out=out, n_threads=n_threads)
 
 
 def fold_wire_batch_host(

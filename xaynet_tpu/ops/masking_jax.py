@@ -253,9 +253,9 @@ def calibrate_mask_kernel(
     """Resolve (and memoize) the auto route for this shape NOW.
 
     ``sum_masks(kernel="auto")`` calibrates lazily inside its first call;
-    steady-state measurements (tools/bench_round.py) call this first so the
-    one-time probe race stays out of the per-round wall — exactly how a
-    long-running participant amortizes it."""
+    steady-state measurements call this first so the one-time probe race
+    stays out of the per-round wall — exactly how a long-running participant
+    amortizes it."""
     return _resolve_mask_kernel(seeds, length, config, seed_batch, mesh)
 
 
@@ -270,10 +270,9 @@ def _acc_unit(unit_acc, group_unit: np.ndarray, ol_u: np.ndarray) -> np.ndarray:
 def _host_sampler_threads(n_items: int, default_cap: int) -> int:
     """Thread budget for the host sampler routes. An explicit
     ``XAYNET_NATIVE_THREADS`` pin wins OUTRIGHT (bounded only by the item
-    count): it is the thread key the bench records in the gated
-    BENCH_HISTORY series, so the code silently second-guessing it would
-    relabel the experiment (the BENCH_r05 lesson) — and the operator who
-    pins it owns any memory trade. The default is the core count capped
+    count): the code silently second-guessing it would relabel the
+    operator's experiment — and the operator who pins it owns any memory
+    trade. The default is the core count capped
     at ``default_cap`` (the fused route passes a small cap because each
     thread holds an ``8 * length``-byte u64 partial accumulator, ~200 MB
     at 25M params)."""

@@ -36,13 +36,11 @@ from .mesh import MODEL_AXIS, make_mesh, pad_to_multiple
 logger = logging.getLogger(__name__)
 
 # cross-shard combine traffic (bytes actually copied), by path: "scatter" =
-# decomposing the global accumulator into per-shard buffers (native plans
-# copy; device plans decompose zero-copy), "gather" = reassembling /
-# materializing the accumulator on the host (the final model download and
-# any snapshot/checkpoint read). The reduce-scatter layout keeps the
-# accumulator per-shard ACROSS drain windows, so these counters advance
-# once per round instead of twice per drain — the bench's bytes-moved
-# series reads them.
+# decomposing the global accumulator into per-shard buffers (zero-copy, so
+# nothing counts it), "gather" = materializing the unmasked result on the
+# host (the final model download). The reduce-scatter layout keeps the
+# accumulator per-shard ACROSS drain windows, so the counter advances once
+# per round instead of twice per drain.
 BYTES_REDUCED = get_registry().counter(
     "xaynet_bytes_reduced_total",
     "Accumulator bytes copied on the cross-shard combine path, by "
@@ -165,119 +163,6 @@ def _build_planar_ok(n_limbs: int, order: int, multi_device: bool):
     return check
 
 
-def _sharded_native_fan_out(
-    acc_np: np.ndarray,
-    batch_np: np.ndarray,
-    batch_dtype,
-    slice_fold,
-    batch_fold,
-    n_shards: int,
-    state: dict,
-) -> np.ndarray:
-    """Shared thread fan-out for the per-shard strided native folds: one
-    concurrent kernel call per mesh shard over the full staged batch —
-    shard ``d`` reads and writes only its contiguous plane slice of the
-    shared acc/out buffers (disjoint columns, no synchronization beyond
-    the join), each call under the per-shard thread budget. The GIL is
-    released inside the C++ kernel, so the threads genuinely overlap the
-    shard folds; they are spawned per call (spawn cost ~10us each, noise
-    against a >=100ms fold) because the aggregator has no close() hook to
-    own a pool's lifecycle. ``slice_fold(acc, batch, spare, lo, hi,
-    budget) -> bool`` folds one shard's column slice; ``batch_fold(acc,
-    batch, out) -> acc`` is the exact generic fallback when the native
-    library becomes unavailable mid-round. Returns the new accumulator
-    (``state['spare']`` reused when possible, exactly like the
-    single-device ping-pong)."""
-    import threading
-
-    from .mesh import shard_slices
-    from .shards import shard_thread_budget
-
-    acc_c = np.ascontiguousarray(acc_np, dtype=np.uint32)
-    batch_c = np.ascontiguousarray(batch_np, dtype=batch_dtype)
-    spare = state["spare"]
-    if not (
-        spare is not None
-        and spare.shape == acc_c.shape
-        and spare.dtype == np.uint32
-        and spare.flags.c_contiguous
-        and spare is not acc_c
-    ):
-        spare = np.empty_like(acc_c)
-    if not state["budget"]:
-        state["budget"] = shard_thread_budget(n_shards)
-    budget = state["budget"]
-    slices = shard_slices(acc_c.shape[1], n_shards)
-    results = [False] * n_shards
-    errors: list[BaseException] = []
-
-    def fold_slice(i: int, lo: int, hi: int) -> None:
-        try:
-            results[i] = slice_fold(acc_c, batch_c, spare, lo, hi, budget)
-        except BaseException as e:  # surfaced after the join
-            errors.append(e)
-
-    threads = [
-        threading.Thread(
-            target=fold_slice, args=(i, lo, hi), name=f"xn-shard-fold-{i}", daemon=True
-        )
-        for i, (lo, hi) in enumerate(slices)
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    if errors:
-        raise errors[0]
-    if all(results):
-        return spare
-    return batch_fold(acc_c, batch_c, spare)
-
-
-def _sharded_native_fold_packed(
-    acc_np: np.ndarray, packed_np: np.ndarray, order_limbs, n_shards: int, state: dict
-) -> np.ndarray:
-    """Packed twin of :func:`_sharded_native_fold`: the shared fan-out
-    over the strided packed-fold kernel (``ops.limbs.fold_packed_slice_host``)
-    reading the byte-planar batch directly."""
-    return _sharded_native_fan_out(
-        acc_np,
-        packed_np,
-        np.uint8,
-        lambda acc, packed, spare, lo, hi, budget: host_limbs.fold_packed_slice_host(
-            acc, packed, spare, lo, hi, order_limbs, n_threads=budget
-        ),
-        # library unavailable mid-round: exact generic fallback (one unpack)
-        lambda acc, packed, out: host_limbs.fold_packed_batch_host(
-            acc, packed, order_limbs, out=out
-        ),
-        n_shards,
-        state,
-    )
-
-
-def _sharded_native_fold(
-    acc_np: np.ndarray, stack_np: np.ndarray, order_limbs, n_shards: int, state: dict
-) -> np.ndarray:
-    """The shared fan-out over the strided planar-fold kernel
-    (``ops.limbs.fold_planar_slice_host``) reading the full host planar
-    batch."""
-    return _sharded_native_fan_out(
-        acc_np,
-        stack_np,
-        np.uint32,
-        lambda acc, stack, spare, lo, hi, budget: host_limbs.fold_planar_slice_host(
-            acc, stack, spare, lo, hi, order_limbs, n_threads=budget
-        ),
-        # library unavailable mid-round: exact generic fallback
-        lambda acc, stack, out: host_limbs.fold_planar_batch_host(
-            acc, stack, order_limbs, out=out
-        ),
-        n_shards,
-        state,
-    )
-
-
 class ShardedAggregator:
     """Accumulates masked updates on-device, sharded over the model axis.
 
@@ -340,10 +225,10 @@ class ShardedAggregator:
 
     @property
     def acc(self):
-        """The global planar accumulator. With a live (adopted) shard plan
-        the per-shard buffers are authoritative and this READ reassembles
-        them on demand — zero-copy for device plans, one counted
-        concatenation for native host plans. The reduce-scatter contract:
+        """The global planar accumulator, a ``jax.Array`` always. With a
+        live (adopted) shard plan the per-shard buffers are authoritative
+        and this READ reassembles them on demand, zero-copy. The
+        reduce-scatter contract:
         nothing gathers per drain window anymore; only explicit reads
         (snapshot, checkpoint, the final model download) pay the gather."""
         plan = self._live_plan
@@ -366,16 +251,6 @@ class ShardedAggregator:
         reassemble on demand."""
         self._live_plan = plan
 
-    def release_plan_pages(self) -> None:
-        """Give the adopted plan's pool pages back (the round's unmask
-        tail, docs/DESIGN.md §19) and drop the plan — the buffers may be
-        re-leased to another tenant, so the accumulator must never be
-        reassembled from them again."""
-        plan = self._live_plan
-        if plan is not None:
-            self._live_plan = None
-            plan.release_pages()
-
     def _to_planar_padded(self, stack: np.ndarray) -> np.ndarray:
         """Wire ``[K, n, L]`` -> planar padded ``[K, L, padded_len]`` (host)."""
         planar = wire_to_planar(stack)
@@ -397,14 +272,8 @@ class ShardedAggregator:
         if stack.shape[0] > MAX_LAZY_BATCH:
             raise ValueError("batch too large for lazy-carry fold")
         planar = self._to_planar_padded(stack)
-        self._resolve_kernel_cheap(stack.shape[0])
-        if self.kernel_used == "native-u64":
-            # the host kernel reads the planar directly — staging it onto
-            # the (CPU) jax device would only buy a copy
-            self.acc = self._fold(self.acc, planar)
-        else:
-            staged = jax.device_put(planar, self._batch_sharding)
-            self.acc = self._fold(self.acc, staged)
+        staged = jax.device_put(planar, self._batch_sharding)
+        self.acc = self._fold(self.acc, staged)
         self.nb_models += stack.shape[0]
 
     def add_planar_batch(self, stack_planar: jax.Array) -> None:
@@ -610,8 +479,6 @@ class ShardedAggregator:
         aggregator (one per round) would recompile every round and retain
         every old executable.
         """
-        if kernel == "native-u64":
-            return self._make_native_fold_fn()
         if kernel in ("pallas", "pallas-interpret"):
             interpret = kernel == "pallas-interpret"
             key = (kernel, _mesh_key(self.mesh), self.order)
@@ -652,119 +519,11 @@ class ShardedAggregator:
             fn = _FOLD_FN_CACHE[key] = lambda a, s: fold_planar_batch(a, s, order)
         return fn
 
-    def _make_native_fold_fn(self):
-        """Host C++ single-pass u64 fold (``utils.native``), same
-        ``(acc, staged) -> acc`` contract as the device folds but over host
-        numpy (jax inputs are viewed with ``np.asarray`` — zero-copy for
-        CPU-backend arrays; mesh-sharded inputs gather once). NOT memoized
-        in ``_FOLD_FN_CACHE``: there is no compiled executable to leak, and
-        the closure carries a per-aggregator spare accumulator so the
-        steady state allocates nothing (a fresh 200 MB result buffer costs
-        ~0.15 s/fold in page faults at 25M params).
-
-        On a multi-device mesh the fold runs ONE CONCURRENT STRIDED KERNEL
-        CALL PER SHARD — each folds its device's contiguous plane slice
-        straight out of the full staged batch (zero slice copies) under
-        the per-shard thread budget (the process-wide auto-calibrated
-        budget split across shards, ``XAYNET_NATIVE_SHARD_THREADS`` to
-        pin) — so the host kernel honors the mesh decomposition instead of
-        refusing it, and the result stays host-resident (``unmask_limbs``
-        and ``snapshot`` handle a host accumulator)."""
-        order = self.order
-        order_limbs = host_limbs.order_limbs_for(order)
-        # u64 running-sum headroom: K+1 terms < order each must fit u64
-        # (None = pow2-boundary order, which wraps exactly for any K)
-        headroom = (
-            None if order == (1 << (32 * self.n_limbs)) else (1 << 64) // order
-        )
-        n_shards = self.mesh.devices.size
-        state = {"spare": None, "warned": False, "budget": 0}
-
-        def fold(acc, staged):
-            # host kernel reads host memory (zero-copy on CPU)  # lint: sync-ok
-            stack_np = np.asarray(staged)  # lint: sync-ok
-            if headroom is not None and stack_np.shape[0] + 1 > headroom:
-                # the usability check binds kernel_used on the FIRST batch's
-                # K; a later larger batch past the u64 headroom (high-order
-                # 2-limb configs) must take the XLA fold, not
-                # fold_planar_batch_host's silent pairwise-numpy fallback
-                if not state["warned"]:
-                    state["warned"] = True
-                    logger.warning(
-                        "native-u64 headroom exceeded at K=%d (order ~2^%d); "
-                        "folding oversized batches with the XLA kernel",
-                        stack_np.shape[0],
-                        order.bit_length(),
-                    )
-                return fold_planar_batch(np.asarray(acc), stack_np, order)  # lint: sync-ok
-            acc_np = np.asarray(acc)  # lint: sync-ok
-            if n_shards > 1:
-                out = _sharded_native_fold(acc_np, stack_np, order_limbs, n_shards, state)
-            else:
-                out = host_limbs.fold_planar_batch_host(
-                    acc_np, stack_np, order_limbs, out=state["spare"]
-                )
-            # the old accumulator's buffer becomes the next spare: the
-            # aggregator owns ``acc`` exclusively (readers go through
-            # snapshot(), which copies), so it is dead once the caller
-            # rebinds self.acc to the returned array. jax-owned buffers
-            # (the initial zeros) are read-only views — never reused.
-            state["spare"] = (
-                acc_np if (out is not acc_np and acc_np.flags.writeable) else None
-            )
-            return out
-
-        return fold
-
     def packed_staging_usable(self) -> bool:
         """Whether packed byte-planar staging actually shrinks anything:
         the wire width must be narrower than the limb width (at the
         ``order == 2^(32L)`` boundary bpn == 4L and packing is a no-op)."""
         return self.packed_width < 4 * self.n_limbs
-
-    def _make_native_packed_fold_fn(self):
-        """Host packed fold ``(acc u32[L,n], packed u8[K,bpn,n]) -> acc``:
-        the native kernel reads the byte planes directly (25% less batch
-        traffic at bpn=6 than the unpacked planar read), with the same
-        spare ping-pong, multi-shard fan-out and oversized-batch fallback
-        as :meth:`_make_native_fold_fn`."""
-        order = self.order
-        order_limbs = host_limbs.order_limbs_for(order)
-        n_limbs = self.n_limbs
-        headroom = (
-            None if order == (1 << (32 * self.n_limbs)) else (1 << 64) // order
-        )
-        n_shards = self.mesh.devices.size
-        state = {"spare": None, "warned": False, "budget": 0}
-
-        def fold(acc, packed):
-            packed_np = np.asarray(packed)  # host kernel reads host memory  # lint: sync-ok
-            acc_np = np.asarray(acc)  # lint: sync-ok
-            if headroom is not None and packed_np.shape[0] + 1 > headroom:
-                if not state["warned"]:
-                    state["warned"] = True
-                    logger.warning(
-                        "native-u64 headroom exceeded at K=%d (order ~2^%d); "
-                        "folding oversized packed batches with the XLA kernel",
-                        packed_np.shape[0],
-                        order.bit_length(),
-                    )
-                planar = host_limbs.unpack_planar(packed_np, n_limbs)
-                return fold_planar_batch(acc_np, planar, order)
-            if n_shards > 1:
-                out = _sharded_native_fold_packed(
-                    acc_np, packed_np, order_limbs, n_shards, state
-                )
-            else:
-                out = host_limbs.fold_packed_batch_host(
-                    acc_np, packed_np, order_limbs, out=state["spare"]
-                )
-            state["spare"] = (
-                acc_np if (out is not acc_np and acc_np.flags.writeable) else None
-            )
-            return out
-
-        return fold
 
     def _make_packed_fold_fn(self, kernel: str):
         """The packed-batch fold callable for ``kernel`` (byte-planar
@@ -773,8 +532,6 @@ class ShardedAggregator:
         fold in one jit (``ops.fold_jax.fold_packed_batch``) so only packed
         bytes cross host->device; Pallas kernels unpack in a separate jit
         (``pallas_call`` reads its operand from HBM — fusion buys nothing)."""
-        if kernel == "native-u64":
-            return self._make_native_packed_fold_fn()
         n_limbs, order = self.n_limbs, self.order
         if kernel in ("pallas", "pallas-interpret"):
             from ..ops.limbs_jax import packed_planar_to_limbs_jit
@@ -818,25 +575,6 @@ class ShardedAggregator:
             staged_packed.shape[0] * staged_packed.shape[-1],
             lambda: self._packed_fold_fn(acc, staged_packed),
         )
-
-    def _native_u64_usable(self, k: int) -> bool:
-        """Whether the native u64 fold can serve THIS aggregator: an order
-        within 2 limbs whose K+1-term running sum fits u64
-        (``fold_planar_batch_host``'s fast path — anything else would
-        silently fall back to the slow pairwise tree), and a loadable
-        shared library. Multi-device meshes are served too: each device's
-        contiguous plane slice folds through the strided kernel entry with
-        a per-shard thread budget (one concurrent call per shard), so the
-        mesh no longer forces the XLA fallback."""
-        if self.n_limbs > 2:
-            return False
-        if self.order != (1 << (32 * self.n_limbs)) and (k + 1) > (
-            (1 << 64) // self.order
-        ):
-            return False
-        from ..utils import native
-
-        return native.load() is not None
 
     def _make_unpack_fn(self):
         """Device wire-unpack + validity callable, memoized process-wide
@@ -948,22 +686,11 @@ class ShardedAggregator:
 
     def _resolve_kernel_cheap(self, k: int) -> None:
         """Resolve ``kernel_used`` when no timing run is needed — explicit
-        kernel, or an auto verdict already memoized for this shape. Callers
-        invoke this BEFORE staging the first batch: when the winner is the
-        host-native kernel, skipping resolution-time ``device_put`` saves a
-        full-batch host->device copy per round (~13 GB at 25M/batch 64)
-        whose result the native fold would only view back on the host."""
+        kernel, or an auto verdict already memoized for this shape."""
         if self.kernel_used is not None:
             return
         if self.kernel != "auto":
-            used = self.kernel
-            if used == "native-u64" and not self._native_u64_usable(k):
-                logger.warning(
-                    "native-u64 fold unavailable (no loadable library, or order "
-                    "outside the u64 fast path); falling back to xla"
-                )
-                used = "xla"
-            self.kernel_used = used
+            self.kernel_used = self.kernel
             self._record_resolution("configured")
             return
         key = self._auto_cache_key(k)
@@ -1019,41 +746,25 @@ class ShardedAggregator:
             return
         backend = jax.default_backend()
         key = self._auto_cache_key(staged.shape[0])
-        if backend == "cpu":
-            # interpret-mode Pallas is an oracle, not a production kernel —
-            # but the native single-pass u64 fold IS: race it against XLA on
-            # the real staged batch
-            candidates = ["xla"]
-            if self._native_u64_usable(staged.shape[0]):
-                candidates.append("native-u64")
-        else:
-            candidates = ["xla", "pallas"]
+        # interpret-mode Pallas is an oracle, not a production kernel
+        candidates = ["xla"] if backend == "cpu" else ["xla", "pallas"]
         if len(candidates) == 1:
             self.kernel_used = candidates[0]
             self._record_resolution("only-candidate")
         else:
             race, results, fns = {}, {}, {}
-            host_staged = None
             for name in candidates:
                 try:
                     fold = self._make_fold_fn(name)
-                    arg = staged
-                    if name == "native-u64":
-                        # the production native path never stages to device
-                        # (the kernel reads host memory), so time it on the
-                        # host view — on the CPU backend this is zero-copy
-                        if host_staged is None:
-                            host_staged = np.asarray(staged)  # calibration host view  # lint: sync-ok
-                        arg = host_staged
                     scratch = self._zero_acc()
                     # compile / first touch
-                    scratch, first = profiling.measure(lambda: fold(scratch, arg))
+                    scratch, first = profiling.measure(lambda: fold(scratch, staged))
                     # best of three: one draw is not a verdict (on the v5e a
                     # single timed Pallas fold read 14 ms in one process and
                     # 176 ms in the next, flipping the winner between runs)
                     dt = float("inf")
                     for _ in range(_RACE_DRAWS):
-                        scratch, draw = profiling.measure(lambda: fold(scratch, arg))
+                        scratch, draw = profiling.measure(lambda: fold(scratch, staged))
                         dt = min(dt, draw)
                     profiling.record_calibration(name, dt)
                     race[name] = {
@@ -1120,24 +831,6 @@ class ShardedAggregator:
                 self.padded_length,
                 lambda: self._unmask_plan(self._live_plan, planar),
             )
-        if not isinstance(self.acc, jax.Array):
-            # the native fold keeps the accumulator host-resident (it would
-            # previously ride into the jit as an implicit upload; a
-            # multi-device mesh makes that upload a sharding conflict):
-            # unmask is the same elementwise modular subtract, on host
-            # limbs, for a result the caller reads on the host anyway
-            acc_wire = np.ascontiguousarray(
-                np.asarray(self.acc)[:, : self.model_length].T
-            )
-            mask_wire = np.ascontiguousarray(planar[:, : self.model_length].T)
-            order_limbs = host_limbs.order_limbs_for(self.order)
-            return profiling.timed_kernel(
-                "unmask",
-                self.padded_length,
-                lambda: np.ascontiguousarray(
-                    host_limbs.mod_sub(acc_wire, mask_wire, order_limbs)
-                ),
-            )
         mask_dev = jax.device_put(jnp.asarray(planar), self._acc_sharding)
         out = profiling.timed_kernel(
             "unmask",
@@ -1158,12 +851,6 @@ class ShardedAggregator:
         real_hi = min(hi, self.model_length)
         if lo >= real_hi:
             return
-        if plan.native:
-            order_limbs = host_limbs.order_limbs_for(self.order)
-            acc_w = np.ascontiguousarray(plan.accs[d][:, : real_hi - lo].T)  # lint: guarded-ok: drain barrier read
-            mask_w = np.ascontiguousarray(mask_planar[:, lo:real_hi].T)
-            out[lo:real_hi] = host_limbs.mod_sub(acc_w, mask_w, order_limbs)
-            return
         mask_dev = jax.device_put(
             np.ascontiguousarray(mask_planar[:, lo:hi]), plan.devices[d]
         )
@@ -1175,29 +862,24 @@ class ShardedAggregator:
 
     def _unmask_plan(self, plan, mask_planar: np.ndarray) -> np.ndarray:
         """Per-shard in-place unmask against a live reduce-scatter plan:
-        native plans subtract on each host shard buffer, device plans
-        dispatch one subtract per device (all in flight before the first
-        fetch) — either way only the UNMASKED per-shard slices move, once,
-        into the host wire result."""
+        one subtract per device (all in flight before the first fetch) —
+        only the UNMASKED per-shard slices move, once, into the host wire
+        result."""
         out = np.empty((self.model_length, self.n_limbs), dtype=np.uint32)
-        if plan.native:
-            for d in range(len(plan.slices)):
-                self.unmask_shard(plan, d, mask_planar, out)
-        else:
-            pending = []
-            for d, (lo, hi) in enumerate(plan.slices):
-                mask_dev = jax.device_put(
-                    np.ascontiguousarray(mask_planar[:, lo:hi]), plan.devices[d]
-                )
-                # dispatch every shard's subtract before fetching any: the
-                # per-device kernels overlap, the downloads serialize after
-                pending.append(
-                    (lo, hi, _unmask_kernel(plan.accs[d], mask_dev, self.order))  # lint: guarded-ok: drain barrier read
-                )
-            for lo, hi, res in pending:
-                real_hi = min(hi, self.model_length)
-                if lo < real_hi:
-                    out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T
+        pending = []
+        for d, (lo, hi) in enumerate(plan.slices):
+            mask_dev = jax.device_put(
+                np.ascontiguousarray(mask_planar[:, lo:hi]), plan.devices[d]
+            )
+            # dispatch every shard's subtract before fetching any: the
+            # per-device kernels overlap, the downloads serialize after
+            pending.append(
+                (lo, hi, _unmask_kernel(plan.accs[d], mask_dev, self.order))  # lint: guarded-ok: drain barrier read
+            )
+        for lo, hi, res in pending:
+            real_hi = min(hi, self.model_length)
+            if lo < real_hi:
+                out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T
         BYTES_REDUCED.labels(path="gather").inc(out.nbytes)
         return np.ascontiguousarray(out)
 
@@ -1211,24 +893,19 @@ class ShardedAggregator:
         self.acc = jax.device_put(jnp.asarray(planar), self._acc_sharding)
         self.nb_models = nb_models
 
-    def snapshot_shards(self) -> Optional[list[tuple[int, int, np.ndarray]]]:
+    def snapshot_shards(self) -> list[tuple[int, int, np.ndarray]]:
         """Packed per-shard planes ``[(lo, hi, uint32[L, hi-lo])]`` of the
         PADDED model axis — the journal form that lets a device round
         checkpoint without reassembling the global accumulator (each plane
-        is one device/shard slice, fetched independently). Returns None when
-        no per-shard decomposition exists; the caller falls back to the
-        gathered wire snapshot."""
+        is one device/shard slice, fetched independently)."""
         plan = self._live_plan
         if plan is not None:
             return [
                 (lo, hi, np.asarray(acc))  # lint: guarded-ok: drain barrier read
                 for (lo, hi), acc in zip(plan.slices, plan.accs)
             ]
-        acc = self._acc
-        if not isinstance(acc, jax.Array):
-            return None
         planes: dict[int, tuple[int, int, np.ndarray]] = {}
-        for s in acc.addressable_shards:
+        for s in self._acc.addressable_shards:
             col = s.index[1]
             lo = col.start if col.start is not None else 0
             hi = col.stop if col.stop is not None else self.padded_length

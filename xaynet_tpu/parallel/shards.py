@@ -3,9 +3,7 @@
 The mesh-sharded fold (``ShardedAggregator``) runs ONE program over the
 whole mesh per batch: a single dispatch, a single accumulator, a single
 host sync at drain. That shape cannot overlap per-device work — every
-device waits for the slowest transfer, and the host-native kernel was
-locked out of multi-device meshes entirely because it had no notion of a
-device slice.
+device waits for the slowest transfer.
 
 A :class:`ShardPlan` decomposes the aggregator's planar accumulator into
 per-shard owned buffers — one per mesh device, each covering that device's
@@ -16,21 +14,11 @@ transfers that overlap other shards' in-flight folds (the DrJAX-style
 MapReduce pipelining of arxiv 2403.07128, applied across the mesh instead
 of across batches).
 
-Two shard-fold backends, chosen by the aggregator's resolved kernel:
-
-- **native-u64** — per-shard host buffers folded by the threaded C++
-  kernel. The strided entry (``ops.limbs.fold_planar_slice_host``) reads a
-  shard's column slice straight out of the full staged batch, so the
-  sequential multi-device fold and the bench's fold-only loop copy
-  nothing; the streaming path folds contiguous per-shard ring buffers.
-  Each call carries a per-shard thread budget: the process-wide
-  auto-calibrated budget (``XAYNET_NATIVE_THREADS`` / 2x cores) split
-  across the shards that now run concurrently, overridable with
-  ``XAYNET_NATIVE_SHARD_THREADS``.
-- **device kernels** (xla/pallas) — per-device single-device arrays folded
-  by the already-jitted ``fold_planar_batch`` (its ``donate_argnums=(0,)``
-  is the per-shard accumulator donation); the executable is shared across
-  shards (same shapes, same program).
+One shard-fold backend: per-device single-device arrays folded by the
+aggregator's resolved kernel (xla/pallas) — the already-jitted
+``fold_planar_batch`` (its ``donate_argnums=(0,)`` is the per-shard
+accumulator donation); the executable is shared across shards (same
+shapes, same program).
 
 Exactness: the fold is an exact modular sum and the model axis is
 embarrassingly parallel, so any decomposition of the column axis folds to
@@ -41,85 +29,34 @@ barrier guarantees.
 
 Ownership contract: while a plan is ACTIVE (built and not yet
 reassembled), the per-shard buffers are the authoritative accumulator and
-the aggregator's global ``acc`` is stale — for device kernels the first
-donated fold actually invalidates it (the zero-copy decomposition aliases
-its buffers). ``reassemble()`` publishes the per-shard state back as the
+the aggregator's global ``acc`` is stale — the first donated fold
+actually invalidates it (the zero-copy decomposition aliases its
+buffers). ``reassemble()`` publishes the per-shard state back as the
 global accumulator; the streaming pipeline calls it from ``drain()``, its
 cross-shard barrier.
 """
 
 from __future__ import annotations
 
-import logging
-import os
 import threading
-import weakref
-from concurrent.futures import ThreadPoolExecutor
 
-import numpy as np
+import jax
 
-from ..ops import limbs as host_limbs
 from .mesh import shard_slices
-
-logger = logging.getLogger(__name__)
-
-SHARD_THREADS_ENV = "XAYNET_NATIVE_SHARD_THREADS"
-
-
-def _release_plan_leases(pool, leases: list) -> None:
-    """Module-level so a plan's GC finalizer holds no plan reference."""
-    for lease in leases:
-        pool.release(lease)
-
-
-def shard_thread_budget(n_shards: int, explicit: int = 0) -> int:
-    """Per-shard native worker-thread budget: an explicit setting wins,
-    then the ``XAYNET_NATIVE_SHARD_THREADS`` env pin (what the bench
-    records next to its headline), then the process-wide auto-calibrated
-    budget split across the shards that will run concurrently."""
-    if explicit > 0:
-        return explicit
-    env = os.environ.get(SHARD_THREADS_ENV, "")
-    if env:
-        try:
-            return max(1, int(env))
-        except ValueError:
-            logger.warning("ignoring non-integer %s=%r", SHARD_THREADS_ENV, env)
-    return max(1, host_limbs.native_fold_threads() // n_shards)
 
 
 class ShardPlan:
     """Per-shard accumulator state + fold entry points for one aggregator.
 
-    Built against a resolved kernel (``agg.kernel_used``); ``zero_accs``
-    starts from zeros without reading ``agg.acc`` (kernel calibration and
-    tests race plans without touching the live accumulator).
+    Built against a resolved kernel (``agg.kernel_used``).
     """
 
-    def __init__(self, agg, shard_threads: int = 0, zero_accs: bool = False,
-                 pool=None, tenant: str = "default"):
+    def __init__(self, agg):
         if agg.kernel_used is None:
             raise ValueError("kernel must be resolved before building a shard plan")
         self.agg = agg
-        # paged-pool seam (docs/DESIGN.md §19): with a pool, the per-shard
-        # accumulator/spare buffers are page runs LEASED from the shared
-        # arena under this plan's tenant instead of privately-owned
-        # allocations — tenants' variable-length plans pack into one slab
-        # set. Device plans lease from the capacity LEDGER (fold kernels
-        # donate buffers, so page identity cannot survive a fold there).
-        # Pages release at the round's unmask (`release_pages`), with a GC
-        # finalizer + the Idle-phase reclaim as crash-path backstops.
-        self.tenant = tenant
-        self._pool = pool
-        self._pool_leases: list = []
-        self.native = agg.kernel_used == "native-u64"
-        self.n_shards = agg.mesh.devices.size
-        self.slices = shard_slices(agg.padded_length, self.n_shards)
+        self.slices = shard_slices(agg.padded_length, agg.mesh.devices.size)
         self.devices = list(agg.mesh.devices.flat)
-        self.order_limbs = host_limbs.order_limbs_for(agg.order)
-        self.n_threads = shard_thread_budget(self.n_shards, shard_threads) if self.native else 0
-        self._pool: ThreadPoolExecutor | None = None
-        self._warned_fallback = False  # guarded-by: _device_dispatch_lock
         # serializes device folds issued from the D worker threads: jax's
         # dispatch/execution path is not reliably thread-safe for
         # concurrent donating jit calls on the virtual-device CPU backend
@@ -130,140 +67,33 @@ class ShardPlan:
         # (XLA's intra-op pool still spans the cores, and staging copies
         # keep overlapping). On real accelerators only the host-side
         # dispatch serializes — per-device execution stays concurrent,
-        # which is the point of the shard fan-out. The native path never
-        # takes the lock (synchronous GIL-released kernel calls over
-        # disjoint buffers).
+        # which is the point of the shard fan-out.
         self._device_dispatch_lock = threading.Lock()
-        self._serialize_device_folds = False
-        if not self.native:
-            import jax
-
-            self._serialize_device_folds = jax.default_backend() == "cpu"
-        # accs/spares carry a guarded-by annotation for the DEVICE fold
-        # path (the PR-7 torn-slice class: concurrent donating jit calls);
-        # the native path's slot accesses are per-shard-disjoint by
-        # construction and carry per-line `# lint: guarded-ok` rationales
-        if self.native:
-            if zero_accs:
-                self.accs = [  # guarded-by: _device_dispatch_lock
-                    self._alloc((agg.n_limbs, hi - lo))
-                    for lo, hi in self.slices
-                ]
-            else:
-                acc_np = np.asarray(agg.acc)
-                self.accs = []
-                for lo, hi in self.slices:
-                    buf = self._alloc((agg.n_limbs, hi - lo))
-                    np.copyto(buf, acc_np[:, lo:hi])
-                    self.accs.append(buf)
-                from .aggregator import BYTES_REDUCED
-
-                # host memory has no sharded view: decomposing the global
-                # accumulator copies it once (the reduce-scatter layout
-                # keeps the plan across drain windows, so this is per
-                # round, not per drain)
-                BYTES_REDUCED.labels(path="scatter").inc(int(acc_np.nbytes))
-            self.spares: list = [  # guarded-by: _device_dispatch_lock
-                self._alloc(a.shape) for a in self.accs
-            ]
-        else:
-            import jax
-            import jax.numpy as jnp
-
-            if zero_accs:
-                self.accs = [
-                    jax.device_put(
-                        jnp.zeros((agg.n_limbs, hi - lo), dtype=jnp.uint32), dev
-                    )
-                    for (lo, hi), dev in zip(self.slices, self.devices)
-                ]
-            elif not isinstance(agg.acc, jax.Array):
-                # a host-resident accumulator (an earlier native fold left
-                # it on the host): upload each device its slice
-                acc_np = np.asarray(agg.acc)
-                self.accs = [
-                    jax.device_put(np.ascontiguousarray(acc_np[:, lo:hi]), dev)
-                    for (lo, hi), dev in zip(self.slices, self.devices)
-                ]
-            else:
-                # zero-copy decomposition: the addressable shards of the
-                # mesh-sharded accumulator ARE the per-device slices; the
-                # first donated fold invalidates the global array, which is
-                # exactly the ownership handoff documented above
-                by_start = {
-                    s.index[-1].start or 0: s.data for s in agg.acc.addressable_shards
-                }
-                self.accs = [by_start[lo] for lo, _ in self.slices]
-            self.spares = []
-            if self._pool is not None:
-                # device plans lease from the CAPACITY LEDGER: the
-                # accumulator's HBM footprint is charged to the tenant so
-                # a plan that would not fit fails fast at build time
-                self._pool_leases.append(
-                    self._pool.lease_device(
-                        self.tenant, agg.n_limbs * agg.padded_length * 4
-                    )
-                )
-        if self._pool is not None:
-            # crash-path backstop: a plan dropped without release_pages()
-            # gives its pages back at collection time (by then nothing can
-            # alias the leased runs); Idle's reclaim covers the rest
-            weakref.finalize(
-                self, _release_plan_leases, self._pool, self._pool_leases
-            )
-
-    def _alloc(self, shape) -> np.ndarray:
-        """A zeroed uint32 host buffer: a page-run lease from the shared
-        pool when one is attached, a private allocation otherwise."""
-        if self._pool is None:
-            return np.zeros(shape, dtype=np.uint32)
-        lease = self._pool.lease_host(self.tenant, shape, np.uint32)
-        self._pool_leases.append(lease)
-        return lease.array
-
-    def release_pages(self) -> None:
-        """Release every page lease this plan holds (the round's unmask
-        path; idempotent against the GC finalizer and the Idle reclaim).
-        The per-shard buffers must no longer be read past this point —
-        the pool may re-lease their pages to another tenant."""
-        if self._pool is None:
-            return
-        for lease in self._pool_leases:
-            self._pool.release(lease)
+        self._serialize_device_folds = jax.default_backend() == "cpu"
+        # zero-copy decomposition: the addressable shards of the
+        # mesh-sharded accumulator ARE the per-device slices; the first
+        # donated fold invalidates the global array, which is exactly the
+        # ownership handoff documented above. accs carries a guarded-by
+        # annotation (the PR-7 torn-slice class: concurrent donating jit
+        # calls)
+        by_start = {
+            s.index[-1].start or 0: s.data for s in agg.acc.addressable_shards
+        }
+        self.accs = [  # guarded-by: _device_dispatch_lock
+            by_start[lo] for lo, _ in self.slices
+        ]
 
     # -- folds ------------------------------------------------------------
 
     def fold_shard(self, d: int, batch) -> None:
-        """Fold a per-shard batch ``[K, L, width]`` into shard ``d``'s
-        accumulator. Native: a host-contiguous array folded by the C++
-        kernel under this plan's per-shard thread budget, ping-ponging the
-        shard's donated spare buffer. Device: a ``device[d]``-resident
-        array folded by the jitted kernel (accumulator donated).
+        """Fold a per-shard batch ``[K, L, width]``, a ``device[d]``-resident
+        array, into shard ``d``'s accumulator with the jitted kernel
+        (accumulator donated).
 
         The accumulator is reassigned only after the fold call returns, so
         an exception leaves the shard consistent — the streaming pipeline's
         per-shard sync-retry relies on this."""
-        if self.native:
-            stack_np = np.asarray(batch)  # host-kernel view  # lint: sync-ok
-            if not host_limbs.u64_fold_applicable(
-                stack_np.shape[0], self.agg.n_limbs, self.order_limbs
-            ):
-                self._warn_fallback(stack_np.shape[0])
-            # native slot accesses: shard d's buffers are owned by its
-            # single worker; slots are disjoint across shards and the
-            # host kernel performs no device dispatch
-            acc = self.accs[d]  # lint: guarded-ok: single-owner shard slot
-            out = host_limbs.fold_planar_batch_host(
-                acc,
-                stack_np,
-                self.order_limbs,
-                out=self.spares[d],  # lint: guarded-ok: single-owner shard slot
-                n_threads=self.n_threads,
-            )
-            spare_back = acc if (out is not acc and acc.flags.writeable) else None
-            self.spares[d] = spare_back  # lint: guarded-ok: single-owner shard slot
-            self.accs[d] = out  # lint: guarded-ok: single-owner shard slot
-        elif self.agg.kernel_used in ("pallas", "pallas-interpret"):
+        if self.agg.kernel_used in ("pallas", "pallas-interpret"):
             from ..ops import fold_pallas
 
             # late module-attribute lookup so test spies see the call, same
@@ -287,35 +117,11 @@ class ShardPlan:
 
     def fold_shard_packed(self, d: int, packed) -> None:
         """Fold a per-shard PACKED byte-planar batch ``uint8[K, bpn, width]``
-        into shard ``d``'s accumulator (the packed-staging streaming path).
-        Native: the strided packed kernel reads the byte planes directly
-        (``ops.limbs.fold_packed_batch_host``), falling back to one unpack +
-        the planar fold when the u64 path doesn't apply. Device: the fused
-        unpack+fold jit (``ops.fold_jax.fold_packed_batch``) on the shard's
-        device — only packed bytes ever cross host->device.
+        into shard ``d``'s accumulator (the packed-staging streaming path):
+        the fused unpack+fold jit (``ops.fold_jax.fold_packed_batch``) on
+        the shard's device — only packed bytes ever cross host->device.
         Consistency contract matches :meth:`fold_shard` exactly (the
         accumulator is reassigned only after the fold returns)."""
-        if self.native:
-            packed_np = np.asarray(packed)  # host-kernel view  # lint: sync-ok
-            if not (
-                packed_np.shape[1] <= 8
-                and host_limbs.u64_fold_applicable(
-                    packed_np.shape[0], self.agg.n_limbs, self.order_limbs
-                )
-            ):
-                self._warn_fallback(packed_np.shape[0])
-            acc = self.accs[d]  # lint: guarded-ok: single-owner shard slot
-            out = host_limbs.fold_packed_batch_host(
-                acc,
-                packed_np,
-                self.order_limbs,
-                out=self.spares[d],  # lint: guarded-ok: single-owner shard slot
-                n_threads=self.n_threads,
-            )
-            spare_back = acc if (out is not acc and acc.flags.writeable) else None
-            self.spares[d] = spare_back  # lint: guarded-ok: single-owner shard slot
-            self.accs[d] = out  # lint: guarded-ok: single-owner shard slot
-            return
         from ..ops.fold_jax import fold_packed_batch
 
         n_limbs, order = self.agg.n_limbs, self.agg.order
@@ -346,106 +152,30 @@ class ShardPlan:
         with self._device_dispatch_lock:
             new_acc = call(self.accs[d])
             if self._serialize_device_folds:
-                import jax
-
                 new_acc = jax.block_until_ready(new_acc)  # lint: sync-ok
             # reassign INSIDE the lock: the slot write itself must not
             # interleave with another shard's donating dispatch (the PR-7
             # torn-slice hazard this lock exists for)
             self.accs[d] = new_acc
 
-    def fold_shard_slice(self, d: int, full_planar: np.ndarray) -> None:
-        """Fold shard ``d``'s column slice straight out of a FULL staged
-        planar ``uint32[K, L, padded]`` batch — the strided native read,
-        zero slice copies (native plans only)."""
-        if not self.native:
-            raise RuntimeError("slice folds are a native-kernel path")
-        lo, hi = self.slices[d]
-        acc, spare = self.accs[d], self.spares[d]  # lint: guarded-ok: single-owner shard slot
-        if spare is None:
-            spare = np.empty_like(acc)
-        if host_limbs.fold_planar_slice_host(
-            acc,
-            full_planar,
-            spare,
-            lo,
-            hi,
-            self.order_limbs,
-            n_threads=self.n_threads,
-            acc_cols=hi - lo,
-        ):
-            self.accs[d], self.spares[d] = spare, acc  # lint: guarded-ok: single-owner shard slot
-            return
-        # u64 headroom exceeded (or library gone mid-round): copy the slice
-        # and take the generic fold — exact, just not single-pass
-        self._warn_fallback(full_planar.shape[0])
-        self.fold_shard(d, np.ascontiguousarray(full_planar[:, :, lo:hi]))
-
-    def fold_full(self, full_planar: np.ndarray) -> None:
-        """Fold every shard's slice of a full staged batch CONCURRENTLY
-        (one strided kernel call per shard, each under the per-shard thread
-        budget) — the sequential multi-device native fold and the bench's
-        fold-only loop. The calls release the GIL inside the C++ kernel,
-        so a thread pool genuinely overlaps them."""
-        if not self.native:
-            raise RuntimeError("fold_full is a native-kernel path")
-        if self._pool is None:
-            self._pool = ThreadPoolExecutor(
-                max_workers=self.n_shards, thread_name_prefix="xn-shard-fold"
-            )
-        list(
-            self._pool.map(
-                lambda d: self.fold_shard_slice(d, full_planar), range(self.n_shards)
-            )
-        )
-
-    def _warn_fallback(self, k: int) -> None:
-        if not self._warned_fallback:  # lint: guarded-ok: benign idempotent warn latch
-            self._warned_fallback = True  # lint: guarded-ok: benign idempotent warn latch
-            logger.warning(
-                "native u64 headroom exceeded at K=%d (order ~2^%d); shard "
-                "folds taking the generic host path for oversized batches",
-                k,
-                self.agg.order.bit_length(),
-            )
-
     # -- barrier / reassembly ---------------------------------------------
 
     def block_until_ready(self) -> None:
-        """Wait for every shard's in-flight device fold (native folds are
-        synchronous — nothing to wait for)."""
-        if not self.native:
-            import jax
-
-            # lint: guarded-ok: drain barrier — workers quiesced behind the queue join
-            jax.block_until_ready(self.accs)  # lint: sync-ok  # lint: guarded-ok: drain barrier read
+        """Wait for every shard's in-flight device fold."""
+        # lint: guarded-ok: drain barrier — workers quiesced behind the queue join
+        jax.block_until_ready(self.accs)  # lint: sync-ok  # lint: guarded-ok: drain barrier read
 
     def reassemble(self):
         """The global planar accumulator assembled from the per-shard
-        state: zero-copy for device plans
-        (``make_array_from_single_device_arrays`` over the per-device
-        buffers, which ARE the mesh sharding's shards), one counted
-        concatenation copy for native plans (host memory has no sharded
-        view). Reduce-scatter contract (DESIGN §17): this is a READ — an
+        state, zero-copy (``make_array_from_single_device_arrays`` over the
+        per-device buffers, which ARE the mesh sharding's shards).
+        Reduce-scatter contract (DESIGN §17): this is a READ — an
         adopted plan stays authoritative afterwards and keeps folding into
         the same per-shard buffers (``ShardedAggregator.acc`` calls this
         on demand for snapshot/checkpoint/final download). Only an
         explicit ``acc`` WRITE supersedes the plan."""
-        if self.native:
-            from .aggregator import BYTES_REDUCED
-
-            out = np.concatenate(self.accs, axis=1)  # lint: guarded-ok: drain barrier read
-            BYTES_REDUCED.labels(path="gather").inc(int(out.nbytes))
-            return out
-        import jax
-
         return jax.make_array_from_single_device_arrays(
             (self.agg.n_limbs, self.agg.padded_length),
             self.agg._acc_sharding,
             list(self.accs),  # lint: guarded-ok: drain barrier read
         )
-
-    def close(self) -> None:
-        if self._pool is not None:
-            self._pool.shutdown(wait=False)
-            self._pool = None

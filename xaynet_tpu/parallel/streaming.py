@@ -10,9 +10,8 @@ the device take turns idling. This module turns that into a pipeline:
   while batch N folds, and the per-batch ``np.pad``/``np.stack``
   allocations (plus their page-fault tax, ~0.15 s per 200 MB at 25M
   params) disappear entirely. A buffer is reused only after the fold that
-  consumed it has finished reading host memory (for device kernels: after
-  the ``device_put`` transfer is complete; for the native host kernel:
-  after the fold call returns). A single-device pipeline lends the buffer
+  consumed it has finished reading host memory (after the ``device_put``
+  transfer is complete). A single-device pipeline lends the buffer
   to a batch that is still filling (``open_batch``), so its rows are
   written into their slots as they arrive (``stage_row``) and submitting
   the batch (``submit_staged``) relays nothing out.
@@ -38,9 +37,8 @@ pipeline runs ONE FOLD WORKER PER SHARD instead of the single FIFO worker:
 each mesh device owns its contiguous model-axis plane slice with a donated
 per-shard accumulator (``shards.ShardPlan``), the producer slices the
 padded batch ONCE on the host into per-shard staging rings, and each
-shard's host→device transfer overlaps the other shards' in-flight folds
-(device kernels) or each shard's threaded host fold runs concurrently
-under a split thread budget (the native kernel). A batch COMMITS — counts
+shard's host→device transfer overlaps the other shards' in-flight folds.
+A batch COMMITS — counts
 toward ``nb_models`` / leaves flight — only when EVERY shard folded its
 slice (``_BatchJob``), so per-shard progress skew never shows up in the
 accounting; ``drain()`` is the cross-shard barrier that performs the one
@@ -436,7 +434,6 @@ class StreamingAggregator:
         dispatch_ahead: int = 2,
         max_batch: int = 64,
         shard_parallel: bool | None = None,
-        shard_threads: int = 0,
         packed: bool | None = None,
         tenant: str = "default",
         pool=None,
@@ -453,19 +450,15 @@ class StreamingAggregator:
         self.dispatch_ahead = dispatch_ahead
         self.max_batch = min(max_batch, MAX_LAZY_BATCH)
         # shard-parallel: one fold worker per mesh device, on by default
-        # whenever the mesh actually has more than one (None = auto);
-        # shard_threads pins the per-shard native thread budget (0 = split
-        # the process budget across shards / XAYNET_NATIVE_SHARD_THREADS)
+        # whenever the mesh actually has more than one (None = auto)
         n_dev = agg.mesh.devices.size
         self._sharded = n_dev > 1 and (shard_parallel is None or shard_parallel)
         self._n_shards = n_dev if self._sharded else 1
-        self._shard_threads = shard_threads
         # packed staging (on by default wherever it shrinks anything): the
         # planar submit paths stage byte-planar uint8[K, bpn, width] planes
         # — bpn/(4L) of the unpacked ring/transfer bytes — and the fold
-        # reads the packed planes directly (native) or unpacks in-graph
-        # (device). The fold math is the exact same modular sum over the
-        # exact same (validated, < order) elements, so the aggregate is
+        # unpacks in-graph. The fold math is the exact same modular sum over
+        # the exact same (validated, < order) elements, so the aggregate is
         # byte-identical to unpacked staging.
         self._packed = (
             agg.packed_staging_usable() if packed is None
@@ -473,7 +466,7 @@ class StreamingAggregator:
         )
         # multi-tenant seam (docs/DESIGN.md §19): the tenant id labels this
         # pipeline's page leases, scheduler slots, spans and flight dumps;
-        # the shared pool backs the staging rings and shard-plan buffers;
+        # the shared pool backs the staging rings;
         # the scheduler interleaves this tenant's fold batches with other
         # tenants' on the one mesh (fairness + global in-flight bound)
         self.tenant = tenant
@@ -546,17 +539,13 @@ class StreamingAggregator:
             for w in self._shard_workers:
                 if w is not None and w.is_alive():
                     w.join(timeout=60.0)
-        if self._plan is not None:  # lint: guarded-ok: post-drain, workers joined above
-            # shut the plan's fold pool; the per-shard buffers stay ADOPTED
-            # by the aggregator (reduce-scatter) so finalize/unmask/snapshot
-            # after close still read the accumulator — on a poisoned
-            # pipeline they surface the error through drain() first
-            self._plan.close()  # lint: guarded-ok: post-drain, workers joined above
-            self._plan = None  # lint: guarded-ok: post-drain, workers joined above
+        # the per-shard buffers stay ADOPTED by the aggregator
+        # (reduce-scatter) so finalize/unmask/snapshot after close still
+        # read the accumulator — on a poisoned pipeline they surface the
+        # error through drain() first
+        self._plan = None  # lint: guarded-ok: post-drain, workers joined above
         # staging pages go back to the pool (nothing is in flight past the
-        # drain/joins above); the shard plan's accumulator pages stay
-        # leased — unmask still reads them — and release through
-        # StagedAggregator.release_pool / the round-boundary reclaim
+        # drain/joins above)
         with self._lock:
             rings = list(self._rings.values()) + list(self._shard_rings.values())
             self._rings.clear()
@@ -1104,22 +1093,16 @@ class StreamingAggregator:
                 np.asarray(payload), agg.n_limbs  # host ring view  # lint: sync-ok
             )
             agg._resolve_kernel(jax.device_put(planar, agg._batch_sharding))
-        if agg.kernel_used == "native-u64":
-            # host fold reads the ring buffer directly (synchronous)
-            # — no device staging at all (packed: the byte planes fold
-            # in place through the native packed kernel)
-            self._credit(payload, k, packed=packed)
-        else:
-            with _h2d(kind, payload.nbytes):
-                staged = jax.device_put(
-                    payload, agg._batch_packed_sharding if packed else agg._batch_sharding
-                )
-                self._credit(staged, k, packed=packed)
-                try:
-                    jax.block_until_ready(staged)  # host buffer free to reuse  # lint: sync-ok
-                except BaseException as e:
-                    # _credit already handed the count off: settled
-                    raise _UnsafeFoldError(settled=True) from e
+        with _h2d(kind, payload.nbytes):
+            staged = jax.device_put(
+                payload, agg._batch_packed_sharding if packed else agg._batch_sharding
+            )
+            self._credit(staged, k, packed=packed)
+            try:
+                jax.block_until_ready(staged)  # host buffer free to reuse  # lint: sync-ok
+            except BaseException as e:
+                # _credit already handed the count off: settled
+                raise _UnsafeFoldError(settled=True) from e
         ticket.accepted = np.ones(k, dtype=bool)
 
     def _degrade_and_retry(self, payload, kind: str, k: int, ticket, seq: int,
@@ -1316,11 +1299,11 @@ class StreamingAggregator:
     # accumulators (shards.ShardPlan) into the aggregator's global acc.
 
     def _ensure_plan(self, k: int, calib_staged):
-        """Resolve the fold kernel (racing XLA against the per-shard native
-        fold on the first real batch, exactly like the sequential path) and
-        build the shard plan. ``calib_staged`` lazily produces a full
-        staged planar ``[K, L, padded]`` (host or device) — only invoked
-        when an auto verdict is not already memoized for this shape."""
+        """Resolve the fold kernel (on the first real batch, exactly like
+        the sequential path) and build the shard plan. ``calib_staged``
+        lazily produces a full staged planar ``[K, L, padded]`` (host or
+        device) — only invoked when an auto verdict is not already memoized
+        for this shape."""
         agg = self.agg
         if agg.kernel_used is None:
             agg._resolve_kernel_cheap(k)
@@ -1335,11 +1318,8 @@ class StreamingAggregator:
             plan = self._plan
         if plan is not None and agg._live_plan is not plan:
             # an explicit accumulator write (restore/reset) superseded the
-            # adopted plan: the per-shard buffers are stale — shut its
-            # fold pool (only this producer folds into it, so nothing is
-            # in flight), give its pages back, and rebuild
-            plan.close()
-            plan.release_pages()
+            # adopted plan: the per-shard buffers are stale (only this
+            # producer folds into it, so nothing is in flight) — rebuild
             plan = None
         if plan is None:
             from .shards import ShardPlan
@@ -1350,12 +1330,7 @@ class StreamingAggregator:
             # persists across drain windows as the authoritative
             # accumulator, so the per-drain reassemble+decompose round
             # trip is gone — the only gathers left are explicit acc reads
-            plan = ShardPlan(
-                agg,
-                shard_threads=self._shard_threads,
-                pool=self._pool,
-                tenant=self.tenant,
-            )
+            plan = ShardPlan(agg)
             agg.adopt_plan(plan)
             with self._lock:
                 self._plan = plan
@@ -1659,20 +1634,9 @@ class StreamingAggregator:
         with self._lock:
             plan = self._plan
         if job.kind == "wire":
-            piece = payload
-            if plan.native:
-                # materialize THIS shard's slice of the unpack output (the
-                # host kernel reads host memory); other shards keep folding
-                piece = np.asarray(piece)  # lint: sync-ok
-            plan.fold_shard(d, piece)
+            plan.fold_shard(d, payload)
             return
         packed = job.kind == "packed"
-        if plan.native:
-            if packed:
-                plan.fold_shard_packed(d, payload)
-            else:
-                plan.fold_shard(d, payload)
-            return
         import jax
 
         with plan._device_dispatch_lock:
@@ -1846,16 +1810,11 @@ class StreamingAggregator:
         the PR-7-hardened sequence a missed divergent copy would break)."""
         self._slot_acquire()
         try:
-            if plan.native:
-                full = np.asarray(stacked)  # lint: sync-ok
-                for d in range(plan.n_shards):
-                    plan.fold_shard_slice(d, full)
-            else:
-                by_start = {
-                    s.index[-1].start or 0: s.data for s in stacked.addressable_shards
-                }
-                for d, (lo, _hi) in enumerate(plan.slices):
-                    plan.fold_shard(d, by_start[lo])
+            by_start = {
+                s.index[-1].start or 0: s.data for s in stacked.addressable_shards
+            }
+            for d, (lo, _hi) in enumerate(plan.slices):
+                plan.fold_shard(d, by_start[lo])
         finally:
             self._slot_release()
         with self._lock:
@@ -1940,9 +1899,7 @@ class StreamingAggregator:
                 self._in_flight_models -= sum(t.k for t in pending)
         # reduce-scatter: the plan PERSISTS across drain windows — the
         # per-shard accumulators stay authoritative (agg.acc reads
-        # reassemble on demand; unmask subtracts per shard). The old
-        # reassemble-here / re-decompose-next-window round trip (two full
-        # accumulator copies per drain on native plans) is gone.
+        # reassemble on demand; unmask subtracts per shard)
         self._publish_overlap()
         return accepted
 

@@ -53,7 +53,6 @@ def build_staged_aggregator(shared) -> "StagedAggregator":
         dispatch_ahead=settings.aggregation.dispatch_ahead,
         staging_buffers=settings.aggregation.staging_buffers,
         shard_parallel=settings.aggregation.shard_parallel,
-        shard_threads=settings.aggregation.shard_threads,
         packed_staging=settings.aggregation.packed_staging,
         tenant=shared.tenant,
     )
@@ -67,12 +66,11 @@ class DeviceAggregation(Aggregation):
     Unmask phase has even subtracted the mask. This view keeps the
     accumulator where it is: ``unmask_array``/``unmask`` subtract the
     elected mask per-shard in place (``ShardedAggregator.unmask_limbs`` —
-    each mesh device subtracts its own model-axis slice; the host
-    ``mod_sub`` runs only when a native fold left the accumulator
-    host-resident), and only the *unmasked* result crosses to the host for
-    the fixed-point decode. Validation and the tiny unit channel need no
-    accumulator read at all; ``object`` stays available for
-    checkpoint/test paths that genuinely want the gathered aggregate.
+    each mesh device subtracts its own model-axis slice), and only the
+    *unmasked* result crosses to the host for the fixed-point decode.
+    Validation and the tiny unit channel need no accumulator read at all;
+    ``object`` stays available for checkpoint/test paths that genuinely
+    want the gathered aggregate.
     """
 
     def __init__(self, config: MaskConfigPair, object_size: int, device, unit_acc, stream=None):
@@ -197,12 +195,6 @@ class DeviceAggregation(Aggregation):
         values = limb_ops.limbs_to_ints(n_vect)
         return Model(decode_vect_exact(values, self._config.vect, self.nb_models, scalar_sum))
 
-    def release_pool(self) -> None:
-        """Round-end page release (the Unmask phase calls this AFTER the
-        unmasked model is decoded and persisted — see
-        ``StagedAggregator.release_pool``)."""
-        self._device.release_plan_pages()
-
 
 class _OpenBatch:
     """One fold batch still filling: a ring buffer of the streaming
@@ -239,7 +231,6 @@ class StagedAggregator:
         dispatch_ahead: int = 2,
         staging_buffers: int = 3,
         shard_parallel: bool = True,
-        shard_threads: int = 0,
         packed_staging: bool = True,
         tenant: str = "default",
     ):
@@ -275,7 +266,6 @@ class StagedAggregator:
                 dispatch_ahead=dispatch_ahead,
                 max_batch=self.batch_size,
                 shard_parallel=shard_parallel,
-                shard_threads=shard_threads,
                 packed=packed_staging,
                 tenant=tenant,
             )
@@ -649,17 +639,10 @@ class StagedAggregator:
         """
         self.drain()
         if self._device is not None:
-            planes = self._device.snapshot_shards()
-            if planes is not None:
-                return AggSnapshot(
-                    nb_models=self._device.nb_models,
-                    unit=np.array(self._unit_acc),
-                    planes=planes,
-                )
             return AggSnapshot(
                 nb_models=self._device.nb_models,
                 unit=np.array(self._unit_acc),
-                vect=self._device.snapshot(),
+                planes=self._device.snapshot_shards(),
             )
         return AggSnapshot(
             nb_models=self._host.nb_models,
@@ -714,15 +697,6 @@ class StagedAggregator:
         )
         agg.nb_models = self._device.nb_models
         return agg
-
-    def release_pool(self) -> None:
-        """Round-end page release (the Unmask tail, docs/DESIGN.md §19):
-        the shard plan's leased accumulator pages go back to the shared
-        pool once the unmasked model is decoded — nothing reads the
-        accumulator past this point, so the pool may re-lease the pages to
-        another tenant immediately."""
-        if self._device is not None:
-            self._device.release_plan_pages()
 
     def finalize_inplace(self, defer_drain: bool = False) -> Aggregation:
         """The Unmask handoff WITHOUT gathering the accumulator.

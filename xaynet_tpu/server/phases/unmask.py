@@ -77,16 +77,9 @@ class Unmask(PhaseState):
         # republish idempotently (ModelStorage contract), never corrupt
         maybe_kill("unmask:publish")
         await self._publish_proof()
-        # round-end page release (docs/DESIGN.md §19): the accumulator's
-        # pool pages go back the moment the unmasked model is decoded and
-        # persisted — this is the clean half of the leases == releases
-        # round invariant (Idle's reclaim is the crash-path backstop)
-        release = getattr(self.model_agg, "release_pool", None)
-        if release is not None:
-            release()
         if self.shared.settings.resilience.checkpoint_enabled:
-            # retire the round journal: the model is published and the
-            # pool pages are back — nothing left for a resume to redo
+            # retire the round journal: the model is published — nothing
+            # left for a resume to redo
             # (Idle's delete is the backstop for disabled-journal configs)
             await self.shared.store.coordinator.delete_round_checkpoint()
 

@@ -193,10 +193,9 @@ class LoggingSettings:
 class AggregationSettings:
     device: bool = False  # fold updates on the TPU mesh instead of host numpy
     batch_size: int = 64  # staged updates per device fold
-    # fold kernel when device=True: auto (calibrate on the first flush —
-    # XLA vs Pallas on accelerators, XLA vs the native host u64 fold on
-    # CPU), xla, pallas, pallas-interpret (CI oracle path), or native-u64
-    # (host C++ single-pass fold; falls back to xla when unavailable)
+    # fold kernel when device=True: auto (XLA on the CPU backend; on an
+    # accelerator the first flush races XLA against Pallas), xla, pallas,
+    # or pallas-interpret (CI oracle path)
     kernel: str = "auto"
     # streaming pipeline (device=True): how many submitted fold batches may
     # be in flight behind the fold worker before flush() backpressures
@@ -211,10 +210,6 @@ class AggregationSettings:
     # false forces the legacy single FIFO fold worker (the mesh-sharded
     # single-program fold); single-device meshes ignore the flag
     shard_parallel: bool = True
-    # per-shard native fold thread budget (native-u64 kernel only): 0
-    # splits the process-wide budget (XAYNET_NATIVE_THREADS / 2x cores)
-    # across the shards; > 0 pins threads per shard
-    shard_threads: int = 0
     # packed byte-planar staging (docs/DESIGN.md §17): planar update
     # batches stage as ceil(log2(order)/8)-byte planes instead of full
     # uint32 limb planes — bpn/(4L) of the ring memory and host->device
@@ -744,8 +739,6 @@ class Settings:
             )
         if self.aggregation.wire_ingest and not self.aggregation.device:
             raise SettingsError("aggregation.wire_ingest requires aggregation.device = true")
-        if self.aggregation.shard_threads < 0:
-            raise SettingsError("aggregation.shard_threads must be >= 0 (0 = auto split)")
         if self.metrics.trace not in ("", "on", "failure", "off"):
             raise SettingsError(
                 "metrics.trace must be on | failure | off (or omitted to "
@@ -903,9 +896,6 @@ class Settings:
                 wire_ingest=bool(agg_raw.get("wire_ingest", base.aggregation.wire_ingest)),
                 shard_parallel=bool(
                     agg_raw.get("shard_parallel", base.aggregation.shard_parallel)
-                ),
-                shard_threads=int(
-                    agg_raw.get("shard_threads", base.aggregation.shard_threads)
                 ),
                 packed_staging=bool(
                     agg_raw.get("packed_staging", base.aggregation.packed_staging)

@@ -4,7 +4,7 @@ One structured JSON object per completed round, appended to a JSONL file:
 round id, per-phase durations, message accepted/rejected/discarded counts
 per phase, unique-mask total, aggregation kernel stats (calls, device-synced
 seconds, elements, derived elements/sec) and any phase events. Consumers
-(``bench.py``, dashboards) read one artifact instead of scraping coordinator
+(dashboards) read one artifact instead of scraping coordinator
 logs.
 
 Fed by ``telemetry.bridge.BridgedMetrics``: a report window opens when Idle

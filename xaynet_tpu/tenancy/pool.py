@@ -24,14 +24,14 @@ Two arenas, one accounting discipline:
   ephemeral by design and literal page views cannot survive a fold. What
   multi-tenant admission needs from HBM is the *budget*: the ledger
   tracks pages leased per tenant against the configured capacity and
-  fails fast when a new tenant's plan would not fit.
+  fails fast when a lease would not fit. Nothing charges it yet: shard
+  plans lease no pages (docs/DESIGN.md §19).
 
 Accounting invariant (checked at round boundaries and by the
 ``tenant-scope`` analysis pass's sanctioned-site whitelist): **leases ==
-releases at round end** — every page run leased for a round's shard plan
-and staging rings is released when the round's accumulator dies. The
-clean path releases explicitly (`StagedAggregator.release_pool`, ring
-close); `reclaim()` is the crash-path backstop the next round's Idle
+releases at round end** — every page run leased for a round's staging
+rings is released when the round's pipeline closes. The clean path
+releases explicitly (ring close); `reclaim()` is the crash-path backstop the next round's Idle
 phase runs, counting every straggler it had to force-release.
 """
 

@@ -2,10 +2,9 @@
 compiling cost this process.
 
 One policy for every entry point that compiles device code (the
-coordinator runner, ``bench.py``, ``tools/bench_round.py``,
-``tools/trace_overhead.py``): the cache directory is placed from OUTSIDE.
-If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
-module leaves the directory alone; otherwise the cache goes to
+coordinator runner, ``tools/trace_overhead.py``): the cache directory is
+placed from OUTSIDE. If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+reads it and this module leaves the directory alone; otherwise the cache goes to
 ``<checkout>/.jax_cache`` (git-ignored) — a fixed path, so every process
 started from a checkout finds what an earlier one built. The fold kernels
 compile in seconds to tens of seconds and the in-graph ChaCha derive in

@@ -3,19 +3,9 @@
 Single source of truth for the aggregation fold kernel names, shared by
 ``parallel.aggregator`` (which executes them) and ``server.settings`` (which
 validates configs without importing jax).
-
-``native-u64`` is the host C++ single-pass fold (``utils.native`` /
-``native/xaynet_native.cpp``): threaded over the element axis, it beats the
-XLA CPU fold ~2.5x at the 25M-param bench shape, so ``auto`` races it
-against XLA on CPU backends (<= 2-limb orders). Multi-device meshes are
-served too: each device's contiguous plane slice folds through the strided
-kernel entry under a per-shard thread budget — sequentially via one
-concurrent slice call per shard, and in the streaming pipeline via one
-fold worker per shard (``parallel.shards``). It degrades to ``xla``
-cleanly when the shared library won't build.
 """
 
-FOLD_KERNELS = ("auto", "xla", "pallas", "pallas-interpret", "native-u64")
+FOLD_KERNELS = ("auto", "xla", "pallas", "pallas-interpret")
 
 # Sum2 mask derive+sum kernels (``ops.masking_jax.sum_masks``):
 #

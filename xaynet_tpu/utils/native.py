@@ -18,7 +18,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 9
+_ABI_VERSION = 10
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -95,7 +95,7 @@ def load() -> Optional[ctypes.CDLL]:
         lib.xn_sample_fold_u64.restype = ctypes.c_uint64
         lib.xn_mod_add.argtypes = [u32p, u32p, u32p, ctypes.c_uint64, ctypes.c_uint32, u32p]
         lib.xn_mod_add.restype = None
-        lib.xn_fold_planar_u64.argtypes = [
+        lib.xn_fold_wire_u64.argtypes = [
             u32p,
             u32p,
             u32p,
@@ -104,43 +104,7 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint64,
             u32p,
         ]
-        lib.xn_fold_planar_u64.restype = None
-        lib.xn_fold_wire_u64.argtypes = list(lib.xn_fold_planar_u64.argtypes)
         lib.xn_fold_wire_u64.restype = None
-        # strided slice fold: pointers pre-offset to the slice start, plane
-        # and batch strides in ELEMENTS, explicit per-call thread budget
-        lib.xn_fold_planar_u64_strided.argtypes = [
-            u32p,
-            u32p,
-            u32p,
-            ctypes.c_uint64,  # width
-            ctypes.c_uint64,  # acc/out plane stride
-            ctypes.c_uint64,  # stack row (limb-plane) stride
-            ctypes.c_uint64,  # stack batch (update) stride
-            ctypes.c_uint32,  # n_limbs
-            ctypes.c_uint64,  # k
-            u32p,
-            ctypes.c_uint32,  # n_threads (0 = process default)
-        ]
-        lib.xn_fold_planar_u64_strided.restype = None
-        # packed byte-planar fold (ABI 8): the staged batch arrives as
-        # uint8[K, bpn, n] byte planes (ops/limbs.py pack_planar) and folds
-        # into the planar u32 accumulator without ever unpacking
-        lib.xn_fold_packed_u64_strided.argtypes = [
-            u32p,
-            u8p,
-            u32p,
-            ctypes.c_uint64,  # width
-            ctypes.c_uint64,  # acc/out plane stride (elements)
-            ctypes.c_uint64,  # packed byte-plane stride (bytes)
-            ctypes.c_uint64,  # packed batch (update) stride (bytes)
-            ctypes.c_uint32,  # n_limbs
-            ctypes.c_uint32,  # bpn
-            ctypes.c_uint64,  # k
-            u32p,
-            ctypes.c_uint32,  # n_threads (0 = process default)
-        ]
-        lib.xn_fold_packed_u64_strided.restype = None
         lib.xn_pack_wire_planes.argtypes = [
             u32p,
             ctypes.c_uint64,  # n elements
@@ -161,8 +125,6 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint32,  # n_threads
         ]
         lib.xn_pack_planar_planes.restype = None
-        lib.xn_fold_threads.argtypes = []
-        lib.xn_fold_threads.restype = ctypes.c_uint32
         lib.xn_mod_sub.argtypes = [u32p, u32p, u32p, ctypes.c_uint64, ctypes.c_uint32, u32p]
         lib.xn_mod_sub.restype = None
         lib.xn_decode_f64.argtypes = [
@@ -269,7 +231,7 @@ def np_u8p_at(arr, byte_offset: int):
 
 def np_u32p_at(arr, element_offset: int):
     """Pointer to ``arr``'s buffer offset by ``element_offset`` uint32
-    elements — how the strided slice kernels address one shard's column
+    elements — how the plane-pack kernels address one shard's column
     slice of a larger C-contiguous array without materializing a copy."""
     return ctypes.cast(
         ctypes.c_void_p(arr.ctypes.data + 4 * element_offset),
