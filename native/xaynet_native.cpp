@@ -13,11 +13,13 @@
 //
 // Built as a plain shared library; loaded via ctypes (no pybind11).
 
+#include <atomic>
 #include <cerrno>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
 #include <ctime>
+#include <memory>
 #include <thread>
 #include <vector>
 
@@ -81,14 +83,16 @@ inline __m256i rotl8v(__m256i x, int n) {
   return _mm256_or_si256(_mm256_slli_epi32(x, n), _mm256_srli_epi32(x, 32 - n));
 }
 
-#define XN_QUARTER8(a, b, c, d)            \
-  a = _mm256_add_epi32(a, b);              \
-  d = rotl8v(_mm256_xor_si256(d, a), 16);  \
-  c = _mm256_add_epi32(c, d);              \
-  b = rotl8v(_mm256_xor_si256(b, c), 12);  \
-  a = _mm256_add_epi32(a, b);              \
-  d = rotl8v(_mm256_xor_si256(d, a), 8);   \
-  c = _mm256_add_epi32(c, d);              \
+// rotations by 16 and 8 bits move whole bytes: one shuffle instead of two
+// shifts and an or
+#define XN_QUARTER8(a, b, c, d)                             \
+  a = _mm256_add_epi32(a, b);                               \
+  d = _mm256_shuffle_epi8(_mm256_xor_si256(d, a), rot16);   \
+  c = _mm256_add_epi32(c, d);                               \
+  b = rotl8v(_mm256_xor_si256(b, c), 12);                   \
+  a = _mm256_add_epi32(a, b);                               \
+  d = _mm256_shuffle_epi8(_mm256_xor_si256(d, a), rot8);    \
+  c = _mm256_add_epi32(c, d);                               \
   b = rotl8v(_mm256_xor_si256(b, c), 7)
 
 // Eight consecutive ChaCha20 blocks in parallel (one block per SIMD lane).
@@ -108,6 +112,10 @@ void chacha20_blocks8(const uint32_t key[8], uint64_t counter0, uint8_t out[512]
   s[14] = _mm256_setzero_si256();
   s[15] = _mm256_setzero_si256();
 
+  const __m256i rot16 = _mm256_set_epi8(13, 12, 15, 14, 9, 8, 11, 10, 5, 4, 7, 6, 1, 0, 3, 2,
+                                        13, 12, 15, 14, 9, 8, 11, 10, 5, 4, 7, 6, 1, 0, 3, 2);
+  const __m256i rot8 = _mm256_set_epi8(14, 13, 12, 15, 10, 9, 8, 11, 6, 5, 4, 7, 2, 1, 0, 3,
+                                       14, 13, 12, 15, 10, 9, 8, 11, 6, 5, 4, 7, 2, 1, 0, 3);
   __m256i w0 = s[0], w1 = s[1], w2 = s[2], w3 = s[3], w4 = s[4], w5 = s[5],
           w6 = s[6], w7 = s[7], w8 = s[8], w9 = s[9], w10 = s[10], w11 = s[11],
           w12 = s[12], w13 = s[13], w14 = s[14], w15 = s[15];
@@ -320,73 +328,6 @@ XN_EXPORT uint64_t xn_sample_uniform(const uint8_t key_bytes[32], uint64_t byte_
   return offset;
 }
 
-// Fused sample+fold (the host twin of the Pallas mask kernel): draw `count`
-// uniform values below `order` from the keystream exactly like
-// xn_sample_uniform (same attempts, same acceptance, same end cursor) and
-// ADD each accepted value into the u64 accumulator `acc[count]` instead of
-// materializing the mask. Orders must fit 8 little-endian bytes; the CALLER
-// owns the lazy-reduction headroom (sum of all folded values per slot must
-// stay below 2^64 — reduce `acc` mod order between waves). Returns the end
-// byte cursor, or 0 when the order is out of range for this entry.
-XN_EXPORT uint64_t xn_sample_fold_u64(const uint8_t key_bytes[32], uint64_t byte_offset,
-                                      uint64_t count, const uint8_t* order_le,
-                                      uint32_t order_nbytes, uint64_t* acc) {
-  if (order_nbytes == 0 || order_nbytes > 8) return 0;
-  uint32_t key[8];
-  std::memcpy(key, key_bytes, 32);
-  uint64_t order64 = 0;
-  for (int i = (int)order_nbytes - 1; i >= 0; i--)
-    order64 = (order64 << 8) | order_le[i];
-  const uint64_t vmask =
-      order_nbytes == 8 ? ~0ull : ((1ull << (8 * order_nbytes)) - 1);
-
-  constexpr uint64_t CHUNK_BLOCKS = 1024;
-  std::vector<uint8_t> buf(CHUNK_BLOCKS * 64 + 512);
-  uint64_t avail = 0;
-  uint64_t next_block = byte_offset / 64;
-  uint64_t intra = byte_offset % 64;
-  if (intra) {
-    uint8_t first[64];
-    chacha20_block(key, next_block, first);
-    next_block++;
-    avail = 64 - intra;
-    std::memcpy(buf.data(), first + intra, avail);
-  }
-
-  uint64_t offset = byte_offset;
-  uint64_t pos = 0;
-  uint64_t got = 0;
-  while (got < count) {
-    if (avail - pos < order_nbytes + 8) {
-      uint64_t tail = avail - pos;
-      std::memmove(buf.data(), buf.data() + pos, tail);
-      chacha20_fill(key, next_block, CHUNK_BLOCKS, buf.data() + tail);
-      next_block += CHUNK_BLOCKS;
-      avail = tail + CHUNK_BLOCKS * 64;
-      pos = 0;
-    }
-    const uint64_t n_here = (avail - pos - 8) / order_nbytes;
-    const uint8_t* p = buf.data() + pos;
-    uint64_t consumed = 0;
-    for (uint64_t i = 0; i < n_here; i++) {
-      uint64_t v;
-      std::memcpy(&v, p + i * order_nbytes, 8);
-      v &= vmask;
-      consumed += order_nbytes;
-      if (v < order64) {
-        acc[got] += v;  // lazy: caller reduces mod order between waves
-        got++;
-        if (got == count) break;
-      }
-    }
-    pos += consumed;
-    offset += consumed;
-  }
-  return offset;
-}
-
-
-
 // (a + b) mod order, elementwise over `n` values of `n_limbs` uint32 limbs
 // (little-endian limb order, wire layout [n, L]); a, b < order.
 // `order_limbs` may be all zero when order == 2^(32*L) (natural wraparound).
@@ -541,6 +482,352 @@ void fold_wire_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* o
 }
 
 }  // namespace
+
+// ---------------------------------------------------------------------------
+// Streaming derive-and-sum (ABI 11): the sum participant's masks, derived and
+// summed in one pass, with no mask ever in memory (docs/DESIGN.md section 15).
+//
+// Every attempt of the rejection sampler consumes exactly `bpn` bytes,
+// accepted or not, and ChaCha20 is addressable by block. So candidate j of a
+// seed sits at keystream byte `byte_offset + j * bpn`, known in advance; only
+// the OUTPUT index of an accepted candidate (how many were accepted before it)
+// depends on the stream. A seed's candidates are therefore cut into segments
+// of `seg_cand` candidates. Any thread samples any segment into a small
+// compacted buffer, then commits it in segment order: reads the seed's running
+// count `pos`, publishes `pos + cnt` for the next segment, and only then adds
+// its values into `acc[pos : pos + cnt]`. The ranges of one seed are disjoint,
+// so the threads of a group share one accumulator with no lock, and the
+// serial part of a segment is two loads and two stores. The walk of a seed
+// stops at the segment in which the n-th acceptance falls; the end cursor is
+// the byte after that attempt, as in xn_sample_uniform.
+//
+// Threads are split into `n_groups` groups, each with an accumulator of its
+// own (group 0 uses the caller's) and every n_groups-th seed: one group when
+// an accumulator is large (all threads share each seed's segments), one
+// thread a group when seeds are many and small (seed-grained work, nothing
+// waits). The caller picks the split from n, k, the draw width and the
+// acceptance rate; the result does not depend on it.
+//
+// Accumulation is lazy in a word of `acc_stride` bytes (8, 12 or 16) that k
+// sums cannot overflow ((k + 1) * order < 2^(8 * acc_stride), the caller's
+// choice), reduced once at the end; with `eager` set (a 16-byte order too
+// close to 2^128 for any headroom) every add is a modular add instead.
+
+namespace {
+
+using u128 = unsigned __int128;
+
+inline void spin_wait(unsigned& spins) {
+  if (++spins < 64) {
+#if defined(__x86_64__) || defined(__i386__)
+    __builtin_ia32_pause();
+#endif
+  } else {
+    std::this_thread::yield();
+  }
+}
+
+struct alignas(64) DeriveSeed {
+  std::atomic<uint64_t> next{0};   // next segment to sample (a ticket)
+  std::atomic<uint64_t> turn{0};   // the segment whose commit is due
+  std::atomic<uint64_t> added{0};  // committed segments whose adds are complete
+  std::atomic<bool> done{false};   // the segment holding the n-th accept has committed
+  uint64_t pos = 0;                // accepted so far; owned by the committer in turn
+  uint64_t total = 0;              // committed segments; valid once `done`
+};
+
+// Compact the accepted candidates of `c` attempts at `p` into `vals`.
+// Orders of up to 8 bytes: one masked 8-byte load an attempt, and a store
+// that always happens with a count that moves only on accept, so a 28%
+// acceptance rate costs no branch mispredictions.
+inline uint64_t sample_segment(const uint8_t* p, uint64_t c, uint32_t bpn, u128 order,
+                               uint64_t* vals) {
+  const uint64_t order64 = (uint64_t)order;
+  const uint64_t vmask = bpn == 8 ? ~0ull : ((1ull << (8 * bpn)) - 1);
+  uint64_t cnt = 0;
+  for (uint64_t i = 0; i < c; i++) {
+    uint64_t v;
+    std::memcpy(&v, p + i * bpn, 8);
+    v &= vmask;
+    vals[cnt] = v;
+    cnt += v < order64;
+  }
+  return cnt;
+}
+
+// Orders of 9 to 16 bytes: the top eight bytes decide nearly every attempt
+// (a 75-bit order in 10 bytes rejects 98% of them there), the whole value is
+// loaded only for a candidate that survives them.
+inline uint64_t sample_segment(const uint8_t* p, uint64_t c, uint32_t bpn, u128 order,
+                               u128* vals) {
+  const uint32_t top_at = bpn - 8;
+  const uint64_t order_top = (uint64_t)(order >> (8 * top_at));
+  uint64_t cnt = 0;
+  for (uint64_t i = 0; i < c; i++) {
+    const uint8_t* q = p + i * bpn;
+    uint64_t top;
+    std::memcpy(&top, q + top_at, 8);
+    if (top > order_top) continue;
+    const u128 v = load_le16(q, bpn);
+    if (v < order) vals[cnt++] = v;
+  }
+  return cnt;
+}
+
+// A segment is sampled in chunks of this much keystream, so that the bytes
+// are consumed from the cache they were generated into whatever the
+// segment's length.
+constexpr uint64_t DS_CHUNK_BYTES = 64 * 1024;
+
+// Sample the `c` candidates that start at keystream byte `b0` of `key` into
+// `vals` (room for `c`); returns how many were accepted. `ks` holds a chunk:
+// whole blocks around its bytes and 16 bytes of slack for the last wide load.
+template <typename V>
+inline uint64_t sample_range(const uint32_t key[8], uint64_t b0, uint64_t c, uint32_t bpn,
+                             u128 order, uint8_t* ks, V* vals) {
+  const uint64_t step = DS_CHUNK_BYTES / bpn;
+  uint64_t cnt = 0;
+  for (uint64_t c0 = 0; c0 < c; c0 += step) {
+    const uint64_t here = c - c0 < step ? c - c0 : step;
+    const uint64_t b = b0 + c0 * bpn;
+    chacha20_fill(key, b / 64, (b % 64 + here * bpn + 63) / 64, ks);
+    cnt += sample_segment(ks + b % 64, here, bpn, order, vals + cnt);
+  }
+  return cnt;
+}
+
+// Index of the `nth` (1-based) accepted candidate of those from byte `b0`.
+inline uint64_t nth_accept(const uint32_t key[8], uint64_t b0, uint32_t bpn, u128 order,
+                           uint64_t nth, uint8_t* ks) {
+  const uint64_t step = DS_CHUNK_BYTES / bpn;
+  for (uint64_t c0 = 0;; c0 += step) {
+    const uint64_t b = b0 + c0 * bpn;
+    chacha20_fill(key, b / 64, (b % 64 + step * bpn + 63) / 64, ks);
+    const uint8_t* p = ks + b % 64;
+    for (uint64_t i = 0; i < step; i++) {
+      if (load_le16(p + i * bpn, bpn) < order && --nth == 0) return c0 + i;
+    }
+  }
+}
+
+template <int S>
+inline u128 acc_load(const uint8_t* a) {
+  if (S == 8) {
+    uint64_t v;
+    std::memcpy(&v, a, 8);
+    return v;
+  }
+  uint64_t lo;
+  std::memcpy(&lo, a, 8);
+  if (S == 12) {
+    uint32_t hi;
+    std::memcpy(&hi, a + 8, 4);
+    return ((u128)hi << 64) | lo;
+  }
+  uint64_t hi;
+  std::memcpy(&hi, a + 8, 8);
+  return ((u128)hi << 64) | lo;
+}
+
+template <int S>
+inline void acc_store(uint8_t* a, u128 v) {
+  const uint64_t lo = (uint64_t)v;
+  std::memcpy(a, &lo, 8);
+  if (S == 12) {
+    const uint32_t hi = (uint32_t)(v >> 64);
+    std::memcpy(a + 8, &hi, 4);
+  } else if (S == 16) {
+    const uint64_t hi = (uint64_t)(v >> 64);
+    std::memcpy(a + 8, &hi, 8);
+  }
+}
+
+// (a + b) mod order for a, b < order <= 2^128 - 1: the true sum is under
+// 2^129, so a lost carry or a sum at or over the order both mean one
+// subtraction, and u128 wraparound makes it exact.
+inline u128 mod_add128(u128 a, u128 b, u128 order) {
+  const u128 s = a + b;
+  return (s < a || s >= order) ? s - order : s;
+}
+
+template <typename V, int S>
+inline void acc_add(uint8_t* acc, uint64_t pos, const V* vals, uint64_t cnt, bool eager,
+                    u128 order) {
+  uint8_t* a = acc + pos * S;
+  if (eager) {
+    for (uint64_t i = 0; i < cnt; i++)
+      acc_store<S>(a + i * S, mod_add128(acc_load<S>(a + i * S), vals[i], order));
+  } else {
+    for (uint64_t i = 0; i < cnt; i++)
+      acc_store<S>(a + i * S, acc_load<S>(a + i * S) + vals[i]);
+  }
+}
+
+inline double to_double(u128 v) {
+  return (double)(uint64_t)(v >> 64) * 18446744073709551616.0 + (double)(uint64_t)v;
+}
+
+// sum mod order for sum < 2^40 * order: the quotient is small, so one
+// double multiply lands within one of it and the loops below run at most
+// once each.
+inline u128 reduce128(u128 sum, u128 order, double inv_order) {
+  u128 below = (u128)(uint64_t)(to_double(sum) * inv_order) * order;
+  while (below > sum) below -= order;
+  u128 r = sum - below;
+  while (r >= order) r -= order;
+  return r;
+}
+
+struct DeriveSumArgs {
+  const uint8_t* seeds;
+  const uint64_t* offsets;
+  uint64_t k, n;
+  u128 order;
+  uint32_t bpn;
+  uint8_t* acc;
+  bool eager;
+  uint32_t n_limbs;
+  uint32_t* out;
+  uint64_t* ends;
+  uint32_t n_threads, n_groups;
+  uint64_t seg_cand;
+};
+
+template <typename V, int S>
+int derive_sum_run(const DeriveSumArgs& a) {
+  const uint32_t nt = a.n_threads;
+  const uint32_t ng = a.n_groups;
+  const uint64_t seg_bytes = a.seg_cand * a.bpn;
+
+  // group 0 sums into the caller's accumulator, the others into their own
+  std::vector<std::unique_ptr<uint8_t, decltype(&std::free)>> extra;
+  std::vector<uint8_t*> accs(ng, a.acc);
+  for (uint32_t g = 1; g < ng; g++) {
+    extra.emplace_back((uint8_t*)std::calloc(a.n, S), &std::free);
+    if (!extra.back()) return 2;
+    accs[g] = extra.back().get();
+  }
+  std::unique_ptr<DeriveSeed[]> seeds(new DeriveSeed[a.k]);
+
+  auto worker = [&](uint32_t t) {
+    const uint32_t g = t % ng;
+    uint8_t* acc = accs[g];
+    std::vector<uint8_t> ks(DS_CHUNK_BYTES + 64 + 64 + 16);
+    std::unique_ptr<V[]> vals(new V[a.seg_cand]);  // touched as far as it fills
+    for (uint64_t s = g; s < a.k; s += ng) {
+      DeriveSeed& st = seeds[s];
+      DeriveSeed* prev = s >= ng ? &seeds[s - ng] : nullptr;
+      uint32_t key[8];
+      std::memcpy(key, a.seeds + 32 * s, 32);
+      while (!st.done.load(std::memory_order_acquire)) {
+        const uint64_t j = st.next.fetch_add(1, std::memory_order_relaxed);
+        const uint64_t b0 = a.offsets[s] + j * seg_bytes;
+        const uint64_t cnt =
+            sample_range(key, b0, a.seg_cand, a.bpn, a.order, ks.data(), vals.get());
+
+        // the last segment's commit sets `done` and leaves `turn` where it is
+        unsigned spins = 0;
+        while (st.turn.load(std::memory_order_acquire) != j &&
+               !st.done.load(std::memory_order_acquire))
+          spin_wait(spins);
+        if (st.turn.load(std::memory_order_acquire) != j) break;  // a ticket past it
+        const uint64_t pos = st.pos;
+        uint64_t take = cnt;
+        if (pos + cnt >= a.n) {
+          take = a.n - pos;
+          a.ends[s] =
+              b0 + (nth_accept(key, b0, a.bpn, a.order, take, ks.data()) + 1) * a.bpn;
+          st.total = j + 1;
+          st.done.store(true, std::memory_order_release);
+        } else {
+          st.pos = pos + cnt;
+          st.turn.store(j + 1, std::memory_order_release);
+        }
+        // this group's previous seed may still be adding into the same slots
+        if (prev != nullptr) {
+          spins = 0;
+          while (prev->added.load(std::memory_order_acquire) != prev->total) spin_wait(spins);
+        }
+        acc_add<V, S>(acc, pos, vals.get(), take, a.eager, a.order);
+        st.added.fetch_add(1, std::memory_order_release);
+      }
+    }
+  };
+
+  if (nt <= 1) {
+    worker(0);
+  } else {
+    std::vector<std::thread> threads;
+    std::vector<uint32_t> unspawned;
+    threads.reserve(nt - 1);
+    for (uint32_t t = 1; t < nt; t++) {
+      try {
+        threads.emplace_back(worker, t);
+      } catch (...) {
+        unspawned.push_back(t);  // a worker is complete alone: run it here
+      }
+    }
+    worker(0);
+    for (uint32_t t : unspawned) worker(t);
+    for (auto& th : threads) th.join();
+  }
+
+  // merge the groups, reduce, write uint32[n, L]; `out` may be `acc` itself
+  // when an accumulator word is an element's limbs (S == 4 * n_limbs)
+  const double inv_order = 1.0 / to_double(a.order);
+  const uint32_t L = a.n_limbs;
+  run_sliced(
+      a.n, 4096,
+      [&](uint64_t s0, uint64_t s1) {
+        for (uint64_t i = s0; i < s1; i++) {
+          u128 v = acc_load<S>(a.acc + i * S);
+          for (uint32_t g = 1; g < ng; g++) {
+            const u128 w = acc_load<S>(accs[g] + i * S);
+            v = a.eager ? mod_add128(v, w, a.order) : v + w;
+          }
+          if (!a.eager) v = reduce128(v, a.order, inv_order);
+          uint32_t* o = a.out + i * L;
+          for (uint32_t l = 0; l < L; l++) o[l] = (uint32_t)(v >> (32 * l));
+        }
+      },
+      nt);
+  return 0;
+}
+
+}  // namespace
+
+// Derive the masks of `k` seeds (32 bytes each at `seeds`; the vector draws
+// of seed s start at keystream byte `byte_offsets[s]`, after its unit draw)
+// and write their elementwise sum mod `order` to `out` as uint32[n, n_limbs].
+// `acc` is `n * acc_stride` zeroed bytes (it may be `out` when acc_stride ==
+// 4 * n_limbs); `end_offsets[s]` receives seed s's end cursor. Returns 0, or
+// 1 for arguments outside this entry (order over 16 bytes, a stride that
+// cannot hold it), or 2 when a group's accumulator could not be allocated.
+XN_EXPORT int xn_derive_sum(const uint8_t* seeds, const uint64_t* byte_offsets, uint64_t k,
+                            uint64_t n, const uint8_t* order_le, uint32_t order_nbytes,
+                            uint32_t acc_stride, uint8_t* acc, uint32_t eager,
+                            uint32_t n_limbs, uint32_t* out, uint64_t* end_offsets,
+                            uint32_t n_threads, uint32_t n_groups, uint64_t seg_cand) {
+  if (order_nbytes == 0 || order_nbytes > 16 || n_limbs == 0 || n_limbs > 4 || k == 0 ||
+      n == 0 || seg_cand == 0)
+    return 1;
+  u128 order = 0;
+  for (int i = (int)order_nbytes - 1; i >= 0; i--) order = (order << 8) | order_le[i];
+  if (order == 0 || (eager && acc_stride != 16)) return 1;
+  uint32_t nt = n_threads < 1 ? 1 : (n_threads > 64 ? 64 : n_threads);
+  uint32_t ng = n_groups < 1 ? 1 : n_groups;
+  if (ng > nt) ng = nt;
+  if (ng > k) ng = (uint32_t)k;
+  const DeriveSumArgs a{seeds,   byte_offsets, k,   n,           order, order_nbytes, acc,
+                        eager != 0, n_limbs,   out, end_offsets, nt,    ng,           seg_cand};
+  const bool narrow = order_nbytes <= 8;
+  if (acc_stride == 8) return narrow ? derive_sum_run<uint64_t, 8>(a) : 1;
+  if (acc_stride == 12)
+    return narrow ? derive_sum_run<uint64_t, 12>(a) : derive_sum_run<u128, 12>(a);
+  if (acc_stride == 16)
+    return narrow ? derive_sum_run<uint64_t, 16>(a) : derive_sum_run<u128, 16>(a);
+  return 1;
+}
 
 // Pack wire-layout uint32 elements into byte-planar planes (ABI 8; the
 // staging-ring pack of ops/limbs.py). `wire` points at n elements of
@@ -839,7 +1126,7 @@ XN_EXPORT uint64_t xn_count_ge(const uint32_t* limbs, uint64_t count, uint32_t n
   return bad;
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 10; }
+XN_EXPORT uint32_t xn_abi_version(void) { return 11; }
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST

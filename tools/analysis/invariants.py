@@ -83,10 +83,6 @@ NB_MODELS_SITES: dict[tuple[str, str], str] = {
         "host handoff copies the device count verbatim",
     ("xaynet_tpu/server/aggregation.py", "DeviceAggregation.__init__"):
         "in-place unmask view copies the device count verbatim",
-    # participant-side local mask aggregation (SDK): not the coordinator
-    # invariant, but the same field name on the shared Aggregation type
-    ("xaynet_tpu/sdk/state_machine.py", "StateMachine._aggregate_masks"):
-        "participant-local sum-mask reconstruction bookkeeping",
 }
 
 WATERMARK_SITES: dict[tuple[str, str], str] = {

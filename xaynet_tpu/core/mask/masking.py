@@ -56,6 +56,17 @@ class UnmaskingError(ValueError):
         self.kind = kind
 
 
+def check_nb_models(config: MaskConfigPair, count: int) -> None:
+    """Raise what ``Aggregation.validate_aggregation`` raises while ``count``
+    valid objects of ``config`` are aggregated one by one: nothing up to the
+    smaller ``max_nb_models``; past it the object at that index finds the
+    vector's count full first (``TooManyModels``) unless only the unit's
+    is (``TooManyScalars``)."""
+    cap_vect, cap_unit = config.vect.max_nb_models, config.unit.max_nb_models
+    if count > min(cap_vect, cap_unit):
+        raise AggregationError("TooManyModels" if cap_vect <= cap_unit else "TooManyScalars")
+
+
 def _order_limbs(config: MaskConfig) -> np.ndarray:
     return limb_ops.order_limbs_for(config.order)
 

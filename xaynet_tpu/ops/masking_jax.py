@@ -36,6 +36,7 @@ import numpy as np
 
 from ..core.crypto.prng import StreamSampler
 from ..core.mask.config import MaskConfigPair
+from ..core.mask.derive_sum import derive_and_sum
 from ..core.mask.encode import clamp_scalar, encode_unit, encode_vect_limbs
 from ..telemetry import profiling, report as round_report
 from ..telemetry import tracing as trace
@@ -267,29 +268,12 @@ def _acc_unit(unit_acc, group_unit: np.ndarray, ol_u: np.ndarray) -> np.ndarray:
     return host_limbs.mod_add(unit_acc[None, :], group_unit[None, :], ol_u)[0]
 
 
-def _host_sampler_threads(n_items: int, default_cap: int) -> int:
-    """Thread budget for the host sampler routes. An explicit
-    ``XAYNET_NATIVE_THREADS`` pin wins OUTRIGHT (bounded only by the item
-    count): the code silently second-guessing it would relabel the
-    operator's experiment — and the operator who pins it owns any memory
-    trade. The default is the core count capped
-    at ``default_cap`` (the fused route passes a small cap because each
-    thread holds an ``8 * length``-byte u64 partial accumulator, ~200 MB
-    at 25M params)."""
-    env = os.environ.get("XAYNET_NATIVE_THREADS", "")
-    if env:
-        try:
-            return max(1, min(int(env), n_items))
-        except ValueError:
-            logger.warning("ignoring non-integer XAYNET_NATIVE_THREADS=%r", env)
-    return max(1, min(os.cpu_count() or 1, n_items, default_cap))
-
-
 def _mask_route(used: str, seeds, length, config, seed_batch, mesh):
     if used == "host-chunked":
         return _sum_masks(seeds, length, config, seed_batch)
     if used == "host-threaded":
-        return _sum_masks_host_threaded(seeds, length, config, seed_batch)
+        # the one host derive-and-sum, the SDK's CPU sum participant's too
+        return derive_and_sum(seeds, length, config)
     if used in ("fused-pallas", "fused-pallas-interpret"):
         return _sum_masks_fused(
             seeds, length, config, seed_batch, interpret=used == "fused-pallas-interpret"
@@ -400,6 +384,9 @@ def sum_masks(
     - ``fused-pallas[-interpret]`` — the Pallas keystream→reject→fold
       kernel: masks never materialize in HBM
       (``fold_pallas.mask_fold_planar_pallas``);
+    - ``host-threaded`` — the host's streaming derive-and-sum
+      (``core.mask.derive_sum``: no mask in memory, every core), the SDK's
+      CPU sum participant's route too;
     - ``host-chunked`` — the pre-promotion path (host unit draws + chunked
       device vector derivation + ``aggregate_batch`` folds);
     - ``auto`` — races the candidates once per (backend, shape) on a probe
@@ -464,146 +451,6 @@ def _sum_masks_batched(
         stream.close()
     assert unit_acc is not None
     return unit_acc, vect
-
-
-def _sum_masks_host_fused(
-    seeds: list[bytes], length: int, config: MaskConfigPair
-) -> tuple[np.ndarray, np.ndarray] | None:
-    """The native twin of the Pallas fused kernel: ``xn_sample_fold_u64``
-    rejection-samples each seed's mask straight INTO a u64 accumulator —
-    no mask bytes, no bytes→limbs pass, no stack, no separate fold read.
-    Seeds split across threads with per-thread partial accumulators
-    (disjoint memory; the GIL is released inside the native call), merged
-    with the exact limb ``mod_add``. Returns ``None`` when the entry
-    doesn't apply (no library, order wider than 8 bytes) so the caller
-    falls back to the materializing wave path."""
-    from ..utils import native
-
-    lib = native.load()
-    order = config.vect.order
-    bpn = host_limbs.draw_width_for(order)
-    # order > 2^63 can't even hold residual + one fold in u64 (2*order - 2
-    # wraps), so the wave path serves those
-    if (
-        lib is None
-        or bpn > 8
-        or order > (1 << 63)
-        or not hasattr(lib, "xn_sample_fold_u64")
-    ):
-        return None
-    from concurrent.futures import ThreadPoolExecutor
-
-    order_le = order.to_bytes(bpn, "little")
-    ol_u = host_limbs.order_limbs_for(config.unit.order)
-    n_limb = host_limbs.n_limbs_for_order(order)
-    # u64 lazy-reduction headroom: the unreduced partial holds one reduced
-    # residual (< order) plus up to `reduce_every` folds (< order each), so
-    # (reduce_every + 1) * order must stay below 2^64 (>= 1 for any
-    # order <= 2^63; huge for typical orders)
-    reduce_every = max(1, (1 << 64) // order - 2)
-    nt = _host_sampler_threads(len(seeds), default_cap=4)
-    chunks = [seeds[i::nt] for i in range(nt)]
-
-    def run_chunk(chunk: list[bytes]):
-        acc = np.zeros(length, dtype=np.uint64)
-        units = []
-        since_reduce = 0
-        for seed in chunk:
-            sampler = StreamSampler(seed)
-            units.append(sampler.draw_limbs(1, config.unit.order)[0])
-            if since_reduce >= reduce_every:
-                np.mod(acc, np.uint64(order), out=acc)
-                since_reduce = 1
-            else:
-                since_reduce += 1
-            end = lib.xn_sample_fold_u64(
-                native.as_u8p(seed),
-                sampler.consumed_bytes,
-                length,
-                native.as_u8p(order_le),
-                bpn,
-                native.np_u64p(acc),
-            )
-            if end == 0:  # out-of-range order: caller takes the wave path
-                return None
-        np.mod(acc, np.uint64(order), out=acc)
-        return acc, units
-
-    with ThreadPoolExecutor(max_workers=nt) as pool:
-        results = list(pool.map(run_chunk, chunks))
-    if any(r is None for r in results):
-        return None
-
-    def to_limbs(acc64: np.ndarray) -> np.ndarray:
-        lo = (acc64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-        if n_limb == 1:
-            return lo[:, None]
-        hi = (acc64 >> np.uint64(32)).astype(np.uint32)
-        return np.stack([lo, hi], axis=1)
-
-    ol_v = host_limbs.order_limbs_for(order)
-    vect_acc: np.ndarray | None = None
-    unit_acc: np.ndarray | None = None
-    for acc64, units in results:
-        part = to_limbs(acc64)
-        vect_acc = part if vect_acc is None else host_limbs.mod_add(vect_acc, part, ol_v)
-        for u in units:
-            unit_acc = _acc_unit(unit_acc, u, ol_u)
-    assert unit_acc is not None and vect_acc is not None
-    return unit_acc, vect_acc
-
-
-def _sum_masks_host_threaded(
-    seeds: list[bytes], length: int, config: MaskConfigPair, seed_batch: int
-) -> tuple[np.ndarray, np.ndarray]:
-    """The CPU incumbent: the fused native sample+fold when it applies
-    (``_sum_masks_host_fused`` — the mask never materializes), else
-    per-seed derivations on the native (AVX2) ``StreamSampler`` across a
-    GIL-released thread pool, folded per wave with the single-pass native
-    batch fold. Memory stays bounded by ``seed_batch * length`` mask
-    elements (one wave at a time) — the shape that lets 10k-seed Sum2
-    legs run on a laptop."""
-    fused = _sum_masks_host_fused(seeds, length, config)
-    if fused is not None:
-        return fused
-    from concurrent.futures import ThreadPoolExecutor
-
-    ol_v = host_limbs.order_limbs_for(config.vect.order)
-    ol_u = host_limbs.order_limbs_for(config.unit.order)
-    step = max(1, seed_batch)
-
-    def derive(seed: bytes) -> tuple[np.ndarray, np.ndarray]:
-        sampler = StreamSampler(seed)
-        unit = sampler.draw_limbs(1, config.unit.order)[0]
-        return unit, sampler.draw_limbs(length, config.vect.order)
-
-    unit_acc: np.ndarray | None = None
-    vect_acc: np.ndarray | None = None
-    with ThreadPoolExecutor(max_workers=_host_sampler_threads(len(seeds), default_cap=8)) as pool:
-        for g0 in range(0, len(seeds), step):
-            group = seeds[g0 : g0 + step]
-            pairs = list(pool.map(derive, group))
-            units = np.stack([u for u, _ in pairs])
-            vects = np.stack([v for _, v in pairs])
-            pairs.clear()
-            group_unit = host_limbs.batch_mod_sum(units[:, None, :], ol_u)[0]
-            if vect_acc is None:
-                vect_acc = host_limbs.batch_mod_sum(vects, ol_v)
-                unit_acc = group_unit
-            else:
-                # batch + running accumulator in one native read; tree
-                # fallback only for orders outside the single-pass kernels
-                fast = host_limbs.fold_wire_batch_host(vect_acc, vects, ol_v)
-                vect_acc = (
-                    fast
-                    if fast is not None
-                    else host_limbs.mod_add(
-                        vect_acc, host_limbs.batch_mod_sum(vects, ol_v), ol_v
-                    )
-                )
-                unit_acc = _acc_unit(unit_acc, group_unit, ol_u)
-    assert unit_acc is not None and vect_acc is not None
-    return unit_acc, vect_acc
 
 
 def _sum_masks_fused(

@@ -28,9 +28,10 @@ import numpy as np
 from ..core.common import RoundParameters
 from ..core.crypto.encrypt import EncryptKeyPair, PublicEncryptKey
 from ..core.crypto.sign import SigningKeyPair, is_eligible
-from ..core.mask.masking import Aggregation, Masker
+from ..core.mask.derive_sum import derive_and_sum
+from ..core.mask.masking import Masker, check_nb_models
 from ..core.mask.model import Scalar
-from ..core.mask.object import MaskObject
+from ..core.mask.object import MaskObject, MaskUnit, MaskVect
 from ..core.message import Message, Sum, Sum2, Update
 from ..core.message.encoder import DEFAULT_MAX_MESSAGE_SIZE, MIN_MESSAGE_SIZE, MessageEncoder
 from .traits import ModelStore, Notify, XaynetClient
@@ -405,7 +406,6 @@ class StateMachine:
         )
         if use_device:
             try:
-                from ..core.mask.object import MaskUnit, MaskVect
                 from ..ops import masking_jax
 
                 # the kwarg is only passed when pinned: the default route
@@ -422,34 +422,13 @@ class StateMachine:
                 if self.device_sum2_strict:
                     raise
                 logger.warning("device mask aggregation failed; using host path", exc_info=True)
-        # mask derivations are independent per seed and the native sampler
-        # releases the GIL, so they parallelize across threads
-        from concurrent.futures import ThreadPoolExecutor
-
-        mask_agg = Aggregation(config, length)
-        # the validation loop below scribbles on nb_models and resets it to
-        # 0; that is only correct against a freshly-built Aggregation
-        assert mask_agg.nb_models == 0
-        if len(mask_seeds) > 1:
-            with ThreadPoolExecutor(max_workers=min(8, len(mask_seeds))) as pool:
-                masks = list(pool.map(lambda s: s.derive_mask(length, config), mask_seeds))
-        else:
-            masks = [s.derive_mask(length, config) for s in mask_seeds]
-        # replicate the incremental loop's per-mask error precedence exactly:
-        # mask i is validated against the state where i models are already
-        # folded, so a mismatched/invalid mask at a low index still raises
-        # before a count overflow at a higher one (masking.rs check order)
-        for i, mask in enumerate(masks):
-            mask_agg.nb_models = i
-            mask_agg.validate_aggregation(mask)
-        mask_agg.nb_models = 0
-        # one batched fold (native single-pass on <=2-limb configs) instead
-        # of len(masks) sequential modular adds
-        mask_agg.aggregate_batch(
-            np.stack([m.vect.data for m in masks]),
-            np.stack([m.unit.data for m in masks]),
-        )
-        return mask_agg.object
+        # the host route: one streaming derive-and-sum, no mask in memory
+        # (core/mask/derive_sum.py). Derived masks are of this configuration
+        # and length and below the order by construction, so of the checks
+        # aggregating them one by one would make only the count can fail
+        check_nb_models(config, len(mask_seeds))
+        unit, vect = derive_and_sum([s.as_bytes() for s in mask_seeds], length, config)
+        return MaskObject(MaskVect(config.vect, vect), MaskUnit(config.unit, unit))
 
     # --- sending ----------------------------------------------------------
 

@@ -11,7 +11,8 @@ from __future__ import annotations
 
 import asyncio
 import copy
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field
 from enum import Enum
 from typing import Generic, Optional, TypeVar
 
@@ -36,6 +37,9 @@ class Event(Generic[T]):
 
     round_id: int
     event: T
+    # when it was made (``time.monotonic``): what the API layer measures a
+    # phase's first arrival from
+    at: float = field(default_factory=time.monotonic, compare=False)
 
 
 class ModelUpdate:

@@ -42,6 +42,7 @@ from ..telemetry import tracing as trace
 from ..telemetry.registry import MetricsRegistry, get_registry
 from ..utils import native, tracing
 from . import stages
+from .events import PhaseName
 from .requests import RequestError
 from .services import Fetcher, PetMessageHandler, ServiceError
 
@@ -237,6 +238,16 @@ class RestServer:
             "bodies, TLS, no free reader).",
             ("route",),
         )
+        self._sum2_first_arrival = self.registry.histogram(
+            "xaynet_sum2_first_arrival_seconds",
+            "Sum2 phase announced -> the headers of the first message POSTed "
+            "in it parsed, once a round: the sum participant's fetch, derive, "
+            "sum, encode and seal as the coordinator sees them; the rest of "
+            "the phase is that message's path through the coordinator.",
+            buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0, 80.0, 160.0),
+        )
+        # the Sum2 phase event whose first message was seen, a tenant
+        self._sum2_seen: dict[str, object] = {}
         self._lag_task: Optional[asyncio.Task] = None
         self._server: Optional[asyncio.AbstractServer] = None
         # the bounded rest-body pool (made when a body first needs it) and
@@ -479,6 +490,8 @@ class RestServer:
                     tenant=tenant,
                 ).inc()
                 return 429, b"tenant not accepting traffic", "text/plain", extra
+        if method == "POST" and path == "/message":
+            self._note_sum2_arrival(tenant, routes)
         # handlers return (status, payload, ctype) or + an extra-headers dict
         if path in _UNTRACED_PATHS:
             result = await self._dispatch(method, path, url.query, body, headers, routes)
@@ -511,6 +524,16 @@ class RestServer:
             tenant=tenant,
         ).inc()
         return status, payload, ctype, extra
+
+    def _note_sum2_arrival(self, tenant: str, routes: TenantRoutes) -> None:
+        """One observation a round: a message's headers are parsed, the phase
+        is Sum2, and no message of this Sum2 came before."""
+        if routes.fetcher is None:  # a server that only takes messages (tools, tests)
+            return
+        entered = routes.fetcher.events.phase.get_latest()
+        if entered.event is PhaseName.SUM2 and self._sum2_seen.get(tenant) is not entered:
+            self._sum2_seen[tenant] = entered
+            self._sum2_first_arrival.observe(time.monotonic() - entered.at)
 
     async def _dispatch(self, method: str, path: str, query: str, body: bytes,
                         headers, routes: TenantRoutes):

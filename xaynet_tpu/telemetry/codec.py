@@ -16,7 +16,9 @@ counted where the choice is made:
   ``pack_planar_slice`` (limbs -> byte planes);
 - ``derive``: ``core/crypto/prng.py::StreamSampler.draw_limbs`` (seed -> mask
   elements), in the process that derives: the sum participant's, not the
-  coordinator's;
+  coordinator's. A third route, ``fused``, counts the elements that
+  ``core/mask/derive_sum.py`` derived and summed in one native pass with no
+  mask in memory (the sum participant's Sum2 on a host with the library);
 - ``decode``: ``core/mask/encode.py::decode_vect_fast``, ``decode_vect_any``
   (unmasked limbs -> float64).
 """
@@ -29,10 +31,15 @@ ELEMENTS = get_registry().counter(
     "xaynet_codec_elements_total",
     "Group elements through a width-dependent host operation (parse, validate, "
     "stage, derive, decode), by the route it took: fast = a kernel of the native "
-    "library, generic = numpy or Python (telemetry/codec.py).",
+    "library, generic = numpy or Python, fused (derive only) = the library "
+    "derived and summed with no mask in memory (telemetry/codec.py).",
     ("op", "route"),
 )
 
 
 def count(op: str, fast: bool, elements: int) -> None:
     ELEMENTS.labels(op=op, route="fast" if fast else "generic").inc(elements)
+
+
+def count_fused(op: str, elements: int) -> None:
+    ELEMENTS.labels(op=op, route="fused").inc(elements)

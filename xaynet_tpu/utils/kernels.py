@@ -18,12 +18,12 @@ FOLD_KERNELS = ("auto", "xla", "pallas", "pallas-interpret")
 # - ``fused-pallas-interpret`` — the same kernel through the Pallas
 #                      interpreter (the CPU route that keeps the fused kernel
 #                      continuously exercised without a Mosaic compiler);
-# - ``host-threaded`` — the CPU incumbent: the fused native sample+fold
-#                      (``xn_sample_fold_u64`` — accepted draws accumulate
-#                      straight into a u64 buffer, the mask never
-#                      materializes) when the order fits, else the native
-#                      (AVX2) ``StreamSampler`` across a GIL-released
-#                      thread pool with the single-pass batch fold;
+# - ``host-threaded`` — the CPU incumbent: the host's one streaming
+#                      derive-and-sum (``core.mask.derive_sum``): accepted
+#                      draws accumulate as they are sampled, over every core,
+#                      and no mask materializes (``xn_derive_sum``, draws of
+#                      up to 16 bytes); wider orders and a host without the
+#                      library fold a bounded wave of ``StreamSampler`` masks;
 # - ``host-chunked`` — the pre-promotion device path (host unit draws per
 #                      seed + host-chunked device vector derivation), kept
 #                      as an explicit fallback;

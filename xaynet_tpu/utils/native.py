@@ -18,7 +18,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 10
+_ABI_VERSION = 11
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -81,18 +81,27 @@ def load() -> Optional[ctypes.CDLL]:
             u8p,
         ]
         lib.xn_sample_uniform.restype = ctypes.c_uint64
-        # fused sample+fold (ABI 7): accepted draws accumulate into a u64
-        # buffer instead of materializing the mask bytes
+        # streaming derive-and-sum (ABI 11): the masks of k seeds sampled
+        # and summed in one call, threads inside (core/mask/derive_sum.py)
         u64p = ctypes.POINTER(ctypes.c_uint64)
-        lib.xn_sample_fold_u64.argtypes = [
-            u8p,
-            ctypes.c_uint64,
-            ctypes.c_uint64,
-            u8p,
-            ctypes.c_uint32,
-            u64p,
+        lib.xn_derive_sum.argtypes = [
+            u8p,  # k seeds of 32 bytes
+            u64p,  # k keystream byte offsets (after each seed's unit draw)
+            ctypes.c_uint64,  # k
+            ctypes.c_uint64,  # n
+            u8p,  # order, little-endian
+            ctypes.c_uint32,  # its byte length: the draw width
+            ctypes.c_uint32,  # accumulator stride in bytes: 8, 12 or 16
+            u8p,  # accumulator, n * stride zeroed bytes (may be `out`)
+            ctypes.c_uint32,  # eager: modular add instead of lazy sums
+            ctypes.c_uint32,  # n_limbs of an output element
+            u32p,  # out uint32[n, n_limbs]
+            u64p,  # k end cursors
+            ctypes.c_uint32,  # threads
+            ctypes.c_uint32,  # groups of threads, an accumulator each
+            ctypes.c_uint64,  # candidates a segment
         ]
-        lib.xn_sample_fold_u64.restype = ctypes.c_uint64
+        lib.xn_derive_sum.restype = ctypes.c_int
         lib.xn_mod_add.argtypes = [u32p, u32p, u32p, ctypes.c_uint64, ctypes.c_uint32, u32p]
         lib.xn_mod_add.restype = None
         lib.xn_fold_wire_u64.argtypes = [
