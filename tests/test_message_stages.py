@@ -145,8 +145,7 @@ def served_round():
     tracer.configure(mode="on")
     try:
         out = asyncio.run(asyncio.wait_for(_round(), timeout=120))
-        out["spans"] = [s for s in tracer.ring_spans()
-                        if out["t_open"] <= s.start <= out["t_close"]]
+        out["spans"] = [s for s in tracer.ring_spans() if s.start >= out["t_open"]]
     finally:
         tracer.configure(mode=mode)
     return out
@@ -166,6 +165,17 @@ def test_stage_spans_share_the_rid_and_parent_of_their_message(served_round):
     for s in spans:
         if s.name in names:
             by_rid.setdefault(s.attrs["rid"], []).append(s)
+    # The window's messages are the Updates that reached the phase before it
+    # closed. Not "every span that started before ``t_close``": the sum
+    # participant posts its Sum2 message within milliseconds of the phase's
+    # end, and under a loaded host its ``rest.read_body`` starts before the
+    # test's poll has read ``t_close`` (a fifth rid with one span); the next
+    # round's messages follow within tens of milliseconds.
+    reached = stages._SPANS["request_wait"]
+    by_rid = {
+        rid: chain for rid, chain in by_rid.items()
+        if any(s.name == reached and s.start <= served_round["t_close"] for s in chain)
+    }
     assert len(by_rid) == N_UPDATE and "-" not in by_rid
     for rid, chain in by_rid.items():
         assert sorted(s.name for s in chain) == sorted(names), rid

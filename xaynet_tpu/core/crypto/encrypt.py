@@ -36,6 +36,9 @@ except ImportError:  # ... pure-stdlib fallback otherwise (see _purecrypto)
 
     _HAVE_CRYPTO = False
 
+from ...utils import native
+from . import unlocked
+
 SEALBYTES = 48
 PUBLIC_KEY_LENGTH = 32
 SECRET_KEY_LENGTH = 32
@@ -102,13 +105,32 @@ class SecretEncryptKey:
 
     def decrypt(
         self, sealed: "bytes | bytearray | memoryview", pk: "PublicEncryptKey | None" = None
-    ) -> bytes:
+    ) -> "bytes | memoryview":
         """Open a sealed box addressed to this key.
 
         ``pk`` (our own public key) is accepted for reference API parity; it
         is recomputed when omitted. ``sealed`` is read through the buffer
-        protocol: the ciphertext of a 179 MB upload is not copied first.
+        protocol and never written to, whatever its type: the ciphertext of
+        a 179 MB upload is not copied first. A long box is opened by a
+        foreign call with the interpreter lock released (``unlocked``) into
+        one buffer allocated at the plaintext's length, and the plaintext is
+        a view of it; a short one by the wheel, as ``bytes``.
         """
+        return self._open(sealed, pk, in_place=False)
+
+    def decrypt_in_place(
+        self, sealed: "bytearray | memoryview", pk: "PublicEncryptKey | None" = None
+    ) -> "bytes | memoryview":
+        """:meth:`decrypt` for a caller that owns ``sealed`` and gives it up:
+        a long box's plaintext is written over its ciphertext, so no second
+        buffer of the body's size exists, and the view returned keeps
+        ``sealed`` alive. Whatever the outcome, ``sealed`` no longer holds
+        the box. A read-only buffer is refused."""
+        if memoryview(sealed).readonly:
+            raise TypeError("decrypt_in_place needs a writable buffer")
+        return self._open(sealed, pk, in_place=True)
+
+    def _open(self, sealed, pk: "PublicEncryptKey | None", in_place: bool) -> "bytes | memoryview":
         if len(sealed) < SEALBYTES:
             raise DecryptError("sealed box too short")
         my_pk = pk.as_bytes() if pk is not None else self.public_key().as_bytes()
@@ -117,13 +139,21 @@ class SecretEncryptKey:
         if _HAVE_CRYPTO:
             sk = X25519PrivateKey.from_private_bytes(self.bytes_)
             shared = sk.exchange(X25519PublicKey.from_public_bytes(eph_pk))
-            key = _derive_key(shared, eph_pk, my_pk)
+        else:
+            shared = _purecrypto.x25519(self.bytes_, eph_pk)
+        key = _derive_key(shared, eph_pk, my_pk)
+        # one algorithm, routed by the box's length (unlocked.UNLOCKED_MIN)
+        if unlocked.choose("open", len(ct)):
+            size = len(ct) - unlocked.TAG_LENGTH
+            out = ct if in_place else native.uninitialised_bytearray(None, size)
+            if not unlocked.open_into(key, _ZERO_NONCE, ct, out):
+                raise DecryptError("sealed box authentication failed")
+            return memoryview(out)[:size]
+        if _HAVE_CRYPTO:
             try:
                 return ChaCha20Poly1305(key).decrypt(_ZERO_NONCE, ct, None)
             except InvalidTag as e:
                 raise DecryptError("sealed box authentication failed") from e
-        shared = _purecrypto.x25519(self.bytes_, eph_pk)
-        key = _derive_key(shared, eph_pk, my_pk)
         try:
             return _purecrypto.chacha20poly1305_decrypt(key, _ZERO_NONCE, bytes(ct))
         except _purecrypto.AeadTagError as e:

@@ -28,6 +28,7 @@ except ImportError:
 
     _HAVE_CRYPTO = False
 
+from . import unlocked
 from .hash import sha256
 
 PUBLIC_KEY_LENGTH = 32
@@ -91,7 +92,14 @@ def sign_detached(secret: bytes, data: bytes) -> bytes:
     return Ed25519PrivateKey.from_private_bytes(secret).sign(data)
 
 
-def verify_detached(public: bytes, signature: bytes, data: bytes) -> bool:
+def verify_detached(public: bytes, signature: bytes, data) -> bool:
+    """Ed25519 verify of ``signature`` over ``data`` (any buffer). One
+    algorithm, routed by ``len(data)``: long inputs (a message's signed
+    bytes) through a foreign call with the interpreter lock released
+    (``unlocked``), short ones (task signatures, Sum messages, chunks)
+    through the wheel, whose call is cheaper than the lock's hand-over."""
+    if unlocked.choose("verify", len(data)):
+        return unlocked.ed25519_verify(public, signature, data)
     if not _HAVE_CRYPTO:
         try:
             return _purecrypto.ed25519_verify(public, signature, data)

@@ -94,9 +94,15 @@ class Message:
         payload's element parse/validity to the accelerator (see
         ``parse_mask_vect``); all other payloads parse eagerly."""
         length = cls._declared_length(data)
-        signature = data[:SIGNATURE_LENGTH]
-        participant_pk = data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH]
-        coordinator_pk = data[SIGNATURE_LENGTH + PK_LENGTH : SIGNATURE_LENGTH + 2 * PK_LENGTH]
+        # ``data`` may be a view of the buffer a sealed box was opened into:
+        # the header's fields are copied out, the payload is sliced as a view
+        # (no second copy of a 179 MB body, whatever ``data`` is)
+        data = memoryview(data)
+        signature = bytes(data[:SIGNATURE_LENGTH])
+        participant_pk = bytes(data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH])
+        coordinator_pk = bytes(
+            data[SIGNATURE_LENGTH + PK_LENGTH : SIGNATURE_LENGTH + 2 * PK_LENGTH]
+        )
         tag_raw = data[SIGNATURE_LENGTH + 2 * PK_LENGTH + 4]
         flags_raw = data[SIGNATURE_LENGTH + 2 * PK_LENGTH + 5]
         try:
@@ -133,10 +139,11 @@ class Message:
         pass over the signed bytes, no copy of them), for callers that time
         it apart from the parse and then parse with ``verify=False``."""
         length = cls._declared_length(data)
+        data = memoryview(data)
         if not crypto_sign.verify_detached(
-            data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH],
-            data[:SIGNATURE_LENGTH],
-            memoryview(data)[SIGNATURE_LENGTH:length],
+            bytes(data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH]),
+            bytes(data[:SIGNATURE_LENGTH]),
+            data[SIGNATURE_LENGTH:length],
         ):
             raise DecodeError("invalid message signature")
 
@@ -168,7 +175,7 @@ def peek_header(data: bytes) -> tuple[bytes, Tag, bool]:
         raise DecodeError(f"invalid tag {tag_raw}") from e
     flags_raw = data[SIGNATURE_LENGTH + 2 * PK_LENGTH + 5]
     return (
-        data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH],
+        bytes(data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH]),
         tag,
         bool(flags_raw & Flags.MULTIPART),
     )

@@ -73,7 +73,8 @@ def parse_local_seed_dict(data: bytes, offset: int = 0) -> tuple[dict, int]:
     return _seed_dict_from_value(value), consumed
 
 
-def _seed_dict_from_value(value: bytes) -> dict:
+def _seed_dict_from_value(value) -> dict:
+    value = bytes(value)  # keys and seeds are hashed and kept: never views of a body
     if len(value) % SEED_DICT_ENTRY_LENGTH != 0:
         raise DecodeError("seed dict length not a multiple of the entry size")
     out: dict = {}
@@ -114,8 +115,8 @@ class Sum:
         if len(data) < SIGNATURE_LENGTH + PK_LENGTH:
             raise DecodeError("sum payload too short")
         return cls(
-            sum_signature=data[:SIGNATURE_LENGTH],
-            ephm_pk=data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH],
+            sum_signature=bytes(data[:SIGNATURE_LENGTH]),
+            ephm_pk=bytes(data[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH]),
         )
 
 
@@ -155,8 +156,8 @@ class Update:
         masked, consumed = parse_mask_object(data, 2 * SIGNATURE_LENGTH, lazy_vect=lazy_vect)
         seed_dict, _ = parse_local_seed_dict(data, 2 * SIGNATURE_LENGTH + consumed)
         return cls(
-            sum_signature=data[:SIGNATURE_LENGTH],
-            update_signature=data[SIGNATURE_LENGTH : 2 * SIGNATURE_LENGTH],
+            sum_signature=bytes(data[:SIGNATURE_LENGTH]),
+            update_signature=bytes(data[SIGNATURE_LENGTH : 2 * SIGNATURE_LENGTH]),
             masked_model=masked,
             local_seed_dict=seed_dict,
             wire_planar=bool(getattr(masked.vect, "planar", False)),
@@ -197,7 +198,7 @@ class Sum2:
         if len(data) < SIGNATURE_LENGTH:
             raise DecodeError("sum2 payload too short")
         mask, _ = parse_mask_object(data, SIGNATURE_LENGTH)
-        return cls(sum_signature=data[:SIGNATURE_LENGTH], model_mask=mask)
+        return cls(sum_signature=bytes(data[:SIGNATURE_LENGTH]), model_mask=mask)
 
     @classmethod
     def from_stream(cls, reader) -> "Sum2":
@@ -235,7 +236,10 @@ class Chunk:
         if len(data) < CHUNK_HEADER_LENGTH:
             raise DecodeError("chunk payload too short")
         cid, mid, flags = struct.unpack_from(">HHB", data)
-        return cls(id=cid, message_id=mid, last=bool(flags & 1), data=data[CHUNK_HEADER_LENGTH:], tag=tag)
+        return cls(
+            id=cid, message_id=mid, last=bool(flags & 1),
+            data=bytes(data[CHUNK_HEADER_LENGTH:]), tag=tag,
+        )
 
 
 Payload = Union[Sum, Update, Sum2, Chunk]

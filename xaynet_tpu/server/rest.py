@@ -75,13 +75,6 @@ DIRECT_BODY_MIN = 1 << 20
 # reader while its peer is sending.
 BODY_READERS = 16
 
-# ``bytearray(n)`` zero-fills its n bytes under the interpreter lock: 110 ms
-# of the event loop for a 179 MB body that recv() is about to overwrite. The
-# C API's constructor, given no source, allocates and touches nothing.
-_uninitialised_bytearray = ctypes.PYFUNCTYPE(
-    ctypes.py_object, ctypes.c_char_p, ctypes.c_ssize_t
-)(("PyByteArray_FromStringAndSize", ctypes.pythonapi))
-
 SPAN_REQUEST = trace.declare_span("rest.request")
 
 # polled endpoints are untraced: monitoring (/metrics, /healthz) and the
@@ -366,7 +359,7 @@ class RestServer:
         # nothing below suspends until the thread has the socket, so no byte
         # reaches the StreamReader between here and resume_reading()
         transport.pause_reading()
-        body = _uninitialised_bytearray(None, length)
+        body = native.uninitialised_bytearray(None, length)
         buffered = len(reader._buffer)
         if buffered:
             # the segment that carried the headers carried these; the buffer

@@ -77,8 +77,9 @@ class PetMessageHandler:
         self._multipart: dict[tuple[bytes, int], MessageBuilder] = {}
         self.max_multipart_buffers = 4096
 
-    async def handle_message(self, encrypted: bytes) -> None:
-        """Decrypt, verify, validate and forward one message.
+    async def handle_message(self, encrypted: "bytes | bytearray") -> None:
+        """Decrypt, verify, validate and forward one message. A
+        ``bytearray`` is given up by the caller (opened in place).
 
         Raises ``ServiceError`` (pipeline drop) or ``RequestError`` (state
         machine rejection).
@@ -111,10 +112,17 @@ class PetMessageHandler:
         """
         # sealed-box open (CPU) — reference: decryptor.rs:48-69. Passing our
         # public key skips a per-message X25519 recompute of it (milliseconds
-        # per message on the pure-python fallback)
+        # per message on the pure-python fallback). A ``bytearray`` is the
+        # buffer ``rest.py`` read the body into, which nothing reads again:
+        # the pipeline gives it up and a long box is opened over its own
+        # ciphertext; ``raw`` is then a view that keeps the buffer alive (and
+        # so does a lazily parsed vector that points into it)
         with stages.stage("open", ctx=ctx, rid=rid, bytes=len(encrypted)):
             try:
-                raw = keys.secret.decrypt(encrypted, keys.public)
+                if isinstance(encrypted, bytearray):
+                    raw = keys.secret.decrypt_in_place(encrypted, keys.public)
+                else:
+                    raw = keys.secret.decrypt(encrypted, keys.public)
             except (DecryptError, ValueError) as e:
                 raise ServiceError("decrypt", str(e)) from e
         # phase filter before the expensive signature check

@@ -213,6 +213,15 @@ def load() -> Optional[ctypes.CDLL]:
     return _lib
 
 
+# ``bytearray(n)`` zero-fills its n bytes under the interpreter lock: 110 ms
+# of the event loop for a 179 MB body that recv() (or a sealed box's open) is
+# about to overwrite. The C API's constructor, given no source, allocates and
+# touches nothing: ``uninitialised_bytearray(None, n)``.
+uninitialised_bytearray = ctypes.PYFUNCTYPE(
+    ctypes.py_object, ctypes.c_char_p, ctypes.c_ssize_t
+)(("PyByteArray_FromStringAndSize", ctypes.pythonapi))
+
+
 def as_u8p(buf) -> "ctypes.pointer":
     return ctypes.cast(ctypes.c_char_p(bytes(buf)), ctypes.POINTER(ctypes.c_uint8))
 
