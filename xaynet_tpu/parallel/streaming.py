@@ -85,6 +85,7 @@ from ..telemetry import profiling
 from ..telemetry import tracing as trace
 from ..telemetry.recorder import flight_dump
 from ..telemetry.registry import get_registry
+from ..telemetry.timeline import intersection, measure, merge_intervals
 from ..tenancy.pool import get_pool
 from ..tenancy.scheduler import get_scheduler
 # BYTES_STAGED: one module owns the xaynet_bytes_staged_total family —
@@ -99,6 +100,7 @@ logger = logging.getLogger(__name__)
 # after the fact (record_span), which no mirror can carry; so are the
 # per-shard stream.stage spans of the shard-parallel submit paths.
 SPAN_STAGE = trace.declare_span("stream.stage", mirror=True)
+SPAN_RING_WAIT = trace.declare_span("stream.ring_wait", mirror=True)
 SPAN_H2D = trace.declare_span("stream.h2d", mirror=True)
 SPAN_FOLD = trace.declare_span("stream.fold", mirror=True)
 SPAN_COMMIT = trace.declare_span("stream.commit")
@@ -118,7 +120,10 @@ OVERLAP_RATIO = _registry.gauge(
     "xaynet_streaming_overlap_ratio",
     "Fraction of the shorter pipeline leg (staging vs folding) that ran "
     "concurrently with the other leg during the last drain window "
-    "(1 = perfect overlap, 0 = fully serialized).",
+    "(1 = perfect overlap, 0 = fully serialized). A batch's staging leg is "
+    "the time its ring buffer is lent to it, buffer in hand to hand-over; "
+    "its folding leg is its fold worker item. Time in which neither leg "
+    "runs (waiting for arrivals) counts for nothing.",
 )
 BATCHES_TOTAL = _registry.counter(
     "xaynet_streaming_batches_total",
@@ -171,6 +176,16 @@ ROWS_STAGED = _registry.counter(
     "flush = inside the submit call that closed the batch).",
     ("route",),
 )
+RING_WAIT_SECONDS = _registry.histogram(
+    "xaynet_streaming_ring_wait_seconds",
+    "One acquisition of a staging ring buffer, from the call to the buffer "
+    "in hand, by how it was met (free = a buffer lay in the ring, leased = "
+    "a new buffer was leased and zero-filled, waited = every buffer was "
+    "owned by a batch in flight). _count is the acquisitions by kind.",
+    ("how",),
+    buckets=(0.0001, 0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+             10.0, 30.0, 60.0),
+)
 _SHUTDOWN = object()
 
 
@@ -185,6 +200,15 @@ def _h2d(kind: str, nbytes: int):
         yield
     H2D_SECONDS.observe(time.monotonic() - t0)
     H2D_BYTES.inc(nbytes)
+
+
+def _overlap_ratio(stage, fold) -> float | None:
+    """Seconds in which a staging leg and a folding leg both ran, over the
+    shorter leg's seconds (each leg the union of its batches' intervals);
+    None while either leg is empty."""
+    a, b = merge_intervals(stage), merge_intervals(fold)
+    shorter = min(measure(a), measure(b))
+    return intersection(a, b) / shorter if shorter > 0 else None
 
 
 class StreamingError(RuntimeError):
@@ -362,12 +386,19 @@ class _StagingRing:
         return lease
 
     def acquire(self, timeout: float | None = None) -> np.ndarray:
-        try:
-            lease = self._free.get_nowait()
-        except queue_mod.Empty:
-            lease = self._grow()
-            if lease is None:
-                lease = self._free.get(timeout=timeout)
+        t0 = time.monotonic()
+        with trace.get_tracer().span(SPAN_RING_WAIT, tenant=self._tenant) as wait:
+            how = "free"
+            try:
+                lease = self._free.get_nowait()
+            except queue_mod.Empty:
+                how = "leased"
+                lease = self._grow()
+                if lease is None:
+                    how = "waited"
+                    lease = self._free.get(timeout=timeout)
+            wait.set(how=how)
+        RING_WAIT_SECONDS.labels(how=how).observe(time.monotonic() - t0)
         # pin first, read second: set_migrator takes the pool lock, so a
         # compaction mid-flight either finished (lease.array is the new
         # view) or will now skip this lease entirely
@@ -479,8 +510,6 @@ class StreamingAggregator:
         self._shard_queues: list[queue_mod.Queue] | None = None
         self._shard_workers: list[threading.Thread | None] = []
         self._shard_rings: dict[int, _StagingRing] = {}  # guarded-by: _lock
-        self._shard_stage_seconds = [0.0] * self._n_shards  # guarded-by: _lock
-        self._shard_fold_seconds = [0.0] * self._n_shards  # guarded-by: _lock
         self._queue: queue_mod.Queue = queue_mod.Queue(maxsize=dispatch_ahead)
         self._rings: dict[str, _StagingRing] = {}  # lazy: planar / wire  # guarded-by: _lock
         self._pending: list[StreamTicket] = []  # awaiting ok sync  # guarded-by: _lock
@@ -497,10 +526,11 @@ class StreamingAggregator:
         # only in close(): a degraded pipeline abandoned on phase failure
         # must not leave the gauge stuck at 1 for later healthy rounds
         DEGRADED.set(0)
-        # overlap accounting, reset per drain window
-        self._stage_seconds = 0.0
-        self._fold_seconds = 0.0
-        self._window_start: float | None = None
+        # overlap accounting, reset per drain window: (start, end) of every
+        # batch's legs, under "stage" / "fold" and, shard-parallel, under
+        # ("stage", d) / ("fold", d) as well
+        self._legs: dict = {}  # guarded-by: _lock
+        self._lent_since: dict[int, float] = {}  # id(open buffer) -> lent at  # guarded-by: _lock
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -654,8 +684,6 @@ class StreamingAggregator:
         err = self._poisoned()
         if err is not None:
             raise self._poison_error() from err
-        if self._window_start is None:
-            self._window_start = time.monotonic()
 
     def _check(self, k: int) -> None:
         self._check_usable()
@@ -709,8 +737,7 @@ class StreamingAggregator:
         finally:
             self._slot_release()
             self._ring(kind).release(buf)
-            with self._lock:
-                self._fold_seconds += time.monotonic() - t0
+            self._leg(t0, "fold")
         BATCHES_TOTAL.labels(stage="folded").inc()
 
     # -- host batches: open, fill slot by slot, submit ----------------------
@@ -741,7 +768,10 @@ class StreamingAggregator:
         if self._sharded:
             raise StreamingError("shard-parallel pipelines stage per shard at submit")
         self._check_usable()
-        return self._ring(self._host_kind).acquire()
+        buf = self._ring(self._host_kind).acquire()
+        with self._lock:
+            self._lent_since[id(buf)] = time.monotonic()
+        return buf
 
     def _relay_wire_rows(self, view: np.ndarray, stack: np.ndarray) -> None:
         """Wire-layout ``uint32[k, model_len, L]`` rows into ``k`` slots of
@@ -768,14 +798,13 @@ class StreamingAggregator:
         writer."""
         if wire.shape != (self.agg.model_length, self.agg.n_limbs):
             raise ValueError("expected uint32[model_len, L]")
-        t0 = time.monotonic()
         self._relay_wire_rows(buf[i : i + 1], wire[None])
         ROWS_STAGED.labels(route="arrival").inc()
-        with self._lock:
-            self._stage_seconds += time.monotonic() - t0
 
     def release_batch(self, buf: np.ndarray) -> None:
         """Return an open batch's buffer unfolded (a failed slot write)."""
+        with self._lock:
+            self._lent_since.pop(id(buf), None)
         self._ring(self._host_kind).release(buf)
 
     def submit_staged(self, buf: np.ndarray, k: int) -> StreamTicket:
@@ -795,6 +824,10 @@ class StreamingAggregator:
             BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
             ticket = StreamTicket(k)
             self._batch_seq += 1
+        with self._lock:
+            lent = self._lent_since.pop(id(buf), None)
+        if lent is not None:
+            self._leg(lent, "stage")  # the buffer in hand -> handed over
         self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
         return ticket
 
@@ -804,7 +837,6 @@ class StreamingAggregator:
         with trace.get_tracer().span(
             SPAN_STAGE, batch=self._batch_seq + 1, kind=self._host_kind, k=k, route="flush"
         ):
-            t0 = time.monotonic()
             buf = self.open_batch()
             try:
                 fill(buf[:k])
@@ -812,8 +844,6 @@ class StreamingAggregator:
                 self.release_batch(buf)
                 raise
             ROWS_STAGED.labels(route="flush").inc(k)
-            with self._lock:
-                self._stage_seconds += time.monotonic() - t0
         return self.submit_staged(buf, k)
 
     def submit_batch(self, stack: np.ndarray) -> StreamTicket:
@@ -1011,8 +1041,7 @@ class StreamingAggregator:
             BYTES_STAGED.labels(layout="wire").inc(view.nbytes)
             ROWS_STAGED.labels(route="flush").inc(k)
             ticket = StreamTicket(k)
-            with self._lock:
-                self._stage_seconds += time.monotonic() - t0
+            self._leg(t0, "stage")
         if self._sharded:
             return self._dispatch_sharded_wire(ring, buf, view, k, ticket)
         self._batch_seq += 1
@@ -1177,8 +1206,7 @@ class StreamingAggregator:
                 self._slot_release()
                 if buf is not None:
                     self._ring(kind).release(buf)
-                with self._lock:
-                    self._fold_seconds += time.monotonic() - agg_t0
+                self._leg(agg_t0, "fold")
                 INFLIGHT_FOLDS.dec()
                 # a failed fold is NOT folded: dashboards comparing staged vs
                 # folded must be able to see the loss
@@ -1268,27 +1296,25 @@ class StreamingAggregator:
         self._publish_overlap()
         return accepted
 
+    def _leg(self, start: float, *keys) -> None:
+        """A leg that began at ``start`` ends now: kept under each key for
+        the drain window's overlap ratio."""
+        leg = (start, time.monotonic())
+        with self._lock:
+            for key in keys:
+                self._legs.setdefault(key, []).append(leg)
+
     def _publish_overlap(self) -> None:
-        if self._window_start is None:
-            return
-        wall = max(time.monotonic() - self._window_start, 1e-9)
         with self._lock:  # the drain barrier already quiesced the workers
-            shorter = min(self._stage_seconds, self._fold_seconds)
-            if shorter > 0:
-                overlap = (self._stage_seconds + self._fold_seconds - wall) / shorter
-                OVERLAP_RATIO.set(max(0.0, min(1.0, overlap)))
-            if self._sharded:
-                for d in range(self._n_shards):
-                    s, f = self._shard_stage_seconds[d], self._shard_fold_seconds[d]
-                    sh = min(s, f)
-                    if sh > 0:
-                        ov = (s + f - wall) / sh
-                        SHARD_OVERLAP.labels(shard=str(d)).set(max(0.0, min(1.0, ov)))
-                    self._shard_stage_seconds[d] = 0.0
-                    self._shard_fold_seconds[d] = 0.0
-            self._stage_seconds = 0.0
-            self._fold_seconds = 0.0
-        self._window_start = None
+            legs, self._legs = self._legs, {}
+        ratio = _overlap_ratio(legs.get("stage", ()), legs.get("fold", ()))
+        if ratio is not None:
+            OVERLAP_RATIO.set(ratio)
+        if self._sharded:
+            for d in range(self._n_shards):
+                ratio = _overlap_ratio(legs.get(("stage", d), ()), legs.get(("fold", d), ()))
+                if ratio is not None:
+                    SHARD_OVERLAP.labels(shard=str(d)).set(ratio)
 
     # -- shard-parallel mode ----------------------------------------------
     #
@@ -1433,9 +1459,7 @@ class StreamingAggregator:
                 layout="packed" if self._packed else "unpacked"
             ).inc(view.nbytes)
             dt = time.monotonic() - t0
-            with self._lock:
-                self._stage_seconds += dt
-                self._shard_stage_seconds[d] += dt
+            self._leg(t0, "stage", ("stage", d))
             trace.get_tracer().record_span(
                 SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
             )
@@ -1471,9 +1495,7 @@ class StreamingAggregator:
                 layout="packed" if self._packed else "unpacked"
             ).inc(view.nbytes)
             dt = time.monotonic() - t0
-            with self._lock:
-                self._stage_seconds += dt
-                self._shard_stage_seconds[d] += dt
+            self._leg(t0, "stage", ("stage", d))
             trace.get_tracer().record_span(
                 SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
             )
@@ -1536,8 +1558,7 @@ class StreamingAggregator:
             for i, (_jb, _d, _p, ring, buf) in enumerate(items):
                 if not released[i] and ring is not None:
                     ring.release(buf)
-            with self._lock:
-                self._fold_seconds += time.monotonic() - t0
+            self._leg(t0, "fold")
         BATCHES_TOTAL.labels(stage="folded").inc()
 
     def _dispatch_sharded_wire(
@@ -1728,13 +1749,9 @@ class StreamingAggregator:
                 finally:
                     if ring is not None:
                         ring.release(buf)
-                    dt = time.monotonic() - t0
-                    with self._lock:
-                        self._shard_fold_seconds[d] += dt
-                        # D workers run concurrently: credit the global fold
-                        # leg 1/D of each worker's wall so the overlap ratio
-                        # keeps its single-pipeline meaning
-                        self._fold_seconds += dt / self._n_shards
+                    # D workers run concurrently: the global fold leg is
+                    # the union of their intervals
+                    self._leg(t0, "fold", ("fold", d))
                     SHARD_INFLIGHT.labels(shard=str(d)).dec()
                     fold_span.set(outcome="failed" if failed else "folded")
         finally:

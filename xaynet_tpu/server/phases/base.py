@@ -63,6 +63,13 @@ PHASE_OUTCOMES = get_registry().counter(
     ("phase", "outcome"),
 )
 
+ACCEPT_GAP_MAX = get_registry().gauge(
+    "xaynet_update_accept_gap_max_seconds",
+    "Longest time between two consecutive accepted updates of the current "
+    "(or last) Update phase: a fold batch's boundary as the senders feel "
+    "it. 0 from the phase's opening until its second accepted update.",
+)
+
 # one span name per phase — spelled out (not built in a loop) so the
 # analysis `span` pass can cross-check the literal set against the DESIGN
 # §16 span table exactly like the metrics table
@@ -217,6 +224,9 @@ class PhaseState:
     # would make a resumed 100-participant round look like a 5-participant
     # deployment to the adaptive shrink clamp)
     arrivals_offset: int = 0
+    # the Update window's last accepted request and its longest gap so far
+    _last_accept: Optional[float] = None
+    _accept_gap_max: float = 0.0
 
     def __init__(self, shared: Shared):
         self.shared = shared
@@ -343,6 +353,9 @@ class PhaseState:
             params.count.max,
             getattr(params.count, "effective_quorum", None),
         )
+        if self.NAME is PhaseName.UPDATE:
+            self._last_accept, self._accept_gap_max = None, 0.0
+            ACCEPT_GAP_MAX.set(0.0)
         logger.debug(
             "processing requests for min %.1fs / max %.1fs (count %d..%d, quorum %d)",
             params.time.min,
@@ -558,6 +571,7 @@ class PhaseState:
             raise
         counter.accepted += 1
         self._record_handled(t0)
+        self._note_accept_gap()
         if self.shared.metrics is not None:
             self.shared.metrics.message_accepted(self.shared.round_id, self.NAME.value)
         self._respond(env, None)
@@ -616,10 +630,22 @@ class PhaseState:
             raise
         counter.accepted += k
         self._record_handled(t0)
+        self._note_accept_gap()
         if self.shared.metrics is not None:
             for _ in range(k):  # dashboards count UPDATES, not envelopes
                 self.shared.metrics.message_accepted(self.shared.round_id, self.NAME.value)
         self._respond(env, None)
+
+    def _note_accept_gap(self) -> None:
+        """An Update request (or envelope) was just counted accepted: keep
+        the longest gap since the one before it on the gauge."""
+        if self.NAME is not PhaseName.UPDATE:
+            return
+        now = time_mod.monotonic()
+        if self._last_accept is not None and now - self._last_accept > self._accept_gap_max:
+            self._accept_gap_max = now - self._last_accept
+            ACCEPT_GAP_MAX.set(self._accept_gap_max)
+        self._last_accept = now
 
     def _record_handled(self, t0: float) -> None:
         """Per-request handler latency; registry-only (the bridge implements

@@ -115,7 +115,7 @@ _TOP_K = 5
 _SPARK_WINDOW = 64
 
 
-def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
+def merge_intervals(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     """Sorted, disjoint union of (start, end) intervals."""
     merged: list[tuple[float, float]] = []
     for start, end in sorted(intervals):
@@ -126,11 +126,12 @@ def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return merged
 
 
-def _measure(merged: list[tuple[float, float]]) -> float:
+def measure(merged: list[tuple[float, float]]) -> float:
+    """Summed length of disjoint intervals."""
     return sum(end - start for start, end in merged)
 
 
-def _intersection(
+def intersection(
     a: list[tuple[float, float]], b: list[tuple[float, float]]
 ) -> float:
     """Measure of the intersection of two disjoint-sorted interval lists."""
@@ -207,7 +208,7 @@ def fold_spans(round_id: int, spans: list) -> Optional[dict]:
             phase_iv.setdefault(phase, []).append((span.start, end))
     if root is None and not phase_iv:
         return None
-    merged = {p: _merge(iv) for p, iv in phase_iv.items()}
+    merged = {p: merge_intervals(iv) for p, iv in phase_iv.items()}
     # bracket: Idle-close -> Unmask-complete; a round that died before
     # unmask (or a buffer that lost idle to the cap) falls back to the
     # edges the buffer still has, and an empty decomposition falls back to
@@ -235,21 +236,21 @@ def fold_spans(round_id: int, spans: list) -> Optional[dict]:
         for p, iv in merged.items()
     }
     clipped = {p: iv for p, iv in clipped.items() if iv}
-    union = _merge([pair for iv in clipped.values() for pair in iv])
-    union_s = _measure(union)
+    union = merge_intervals([pair for iv in clipped.values() for pair in iv])
+    union_s = measure(union)
     phases: dict[str, dict[str, float]] = {}
     total_phase_wall = 0.0
     for p in _WORK_PHASES:
         iv = clipped.get(p)
         if not iv:
             continue
-        p_wall = _measure(iv)
-        others = _merge(
+        p_wall = measure(iv)
+        others = merge_intervals(
             [pair for q, oiv in clipped.items() if q != p for pair in oiv]
         )
         phases[p] = {
             "wall_s": round(p_wall, 6),
-            "self_s": round(p_wall - _intersection(iv, others), 6),
+            "self_s": round(p_wall - intersection(iv, others), 6),
         }
         total_phase_wall += p_wall
     overlap = max(0.0, total_phase_wall - union_s)

@@ -38,8 +38,26 @@ def _build() -> bool:
             )
             return True
         except Exception as e:  # retry without SIMD flags, then give up
-            logger.debug("native build failed (%s): %s", args, e)
+            failure = f"{e}: {(getattr(e, 'stderr', None) or b'')[-400:]!r}"
+            logger.debug("native build failed (%s): %s", args, failure)
+    # said once, aloud: everything falls back to numpy and Python from here
+    logger.warning("native library not built, using the fallbacks: %s", failure)
     return False
+
+
+def ensure_built() -> None:
+    """Build the library where it is missing or older than its source.
+    Processes that may get here at the same time (test workers) take a lock
+    of their own around the call: ``make`` writes the file in place."""
+    if not os.path.isdir(_NATIVE_DIR):
+        return
+    src = os.path.join(_NATIVE_DIR, "xaynet_native.cpp")
+    stale = os.path.exists(src) and (
+        not os.path.exists(_LIB_PATH)
+        or os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
+    )
+    if stale:
+        _build()
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -53,14 +71,7 @@ def load() -> Optional[ctypes.CDLL]:
     # rebuild BEFORE the first dlopen: once a (stale) library is loaded,
     # re-dlopening the same path returns the already-loaded image, so the
     # staleness check must be mtime-based, not load-and-inspect
-    if os.path.isdir(_NATIVE_DIR):
-        src = os.path.join(_NATIVE_DIR, "xaynet_native.cpp")
-        stale = os.path.exists(src) and (
-            not os.path.exists(_LIB_PATH)
-            or os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
-        )
-        if stale:
-            _build()
+    ensure_built()
     if not os.path.exists(_LIB_PATH):
         return None
     try:
