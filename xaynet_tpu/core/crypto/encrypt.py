@@ -51,6 +51,15 @@ class DecryptError(ValueError):
     """Sealed box could not be opened."""
 
 
+def _agree(secret: bytes, public: bytes) -> bytes:
+    """X25519 of our secret key and their public key."""
+    if not _HAVE_CRYPTO:
+        return _purecrypto.x25519(secret, public)
+    return X25519PrivateKey.from_private_bytes(secret).exchange(
+        X25519PublicKey.from_public_bytes(public)
+    )
+
+
 def _derive_key(shared: bytes, eph_pk: bytes, recipient_pk: bytes) -> bytes:
     info = b"xaynet-tpu-sealedbox" + eph_pk + recipient_pk
     if not _HAVE_CRYPTO:
@@ -72,18 +81,40 @@ class PublicEncryptKey:
 
     def encrypt(self, message: bytes) -> bytes:
         """Seal ``message`` for this public key (anyone can seal)."""
+        box = native.uninitialised_bytearray(None, len(message) + SEALBYTES)
+        box[PUBLIC_KEY_LENGTH : len(box) - unlocked.TAG_LENGTH] = message
+        self.encrypt_in_place(box)
+        return bytes(box)
+
+    def encrypt_in_place(self, box: "bytearray | memoryview") -> str:
+        """:meth:`encrypt` for a caller that composed the message where the
+        box will be: ``box`` holds the plaintext at ``[32 : len(box) - 16]``
+        and leaves as the sealed box (ephemeral key in front, tag behind),
+        with no second buffer of the message's size. A long message is
+        sealed by a foreign call with the interpreter lock released
+        (``unlocked``), a short one by the wheel. Returns the route taken
+        (``unlocked.choose``'s, for the caller's span)."""
+        if len(box) < SEALBYTES:
+            raise ValueError("no room for the ephemeral key and the tag")
+        view = memoryview(box)
+        if view.readonly:
+            raise TypeError("encrypt_in_place needs a writable buffer")
+        ephemeral = EncryptKeyPair.generate()
+        eph_pk = ephemeral.public.as_bytes()
+        key = _derive_key(_agree(ephemeral.secret.as_bytes(), self.bytes_), eph_pk, self.bytes_)
+        view[:PUBLIC_KEY_LENGTH] = eph_pk
+        sealed = view[PUBLIC_KEY_LENGTH:]
+        plain = sealed[: len(sealed) - unlocked.TAG_LENGTH]
+        # one algorithm, routed by the box's length (unlocked.UNLOCKED_MIN)
+        if unlocked.choose("seal", len(sealed)):
+            if not unlocked.seal_into(key, _ZERO_NONCE, plain, sealed):
+                raise RuntimeError("libcrypto refused to seal")
+            return "unlocked"
         if _HAVE_CRYPTO:
-            eph_sk = X25519PrivateKey.generate()
-            eph_pk = eph_sk.public_key().public_bytes_raw()
-            shared = eph_sk.exchange(X25519PublicKey.from_public_bytes(self.bytes_))
-            key = _derive_key(shared, eph_pk, self.bytes_)
-            ct = ChaCha20Poly1305(key).encrypt(_ZERO_NONCE, message, None)
-            return eph_pk + ct
-        eph_seed = os.urandom(32)
-        eph_pk = _purecrypto.x25519_public(eph_seed)
-        shared = _purecrypto.x25519(eph_seed, self.bytes_)
-        key = _derive_key(shared, eph_pk, self.bytes_)
-        return eph_pk + _purecrypto.chacha20poly1305_encrypt(key, _ZERO_NONCE, message)
+            sealed[:] = ChaCha20Poly1305(key).encrypt(_ZERO_NONCE, plain, None)
+        else:
+            sealed[:] = _purecrypto.chacha20poly1305_encrypt(key, _ZERO_NONCE, bytes(plain))
+        return "wheel"
 
 
 @dataclass(frozen=True)
@@ -136,12 +167,7 @@ class SecretEncryptKey:
         my_pk = pk.as_bytes() if pk is not None else self.public_key().as_bytes()
         view = memoryview(sealed)
         eph_pk, ct = bytes(view[:32]), view[32:]
-        if _HAVE_CRYPTO:
-            sk = X25519PrivateKey.from_private_bytes(self.bytes_)
-            shared = sk.exchange(X25519PublicKey.from_public_bytes(eph_pk))
-        else:
-            shared = _purecrypto.x25519(self.bytes_, eph_pk)
-        key = _derive_key(shared, eph_pk, my_pk)
+        key = _derive_key(_agree(self.bytes_, eph_pk), eph_pk, my_pk)
         # one algorithm, routed by the box's length (unlocked.UNLOCKED_MIN)
         if unlocked.choose("open", len(ct)):
             size = len(ct) - unlocked.TAG_LENGTH

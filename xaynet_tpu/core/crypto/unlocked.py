@@ -1,4 +1,4 @@
-"""ChaCha20-Poly1305 open and Ed25519 verify as foreign calls.
+"""ChaCha20-Poly1305 open and seal and Ed25519 verify as foreign calls.
 
 The ``cryptography`` wheel keeps the interpreter lock for its whole pass
 over a buffer: while one ``pet-msg`` worker opens or verifies a 179 MB
@@ -18,6 +18,12 @@ Below ``UNLOCKED_MIN`` bytes a call here costs more than it frees (a
 wheel's few, and a thread that gave the lock up waits a switch interval to
 get it back), so callers keep the wheel there: seed boxes, Sum messages,
 task signatures, multipart chunks.
+
+The seal is the open's twin for the sending side (a participant's Update or
+Sum2 message, 179 MB and up): in place, so the message is sealed in the
+buffer it was composed in and sent from, where the wheel's ``encrypt``
+returns a fresh buffer of the message's length and the caller needs a
+second one to put the ephemeral key in front.
 """
 
 from __future__ import annotations
@@ -48,26 +54,28 @@ TAG_LENGTH = 16
 
 BYTES = get_registry().counter(
     "xaynet_crypto_bytes_total",
-    "Bytes through a sealed-box open (the box) or an Ed25519 verify (the "
-    "signed bytes), by the route chosen for them: unlocked = a foreign call "
+    "Bytes through a sealed-box open or seal (the box) or an Ed25519 verify "
+    "(the signed bytes), by the route chosen for them: unlocked = a foreign call "
     "into the system's libcrypto with the interpreter lock released, wheel = "
     "the cryptography wheel (or the pure-Python stand-in), which holds the "
     "lock: short inputs, and every input where no library loads.",
     ("op", "route"),
 )
 
+_EVP_CTRL_AEAD_GET_TAG = 0x10
 _EVP_CTRL_AEAD_SET_TAG = 0x11
 _EVP_PKEY_ED25519 = 1087
-# EVP_DecryptUpdate takes an int length and a body reaches 1 << 32
+# EVP_DecryptUpdate and EVP_EncryptUpdate take an int length and a body
+# reaches 1 << 32
 _PIECE = 1 << 30
 
 
 class _Lib:
-    """The calls used here. The two that pass over the data are bound
+    """The calls used here. The three that pass over the data are bound
     through ``ctypes.CDLL``, which releases the interpreter lock around a
     call; the others (contexts, key, tag: microseconds) through
-    ``ctypes.PyDLL``, which keeps it, so an open or a verify gives the lock
-    up once and not seven times: a thread that gives it up beside a busy one
+    ``ctypes.PyDLL``, which keeps it, so an open, a seal or a verify gives
+    the lock up once and not seven times: a thread that gives it up beside a busy one
     waits a switch interval (5 ms) to get it back."""
 
     def __init__(self, soname: str):
@@ -81,6 +89,9 @@ class _Lib:
             (unlocked, "EVP_DecryptUpdate", i, [vp, vp, ctypes.POINTER(i), vp, i]),
             (held, "EVP_CIPHER_CTX_ctrl", i, [vp, i, i, vp]),
             (held, "EVP_DecryptFinal_ex", i, [vp, vp, ctypes.POINTER(i)]),
+            (held, "EVP_EncryptInit_ex", i, [vp, vp, vp, ctypes.c_char_p, ctypes.c_char_p]),
+            (unlocked, "EVP_EncryptUpdate", i, [vp, vp, ctypes.POINTER(i), vp, i]),
+            (held, "EVP_EncryptFinal_ex", i, [vp, vp, ctypes.POINTER(i)]),
             (held, "EVP_PKEY_new_raw_public_key", vp, [i, vp, ctypes.c_char_p, sz]),
             (held, "EVP_PKEY_free", None, [vp]),
             (held, "EVP_MD_CTX_new", vp, []),
@@ -120,7 +131,7 @@ def load() -> Optional[_Lib]:
 
 
 def choose(op: str, length: int) -> bool:
-    """Choose the route of one ``op`` ("open" or "verify") over ``length``
+    """Choose the route of one ``op`` ("open", "seal" or "verify") over ``length``
     bytes and count them on it: True for the foreign call."""
     foreign = length >= UNLOCKED_MIN and load() is not None
     BYTES.labels(op=op, route="unlocked" if foreign else "wheel").inc(length)
@@ -170,6 +181,42 @@ def open_into(
     return ok
 
 
+def seal_into(
+    key: bytes, nonce: bytes, plain, out, aad: bytes = b"", lib: Optional[_Lib] = None
+) -> bool:
+    """ChaCha20-Poly1305-IETF seal of ``plain`` into ``out[: len(plain) + 16]``
+    (ciphertext ‖ 16-byte tag); ``out`` may begin where ``plain`` does (in
+    place: the tag is written behind the ciphertext). False where the
+    library refuses, and ``out`` then holds nothing a caller may send. One
+    pass, the lock released. A sealed box has no associated data; ``aad`` is
+    there for the RFC's vectors."""
+    lib = lib or load()
+    src, pin_src = _address(plain)
+    dst, pin_dst = _address(out)
+    n = pin_src.size
+    if pin_dst.size < n + TAG_LENGTH or not pin_dst.flags.writeable:
+        raise ValueError("no writable room for the ciphertext and its tag")
+    ctx = lib.EVP_CIPHER_CTX_new()
+    try:
+        ok = lib.EVP_EncryptInit_ex(ctx, lib.EVP_chacha20_poly1305(), None, key, nonce) == 1
+        done, outl = 0, ctypes.c_int(0)
+        if ok and aad:
+            ok = lib.EVP_EncryptUpdate(ctx, None, ctypes.byref(outl), aad, len(aad)) == 1
+        while ok and done < n:
+            take = min(_PIECE, n - done)
+            ok = lib.EVP_EncryptUpdate(ctx, dst + done, ctypes.byref(outl), src + done, take) == 1
+            done += take
+        ok = ok and lib.EVP_EncryptFinal_ex(ctx, dst + n, ctypes.byref(outl)) == 1
+        ok = ok and lib.EVP_CIPHER_CTX_ctrl(
+            ctx, _EVP_CTRL_AEAD_GET_TAG, TAG_LENGTH, dst + n
+        ) == 1
+    finally:
+        lib.EVP_CIPHER_CTX_free(ctx)
+    if not ok:
+        lib.ERR_clear_error()
+    return ok
+
+
 def ed25519_verify(public: bytes, signature: bytes, data, lib: Optional[_Lib] = None) -> bool:
     """Ed25519 verify of ``signature`` over ``data`` (any contiguous
     buffer), the pass over ``data`` with the lock released."""
@@ -194,8 +241,9 @@ def ed25519_verify(public: bytes, signature: bytes, data, lib: Optional[_Lib] = 
 
 
 def _answers(lib: _Lib) -> bool:
-    """Whether ``lib`` opens a known box and verifies a known signature
-    (RFC 8032 section 7.1, TEST 1), and refuses both once damaged."""
+    """Whether ``lib`` opens a known box, seals a known one (RFC 8439
+    section 2.8.2) and verifies a known signature (RFC 8032 section 7.1,
+    TEST 1), and refuses box and signature once damaged."""
     key, nonce = bytes(range(32)), bytes(12)
     box = bytes.fromhex("60d93b5fc8927b847dc08860b4c9956ea82b48a0c247")
     public = bytes.fromhex("d75a980182b10ab7d54bfed3c964073a0ee172f3daa62325af021a68f707511a")
@@ -203,11 +251,27 @@ def _answers(lib: _Lib) -> bool:
         "e5564300c360ac729086e2cc806e828a84877f1eb8e5d974d873e06522490155"
         "5fb8821590a33bacc61e39701cf9b46bd25bf5f0595bbe24655141438e7a100b"
     )
-    out = bytearray(6)
+    rfc_plain = (
+        b"Ladies and Gentlemen of the class of '99: If I could offer you "
+        b"only one tip for the future, sunscreen would be it."
+    )
+    rfc_box = bytes.fromhex(
+        "d31a8d34648e60db7b86afbc53ef7ec2a4aded51296e08fea9e2b5a736ee62d6"
+        "3dbea45e8ca9671282fafb69da92728b1a71de0a9e060b2905d6a5b67ecd3b36"
+        "92ddbd7f2d778b8c9803aee328091b58fab324e4fad675945585808b4831d7bc"
+        "3ff4def08e4b7a9de576d26586cec64b6116"
+        "1ae10b594f09e26a7e902ecbd0600691"
+    )
+    out, sealed = bytearray(6), bytearray(len(rfc_box))
     return (
         open_into(key, nonce, box, out, lib=lib)
         and bytes(out) == b"xaynet"
         and not open_into(key, nonce, box[:-1] + b"\x00", out, lib=lib)
+        and seal_into(
+            bytes(range(0x80, 0xA0)), bytes.fromhex("070000004041424344454647"),
+            rfc_plain, sealed, aad=bytes.fromhex("50515253c0c1c2c3c4c5c6c7"), lib=lib,
+        )
+        and bytes(sealed) == rfc_box
         and ed25519_verify(public, signature, b"", lib=lib)
         and not ed25519_verify(public, signature, b"x", lib=lib)
     )

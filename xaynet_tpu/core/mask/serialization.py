@@ -29,6 +29,7 @@ import struct
 import numpy as np
 
 from ...ops import limbs as limb_ops
+from ...utils import native
 from .config import MASK_CONFIG_LENGTH, MaskConfig
 from .object import MaskObject, MaskUnit, MaskVect
 
@@ -86,28 +87,47 @@ def vect_element_block(wire: bytes) -> np.ndarray:
     return np.frombuffer(wire, dtype=np.uint8)[VECT_HEADER_LENGTH:]
 
 
-def serialize_mask_vect(vect: MaskVect, planar: bool = False) -> bytes:
-    bpn = vect.config.bytes_per_number
-    if planar:
-        from .object import LazyWireMaskVect
+def compose_buffer(length: int, write) -> bytearray:
+    """One buffer of ``length`` bytes (no zero fill) filled by
+    ``write(buf, 0) -> end``, which has to end at ``length``: a serialiser
+    that disagrees with its ``serialized_length()`` would leave bytes of the
+    buffer unwritten."""
+    buf = native.uninitialised_bytearray(None, length)
+    # a view cannot be resized by a slice assignment of another length
+    if write(memoryview(buf), 0) != length:
+        raise ValueError("serialized length disagrees with the serialiser")
+    return buf
 
-        if isinstance(vect, LazyWireMaskVect) and vect.planar and not vect.materialized:
-            # parsed-from-planar-wire and never touched: re-emit the block
-            block = np.asarray(vect.wire_block).tobytes()
-        else:
-            interleaved = limb_ops.limbs_to_bytes_le(vect.data, bpn)
-            block = np.ascontiguousarray(
-                np.frombuffer(interleaved, dtype=np.uint8).reshape(len(vect), bpn).T
-            ).tobytes()
-        return (
-            vect.config.to_bytes()
-            + struct.pack(">I", len(vect) | WIRE_PLANAR_FLAG)
-            + block
-        )
-    return (
-        vect.config.to_bytes()
-        + struct.pack(">I", len(vect))
-        + limb_ops.limbs_to_bytes_le(vect.data, bpn)
+
+def compose_bytes(length: int, write) -> bytes:
+    """``to_bytes`` in terms of the write-into form."""
+    return bytes(compose_buffer(length, write))
+
+
+def write_mask_vect(vect: MaskVect, buf, offset: int, planar: bool = False) -> int:
+    """Serialise ``vect`` into the writable buffer ``buf`` at ``offset``;
+    returns the offset behind it. The element block goes from the limbs to
+    its place in ``buf`` in one pass (``limbs_into_wire``)."""
+    bpn, count = vect.config.bytes_per_number, len(vect)
+    start = offset + VECT_HEADER_LENGTH
+    end = start + count * bpn
+    buf[offset : offset + MASK_CONFIG_LENGTH] = vect.config.to_bytes()
+    struct.pack_into(
+        ">I", buf, offset + MASK_CONFIG_LENGTH, count | WIRE_PLANAR_FLAG if planar else count
+    )
+    block = np.frombuffer(buf, dtype=np.uint8, count=count * bpn, offset=start)
+    if planar and getattr(vect, "planar", False) and not vect.materialized:
+        # parsed-from-planar-wire and never touched: re-emit the block
+        block[...] = np.asarray(vect.wire_block)
+    else:
+        limb_ops.limbs_into_wire(vect.data, bpn, block, planar=planar)
+    return end
+
+
+def serialize_mask_vect(vect: MaskVect, planar: bool = False) -> bytes:
+    return compose_bytes(
+        serialized_vect_length(vect.config, len(vect)),
+        lambda buf, offset: write_mask_vect(vect, buf, offset, planar=planar),
     )
 
 
@@ -148,9 +168,21 @@ def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[M
     return vect, end - offset
 
 
-def serialize_mask_unit(unit: MaskUnit) -> bytes:
+def write_mask_unit(unit: MaskUnit, buf, offset: int) -> int:
     bpn = unit.config.bytes_per_number
-    return unit.config.to_bytes() + limb_ops.limbs_to_bytes_le(unit.data[None, :], bpn)
+    start = offset + MASK_CONFIG_LENGTH
+    buf[offset:start] = unit.config.to_bytes()
+    limb_ops.limbs_into_wire(
+        unit.data[None, :], bpn, np.frombuffer(buf, dtype=np.uint8, count=bpn, offset=start)
+    )
+    return start + bpn
+
+
+def serialize_mask_unit(unit: MaskUnit) -> bytes:
+    return compose_bytes(
+        MASK_CONFIG_LENGTH + unit.config.bytes_per_number,
+        lambda buf, offset: write_mask_unit(unit, buf, offset),
+    )
 
 
 def parse_mask_unit(data: bytes, offset: int = 0) -> tuple[MaskUnit, int]:
@@ -249,10 +281,18 @@ def parse_mask_unit_stream(reader) -> MaskUnit:
     return unit
 
 
-def serialize_mask_object(obj: MaskObject, planar_vect: bool = False) -> bytes:
+def write_mask_object(obj: MaskObject, buf, offset: int, planar_vect: bool = False) -> int:
     """``planar_vect`` emits the VECTOR part in the v2 byte-planar layout
     (the unit part is one element — planes would be a no-op relabel)."""
-    return serialize_mask_vect(obj.vect, planar=planar_vect) + serialize_mask_unit(obj.unit)
+    offset = write_mask_vect(obj.vect, buf, offset, planar=planar_vect)
+    return write_mask_unit(obj.unit, buf, offset)
+
+
+def serialize_mask_object(obj: MaskObject, planar_vect: bool = False) -> bytes:
+    return compose_bytes(
+        serialized_object_length(obj.config, len(obj)),
+        lambda buf, offset: write_mask_object(obj, buf, offset, planar_vect=planar_vect),
+    )
 
 
 def parse_mask_object(

@@ -22,8 +22,14 @@ from typing import Iterator
 
 import numpy as np
 
+from ...telemetry import tracing as trace
+from ..mask.serialization import compose_buffer, compose_bytes
 from .message import HEADER_LENGTH, Message
 from .payloads import CHUNK_HEADER_LENGTH, Chunk
+
+# the two passes of composing a part, under the sender's ``message.compose``
+SPAN_SERIALISE = trace.declare_span("message.serialise")
+SPAN_SIGN = trace.declare_span("message.sign")
 
 # minimum sensible ceiling: header + chunk header + 1 byte of progress
 # (reference: rust/xaynet-sdk/src/settings/max_message_size.rs:4-80)
@@ -42,9 +48,11 @@ MAX_CHUNKS = 0xFFFF
 class MessageEncoder:
     """Encodes (and signs) a message, chunking it when oversized.
 
-    Parts are produced ON DEMAND (``part(i)``): a paused/retried multipart
-    send holds one payload copy plus the index, never the full list of
-    signed+sealed parts.
+    Parts are produced ON DEMAND (``part(i)``, or ``write_part`` into the
+    caller's buffer): a paused/retried multipart send holds one payload copy
+    plus the index, never the full list of signed+sealed parts; a message
+    that goes out in one part holds no copy at all: its length comes from
+    ``serialized_length()`` and it is serialised where it is sent from.
     """
 
     def __init__(
@@ -57,49 +65,83 @@ class MessageEncoder:
         self.message = message
         self.secret_signing_key = secret_signing_key
         self.max_message_size = max_message_size
-        self._payload_bytes = message.payload.to_bytes()
-        if (
-            max_message_size is None
-            or HEADER_LENGTH + len(self._payload_bytes) <= max_message_size
-        ):
+        self._payload_length = message.payload_length()
+        # a multipart send's payload, serialised once when first needed
+        self._payload: bytearray | None = None
+        if max_message_size is None or HEADER_LENGTH + self._payload_length <= max_message_size:
             self._budget = None
             self.n_parts = 1
         else:
             self._budget = max(max_message_size - HEADER_LENGTH - CHUNK_HEADER_LENGTH, 1)
-            self.n_parts = -(-len(self._payload_bytes) // self._budget)
+            self.n_parts = -(-self._payload_length // self._budget)
             if self.n_parts > MAX_CHUNKS:
                 # the u16 chunk id cannot address more parts; wrapping would
                 # corrupt reassembly silently — refuse loudly instead
                 raise ValueError(
                     f"payload needs {self.n_parts} chunks but the wire chunk id "
                     f"is u16 (max {MAX_CHUNKS}); raise max_message_size "
-                    f"(>= {HEADER_LENGTH + CHUNK_HEADER_LENGTH + -(-len(self._payload_bytes) // MAX_CHUNKS)})"
+                    f"(>= {HEADER_LENGTH + CHUNK_HEADER_LENGTH + -(-self._payload_length // MAX_CHUNKS)})"
                 )
             self.message_id = (
                 message_id if message_id is not None else struct.unpack(">H", os.urandom(2))[0]
             )
 
-    def part(self, i: int) -> bytes:
-        """The ``i``-th signed wire part (0-based)."""
+    def payload_bytes(self) -> "bytes | bytearray":
+        """The payload's wire bytes (what ``StateMachine.save`` keeps of a
+        send in flight): a multipart send's one retained buffer, which its
+        chunks are views of; serialised from the message anew for a one-part
+        send, which retains none."""
+        if self._budget is None:
+            return self.message.payload.to_bytes()
+        if self._payload is None:
+            self._payload = compose_buffer(self._payload_length, self.message.payload.write_into)
+        return self._payload
+
+    def _part(self, i: int) -> Message:
         if not 0 <= i < self.n_parts:
             raise IndexError(i)
         if self._budget is None:
-            return self.message.to_bytes(self.secret_signing_key)
+            return self.message
         chunk = Chunk(
             id=i + 1,
             message_id=self.message_id,
             last=(i == self.n_parts - 1),
-            data=self._payload_bytes[i * self._budget : (i + 1) * self._budget],
+            data=memoryview(self.payload_bytes())[i * self._budget : (i + 1) * self._budget],
             tag=self.message.tag,
         )
-        part = Message(
+        return Message(
             participant_pk=self.message.participant_pk,
             coordinator_pk=self.message.coordinator_pk,
             payload=chunk,
             tag=self.message.tag,
             is_multipart=True,
         )
-        return part.to_bytes(self.secret_signing_key)
+
+    def part_length(self, i: int) -> int:
+        """The length of the ``i``-th wire part, from lengths alone."""
+        if not 0 <= i < self.n_parts:
+            raise IndexError(i)
+        if self._budget is None:
+            return HEADER_LENGTH + self._payload_length
+        rest = self._payload_length - i * self._budget
+        return HEADER_LENGTH + CHUNK_HEADER_LENGTH + min(self._budget, rest)
+
+    def write_part(self, i: int, buf, offset: int = 0) -> int:
+        """Serialise and sign the ``i``-th wire part (0-based) into the
+        writable buffer ``buf`` at ``offset``; returns the offset behind it."""
+        part = self._part(i)
+        tracer = trace.get_tracer()
+        with tracer.span(SPAN_SERIALISE):
+            end = part.write_into(buf, offset)
+        with tracer.span(SPAN_SIGN):
+            Message.sign_into(buf, offset, end, self.secret_signing_key)
+        return end
+
+    def part(self, i: int) -> bytes:
+        """The ``i``-th signed wire part (0-based)."""
+        return compose_bytes(
+            self.part_length(i), lambda buf, offset: self.write_part(i, buf, offset)
+        )
 
     def __iter__(self) -> Iterator[bytes]:
         for i in range(self.n_parts):

@@ -19,7 +19,7 @@ from dataclasses import dataclass
 from enum import IntEnum, IntFlag
 
 from ..crypto import sign as crypto_sign
-from ..mask.serialization import DecodeError
+from ..mask.serialization import DecodeError, compose_buffer
 from .payloads import Chunk, Payload, Sum, Sum2, Update, parse_payload
 
 SIGNATURE_LENGTH = 64
@@ -63,27 +63,41 @@ class Message:
     def serialized_length(self) -> int:
         return HEADER_LENGTH + self.payload_length()
 
+    def write_into(self, buf, offset: int = 0) -> int:
+        """Serialise header and payload into the writable buffer ``buf`` at
+        ``offset`` (the signature field holds ``self.signature``, or zeros
+        until :meth:`sign_into`); returns the offset behind the message. The
+        payload is written where it stays: no buffer of its own."""
+        total = self.serialized_length()
+        at = offset + SIGNATURE_LENGTH
+        # through a view: a field of another length than its slot is refused,
+        # where a ``bytearray`` would grow or shrink and shift what follows
+        view = memoryview(buf)
+        view[offset:at] = self.signature or bytes(SIGNATURE_LENGTH)
+        view[at : at + PK_LENGTH] = self.participant_pk
+        view[at + PK_LENGTH : at + 2 * PK_LENGTH] = self.coordinator_pk
+        struct.pack_into(
+            ">IBBxx", view, at + 2 * PK_LENGTH,
+            total, int(self.tag), int(Flags.MULTIPART) if self.is_multipart else 0,
+        )
+        if self.payload.write_into(view, offset + HEADER_LENGTH) != offset + total:
+            raise ValueError("payload length disagrees with its serialiser")
+        return offset + total
+
+    @staticmethod
+    def sign_into(buf, offset: int, end: int, secret_signing_key: bytes) -> None:
+        """Sign the message serialised at ``buf[offset:end]`` over a view of
+        its signed bytes (signing a 150 MB update must not copy the payload)
+        and write the signature in front."""
+        view = memoryview(buf)
+        at = offset + SIGNATURE_LENGTH
+        view[offset:at] = crypto_sign.sign_detached(secret_signing_key, view[at:end])
+
     def to_bytes(self, secret_signing_key: bytes | None = None) -> bytes:
         """Serialize; signs on serialize when a secret key is given."""
-        total = self.serialized_length()
-        buf = bytearray(total)
-        buf[SIGNATURE_LENGTH : SIGNATURE_LENGTH + PK_LENGTH] = self.participant_pk
-        buf[SIGNATURE_LENGTH + PK_LENGTH : SIGNATURE_LENGTH + 2 * PK_LENGTH] = self.coordinator_pk
-        struct.pack_into(">I", buf, SIGNATURE_LENGTH + 2 * PK_LENGTH, total)
-        buf[SIGNATURE_LENGTH + 2 * PK_LENGTH + 4] = int(self.tag)
-        buf[SIGNATURE_LENGTH + 2 * PK_LENGTH + 5] = (
-            int(Flags.MULTIPART) if self.is_multipart else 0
-        )
-        # reserved bytes stay zero
-        buf[HEADER_LENGTH:] = self.payload.to_bytes()
+        buf = compose_buffer(self.serialized_length(), self.write_into)
         if secret_signing_key is not None:
-            # memoryview: signing a 150 MB update must not copy the payload
-            sig = crypto_sign.sign_detached(
-                secret_signing_key, memoryview(buf)[SIGNATURE_LENGTH:total]
-            )
-            buf[:SIGNATURE_LENGTH] = sig
-        elif self.signature is not None:
-            buf[:SIGNATURE_LENGTH] = self.signature
+            self.sign_into(buf, 0, len(buf), secret_signing_key)
         return bytes(buf)
 
     @classmethod

@@ -23,10 +23,12 @@ from ..mask.object import MaskObject
 from ..mask.seed import ENCRYPTED_MASK_SEED_LENGTH, EncryptedMaskSeed
 from ..mask.serialization import (
     DecodeError,
+    compose_bytes,
     parse_mask_object,
     parse_mask_unit_stream,
     parse_mask_vect_stream,
-    serialize_mask_object,
+    serialized_object_length,
+    write_mask_object,
 )
 
 SIGNATURE_LENGTH = 64
@@ -56,16 +58,32 @@ def lv_decode(data: bytes, offset: int = 0) -> tuple[bytes, int]:
     return data[offset + 4 : offset + length], length
 
 
-def serialize_local_seed_dict(seed_dict: dict) -> bytes:
-    body = bytearray()
+def serialized_seed_dict_length(seed_dict: dict) -> int:
+    return 4 + SEED_DICT_ENTRY_LENGTH * len(seed_dict)
+
+
+def write_local_seed_dict(seed_dict: dict, buf, offset: int) -> int:
+    """Serialise the LV-encoded dictionary into ``buf`` at ``offset``;
+    returns the offset behind it."""
+    struct.pack_into(">I", buf, offset, serialized_seed_dict_length(seed_dict))
+    offset += 4
     for pk, seed in seed_dict.items():
         if len(pk) != PK_LENGTH:
             raise ValueError("seed dict key must be a 32-byte public key")
         seed_bytes = seed.as_bytes() if isinstance(seed, EncryptedMaskSeed) else bytes(seed)
         if len(seed_bytes) != ENCRYPTED_MASK_SEED_LENGTH:
             raise ValueError("seed dict value must be an 80-byte encrypted seed")
-        body += pk + seed_bytes
-    return lv_encode(bytes(body))
+        buf[offset : offset + PK_LENGTH] = pk
+        buf[offset + PK_LENGTH : offset + SEED_DICT_ENTRY_LENGTH] = seed_bytes
+        offset += SEED_DICT_ENTRY_LENGTH
+    return offset
+
+
+def serialize_local_seed_dict(seed_dict: dict) -> bytes:
+    return compose_bytes(
+        serialized_seed_dict_length(seed_dict),
+        lambda buf, offset: write_local_seed_dict(seed_dict, buf, offset),
+    )
 
 
 def parse_local_seed_dict(data: bytes, offset: int = 0) -> tuple[dict, int]:
@@ -97,6 +115,18 @@ def parse_local_seed_dict_stream(reader) -> dict:
 
 
 # --- payloads ---------------------------------------------------------------
+#
+# One serialiser a payload: ``write_into(buf, offset) -> end`` writes it into
+# a writable buffer (a message is composed once, in the buffer it is sealed
+# and sent from); ``to_bytes()`` is that, into a buffer of its own.
+
+
+def _write_fields(buf, offset: int, *fields) -> int:
+    """Byte fields, one behind the other."""
+    for field in fields:
+        buf[offset : offset + len(field)] = field
+        offset += len(field)
+    return offset
 
 
 @dataclass
@@ -107,8 +137,11 @@ class Sum:
     def serialized_length(self) -> int:
         return SIGNATURE_LENGTH + PK_LENGTH
 
+    def write_into(self, buf, offset: int) -> int:
+        return _write_fields(buf, offset, self.sum_signature, self.ephm_pk)
+
     def to_bytes(self) -> bytes:
-        return self.sum_signature + self.ephm_pk
+        return compose_bytes(self.serialized_length(), self.write_into)
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "Sum":
@@ -132,22 +165,21 @@ class Update:
     wire_planar: bool = False
 
     def serialized_length(self) -> int:
-        from ..mask.serialization import serialized_object_length
-
         return (
             2 * SIGNATURE_LENGTH
             + serialized_object_length(self.masked_model.config, len(self.masked_model))
-            + 4
-            + SEED_DICT_ENTRY_LENGTH * len(self.local_seed_dict)
+            + serialized_seed_dict_length(self.local_seed_dict)
         )
 
-    def to_bytes(self) -> bytes:
-        return (
-            self.sum_signature
-            + self.update_signature
-            + serialize_mask_object(self.masked_model, planar_vect=self.wire_planar)
-            + serialize_local_seed_dict(self.local_seed_dict)
+    def write_into(self, buf, offset: int) -> int:
+        offset = _write_fields(buf, offset, self.sum_signature, self.update_signature)
+        offset = write_mask_object(
+            self.masked_model, buf, offset, planar_vect=self.wire_planar
         )
+        return write_local_seed_dict(self.local_seed_dict, buf, offset)
+
+    def to_bytes(self) -> bytes:
+        return compose_bytes(self.serialized_length(), self.write_into)
 
     @classmethod
     def from_bytes(cls, data: bytes, lazy_vect: bool = False) -> "Update":
@@ -184,14 +216,16 @@ class Sum2:
     model_mask: MaskObject
 
     def serialized_length(self) -> int:
-        from ..mask.serialization import serialized_object_length
-
         return SIGNATURE_LENGTH + serialized_object_length(
             self.model_mask.config, len(self.model_mask)
         )
 
+    def write_into(self, buf, offset: int) -> int:
+        offset = _write_fields(buf, offset, self.sum_signature)
+        return write_mask_object(self.model_mask, buf, offset)
+
     def to_bytes(self) -> bytes:
-        return self.sum_signature + serialize_mask_object(self.model_mask)
+        return compose_bytes(self.serialized_length(), self.write_into)
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "Sum2":
@@ -225,11 +259,15 @@ class Chunk:
     def serialized_length(self) -> int:
         return CHUNK_HEADER_LENGTH + len(self.data)
 
-    def to_bytes(self) -> bytes:
-        return (
-            struct.pack(">HHB3x", self.id & 0xFFFF, self.message_id & 0xFFFF, 1 if self.last else 0)
-            + self.data
+    def write_into(self, buf, offset: int) -> int:
+        struct.pack_into(
+            ">HHB3x", buf, offset,
+            self.id & 0xFFFF, self.message_id & 0xFFFF, 1 if self.last else 0,
         )
+        return _write_fields(buf, offset + CHUNK_HEADER_LENGTH, self.data)
+
+    def to_bytes(self) -> bytes:
+        return compose_bytes(self.serialized_length(), self.write_into)
 
     @classmethod
     def from_bytes(cls, data: bytes, tag=None) -> "Chunk":

@@ -172,22 +172,44 @@ def bytes_le_to_limbs(
     return padded.view("<u4").astype(_U32, copy=False)
 
 
-def limbs_to_bytes_le(arr: np.ndarray, bytes_per_number: int) -> bytes:
-    """Serialize ``uint32[n, L]`` limbs as fixed-width little-endian integers."""
+def limbs_into_wire(
+    arr: np.ndarray, bytes_per_number: int, out: np.ndarray, planar: bool = False
+) -> None:
+    """Write ``uint32[n, L]`` limbs as ``n`` fixed-width little-endian
+    integers into ``out``, a writable contiguous ``uint8[n * bytes_per_number]``
+    (a view of the message being composed: the block is packed where it is
+    sent from, never in a buffer of its own). ``planar`` writes the v2
+    byte-planar layout instead: plane ``b`` holds byte ``b`` of every element.
+    """
     arr = np.ascontiguousarray(np.asarray(arr, dtype=_U32))
     n = arr.shape[0]
+    if out.dtype != np.uint8 or out.size != n * bytes_per_number or not (
+        out.flags.c_contiguous and out.flags.writeable
+    ):
+        raise ValueError("destination is not a writable contiguous uint8[n * bytes_per_number]")
+    rows = arr.astype("<u4", copy=False).view(np.uint8).reshape(n, 4 * arr.shape[1])
+    rows = rows[:, :bytes_per_number]
+    if planar:
+        # one strided pass from the limbs' own bytes: no interleaved block
+        out.reshape(bytes_per_number, n)[...] = rows.T
+        return
     from ..utils import native
 
     lib = native.load()
     # native codec assumes the wire width and limb count agree (L == ceil(bpn/4))
     if lib is not None and n > 0 and arr.shape[1] == n_limbs_for_bytes(bytes_per_number):
-        out = np.empty(n * bytes_per_number, dtype=np.uint8)
         lib.xn_limbs_to_wire(
             native.np_u32p(arr), n, bytes_per_number, arr.shape[1], native.np_u8p(out)
         )
-        return out.tobytes()
-    raw = arr.astype("<u4").view(np.uint8).reshape(n, -1)
-    return raw[:, :bytes_per_number].tobytes()
+        return
+    out.reshape(n, bytes_per_number)[...] = rows
+
+
+def limbs_to_bytes_le(arr: np.ndarray, bytes_per_number: int) -> bytes:
+    """Serialize ``uint32[n, L]`` limbs as fixed-width little-endian integers."""
+    out = np.empty(len(arr) * bytes_per_number, dtype=np.uint8)
+    limbs_into_wire(arr, bytes_per_number, out)
+    return out.tobytes()
 
 
 def lt_const(a: np.ndarray, order_limbs: np.ndarray) -> np.ndarray:

@@ -123,13 +123,17 @@ class InProcessClient(XaynetClient):
     async def get_model(self) -> Optional[np.ndarray]:
         return self.fetcher.model()
 
-    async def send_message(self, encrypted: bytes) -> None:
+    async def send_message(self, encrypted) -> None:
         """Mirrors the REST semantics: drops/rejections are swallowed
         (POST /message answers 200 regardless; clients learn outcomes from
         round progression)."""
         from ..server.requests import RequestError
         from ..server.services import ServiceError
 
+        if isinstance(encrypted, bytearray):
+            # the pipeline opens a ``bytearray`` in place, and the sender
+            # keeps its box for a retry: hand over what cannot be written to
+            encrypted = memoryview(encrypted).toreadonly()
         try:
             await self.handler.handle_message(encrypted)
         except (ServiceError, RequestError):
@@ -311,7 +315,12 @@ class HttpClient(XaynetClient):
             f"{extra}"
             f"Connection: {connection}\r\n\r\n"
         ).encode()
-        writer.write(head + (body or b""))
+        # head, then the body as the object it came as: the transport sends
+        # from it and keeps what the socket did not take as a view, where
+        # ``head + body`` would copy a 179 MB message to send it
+        writer.write(head)
+        if body:
+            writer.write(body)
         await asyncio.wait_for(writer.drain(), self.timeout)
         status_line = await asyncio.wait_for(reader.readline(), self.timeout)
         if status_line and response_begun is not None:
@@ -395,7 +404,8 @@ class HttpClient(XaynetClient):
         self._raise_for_status(status, headers, "GET /model")
         return np.frombuffer(body, dtype=np.float64)
 
-    async def send_message(self, encrypted: bytes) -> None:
+    async def send_message(self, encrypted) -> None:
+        """``encrypted`` is any contiguous buffer; it is sent from as it is."""
         status, headers, body = await self._request("POST", "/message", encrypted)
         self._raise_for_status(status, headers, f"POST /message: {body[:200]!r}")
 

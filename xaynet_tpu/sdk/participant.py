@@ -20,6 +20,7 @@ from typing import Optional, Union
 import numpy as np
 
 from ..core.crypto.sign import SigningKeyPair
+from ..telemetry import tracing as trace
 from .client import HttpClient, ResilientClient
 from .state_machine import PetSettings, StateMachine, Task, TransitionOutcome
 from .traits import ModelStore, Notify, XaynetClient
@@ -176,6 +177,8 @@ class Participant:
             client = getattr(client, "inner", None)
         if not self._loop.is_closed():
             self._loop.close()
+            # the last round's spans, where a trace directory is configured
+            trace.get_tracer().end_followed()
 
     def __del__(self):  # noqa: D105 — deterministic teardown beats GC races
         try:

@@ -26,7 +26,12 @@ from typing import Optional
 import numpy as np
 
 from ..core.common import RoundParameters
-from ..core.crypto.encrypt import EncryptKeyPair, PublicEncryptKey
+from ..core.crypto.encrypt import (
+    PUBLIC_KEY_LENGTH,
+    SEALBYTES,
+    EncryptKeyPair,
+    PublicEncryptKey,
+)
 from ..core.crypto.sign import SigningKeyPair, is_eligible
 from ..core.mask.derive_sum import derive_and_sum
 from ..core.mask.masking import Masker, check_nb_models
@@ -34,9 +39,16 @@ from ..core.mask.model import Scalar
 from ..core.mask.object import MaskObject, MaskUnit, MaskVect
 from ..core.message import Message, Sum, Sum2, Update
 from ..core.message.encoder import DEFAULT_MAX_MESSAGE_SIZE, MIN_MESSAGE_SIZE, MessageEncoder
+from ..telemetry import tracing as trace
+from ..utils import native
 from .traits import ModelStore, Notify, XaynetClient
 
 logger = logging.getLogger("xaynet.participant")
+
+# one part of a message composed for sending (serialise and sign are the
+# encoder's spans, under it); the seal is its last step
+SPAN_COMPOSE = trace.declare_span("message.compose")
+SPAN_SEAL = trace.declare_span("message.seal")
 
 
 def _is_transient_client_error(err: BaseException) -> bool:
@@ -151,20 +163,52 @@ class _RawPayload:
     def to_bytes(self) -> bytes:
         return self.raw
 
+    def write_into(self, buf, offset: int) -> int:
+        end = offset + len(self.raw)
+        buf[offset:end] = self.raw
+        return end
+
     def serialized_length(self) -> int:
         return len(self.raw)
 
 
 class _PendingSend:
-    """An in-flight multipart send: encoder + next undelivered part."""
+    """An in-flight send: encoder + next undelivered part + that part's
+    sealed box, composed once.
+
+    A part is serialised into one buffer laid out as its sealed box (32
+    bytes for the ephemeral key, the message, 16 for the tag), signed over a
+    view of it and sealed in place; the client sends that buffer. It is kept
+    until the part is through, so a part that failed transiently goes out
+    again as the same bytes, not composed again, and dropped then."""
 
     def __init__(self, encoder: MessageEncoder, coordinator_pk: bytes, next_index: int = 0):
         self.encoder = encoder
         self.coordinator_pk = PublicEncryptKey(coordinator_pk)
         self.next_index = next_index
+        self._sealed: Optional[bytearray] = None  # the box of part ``next_index``
 
-    def sealed_part(self, i: int) -> bytes:
-        return self.coordinator_pk.encrypt(self.encoder.part(i))
+    def sealed_part(self) -> bytearray:
+        """The sealed box of the next undelivered part."""
+        if self._sealed is None:
+            i = self.next_index
+            tracer = trace.get_tracer()
+            with tracer.span(SPAN_COMPOSE, part=i) as span:
+                box = native.uninitialised_bytearray(
+                    None, self.encoder.part_length(i) + SEALBYTES
+                )
+                self.encoder.write_part(i, box, PUBLIC_KEY_LENGTH)
+                with tracer.span(SPAN_SEAL, bytes=len(box)) as seal:
+                    route = self.coordinator_pk.encrypt_in_place(box)
+                    seal.set(route=route)
+                span.set(bytes=len(box), route=route)
+            self._sealed = box
+        return self._sealed
+
+    def delivered(self) -> None:
+        """The part in flight is through: let its box go, move on."""
+        self._sealed = None
+        self.next_index += 1
 
 
 class StateMachine:
@@ -197,7 +241,8 @@ class StateMachine:
         # chunk-level send retry (reference: sending.rs:96-113): the
         # in-flight multipart send is ONE payload copy plus a part index —
         # each part is signed+sealed lazily when its turn comes, so a
-        # paused 270MB send doesn't hold a second materialized part list.
+        # paused 270MB send doesn't hold a second materialized part list;
+        # a send in one part holds its one sealed box and no payload copy.
         # Delivered parts are never re-sent.
         self._pending: Optional[_PendingSend] = None
         self._after_send_phase: Optional[PhaseKind] = None
@@ -237,6 +282,9 @@ class StateMachine:
             set_round_trace = getattr(self.client, "set_round_trace", None)
             if set_round_trace is not None:
                 set_round_trace(fresh.seed.as_bytes())
+            # and, where a trace directory is configured, the participant's
+            # own spans (message.compose) into a window of that trace
+            trace.get_tracer().follow_round(trace.round_trace_id(fresh.seed.as_bytes()))
 
         if self._pending is not None:
             return await self._drain_sends()
@@ -455,7 +503,7 @@ class StateMachine:
         assert self._pending is not None
         pending = self._pending
         while pending.next_index < pending.encoder.n_parts:
-            sealed = pending.sealed_part(pending.next_index)
+            sealed = pending.sealed_part()
             try:
                 await self.client.send_message(sealed)
             except asyncio.CancelledError:
@@ -484,7 +532,7 @@ class StateMachine:
                     e,
                 )
                 return TransitionOutcome.PENDING
-            pending.next_index += 1
+            pending.delivered()
         self._pending = None
         if self._after_send_phase is not None:
             self.phase = self._after_send_phase
@@ -513,7 +561,7 @@ class StateMachine:
             # where it stopped): ONE payload copy + cursor, not sealed parts
             "pending_send": (
                 {
-                    "payload": base64.b64encode(self._pending.encoder._payload_bytes).decode(),
+                    "payload": base64.b64encode(self._pending.encoder.payload_bytes()).decode(),
                     "tag": int(self._pending.encoder.message.tag),
                     "message_id": getattr(self._pending.encoder, "message_id", 0),
                     "max_message_size": self._pending.encoder.max_message_size,

@@ -35,7 +35,11 @@ class XaynetClient(ABC):
         """The latest global model, or None while unavailable."""
 
     @abstractmethod
-    async def send_message(self, encrypted: bytes) -> None: ...
+    async def send_message(self, encrypted: "bytes | bytearray") -> None:
+        """Deliver one sealed message. The state machine hands over the
+        buffer it composed the message in and keeps it until the send is
+        through (a transient failure sends it again): send from it, do not
+        write to it."""
 
 
 class ModelStore(ABC):

@@ -372,6 +372,10 @@ class Tracer:
         self._round_trace: Optional[str] = None  # guarded-by: _lock
         self._round_root: Optional[str] = None  # guarded-by: _lock
         self._round_start: float = 0.0  # guarded-by: _lock
+        # follow_round's windows: whether the open one is a participant's,
+        # and how many it has opened (their round ids)
+        self._round_followed = False  # guarded-by: _lock
+        self._followed = 0  # guarded-by: _lock
         # monotonic anchor for export timestamps (one per process)
         self.anchor = time.monotonic()
         # round-boundary listeners (the flight recorder snapshots registry
@@ -519,6 +523,34 @@ class Tracer:
             except Exception:  # a telemetry consumer must never fail a round
                 logger.exception("trace round hook failed")
 
+    def follow_round(self, trace_id: str) -> None:
+        """A participant's :meth:`begin_round`, where an export is configured
+        (else its spans stay in the ring, as before). It knows a round by
+        its seed alone, so its window is keyed by the trace id: nothing
+        happens while a window of that trace is open (its own, or an
+        in-process coordinator's, which owns its windows); else the window
+        before is flushed and one opened whose round id is this process's
+        count of such windows (a round has more than one where a participant
+        left, ``end_followed``, and another went on)."""
+        if not (self.trace_dir and self.mode == "on"):
+            return
+        with self._lock:
+            if self._round_trace == trace_id:
+                return
+            self._followed += 1
+            round_id = self._followed
+        self.begin_round(round_id, trace_id)
+        with self._lock:
+            self._round_followed = self._round_trace == trace_id
+
+    def end_followed(self) -> None:
+        """Flush a window :meth:`follow_round` opened (the participant is
+        leaving); a coordinator's window is not touched."""
+        with self._lock:
+            followed = self._round_followed
+        if followed:
+            self.end_round()
+
     def round_ctx(self) -> Optional[TraceContext]:
         """The current round's root context (worker threads parent here)."""
         with self._lock:
@@ -550,6 +582,7 @@ class Tracer:
             self._round_id = None
             self._round_trace = None
             self._round_root = None
+            self._round_followed = False
         SPANS_TOTAL.labels(subsystem=root.subsystem).inc()
         # export contract: every `parent` resolves WITHIN the bundle. A span
         # that started under the previous window (its parent was exported
