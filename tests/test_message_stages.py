@@ -1,10 +1,14 @@
-"""The stages of one Update message (server/stages.py, docs/DESIGN.md §16):
-one span and one histogram observation per stage per accepted update, all
-under the message's own request span and request id, closing on the
-message's residence; the host-to-device copy counted in bytes."""
+"""The stages of one message (server/stages.py, docs/DESIGN.md §16): one
+span and one histogram observation per stage per accepted update, all under
+the message's own request span and request id, labelled with the phase the
+message arrived in, closing on the message's residence; the host-to-device
+copy counted in bytes. And the run outside the Update window: the Sum2
+message's chain, the Unmask phase's stages (telemetry/unmask.py) and the
+start-up timeline (telemetry/startup.py)."""
 
 import asyncio
 import json
+import os
 import time
 from fractions import Fraction
 from pathlib import Path
@@ -34,14 +38,22 @@ from xaynet_tpu.storage.memory import (
     NoOpTrustAnchor,
 )
 from xaynet_tpu.storage.traits import Store
-from xaynet_tpu.telemetry import tracing
+from xaynet_tpu.telemetry import BridgedMetrics, tracing
+from xaynet_tpu.telemetry import unmask as unmask_stages
 
 REPO = Path(__file__).resolve().parent.parent
-N_SUM, N_UPDATE, MODEL_LEN = 1, 4, 20_011
+N_SUM, N_UPDATE, MODEL_LEN = 1, 4, 500_009
 SUM_PROB, UPDATE_PROB = 0.4, 0.5
 # the chain of a message's residence, in order (to_planar runs beside it)
 CHAIN = ("read_body", "pool_wait", "open", "verify", "parse", "resume_wait",
          "request_wait", "validate", "seed_dict", "stage", "flush", "verdict_wait")
+# the Sum2 message's chain: the same stages up to the channel, then the
+# Sum2 phase's own
+SUM2_CHAIN = ("read_body", "pool_wait", "open", "verify", "parse", "resume_wait",
+              "request_wait", "score", "verdict_wait")
+# the Unmask phase on the host arm (no device, a trust anchor, no journal)
+UNMASK_HOST = ("elect", "validate", "subtract", "decode", "save", "proof")
+UNMASK_ELSEWHERE = ("mask_put", "fetch", "retire")
 
 
 class ArrayModelStore(ModelStore):
@@ -52,14 +64,16 @@ class ArrayModelStore(ModelStore):
         return self.model
 
 
-def _counts() -> dict:
-    return {key[0]: child.count for key, child in stages.SECONDS.children()}
+def _counts(phase: str = "update") -> dict:
+    """Observations so far of each stage, of messages that arrived in ``phase``."""
+    return {key[0]: child.count for key, child in stages.SECONDS.children() if key[1] == phase}
 
 
-async def _round() -> dict:
-    """One PET round over the REST API on localhost, host aggregation, a
-    fold batch of one (so every accepted update fills its batch and pays a
-    flush). Returns what the assertions need."""
+def _unmask_counts() -> dict:
+    return {key[0]: child.count for key, child in unmask_stages.SECONDS.children()}
+
+
+def _settings(model_length: int = MODEL_LEN) -> Settings:
     settings = Settings(
         pet=ServerPet(
             sum=PhaseSettings(prob=SUM_PROB, count=CountSettings(N_SUM, N_SUM), time=TimeSettings(0, 30)),
@@ -67,17 +81,28 @@ async def _round() -> dict:
             sum2=Sum2Settings(count=CountSettings(N_SUM, N_SUM), time=TimeSettings(0, 30)),
         )
     )
-    settings.model.length = MODEL_LEN
+    settings.model.length = model_length
+    return settings
+
+
+async def _round() -> dict:
+    """One PET round over the REST API on localhost, host aggregation, a
+    fold batch of one (so every accepted update fills its batch and pays a
+    flush). Returns what the assertions need."""
+    settings = _settings()
     settings.aggregation.batch_size = 1
     store = Store(InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor())
-    machine, request_tx, events = await StateMachineInitializer(settings, store).init()
+    # a recorder, so that the phases' durations reach /metrics as they do
+    # behind the runner
+    machine, request_tx, events = await StateMachineInitializer(
+        settings, store, BridgedMetrics()).init()
     fetcher = Fetcher(events)
     rest = RestServer(fetcher, PetMessageHandler(events, request_tx))
     host, port = await rest.start("127.0.0.1", 0)
     machine_task = asyncio.create_task(machine.run())
     url = f"http://{host}:{port}"
     probe = HttpClient(url)
-    out = {}
+    out = {"sum2_before": _counts("sum2"), "unmask_before": _unmask_counts()}
     try:
         while fetcher.phase().value != "sum":
             await asyncio.sleep(0.01)
@@ -106,7 +131,9 @@ async def _round() -> dict:
                     await sm.transition()
                 except Exception:
                     pass
-                if await probe.get_model() is not None and sm.phase.value == "awaiting":
+                # the round's model is published: whatever role the next
+                # round's draw gives this participant is not of this round
+                if await probe.get_model() is not None:
                     return
                 await asyncio.sleep(0.01)
 
@@ -131,6 +158,10 @@ async def _round() -> dict:
         await asyncio.gather(close_window(), *(drive(p) for p in updaters), *sum_tasks)
         out["health"] = json.loads((await probe._request("GET", "/healthz"))[2])
         assert await probe.get_model() is not None
+        # the model is published: the round's one Sum2 phase and one Unmask
+        # phase lie between the window's opening and here
+        out["sum2_after"], out["unmask_after"] = _counts("sum2"), _unmask_counts()
+        out["metrics_end"] = (await probe._request("GET", "/metrics"))[2].decode()
     finally:
         machine_task.cancel()
         await rest.stop()
@@ -153,8 +184,66 @@ def served_round():
 
 @pytest.mark.parametrize("label", CHAIN)
 def test_each_stage_observed_once_per_accepted_update(served_round, label):
+    # observations of messages that arrived in the Update phase: the sum
+    # participant's Sum2 message, which under a loaded host is read, opened
+    # and parsed before the poll has seen the phase end, is not among them
     before, after = served_round["before"], served_round["after"]
     assert after.get(label, 0) - before.get(label, 0) == N_UPDATE
+
+
+@pytest.mark.parametrize("label", SUM2_CHAIN)
+def test_sum2_message_chain_observed_once_per_sum_participant(served_round, label):
+    before, after = served_round["sum2_before"], served_round["sum2_after"]
+    assert after.get(label, 0) - before.get(label, 0) == N_SUM
+
+
+def test_sum2_message_has_no_stage_of_the_update_phase(served_round):
+    before, after = served_round["sum2_before"], served_round["sum2_after"]
+    for label in ("validate", "seed_dict", "stage", "flush", "to_planar"):
+        assert after.get(label, 0) == before.get(label, 0), label
+
+
+def test_stages_of_one_message_carry_one_phase(served_round):
+    """The last update's ``verdict_wait`` ends after the phase has moved on
+    and the Sum2 message's ``read_body`` begins as the phase does: each
+    message's spans all carry the phase its ``rest.request`` opened in."""
+    spans = served_round["spans"]
+    requests = {s.span_id: s for s in spans
+                if s.name == "rest.request" and s.attrs.get("path") == "/message"}
+    assert {r.attrs["phase"] for r in requests.values()} >= {"update", "sum2"}
+    names = set(stages._SPANS.values()) - {stages._SPANS["to_planar"]}  # beside the chain
+
+    def request_of(span):
+        # the Sum2 message's verdict reaches its sender after the round's
+        # window has closed (the state machine runs on through Unmask without
+        # yielding), and the flush turns a parent still open into a link
+        return requests.get(span.parent_id or span.attrs.get("link"))
+
+    seen = 0
+    for s in spans:
+        if s.name in names and request_of(s) is not None:
+            assert s.attrs["phase"] == request_of(s).attrs["phase"], s.name
+            seen += 1
+    assert seen >= len(CHAIN) * N_UPDATE + len(SUM2_CHAIN) * N_SUM
+    score = [s for s in spans if s.name == "sum2.score"]
+    assert len(score) == N_SUM and score[0].attrs["phase"] == "sum2"
+    assert request_of(score[0]).attrs["phase"] == "sum2"
+    assert score[0].attrs["bytes"] > 0
+
+
+def test_sum_participant_spans_its_two_steps_before_the_compose(served_round):
+    """``sum2.open_seeds`` then ``sum2.derive`` then the Sum2 message's
+    ``message.compose``, in the participant's own trace (here one process)."""
+    spans = served_round["spans"]
+    opened = [s for s in spans if s.name == "sum2.open_seeds"]  # the empty polls leave none
+    derived = [s for s in spans if s.name == "sum2.derive"]
+    assert len(opened) == N_SUM and len(derived) == N_SUM
+    assert opened[0].attrs["masks"] == N_UPDATE
+    assert derived[0].attrs["masks"] == N_UPDATE and derived[0].attrs["elements"] == MODEL_LEN
+    assert derived[0].attrs["route"] in ("fused", "fast", "generic")
+    composed = [s for s in spans if s.name == "message.compose" and s.start >= derived[0].start]
+    assert composed and opened[0].start + opened[0].duration <= derived[0].start
+    assert derived[0].start + derived[0].duration <= composed[0].start
 
 
 def test_stage_spans_share_the_rid_and_parent_of_their_message(served_round):
@@ -203,13 +292,55 @@ def test_stage_closure_from_metrics_reaches_95_percent(served_round):
     assert closure is not None and 95.0 <= closure <= 100.5, closure
 
 
+@pytest.mark.parametrize("label", UNMASK_HOST)
+def test_each_unmask_stage_of_the_host_arm_observed_once_a_round(served_round, label):
+    before, after = served_round["unmask_before"], served_round["unmask_after"]
+    assert after.get(label, 0) - before.get(label, 0) == 1
+
+
+def test_unmask_stages_of_other_arms_are_not_observed_on_the_host_arm(served_round):
+    before, after = served_round["unmask_before"], served_round["unmask_after"]
+    for label in UNMASK_ELSEWHERE:
+        assert after.get(label, 0) == before.get(label, 0), label
+
+
+def test_unmask_spans_lie_under_the_phase_span(served_round):
+    spans = served_round["spans"]
+    phase = [s for s in spans if s.name == "phase.unmask"]
+    assert len(phase) == 1
+    lo, hi = phase[0].start, phase[0].start + phase[0].duration
+    under = [s for s in spans if s.name.startswith("unmask.")]
+    assert sorted(s.name for s in under) == sorted(f"unmask.{label}" for label in UNMASK_HOST)
+    for s in under:
+        assert s.parent_id == phase[0].span_id, s.name
+        assert s.start >= lo and s.start + s.duration <= hi, s.name
+    moved = {s.name: s.attrs.get("bytes") for s in under}
+    assert moved["unmask.subtract"] >= 6 * MODEL_LEN and moved["unmask.save"] == 8 * MODEL_LEN
+
+
+def test_unmask_stage_closure_from_metrics_reaches_95_percent(served_round):
+    """``unmask.stage_closure`` as the benchmark computes it: the metric's own
+    spec, run by the shipped reader over the round's /metrics reads."""
+    from benchmark.harness.coordinator import parse_metrics
+    from benchmark.readers import prom_ratio
+
+    spec = json.loads((REPO / "benchmark/layer_metrics/unmask.stage_closure.json").read_text())
+    assert spec["reader"] == "prom_ratio" and spec["args"]["span"] == ["open", "end"]
+    ctx = {"metrics": {"open": parse_metrics(served_round["metrics_open"]),
+                       "end": parse_metrics(served_round["metrics_end"])}}
+    closure = prom_ratio.read(ctx, **spec["args"])
+    assert closure is not None and 95.0 <= closure <= 100.5, closure
+
+
 def test_healthz_lists_the_mirrored_span_names(served_round):
     section = served_round["health"]["trace"]
     assert section["mode"] == "on" and section["mirror"] is False  # no device, no sink
     listed = set(section["mirrored_spans"])
     assert {"rest.read_body", "pipeline.verify", "update.await_request", "update.flush"} <= listed
-    assert not listed & {"round", "rest.request", "phase.update",
-                         "pipeline.pool_wait", "update.request_wait"}
+    assert {"sum2.score"} | {f"unmask.{label}" for label in UNMASK_HOST + UNMASK_ELSEWHERE} <= listed
+    assert not listed & {"round", "rest.request", "phase.update", "phase.unmask",
+                         "pipeline.pool_wait", "update.request_wait",
+                         "startup.imports", "startup.serving"}
 
 
 def test_loop_lag_is_observed_while_the_server_runs(served_round):
@@ -240,7 +371,7 @@ def test_h2d_bytes_equal_the_staged_batch_and_to_planar_is_observed():
                            mesh=make_mesh(jax.devices()[:1]))
     staged0 = sum(child.value for _, child in BYTES_STAGED.children())
     h2d0, n0 = streaming.H2D_BYTES.value, streaming.H2D_SECONDS.count
-    planar0 = _counts().get("to_planar", 0)
+    planar0 = _counts("-").get("to_planar", 0)  # staged here, by no message
     rng = np.random.default_rng(3)
     for _ in range(k):
         _, masked = Masker(config).mask(
@@ -252,6 +383,178 @@ def test_h2d_bytes_equal_the_staged_batch_and_to_planar_is_observed():
     assert agg.nb_models == k and staged > 0
     assert streaming.H2D_BYTES.value - h2d0 == staged
     assert streaming.H2D_SECONDS.count - n0 == 1
-    assert _counts().get("to_planar", 0) - planar0 == k
+    assert _counts("-").get("to_planar", 0) - planar0 == k
     h2d = [s for s in tracing.get_tracer().ring_spans() if s.name == "stream.h2d"][-1]
     assert h2d.attrs["bytes"] == staged
+
+
+def test_unmask_stages_of_the_device_arm_are_observed_once_and_carry_bytes():
+    """Device aggregation on the CPU backend, one device: the mask goes to
+    the device, is subtracted there and the result comes back, a stage each;
+    the unmasked model is the mean."""
+    pytest.importorskip("jax")
+    import jax
+
+    from xaynet_tpu.core.mask import (
+        BoundType, DataType, GroupType, MaskConfig, Masker, ModelType, Scalar)
+    from xaynet_tpu.core.mask.masking import Aggregation
+    from xaynet_tpu.parallel.mesh import make_mesh
+    from xaynet_tpu.server.aggregation import DeviceAggregation, StagedAggregator
+
+    config = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M3).pair()
+    n, k = 1_003, 3
+    agg = StagedAggregator(config, n, device=True, batch_size=k, kernel="xla",
+                           mesh=make_mesh(jax.devices()[:1]))
+    masks = Aggregation(config, n)
+    rng = np.random.default_rng(5)
+    weights = rng.uniform(-1, 1, size=(k, n)).astype(np.float32)
+    for row in weights:
+        seed, masked = Masker(config).mask(Scalar(1, k), row)
+        agg.validate_aggregation(masked)
+        agg.stage(masked)
+        mask = seed.derive_mask(n, config)
+        masks.validate_aggregation(mask)
+        masks.aggregate(mask)
+    view = agg.finalize_inplace()
+    assert isinstance(view, DeviceAggregation)
+    before, t0 = _unmask_counts(), time.monotonic()
+    view.validate_unmasking(masks.object)
+    model = view.unmask_array(masks.object)
+    after = _unmask_counts()
+    np.testing.assert_allclose(model, weights.astype(np.float64).mean(axis=0), atol=1e-9)
+    for label in ("mask_put", "subtract", "fetch", "decode"):
+        assert after.get(label, 0) - before.get(label, 0) == 1, label
+    spans = {s.name: s for s in tracing.get_tracer().ring_spans() if s.start >= t0}
+    wire_bytes = masks.object.vect.data.nbytes
+    assert spans["unmask.mask_put"].attrs["bytes"] == wire_bytes
+    assert spans["unmask.fetch"].attrs["bytes"] == wire_bytes
+    assert spans["unmask.subtract"].attrs["bytes"] >= wire_bytes  # planar, padded
+    order = [spans[f"unmask.{label}"] for label in ("mask_put", "subtract", "fetch", "decode")]
+    for first, then in zip(order, order[1:]):
+        assert first.start + first.duration <= then.start
+
+
+def test_two_tenants_in_different_phases_are_labelled_apart():
+    """One process, one listener, two tenants: ``a``'s machine runs and
+    stands in Sum, ``b``'s was never started and stands in Idle. A message
+    POSTed to each is labelled by its own coordinator's phase."""
+    from xaynet_tpu.server.rest import TenantRoutes
+
+    async def scenario():
+        built, tasks = {}, []
+        for tenant in ("a", "b"):
+            store = Store(InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor())
+            settings = _settings(11)
+            machine, request_tx, events = await StateMachineInitializer(
+                settings, store, tenant=tenant).init()
+            built[tenant] = (machine, Fetcher(events), PetMessageHandler(events, request_tx))
+        routes = {t: TenantRoutes(fetcher=f, handler=h) for t, (_, f, h) in built.items()}
+        rest = RestServer(built["a"][1], built["a"][2], tenants=routes, default_tenant="a")
+        host, port = await rest.start("127.0.0.1", 0)
+        tasks.append(asyncio.create_task(built["a"][0].run()))
+        probe = HttpClient(f"http://{host}:{port}")
+        try:
+            while built["a"][1].phase().value != "sum":
+                await asyncio.sleep(0.01)
+            assert built["b"][1].phase().value == "idle"
+            before = {phase: _counts(phase) for phase in ("sum", "idle")}
+            t0 = time.monotonic()
+            for tenant in ("a", "b"):  # a box that does not open: dropped at `open`
+                status, _, _ = await probe._request("POST", f"/t/{tenant}/message", b"\x07" * 200)
+                assert status == 200
+            after = {phase: _counts(phase) for phase in ("sum", "idle")}
+        finally:
+            for task in tasks:
+                task.cancel()
+            await rest.stop()
+            await asyncio.gather(*tasks, return_exceptions=True)
+        return before, after, t0
+
+    tracer = tracing.get_tracer()
+    mode = tracer.mode
+    tracer.configure(mode="on")
+    try:
+        before, after, t0 = asyncio.run(asyncio.wait_for(scenario(), timeout=60))
+        spans = [s for s in tracer.ring_spans() if s.start >= t0]
+    finally:
+        tracer.configure(mode=mode)
+    for phase in ("sum", "idle"):
+        for label in ("pool_wait", "open"):
+            assert after[phase].get(label, 0) - before[phase].get(label, 0) == 1, (phase, label)
+    requests = {s.span_id: s for s in spans if s.name == "rest.request"}
+    assert sorted((r.attrs["tenant"], r.attrs["phase"]) for r in requests.values()) == [
+        ("a", "sum"), ("b", "idle")]
+    opened = [s for s in spans if s.name == "pipeline.open"]
+    assert sorted((requests[s.parent_id].attrs["tenant"], s.attrs["phase"]) for s in opened) == [
+        ("a", "sum"), ("b", "idle")]
+
+
+_SERVE_ONCE = """
+import asyncio, json, socket, sys
+from xaynet_tpu.resilience.checkpoint import RECOVERY_SECONDS
+from xaynet_tpu.sdk.client import HttpClient
+from xaynet_tpu.server import runner
+from xaynet_tpu.telemetry import startup
+
+sys.path.insert(0, "tests")
+from test_message_stages import _settings
+
+tenants, scratch = json.loads(sys.argv[1]), sys.argv[2]
+with socket.socket() as sock:
+    sock.bind(("127.0.0.1", 0))
+    port = sock.getsockname()[1]
+settings = _settings(11)
+settings.api.bind_address = f"127.0.0.1:{port}"
+settings.metrics.flight_dir = scratch + "/flight"
+settings.storage.model_dir = scratch + "/models"
+if tenants:
+    settings.tenancy.enabled, settings.tenancy.tenants = True, tenants
+
+async def scenario():
+    serving = asyncio.create_task(runner.serve(settings))
+    probe = HttpClient(f"http://127.0.0.1:{port}")
+    try:
+        for _ in range(500):
+            try:
+                return json.loads((await probe._request("GET", "/healthz"))[2])
+            except Exception:
+                assert not serving.done(), serving.exception()
+                await asyncio.sleep(0.02)
+        raise AssertionError("the coordinator did not start serving")
+    finally:
+        serving.cancel()
+        await asyncio.gather(serving, return_exceptions=True)
+
+section = asyncio.run(asyncio.wait_for(scenario(), timeout=60))["startup"]
+print(json.dumps({"section": section, "recovery": RECOVERY_SECONDS.value,
+                  "gauge": {key[0]: child.value for key, child in startup.SECONDS.children()}}))
+"""
+
+
+@pytest.mark.parametrize("tenants", [[], ["t0", "t1"]], ids=["one", "tenants"])
+def test_serve_marks_its_start_up_and_healthz_reports_it(tmp_path, tenants):
+    """The runner's ``serve()`` (and ``serve_tenants()``, by the same helper)
+    as a process of its own, since it configures the process: the five marks
+    in order, ``serving`` the last, the gauge and the recovery wall from the
+    same marks."""
+    import subprocess
+    import sys
+
+    done = subprocess.run(
+        [sys.executable, "-c", _SERVE_ONCE, json.dumps(tenants), str(tmp_path)], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    section, steps = out["section"], ("imports", "backend", "store", "machine", "serving")
+    assert section["origin"] in ("proc", "import")
+    marks = [section[step] for step in steps]
+    assert [m["at"] for m in marks] == sorted(m["at"] for m in marks)
+    assert section["serving"]["at"] == max(m["at"] for m in marks)
+    assert marks[0]["took"] == marks[0]["at"] > 0
+    for before, mark in zip(marks, marks[1:]):
+        assert mark["took"] == pytest.approx(mark["at"] - before["at"], abs=2e-6)
+    assert section["backend"]["took"] + section["machine"]["took"] <= section["serving"]["at"]
+    for step in steps:
+        assert out["gauge"][step] == pytest.approx(section[step]["took"], abs=1e-6)
+    assert out["recovery"] == pytest.approx(
+        section["serving"]["at"] - section["imports"]["at"], abs=2e-6)

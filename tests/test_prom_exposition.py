@@ -123,7 +123,9 @@ def test_full_registry_render_conforms():
         if sample_name.endswith("_bucket") and types[family] == "histogram":
             le = labels.pop("le")
             bound = _parse_value(le)
-            key = (family, tuple(sorted(labels.items())))
+            # in the order rendered (the family's labelnames, which need not
+            # be alphabetical: xaynet_message_pipeline_seconds{stage,phase})
+            key = (family, tuple(labels.items()))
             series = buckets.setdefault(key, [])
             if series:
                 assert bound > series[-1][0], f"le not ascending in {family}"

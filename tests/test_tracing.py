@@ -502,6 +502,94 @@ def test_mirror_is_never_called_with_tracing_off(mirror_log):
     assert mirror_log == []
 
 
+_BRACKETS = [("message", "score")] + [
+    ("unmask", label) for label in
+    ("elect", "validate", "mask_put", "subtract", "fetch", "decode", "save", "proof", "retire")]
+
+
+@pytest.mark.parametrize("table,label", _BRACKETS)
+def test_stage_brackets_off_make_no_span_and_still_observe(table, label, mirror_log, monkeypatch):
+    """The brackets outside the Update window (``server/stages.py``'s
+    ``score``, ``telemetry/unmask.py``'s nine) with the tracer ``off``: no
+    ``Span``, no call of the mirror, and the histogram observed all the
+    same, so ``/metrics`` keeps its stage tables whatever the trace mode."""
+    from xaynet_tpu.server import stages
+    from xaynet_tpu.telemetry import unmask as unmask_stages
+
+    if table == "message":
+        child = stages.SECONDS.labels(stage=label, phase="sum2")
+        bracket = lambda: stages.stage(label, phase="sum2", bytes=7)  # noqa: E731
+    else:
+        child = unmask_stages.SECONDS.labels(stage=label)
+        bracket = lambda: unmask_stages.stage(label, bytes=7)  # noqa: E731
+    process_tracer = tracing.get_tracer()
+    mode, mirror = process_tracer.mode, process_tracer._mirror
+    process_tracer.configure(mode="off")
+    process_tracer.set_mirror(_FakeAnnotation)
+    made = []
+    try:
+        with monkeypatch.context() as patched:
+            patched.setattr(tracing, "Span", lambda *a, **k: made.append(a))
+            ring, count, total = len(process_tracer.ring_spans()), child.count, child.sum
+            with bracket() as span:
+                span.set(more=1)  # the null span takes attributes and drops them
+                time.sleep(0.002)
+        assert child.count == count + 1 and child.sum - total >= 0.002
+        assert len(process_tracer.ring_spans()) == ring and made == [] and mirror_log == []
+        # and on: one span of the stage's name beside the observation
+        process_tracer.configure(mode="on")
+        with bracket():
+            pass
+        assert child.count == count + 2 and len(mirror_log) == 2
+        assert process_tracer.ring_spans()[-1].attrs["bytes"] == 7
+    finally:
+        process_tracer.configure(mode=mode)
+        process_tracer.set_mirror(mirror)
+
+
+def test_validator_holds_the_stage_spans_to_their_place(tracer):
+    """``unmask.*`` lies under ``phase.unmask`` and ``sum2.score`` under its
+    ``rest.request`` (tools/trace_report.py); a request still open when the
+    round's window flushed rides as a link and is let through."""
+    import importlib
+
+    for module in ("server.rest", "server.stages", "server.phases.base", "telemetry.unmask"):
+        importlib.import_module(f"xaynet_tpu.{module}")
+    tracer.begin_round(6, tracing.round_trace_id(b"y" * 32))
+    for phase in ("sum", "update"):
+        with tracer.span(f"phase.{phase}"):
+            pass
+    with tracer.span("phase.sum2"):
+        with tracer.span("rest.request", method="POST", path="/message"):
+            with tracer.span("sum2.score"):
+                pass
+    with tracer.span("phase.unmask"):
+        with tracer.span("unmask.elect"):
+            pass
+        with tracer.span("unmask.save"):
+            pass
+    events = [e for e in tracing.to_chrome_trace(tracer.end_round())["traceEvents"]
+              if e.get("ph") == "X"]
+    assert trace_report.validate(events) == []
+    by_name = {e["name"]: e for e in events}
+    # a stage of the Unmask phase hung under another phase
+    by_name["unmask.save"]["args"]["parent"] = by_name["phase.sum2"]["args"]["span"]
+    by_name["unmask.save"]["ts"] = by_name["phase.sum2"]["ts"]
+    by_name["unmask.save"]["dur"] = 0.0
+    problems = trace_report.validate(events)
+    assert len(problems) == 1 and "unmask.save" in problems[0] and "phase.unmask" in problems[0]
+    by_name["unmask.save"]["args"]["parent"] = by_name["phase.unmask"]["args"]["span"]
+    by_name["unmask.save"]["ts"] = by_name["phase.unmask"]["ts"]
+    # the score hung under the phase, not under its message's request
+    score = by_name["sum2.score"]["args"]
+    score["parent"] = by_name["phase.sum2"]["args"]["span"]
+    problems = trace_report.validate(events)
+    assert len(problems) == 1 and "sum2.score" in problems[0] and "rest.request" in problems[0]
+    # its request was still open at the flush: a link, no parent
+    score["link"], score["parent"] = by_name["rest.request"]["args"]["span"], None
+    assert trace_report.validate(events) == []
+
+
 def test_a_failing_mirror_never_fails_the_span(tracer):
     def broken(name, **attrs):
         raise RuntimeError("sink down")

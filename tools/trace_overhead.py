@@ -19,7 +19,9 @@ Usage:
                     [--k K] [--batches B] [--reps R]
 Prints one JSON line: {updates_per_s_on, updates_per_s_off, overhead_pct,
 span_cost_us, span_cost_mirrored_us (the mirror sink set, no profiler
-session), timeline_fold_us, timeline_fold_pct_of_window, ...}.
+session), unmask_bracket_on_us / unmask_bracket_off_us (one stage bracket of
+``telemetry/unmask.py``: span + histogram observation, and the observation
+alone with the tracer off), timeline_fold_us, timeline_fold_pct_of_window, ...}.
 """
 
 from __future__ import annotations
@@ -158,6 +160,26 @@ def main() -> None:
     finally:
         tracer.set_mirror(None)
 
+    # one stage bracket of the tables outside the Update window
+    # (telemetry/unmask.py; server/stages.py's are the same call): a span
+    # and a histogram observation with the tracer on, the observation alone
+    # with it off. A round makes about 25 of them.
+    from xaynet_tpu.telemetry import unmask as unmask_stages
+
+    def _bracket_cost_us(mode: str) -> float:
+        tracer.configure(mode=mode)
+        t0 = time.perf_counter()
+        for _ in range(n_probe):
+            with unmask_stages.stage("retire"):
+                pass
+        return (time.perf_counter() - t0) / n_probe * 1e6
+
+    try:
+        bracket_off_us = _bracket_cost_us("off")
+        bracket_on_us = _bracket_cost_us("on")
+    finally:
+        tracer.configure(mode="on")
+
     # the always-on timeline fold (DESIGN §20): one O(n) pass per round
     # over the span buffer. Time it on a synthetic buffer shaped like a
     # real round (phase spans + streaming children, half the 8192 cap) and
@@ -210,6 +232,8 @@ def main() -> None:
                 "pair_ratios": [round(r, 4) for r in ratios],
                 "span_cost_us": round(span_cost_us, 2),
                 "span_cost_mirrored_us": round(span_cost_mirrored_us, 2),
+                "unmask_bracket_on_us": round(bracket_on_us, 2),
+                "unmask_bracket_off_us": round(bracket_off_us, 2),
                 "timeline_fold_us": round(fold_cost_us, 2),
                 "timeline_fold_spans": len(buffer),
                 "timeline_fold_pct_of_window": round(fold_pct_of_window, 4),

@@ -14,8 +14,10 @@ asks of it:
 - ``--validate`` — the CI schema gate: timestamps monotonic and finite,
   no orphan parents (every ``parent`` resolves within the bundle — remote
   hops ride ``link`` attributes precisely so this stays strict), children
-  inside their parents' windows (small tolerance), and the round's phase
-  spans covering the round span;
+  inside their parents' windows (small tolerance), the stage spans with one
+  place in the tree under it (``unmask.*`` inside ``phase.unmask``,
+  ``sum2.score`` inside its ``rest.request``), and the round's phase spans
+  covering the round span;
 - ``--round-report`` — cross-check the trace's phase walls against the
   round report JSONL (``[metrics] round_report_path``): the two artifacts
   measure the same bracket, so a drift beyond tolerance means one of them
@@ -49,6 +51,14 @@ _NEST_TOLERANCE_US = 50_000.0
 # phase spans the round must contain to count as covered (idle/failure/
 # shutdown are round-boundary or error phases and legitimately absent)
 _REQUIRED_PHASES = ("phase.sum", "phase.update", "phase.sum2", "phase.unmask")
+
+# stage spans that have one place in the tree: name (or prefix ending in a
+# dot) -> the name their parent must carry. The generic check above holds
+# them inside whatever parent they name; this one says which parent that is.
+# A parent that was still open when the round's window flushed rides as a
+# `link` (the Sum2 message's request outlives the round it closes) and is
+# not held to it.
+_STAGE_PARENTS = {"unmask.": "phase.unmask", "sum2.score": "rest.request"}
 
 # round-report cross-check tolerance: the trace span and the report wall
 # bracket the same process+purge region, so they agree to scheduling noise
@@ -112,6 +122,17 @@ def validate(events: list[dict]) -> list[str]:
             problems.append(
                 f"{e.get('name')} (span {_span_id(e)}) escapes its parent "
                 f"{parent.get('name')}'s window"
+            )
+    for e in events:
+        name = str(e.get("name", ""))
+        want = _STAGE_PARENTS.get(name) or _STAGE_PARENTS.get(name.split(".")[0] + ".")
+        parent = by_span.get(_parent_id(e) or "")
+        if want is None or (parent is None and (e.get("args") or {}).get("link")):
+            continue
+        if parent is None or parent.get("name") != want:
+            problems.append(
+                f"{name} (span {_span_id(e)}) is not under a {want} span "
+                f"(parent: {parent.get('name') if parent else None})"
             )
     rounds = [e for e in events if e.get("name") == "round"]
     if len(rounds) != 1:

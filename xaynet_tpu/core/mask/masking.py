@@ -22,14 +22,14 @@ from typing import Union
 import numpy as np
 
 from ...ops import limbs as limb_ops
+from ...telemetry import unmask as unmask_stages
 from ..crypto.prng import StreamSampler
+from . import encode as _encode
 from .config import MaskConfig, MaskConfigPair
 from .encode import (
     clamp_scalar,
     decode_scalar_sum,
-    decode_vect_any,
     decode_vect_exact,
-    decode_vect_fast,
     encode_unit,
     encode_vect_limbs,
     has_fast_path,
@@ -287,27 +287,48 @@ class Aggregation:
 
     # --- unmasking (reference: masking.rs:190-231) ------------------------
 
-    def _unmasked_limbs(self, mask_obj: MaskObject) -> tuple[np.ndarray, int]:
-        config_n, config_1 = self.object.vect.config, self.object.unit.config
-        n_vect = limb_ops.mod_sub(self.object.vect.data, mask_obj.vect.data, _order_limbs(config_n))
+    def _unmasked_vect(self, mask_obj: MaskObject) -> np.ndarray:
+        # the host arm's `subtract` stage of the Unmask phase
+        # (telemetry/unmask.py); the device arm brackets its own three
+        with unmask_stages.stage("subtract", bytes=mask_obj.vect.data.nbytes):
+            return limb_ops.mod_sub(
+                self.object.vect.data, mask_obj.vect.data, _order_limbs(self.config.vect)
+            )
+
+    def _unmasked_unit(self, mask_obj: MaskObject) -> int:
         n_unit = limb_ops.mod_sub(
-            self.object.unit.data[None, :], mask_obj.unit.data[None, :], _order_limbs(config_1)
+            self.object.unit.data[None, :],
+            mask_obj.unit.data[None, :],
+            _order_limbs(self.config.unit),
         )[0]
-        return n_vect, limb_ops.limbs_to_int(n_unit)
+        return limb_ops.limbs_to_int(n_unit)
+
+    def _unmasked_limbs(self, mask_obj: MaskObject) -> tuple[np.ndarray, int]:
+        return self._unmasked_vect(mask_obj), self._unmasked_unit(mask_obj)
+
+    # configs are read through ``self.config``, never ``self.object``: on the
+    # device-resident subclass (server/aggregation.py) the latter gathers
+    # the mesh accumulator
 
     def unmask(self, mask_obj: MaskObject) -> Model:
         """Exact unmasking -> ``Model`` of rational weights (reference parity)."""
-        config_n, config_1 = self.object.vect.config, self.object.unit.config
+        config = self.config
         n_vect, n_unit = self._unmasked_limbs(mask_obj)
-        scalar_sum = decode_scalar_sum(n_unit, config_1, self.nb_models)
+        scalar_sum = decode_scalar_sum(n_unit, config.unit, self.nb_models)
         values = limb_ops.limbs_to_ints(n_vect)
-        return Model(decode_vect_exact(values, config_n, self.nb_models, scalar_sum))
+        return Model(decode_vect_exact(values, config.vect, self.nb_models, scalar_sum))
 
     def unmask_array(self, mask_obj: MaskObject) -> np.ndarray:
-        """Fast unmasking -> float64 numpy array (double-double decode)."""
-        config_n, config_1 = self.object.vect.config, self.object.unit.config
-        n_vect, n_unit = self._unmasked_limbs(mask_obj)
-        scalar_sum = decode_scalar_sum(n_unit, config_1, self.nb_models)
-        if has_fast_path(config_n):
-            return decode_vect_fast(n_vect, config_n, self.nb_models, scalar_sum)
-        return decode_vect_any(n_vect, config_n, self.nb_models, scalar_sum)
+        """Fast unmasking -> float64 numpy array (double-double decode):
+        the vector's subtract (its stages bracketed where they run), then
+        the `decode` stage of the Unmask phase (telemetry/unmask.py)."""
+        config = self.config
+        n_vect = self._unmasked_vect(mask_obj)
+        with unmask_stages.stage("decode", bytes=n_vect.nbytes):
+            scalar_sum = decode_scalar_sum(self._unmasked_unit(mask_obj), config.unit, self.nb_models)
+            # the vector's decoders are looked up in their module when they
+            # run, as the device arm always did: a launcher may stand in for
+            # one (benchmark/tests/serve_broken.py alters the answer there)
+            if has_fast_path(config.vect):
+                return _encode.decode_vect_fast(n_vect, config.vect, self.nb_models, scalar_sum)
+            return _encode.decode_vect_any(n_vect, config.vect, self.nb_models, scalar_sum)

@@ -29,6 +29,7 @@ from ..ops.fold_jax import (
     wire_to_planar,
 )
 from ..telemetry import profiling
+from ..telemetry import unmask as unmask_stages
 from ..telemetry.registry import get_registry
 from ..utils.kernels import FOLD_KERNELS
 from .mesh import MODEL_AXIS, make_mesh, pad_to_multiple
@@ -819,7 +820,6 @@ class ShardedAggregator:
 
     def unmask_limbs(self, mask_vect) -> np.ndarray:
         """Subtract the aggregated mask; returns host wire ``uint32[model_len, L]``."""
-        planar = self.mask_planar(mask_vect)
         if self._live_plan is not None:
             # reduce-scatter unmask: each shard subtracts ITS slice of the
             # mask against its own accumulator buffer — the aggregate is
@@ -829,15 +829,26 @@ class ShardedAggregator:
             return profiling.timed_kernel(
                 "unmask",
                 self.padded_length,
-                lambda: self._unmask_plan(self._live_plan, planar),
+                lambda: self._unmask_plan(self._live_plan, mask_vect),
             )
-        mask_dev = jax.device_put(jnp.asarray(planar), self._acc_sharding)
-        out = profiling.timed_kernel(
-            "unmask",
-            self.padded_length,
-            lambda: _unmask_kernel(self.acc, mask_dev, self.order),
-        )
-        return np.ascontiguousarray(np.asarray(out)[:, : self.model_length].T)
+        # three stages of the Unmask phase (telemetry/unmask.py), each ended
+        # by a wait for its own result: what would otherwise be paid inside
+        # the fetch is told apart, and nothing runs beside them to overlap
+        with unmask_stages.stage("mask_put", bytes=np.asarray(mask_vect).nbytes):
+            planar = self.mask_planar(mask_vect)
+            mask_dev = jax.block_until_ready(  # lint: sync-ok
+                jax.device_put(jnp.asarray(planar), self._acc_sharding)
+            )
+        with unmask_stages.stage("subtract", bytes=planar.nbytes):
+            out = jax.block_until_ready(  # lint: sync-ok
+                profiling.timed_kernel(
+                    "unmask",
+                    self.padded_length,
+                    lambda: _unmask_kernel(self.acc, mask_dev, self.order),
+                )
+            )
+        with unmask_stages.stage("fetch", bytes=self.model_length * self.n_limbs * 4):
+            return np.ascontiguousarray(np.asarray(out)[:, : self.model_length].T)
 
     def unmask_shard(self, plan, d: int, mask_planar: np.ndarray, out: np.ndarray) -> None:
         """One shard's leg of the reduce-scatter unmask: subtract shard
@@ -860,28 +871,34 @@ class ShardedAggregator:
         # it here so Unmask never touches the device again  # lint: sync-ok
         out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T  # lint: sync-ok
 
-    def _unmask_plan(self, plan, mask_planar: np.ndarray) -> np.ndarray:
+    def _unmask_plan(self, plan, mask_vect) -> np.ndarray:
         """Per-shard in-place unmask against a live reduce-scatter plan:
         one subtract per device (all in flight before the first fetch) —
         only the UNMASKED per-shard slices move, once, into the host wire
         result."""
         out = np.empty((self.model_length, self.n_limbs), dtype=np.uint32)
-        pending = []
-        for d, (lo, hi) in enumerate(plan.slices):
-            mask_dev = jax.device_put(
-                np.ascontiguousarray(mask_planar[:, lo:hi]), plan.devices[d]
-            )
-            # dispatch every shard's subtract before fetching any: the
-            # per-device kernels overlap, the downloads serialize after
-            pending.append(
-                (lo, hi, _unmask_kernel(plan.accs[d], mask_dev, self.order))  # lint: guarded-ok: drain barrier read
-            )
-        for lo, hi, res in pending:
-            real_hi = min(hi, self.model_length)
-            if lo < real_hi:
-                out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T
-        BYTES_REDUCED.labels(path="gather").inc(out.nbytes)
-        return np.ascontiguousarray(out)
+        # the stages of the one-device arm (``unmask_limbs``), a shard each:
+        # every slice is put before any subtract is dispatched, and every
+        # subtract dispatched before any result is fetched, so the
+        # per-device transfers and kernels overlap within their stage
+        with unmask_stages.stage("mask_put", bytes=np.asarray(mask_vect).nbytes):
+            mask_planar = self.mask_planar(mask_vect)
+            masks = jax.block_until_ready([  # lint: sync-ok
+                jax.device_put(np.ascontiguousarray(mask_planar[:, lo:hi]), plan.devices[d])
+                for d, (lo, hi) in enumerate(plan.slices)
+            ])
+        with unmask_stages.stage("subtract", bytes=mask_planar.nbytes):
+            results = jax.block_until_ready([  # lint: sync-ok
+                _unmask_kernel(acc, mask_dev, self.order)  # lint: guarded-ok: drain barrier read
+                for acc, mask_dev in zip(plan.accs, masks)
+            ])
+        with unmask_stages.stage("fetch", bytes=out.nbytes):
+            for (lo, hi), res in zip(plan.slices, results):
+                real_hi = min(hi, self.model_length)
+                if lo < real_hi:
+                    out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T
+            BYTES_REDUCED.labels(path="gather").inc(out.nbytes)
+            return np.ascontiguousarray(out)
 
     def snapshot(self) -> np.ndarray:
         """Host wire-layout copy of the aggregate (checkpoints / tests)."""

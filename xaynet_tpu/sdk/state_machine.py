@@ -19,6 +19,7 @@ import base64
 import enum
 import json
 import logging
+import time
 from dataclasses import dataclass
 from fractions import Fraction
 from typing import Optional
@@ -39,7 +40,7 @@ from ..core.mask.model import Scalar
 from ..core.mask.object import MaskObject, MaskUnit, MaskVect
 from ..core.message import Message, Sum, Sum2, Update
 from ..core.message.encoder import DEFAULT_MAX_MESSAGE_SIZE, MIN_MESSAGE_SIZE, MessageEncoder
-from ..telemetry import tracing as trace
+from ..telemetry import codec, tracing as trace
 from ..utils import native
 from .traits import ModelStore, Notify, XaynetClient
 
@@ -49,6 +50,19 @@ logger = logging.getLogger("xaynet.participant")
 # encoder's spans, under it); the seal is its last step
 SPAN_COMPOSE = trace.declare_span("message.compose")
 SPAN_SEAL = trace.declare_span("message.seal")
+# the sum participant's two steps before it composes its Sum2 message: the
+# seed dictionary fetched and every seed box opened (recorded when it ends:
+# the polls before the dictionary is served leave no span), then the masks derived
+# and summed (attrs: masks = the seeds opened, elements, route; an attribute
+# with "seed" in its name would leave the process redacted)
+SPAN_OPEN_SEEDS = trace.declare_span("sum2.open_seeds")
+SPAN_DERIVE = trace.declare_span("sum2.derive")
+
+
+def _derived_by_route() -> dict[str, float]:
+    """Elements this process has derived so far, by route."""
+    return {key[1]: child.value for key, child in codec.ELEMENTS.children()
+            if key[0] == "derive"}
 
 
 def _is_transient_client_error(err: BaseException) -> bool:
@@ -413,17 +427,28 @@ class StateMachine:
     async def _step_sum2(self) -> TransitionOutcome:
         """Fetch seeds, derive + aggregate masks, upload (sum2.rs:82-204)."""
         assert self.round_params is not None and self.ephm_keys is not None
+        tracer = trace.get_tracer()
+        asked = time.monotonic()
         seeds = await self.client.get_seeds(self.keys.public)
         if not seeds:
-            return TransitionOutcome.PENDING
-
-        length = self.round_params.model_length
-        config = self.round_params.mask_config
+            return TransitionOutcome.PENDING  # a poll that found nothing leaves no span
         mask_seeds = [
             encrypted.decrypt(self.ephm_keys.secret, self.ephm_keys.public)
             for encrypted in seeds.values()
         ]
-        mask_obj = self._aggregate_masks(mask_seeds, length, config)
+        tracer.record_span(SPAN_OPEN_SEEDS, start=asked, duration=time.monotonic() - asked,
+                           masks=len(mask_seeds))
+
+        length = self.round_params.model_length
+        config = self.round_params.mask_config
+        with tracer.span(SPAN_DERIVE, masks=len(mask_seeds), elements=length) as span:
+            before = _derived_by_route()
+            mask_obj = self._aggregate_masks(mask_seeds, length, config)
+            # the route most of the elements took (telemetry/codec.py):
+            # `fused`, `fast` or `generic` on the host (the unit's one
+            # element always goes `fast`); none of them moves on a device
+            moved = {r: n - before.get(r, 0) for r, n in _derived_by_route().items()}
+            span.set(route=max(moved, key=moved.get) if any(moved.values()) else "device")
 
         payload = Sum2(sum_signature=self.sum_signature, model_mask=mask_obj)
         return await self._send(payload, PhaseKind.AWAITING)

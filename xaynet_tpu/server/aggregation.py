@@ -33,6 +33,7 @@ from ..ops import limbs as limb_ops
 from ..resilience.checkpoint import AggSnapshot
 from ..telemetry import profiling
 from ..telemetry import tracing as trace
+from ..telemetry import unmask as unmask_stages
 from ..utils.tracing import current_request_id
 from . import stages
 
@@ -142,58 +143,47 @@ class DeviceAggregation(Aggregation):
         falls back to the drain-time subtract, byte-identical either way:
         a failed shard's accumulator is untouched)."""
         stream = self._stream
-        planar = self._device.mask_planar(mask_obj.vect.data)
-        job = stream.stage_unmask(planar)
+        # the device's part runs on the shard workers; what this task does
+        # meanwhile carries the stages' names (telemetry/unmask.py)
+        with unmask_stages.stage("mask_put", bytes=mask_obj.vect.data.nbytes):
+            planar = self._device.mask_planar(mask_obj.vect.data)
+            job = stream.stage_unmask(planar)
         try:
             # the deferred acceptance sync + completion barrier; fold
             # errors surface here exactly as they would have at the
             # sum2 finalize in the serial flow
-            stream.drain()
+            with unmask_stages.stage("subtract"):
+                stream.drain()
         except Exception:
             self._settle_stream()
             raise
-        out = stream.finish_unmask(job) if job is not None else None
+        out = None
+        if job is not None:
+            with unmask_stages.stage("fetch", bytes=job.out.nbytes):
+                out = stream.finish_unmask(job)
         self._settle_stream()
         return out
 
-    def _unmasked_limbs(self, mask_obj: MaskObject) -> tuple[np.ndarray, int]:
+    # ``unmask`` and ``unmask_array`` are the base's: they read the carried
+    # config pair (``self.config``) and these two, never ``self.object``
+
+    def _unmasked_vect(self, mask_obj: MaskObject) -> np.ndarray:
+        # mask_put, subtract and fetch are bracketed where they run
+        # (ShardedAggregator.unmask_limbs, or the eager arm above).
         # per-shard in-place subtract: the mask planes upload with the
         # accumulator's sharding and each device subtracts its own slice;
         # the gather happens AFTER the subtraction, on the unmasked result
         n_vect = self._eager_unmask(mask_obj) if self._stream is not None else None
         if n_vect is None:
             n_vect = self._device.unmask_limbs(mask_obj.vect.data)
+        return n_vect
+
+    def _unmasked_unit(self, mask_obj: MaskObject) -> int:
         ol_u = limb_ops.order_limbs_for(self._config.unit.order)
         n_unit = limb_ops.mod_sub(
             self._unit_acc[None, :], np.asarray(mask_obj.unit.data)[None, :], ol_u
         )[0]
-        return n_vect, limb_ops.limbs_to_int(n_unit)
-
-    # the base implementations read configs through ``self.object`` —
-    # which HERE would gather the mesh accumulator; re-expressed on the
-    # carried config pair so unmasking never touches the property
-    def unmask_array(self, mask_obj: MaskObject) -> np.ndarray:
-        from ..core.mask.encode import (
-            decode_scalar_sum,
-            decode_vect_any,
-            decode_vect_fast,
-            has_fast_path,
-        )
-
-        n_vect, n_unit = self._unmasked_limbs(mask_obj)
-        scalar_sum = decode_scalar_sum(n_unit, self._config.unit, self.nb_models)
-        if has_fast_path(self._config.vect):
-            return decode_vect_fast(n_vect, self._config.vect, self.nb_models, scalar_sum)
-        return decode_vect_any(n_vect, self._config.vect, self.nb_models, scalar_sum)
-
-    def unmask(self, mask_obj: MaskObject):
-        from ..core.mask.encode import decode_scalar_sum, decode_vect_exact
-        from ..core.mask.model import Model
-
-        n_vect, n_unit = self._unmasked_limbs(mask_obj)
-        scalar_sum = decode_scalar_sum(n_unit, self._config.unit, self.nb_models)
-        values = limb_ops.limbs_to_ints(n_vect)
-        return Model(decode_vect_exact(values, self._config.vect, self.nb_models, scalar_sum))
+        return limb_ops.limbs_to_int(n_unit)
 
 
 class _OpenBatch:
@@ -461,7 +451,7 @@ class StagedAggregator:
             # the relayout outlives this call (and may outlive the request
             # that staged it), so its span LINKS the caller's span instead
             # of parenting to it
-            caller, rid = trace.current_ctx(), current_request_id()
+            caller, rid, arrived = trace.current_ctx(), current_request_id(), stages.current_phase()
             if planar_dev is not None:
                 # wire ingest: validate_aggregation already unpacked this
                 # update on device — stage the device-resident planar
@@ -478,7 +468,9 @@ class StagedAggregator:
 
                 def write_slot(data=obj.vect.data):
                     buf = batch.buffer(stream)
-                    with stages.stage("to_planar", link=caller, rid=rid, bytes=data.nbytes):
+                    with stages.stage(
+                        "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes
+                    ):
                         stream.stage_row(buf, slot, data)
 
                 batch.writes.append(self._ingest_pool.submit(write_slot))
@@ -490,7 +482,9 @@ class StagedAggregator:
                 padded = self._device.padded_length
 
                 def to_planar(data=obj.vect.data):
-                    with stages.stage("to_planar", link=caller, rid=rid, bytes=data.nbytes):
+                    with stages.stage(
+                        "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes
+                    ):
                         planar = wire_to_planar(data)
                         if planar.shape[1] != padded:
                             planar = np.pad(planar, ((0, 0), (0, padded - planar.shape[1])))

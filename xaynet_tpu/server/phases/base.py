@@ -46,7 +46,7 @@ from ..requests import (
     PartialAggregate,
     RequestError,
     RequestReceiver,
-    UPDATE_REQUESTS,
+    STAGED_REQUESTS,
     StateMachineRequest,
 )
 from ..settings import PhaseSettings, Settings, Sum2Settings
@@ -508,8 +508,13 @@ class PhaseState:
                 last_accept = time_mod.monotonic()
 
     async def _process_single(self, env, counter: _Counter) -> None:
-        if env.enqueued and isinstance(env.request, UPDATE_REQUESTS):
-            stages.waited("request_wait", env.enqueued, ctx=env.ctx, rid=env.request_id)
+        if env.phase == "-":
+            # nothing named the arrival (the ingest pipeline's batches): the
+            # phase that handles the message stands in
+            env.phase = self.NAME.value
+        if env.enqueued and isinstance(env.request, STAGED_REQUESTS):
+            stages.waited("request_wait", env.enqueued, ctx=env.ctx, rid=env.request_id,
+                          phase=env.phase)
         if isinstance(env.request, CoalescedUpdates):
             # unpack the micro-batch: every member is counted, handled and
             # answered exactly as if it had arrived alone (count.min/max
@@ -517,7 +522,7 @@ class PhaseState:
             # phase gets one batch-done hook for the stacked fold dispatch
             try:
                 await self.coalesced_batch_start(env.request.members)
-                for member_env in env.request.envelopes(env.request_id):
+                for member_env in env.request.envelopes(env.request_id, env.phase):
                     await self._process_single(member_env, counter)
                 await self.coalesced_batch_done(len(env.request))
             except BaseException as err:
@@ -550,7 +555,9 @@ class PhaseState:
             # the sender's request id and trace context, re-entered on this
             # side of the channel: the phase's per-message spans are
             # children of the message's own request span
-            with tracing.use_request_id(env.request_id), trace.use_ctx(env.ctx):
+            with tracing.use_request_id(env.request_id), stages.use_phase(
+                env.phase
+            ), trace.use_ctx(env.ctx):
                 await self.handle_request(env.request)
         except RequestError as err:
             counter.rejected += 1

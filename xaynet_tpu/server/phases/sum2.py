@@ -41,6 +41,7 @@ from ...resilience.checkpoint import (
 )
 from ...telemetry import tracing as trace
 from ...telemetry.timeline import record_overlap
+from .. import stages
 from ..aggregation import StagedAggregator
 from ..events import DictionaryUpdate, PhaseName
 from ..requests import RequestError, StateMachineRequest, Sum2Request
@@ -182,17 +183,20 @@ class Sum2Phase(PhaseState):
     async def handle_request(self, req: StateMachineRequest) -> None:
         if not isinstance(req, Sum2Request):
             raise RequestError(RequestError.Kind.MESSAGE_REJECTED, "not a sum2 message")
-        err = await self.shared.store.coordinator.incr_mask_score(
-            req.participant_pk, req.model_mask
-        )
-        if err is not None:
-            raise RequestError(RequestError.Kind.MESSAGE_REJECTED, err.value)
-        if self._base is not None:
-            # journal-before-ack: the accepted vote is durable before the
-            # acknowledgement leaves (rewrite; votes are mask-sized)
-            self._votes.append(
-                (req.participant_pk, serialize_mask_object(req.model_mask))
+        # this phase's stage of the message's chain (server/stages.py), as
+        # validate, seed_dict, stage and flush are the Update phase's
+        with stages.stage("score", bytes=req.model_mask.vect.data.nbytes):
+            err = await self.shared.store.coordinator.incr_mask_score(
+                req.participant_pk, req.model_mask
             )
-            self._base.mask_votes = list(self._votes)
-            await write_entry(self.shared, self._base)
+            if err is not None:
+                raise RequestError(RequestError.Kind.MESSAGE_REJECTED, err.value)
+            if self._base is not None:
+                # journal-before-ack: the accepted vote is durable before the
+                # acknowledgement leaves (rewrite; votes are mask-sized)
+                self._votes.append(
+                    (req.participant_pk, serialize_mask_object(req.model_mask))
+                )
+                self._base.mask_votes = list(self._votes)
+                await write_entry(self.shared, self._base)
         maybe_kill("sum2")

@@ -27,6 +27,7 @@ from ...core.mask.masking import Aggregation, UnmaskingError
 from ...core.mask.object import MaskObject
 from ...resilience.chaos import maybe_kill
 from ...telemetry import profiling
+from ...telemetry import unmask as unmask_stages
 from ...telemetry.registry import get_registry
 from ..events import ModelUpdate, PhaseName
 from .base import PhaseError, PhaseState
@@ -49,17 +50,22 @@ class Unmask(PhaseState):
         self.global_model: np.ndarray | None = None
 
     async def process(self) -> None:
-        if self.shared.metrics is not None:
-            n_masks = await self.shared.store.coordinator.number_of_unique_masks()
-            self.shared.metrics.masks_total(self.shared.round_id, n_masks)
-        best = await self.shared.store.coordinator.best_masks()
-        if best is None:
-            raise PhaseError("NoMask", "no masks submitted")
-        mask = self._freeze_mask_dict(best)
-        try:
-            self.model_agg.validate_unmasking(mask)
-        except UnmaskingError as err:
-            raise PhaseError("Unmasking", err.kind) from err
+        # the phase, stage by stage (telemetry/unmask.py): the brackets here,
+        # in the aggregation's ``unmask_array`` (mask_put, subtract, fetch,
+        # decode) and nothing between them
+        with unmask_stages.stage("elect"):
+            if self.shared.metrics is not None:
+                n_masks = await self.shared.store.coordinator.number_of_unique_masks()
+                self.shared.metrics.masks_total(self.shared.round_id, n_masks)
+            best = await self.shared.store.coordinator.best_masks()
+            if best is None:
+                raise PhaseError("NoMask", "no masks submitted")
+            mask = self._freeze_mask_dict(best)
+        with unmask_stages.stage("validate", bytes=mask.vect.data.nbytes):
+            try:
+                self.model_agg.validate_unmasking(mask)
+            except UnmaskingError as err:
+                raise PhaseError("Unmasking", err.kind) from err
         from ..aggregation import DeviceAggregation
 
         if isinstance(self.model_agg, DeviceAggregation):
@@ -71,17 +77,21 @@ class Unmask(PhaseState):
             self.global_model = profiling.timed_kernel(
                 "unmask", len(self.model_agg), lambda: self.model_agg.unmask_array(mask)
             )
-        await self._save_global_model()
+        with unmask_stages.stage("save", bytes=8 * len(self.global_model)):
+            await self._save_global_model()
         # chaos hook (kill-matrix harness): the publish window — the model
         # is persisted but the journal not yet retired; a restart must
         # republish idempotently (ModelStorage contract), never corrupt
         maybe_kill("unmask:publish")
-        await self._publish_proof()
+        if self.shared.store.trust_anchor is not None:
+            with unmask_stages.stage("proof", bytes=8 * len(self.global_model)):
+                await self._publish_proof()
         if self.shared.settings.resilience.checkpoint_enabled:
             # retire the round journal: the model is published — nothing
             # left for a resume to redo
             # (Idle's delete is the backstop for disabled-journal configs)
-            await self.shared.store.coordinator.delete_round_checkpoint()
+            with unmask_stages.stage("retire"):
+                await self.shared.store.coordinator.delete_round_checkpoint()
 
     def broadcast(self) -> None:
         assert self.global_model is not None
@@ -141,8 +151,6 @@ class Unmask(PhaseState):
             logger.warning("failed to update latest global model id: %s", err)
 
     async def _publish_proof(self) -> None:
-        if self.shared.store.trust_anchor is None:
-            return
         assert self.global_model is not None
         data = np.asarray(self.global_model, dtype=np.float64).tobytes()
         await self.shared.store.trust_anchor.publish_proof(data)

@@ -87,12 +87,13 @@ class CoalescedUpdates:
     def __len__(self) -> int:
         return len(self.members)
 
-    def envelopes(self, fallback_request_id: str = "-"):
+    def envelopes(self, fallback_request_id: str = "-", phase: str = "-"):
         """One per-member ``_Envelope``, carrying the member's own tracing
-        id (so batched log lines keep per-message correlation)."""
+        id (so batched log lines keep per-message correlation) and the
+        batch's arrival phase."""
         ids = self.request_ids or [fallback_request_id] * len(self.members)
         return [
-            _Envelope(req, fut, rid)
+            _Envelope(req, fut, rid, phase)
             for req, fut, rid in zip(self.members, self.responses, ids)
         ]
 
@@ -142,8 +143,9 @@ class EnvelopeReplay(Exception):
     folded envelope as rejected data loss."""
 
 
-# what travels the per-message stage chain of server/stages.py
-UPDATE_REQUESTS = (UpdateRequest, CoalescedUpdates)
+# what travels the per-message stage chain of server/stages.py: a message
+# of any phase (an edge's partial aggregate is an envelope, not a message)
+STAGED_REQUESTS = (SumRequest, UpdateRequest, Sum2Request, CoalescedUpdates)
 
 StateMachineRequest = Union[
     SumRequest, UpdateRequest, Sum2Request, CoalescedUpdates, PartialAggregate
@@ -172,6 +174,9 @@ class _Envelope:
     request: StateMachineRequest
     response: asyncio.Future
     request_id: str = "-"
+    # the phase its coordinator was in when the message arrived
+    # (server/stages.py): the label of every stage on the far side
+    phase: str = "-"
     # when it entered the channel (``time.monotonic()``) and the sender's
     # trace context: the phase takes the channel wait from the first and
     # parents its per-message spans to the second (server/stages.py).
@@ -299,11 +304,12 @@ class RequestSender:
         """
         fut: asyncio.Future = asyncio.get_running_loop().create_future()
         env = _Envelope(
-            req, fut, tracing.current_request_id(), time.monotonic(), trace.current_ctx()
+            req, fut, tracing.current_request_id(), stages.current_phase(),
+            time.monotonic(), trace.current_ctx(),
         )
         self._receiver._enqueue(env)
         try:
             await fut
         finally:
-            if env.resolved and isinstance(req, UPDATE_REQUESTS):
+            if env.resolved and isinstance(req, STAGED_REQUESTS):
                 stages.waited("verdict_wait", env.resolved)

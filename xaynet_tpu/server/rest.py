@@ -40,6 +40,7 @@ import numpy as np
 
 from ..telemetry import tracing as trace
 from ..telemetry.registry import MetricsRegistry, get_registry
+from ..telemetry.startup import get_timeline
 from ..utils import native, tracing
 from . import stages
 from .events import PhaseName
@@ -494,9 +495,18 @@ class RestServer:
             # admission span below lands in the same trace
             remote = trace.parse_header(headers.get(trace.TRACE_HEADER.lower()))
             # a message is named here, before its body is read: every stage
-            # span from the socket to the fold carries this id as `rid`
-            with tracing.use_request_id(tracing.make_request_id()), trace.get_tracer().span(
-                SPAN_REQUEST, link=remote, method=method, path=path, tenant=tenant
+            # span from the socket to the fold carries this id as `rid`, and
+            # as `phase` the phase its own tenant's coordinator is in now (a
+            # server that only takes messages leaves that to its handler)
+            arrived = (
+                {"phase": routes.fetcher.phase().value}
+                if method == "POST" and path == "/message" and routes.fetcher is not None
+                else {}
+            )
+            with tracing.use_request_id(tracing.make_request_id()), stages.use_phase(
+                arrived.get("phase", "-")
+            ), trace.get_tracer().span(
+                SPAN_REQUEST, link=remote, method=method, path=path, tenant=tenant, **arrived
             ) as span:
                 if read_body is not None:
                     with stages.stage(
@@ -618,6 +628,11 @@ class RestServer:
                     "mirror": tracer.mirrored,
                     "mirrored_spans": trace.mirrored_span_names(),
                 }
+                # process start to serving, step by step (where a runner
+                # has marked the steps: telemetry/startup.py)
+                startup = get_timeline().report()
+                if startup is not None:
+                    payload["startup"] = startup
                 if routes.health_extra is not None:
                     # role-specific sections (the edge runner reports its
                     # upstream link + envelope backlog here); an extra

@@ -26,6 +26,7 @@ from ..storage.memory import (
 )
 from ..storage.traits import Store
 from ..telemetry import BridgedMetrics, RoundReporter
+from ..telemetry.startup import get_timeline
 from ..utils import tracing
 from .metrics import InfluxHttpMetrics, InfluxLineMetrics, JsonlMetrics, LogMetrics
 from .rest import RestServer
@@ -117,17 +118,28 @@ def init_logging(settings: Settings) -> None:
             handler.addFilter(tracing.RequestIdFilter())
 
 
+def _mark_serving(startup) -> None:
+    """The API accepts requests: the timeline's last mark, and from the same
+    marks the restart-to-serving wall (docs/DESIGN.md §9): entry of
+    ``serve()`` to the API accepting requests, store restore + journal
+    resume included — THE recovery-time number the kill-matrix gate tracks."""
+    from ..resilience.checkpoint import RECOVERY_SECONDS
+
+    startup.mark("serving")
+    RECOVERY_SECONDS.set(startup.seconds("imports", "serving"))
+
+
 async def serve(settings: Settings, store: Optional[Store] = None) -> None:
     if settings.tenancy.enabled:
         # multi-tenant wiring: one process, one REST listener, N tenant
         # round pipelines over the shared mesh/pool/scheduler (§19)
         await serve_tenants(settings)
         return
-    import time as _time
-
-    boot_t0 = _time.monotonic()
+    startup = get_timeline()
+    startup.mark("imports")
     init_logging(settings)
     device_report = init_device_backend(settings)
+    startup.mark("backend")
     store = store if store is not None else init_store(settings)
     if settings.storage.backend == "s3":
         # reference creates the bucket at startup (main.rs init_store path)
@@ -147,6 +159,7 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
     from ..resilience import wrap_store
 
     store = wrap_store(store, settings.resilience)
+    startup.mark("store")
     # registry-first telemetry: the configured sink (if any) and the
     # per-round JSON reporter both consume the bridge's measurements
     reporter = (
@@ -179,6 +192,7 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
     calibcache.configure_from_env()
     initializer = StateMachineInitializer(settings, store, metrics)
     machine, request_tx, events = await initializer.init()
+    startup.mark("machine")
 
     handler = PetMessageHandler(
         events, request_tx, wire_ingest=settings.aggregation.wire_ingest
@@ -215,12 +229,7 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
             tls.verify_mode = ssl.CERT_REQUIRED
             tls.load_verify_locations(settings.api.tls_client_auth)
     await rest.start(host or "127.0.0.1", int(port or 8081), tls)
-    # restart-to-serving wall (docs/DESIGN.md §9): process entry to the API
-    # accepting requests, store restore + journal resume included — THE
-    # recovery-time number the kill-matrix bench gate tracks
-    from ..resilience.checkpoint import RECOVERY_SECONDS
-
-    RECOVERY_SECONDS.set(_time.monotonic() - boot_t0)
+    _mark_serving(startup)
 
     stop = asyncio.get_running_loop().create_future()
     for sig in (signal.SIGINT, signal.SIGTERM):
@@ -308,7 +317,9 @@ async def _build_tenant_context(settings: Settings, tenant: str, budget, registr
     from .rest import TenantRoutes
 
     tset = _tenant_settings(settings, tenant)
+    startup = get_timeline()  # a boot's first tenant marks; later ones find the steps marked
     device_report = init_device_backend(tset)
+    startup.mark("backend")
     raw_store = init_store(tset, tenant)
     if tset.storage.backend == "s3":
         # same startup contract as the single-tenant serve() path:
@@ -318,6 +329,7 @@ async def _build_tenant_context(settings: Settings, tenant: str, budget, registr
         if isinstance(raw_store.models, S3ModelStorage):
             await raw_store.models.create_bucket()
     store = wrap_store(raw_store, tset.resilience, tenant=tenant)
+    startup.mark("store")
     reporter = (
         RoundReporter(tset.metrics.round_report_path, tenant=tenant)
         if tset.metrics.round_report_path
@@ -393,9 +405,8 @@ async def serve_tenants(settings: Settings) -> None:
     )
     from .rest import TenantRoutes
 
-    import time as _time
-
-    boot_t0 = _time.monotonic()
+    startup = get_timeline()
+    startup.mark("imports")
     init_logging(settings)
     ten = settings.tenancy
     configure_pool(ten.page_kib, ten.slab_pages, ten.host_pages, ten.device_pages)
@@ -425,6 +436,7 @@ async def serve_tenants(settings: Settings) -> None:
     for tenant in ten.tenants:
         _, troutes = await _build_tenant_context(settings, tenant, budget, registry)
         routes[tenant] = troutes
+    startup.mark("machine")  # every tenant's; backend and store are the first one's
 
     # elastic lifecycle (docs/DESIGN.md §23): the manager owns runtime
     # onboard/drain over the SAME builder the boot loop used, fault
@@ -466,12 +478,9 @@ async def serve_tenants(settings: Settings) -> None:
             tls.verify_mode = ssl.CERT_REQUIRED
             tls.load_verify_locations(settings.api.tls_client_auth)
     await rest.start(host or "127.0.0.1", int(port or 8081), tls)
-    # restart-to-serving wall: EVERY tenant's store restore + journal
-    # resume ran before the listener came up (each tenant resumes
-    # independently from its scoped journal)
-    from ..resilience.checkpoint import RECOVERY_SECONDS
-
-    RECOVERY_SECONDS.set(_time.monotonic() - boot_t0)
+    # EVERY tenant's store restore + journal resume ran before the listener
+    # came up (each tenant resumes independently from its scoped journal)
+    _mark_serving(startup)
     logger.info(
         "multi-tenant coordinator up: %d tenants (%s), default=%s",
         len(registry),

@@ -84,9 +84,14 @@ class PetMessageHandler:
         Raises ``ServiceError`` (pipeline drop) or ``RequestError`` (state
         machine rejection).
         """
-        with tracing.use_request_id(tracing.request_id_or_fresh()):
-            with stages.SECONDS.labels(stage="total").time():
-                with stages.SECONDS.labels(stage="decrypt_parse").time():
+        # the REST layer has named the message and the phase it arrived in;
+        # callers that skip the socket (in-process clients, tests) have not
+        arrived = stages.current_phase()
+        if arrived == "-":
+            arrived = self.events.phase.get_latest().event.value
+        with tracing.use_request_id(tracing.request_id_or_fresh()), stages.use_phase(arrived):
+            with stages.seconds("total").time():
+                with stages.seconds("decrypt_parse").time():
                     message = await self._parse_message(encrypted)
                 if message is None:
                     return  # multipart message still incomplete
@@ -102,14 +107,18 @@ class PetMessageHandler:
         phase: PhaseName,
         ctx: Optional[trace.TraceContext] = None,
         rid: str = "-",
+        arrived: Optional[str] = None,
     ) -> Message:
         """Sealed-box open + phase filter + signature verify + parse.
 
         Synchronous CPU body shared by the per-message path and the batched
         ingest workers; always runs on a worker thread, so the caller hands
-        over what does not cross the hop: the parent span's ``ctx`` and the
-        request id.
+        over what does not cross the hop: the parent span's ``ctx``, the
+        request id and the phase the message arrived in (a batch, whose
+        members' arrivals the intake does not keep, is labelled by the
+        phase its filter runs against).
         """
+        arrived = arrived or phase.value
         # sealed-box open (CPU) — reference: decryptor.rs:48-69. Passing our
         # public key skips a per-message X25519 recompute of it (milliseconds
         # per message on the pure-python fallback). A ``bytearray`` is the
@@ -117,7 +126,7 @@ class PetMessageHandler:
         # the pipeline gives it up and a long box is opened over its own
         # ciphertext; ``raw`` is then a view that keeps the buffer alive (and
         # so does a lazily parsed vector that points into it)
-        with stages.stage("open", ctx=ctx, rid=rid, bytes=len(encrypted)):
+        with stages.stage("open", ctx=ctx, rid=rid, phase=arrived, bytes=len(encrypted)):
             try:
                 if isinstance(encrypted, bytearray):
                     raw = keys.secret.decrypt_in_place(encrypted, keys.public)
@@ -142,9 +151,9 @@ class PetMessageHandler:
         # signature verification, then the full parse: one pass each over
         # the body, timed apart
         try:
-            with stages.stage("verify", ctx=ctx, rid=rid, bytes=len(raw)):
+            with stages.stage("verify", ctx=ctx, rid=rid, phase=arrived, bytes=len(raw)):
                 Message.verify_bytes(raw)
-            with stages.stage("parse", ctx=ctx, rid=rid, bytes=len(raw)):
+            with stages.stage("parse", ctx=ctx, rid=rid, phase=arrived, bytes=len(raw)):
                 return Message.from_bytes(
                     raw, verify=False, lazy_update_vect=self.wire_ingest
                 )
@@ -156,10 +165,11 @@ class PetMessageHandler:
         keys: EncryptKeyPair = self.events.keys.get_latest().event
         phase: PhaseName = self.events.phase.get_latest().event
         ctx, rid, submitted = trace.current_ctx(), tracing.current_request_id(), time.monotonic()
+        arrived = stages.current_phase()
 
         def on_worker() -> tuple[Message, float]:
-            stages.waited("pool_wait", submitted, ctx=ctx, rid=rid)
-            message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid)
+            stages.waited("pool_wait", submitted, ctx=ctx, rid=rid, phase=arrived)
+            message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid, arrived)
             return message, time.monotonic()
 
         message, returned = await loop.run_in_executor(self._pool, on_worker)
@@ -197,7 +207,7 @@ class PetMessageHandler:
                     out.append(e)
             return out
 
-        with stages.SECONDS.labels(stage="decrypt_parse_batch").time():
+        with stages.seconds("decrypt_parse_batch", phase.value).time():
             results = await loop.run_in_executor(self._pool, run)
         final = []
         for res in results:

@@ -1,12 +1,24 @@
-"""The stages of one Update message, socket to fold (docs/DESIGN.md §16).
+"""The stages of one message, socket to verdict (docs/DESIGN.md §16).
 
 A message's residence in the coordinator is a chain of stages on several
 threads and two queues. Each stage is written down twice by one call here:
 as a span of the tracer (``telemetry/tracing.py``; child of the message's
 ``rest.request`` span, attribute ``rid`` = the request id of
 ``utils/tracing.py``) and as one observation on
-``xaynet_message_pipeline_seconds{stage=...}``. The spans say where one
-message's seconds went; the histogram says it for a window of ``/metrics``.
+``xaynet_message_pipeline_seconds{stage=..., phase=...}``. The spans say
+where one message's seconds went; the histogram says it for a window of
+``/metrics``.
+
+``phase`` is the phase the message's own coordinator was in **when the
+message arrived**: read once from that coordinator's published state where
+the message's ``rest.request`` span opens (``server/rest.py``; callers that
+skip the socket: ``PetMessageHandler.handle_message``) and carried with the
+message as its ``rid`` is (:func:`use_phase`, the request envelope), so all
+stages of one message carry one phase: the last update's ``verdict_wait``
+ends after the phase has moved on and is still an ``update`` observation,
+and the Sum2 message is ``sum2`` from its first byte. It is no process-wide
+variable: one process can serve several tenants. ``-`` = no message is
+being handled (work staged by a test or a tool).
 
 Work is bracketed where it happens (:func:`stage`, a ``with`` block). The
 waits start on one task or thread and end on another, so they are recorded
@@ -17,6 +29,10 @@ the worker has returned (``resume_wait``), in the request channel
 verdict (``verdict_wait``). Those reach the tracer and the histogram, not
 the mirror sink, which has no call for an interval that is already over.
 
+What a phase does with the message is a stage of the chain too:
+``validate``, ``seed_dict``, ``stage`` and ``flush`` are the Update
+phase's, ``score`` the Sum2 phase's.
+
 The labels ``total`` (a message's whole handling after its body is read)
 and ``decrypt_parse`` / ``decrypt_parse_batch`` (the pool hop, wait
 included) keep their older meaning; no stage label here starts with
@@ -25,6 +41,7 @@ included) keep their older meaning; no stage label here starts with
 
 from __future__ import annotations
 
+import contextvars
 import time
 from contextlib import contextmanager
 from typing import Optional
@@ -39,10 +56,11 @@ SECONDS = get_registry().histogram(
     "xaynet_message_pipeline_seconds",
     "Wall time of one stage of a message's handling, by stage: read_body, "
     "pool_wait, open, verify, parse, resume_wait, request_wait, validate, "
-    "seed_dict, stage, to_planar, flush, verdict_wait (server/stages.py); "
-    "decrypt_parse[_batch] = the pool hop (pool_wait to resume_wait); "
-    "total = body read to the state machine's verdict.",
-    ("stage",),
+    "seed_dict, stage, to_planar, flush, score, verdict_wait "
+    "(server/stages.py); decrypt_parse[_batch] = the pool hop (pool_wait to "
+    "resume_wait); total = body read to the state machine's verdict. phase = "
+    "the phase the message's coordinator was in when the message arrived.",
+    ("stage", "phase"),
     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
              0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0),
 )
@@ -62,25 +80,47 @@ _SPANS: dict[str, str] = {
     "stage": trace.declare_span("update.stage", mirror=True),
     "to_planar": trace.declare_span("update.to_planar", mirror=True),
     "flush": trace.declare_span("update.flush", mirror=True),
+    "score": trace.declare_span("sum2.score", mirror=True),
     "verdict_wait": trace.declare_span("update.verdict_wait"),
 }
 # the state machine with nothing to do: waiting for the next request
 SPAN_AWAIT_REQUEST = trace.declare_span("update.await_request", mirror=True)
 
+_phase: contextvars.ContextVar[str] = contextvars.ContextVar("xaynet_message_phase", default="-")
+
+
+def current_phase() -> str:
+    """The arrival phase of the message being handled (``-`` outside one)."""
+    return _phase.get()
+
 
 @contextmanager
+def use_phase(phase: str):
+    """Name the arrival phase of the message handled inside (where it
+    arrives) or re-enter it on the far side of a queue or a thread hop."""
+    token = _phase.set(phase)
+    try:
+        yield
+    finally:
+        _phase.reset(token)
+
+
+def seconds(label: str, phase: Optional[str] = None):
+    """The histogram child of ``label`` for ``phase`` (default: the message
+    being handled), for the lumps that are timed with no span."""
+    return SECONDS.labels(stage=label, phase=phase or _phase.get())
+
+
 def stage(label: str, ctx: Optional[trace.TraceContext] = None,
           link: Optional[trace.TraceContext] = None, **attrs):
     """Bracket one stage where it runs. ``ctx`` names the parent on a worker
     thread (the ambient context does not cross ``run_in_executor``);
-    ``rid`` defaults to the ambient request id, so pass it there too."""
+    ``rid`` and ``phase`` default to the ambient ones, so pass them there
+    too."""
     attrs.setdefault("rid", current_request_id())
-    t0 = time.monotonic()
-    try:
-        with trace.get_tracer().span(_SPANS[label], ctx=ctx, link=link, **attrs) as span:
-            yield span
-    finally:
-        SECONDS.labels(stage=label).observe(time.monotonic() - t0)
+    phase = attrs.setdefault("phase", _phase.get())
+    return trace.timed_span(_SPANS[label], SECONDS.labels(stage=label, phase=phase),
+                            ctx=ctx, link=link, **attrs)
 
 
 def waited(label: str, since: float, ctx: Optional[trace.TraceContext] = None,
@@ -88,7 +128,8 @@ def waited(label: str, since: float, ctx: Optional[trace.TraceContext] = None,
     """Record a queue wait that began at ``since`` (``time.monotonic()``, on
     another task or thread) and ends now."""
     attrs.setdefault("rid", current_request_id())
-    seconds = max(0.0, time.monotonic() - since)
-    trace.get_tracer().record_span(_SPANS[label], start=since, duration=seconds,
+    phase = attrs.setdefault("phase", _phase.get())
+    took = max(0.0, time.monotonic() - since)
+    trace.get_tracer().record_span(_SPANS[label], start=since, duration=took,
                                    ctx=ctx, **attrs)
-    SECONDS.labels(stage=label).observe(seconds)
+    SECONDS.labels(stage=label, phase=phase).observe(took)

@@ -1,0 +1,63 @@
+"""The stages of the Unmask phase, election to retire (docs/DESIGN.md §16).
+
+The phase does nine things to one object of the vector's size, all on the
+state machine's task. Each is written down twice by one call here
+(``tracing.timed_span``, as a message's stages are in ``server/stages.py``):
+as a span under ``phase.unmask`` and as one observation on
+``xaynet_unmask_seconds{stage=...}``, bracketed where the work happens:
+
+- ``elect``: the store's count of masks, its two best (with the parse of
+  the serialised mask) and the unique-maximum election (``phases/unmask.py``);
+- ``validate``: ``validate_unmasking`` (the mask's validity scan);
+- ``mask_put``: the mask relaid out planar and padded, and its
+  ``device_put`` until the array is ready (``parallel/aggregator.py``);
+- ``subtract``: the subtract kernel until its result is ready (on the host
+  arm ``mod_sub`` over the vector, ``core/mask/masking.py``);
+- ``fetch``: device to host and the transposition to the wire layout;
+- ``decode``: the unit's ``mod_sub``, ``decode_scalar_sum``, ``decode_vect_*``;
+- ``save``: the float64 bytes, the model store, the latest-model pointer;
+- ``proof`` and ``retire``: the trust anchor's proof and the journal's
+  retire, where they run.
+
+The eager per-shard unmask (docs/DESIGN.md §22) does the device's part on
+the shard workers (``overlap.eager_unmask``); what the phase's task does
+meanwhile carries the same names: ``mask_put`` is the relayout, ``subtract``
+the wait for the shards' tail jobs, ``fetch`` the assembled result.
+"""
+
+from __future__ import annotations
+
+from . import tracing as trace
+from .registry import get_registry
+
+# a toy round's microseconds up to the seconds a stage takes of a 256 MB mask
+SECONDS = get_registry().histogram(
+    "xaynet_unmask_seconds",
+    "Wall time of one stage of the Unmask phase, by stage: elect, validate, "
+    "mask_put, subtract, fetch, decode, save, proof, retire "
+    "(telemetry/unmask.py).",
+    ("stage",),
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+             0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
+)
+
+# stage label -> span name; spelled out (not built in a loop) so the
+# analysis `span` pass reads the literal set against the DESIGN §16 table.
+# Every stage starts and ends on the state machine's thread: all mirrored.
+_SPANS: dict[str, str] = {
+    "elect": trace.declare_span("unmask.elect", mirror=True),
+    "validate": trace.declare_span("unmask.validate", mirror=True),
+    "mask_put": trace.declare_span("unmask.mask_put", mirror=True),
+    "subtract": trace.declare_span("unmask.subtract", mirror=True),
+    "fetch": trace.declare_span("unmask.fetch", mirror=True),
+    "decode": trace.declare_span("unmask.decode", mirror=True),
+    "save": trace.declare_span("unmask.save", mirror=True),
+    "proof": trace.declare_span("unmask.proof", mirror=True),
+    "retire": trace.declare_span("unmask.retire", mirror=True),
+}
+
+
+def stage(label: str, **attrs):
+    """Bracket one stage of the Unmask phase where it runs (a ``with``
+    block on the phase's task: the ambient ``phase.unmask`` is the parent)."""
+    return trace.timed_span(_SPANS[label], SECONDS.labels(stage=label), **attrs)
