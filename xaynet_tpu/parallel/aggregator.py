@@ -813,9 +813,16 @@ class ShardedAggregator:
         :meth:`unmask_limbs` and the eager per-shard unmask staging
         (docs/DESIGN.md §22), which needs the planar before the drain."""
         mask = np.asarray(mask_vect, dtype=np.uint32)
-        planar = wire_to_planar(mask) if mask.shape == (self.model_length, self.n_limbs) else mask
-        if planar.shape[1] != self.padded_length:
-            planar = np.pad(planar, ((0, 0), (0, self.padded_length - planar.shape[1])))
+        if mask.shape == (self.n_limbs, self.padded_length):
+            return mask
+        if mask.shape == (self.model_length, self.n_limbs):
+            mask = mask.T
+        # one pass: the transposition is written straight into the padded
+        # array, of which only the padding columns are zeroed (not a
+        # contiguous transpose that a pad then copies)
+        planar = np.empty((self.n_limbs, self.padded_length), dtype=np.uint32)
+        planar[:, mask.shape[1] :] = 0
+        planar[:, : mask.shape[1]] = mask
         return planar
 
     def unmask_limbs(self, mask_vect) -> np.ndarray:

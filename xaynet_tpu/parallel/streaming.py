@@ -1922,6 +1922,20 @@ class StreamingAggregator:
 
     # -- eager per-shard unmask (docs/DESIGN.md §22) ------------------------
 
+    def can_stage_unmask(self) -> bool:
+        """Whether :meth:`stage_unmask` would take a mask now: a sharded
+        pipeline with a live plan, neither degraded, poisoned nor closed.
+        The caller asks before it relays the mask out, so a pipeline that
+        cannot stage (one device, above all) costs it no planar."""
+        with self._lock:
+            return (
+                self._sharded
+                and self._plan is not None
+                and not self._degraded
+                and self._error is None
+                and not self._closed
+            )
+
     def stage_unmask(self, mask_planar: np.ndarray) -> "_UnmaskJob | None":
         """Enqueue the round's unmask as per-shard tail jobs: each shard
         subtracts its mask slice as soon as ITS last queued fold commits,
@@ -1930,16 +1944,7 @@ class StreamingAggregator:
         eager path (not sharded, no live plan, degraded, or poisoned) —
         the caller falls back to the drain-time unmask. The returned job
         settles in :meth:`finish_unmask`."""
-        with self._lock:
-            plan = self._plan
-            eligible = (
-                self._sharded
-                and plan is not None
-                and not self._degraded
-                and self._error is None
-                and not self._closed
-            )
-        if not eligible:
+        if not self.can_stage_unmask():
             return None
         agg = self.agg
         out = np.empty((agg.model_length, agg.n_limbs), dtype=np.uint32)

@@ -143,11 +143,12 @@ class DeviceAggregation(Aggregation):
         falls back to the drain-time subtract, byte-identical either way:
         a failed shard's accumulator is untouched)."""
         stream = self._stream
-        # the device's part runs on the shard workers; what this task does
-        # meanwhile carries the stages' names (telemetry/unmask.py)
-        with unmask_stages.stage("mask_put", bytes=mask_obj.vect.data.nbytes):
-            planar = self._device.mask_planar(mask_obj.vect.data)
-            job = stream.stage_unmask(planar)
+        job = None
+        if stream.can_stage_unmask():
+            # the device's part runs on the shard workers; what this task
+            # does meanwhile carries the stages' names (telemetry/unmask.py)
+            with unmask_stages.stage("mask_put", bytes=mask_obj.vect.data.nbytes):
+                job = stream.stage_unmask(self._device.mask_planar(mask_obj.vect.data))
         try:
             # the deferred acceptance sync + completion barrier; fold
             # errors surface here exactly as they would have at the

@@ -8,18 +8,26 @@ single submission before writing; mask scores require sum membership and a
 single submission per participant. Atomicity here comes from the asyncio
 single-thread execution model (no awaits inside the critical sections).
 
-Masks in the score dict are keyed by their serialized bytes, mirroring the
-Redis sorted-set keyed by the serialized mask object.
+Mask votes are counted under the mask's canonical content (both
+configurations, every element of the limb array, the unit): two votes share
+a count exactly when their ``MaskObject``s are equal, which is when their
+canonical serialisations (the Redis sorted set's members) are byte-equal.
+The first parsed object seen for a mask is the one kept and the one
+``best_masks`` hands to the election: nothing is serialised to score and
+nothing parsed to elect (docs/DESIGN.md §16 "The vote key").
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Optional
+
+import numpy as np
 
 from ..core.common import LocalSeedDict, SeedDict, SumDict
 from ..core.mask.object import MaskObject
-from ..core.mask.serialization import parse_mask_object, serialize_mask_object
 from .traits import (
+    MASK_VOTES,
     CoordinatorStorage,
     LocalSeedDictAddError,
     MaskScoreIncrError,
@@ -30,13 +38,46 @@ from .traits import (
 )
 
 
+# elements of the vector that go into a vote's prefilter: what an honest
+# mask of other seeds differs in with all but one chance in the group's order
+_PREFILTER_ELEMENTS = 16
+
+
+def _vote_prefilter(mask: MaskObject) -> bytes:
+    """A few dozen bytes two equal masks must share: the configurations,
+    the length, the unit and the vector's first elements. Never the test
+    itself: a vote is counted with a kept mask only after the full
+    comparison (``MaskObject.__eq__``), which the prefilter spares for a
+    round's first vote and for masks of other seeds."""
+    return b"".join(
+        (
+            mask.vect.config.to_bytes(),
+            mask.unit.config.to_bytes(),
+            len(mask.vect).to_bytes(8, "big"),
+            # one dtype: equal masks must never differ in their prefilter
+            np.asarray(mask.unit.data, dtype=np.uint32).tobytes(),
+            np.asarray(mask.vect.data[:_PREFILTER_ELEMENTS], dtype=np.uint32).tobytes(),
+        )
+    )
+
+
+@dataclass
+class _ScoredMask:
+    """One distinct mask of the round: the first parsed object voted for it
+    (owned by the store until ``delete_dicts``) and its count of votes."""
+
+    prefilter: bytes
+    mask: MaskObject
+    score: int = 1
+
+
 class InMemoryCoordinatorStorage(CoordinatorStorage):
     def __init__(self):
         self._state: Optional[bytes] = None
         self._sum_dict: dict[bytes, bytes] = {}
         self._seed_dict: dict[bytes, dict[bytes, object]] = {}
         self._update_submitted: set[bytes] = set()
-        self._mask_scores: dict[bytes, int] = {}
+        self._mask_scores: list[_ScoredMask] = []  # in order of first vote
         self._mask_submitted: set[bytes] = set()
         self._latest_global_model_id: Optional[str] = None
 
@@ -84,16 +125,22 @@ class InMemoryCoordinatorStorage(CoordinatorStorage):
             return MaskScoreIncrError.UNKNOWN_SUM_PK
         if pk in self._mask_submitted:
             return MaskScoreIncrError.MASK_ALREADY_SUBMITTED
-        key = serialize_mask_object(mask)
-        self._mask_scores[key] = self._mask_scores.get(key, 0) + 1
+        prefilter = _vote_prefilter(mask)
+        for kept in self._mask_scores:
+            if kept.prefilter == prefilter and kept.mask == mask:
+                kept.score += 1
+                break
+        else:
+            self._mask_scores.append(_ScoredMask(prefilter, mask))
         self._mask_submitted.add(pk)
+        MASK_VOTES.labels(route="kept").inc()
         return None
 
     async def best_masks(self) -> Optional[list[tuple[MaskObject, int]]]:
         if not self._mask_scores:
             return None
-        top = sorted(self._mask_scores.items(), key=lambda kv: kv[1], reverse=True)[:2]
-        return [(parse_mask_object(data)[0], score) for data, score in top]
+        top = sorted(self._mask_scores, key=lambda kept: kept.score, reverse=True)[:2]
+        return [(kept.mask, kept.score) for kept in top]
 
     async def number_of_unique_masks(self) -> int:
         return len(self._mask_scores)

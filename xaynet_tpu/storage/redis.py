@@ -23,6 +23,7 @@ from ..core.mask.object import MaskObject
 from ..core.mask.seed import EncryptedMaskSeed
 from ..core.mask.serialization import parse_mask_object, serialize_mask_object
 from .traits import (
+    MASK_VOTES,
     CoordinatorStorage,
     LocalSeedDictAddError,
     MaskScoreIncrError,
@@ -332,11 +333,14 @@ class RedisCoordinatorStorage(CoordinatorStorage):
             serialize_mask_object(mask),
             replay_safe=False,
         )
-        return {
+        err = {
             0: None,
             -1: MaskScoreIncrError.UNKNOWN_SUM_PK,
             -2: MaskScoreIncrError.MASK_ALREADY_SUBMITTED,
         }[int(code)]
+        if err is None:
+            MASK_VOTES.labels(route="serialised").inc()
+        return err
 
     async def best_masks(self):
         reply = await self.client.command(

@@ -19,6 +19,18 @@ from typing import Optional
 
 from ..core.common import LocalSeedDict, SeedDict, SumDict
 from ..core.mask.object import MaskObject
+from ..telemetry.registry import get_registry
+
+# counted by the store that scored the vote, where it chose how to key it
+MASK_VOTES = get_registry().counter(
+    "xaynet_sum2_mask_votes_total",
+    "Sum2 mask votes scored, by how the store keyed the mask: kept = the "
+    "parsed object was kept and compared, nothing serialised (the in-memory "
+    "stores); serialised = the mask was serialised again to be the key (an "
+    "external store's sorted set). The journal's durable copy of a vote is "
+    "not counted (storage/traits.py).",
+    ("route",),
+)
 
 
 class StorageError(RuntimeError):

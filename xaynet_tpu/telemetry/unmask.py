@@ -6,11 +6,12 @@ state machine's task. Each is written down twice by one call here
 as a span under ``phase.unmask`` and as one observation on
 ``xaynet_unmask_seconds{stage=...}``, bracketed where the work happens:
 
-- ``elect``: the store's count of masks, its two best (with the parse of
-  the serialised mask) and the unique-maximum election (``phases/unmask.py``);
+- ``elect``: the store's count of masks, its two best (the objects the
+  in-memory store kept; the Redis store parses its serialised members) and
+  the unique-maximum election (``phases/unmask.py``);
 - ``validate``: ``validate_unmasking`` (the mask's validity scan);
-- ``mask_put``: the mask relaid out planar and padded, and its
-  ``device_put`` until the array is ready (``parallel/aggregator.py``);
+- ``mask_put``: the mask relaid out planar and padded, once a phase, and
+  its ``device_put`` until the array is ready (``parallel/aggregator.py``);
 - ``subtract``: the subtract kernel until its result is ready (on the host
   arm ``mod_sub`` over the vector, ``core/mask/masking.py``);
 - ``fetch``: device to host and the transposition to the wire layout;
