@@ -12,7 +12,9 @@ published model has to equal it bit for bit at 2 and 3 limbs, with a ring of
 batch (closed degraded), in two arrival orders: so the result does not
 depend on which update landed in which batch. Around the Update phase the
 counters have to say what the ring did: the folded batches, and the ring's
-acquisitions by kind, which add up to the batches.
+acquisitions by kind, which add up to the batches. The same round on a mesh
+of four of the CPU backend's devices (ISSUE 40): the shard-parallel pipeline,
+every update written into its four per-shard slots as it arrives.
 """
 
 import asyncio
@@ -109,6 +111,14 @@ def one_device(monkeypatch, tmp_path):
     monkeypatch.setattr(aggregator_mod, "make_mesh", lambda: make_mesh(jax.devices()[:1]))
 
 
+@pytest.fixture
+def four_devices(monkeypatch, tmp_path):
+    """A four-chip host: the served round takes four of the CPU backend's
+    eight devices, so its pipeline is the shard-parallel one as shipped."""
+    monkeypatch.setenv("XAYNET_FLIGHT_DIR", str(tmp_path / "flight"))
+    monkeypatch.setattr(aggregator_mod, "make_mesh", lambda: make_mesh(jax.devices()[:4]))
+
+
 def _settings(width: str, staging_buffers: int, n_update: int, count_min: int) -> Settings:
     window = TimeSettings(min=0.0, max=60.0)
     s = Settings(pet=ServerPet(
@@ -136,6 +146,9 @@ def _pipeline_counters() -> dict:
     out.update({("ring", how): streaming.RING_WAIT_SECONDS.labels(how=how).count for how in HOWS})
     out["rows", "arrival"] = streaming.ROWS_STAGED.labels(route="arrival").value
     out["rows", "flush"] = streaming.ROWS_STAGED.labels(route="flush").value
+    out["commits"] = streaming.COMMIT_SECONDS.count
+    out["staged_bytes"] = sum(aggregator_mod.BYTES_STAGED.labels(layout=layout).value
+                              for layout in ("packed", "unpacked", "wire"))
     return out
 
 
@@ -230,6 +243,7 @@ def test_served_multibatch_round_equals_the_plain_reference(
     moved = out["moved"]
     assert moved["batches", "folded"] == moved["batches", "staged"] == batches
     assert moved["batches", "failed"] == 0
+    assert moved["commits"] == batches  # one a batch on one shard too
     # every row was written into its slot as it arrived, none at a flush
     assert (moved["rows", "arrival"], moved["rows", "flush"]) == (n_update, 0)
     # one acquisition a batch; a ring of `staging_buffers` leases no more
@@ -240,6 +254,40 @@ def test_served_multibatch_round_equals_the_plain_reference(
     assert ring["free"] + ring["waited"] >= batches - staging_buffers, ring
     assert out["depth"] == depth0  # every buffer went back
     assert out["gap"] > 0.0  # the longest gap between two accepted updates
+
+
+@pytest.mark.parametrize("shape", ["whole", "remainder"])
+@pytest.mark.parametrize("width", list(BOUNDS))
+def test_served_round_on_a_mesh_stages_every_update_at_arrival(width, shape, four_devices):
+    """Two batches (and a remainder batch of one) on four shards: the model
+    is the plain reference's bit for bit, every accepted update was staged
+    at arrival and once (its packed bytes, no planar row beside them), each
+    shard's ring was asked once a batch, and each batch committed once."""
+    config = _config(width)
+    n_update = 2 * K + (shape == "remainder")
+    batches = 2 + (shape == "remainder")
+    count_min = n_update if shape == "whole" else 3 * K
+    weights = _weights(n_update, float(config.add_shift))
+    order = [int(i) for i in np.random.default_rng(11).permutation(n_update)]
+    depth0 = streaming.STAGING_DEPTH.value
+    out = asyncio.run(asyncio.wait_for(
+        _served_round(_settings(width, 2, n_update, count_min), weights, order), 180))
+
+    want = reference_model(weights, int(config.add_shift), config.exp_shift)
+    assert np.array_equal(out["model"].view(np.uint64), want.view(np.uint64))
+    fold = aggregator_mod.fold_kernel_report()
+    padded = -(-MODEL_LEN // 4) * 4
+    assert (fold["shards"], fold["shard_length"]) == (4, padded // 4)
+    moved = out["moved"]
+    assert moved["batches", "folded"] == moved["batches", "staged"] == batches
+    assert moved["batches", "failed"] == 0
+    assert (moved["rows", "arrival"], moved["rows", "flush"]) == (n_update, 0)
+    assert moved["staged_bytes"] == n_update * config.bytes_per_number * padded
+    assert moved["commits"] == batches
+    ring = {how: moved["ring", how] for how in HOWS}
+    assert sum(ring.values()) == 4 * batches, ring
+    assert 4 <= ring["leased"] <= 4 * 2, ring
+    assert out["depth"] == depth0
 
 
 def test_ring_acquisitions_are_counted_by_kind():

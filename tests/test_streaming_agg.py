@@ -346,9 +346,10 @@ def test_prevalidate_skips_count_mismatched_member():
 
 # -- staging at arrival (ISSUE 25) ------------------------------------------
 #
-# A single-device pipeline lends an open batch its ring buffer and every
-# accepted update is written into its own slot by its ``xn-ingest`` task;
-# ``flush()`` only waits for the writes and submits the buffer.
+# The pipeline lends an open batch one ring buffer a shard and every
+# accepted update is written into its own slot of each by its ``xn-ingest``
+# task; ``flush()`` only waits for the writes and submits the buffers. One
+# device is the case of one shard (ISSUE 40: the mesh, further down).
 
 
 def _mesh2():
@@ -411,7 +412,7 @@ def test_arrival_staging_bit_equal_to_host_oracle(packed, shape, cfg):
     objs = _masked_updates(n, total, seed=21, cfg=cfg)
     dev = _staged(n, cfg=cfg, packed_staging=packed, staging_buffers=2)
     stream = dev._stream
-    assert stream._packed is packed and stream.stages_rows
+    assert stream._packed is packed and stream._n_shards == 1
     assert stream.agg.padded_length == 104
     if shape == "reused":
         ring = stream._ring(stream._host_kind)
@@ -456,45 +457,13 @@ def test_arrival_staging_through_the_pallas_fold(packed, cfg):
     assert got.object == want.object
 
 
-def test_rows_land_in_arrival_order_whatever_order_the_pool_finishes_in():
-    from xaynet_tpu.ops import limbs as host_limbs
-
-    n, k = 103, 4
-    objs = _masked_updates(n, k, seed=22)
-    dev = _staged(n, staging_buffers=2)
-    stream = dev._stream
-    ring = stream._ring(stream._host_kind)
-    dirty = ring.acquire()
-    dirty.fill(0xFF)
-    ring.release(dirty)
-    real, finished = stream.stage_row, []
-
-    def late_first(buf, i, wire):
-        time.sleep(0.05 * (k - i))  # slot 0 lands last
-        real(buf, i, wire)
-        finished.append(i)
-
-    stream.stage_row = late_first
-    for obj in objs:
-        dev.stage(obj)
-    _await_writes(dev)
-    assert finished != sorted(finished)
-    buf = dev._open[0].buf
-    for i, obj in enumerate(objs):
-        want = host_limbs.pack_wire(obj.vect.data[None], stream.agg.packed_width)[0]
-        assert np.array_equal(buf[i, :, :n], want)
-        assert not buf[i, :, n:].any()  # the dirty buffer's pad columns
-    dev.drain()
-    assert dev.finalize().object == _oracle(n, objs).object
-
-
 @WIDTHS
-@pytest.mark.parametrize("route", ["fold_partial", "wire_ingest", "mesh2"])
+@pytest.mark.parametrize("route", ["fold_partial", "wire_ingest", "mesh2_partial"])
 def test_other_routes_stage_as_before(route, cfg):
     """What does not take the slot route: an edge partial (one row through
-    ``submit_host_planar_rows``), device-resident wire-ingest parts (never
-    staged on the host) and a shard-parallel mesh (per-shard rings, filled
-    at submit)."""
+    ``submit_batch``, on one device and on a shard-parallel mesh: opened,
+    filled and submitted in the one call) and device-resident wire-ingest
+    parts (never staged on the host)."""
     from xaynet_tpu.core.mask.object import LazyWireMaskVect, MaskObject
     from xaynet_tpu.server.aggregation import StagedAggregator
 
@@ -502,13 +471,14 @@ def test_other_routes_stage_as_before(route, cfg):
     objs = _masked_updates(n, k, seed=23, cfg=cfg)
     host = StagedAggregator(cfg.pair(), n, device=False, batch_size=4)
     arrival0, flush0 = _rows_staged()
-    if route == "fold_partial":
-        dev = _staged(n, cfg=cfg)
+    if route in ("fold_partial", "mesh2_partial"):
+        dev = _staged(n, cfg=cfg, shard_parallel=route == "mesh2_partial")
+        assert dev._stream._n_shards == (2 if route == "mesh2_partial" else 1)
         for s in (host, dev):
             s.fold_partial(objs[0], 2)
         assert _rows_staged() == (arrival0, flush0 + 1)
         want_models = 2
-    elif route == "wire_ingest":
+    else:
         dev = _staged(n, cfg=cfg)
         for obj in objs:
             host.aggregate(obj)
@@ -519,16 +489,6 @@ def test_other_routes_stage_as_before(route, cfg):
         assert not dev._open
         dev.drain()
         assert _rows_staged() == (arrival0, flush0)
-        want_models = k
-    else:
-        dev = _staged(n, cfg=cfg, shard_parallel=True)
-        assert not dev._stream.stages_rows
-        for obj in objs:
-            host.aggregate(obj)
-            dev.aggregate(obj)
-        assert not dev._open
-        dev.drain()
-        assert _rows_staged() == (arrival0, flush0 + k)
         want_models = k
     a, b = host.finalize(), dev.finalize()
     assert a.nb_models == b.nb_models == want_models
@@ -554,37 +514,6 @@ def test_stage_returns_without_waiting_when_every_ring_buffer_is_busy():
     assert dev.finalize().object == _oracle(n, objs).object
 
 
-def test_failed_slot_write_raises_at_flush_and_returns_the_buffer():
-    n = 64
-    objs = _masked_updates(n, 5, seed=25)
-    dev = _staged(n, mesh=_mesh1())
-    stream = dev._stream
-    depth0 = STAGING_DEPTH.value
-    boom = RuntimeError("slot write died (stand-in)")
-    real = stream.stage_row
-
-    def failing(buf, i, wire):
-        if i == 1:
-            raise boom
-        real(buf, i, wire)
-
-    stream.stage_row = failing
-    for obj in objs[:3]:
-        dev.stage(obj)
-    with pytest.raises(RuntimeError) as err:
-        dev.flush()
-    assert err.value is boom
-    assert STAGING_DEPTH.value == depth0
-    assert dev.pending == 0 and dev.nb_models == 0
-    # the buffer went back clean enough: what is staged next folds correctly
-    stream.stage_row = real
-    for obj in objs[3:]:
-        dev.aggregate(obj)
-    dev.drain()
-    assert STAGING_DEPTH.value == depth0
-    assert dev.finalize().object == _oracle(n, objs[3:]).object
-
-
 @pytest.mark.parametrize("exit_", ["close", "abandon"])
 def test_open_batch_lease_is_released_on(exit_):
     import gc
@@ -607,6 +536,223 @@ def test_open_batch_lease_is_released_on(exit_):
         gc.collect()
     assert STAGING_DEPTH.value == depth0
     assert get_pool().balanced(tenant)
+
+
+# -- staging at arrival on a mesh (ISSUE 40) ---------------------------------
+#
+# A shard-parallel pipeline takes the same route: an open batch holds one
+# buffer of every shard's ring, and an update's column range [lo, hi) of a
+# shard is written into that shard's slot as it arrives. The aggregate has to
+# be the one-device route's and the plain integer sum, bit for bit.
+
+
+def _mesh(n_dev):
+    return make_mesh(jax.devices()[:n_dev])
+
+
+def _as_ints(wire) -> list[int]:
+    return [sum(limb << (32 * i) for i, limb in enumerate(row))
+            for row in np.asarray(wire).tolist()]
+
+
+def _int_sum(objs, cfg) -> list[int]:
+    """The plain reference: every element summed in Python integers modulo
+    the group order, with nothing of the program's limb or fold code."""
+    total = [0] * len(objs[0].vect)
+    for obj in objs:
+        total = [(a + b) % cfg.order for a, b in zip(total, _as_ints(obj.vect.data))]
+    return total
+
+
+@WIDTHS
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
+@pytest.mark.parametrize("n", [96, 103, 9], ids=["divides", "padded", "all-pad-shards"])
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_mesh_arrival_staging_equals_one_device_and_the_integer_sum(n_dev, n, packed, cfg):
+    """A full batch of 4 and a batch of 3 closed by drain(), at a length the
+    devices divide, one they do not (pad columns in the last shard) and one
+    shorter than the mesh's padding (whole shards of pad columns)."""
+    total = 7
+    objs = _masked_updates(n, total, seed=40, cfg=cfg)
+    one = _staged(n, cfg=cfg, mesh=_mesh1(), packed_staging=packed)
+    mesh = _staged(n, cfg=cfg, mesh=_mesh(n_dev), shard_parallel=True, packed_staging=packed)
+    stream = mesh._stream
+    assert stream._n_shards == n_dev and stream._packed is packed
+    assert stream._slices[-1][1] == stream.agg.padded_length == -(-n // n_dev) * n_dev
+    arrival0, flush0 = _rows_staged()
+    depth0 = STAGING_DEPTH.value
+    for dev in (one, mesh):
+        for obj in objs:
+            dev.validate_aggregation(obj)
+            dev.aggregate(obj)
+        dev.drain()
+    # one row an update on either route, not one a shard
+    assert _rows_staged() == (arrival0 + 2 * total, flush0)
+    assert STAGING_DEPTH.value == depth0
+    a, b = one.finalize(), mesh.finalize()
+    assert a.nb_models == b.nb_models == total
+    assert np.array_equal(a.object.vect.data, b.object.vect.data)
+    assert _as_ints(b.object.vect.data) == _int_sum(objs, cfg)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+def test_rows_land_in_arrival_order_whatever_order_the_pool_finishes_in(n_dev):
+    """Slots written out of order and from several threads, into dirty
+    buffers: a row's columns lie in its slot of every shard's buffer."""
+    from xaynet_tpu.ops import limbs as host_limbs
+
+    n, k = 103, 8
+    objs = _masked_updates(n, k, seed=41)
+    dev = _staged(n, batch_size=k, mesh=_mesh(n_dev), shard_parallel=True, staging_buffers=2)
+    stream = dev._stream
+    for d in range(n_dev):  # every shard's first buffer is dirty
+        ring = stream._ring(stream._host_kind, d)
+        dirty = ring.acquire()
+        dirty.fill(0xFF)
+        ring.release(dirty)
+    real, finished, writers = stream.stage_row, [], set()
+
+    def late_first(bufs, i, wire):
+        time.sleep(0.03 * (k - i))  # slot 0 lands last
+        real(bufs, i, wire)
+        finished.append(i)
+        writers.add(threading.get_ident())
+
+    stream.stage_row = late_first
+    for obj in objs:
+        dev.stage(obj)
+    _await_writes(dev)
+    assert finished != sorted(finished) and len(writers) > 1
+    bufs = dev._open[0].bufs
+    assert len(bufs) == n_dev
+    for i, obj in enumerate(objs):
+        want = host_limbs.pack_wire(obj.vect.data[None], stream.agg.packed_width)[0]
+        got = np.concatenate([buf[i] for buf in bufs], axis=-1)
+        assert np.array_equal(got[:, :n], want)
+        assert not got[:, n:].any()  # the dirty buffer's pad columns
+    dev.drain()
+    assert dev.finalize().object == _oracle(n, objs).object
+
+
+@WIDTHS
+def test_mesh_round_of_three_batches_takes_each_shards_buffer_again(cfg):
+    """Three batches of 4 through rings of 2 on four shards: every shard's
+    ring is asked three times and leases no more than two buffers; a batch
+    counts once, when its last shard has folded."""
+    from xaynet_tpu.parallel.streaming import (
+        COMMIT_SECONDS, RING_WAIT_SECONDS, SHARD_STAGING_DEPTH)
+
+    n, k, n_dev, batches = 103, 4, 4, 3
+    objs = _masked_updates(n, k * batches, seed=42, cfg=cfg)
+    dev = _staged(n, batch_size=k, cfg=cfg, mesh=_mesh(n_dev), shard_parallel=True,
+                  staging_buffers=2)
+    stream = dev._stream
+    hows = ("free", "leased", "waited")
+    ring0 = {how: RING_WAIT_SECONDS.labels(how=how).count for how in hows}
+    commits0, folded0 = COMMIT_SECONDS.count, BATCHES_TOTAL.labels(stage="folded").value
+    gate, real = threading.Event(), stream._fold_shard_item
+
+    def last_shard_waits(job, d, payload):
+        if d == n_dev - 1:
+            assert gate.wait(30)
+        real(job, d, payload)
+
+    stream._fold_shard_item = last_shard_waits
+    for obj in objs[:k]:
+        dev.validate_aggregation(obj)
+        dev.aggregate(obj)  # the 4th closes batch 1: one item a shard worker
+    deadline = time.monotonic() + 30
+    while sum(q.unfinished_tasks for q in stream._shard_queues) > 1:
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+    # three shards have folded their slice of batch 1: it does not count yet
+    assert dev._device.nb_models == 0 and stream.counted_models() == k
+    assert BATCHES_TOTAL.labels(stage="folded").value == folded0
+    gate.set()
+    for obj in objs[k:]:
+        dev.validate_aggregation(obj)
+        dev.aggregate(obj)
+    dev.drain()
+    assert dev._device.nb_models == k * batches
+    assert BATCHES_TOTAL.labels(stage="folded").value == folded0 + batches
+    assert COMMIT_SECONDS.count == commits0 + batches
+    ring = {how: RING_WAIT_SECONDS.labels(how=how).count - ring0[how] for how in hows}
+    assert sum(ring.values()) == batches * n_dev, ring
+    assert n_dev <= ring["leased"] <= 2 * n_dev, ring  # a ring of 2 a shard
+    for d in range(n_dev):
+        assert SHARD_STAGING_DEPTH.labels(shard=str(d)).value == 0
+        assert not stream._ring(stream._host_kind, d)._inflight
+    got, want = dev.finalize(), _oracle(n, objs, cfg)
+    assert got.nb_models == want.nb_models == k * batches
+    assert got.object == want.object
+
+
+def test_mesh_shards_copy_host_to_device_one_at_a_time(monkeypatch):
+    """The shards' fold workers start together; their copies of one batch do
+    not overlap (``shards.H2D_GATE``), and each shard's span says whose."""
+    from xaynet_tpu.telemetry import tracing
+
+    n, k, n_dev = 103, 4, 4
+    objs = _masked_updates(n, k, seed=44)
+    dev = _staged(n, batch_size=k, mesh=_mesh(n_dev), shard_parallel=True)
+    real = jax.device_put
+
+    def slow_put(x, device=None, **kw):
+        if threading.current_thread().name.startswith("xn-stream-fold-"):
+            time.sleep(0.05)  # a copy long enough to overlap another
+        return real(x, device, **kw)
+
+    monkeypatch.setattr(jax, "device_put", slow_put)
+    t0 = time.monotonic()
+    for obj in objs:
+        dev.validate_aggregation(obj)
+        dev.aggregate(obj)
+    dev.drain()
+    spans = [s for s in tracing.get_tracer().ring_spans()
+             if s.name == "stream.h2d" and s.start >= t0]
+    assert sorted(s.attrs["shard"] for s in spans) == list(range(n_dev))
+    spans.sort(key=lambda s: s.start)
+    for earlier, later in zip(spans, spans[1:]):
+        assert earlier.duration >= 0.05
+        assert later.start >= earlier.start + earlier.duration
+    assert dev.finalize().object == _oracle(n, objs).object
+
+
+@pytest.mark.parametrize("failing_slot", [0, 2], ids=["first-write", "last-write"])
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+def test_failed_slot_write_raises_at_flush_and_gives_back_every_buffer(n_dev, failing_slot):
+    n = 64
+    objs = _masked_updates(n, 5, seed=43)
+    dev = _staged(n, mesh=_mesh(n_dev), shard_parallel=True)
+    stream = dev._stream
+    depth0 = STAGING_DEPTH.value
+    failed0 = BATCHES_TOTAL.labels(stage="failed").value
+    boom = RuntimeError("slot write died (stand-in)")
+    real = stream.stage_row
+
+    def failing(bufs, i, wire):
+        if i == failing_slot:
+            raise boom
+        real(bufs, i, wire)
+
+    stream.stage_row = failing
+    for obj in objs[:3]:
+        dev.stage(obj)
+    with pytest.raises(RuntimeError) as err:
+        dev.flush()
+    assert err.value is boom
+    assert STAGING_DEPTH.value == depth0
+    assert all(not stream._ring(stream._host_kind, d)._inflight for d in range(n_dev))
+    assert dev.pending == 0 and dev.nb_models == 0
+    # nothing is poisoned: what is staged next folds on every shard
+    stream.stage_row = real
+    for obj in objs[3:]:
+        dev.aggregate(obj)
+    dev.drain()
+    assert STAGING_DEPTH.value == depth0
+    assert BATCHES_TOTAL.labels(stage="failed").value == failed0
+    assert not stream.degraded
+    assert dev.finalize().object == _oracle(n, objs[3:]).object
 
 
 def test_staging_ring_grows_on_demand_up_to_size_then_blocks():

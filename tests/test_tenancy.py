@@ -85,6 +85,34 @@ def test_pool_zeroes_cross_tenant_reuse():
     pool.release(b)
 
 
+def test_pool_leaves_a_slab_of_its_own_untouched_and_zero():
+    """A lease that gets a slab made for it is handed the slab's zero pages
+    as they are (no pass over them on the caller's thread); the next lease
+    of the same pages is filled, as ever."""
+    pool = PagePool(page_bytes=4096, slab_pages=4)
+    fills = []
+
+    class Spy(np.ndarray):
+        def fill(self, value):
+            fills.append(self.nbytes)
+            super().fill(value)
+
+    real = np.zeros
+    try:
+        np.zeros = lambda *a, **kw: real(*a, **kw).view(Spy)
+        big = pool.lease_host("a", (8 * 4096,), np.uint8)  # a slab of its own
+    finally:
+        np.zeros = real
+    assert pool.stats()["slabs"] == 1 and not fills
+    assert not np.asarray(big.array).any()
+    big.array[:] = 0xAB
+    pool.release(big)
+    again = pool.lease_host("b", (8 * 4096,), np.uint8)  # the same pages, dirty
+    assert again.slab == 0 and fills == [8 * 4096]
+    assert not np.asarray(again.array).any()
+    pool.release(again)
+
+
 def test_pool_capacity_cap_and_overflow():
     pool = PagePool(page_bytes=4096, slab_pages=4, host_pages=4)
     lease = pool.lease_host("a", (3 * 4096,), np.uint8)

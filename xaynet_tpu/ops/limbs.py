@@ -384,51 +384,6 @@ def _native_pack_planar(planar: np.ndarray, bpn: int, out: np.ndarray) -> bool:
     return True
 
 
-def pack_planar_slice(
-    planar: np.ndarray,
-    lo: int,
-    hi: int,
-    bpn: int,
-    out: np.ndarray,
-    n_threads: int = 0,
-) -> np.ndarray:
-    """Pack the column slice ``[lo, hi)`` of one contiguous planar
-    ``uint32[L, n]`` row into byte-planar ``out[bpn, >= hi-lo]`` in place
-    (native plane kernel: unit-stride reads AND writes; shift-and-mask
-    numpy fallback)."""
-    n_limb, n = planar.shape
-    width = hi - lo
-    if bpn > 4 * n_limb:
-        raise ValueError("pack width exceeds the limb width")
-    view = out[:, :width]
-    from ..utils import native
-
-    lib = native.load()
-    if (
-        lib is not None
-        and hasattr(lib, "xn_pack_planar_planes")
-        and planar.flags.c_contiguous
-        and out.strides[-1] == 1
-    ):
-        lib.xn_pack_planar_planes(
-            native.np_u32p_at(planar, lo),
-            width,
-            n,  # input plane stride
-            bpn,
-            native.np_u8p(view),
-            out.strides[0],
-            max(0, int(n_threads)),
-        )
-        codec.count("stage", True, width)
-        return view
-    codec.count("stage", False, width)
-    for b in range(bpn):
-        view[b, :] = (
-            (planar[b // 4, lo:hi] >> _U32(8 * (b % 4))) & _U32(0xFF)
-        ).astype(np.uint8)
-    return view
-
-
 def pack_wire_slice(
     stack: np.ndarray,
     lo: int,

@@ -99,7 +99,8 @@ def fold_kernel_report() -> dict:
     """The last fold-kernel resolution: ``kernel``, ``source`` (configured |
     race | only-candidate | cached | persisted), the vector and element it
     folded (``model_length``, ``n_limbs``, ``bytes_per_number``), the mesh
-    decomposition it ran on (``acc_slices``: one ``[lo, hi)`` slice per device),
+    decomposition it ran on (``acc_slices``: one ``[lo, hi)`` slice per device;
+    ``shards`` of them, each ``shard_length`` columns with the padding),
     and for a race ``race`` (per candidate ``status`` = ``ok`` or
     ``failed: <ExceptionType>``, first-call and steady ``seconds``) plus
     ``results_equal``. Empty before the first fold."""
@@ -682,6 +683,8 @@ class ShardedAggregator:
             "n_limbs": self.n_limbs,
             "bytes_per_number": self.config.bytes_per_number,
             "acc_slices": shard_slices(self.padded_length, self.mesh.devices.size),
+            "shards": self.mesh.devices.size,
+            "shard_length": self.padded_length // self.mesh.devices.size,
             **extra,
         }
 

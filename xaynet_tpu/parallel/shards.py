@@ -45,6 +45,15 @@ import jax
 from .mesh import shard_slices
 
 
+# one host->device copy of a staged batch at a time, whatever the shard and
+# whichever plan: the copies share the host's path to the chips. Four 2.15 GB
+# copies started together on a four-chip v5e host ran two at 5.3 GB/s and two
+# at 0.3 GB/s, 5.9-10.2 s for the batch where four in a row need under 2
+# (PERF.md section 6, PR 40); a shard's fold still runs beside the next
+# shard's copy.
+H2D_GATE = threading.Lock()
+
+
 class ShardPlan:
     """Per-shard accumulator state + fold entry points for one aggregator.
 

@@ -11,10 +11,11 @@ the device take turns idling. This module turns that into a pipeline:
   allocations (plus their page-fault tax, ~0.15 s per 200 MB at 25M
   params) disappear entirely. A buffer is reused only after the fold that
   consumed it has finished reading host memory (after the ``device_put``
-  transfer is complete). A single-device pipeline lends the buffer
-  to a batch that is still filling (``open_batch``), so its rows are
-  written into their slots as they arrive (``stage_row``) and submitting
-  the batch (``submit_staged``) relays nothing out.
+  transfer is complete). The pipeline lends the buffer to a batch that
+  is still filling (``open_batch``), so its rows are written into their
+  slots as they arrive (``stage_row``) and submitting the batch
+  (``submit_staged``) relays nothing out. There is one ring a shard: a
+  single-device pipeline is the case of one shard as wide as the model.
 - **dispatch-ahead depth** — up to ``dispatch_ahead`` batches are queued to
   a single fold worker thread, so XLA's asynchronous dispatch keeps
   multiple folds in flight behind one another while the producer stages
@@ -35,9 +36,10 @@ how far the pipeline runs ahead.
 **Shard-parallel mode (multi-device meshes).** On a mesh of D devices the
 pipeline runs ONE FOLD WORKER PER SHARD instead of the single FIFO worker:
 each mesh device owns its contiguous model-axis plane slice with a donated
-per-shard accumulator (``shards.ShardPlan``), the producer slices the
-padded batch ONCE on the host into per-shard staging rings, and each
-shard's host→device transfer overlaps the other shards' in-flight folds.
+per-shard accumulator (``shards.ShardPlan``), an open batch holds one
+buffer of every shard's staging ring, every row is written into its column
+range of each (once, as it arrives), and each shard's host→device transfer
+overlaps the other shards' in-flight folds.
 A batch COMMITS — counts
 toward ``nb_models`` / leaves flight — only when EVERY shard folded its
 slice (``_BatchJob``), so per-shard progress skew never shows up in the
@@ -92,13 +94,15 @@ from ..tenancy.scheduler import get_scheduler
 # aggregator.py registers it (wire-ingest staging accounts there too) and
 # the streaming rings account through the shared symbol
 from .aggregator import BYTES_REDUCED, BYTES_STAGED, ShardedAggregator
+from .mesh import shard_slices
+from .shards import H2D_GATE
 
 logger = logging.getLogger(__name__)
 
 # mirror=True: also written into the profiler's trace when the runner has
 # installed its sink. stream.commit and overlap.eager_unmask are recorded
-# after the fact (record_span), which no mirror can carry; so are the
-# per-shard stream.stage spans of the shard-parallel submit paths.
+# after the fact (record_span), which no mirror can carry: a commit barrier
+# begins on the thread of the first shard to fold and ends on the last's.
 SPAN_STAGE = trace.declare_span("stream.stage", mirror=True)
 SPAN_RING_WAIT = trace.declare_span("stream.ring_wait", mirror=True)
 SPAN_H2D = trace.declare_span("stream.h2d", mirror=True)
@@ -186,17 +190,27 @@ RING_WAIT_SECONDS = _registry.histogram(
     buckets=(0.0001, 0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
              10.0, 30.0, 60.0),
 )
+COMMIT_SECONDS = _registry.histogram(
+    "xaynet_streaming_commit_seconds",
+    "One batch's commit (stream.commit): the first shard's fold item done "
+    "until the last one's, when the batch counts: what the slowest shard "
+    "adds to a batch. On one shard, the hand-back of its slot and buffer.",
+    buckets=(0.0001, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+             1.0, 2.5, 5.0, 10.0, 30.0),
+)
 _SHUTDOWN = object()
 
 
 @contextmanager
-def _h2d(kind: str, nbytes: int):
-    """One staged batch's host-to-device copy in ``_fold_payload``: from
-    ``device_put`` to the wait that frees the ring buffer. The fold's
+def _h2d(kind: str, nbytes: int, **shard):
+    """One staged batch's host-to-device copy: from ``device_put`` to the
+    wait that frees the ring buffer. In ``_fold_payload`` the fold's
     dispatch lies between the two (the order is the hot path's, and this
-    adds no sync), so the time is an upper bound of the copy alone."""
+    adds no sync), so the time is an upper bound of the copy alone; a
+    shard's slice of a batch (``_fold_shard_item``, ``shard=d``) is copied
+    before its fold is dispatched, and the time is the copy's."""
     t0 = time.monotonic()
-    with trace.get_tracer().span(SPAN_H2D, kind=kind, bytes=nbytes):
+    with trace.get_tracer().span(SPAN_H2D, kind=kind, bytes=nbytes, **shard):
         yield
     H2D_SECONDS.observe(time.monotonic() - t0)
     H2D_BYTES.inc(nbytes)
@@ -257,7 +271,7 @@ class _BatchJob:
     """
 
     __slots__ = ("kind", "k", "ticket", "seq", "remaining", "failed", "retried",
-                 "staged", "global_release")
+                 "first_done", "staged", "global_release")
 
     def __init__(self, kind: str, k: int, ticket, seq: int, n_shards: int):
         self.kind = kind
@@ -267,6 +281,7 @@ class _BatchJob:
         self.remaining = n_shards  # guarded-by: _lock (the owning pipeline's)
         self.failed = False  # guarded-by: _lock
         self.retried = False  # guarded-by: _lock
+        self.first_done = None  # when the first shard settled  # guarded-by: _lock
         # staged/global_release are NOT lock-guarded: after `remaining`
         # hits zero under the lock, exactly ONE worker (the last shard)
         # reaches the commit tail that touches them — ownership handoff
@@ -341,12 +356,14 @@ class _StagingRing:
     """
 
     def __init__(self, size: int, shape: tuple, dtype, gauge=None,
-                 pool=None, tenant: str = "default"):
+                 pool=None, tenant: str = "default", shard: int | None = None):
         self._free: queue_mod.Queue = queue_mod.Queue()
         self.size = size
         self._shape = shape
         self._dtype = dtype
         self._tenant = tenant
+        # a shard-parallel pipeline's rings say whose they are on the wait span
+        self._span_attrs = {"tenant": tenant, **({} if shard is None else {"shard": shard})}
         # per-shard rings report on the shard-labelled gauge; the global
         # depth gauge keeps counting every owned buffer either way
         self._gauge = gauge
@@ -387,7 +404,7 @@ class _StagingRing:
 
     def acquire(self, timeout: float | None = None) -> np.ndarray:
         t0 = time.monotonic()
-        with trace.get_tracer().span(SPAN_RING_WAIT, tenant=self._tenant) as wait:
+        with trace.get_tracer().span(SPAN_RING_WAIT, **self._span_attrs) as wait:
             how = "free"
             try:
                 lease = self._free.get_nowait()
@@ -485,6 +502,9 @@ class StreamingAggregator:
         n_dev = agg.mesh.devices.size
         self._sharded = n_dev > 1 and (shard_parallel is None or shard_parallel)
         self._n_shards = n_dev if self._sharded else 1
+        # the model-axis column range [lo, hi) a shard's staging buffers
+        # hold: the mesh devices' own slices, or the padded model whole
+        self._slices = shard_slices(agg.padded_length, self._n_shards)
         # packed staging (on by default wherever it shrinks anything): the
         # planar submit paths stage byte-planar uint8[K, bpn, width] planes
         # — bpn/(4L) of the unpacked ring/transfer bytes — and the fold
@@ -509,9 +529,9 @@ class StreamingAggregator:
         self._plan = None  # shards.ShardPlan while accs live  # guarded-by: _lock
         self._shard_queues: list[queue_mod.Queue] | None = None
         self._shard_workers: list[threading.Thread | None] = []
-        self._shard_rings: dict[int, _StagingRing] = {}  # guarded-by: _lock
         self._queue: queue_mod.Queue = queue_mod.Queue(maxsize=dispatch_ahead)
-        self._rings: dict[str, _StagingRing] = {}  # lazy: planar / wire  # guarded-by: _lock
+        # lazy: "wire", or a shard's index (its host batches)  # guarded-by: _lock
+        self._rings: dict[str | int, _StagingRing] = {}
         self._pending: list[StreamTicket] = []  # awaiting ok sync  # guarded-by: _lock
         self._in_flight_models = 0  # submitted, not yet folded  # guarded-by: _lock
         self._error: BaseException | None = None  # guarded-by: _lock
@@ -577,9 +597,8 @@ class StreamingAggregator:
         # staging pages go back to the pool (nothing is in flight past the
         # drain/joins above)
         with self._lock:
-            rings = list(self._rings.values()) + list(self._shard_rings.values())
+            rings = list(self._rings.values())
             self._rings.clear()
-            self._shard_rings.clear()
         for ring in rings:
             ring.close()
         self._sched.release_owner(self._sched_owner)
@@ -608,26 +627,30 @@ class StreamingAggregator:
         with self._lock:
             return self._degraded
 
-    def _ring(self, kind: str) -> _StagingRing:
+    def _ring(self, kind: str, d: int = 0) -> _StagingRing:
+        """The ring of raw wire batches (``kind == "wire"``, whole width on
+        any mesh), else shard ``d``'s ring of host batches in the
+        pipeline's staging layout, as wide as the shard's column range."""
+        key = kind if kind == "wire" else d
         with self._lock:
-            ring = self._rings.get(kind)
+            ring = self._rings.get(key)
             if ring is None:
                 agg = self.agg
-                if kind == "planar":
-                    shape = (self.max_batch, agg.n_limbs, agg.padded_length)
-                    dtype = np.uint32
-                elif kind == "packed":
+                if kind == "wire":
+                    shape: tuple = (self.max_batch, agg.padded_length * agg.config.bytes_per_number)
+                    dtype = np.uint8
+                elif self._packed:
                     # byte-planar packed planes: bpn/(4L) of the planar ring
-                    shape = (self.max_batch, agg.packed_width, agg.padded_length)
+                    shape = (self.max_batch, agg.packed_width, agg.padded_length // self._n_shards)
                     dtype = np.uint8
-                else:  # raw wire bytes
-                    shape = (self.max_batch, agg.padded_length * agg.config.bytes_per_number)
-                    dtype = np.uint8
-                # first-call buffer allocation happens under the lock: once
-                # per kind, before any overlap exists to lose
-                ring = self._rings[kind] = _StagingRing(
+                else:
+                    shape = (self.max_batch, agg.n_limbs, agg.padded_length // self._n_shards)
+                    dtype = np.uint32
+                sharded = self._sharded and kind != "wire"
+                ring = self._rings[key] = _StagingRing(
                     self.staging_buffers, shape, dtype,
-                    pool=self._pool, tenant=self.tenant,
+                    gauge=SHARD_STAGING_DEPTH.labels(shard=str(d)) if sharded else None,
+                    pool=self._pool, tenant=self.tenant, shard=d if sharded else None,
                 )
             return ring
 
@@ -742,113 +765,127 @@ class StreamingAggregator:
 
     # -- host batches: open, fill slot by slot, submit ----------------------
     #
-    # A single-device pipeline lends a batch its ring buffer when the
+    # The pipeline lends a batch one buffer of every shard's ring when the
     # batch OPENS. Rows are written into their slots while the batch is
     # still filling (by the caller's own threads: the update phase writes
-    # each accepted update as it arrives) and submitting is bookkeeping.
-    # The shard-parallel pipeline slices a finished batch across its
-    # per-shard rings instead (``_submit_sharded_*``).
-
-    @property
-    def stages_rows(self) -> bool:
-        """Whether ``open_batch``/``stage_row``/``submit_staged`` apply:
-        one ring of whole-width buffers, i.e. no shard-parallel mode."""
-        return not self._sharded
+    # each accepted update as it arrives), each row's column range
+    # ``[lo, hi)`` into that shard's buffer, and submitting is bookkeeping:
+    # one item to the fold worker, or one to each shard's. A single-device
+    # pipeline is the case of one shard.
 
     @property
     def _host_kind(self) -> str:
         return "packed" if self._packed else "planar"
 
-    def open_batch(self) -> np.ndarray:
-        """Take the ring buffer the next host batch is staged into: row
-        ``i`` of the batch belongs in ``buf[i]``. Blocks while every ring
-        buffer is owned by a batch in flight (the first call of a round
-        leases the first buffer). The buffer goes back through
-        ``submit_staged`` or ``release_batch``."""
-        if self._sharded:
-            raise StreamingError("shard-parallel pipelines stage per shard at submit")
+    def open_batch(self) -> list[np.ndarray]:
+        """Take the ring buffers the next host batch is staged into, one a
+        shard: columns ``[lo, hi)`` of the batch's row ``i`` belong in
+        ``bufs[d][i]``. Blocks while every buffer of a ring is owned by a
+        batch in flight (the first call of a round leases the first
+        buffers). The buffers go back through ``submit_staged`` or
+        ``release_batch``."""
         self._check_usable()
-        buf = self._ring(self._host_kind).acquire()
+        bufs: list[np.ndarray] = []
+        try:
+            for d in range(self._n_shards):
+                bufs.append(self._ring(self._host_kind, d).acquire())
+        except BaseException:
+            self.release_batch(bufs)
+            raise
         with self._lock:
-            self._lent_since[id(buf)] = time.monotonic()
-        return buf
+            self._lent_since[id(bufs[0])] = time.monotonic()
+        return bufs
 
-    def _relay_wire_rows(self, view: np.ndarray, stack: np.ndarray) -> None:
-        """Wire-layout ``uint32[k, model_len, L]`` rows into ``k`` slots of
-        a ring buffer, in the ring's layout, pad columns included (a reused
-        buffer is dirty)."""
+    def _relay_wire_rows(self, view: np.ndarray, stack: np.ndarray, lo: int, hi: int) -> None:
+        """Columns ``[lo, hi)`` of wire-layout ``uint32[k, model_len, L]``
+        rows into ``k`` slots of a ring buffer, in the ring's layout, pad
+        columns included (a reused buffer is dirty)."""
         from ..ops import limbs as host_limbs
 
-        n = self.agg.model_length
-        if self._packed:
-            # pack straight into the byte-planar slots: one strided transpose
-            # of the first bpn wire bytes per element — the same copy class
-            # as the planar transpose below, writing bpn/(4L) of the bytes
-            host_limbs.pack_wire(stack, self.agg.packed_width, out=view[:, :, :n])
-        else:
-            # transpose+pad straight into the slots (numpy strided copy, no
-            # wire_to_planar intermediate)
-            view[:, :, :n] = stack.transpose(0, 2, 1)
-        if self.agg.padded_length != n:
-            view[:, :, n:] = 0
+        real_hi = min(hi, self.agg.model_length)
+        if lo < real_hi:
+            if self._packed:
+                # pack straight into the byte-planar slots (the native
+                # plane-pack kernel): one strided transpose of the first bpn
+                # wire bytes per element — the same copy class as the planar
+                # transpose below, writing bpn/(4L) of the bytes
+                host_limbs.pack_wire_slice(stack, lo, real_hi, self.agg.packed_width, view)
+            else:
+                # transpose straight into the slots (numpy strided copy, no
+                # wire_to_planar intermediate)
+                view[:, :, : real_hi - lo] = stack[:, lo:real_hi, :].transpose(0, 2, 1)
+        if real_hi < hi:
+            view[:, :, max(0, real_hi - lo):] = 0
 
-    def stage_row(self, buf: np.ndarray, i: int, wire: np.ndarray) -> None:
+    def stage_row(self, bufs: list[np.ndarray], i: int, wire: np.ndarray) -> None:
         """Write one wire-layout ``uint32[model_len, L]`` update into slot
-        ``i`` of an open batch's buffer. Any thread; a slot has one
-        writer."""
+        ``i`` of an open batch's buffers, each shard's column range into
+        that shard's. Any thread; a slot has one writer."""
         if wire.shape != (self.agg.model_length, self.agg.n_limbs):
             raise ValueError("expected uint32[model_len, L]")
-        self._relay_wire_rows(buf[i : i + 1], wire[None])
+        for buf, (lo, hi) in zip(bufs, self._slices):
+            self._relay_wire_rows(buf[i : i + 1], wire[None], lo, hi)
         ROWS_STAGED.labels(route="arrival").inc()
 
-    def release_batch(self, buf: np.ndarray) -> None:
-        """Return an open batch's buffer unfolded (a failed slot write)."""
-        with self._lock:
-            self._lent_since.pop(id(buf), None)
-        self._ring(self._host_kind).release(buf)
+    def release_batch(self, bufs: list[np.ndarray]) -> None:
+        """Return an open batch's buffers unfolded (a failed slot write)."""
+        if bufs:
+            with self._lock:
+                self._lent_since.pop(id(bufs[0]), None)
+        for d, buf in enumerate(bufs):
+            self._ring(self._host_kind, d).release(buf)
 
-    def submit_staged(self, buf: np.ndarray, k: int) -> StreamTicket:
-        """Stream-fold the first ``k`` slots of an open batch's buffer,
-        already in the ring's layout. Owns the buffer from here on, an
+    def submit_staged(self, bufs: list[np.ndarray], k: int) -> StreamTicket:
+        """Stream-fold the first ``k`` slots of an open batch's buffers,
+        already in the ring's layout. Owns the buffers from here on, an
         error included."""
         kind = self._host_kind
         try:
             self._check(k)
+            with trace.get_tracer().span(
+                SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k, shards=self._n_shards
+            ):
+                views = [buf[:k] for buf in bufs]
+                plan = self._ensure_plan(k, lambda: self._planar_of(views)) if self._sharded else None
+                BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(
+                    sum(view.nbytes for view in views)
+                )
+                ticket = StreamTicket(k)
         except BaseException:
-            self.release_batch(buf)
+            self.release_batch(bufs)
             raise
-        with trace.get_tracer().span(
-            SPAN_STAGE, batch=self._batch_seq + 1, kind=kind, k=k
-        ):
-            view = buf[:k]
-            BYTES_STAGED.labels(layout="packed" if self._packed else "unpacked").inc(view.nbytes)
-            ticket = StreamTicket(k)
-            self._batch_seq += 1
+        self._batch_seq += 1
         with self._lock:
-            lent = self._lent_since.pop(id(buf), None)
+            lent = self._lent_since.pop(id(bufs[0]), None)
         if lent is not None:
-            self._leg(lent, "stage")  # the buffer in hand -> handed over
-        self._dispatch((buf, view, kind, k, ticket, self._batch_seq))
+            # the buffers in hand -> handed over, every shard's leg alike
+            shards = range(self._n_shards) if self._sharded else ()
+            self._leg(lent, "stage", *(("stage", d) for d in shards))
+        if plan is None:
+            self._dispatch((bufs[0], views[0], kind, k, ticket, self._batch_seq))
+            return ticket
+        job = _BatchJob(kind, k, ticket, self._batch_seq, self._n_shards)
+        self._dispatch_sharded(job, [
+            (job, d, view, self._ring(kind, d), buf)
+            for d, (view, buf) in enumerate(zip(views, bufs))
+        ])
         return ticket
 
-    def _submit_filled(self, k: int, fill) -> StreamTicket:
-        """Open a batch, let ``fill(view)`` relay ``k`` rows out into it
-        inside the ``stream.stage`` span, submit."""
-        with trace.get_tracer().span(
-            SPAN_STAGE, batch=self._batch_seq + 1, kind=self._host_kind, k=k, route="flush"
-        ):
-            buf = self.open_batch()
-            try:
-                fill(buf[:k])
-            except BaseException:
-                self.release_batch(buf)
-                raise
-            ROWS_STAGED.labels(route="flush").inc(k)
-        return self.submit_staged(buf, k)
+    def _planar_of(self, views: list[np.ndarray]) -> np.ndarray:
+        """The staged planar ``uint32[k, L, padded]`` batch that the shards'
+        views hold between them: what the start-up race of the fold kernels
+        times, once, where no verdict is known yet."""
+        if self._packed:
+            from ..ops import limbs as host_limbs
+
+            views = [host_limbs.unpack_planar(view, self.agg.n_limbs) for view in views]
+        return np.concatenate(views, axis=-1)
 
     def submit_batch(self, stack: np.ndarray) -> StreamTicket:
         """Stage + stream-fold wire-layout ``uint32[K, model_len, L]``
-        updates (the pre-validated path: all members count immediately)."""
+        updates (the pre-validated path: all members count immediately):
+        a batch opened, filled and submitted in this one call, each shard's
+        relayout under a ``stream.stage`` span of its own."""
         stack = np.asarray(stack, dtype=np.uint32)  # host input, no device sync  # lint: sync-ok
         if stack.ndim != 3 or stack.shape[2] != self.agg.n_limbs:
             raise ValueError("expected uint32[K, model_len, L]")
@@ -856,29 +893,19 @@ class StreamingAggregator:
             raise ValueError("model length mismatch")
         k = stack.shape[0]
         self._check(k)
-        if self._sharded:
-            return self._submit_sharded_planar_stack(stack, k)
-        return self._submit_filled(k, lambda view: self._relay_wire_rows(view, stack))
-
-    def submit_host_planar_rows(self, rows: list) -> StreamTicket:
-        """Stream-fold host planar ``[L, padded_len]`` rows (numpy), copied
-        into a ring buffer here so the caller can recycle its arrays."""
-        k = len(rows)
-        if k == 0:
-            raise ValueError("empty planar batch")
-        self._check(k)
-        if self._sharded:
-            return self._submit_sharded_planar_rows(rows, k)
-        from ..ops import limbs as host_limbs
-
-        def fill(view):
-            for i, row in enumerate(rows):
-                if self._packed:
-                    host_limbs.pack_planar(row, self.agg.packed_width, out=view[i])
-                else:
-                    np.copyto(view[i], row)
-
-        return self._submit_filled(k, fill)
+        bufs = self.open_batch()
+        try:
+            for d, (buf, (lo, hi)) in enumerate(zip(bufs, self._slices)):
+                with trace.get_tracer().span(
+                    SPAN_STAGE, batch=self._batch_seq + 1, kind=self._host_kind, k=k,
+                    route="flush", shard=d,
+                ):
+                    self._relay_wire_rows(buf[:k], stack, lo, hi)
+        except BaseException:
+            self.release_batch(bufs)
+            raise
+        ROWS_STAGED.labels(route="flush").inc(k)
+        return self.submit_staged(bufs, k)
 
     def fold_planar_rows_now(self, rows: list) -> None:
         """Fold already device-resident, validity-checked planar
@@ -1203,11 +1230,14 @@ class StreamingAggregator:
                     else:
                         outcome = self._degrade_and_retry(payload, kind, k, ticket, seq, first)
             finally:
+                done = time.monotonic()
                 self._slot_release()
                 if buf is not None:
                     self._ring(kind).release(buf)
                 self._leg(agg_t0, "fold")
                 INFLIGHT_FOLDS.dec()
+                # one shard: nobody to wait for, the commit is the hand-back
+                self._record_commit(done, seq, outcome)
                 # a failed fold is NOT folded: dashboards comparing staged vs
                 # folded must be able to see the loss
                 BATCHES_TOTAL.labels(stage=outcome).inc()
@@ -1296,6 +1326,19 @@ class StreamingAggregator:
         self._publish_overlap()
         return accepted
 
+    def _record_commit(self, first_done: float, seq: int, outcome: str) -> None:
+        """``stream.commit`` and its histogram for one batch, by the thread
+        that settles it: ``first_done`` (the first shard's fold item done;
+        on one shard, the only one's) until now, when the batch counts.
+        Recorded after the fact: on a mesh no one thread is there for both
+        ends, so no mirror can carry it."""
+        waited = time.monotonic() - first_done
+        COMMIT_SECONDS.observe(waited)
+        trace.get_tracer().record_span(
+            SPAN_COMMIT, start=first_done, duration=waited, batch=seq, outcome=outcome,
+            shards=self._n_shards,
+        )
+
     def _leg(self, start: float, *keys) -> None:
         """A leg that began at ``start`` ends now: kept under each key for
         the drain window's overlap ratio."""
@@ -1362,28 +1405,6 @@ class StreamingAggregator:
                 self._plan = plan
         return plan
 
-    def _shard_ring(self, d: int) -> _StagingRing:
-        with self._lock:
-            ring = self._shard_rings.get(d)
-            if ring is None:
-                agg = self.agg
-                width = agg.padded_length // self._n_shards
-                if self._packed:
-                    shape: tuple = (self.max_batch, agg.packed_width, width)
-                    dtype = np.uint8
-                else:
-                    shape = (self.max_batch, agg.n_limbs, width)
-                    dtype = np.uint32
-                ring = self._shard_rings[d] = _StagingRing(
-                    self.staging_buffers,
-                    shape,
-                    dtype,
-                    gauge=SHARD_STAGING_DEPTH.labels(shard=str(d)),
-                    pool=self._pool,
-                    tenant=self.tenant,
-                )
-            return ring
-
     def _ensure_shard_workers(self) -> None:
         if self._shard_queues is None:
             self._shard_queues = [
@@ -1415,94 +1436,6 @@ class StreamingAggregator:
             if self._error is None:
                 self._error = cause
                 self._poison_seq = seq
-
-    def _submit_sharded_planar_stack(self, stack: np.ndarray, k: int) -> StreamTicket:
-        """Slice the wire batch ONCE on the host into the per-shard planar
-        rings (each shard's slice transposed straight into its ring buffer
-        — no full-planar intermediate) and dispatch one item per shard."""
-        ticket = StreamTicket(k)
-        agg = self.agg
-        model_len = agg.model_length
-
-        def calib():
-            full = np.zeros((k, agg.n_limbs, agg.padded_length), dtype=np.uint32)
-            full[:, :, :model_len] = stack.transpose(0, 2, 1)
-            return full
-
-        plan = self._ensure_plan(k, calib)
-        from ..ops import limbs as host_limbs
-
-        kind = "packed" if self._packed else "planar"
-        self._batch_seq += 1
-        job = _BatchJob(kind, k, ticket, self._batch_seq, self._n_shards)
-        items = []
-        for d, (lo, hi) in enumerate(plan.slices):
-            t0 = time.monotonic()
-            ring = self._shard_ring(d)
-            buf = ring.acquire()
-            view = buf[:k]
-            real_hi = min(hi, model_len)
-            if lo < real_hi:
-                if self._packed:
-                    # pack this shard's wire slice straight into its
-                    # byte-planar ring buffer (the native plane-pack
-                    # kernel: bpn/(4L) of the bytes the planar transpose
-                    # would write, at memcpy speed)
-                    host_limbs.pack_wire_slice(
-                        stack, lo, real_hi, self.agg.packed_width, view
-                    )
-                else:
-                    view[:, :, : real_hi - lo] = stack[:, lo:real_hi, :].transpose(0, 2, 1)
-            if real_hi < hi:
-                view[:, :, max(0, real_hi - lo):] = 0  # padding columns
-            BYTES_STAGED.labels(
-                layout="packed" if self._packed else "unpacked"
-            ).inc(view.nbytes)
-            dt = time.monotonic() - t0
-            self._leg(t0, "stage", ("stage", d))
-            trace.get_tracer().record_span(
-                SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
-            )
-            items.append((job, d, view, ring, buf))
-        ROWS_STAGED.labels(route="flush").inc(k)
-        self._dispatch_sharded(job, items)
-        return ticket
-
-    def _submit_sharded_planar_rows(self, rows: list, k: int) -> StreamTicket:
-        """Per-shard staging of host planar ``[L, padded]`` rows (sliced
-        once per shard, copied into that shard's ring buffer)."""
-        ticket = StreamTicket(k)
-        plan = self._ensure_plan(k, lambda: np.stack([np.asarray(r) for r in rows]))  # host rows  # lint: sync-ok
-        from ..ops import limbs as host_limbs
-
-        kind = "packed" if self._packed else "planar"
-        self._batch_seq += 1
-        job = _BatchJob(kind, k, ticket, self._batch_seq, self._n_shards)
-        items = []
-        for d, (lo, hi) in enumerate(plan.slices):
-            t0 = time.monotonic()
-            ring = self._shard_ring(d)
-            buf = ring.acquire()
-            view = buf[:k]
-            for i, row in enumerate(rows):
-                if self._packed:
-                    host_limbs.pack_planar_slice(
-                        np.asarray(row), lo, hi, self.agg.packed_width, view[i]  # host rows  # lint: sync-ok
-                    )
-                else:
-                    np.copyto(view[i], row[:, lo:hi])
-            BYTES_STAGED.labels(
-                layout="packed" if self._packed else "unpacked"
-            ).inc(view.nbytes)
-            dt = time.monotonic() - t0
-            self._leg(t0, "stage", ("stage", d))
-            trace.get_tracer().record_span(
-                SPAN_STAGE, start=t0, duration=dt, batch=job.seq, shard=d, k=k
-            )
-            items.append((job, d, view, ring, buf))
-        ROWS_STAGED.labels(route="flush").inc(k)
-        self._dispatch_sharded(job, items)
-        return ticket
 
     def _dispatch_sharded(self, job: _BatchJob, items: list) -> None:
         """Queue one item per shard worker — or, once degraded, fold every
@@ -1546,13 +1479,11 @@ class StreamingAggregator:
             BATCHES_TOTAL.labels(stage="failed").inc()
             raise
         except BaseException as e:
-            unsafe = isinstance(e, _UnsafeFoldError)
-            cause = (e.__cause__ or e) if unsafe else e
-            self._poison(cause, job.seq)
+            self._poison(e, job.seq)
             with self._lock:
                 self._in_flight_models -= job.k
             BATCHES_TOTAL.labels(stage="failed").inc()
-            raise self._poison_error() from cause
+            raise self._poison_error() from e
         finally:
             self._slot_release()
             for i, (_jb, _d, _p, ring, buf) in enumerate(items):
@@ -1624,13 +1555,11 @@ class StreamingAggregator:
                 BATCHES_TOTAL.labels(stage="failed").inc()
                 raise
             except BaseException as e:
-                unsafe = isinstance(e, _UnsafeFoldError)
-                cause = (e.__cause__ or e) if unsafe else e
-                self._poison(cause, seq)
+                self._poison(e, seq)
                 with self._lock:
                     self._in_flight_models -= k
                 BATCHES_TOTAL.labels(stage="failed").inc()
-                raise self._poison_error() from cause
+                raise self._poison_error() from e
             finally:
                 self._slot_release()
                 if not released:
@@ -1648,10 +1577,11 @@ class StreamingAggregator:
         return ticket
 
     def _fold_shard_item(self, job: _BatchJob, d: int, payload) -> None:
-        """Fold one shard's slice of one batch. The shard's accumulator is
-        reassigned only after the fold returns, so an exception here leaves
-        it consistent (the per-shard retry relies on that); failures after
-        the accumulator handoff raise ``_UnsafeFoldError``."""
+        """Fold one shard's slice of one batch: its host-to-device copy, one
+        shard's at a time (``shards.H2D_GATE``), then its fold. The copy is
+        complete before the fold is dispatched and the shard's accumulator
+        is reassigned only after the fold returns, so an exception here
+        leaves it consistent (the per-shard retry relies on that)."""
         with self._lock:
             plan = self._plan
         if job.kind == "wire":
@@ -1660,22 +1590,21 @@ class StreamingAggregator:
         packed = job.kind == "packed"
         import jax
 
-        with plan._device_dispatch_lock:
-            # host-side transfer enqueue only — the copy itself proceeds
-            # async and the barrier below stays outside the lock (packed
-            # staging: only bpn-byte planes cross here, the unpack runs
-            # in-graph on the shard's device)
-            staged = jax.device_put(payload, plan.devices[d])
+        with H2D_GATE, _h2d(job.kind, payload.nbytes, shard=d):
+            with plan._device_dispatch_lock:
+                # host-side transfer enqueue only — the copy itself proceeds
+                # async and the barrier below stays outside the lock (packed
+                # staging: only bpn-byte planes cross here, the unpack runs
+                # in-graph on the shard's device)
+                staged = jax.device_put(payload, plan.devices[d])
+            # the transfer out of the ring buffer completes before the next
+            # shard's begins (the gate) and before this shard's fold is
+            # dispatched, so a failure here leaves the accumulator untouched
+            jax.block_until_ready(staged)  # lint: sync-ok
         if packed:
             plan.fold_shard_packed(d, staged)
         else:
             plan.fold_shard(d, staged)
-        try:
-            # the per-shard transfer out of the ring buffer must complete
-            # before reuse; the fold itself stays in flight behind it
-            jax.block_until_ready(staged)  # lint: sync-ok
-        except BaseException as e:
-            raise _UnsafeFoldError() from e
 
     def _retry_shard(self, job: _BatchJob, d: int, payload, first: BaseException) -> bool:
         """Per-shard leg of the degradation ladder: the failed shard's
@@ -1701,10 +1630,8 @@ class StreamingAggregator:
             self._fold_shard_item(job, d, payload)
             return True
         except BaseException as second:
-            unsafe = isinstance(second, _UnsafeFoldError)
-            cause = (second.__cause__ or second) if unsafe else second
-            cause.__context__ = first
-            self._poison(cause, job.seq)
+            second.__context__ = first
+            self._poison(second, job.seq)
             logger.exception(
                 "streaming shard %d lost batch %d; pipeline poisoned", d, job.seq
             )
@@ -1734,18 +1661,7 @@ class StreamingAggregator:
                         maybe_fail(f"streaming.shard{d}.fold")
                         self._fold_shard_item(job, d, payload)
                     except BaseException as first:
-                        if isinstance(first, _UnsafeFoldError):
-                            cause = first.__cause__ or first
-                            self._poison(cause, job.seq)
-                            failed = True
-                            logger.exception(
-                                "streaming shard %d fold of batch %d failed "
-                                "post-dispatch; pipeline poisoned",
-                                d,
-                                job.seq,
-                            )
-                        else:
-                            failed = not self._retry_shard(job, d, payload, first)
+                        failed = not self._retry_shard(job, d, payload, first)
                 finally:
                     if ring is not None:
                         ring.release(buf)
@@ -1766,9 +1682,12 @@ class StreamingAggregator:
         atomically (or just leave flight when the batch failed); wire
         batches release the shared byte buffer once the mesh transfer
         completed (their credit waits for drain's acceptance sync)."""
+        now = time.monotonic()
         with self._lock:
             if failed:
                 job.failed = True
+            if job.first_done is None:
+                job.first_done = now
             job.remaining -= 1
             last = job.remaining == 0
             if last and job.kind != "wire":  # planar AND packed batches
@@ -1802,15 +1721,9 @@ class StreamingAggregator:
         failed = job.failed  # lint: guarded-ok: last-shard tail, single owner
         retried = job.retried  # lint: guarded-ok: last-shard tail, single owner
         outcome = "failed" if failed else ("folded-degraded" if retried else "folded")
-        # the commit barrier as a zero-width marker span: WHEN the batch
-        # settled its accounting, and how (the last shard records it)
-        trace.get_tracer().record_span(
-            SPAN_COMMIT,
-            start=time.monotonic(),
-            duration=0.0,
-            batch=job.seq,
-            outcome=outcome,
-        )
+        # the commit barrier as the round feels it: the first shard's fold
+        # item done -> the batch counts (the last shard records it)
+        self._record_commit(job.first_done, job.seq, outcome)  # lint: guarded-ok: last-shard tail, single owner
         BATCHES_TOTAL.labels(stage=outcome).inc()
         if failed:
             with self._lock:

@@ -188,23 +188,24 @@ class DeviceAggregation(Aggregation):
 
 
 class _OpenBatch:
-    """One fold batch still filling: a ring buffer of the streaming
-    pipeline and the slot writes submitted into it, in arrival order. The
-    first write to run borrows the buffer (which may wait for a free
-    one); the other writes of the batch wait for it on the lock."""
+    """One fold batch still filling: the ring buffers of the streaming
+    pipeline (one a shard) and the slot writes submitted into them, in
+    arrival order. The first write to run borrows the buffers (which may
+    wait for free ones); the other writes of the batch wait for it on the
+    lock."""
 
-    __slots__ = ("writes", "buf", "_lock")
+    __slots__ = ("writes", "bufs", "_lock")
 
     def __init__(self):
         self.writes: list = []  # futures; write i fills slot i
-        self.buf = None  # guarded-by: _lock
+        self.bufs = None  # guarded-by: _lock
         self._lock = threading.Lock()
 
-    def buffer(self, stream) -> np.ndarray:
+    def buffers(self, stream) -> list:
         with self._lock:
-            if self.buf is None:
-                self.buf = stream.open_batch()
-            return self.buf
+            if self.bufs is None:
+                self.bufs = stream.open_batch()
+            return self.bufs
 
 
 class StagedAggregator:
@@ -229,8 +230,8 @@ class StagedAggregator:
         self.object_size = object_size
         self.tenant = tenant
         self.batch_size = max(1, batch_size)
-        # device: device-resident planars (wire ingest) and, on a
-        # shard-parallel pipeline, futures of host planar arrays
+        # device: device-resident planars (wire ingest) only; a host update
+        # lives in its slot of an open batch
         self._staged_vect: list = []
         self._open: list[_OpenBatch] = []  # device: batches filling in the ring
         self._staged_unit: list[np.ndarray] = []
@@ -412,13 +413,8 @@ class StagedAggregator:
             # model-count adjustment below cannot race the fold worker
             self.drain()
             from ..ops import limbs as limb_ops
-            from ..ops.fold_jax import wire_to_planar
 
-            planar = wire_to_planar(np.asarray(obj.vect.data))
-            padded = self._device.padded_length
-            if planar.shape[1] != padded:
-                planar = np.pad(planar, ((0, 0), (0, padded - planar.shape[1])))
-            self._stream.submit_host_planar_rows([planar])
+            self._stream.submit_batch(np.asarray(obj.vect.data)[None])
             self._stream.drain()
             # the partial counts as `members` models, not the one row folded
             self._device.nb_models += members - 1
@@ -457,10 +453,11 @@ class StagedAggregator:
                 # wire ingest: validate_aggregation already unpacked this
                 # update on device — stage the device-resident planar
                 self._staged_vect.append(planar_dev)
-            elif self._stream.stages_rows:
+            else:
                 # straight from the wire layout into this update's slot of
-                # the open batch's ring buffer (slot = arrival order), so
-                # the flush that closes the batch relays nothing out
+                # the open batch's ring buffers, a shard's columns into that
+                # shard's (slot = arrival order), so the flush that closes
+                # the batch relays nothing out
                 batch = self._open[-1] if self._open else None
                 if batch is None or len(batch.writes) >= self._stream.max_batch:
                     batch = _OpenBatch()
@@ -468,30 +465,13 @@ class StagedAggregator:
                 stream, slot = self._stream, len(batch.writes)
 
                 def write_slot(data=obj.vect.data):
-                    buf = batch.buffer(stream)
+                    bufs = batch.buffers(stream)
                     with stages.stage(
                         "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes
                     ):
-                        stream.stage_row(buf, slot, data)
+                        stream.stage_row(bufs, slot, data)
 
                 batch.writes.append(self._ingest_pool.submit(write_slot))
-            else:
-                # shard-parallel pipeline: rows are sliced across the
-                # per-shard rings when the batch is submitted
-                from ..ops.fold_jax import wire_to_planar
-
-                padded = self._device.padded_length
-
-                def to_planar(data=obj.vect.data):
-                    with stages.stage(
-                        "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes
-                    ):
-                        planar = wire_to_planar(data)
-                        if planar.shape[1] != padded:
-                            planar = np.pad(planar, ((0, 0), (0, padded - planar.shape[1])))
-                        return planar
-
-                self._staged_vect.append(self._ingest_pool.submit(to_planar))
         else:
             self._staged_vect.append(obj.vect.data)
         self._staged_unit.append(obj.unit.data)
@@ -515,29 +495,20 @@ class StagedAggregator:
         stack = None if self._ingest_pool is not None else np.stack(self._staged_vect)
         units = np.stack(self._staged_unit)
         if self._device is not None:
-            import jax
-
             from ..ops import limbs as limb_ops
 
             self._submit_open_batches()
-            parts = [p.result() if hasattr(p, "result") else p for p in self._staged_vect]
-            self._staged_vect.clear()  # consume destructively: free as we fold
+            parts, self._staged_vect = self._staged_vect, []  # consumed: free as we fold
             # wire-v2 members stay PACKED uint8[bpn, padded] through staging
             # (bpn bytes/element vs the 4L a uint32 planar pins) and fold
             # through the fused packed kernel; a mixed round therefore
             # splits one flush by staged layout
-            packed_rows = [
-                p for p in parts if isinstance(p, jax.Array) and p.dtype == "uint8"
-            ]
-            parts = [
-                p for p in parts if not (isinstance(p, jax.Array) and p.dtype == "uint8")
-            ]
+            packed_rows = [p for p in parts if p.dtype == "uint8"]
+            parts = [p for p in parts if p.dtype != "uint8"]
             if packed_rows:
                 self._stream.fold_packed_rows_now(packed_rows)
                 packed_rows.clear()
-            if not parts:
-                pass
-            elif all(isinstance(p, jax.Array) for p in parts):
+            if parts:
                 # wire ingest: every planar is already device-resident and
                 # validity-checked — folded INLINE (not queued: parking
                 # device-resident batches behind dispatch_ahead would pin
@@ -547,17 +518,7 @@ class StagedAggregator:
                 # staged planars + one chunk-sized copy, the pre-streaming
                 # bound.
                 self._stream.fold_planar_rows_now(parts)
-            else:
-                # host planars of a shard-parallel pipeline: sliced into
-                # the per-shard staging rings (no np.stack allocation) and
-                # folded by the workers while this thread returns to
-                # staging the next micro-batch
-                host_rows = [np.asarray(p) for p in parts]
-                for start in range(0, len(host_rows), self._stream.max_batch):
-                    self._stream.submit_host_planar_rows(
-                        host_rows[start : start + self._stream.max_batch]
-                    )
-            parts.clear()
+                parts.clear()
             order_limbs = limb_ops.order_limbs_for(self.config.unit.order)
             batch_unit = limb_ops.batch_mod_sum(units[:, None, :], order_limbs)[0]
             self._unit_acc = limb_ops.mod_add(
@@ -587,12 +548,12 @@ class StagedAggregator:
             for write in batch.writes:
                 error = write.exception()  # waits for the write to end
                 failed = failed or error
-            if batch.buf is None:
-                continue  # no write got as far as borrowing a buffer
+            if batch.bufs is None:
+                continue  # no write got as far as borrowing the buffers
             if failed is None:
-                self._stream.submit_staged(batch.buf, len(batch.writes))
+                self._stream.submit_staged(batch.bufs, len(batch.writes))
             else:
-                self._stream.release_batch(batch.buf)
+                self._stream.release_batch(batch.bufs)
         if failed is not None:
             # what was staged since the last flush is lost with it
             self._staged_vect.clear()

@@ -12,8 +12,8 @@ counted where the choice is made:
 
 - ``parse``: ``ops/limbs.py::bytes_le_to_limbs`` (wire bytes -> limbs);
 - ``validate``: ``ops/limbs.py::all_lt_order`` (element < order);
-- ``stage``: ``ops/limbs.py::pack_wire_slice``, ``pack_wire``, ``pack_planar``,
-  ``pack_planar_slice`` (limbs -> byte planes);
+- ``stage``: ``ops/limbs.py::pack_wire_slice``, ``pack_wire``, ``pack_planar``
+  (limbs -> byte planes);
 - ``derive``: ``core/crypto/prng.py::StreamSampler.draw_limbs`` (seed -> mask
   elements), in the process that derives: the sum participant's, not the
   coordinator's. A third route, ``fused``, counts the elements that

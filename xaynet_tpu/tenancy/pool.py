@@ -201,7 +201,7 @@ class PagePool:
                     f"{self.host_pages - self._in_use['host']} of "
                     f"{self.host_pages} available"
                 )
-            slab_idx, start = -1, None
+            slab_idx, start, fresh = -1, None, False
             for i, slab in enumerate(self._slabs):
                 start = slab.take(pages)
                 if start is not None:
@@ -215,12 +215,20 @@ class PagePool:
                 self._slabs.append(slab)
                 slab_idx = len(self._slabs) - 1
                 start = slab.take(pages)
+                fresh = True
             lease = self._grant_locked(tenant, "host", pages, slab_idx, start)
             slab_buf = self._slabs[slab_idx].buf
         raw = slab_buf[start * self.page_bytes : start * self.page_bytes + nbytes]
         view = raw.view(dtype).reshape(shape)
-        view.fill(0)  # cross-tenant hygiene: never hand over another
-        # tenant's masked bytes
+        if not fresh:
+            view.fill(0)  # cross-tenant hygiene: never hand over another
+            # tenant's masked bytes
+        # a slab made for this lease is zero pages nobody has touched
+        # (``np.zeros``): writing zeros over them would fault every page in
+        # here, on the caller's thread and before it can use any (2.2-3.5 s
+        # for a 2.15 GB staging buffer on the four-chip host, four in a row
+        # a round since compaction trims the empty slabs: PERF.md section 6,
+        # PR 40); left alone, each is mapped by whoever first writes it
         lease.array = view
         return lease
 
