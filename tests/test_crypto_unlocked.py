@@ -424,10 +424,11 @@ def test_the_pipeline_gives_up_a_bytearray_and_only_that(monkeypatch):
     ``rest.py`` read) and leaves ``bytes`` alone; the parsed fields are
     ``bytes`` whatever the plaintext was a view of."""
     from xaynet_tpu.server.events import PhaseName
-    from xaynet_tpu.server.services import PetMessageHandler
+    from xaynet_tpu.server.services import MessageWorkers, PetMessageHandler
 
     monkeypatch.setattr(unlocked, "UNLOCKED_MIN", 64)
-    handler = PetMessageHandler(events=None, request_tx=None)
+    workers = MessageWorkers(1)  # its own, closed below: the process's stay up
+    handler = PetMessageHandler(events=None, request_tx=None, workers=workers)
     message = Message(
         participant_pk=SIGNER.public, coordinator_pk=KEYS.public.as_bytes(),
         payload=Sum(sum_signature=b"\x01" * 64, ephm_pk=b"\x02" * 32), tag=Tag.SUM,
@@ -440,7 +441,7 @@ def test_the_pipeline_gives_up_a_bytearray_and_only_that(monkeypatch):
             assert type(parsed.payload.ephm_pk) is bytes and {parsed.payload.ephm_pk: 1}
             assert (bytes(given) == sealed) is isinstance(given, bytes)
     finally:
-        handler._pool.shutdown(wait=False)
+        workers.close()
 
 
 # --- the lock ---------------------------------------------------------------

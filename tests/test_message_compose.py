@@ -241,12 +241,13 @@ def test_the_coordinators_pipeline_parses_the_composed_box(kind, n_limbs):
     parse) on the box as the HTTP server reads it (a ``bytearray``, opened in
     place) and as an in-process client hands it over."""
     from xaynet_tpu.server.events import PhaseName
-    from xaynet_tpu.server.services import PetMessageHandler
+    from xaynet_tpu.server.services import MessageWorkers, PetMessageHandler
 
     message, payload = _case(kind, n_limbs, 37)
     phase = PhaseName.SUM2 if kind == "sum2" else PhaseName.UPDATE
     box = _pending(message).sealed_part()
-    handler = PetMessageHandler(events=None, request_tx=None)
+    workers = MessageWorkers(1)  # its own, closed below: the process's stay up
+    handler = PetMessageHandler(events=None, request_tx=None, workers=workers)
     try:
         for given in (bytearray(box), memoryview(box).toreadonly()):
             parsed = handler._decrypt_parse_one(given, COORD, phase)
@@ -255,7 +256,7 @@ def test_the_coordinators_pipeline_parses_the_composed_box(kind, n_limbs):
                 parsed.payload.wire_planar = True
             assert parsed.payload.to_bytes() == payload
     finally:
-        handler._pool.shutdown(wait=False)
+        workers.close()
 
 
 # --- the seal: one box whichever route sealed it -------------------------------------

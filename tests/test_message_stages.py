@@ -4,11 +4,15 @@ the message's own request span and request id, labelled with the phase the
 message arrived in, closing on the message's residence; the host-to-device
 copy counted in bytes. And the run outside the Update window: the Sum2
 message's chain, the Unmask phase's stages (telemetry/unmask.py) and the
-start-up timeline (telemetry/startup.py)."""
+start-up timeline (telemetry/startup.py). The message workers: the pool's
+size from the host's cores, one pool a process, and a long message's
+signature checked beside its parse with no result before the verdict."""
 
 import asyncio
+import concurrent.futures
 import json
 import os
+import threading
 import time
 from fractions import Fraction
 from pathlib import Path
@@ -16,6 +20,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from xaynet_tpu.core.crypto.encrypt import EncryptKeyPair
+from xaynet_tpu.core.crypto.sign import SigningKeyPair
 from xaynet_tpu.sdk.client import HttpClient
 from xaynet_tpu.sdk.simulation import keys_for_task
 from xaynet_tpu.sdk.state_machine import PetSettings, StateMachine as ParticipantSM
@@ -42,7 +48,12 @@ from xaynet_tpu.telemetry import BridgedMetrics, tracing
 from xaynet_tpu.telemetry import unmask as unmask_stages
 
 REPO = Path(__file__).resolve().parent.parent
+COORD = EncryptKeyPair.derive_from_seed(bytes(range(32)))
+SIGNER = SigningKeyPair.derive_from_seed(bytes(range(32, 64)))
 N_SUM, N_UPDATE, MODEL_LEN = 1, 4, 500_009
+# at 6 wire bytes an element a message of this many is over
+# ``unlocked.UNLOCKED_MIN``: its signature is checked beside its parse
+LONG_MODEL_LEN = 750_011
 SUM_PROB, UPDATE_PROB = 0.4, 0.5
 # the chain of a message's residence, in order (to_planar runs beside it)
 CHAIN = ("read_body", "pool_wait", "open", "verify", "parse", "resume_wait",
@@ -85,11 +96,11 @@ def _settings(model_length: int = MODEL_LEN) -> Settings:
     return settings
 
 
-async def _round() -> dict:
+async def _round(model_length: int = MODEL_LEN) -> dict:
     """One PET round over the REST API on localhost, host aggregation, a
     fold batch of one (so every accepted update fills its batch and pays a
     flush). Returns what the assertions need."""
-    settings = _settings()
+    settings = _settings(model_length)
     settings.aggregation.batch_size = 1
     store = Store(InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor())
     # a recorder, so that the phases' durations reach /metrics as they do
@@ -121,7 +132,7 @@ async def _round() -> dict:
                     scalar=Fraction(1, N_UPDATE),
                     max_message_size=None),  # one message per update, no chunks
                 HttpClient(url),
-                ArrayModelStore(rng.uniform(-1, 1, MODEL_LEN).astype(np.float32)))
+                ArrayModelStore(rng.uniform(-1, 1, model_length).astype(np.float32)))
             for i in range(N_UPDATE)
         ]
 
@@ -169,17 +180,27 @@ async def _round() -> dict:
     return out
 
 
-@pytest.fixture(scope="module")
-def served_round():
+def _served(model_length: int) -> dict:
     tracer = tracing.get_tracer()
     mode = tracer.mode
     tracer.configure(mode="on")
     try:
-        out = asyncio.run(asyncio.wait_for(_round(), timeout=120))
+        out = asyncio.run(asyncio.wait_for(_round(model_length), timeout=120))
         out["spans"] = [s for s in tracer.ring_spans() if s.start >= out["t_open"]]
     finally:
         tracer.configure(mode=mode)
     return out
+
+
+@pytest.fixture(scope="module")
+def served_round():
+    return _served(MODEL_LEN)
+
+
+@pytest.fixture(scope="module")
+def served_long_round():
+    """The same round with messages over ``unlocked.UNLOCKED_MIN``."""
+    return _served(LONG_MODEL_LEN)
 
 
 @pytest.mark.parametrize("label", CHAIN)
@@ -489,9 +510,327 @@ def test_two_tenants_in_different_phases_are_labelled_apart():
         ("a", "sum"), ("b", "idle")]
 
 
+# --- the message workers ------------------------------------------------------
+
+
+def _bare_handler(size: int):
+    """A handler on workers of its own (closed by the caller), with no state
+    machine behind it: what ``_decrypt_parse_one`` needs and no more."""
+    from xaynet_tpu.server.services import MessageWorkers
+
+    workers = MessageWorkers(size)
+    return PetMessageHandler(events=None, request_tx=None, workers=workers), workers
+
+
+_LONG: dict = {}
+
+
+def _long_sum2_bytes() -> bytearray:
+    """One signed Sum2 message over ``unlocked.UNLOCKED_MIN`` bytes, a fresh
+    copy a call (the callers corrupt theirs)."""
+    from xaynet_tpu.core.mask.config import BoundType, DataType, GroupType, MaskConfig, ModelType
+    from xaynet_tpu.core.mask.object import MaskObject, MaskUnit, MaskVect
+    from xaynet_tpu.core.message import Message, Sum2
+    from xaynet_tpu.ops import limbs as limb_ops
+
+    if "raw" not in _LONG:
+        config = MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B0, ModelType.M3)
+        n_limb = limb_ops.n_limbs_for_bytes(config.bytes_per_number)
+        data = np.random.default_rng(7).integers(
+            0, 1 << 32, size=(LONG_MODEL_LEN, n_limb), dtype=np.uint64).astype(np.uint32)
+        data[:, -1] &= (1 << (config.order.bit_length() - 1 - 32 * (n_limb - 1))) - 1
+        unit = np.zeros(limb_ops.n_limbs_for_order(config.order), dtype=np.uint32)
+        message = Message(
+            participant_pk=SIGNER.public, coordinator_pk=COORD.public.as_bytes(),
+            payload=Sum2(sum_signature=bytes(64),
+                         model_mask=MaskObject(MaskVect(config, data), MaskUnit(config, unit))))
+        _LONG["raw"] = message.to_bytes(SIGNER.secret)
+    return bytearray(_LONG["raw"])
+
+
+def _drop(handler, raw, phase) -> "str | None":
+    """How ``_decrypt_parse_one`` drops the sealed ``raw`` (None: it does not)."""
+    from xaynet_tpu.server.services import ServiceError
+
+    try:
+        handler._decrypt_parse_one(bytearray(COORD.public.encrypt(bytes(raw))), COORD, phase)
+    except ServiceError as err:
+        return str(err)
+    return None
+
+
+def _verify_bytes_moved() -> dict:
+    from xaynet_tpu.server import services
+
+    return {key[0]: child.value for key, child in services._VERIFY_BYTES.children()}
+
+
+@pytest.mark.parametrize("cores,threads", [(1, 4), (4, 4), (13, 8), (30, 16)])
+def test_the_pool_is_sized_from_the_cores_and_never_under_four(monkeypatch, cores, threads):
+    from xaynet_tpu.server import services
+
+    assert services.worker_count(cores) == threads
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(cores)))
+    workers = services.MessageWorkers()
+    try:
+        assert workers.size == threads
+        assert workers.pool._max_workers == workers.verdicts._max_workers == threads
+    finally:
+        workers.close()
+
+
+def test_the_rule_grows_with_the_host_and_leaves_it_cores():
+    from xaynet_tpu.server.services import worker_count
+
+    sizes = [worker_count(cores) for cores in range(1, 257)]
+    assert sizes == sorted(sizes) and min(sizes) == 4
+    # past the floor the other threads of a message's way keep cores
+    assert all(size < cores for cores, size in enumerate(sizes, 1) if cores > 6)
+
+
+def test_where_the_platform_has_no_affinity_the_cpu_count_is_used(monkeypatch):
+    from xaynet_tpu.server import services
+
+    monkeypatch.delattr(os, "sched_getaffinity")
+    monkeypatch.setattr(os, "cpu_count", lambda: 30)
+    assert services.available_cores() == 30
+    monkeypatch.setattr(os, "cpu_count", lambda: None)
+    assert services.available_cores() == 1
+
+
+def test_two_tenants_share_the_process_workers_and_the_gauge_says_their_size():
+    from xaynet_tpu.server import services
+
+    a = PetMessageHandler(events=None, request_tx=None)
+    b = PetMessageHandler(events=None, request_tx=None, wire_ingest=True)
+    assert a.workers is b.workers is services.shared_workers()
+    assert a.workers.size == services.worker_count(services.available_cores()) >= 4
+    assert services._MESSAGE_WORKERS.value == a.workers.size
+
+
+CORRUPTIONS = {
+    "bad signature, sound body": ("signature",),
+    "good signature, malformed body": ("body",),
+    "both bad": ("body", "signature"),
+}
+
+
+@pytest.mark.parametrize("what", list(CORRUPTIONS))
+def test_a_long_message_is_dropped_with_the_error_the_inline_order_gives(monkeypatch, what):
+    """Verify-then-parse on the worker (the order before the signature pass
+    ran beside the parse, which a short message still takes) and the
+    side-by-side route drop one message with one error."""
+    from xaynet_tpu.core.crypto import unlocked
+    from xaynet_tpu.core.message import Message
+    from xaynet_tpu.core.message.message import HEADER_LENGTH
+    from xaynet_tpu.server.events import PhaseName
+
+    raw = _long_sum2_bytes()
+    if "body" in CORRUPTIONS[what]:  # an element over the group's order, then signed
+        first = HEADER_LENGTH + 64 + 8
+        raw[first:first + 6] = b"\xff" * 6
+        Message.sign_into(raw, 0, len(raw), SIGNER.secret)
+    if "signature" in CORRUPTIONS[what]:
+        raw[0] ^= 1
+    handler, workers = _bare_handler(2)
+    try:
+        before = _verify_bytes_moved()
+        beside = _drop(handler, raw, PhaseName.SUM2)
+        moved = _verify_bytes_moved()
+        assert moved.get("beside", 0) - before.get("beside", 0) == len(raw)
+        assert moved.get("inline", 0) == before.get("inline", 0)
+        monkeypatch.setattr(unlocked, "UNLOCKED_MIN", len(raw) + 1)
+        inline = _drop(handler, raw, PhaseName.SUM2)
+        assert _verify_bytes_moved().get("inline", 0) - before.get("inline", 0) == len(raw)
+    finally:
+        workers.close()
+    assert beside is not None and beside == inline
+    if "signature" in CORRUPTIONS[what]:
+        assert beside == "parse: invalid message signature"
+    else:
+        assert beside == "parse: mask vector element >= group order"
+
+
+def test_no_message_leaves_before_the_verdict(monkeypatch):
+    """The parse is done and the signature pass is not: the function has not
+    returned; the verdict comes and it returns the message."""
+    from xaynet_tpu.core.message import Message
+    from xaynet_tpu.server.events import PhaseName
+
+    verdict_due, real = threading.Event(), Message.verify_bytes
+    entered = threading.Event()
+
+    def slow_verify(data):
+        entered.set()
+        assert verdict_due.wait(timeout=30)
+        real(data)
+
+    monkeypatch.setattr(Message, "verify_bytes", staticmethod(slow_verify))
+    sealed = bytearray(COORD.public.encrypt(bytes(_long_sum2_bytes())))
+    handler, workers = _bare_handler(1)
+    try:
+        parsed0 = _counts("sum2").get("parse", 0)
+        result = workers.pool.submit(handler._decrypt_parse_one, sealed, COORD, PhaseName.SUM2)
+        deadline = time.monotonic() + 30
+        while _counts("sum2").get("parse", 0) == parsed0 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert _counts("sum2").get("parse", 0) == parsed0 + 1 and entered.is_set()
+        time.sleep(0.05)
+        assert not result.done()
+        verdict_due.set()
+        assert isinstance(result.result(timeout=30), Message)
+    finally:
+        verdict_due.set()
+        workers.close()
+
+
+def test_a_bad_verdict_that_comes_late_still_drops_the_message(monkeypatch):
+    from xaynet_tpu.core.mask.serialization import DecodeError
+    from xaynet_tpu.core.message import Message
+    from xaynet_tpu.server.events import PhaseName
+    from xaynet_tpu.server.services import ServiceError
+
+    def late_refusal(data):
+        time.sleep(0.2)
+        raise DecodeError("invalid message signature")
+
+    monkeypatch.setattr(Message, "verify_bytes", staticmethod(late_refusal))
+    sealed = bytearray(COORD.public.encrypt(bytes(_long_sum2_bytes())))
+    handler, workers = _bare_handler(1)
+    try:
+        with pytest.raises(ServiceError, match="parse: invalid message signature"):
+            handler._decrypt_parse_one(sealed, COORD, PhaseName.SUM2)
+    finally:
+        workers.close()
+
+
+def test_four_workers_all_waiting_for_their_verdicts_do_not_deadlock(monkeypatch):
+    """Twelve long messages on a pool of four, each signature pass held
+    until four passes run at once: every worker is then inside its wait for
+    the verdict, and the passes still run, on threads that are not the
+    pool's."""
+    from xaynet_tpu.core.message import Message
+    from xaynet_tpu.server.events import PhaseName
+
+    together, real = threading.Barrier(4, timeout=60), Message.verify_bytes
+    threads = set()
+
+    def verify_with_the_others(data):
+        threads.add(threading.current_thread().name)
+        together.wait()
+        real(data)
+
+    monkeypatch.setattr(Message, "verify_bytes", staticmethod(verify_with_the_others))
+    box = COORD.public.encrypt(bytes(_long_sum2_bytes()))
+    handler, workers = _bare_handler(4)
+    try:
+        results = [
+            workers.pool.submit(handler._decrypt_parse_one, bytearray(box), COORD, PhaseName.SUM2)
+            for _ in range(12)
+        ]
+        done, pending = concurrent.futures.wait(results, timeout=120)
+        assert not pending
+        assert all(isinstance(r.result(), Message) for r in done)
+    finally:
+        together.abort()
+        workers.close()
+    assert threads and all(name.startswith("pet-verify") for name in threads)
+
+
+def test_verify_and_verify_beside_once_a_long_message_verify_alone_a_short_one():
+    from xaynet_tpu.core.message import Message, Sum, Tag
+    from xaynet_tpu.server.events import PhaseName
+
+    handler, workers = _bare_handler(1)
+    tracer = tracing.get_tracer()
+    mode = tracer.mode
+    tracer.configure(mode="on")
+    try:
+        long_raw = _long_sum2_bytes()
+        before, t0 = _counts("sum2"), time.monotonic()
+        handler._decrypt_parse_one(
+            bytearray(COORD.public.encrypt(bytes(long_raw))), COORD, PhaseName.SUM2)
+        after = _counts("sum2")
+        for label in ("open", "verify", "parse", "verify_beside"):
+            assert after.get(label, 0) - before.get(label, 0) == 1, label
+        spans = {s.name: s for s in tracer.ring_spans() if s.start >= t0}
+        beside, parse = spans["pipeline.verify_beside"], spans["pipeline.parse"]
+        assert beside.attrs["bytes"] == len(long_raw) and beside.attrs["phase"] == "sum2"
+        assert beside.parent_id == parse.parent_id  # beside the parse, not under it
+        # the chain's `verify` begins where the parse ends: it is the wait
+        wait = spans["pipeline.verify"]
+        assert wait.start >= parse.start + parse.duration - 1e-4
+
+        short = Message(
+            participant_pk=SIGNER.public, coordinator_pk=COORD.public.as_bytes(),
+            payload=Sum(sum_signature=b"\x01" * 64, ephm_pk=b"\x02" * 32), tag=Tag.SUM,
+        ).to_bytes(SIGNER.secret)
+        before, moved = _counts("sum"), _verify_bytes_moved()
+        handler._decrypt_parse_one(COORD.public.encrypt(short), COORD, PhaseName.SUM)
+        after = _counts("sum")
+        for label in ("open", "verify", "parse"):
+            assert after.get(label, 0) - before.get(label, 0) == 1, label
+        assert after.get("verify_beside", 0) == before.get("verify_beside", 0)
+        assert _verify_bytes_moved().get("inline", 0) - moved.get("inline", 0) == len(short)
+    finally:
+        tracer.configure(mode=mode)
+        workers.close()
+
+
+# the chain and the pass beside it, for messages over UNLOCKED_MIN
+@pytest.mark.parametrize("label", CHAIN + ("verify_beside",))
+def test_each_stage_observed_once_per_accepted_long_update(served_long_round, label):
+    before, after = served_long_round["before"], served_long_round["after"]
+    assert after.get(label, 0) - before.get(label, 0) == N_UPDATE
+
+
+def test_the_long_sum2_message_has_its_pass_beside_too(served_long_round):
+    before, after = served_long_round["sum2_before"], served_long_round["sum2_after"]
+    for label in SUM2_CHAIN + ("verify_beside",):
+        assert after.get(label, 0) - before.get(label, 0) == N_SUM, label
+
+
+def _read_metric(name: str, served: dict):
+    """A per-layer metric as the benchmark computes it: its own spec, run by
+    the shipped reader over the round's /metrics reads."""
+    import importlib
+
+    from benchmark.harness.coordinator import parse_metrics
+
+    spec = json.loads((REPO / f"benchmark/layer_metrics/{name}.json").read_text())
+    ctx = {"metrics": {at: parse_metrics(served[f"metrics_{at}"])
+                       for at in ("open", "close", "end")}}
+    return importlib.import_module(f"benchmark.readers.{spec['reader']}").read(ctx, **spec["args"])
+
+
+def test_the_chain_still_closes_with_the_pass_beside_it(served_long_round):
+    """``verify`` is the wait for the verdict, ``verify_beside`` no part of
+    the chain: the twelve stages still sum to the residence."""
+    closure = _read_metric("pipeline.stage_closure", served_long_round)
+    assert closure is not None and 95.0 <= closure <= 100.5, closure
+    # the pass is longer than what the chain waited for it
+    beside = _read_metric("pipeline.verify_beside_ms", served_long_round)
+    waited = _read_metric("pipeline.verify_ms", served_long_round)
+    assert beside is not None and waited is not None and beside > 0
+
+
+def test_the_new_metrics_read_the_workers_and_the_route(served_long_round, served_round):
+    from xaynet_tpu.server import services
+
+    assert _read_metric("pipeline.workers", served_long_round) == services.shared_workers().size
+    # by bytes: the Sum message and nothing else of the long round is inline
+    share = _read_metric("pipeline.verify_beside_share", served_long_round)
+    assert share is not None and 99.0 <= share <= 100.0, share
+    # the short round's messages are all under UNLOCKED_MIN
+    assert _read_metric("pipeline.verify_beside_share", served_round) == 0.0
+    assert _read_metric("pipeline.verify_beside_ms", served_round) is None
+
+
 _SERVE_ONCE = """
 import asyncio, json, socket, sys
 from xaynet_tpu.resilience.checkpoint import RECOVERY_SECONDS
+from xaynet_tpu.core.crypto.encrypt import EncryptKeyPair
+from xaynet_tpu.core.crypto.sign import SigningKeyPair
 from xaynet_tpu.sdk.client import HttpClient
 from xaynet_tpu.server import runner
 from xaynet_tpu.telemetry import startup
@@ -525,8 +864,10 @@ async def scenario():
         serving.cancel()
         await asyncio.gather(serving, return_exceptions=True)
 
-section = asyncio.run(asyncio.wait_for(scenario(), timeout=60))["startup"]
+health = asyncio.run(asyncio.wait_for(scenario(), timeout=60))
+section = health["startup"]
 print(json.dumps({"section": section, "recovery": RECOVERY_SECONDS.value,
+                  "workers": health["message_workers"],
                   "gauge": {key[0]: child.value for key, child in startup.SECONDS.children()}}))
 """
 
@@ -558,3 +899,7 @@ def test_serve_marks_its_start_up_and_healthz_reports_it(tmp_path, tenants):
         assert out["gauge"][step] == pytest.approx(section[step]["took"], abs=1e-6)
     assert out["recovery"] == pytest.approx(
         section["serving"]["at"] - section["imports"]["at"], abs=2e-6)
+    # one pool a process, two tenants or one, at the size the rule gives this host
+    from xaynet_tpu.server.services import available_cores, worker_count
+
+    assert out["workers"] == worker_count(available_cores())

@@ -23,9 +23,21 @@ calls beside the spinning main thread at 4 KiB to 8 MiB: where the foreign
 call starts to pay (``unlocked.UNLOCKED_MIN``). No chip, no jax: a host number, and quoted
 as one (PERF.md section 6, PR 32).
 
+``--side-by-side`` is the worker stage whole: ``--messages`` sealed Sum2
+messages of ``--elements`` elements at ``--bytes`` wire bytes each, all
+handed at once to ``PetMessageHandler._decrypt_parse_one`` on a ``pet-msg``
+pool of ``--workers`` threads, in both orders: ``beside`` (the shipped
+route: a long message's signature pass on a ``pet-verify`` thread while the
+worker parses) and ``serial`` (verify, then parse, on the worker: the order
+until PR 41, made here by running the pass where it is submitted). Printed a
+case: the wall until the last message is parsed, and the mean of each stage
+from ``xaynet_message_pipeline_seconds`` (PERF.md section 6, PR 41).
+
 Run:  python tools/bench_open_verify.py [--sizes 178899224,255570320]
           [--at-once 1,4,8] [--routes wheel,libcrypto,libsodium] [--repeat 3]
       python tools/bench_open_verify.py --sweep
+      python tools/bench_open_verify.py --side-by-side [--messages 8]
+          [--workers 4,8,12] [--bytes 7,10] [--elements 25557032] [--repeat 3]
 """
 
 from __future__ import annotations
@@ -170,6 +182,83 @@ def _case(route: str, op: str, size: int, at_once: int, repeat: int, calls_each:
     }
 
 
+class _WhereSubmitted:
+    """Stands in for the ``pet-verify`` executor: the pass runs on the
+    thread that submits it, before the parse: the serial order."""
+
+    def submit(self, fn, *args):
+        from concurrent.futures import Future
+
+        done: Future = Future()
+        try:
+            done.set_result(fn(*args))
+        except Exception as err:  # the caller reads it from the future, as from a thread's
+            done.set_exception(err)
+        return done
+
+    def shutdown(self, wait: bool = True) -> None:
+        pass
+
+
+def _sealed_sum2(elements: int, bpn: int):
+    """(the coordinator's keys, one sealed Sum2 message of that size)."""
+    from bench_compose import _message  # tools/ is this script's directory
+
+    from xaynet_tpu.core.crypto.encrypt import EncryptKeyPair
+    from xaynet_tpu.utils import native
+
+    native.load()  # built on first use: not a message's cost
+    message, signer = _message(elements, bpn)
+    keys = EncryptKeyPair.derive_from_seed(bytes(range(32, 64)))
+    message.coordinator_pk = keys.public.as_bytes()
+    return keys, keys.public.encrypt(message.to_bytes(signer.secret))
+
+
+def _side_by_side(keys, box: bytes, messages: int, n_workers: int, order: str,
+                  repeat: int) -> dict:
+    from concurrent.futures import wait
+
+    from xaynet_tpu.server import stages
+    from xaynet_tpu.server.events import PhaseName
+    from xaynet_tpu.server.services import MessageWorkers, PetMessageHandler
+
+    workers = MessageWorkers(n_workers)
+    if order == "serial":
+        workers.verdicts = _WhereSubmitted()
+    handler = PetMessageHandler(events=None, request_tx=None, workers=workers)
+    labels = ("open", "verify", "parse", "verify_beside")
+
+    def seconds() -> dict:
+        return {key[0]: (child.sum, child.count)
+                for key, child in stages.SECONDS.children() if key[1] == "sum2"}
+
+    walls, means = [], {label: [] for label in labels}
+    try:
+        for _ in range(repeat):
+            boxes = [bytearray(box) for _ in range(messages)]  # opened in place: a copy each
+            before, t0 = seconds(), time.perf_counter()
+            parsed = [workers.pool.submit(handler._decrypt_parse_one, b, keys, PhaseName.SUM2)
+                      for b in boxes]
+            wait(parsed)
+            walls.append(time.perf_counter() - t0)
+            for future in parsed:
+                future.result()  # a drop would be the tool's fault: raise it
+            after = seconds()
+            for label in labels:
+                s1, n1 = after.get(label, (0.0, 0))
+                s0, n0 = before.get(label, (0.0, 0))
+                if n1 > n0:
+                    means[label].append((s1 - s0) / (n1 - n0))
+            del parsed, boxes
+    finally:
+        workers.close()
+    row = {"body_bytes": len(box), "messages": messages,
+           "workers": n_workers, "order": order, "wall_s": min(walls),
+           "wall_median_s": sorted(walls)[len(walls) // 2]}
+    row.update({f"{label}_ms": 1e3 * sum(v) / len(v) for label, v in means.items() if v})
+    return row
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--sizes", default="178899224,255570320")
@@ -178,8 +267,32 @@ def main() -> None:
     ap.add_argument("--repeat", type=int, default=3)
     ap.add_argument("--sweep", action="store_true",
                     help="one call beside a spinning main thread, 4 KiB to 8 MiB")
+    ap.add_argument("--side-by-side", action="store_true",
+                    help="whole messages through _decrypt_parse_one on a pool, both orders")
+    ap.add_argument("--messages", type=int, default=8)
+    ap.add_argument("--workers", default="4,8,12")
+    ap.add_argument("--bytes", default="7,10", help="wire bytes an element (--side-by-side)")
+    ap.add_argument("--elements", type=int, default=25_557_032)
     ap.add_argument("--json", default=None, help="also write the rows here")
     args = ap.parse_args()
+    if args.side_by_side:
+        print(f"host CPUs this process may run on: {len(os.sched_getaffinity(0))}; "
+              f"libcrypto loads: {unlocked.load() is not None}", flush=True)
+        rows = []
+        for bpn in (int(b) for b in args.bytes.split(",")):
+            keys, box = _sealed_sum2(args.elements, bpn)
+            for n_workers in (int(w) for w in args.workers.split(",")):
+                for order in ("serial", "beside"):
+                    row = _side_by_side(keys, box, args.messages, n_workers, order, args.repeat)
+                    rows.append(row)
+                    print(f"{row['body_bytes']:>10} B x{row['messages']}  W={n_workers:<2} "
+                          f"{order:<6} wall {row['wall_s'] * 1e3:8.1f} ms "
+                          f"(median {row['wall_median_s'] * 1e3:8.1f})  "
+                          + "  ".join(f"{label} {row[label + '_ms']:7.1f}"
+                                      for label in ("open", "verify", "parse", "verify_beside")
+                                      if label + "_ms" in row), flush=True)
+        _write_json(args.json, rows)
+        return
     routes = [r for r in args.routes.split(",") if ROUTES[r]() is not None]
     print(f"host CPUs this process may run on: {len(os.sched_getaffinity(0))}; "
           f"routes that load: {', '.join(routes)}", flush=True)
@@ -201,9 +314,13 @@ def main() -> None:
               f"{row['call_s'] * 1e3:9.3f} ms (min {row['call_min_s'] * 1e3:9.3f})  "
               f"wall {row['wall_s'] * 1e3:9.3f} ms  main stall {row['main_stall_s'] * 1e3:9.3f} ms",
               flush=True)
-    if args.json:
-        os.makedirs(os.path.dirname(os.path.abspath(args.json)), exist_ok=True)
-        with open(args.json, "w") as fh:
+    _write_json(args.json, rows)
+
+
+def _write_json(path, rows: list) -> None:
+    if path:
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+        with open(path, "w") as fh:
             json.dump(rows, fh, indent=1)
 
 

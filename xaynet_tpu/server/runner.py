@@ -118,6 +118,19 @@ def init_logging(settings: Settings) -> None:
             handler.addFilter(tracing.RequestIdFilter())
 
 
+def _health_sections(handler: PetMessageHandler, device_report):
+    """The runner's own sections of ``/healthz``: the size of the process's
+    ``pet-msg`` pool, and the device's report where one aggregates."""
+
+    def report() -> dict:
+        out = {"message_workers": handler.workers.size}
+        if device_report is not None:
+            out.update(device_report())
+        return out
+
+    return report
+
+
 def _mark_serving(startup) -> None:
     """The API accepts requests: the timeline's last mark, and from the same
     marks the restart-to-serving wall (docs/DESIGN.md §9): entry of
@@ -216,7 +229,7 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
         registry=metrics.registry,
         pipeline=pipeline,
         edge_api=edge_api,
-        health_extra=device_report,
+        health_extra=_health_sections(handler, device_report),
     )
     host, _, port = settings.api.bind_address.partition(":")
     tls = None
@@ -374,7 +387,7 @@ async def _build_tenant_context(settings: Settings, tenant: str, budget, registr
         handler=handler,
         pipeline=pipeline,
         edge_api=edge_api,
-        health_extra=device_report,
+        health_extra=_health_sections(handler, device_report),
     )
     logger.info(
         "tenant %s: model_len=%d group=%s (round pipeline up)",

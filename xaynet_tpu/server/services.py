@@ -6,9 +6,12 @@ rust/xaynet-server/src/services/messages/mod.rs:30-118):
     Decryptor -> MessageParser (phase filter + signature verification)
     -> MultipartHandler (chunk reassembly) -> TaskValidator -> StateMachine
 
-CPU-heavy stages (sealed-box open, Ed25519 verify) run on a thread pool so
-the asyncio loop stays responsive — the analogue of the reference's rayon
-offload with a concurrency limit.
+CPU-heavy stages (sealed-box open, Ed25519 verify, parse) run on a thread
+pool so the asyncio loop stays responsive — the analogue of the reference's
+rayon offload with a concurrency limit. The pool is the process's, sized
+from the cores the process may run on (:class:`MessageWorkers`), and a large
+message's signature is checked beside its parse (docs/DESIGN.md §16 "The
+message workers").
 
 ``Fetcher`` exposes the latest event-bus values to the API layer
 (reference: rust/xaynet-server/src/services/fetchers/mod.rs:27-42).
@@ -17,11 +20,14 @@ offload with a concurrency limit.
 from __future__ import annotations
 
 import asyncio
+import os
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 from ..core.common import RoundParameters
+from ..core.crypto import unlocked
 from ..core.crypto.encrypt import DecryptError, EncryptKeyPair
 from ..core.crypto.sign import is_eligible, verify_detached
 from ..core.mask.serialization import DecodeError
@@ -46,6 +52,77 @@ _MULTIPART_BUFFERS = get_registry().gauge(
 )
 
 
+_MESSAGE_WORKERS = get_registry().gauge(
+    "xaynet_message_workers",
+    "Threads of the process's pet-msg pool: the size the rule chose from the "
+    "cores the process may run on (server/services.py::worker_count).",
+)
+_VERIFY_BYTES = get_registry().counter(
+    "xaynet_verify_bytes_total",
+    "Signed bytes of messages by where their signature was checked: beside = "
+    "on a pet-verify thread while the pet-msg worker parsed (a plaintext of "
+    "unlocked.UNLOCKED_MIN bytes or more), inline = on the worker, before the "
+    "parse.",
+    ("route",),
+)
+
+
+def worker_count(cores: int) -> int:
+    """Threads of the ``pet-msg`` pool on a host of ``cores`` cores: half of
+    them rounded up, and one more, never under four. A worker that checks a
+    large message's signature beside its parse keeps a second core busy for
+    the length of the signature pass, so half the cores in workers is the
+    host in use; the other threads that touch a body (``rest-body`` readers,
+    the ``xn-ingest`` pool, the loop) wait on sockets or on these workers
+    most of their time. 13 cores: 8, 30 cores: 16 (docs/DESIGN.md §16)."""
+    return max(4, (cores + 1) // 2 + 1)
+
+
+def available_cores() -> int:
+    """The cores this process may run on (its affinity mask where the
+    platform has one: a container's share, not the machine's count)."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity on this platform
+        return os.cpu_count() or 1
+
+
+class MessageWorkers:
+    """The threads that open, verify and parse messages: the ``pet-msg`` pool
+    and as many ``pet-verify`` threads, which make a large message's
+    signature pass beside its parse. A ``pet-verify`` thread takes nothing
+    from the ``pet-msg`` pool and waits for nothing, and a worker has at most
+    one pass outstanding, so a pass starts at once and a pool in which every
+    worker waits for its verdict still drains. Threads start when first
+    needed. One a process (:func:`shared_workers`) unless a caller brings its
+    own."""
+
+    def __init__(self, size: Optional[int] = None):
+        self.size = size if size is not None else worker_count(available_cores())
+        self.pool = ThreadPoolExecutor(self.size, thread_name_prefix="pet-msg")
+        self.verdicts = ThreadPoolExecutor(self.size, thread_name_prefix="pet-verify")
+
+    def close(self) -> None:
+        self.pool.shutdown(wait=False)
+        self.verdicts.shutdown(wait=False)
+
+
+_shared: Optional[MessageWorkers] = None
+_shared_lock = threading.Lock()
+
+
+def shared_workers() -> MessageWorkers:
+    """The process's message workers, made on first use: every handler of
+    the process (one a tenant) runs on them, so two tenants are not two pools
+    of the host's size."""
+    global _shared
+    with _shared_lock:
+        if _shared is None:
+            _shared = MessageWorkers()
+            _MESSAGE_WORKERS.set(_shared.size)
+        return _shared
+
+
 class ServiceError(Exception):
     """A message was dropped by the pipeline (with the stage as context)."""
 
@@ -61,8 +138,8 @@ class PetMessageHandler:
         self,
         events: EventSubscriber,
         request_tx: RequestSender,
-        max_workers: int = 4,
         wire_ingest: bool = False,
+        workers: Optional[MessageWorkers] = None,
     ):
         self.events = events
         self.request_tx = request_tx
@@ -70,7 +147,7 @@ class PetMessageHandler:
         # element block kept; unpack + validity run on the accelerator in
         # validate_aggregation, before the seed-dict insert)
         self.wire_ingest = wire_ingest
-        self._pool = ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="pet-msg")
+        self.workers = workers if workers is not None else shared_workers()
         # multipart reassembly buffers keyed by (participant_pk, message_id);
         # bounded: abandoned reassemblies are evicted oldest-first so a
         # client cannot grow coordinator memory without completing messages
@@ -148,17 +225,47 @@ class PetMessageHandler:
             raise ServiceError(  # lint: taint-ok: one-byte message-type tag, not key bytes
                 "phase-filter", f"{tag.name} message during {phase.value}"
             )
-        # signature verification, then the full parse: one pass each over
-        # the body, timed apart
+        # the signature pass and the full parse read the same opened bytes
+        # and nothing of each other. A short message (Sum: 280 bytes) has
+        # them one after the other, a long one side by side: the route
+        # depends on the length alone
+        at = dict(ctx=ctx, rid=rid, phase=arrived, bytes=len(raw))
         try:
-            with stages.stage("verify", ctx=ctx, rid=rid, phase=arrived, bytes=len(raw)):
-                Message.verify_bytes(raw)
-            with stages.stage("parse", ctx=ctx, rid=rid, phase=arrived, bytes=len(raw)):
-                return Message.from_bytes(
-                    raw, verify=False, lazy_update_vect=self.wire_ingest
-                )
+            if len(raw) < unlocked.UNLOCKED_MIN:
+                _VERIFY_BYTES.labels(route="inline").inc(len(raw))
+                with stages.stage("verify", **at):
+                    Message.verify_bytes(raw)
+                return self._parse(raw, at)
+            _VERIFY_BYTES.labels(route="beside").inc(len(raw))
+            return self._parse(raw, at, beside=True)
         except DecodeError as e:
             raise ServiceError("parse", str(e)) from e
+
+    def _parse(self, raw, at: dict, beside: bool = False) -> Message:
+        """The full parse; with ``beside`` the signature pass is handed to a
+        ``pet-verify`` thread first (inside the ``parse`` bracket: a queue
+        put, and a thread's start at its first use) and awaited after."""
+        verdict = None
+        try:
+            with stages.stage("parse", **at):
+                if beside:
+                    verdict = self.workers.verdicts.submit(self._verify_beside, raw, at)
+                return Message.from_bytes(raw, verify=False, lazy_update_vect=self.wire_ingest)
+        finally:
+            # nothing of the parse, an error included, leaves before the
+            # verdict is in: a bad signature raises here, over whatever the
+            # parse returned or raised. `verify` is what the chain waits for
+            # the verdict once the parse has returned
+            if verdict is not None:
+                with stages.stage("verify", **at):
+                    verdict.result()
+
+    @staticmethod
+    def _verify_beside(raw, at: dict) -> None:
+        """The whole signature pass of a long message, on a ``pet-verify``
+        thread, beside the chain as ``to_planar`` is."""
+        with stages.stage("verify_beside", **at):
+            Message.verify_bytes(raw)
 
     async def _parse_message(self, encrypted: bytes) -> Optional[Message]:
         loop = asyncio.get_running_loop()
@@ -172,7 +279,7 @@ class PetMessageHandler:
             message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid, arrived)
             return message, time.monotonic()
 
-        message, returned = await loop.run_in_executor(self._pool, on_worker)
+        message, returned = await loop.run_in_executor(self.workers.pool, on_worker)
         # the worker is done; this coroutine waited for the loop since then
         stages.waited("resume_wait", returned)
         if message.is_multipart:
@@ -208,7 +315,7 @@ class PetMessageHandler:
             return out
 
         with stages.seconds("decrypt_parse_batch", phase.value).time():
-            results = await loop.run_in_executor(self._pool, run)
+            results = await loop.run_in_executor(self.workers.pool, run)
         final = []
         for res in results:
             if isinstance(res, ServiceError) or res is None or not res.is_multipart:

@@ -33,6 +33,13 @@ What a phase does with the message is a stage of the chain too:
 ``validate``, ``seed_dict``, ``stage`` and ``flush`` are the Update
 phase's, ``score`` the Sum2 phase's.
 
+Two stages run beside the chain and are no part of its sum: ``to_planar``
+(the slot write, on the ``xn-ingest`` pool) and ``verify_beside`` (a long
+message's whole signature pass, on a ``pet-verify`` thread while the worker
+parses). For such a message ``verify`` is what the chain waits for the
+verdict once the parse has returned; for a short one, whose signature is
+checked on the worker before the parse, it is the pass itself.
+
 The labels ``total`` (a message's whole handling after its body is read)
 and ``decrypt_parse`` / ``decrypt_parse_batch`` (the pool hop, wait
 included) keep their older meaning; no stage label here starts with
@@ -56,8 +63,8 @@ SECONDS = get_registry().histogram(
     "xaynet_message_pipeline_seconds",
     "Wall time of one stage of a message's handling, by stage: read_body, "
     "pool_wait, open, verify, parse, resume_wait, request_wait, validate, "
-    "seed_dict, stage, to_planar, flush, score, verdict_wait "
-    "(server/stages.py); decrypt_parse[_batch] = the pool hop (pool_wait to "
+    "seed_dict, stage, flush, score, verdict_wait; beside the chain to_planar, "
+    "verify_beside (server/stages.py); decrypt_parse[_batch] = the pool hop (pool_wait to "
     "resume_wait); total = body read to the state machine's verdict. phase = "
     "the phase the message's coordinator was in when the message arrived.",
     ("stage", "phase"),
@@ -72,6 +79,7 @@ _SPANS: dict[str, str] = {
     "pool_wait": trace.declare_span("pipeline.pool_wait"),
     "open": trace.declare_span("pipeline.open", mirror=True),
     "verify": trace.declare_span("pipeline.verify", mirror=True),
+    "verify_beside": trace.declare_span("pipeline.verify_beside", mirror=True),
     "parse": trace.declare_span("pipeline.parse", mirror=True),
     "resume_wait": trace.declare_span("pipeline.resume_wait"),
     "request_wait": trace.declare_span("update.request_wait"),
