@@ -39,6 +39,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from ..telemetry import tracing as trace
+from ..telemetry.intake import BodyIntake
 from ..telemetry.registry import MetricsRegistry, get_registry
 from ..telemetry.startup import get_timeline
 from ..utils import native, tracing
@@ -232,6 +233,18 @@ class RestServer:
             "bodies, TLS, no free reader).",
             ("route",),
         )
+        self._loop_cpu = self.registry.counter(
+            "xaynet_event_loop_cpu_seconds_total",
+            "CPU seconds of the thread that runs the API's event loop "
+            "(time.thread_time(), read on that thread every 100 ms).",
+        )
+        self._loop_wall = self.registry.counter(
+            "xaynet_event_loop_wall_seconds_total",
+            "Wall seconds over which xaynet_event_loop_cpu_seconds_total was "
+            "read: the two grow together, so their ratio over any span is the "
+            "loop thread's CPU share.",
+        )
+        self._intake = BodyIntake(self.registry)
         self._sum2_first_arrival = self.registry.histogram(
             "xaynet_sum2_first_arrival_seconds",
             "Sum2 phase announced -> the headers of the first message POSTed "
@@ -240,8 +253,8 @@ class RestServer:
             "the phase is that message's path through the coordinator.",
             buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0, 80.0, 160.0),
         )
-        # the Sum2 phase event whose first message was seen, a tenant
-        self._sum2_seen: dict[str, object] = {}
+        # the phase event whose first message was seen, a tenant
+        self._phase_seen: dict[str, object] = {}
         self._lag_task: Optional[asyncio.Task] = None
         self._server: Optional[asyncio.AbstractServer] = None
         # the bounded rest-body pool (made when a body first needs it) and
@@ -286,12 +299,18 @@ class RestServer:
     async def _watch_loop_lag(self, period: float = 0.1) -> None:
         """Observe, every ``period`` seconds, how late this loop ran a task
         that was due: what tells "many bodies on one loop" from "the loop is
-        idle and the workers are the queue"."""
+        idle and the workers are the queue"; and read this thread's CPU
+        clock beside the wall clock: how much of the loop is in use."""
         loop = asyncio.get_running_loop()
+        cpu, wall = time.thread_time(), loop.time()
         while True:
             due = loop.time() + period
             await asyncio.sleep(period)
             self._loop_lag.observe(max(0.0, loop.time() - due))
+            cpu_now, wall_now = time.thread_time(), loop.time()
+            self._loop_cpu.inc(cpu_now - cpu)
+            self._loop_wall.inc(wall_now - wall)
+            cpu, wall = cpu_now, wall_now
 
     # --- request handling -------------------------------------------------
 
@@ -350,10 +369,11 @@ class RestServer:
         StreamReader on the loop. Never reads past the body."""
         if not length:
             return b""
-        sock = self._direct_socket(reader, writer, length)
+        sock, reason = self._direct_socket(reader, writer, length)
         if sock is None:
             body = await asyncio.wait_for(reader.readexactly(length), self.read_timeout)
             self._body_bytes.labels(route="stream").inc(length)
+            self._intake.read("stream", reason)
             return body
         deadline = time.monotonic() + self.read_timeout
         transport = writer.transport
@@ -384,27 +404,31 @@ class RestServer:
             raise asyncio.IncompleteReadError(b"", length)
         transport.resume_reading()
         self._body_bytes.labels(route="direct").inc(length)
+        self._intake.read("direct", reason)
         return body
 
     def _direct_socket(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int
-    ) -> Optional[socket.socket]:
+    ) -> tuple[Optional[socket.socket], str]:
         """A duplicate of the connection's descriptor if this body is to be
-        read directly, else ``None``. A duplicate, so that a transport closed
+        read directly, else ``None``, and the reason either way (``large``,
+        ``small``, or one of ``intake.TURNED_AWAY``). A duplicate, so that a transport closed
         in mid-body (``stop()``) cannot hand the reader thread a recycled
         descriptor; it shares the transport's non-blocking mode."""
-        if length < DIRECT_BODY_MIN or len(self._direct_reads) >= BODY_READERS:
-            return None
+        if length < DIRECT_BODY_MIN:
+            return None, "small"
         if writer.get_extra_info("ssl_object") is not None:
-            return None
+            return None, "tls"
+        if len(self._direct_reads) >= BODY_READERS:
+            return None, "no_reader"
         trsock = writer.get_extra_info("socket")
         buffer = getattr(reader, "_buffer", None)
         if trsock is None or not isinstance(buffer, bytearray) or len(buffer) >= length:
-            return None
+            return None, "no_socket"
         try:
-            return socket.socket(fileno=os.dup(trsock.fileno()))
+            return socket.socket(fileno=os.dup(trsock.fileno())), "large"
         except OSError:
-            return None  # the transport is already closed: the stream path says how
+            return None, "no_socket"  # the transport is already closed: the stream path says how
 
     def _resolve_tenant(self, path: str):
         """Split a ``/t/<tenant>/<sub>`` target into (tenant id, sub path,
@@ -485,7 +509,7 @@ class RestServer:
                 ).inc()
                 return 429, b"tenant not accepting traffic", "text/plain", extra
         if method == "POST" and path == "/message":
-            self._note_sum2_arrival(tenant, routes)
+            self._note_phase_arrival(tenant, routes)
         # handlers return (status, payload, ctype) or + an extra-headers dict
         if path in _UNTRACED_PATHS:
             result = await self._dispatch(method, path, url.query, body, headers, routes)
@@ -508,12 +532,22 @@ class RestServer:
             ), trace.get_tracer().span(
                 SPAN_REQUEST, link=remote, method=method, path=path, tenant=tenant, **arrived
             ) as span:
-                if read_body is not None:
-                    with stages.stage(
-                        "read_body", bytes=int(headers.get("content-length", "0"))
-                    ):
-                        body = await read_body()
-                result = await self._dispatch(method, path, url.query, body, headers, routes)
+                # a message's body is counted sealed from its first byte
+                # until a worker has opened it (or it is dropped unopened)
+                held = self._intake.hold() if read_body is not None else None
+                try:
+                    if read_body is not None:
+                        with stages.stage(
+                            "read_body", bytes=int(headers.get("content-length", "0"))
+                        ):
+                            body = await read_body()
+                    with stages.use_held(held):
+                        result = await self._dispatch(
+                            method, path, url.query, body, headers, routes
+                        )
+                finally:
+                    if held is not None:
+                        held.release()
                 span.set(status=result[0])
         status, payload, ctype = result[:3]
         extra = result[3] if len(result) > 3 else None
@@ -528,15 +562,31 @@ class RestServer:
         ).inc()
         return status, payload, ctype, extra
 
-    def _note_sum2_arrival(self, tenant: str, routes: TenantRoutes) -> None:
-        """One observation a round: a message's headers are parsed, the phase
-        is Sum2, and no message of this Sum2 came before."""
+    def _note_phase_arrival(self, tenant: str, routes: TenantRoutes) -> None:
+        """A message's headers are parsed and no message of this phase came
+        before. In Update: the high-water mark of resident bodies starts
+        again. In Sum2, once a round: one observation of how long the sum
+        participant took, and one log line of how the Update phase's bodies
+        were read (the counters are the process's: with several tenants, all
+        bodies since the line before)."""
         if routes.fetcher is None:  # a server that only takes messages (tools, tests)
             return
         entered = routes.fetcher.events.phase.get_latest()
-        if entered.event is PhaseName.SUM2 and self._sum2_seen.get(tenant) is not entered:
-            self._sum2_seen[tenant] = entered
+        if self._phase_seen.get(tenant) is entered:
+            return
+        self._phase_seen[tenant] = entered
+        if entered.event is PhaseName.UPDATE:
+            self._intake.new_window()
+        elif entered.event is PhaseName.SUM2:
             self._sum2_first_arrival.observe(time.monotonic() - entered.at)
+            direct, turned, high = self._intake.since_last()
+            if direct or turned:
+                logger.info(
+                    "large bodies since the last Sum2: %d read by rest-body threads, %d through "
+                    "the StreamReader (%s); at most %d message bodies held sealed at once",
+                    direct, sum(turned.values()),
+                    ", ".join(f"{reason} {n}" for reason, n in turned.items()) or "none", high,
+                )
 
     async def _dispatch(self, method: str, path: str, query: str, body: bytes,
                         headers, routes: TenantRoutes):

@@ -185,6 +185,7 @@ class PetMessageHandler:
         ctx: Optional[trace.TraceContext] = None,
         rid: str = "-",
         arrived: Optional[str] = None,
+        held=None,
     ) -> Message:
         """Sealed-box open + phase filter + signature verify + parse.
 
@@ -193,7 +194,8 @@ class PetMessageHandler:
         over what does not cross the hop: the parent span's ``ctx``, the
         request id and the phase the message arrived in (a batch, whose
         members' arrivals the intake does not keep, is labelled by the
-        phase its filter runs against).
+        phase its filter runs against), and the REST layer's count of the
+        body as sealed (``held``), which ends when the box is open.
         """
         arrived = arrived or phase.value
         # sealed-box open (CPU) — reference: decryptor.rs:48-69. Passing our
@@ -211,6 +213,8 @@ class PetMessageHandler:
                     raw = keys.secret.decrypt(encrypted, keys.public)
             except (DecryptError, ValueError) as e:
                 raise ServiceError("decrypt", str(e)) from e
+        if held is not None:
+            held.release()
         # phase filter before the expensive signature check
         # (reference: message_parser.rs:88-141)
         try:
@@ -272,11 +276,11 @@ class PetMessageHandler:
         keys: EncryptKeyPair = self.events.keys.get_latest().event
         phase: PhaseName = self.events.phase.get_latest().event
         ctx, rid, submitted = trace.current_ctx(), tracing.current_request_id(), time.monotonic()
-        arrived = stages.current_phase()
+        arrived, held = stages.current_phase(), stages.current_held()
 
         def on_worker() -> tuple[Message, float]:
             stages.waited("pool_wait", submitted, ctx=ctx, rid=rid, phase=arrived)
-            message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid, arrived)
+            message = self._decrypt_parse_one(encrypted, keys, phase, ctx, rid, arrived, held)
             return message, time.monotonic()
 
         message, returned = await loop.run_in_executor(self.workers.pool, on_worker)

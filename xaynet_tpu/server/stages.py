@@ -103,14 +103,33 @@ def current_phase() -> str:
 
 
 @contextmanager
-def use_phase(phase: str):
-    """Name the arrival phase of the message handled inside (where it
-    arrives) or re-enter it on the far side of a queue or a thread hop."""
-    token = _phase.set(phase)
+def _carried(var: contextvars.ContextVar, value):
+    token = var.set(value)
     try:
         yield
     finally:
-        _phase.reset(token)
+        var.reset(token)
+
+
+def use_phase(phase: str):
+    """Name the arrival phase of the message handled inside (where it
+    arrives) or re-enter it on the far side of a queue or a thread hop."""
+    return _carried(_phase, phase)
+
+
+_held: contextvars.ContextVar = contextvars.ContextVar("xaynet_message_held", default=None)
+
+
+def current_held():
+    """The resident-body count's hold on the message being handled
+    (``telemetry/intake.py``), for the worker that opens it to release;
+    ``None`` where the REST layer counted nothing."""
+    return _held.get()
+
+
+def use_held(held):
+    """Carry ``held`` with the message handled inside, as its phase is."""
+    return _carried(_held, held)
 
 
 def seconds(label: str, phase: Optional[str] = None):

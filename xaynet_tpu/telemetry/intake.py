@@ -1,0 +1,115 @@
+"""What a high fan-in asks of the REST intake (docs/DESIGN.md §16 "The
+intake under a fan-in").
+
+With more connections than ``rest-body`` readers a round's large bodies are
+read on two carriers at once, every connection holds a body while its
+message waits for a worker, and the API's loop, which runs the serial part
+of every message, becomes the bound on intake. Three things are counted,
+each where it happens:
+
+- ``xaynet_rest_body_reads_total{route, reason}``: one a request body read
+  in full, by the carrier that read it and why it was that one.
+  ``route="direct"`` has the one reason ``large``; ``route="stream"`` says
+  ``small`` (under ``rest.DIRECT_BODY_MIN``), ``tls``, ``no_reader`` (a
+  large body on a plain connection that found every reader busy) or
+  ``no_socket`` (the transport gave no descriptor to read from).
+- ``xaynet_rest_bodies_resident`` and ``..._resident_max``: POSTed message
+  bodies held sealed (being read, or read and waiting for a ``pet-msg``
+  worker to open them), now and at the most since the Update phase's first
+  message: sealed bytes in memory are this count times the body size.
+- ``xaynet_event_loop_cpu_seconds_total`` beside
+  ``xaynet_event_loop_wall_seconds_total`` (``server/rest.py``'s lag
+  watcher): the loop thread's own CPU time (``time.thread_time()`` read on
+  that thread) over the wall time it was watched. CPU seconds a message is
+  what the loop can carry whatever the workers do.
+"""
+
+from __future__ import annotations
+
+import threading
+
+from .registry import MetricsRegistry
+
+# why a large body went through the StreamReader, in the order a round's log names them
+TURNED_AWAY = ("no_reader", "tls", "no_socket")
+
+
+class Held:
+    """One message body counted resident until :meth:`release` (any thread,
+    any number of times)."""
+
+    __slots__ = ("_bodies", "_held")
+
+    def __init__(self, bodies: "BodyIntake"):
+        self._bodies, self._held = bodies, True
+
+    def release(self) -> None:
+        if self._held:
+            self._held = False
+            self._bodies._leave()
+
+
+class BodyIntake:
+    """The REST server's body counters: made by ``RestServer`` against the
+    registry it renders."""
+
+    def __init__(self, registry: MetricsRegistry):
+        self._reads = registry.counter(
+            "xaynet_rest_body_reads_total",
+            "Request bodies read in full, by route and why: direct/large = a "
+            "rest-body thread; stream/small, stream/tls, stream/no_reader (a "
+            "large plain-TCP body that found all readers busy), "
+            "stream/no_socket = the event loop's StreamReader "
+            "(telemetry/intake.py).",
+            ("route", "reason"),
+        )
+        self._resident = registry.gauge(
+            "xaynet_rest_bodies_resident",
+            "POSTed message bodies held sealed: being read, or read and not "
+            "yet opened by a pet-msg worker.",
+        )
+        self._resident_max = registry.gauge(
+            "xaynet_rest_bodies_resident_max",
+            "The most message bodies held sealed at one instant since the "
+            "first message of the latest Update phase arrived.",
+        )
+        self._lock = threading.Lock()
+        self._n = 0
+        self._high = 0
+        self._logged: dict[str, float] = {}
+
+    def read(self, route: str, reason: str) -> None:
+        self._reads.labels(route=route, reason=reason).inc()
+
+    def hold(self) -> Held:
+        with self._lock:
+            self._n += 1
+            self._resident.set(self._n)
+            if self._n > self._high:
+                self._high = self._n
+                self._resident_max.set(self._high)
+        return Held(self)
+
+    def _leave(self) -> None:
+        with self._lock:
+            self._n -= 1
+            self._resident.set(self._n)
+
+    def new_window(self) -> None:
+        """An Update phase's first message is here: the high-water mark
+        starts again from what is held now."""
+        with self._lock:
+            self._high = self._n
+            self._resident_max.set(self._high)
+
+    def since_last(self) -> tuple[int, dict[str, int], int]:
+        """(large bodies read directly, {reason: large bodies through the
+        StreamReader, where any}, the high-water mark) since the previous
+        call: what a round's log line says."""
+        now = {"large": self._reads.labels(route="direct", reason="large").value}
+        for reason in TURNED_AWAY:
+            now[reason] = self._reads.labels(route="stream", reason=reason).value
+        grown = {k: int(v - self._logged.get(k, 0.0)) for k, v in now.items()}
+        self._logged = now
+        direct = grown.pop("large")
+        return direct, {reason: n for reason, n in grown.items() if n}, self._high
