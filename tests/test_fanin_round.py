@@ -22,7 +22,6 @@ import asyncio
 import logging
 import sys
 import threading
-import time
 from fractions import Fraction
 
 import jax
@@ -154,6 +153,8 @@ def _intake_counters() -> dict:
     out = {reason: _sample("xaynet_rest_body_reads_total", {"route": "stream", "reason": reason})
            for reason in ("small", "tls", "no_reader", "no_socket")}
     out["large"] = _sample("xaynet_rest_body_reads_total", {"route": "direct", "reason": "large"})
+    out["overflow"] = _sample("xaynet_rest_body_reads_total",
+                              {"route": "overflow", "reason": "no_reader"})
     out["accepted"] = _sample("xaynet_messages_total", {"phase": "update", "outcome": "accepted"})
     return out
 
@@ -273,7 +274,7 @@ def test_served_round_of_two_shipped_batches_equals_the_plain_reference(length, 
     # bodies of 6 x length bytes are small: all through the StreamReader, by name
     assert 6 * length < DIRECT_BODY_MIN
     assert out["update"]["small"] >= n_update
-    assert out["update"]["large"] == out["update"]["no_reader"] == 0
+    assert out["update"]["large"] == out["update"]["overflow"] == out["update"]["no_reader"] == 0
     # every connection held a sealed body at some instant, none is held now
     assert 1 <= out["resident_max"] <= 64 and out["resident"] == 0
     # (d) both counters only rise, together, and the share is a share
@@ -286,20 +287,29 @@ def test_served_round_of_two_shipped_batches_equals_the_plain_reference(length, 
 def test_a_round_of_large_bodies_is_read_by_both_carriers_and_each_update_counts_once(
         one_device, monkeypatch, caplog):
     """(b): more connections than ``BODY_READERS``, every body over
-    ``DIRECT_BODY_MIN``, all sent at one instant: sixteen find a reader, the
-    rest go through the StreamReader as ``no_reader``, and every one of them
-    is in the aggregate once."""
+    ``DIRECT_BODY_MIN``, all sent at one instant: sixteen find a ``rest-body``
+    reader, the rest are received by the ``rest-overflow`` thread as
+    ``no_reader``, none through the StreamReader, and every one of them is in
+    the aggregate once."""
     length, batch, connections = 180_001, 8, BODY_READERS + 8
     assert 6 * length > DIRECT_BODY_MIN
-    # a reader keeps its body a moment, as a peer on a real link would: the
-    # first sixteen are still busy when the other eight ask
-    recv = rest_mod._recv_exactly
+    # a reader keeps its body, as a peer on a real link would, until the other
+    # eight have asked for one: no clock decides who finds the readers busy
+    recv, receive = rest_mod._recv_exactly, rest_mod._OverflowReader.receive
+    asked, all_asked = [], threading.Event()
 
-    def slow_peer(*args):
-        time.sleep(0.3)
+    def held_reader(*args):
+        all_asked.wait(120)
         return recv(*args)
 
-    monkeypatch.setattr(rest_mod, "_recv_exactly", slow_peer)
+    def counted(self, *args):
+        asked.append(args)
+        if len(asked) == connections - BODY_READERS:
+            all_asked.set()
+        return receive(self, *args)
+
+    monkeypatch.setattr(rest_mod, "_recv_exactly", held_reader)
+    monkeypatch.setattr(rest_mod._OverflowReader, "receive", counted)
     caplog.set_level(logging.INFO, logger="xaynet.rest")
     fixed = [weights_fixed(100 + i, length) for i in range(connections)]
     out = asyncio.run(asyncio.wait_for(
@@ -310,16 +320,18 @@ def test_a_round_of_large_bodies_is_read_by_both_carriers_and_each_update_counts
     assert np.array_equal(out["model"].view(np.uint64), want.view(np.uint64))
     update = out["update"]
     assert update["accepted"] == connections and out["seed_dict"] == [connections]
-    assert (update["large"], update["no_reader"]) == (BODY_READERS, connections - BODY_READERS)
-    assert update["tls"] == update["no_socket"] == 0
-    # what rest.reader_full_share reads: no_reader over large + no_reader
-    assert 100.0 * update["no_reader"] / (update["large"] + update["no_reader"]) \
+    assert (update["large"], update["overflow"]) == (BODY_READERS, connections - BODY_READERS)
+    assert update["no_reader"] == update["tls"] == update["no_socket"] == 0
+    # what rest.reader_full_share reads: no_reader over large + no_reader, on any
+    # route; what rest.large_stream_share reads: of those, the StreamReader's
+    assert 100.0 * update["overflow"] / (update["large"] + update["overflow"]) \
         == pytest.approx(100.0 * 8 / 24)
     assert out["resident_max"] == connections and out["resident"] == 0
+    assert _sample("xaynet_rest_overflow_bodies") == 0
     # and the round's log line tells the operator, once
     told = [r.getMessage() for r in caplog.records if "large bodies" in r.getMessage()]
-    assert len(told) == 1 and "16 read by rest-body threads, 8 through the StreamReader " \
-        "(no_reader 8); at most 24 message bodies held sealed at once" in told[0]
+    assert len(told) == 1 and "16 read by rest-body threads, 8 by the rest-overflow thread, " \
+        "0 through the StreamReader (none); at most 24 message bodies held sealed at once" in told[0]
 
 
 # --- the fold at K = 64 under the prime order -----------------------------------

@@ -1,9 +1,11 @@
 """The REST server's body read (docs/DESIGN.md §16): a large body on plain
-TCP is received by a ``rest-body`` thread straight into one buffer of its
-``Content-Length``; everything else goes through the StreamReader. Whichever
-carries it, ``_dispatch`` gets the bytes that were sent and nothing of the
-next request, a slow or vanished peer is dropped unanswered within
-``read_timeout``, and ``stop()`` does not wait for a body.
+TCP is received straight into one buffer of its ``Content-Length``, by a
+``rest-body`` thread while one is free and by the one event-driven
+``rest-overflow`` thread, beside any number of others, while none is;
+everything else goes through the StreamReader. Whichever carries it,
+``_dispatch`` gets the bytes that were sent and nothing of the next request,
+a slow or vanished peer is dropped unanswered within ``read_timeout``, and
+``stop()`` does not wait for a body.
 
 Each test starts its own server on an ephemeral port with its own registry
 and talks to it over a raw connection, so that what one TCP segment carries
@@ -14,9 +16,12 @@ from __future__ import annotations
 
 import asyncio
 import hashlib
+import json
+import os
 import ssl
 import threading
 import time
+from types import SimpleNamespace
 
 import pytest
 
@@ -35,6 +40,15 @@ class _Capture:
 
     async def handle_message(self, body) -> None:
         self.bodies.append((type(body), len(body), hashlib.sha256(body).hexdigest()))
+
+
+class _Fetcher:
+    """What ``/healthz`` asks of a ``Fetcher``."""
+
+    events = SimpleNamespace(params=SimpleNamespace(get_latest=lambda: SimpleNamespace(round_id=1)))
+
+    def phase(self):
+        return SimpleNamespace(value="update")
 
 
 class _Shedding:
@@ -107,8 +121,13 @@ class _Served:
         return [(n, digest) for _, n, digest in self.handler.bodies]
 
 
-def _reader_threads() -> list[threading.Thread]:
-    return [t for t in threading.enumerate() if t.name.startswith("rest-body")]
+def _reader_threads(prefix: str = "rest-") -> list[threading.Thread]:
+    """The ``rest-body`` and ``rest-overflow`` threads alive now."""
+    return [t for t in threading.enumerate() if t.name.startswith(prefix)]
+
+
+def _open_descriptors() -> int:
+    return len(os.listdir("/proc/self/fd"))
 
 
 def _wait_until(predicate, seconds: float = 5.0) -> bool:
@@ -116,6 +135,41 @@ def _wait_until(predicate, seconds: float = 5.0) -> bool:
     while not predicate() and time.monotonic() < deadline:
         time.sleep(0.01)
     return predicate()
+
+
+def _reading(server: RestServer) -> int:
+    """Bodies a direct carrier is receiving at this instant."""
+    return len(server._direct_reads) + len(server._overflow_reads)
+
+
+# the carriers of a body: the two that receive a large one straight from the
+# socket, and the StreamReader
+ROUTE = {"thread": "direct", "overflow": "overflow", "stream": "stream"}
+ROUTES = sorted(ROUTE.values())
+DIRECT = ["thread", "overflow"]
+
+
+@pytest.fixture(params=DIRECT)
+def direct(request, monkeypatch) -> str:
+    """A large body's carrier: a ``rest-body`` thread, or, with no reader to
+    be had, the ``rest-overflow`` thread. Returns its ``route`` label."""
+    return _carried_by(request.param, monkeypatch)
+
+
+@pytest.fixture(params=["stream", *DIRECT])
+def carrier(request, monkeypatch) -> str:
+    """Any of the three carriers; a test sizes its body with ``_size_for``."""
+    return _carried_by(request.param, monkeypatch)
+
+
+def _carried_by(carrier: str, monkeypatch) -> str:
+    if carrier == "overflow":
+        monkeypatch.setattr(rest, "BODY_READERS", 0)
+    return ROUTE[carrier]
+
+
+def _size_for(route: str) -> int:
+    return 4096 if route == "stream" else 4 * MB
 
 
 @pytest.mark.parametrize(
@@ -128,10 +182,15 @@ def _wait_until(predicate, seconds: float = 5.0) -> bool:
         (DIRECT_BODY_MIN + 1, "direct"),
         (5 * MB + 3, "direct"),
         (32 * MB, "direct"),
+        (DIRECT_BODY_MIN, "overflow"),
+        (DIRECT_BODY_MIN + 1, "overflow"),
+        (5 * MB + 3, "overflow"),
+        (32 * MB, "overflow"),
     ],
 )
-def test_dispatch_gets_the_bytes_that_were_sent(size, route):
+def test_dispatch_gets_the_bytes_that_were_sent(size, route, monkeypatch):
     body = _payload(size)
+    route = _carried_by("thread" if route == "direct" else route, monkeypatch)
 
     async def run():
         async with _Served() as s:
@@ -142,16 +201,15 @@ def test_dispatch_gets_the_bytes_that_were_sent(size, route):
             writer.close()
             assert status == 200
             assert s.received() == [_digest(body)]
-            other = "stream" if route == "direct" else "direct"
-            assert (s.read_bytes(route), s.read_bytes(other)) == (size, 0)
-            # the direct path hands the one buffer on; it is not copied back
-            assert s.handler.bodies[0][0] is (bytearray if route == "direct" else bytes)
+            assert {r: s.read_bytes(r) for r in ROUTES} == {r: size * (r == route) for r in ROUTES}
+            # a direct carrier hands the one buffer on; it is not copied back
+            assert s.handler.bodies[0][0] is (bytes if route == "stream" else bytearray)
 
     asyncio.run(run())
 
 
 @pytest.mark.parametrize("with_headers", [0, 1, 1000, 65536, 300_000, 2 * MB])
-def test_body_bytes_that_came_with_the_headers_are_kept(with_headers):
+def test_body_bytes_that_came_with_the_headers_are_kept(with_headers, direct):
     """The segment that carries the headers carries the body's first bytes
     (300,000 of them make the StreamReader pause the transport itself; 2 MiB
     arrive over several reads before the handler first runs)."""
@@ -170,7 +228,32 @@ def test_body_bytes_that_came_with_the_headers_are_kept(with_headers):
             writer.close()
             assert status == 200
             assert s.received() == [_digest(body)]
-            assert s.read_bytes("direct") == size
+            assert s.read_bytes(direct) == size
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("piece", [1000, 100_000])
+def test_a_peer_that_trickles_is_waited_for(piece, direct):
+    """The body comes in pieces with pauses between them, as from a phone:
+    the carrier waits for each and the whole is what was sent."""
+    size = DIRECT_BODY_MIN + 7 * piece + 1
+    body = _payload(size, salt=4)
+
+    async def run():
+        async with _Served() as s:
+            reader, writer = await s.connect()
+            writer.write(_head(size) + body[: size - 7 * piece])
+            for at in range(size - 7 * piece, size, piece):
+                await writer.drain()
+                await asyncio.sleep(0.02)
+                assert s.handler.bodies == []
+                writer.write(body[at: at + piece])
+            status, _, _ = await asyncio.wait_for(_response(reader), 10.0)
+            writer.close()
+            assert status == 200
+            assert s.received() == [_digest(body)]
+            assert s.read_bytes(direct) == size
 
     asyncio.run(run())
 
@@ -185,7 +268,7 @@ def test_body_bytes_that_came_with_the_headers_are_kept(with_headers):
     ],
     ids=["large-small", "small-large", "large-large", "large-get-small-large"],
 )
-def test_pipelined_requests_are_answered_in_order(sizes):
+def test_pipelined_requests_are_answered_in_order(sizes, direct):
     """Requests written back to back on one keep-alive connection: a direct
     read takes its own body and not a byte of the request behind it."""
     bodies = [_payload(n, salt=i) for i, n in enumerate(sizes)]
@@ -202,18 +285,18 @@ def test_pipelined_requests_are_answered_in_order(sizes):
             writer.close()
             assert statuses == [200 if b else 404 for b in bodies]
             assert s.received() == [_digest(b) for b in bodies if b]
-            assert s.read_bytes("direct") == sum(n for n in sizes if n >= DIRECT_BODY_MIN)
+            assert s.read_bytes(direct) == sum(n for n in sizes if n >= DIRECT_BODY_MIN)
             assert s.read_bytes("stream") == sum(n for n in sizes if n < DIRECT_BODY_MIN)
 
     asyncio.run(run())
 
 
 @pytest.mark.parametrize("peer", ["closes", "resets", "stalls"])
-@pytest.mark.parametrize("size", [4096, 4 * MB], ids=["stream", "direct"])
-def test_short_body_is_dropped_unanswered(peer, size):
-    """The slow-client defence on either carrier: the whole body within
+def test_short_body_is_dropped_unanswered(peer, carrier):
+    """The slow-client defence on every carrier: the whole body within
     ``read_timeout`` or the connection goes, with no response, no message
     dispatched, nothing counted as read and no reader left behind."""
+    size = _size_for(carrier)
 
     async def run():
         async with _Served(read_timeout=0.5) as s:
@@ -232,8 +315,8 @@ def test_short_body_is_dropped_unanswered(peer, size):
             writer.close()
             await asyncio.sleep(0.1)
             assert s.handler.bodies == []
-            assert (s.read_bytes("direct"), s.read_bytes("stream")) == (0, 0)
-            assert _wait_until(lambda: not s.server._direct_reads and not s.server._writers)
+            assert [s.read_bytes(r) for r in ROUTES] == [0, 0, 0]
+            assert _wait_until(lambda: not _reading(s.server) and not s.server._writers)
         assert _wait_until(lambda: not _reader_threads())
 
     asyncio.run(run())
@@ -241,9 +324,10 @@ def test_short_body_is_dropped_unanswered(peer, size):
 
 @pytest.mark.parametrize("why", ["tls", "no-free-reader", "no-native-library"])
 def test_what_the_request_shows_chooses_the_carrier(why, tmp_path, monkeypatch):
-    """A large body over TLS, or with every reader busy, is read through the
-    StreamReader, and the counter says so; without the native library the
-    direct read loops in Python instead."""
+    """A large body over TLS is read through the StreamReader and one that
+    finds every reader busy by the ``rest-overflow`` thread, and the counters
+    say so; without the native library a ``rest-body`` thread's read loops
+    in Python instead."""
     size = 3 * MB + 1
     body = _payload(size, salt=2)
     server_ctx = client_ctx = None
@@ -278,42 +362,57 @@ def test_what_the_request_shows_chooses_the_carrier(why, tmp_path, monkeypatch):
                 held.close()
             assert status == 200
             assert s.received() == [_digest(body)]
-            route = "direct" if why == "no-native-library" else "stream"
-            assert s.read_bytes(route) == size
-            assert s.read_bytes("stream" if route == "direct" else "direct") == 0
+            route, reason = {"tls": ("stream", "tls"), "no-free-reader": ("overflow", "no_reader"),
+                             "no-native-library": ("direct", "large")}[why]
+            assert {r: s.read_bytes(r) for r in ROUTES} == {r: size * (r == route) for r in ROUTES}
+            reads = s.registry.get("xaynet_rest_body_reads_total")
+            assert {key: child.value for key, child in reads.children() if child.value} \
+                == {(route, reason): 1}
+            # declared, and 0: no large plain body that had a socket is gathered on the loop
+            assert s.registry.sample_value(
+                "xaynet_rest_body_reads_total", {"route": "stream", "reason": "no_reader"}) == 0
 
     asyncio.run(run())
 
 
-@pytest.mark.parametrize("size", [4096, 4 * MB], ids=["stream", "direct"])
-def test_stop_does_not_wait_for_a_body(size):
-    """PR 21's repair, on either carrier: a connection in mid-body neither
-    holds ``stop()`` for ``read_timeout`` nor leaves a thread reading."""
+def test_stop_does_not_wait_for_a_body(carrier):
+    """PR 21's repair, on every carrier: a connection in mid-body neither
+    holds ``stop()`` for ``read_timeout`` nor leaves a thread reading, a
+    reader registered or a descriptor open."""
+    size = _size_for(carrier)
 
     async def run():
+        descriptors = _open_descriptors()
         s = _Served(read_timeout=120.0)
         await s.__aenter__()
         reader, writer = await s.connect()
         writer.write(_head(size) + _payload(size // 2))
         await writer.drain()
         await asyncio.sleep(0.2)
-        assert len(s.server._direct_reads) == (1 if size >= DIRECT_BODY_MIN else 0)
+        assert (len(s.server._direct_reads), len(s.server._overflow_reads)) \
+            == (carrier == "direct", carrier == "overflow")
+        assert s.registry.sample_value("xaynet_rest_overflow_bodies") == (carrier == "overflow")
         t0 = time.monotonic()
         await s.server.stop()
         assert time.monotonic() - t0 < 2.0
         assert await asyncio.wait_for(reader.read(), 5.0) == b""
         writer.close()
+        await writer.wait_closed()
         assert s.handler.bodies == []
-        assert await asyncio.to_thread(_wait_until, lambda: not _reader_threads())
+        assert await asyncio.to_thread(
+            _wait_until, lambda: not _reading(s.server) and not _reader_threads())
+        await asyncio.sleep(0)  # the thread's last word is a callback on this loop
+        assert s.registry.sample_value("xaynet_rest_overflow_bodies") == 0
+        assert await asyncio.to_thread(_wait_until, lambda: _open_descriptors() <= descriptors)
 
     asyncio.run(run())
 
 
 @pytest.mark.parametrize("shed_by", ["lifecycle", "ingest"])
-@pytest.mark.parametrize("size", [4096, 4 * MB], ids=["stream", "direct"])
-def test_shed_body_leaves_the_connection_in_step(shed_by, size):
+def test_shed_body_leaves_the_connection_in_step(shed_by, carrier):
     """A 429 still consumes its body exactly: the next request on the
     connection is read from its own first byte."""
+    size = _size_for(carrier)
     body = _payload(size, salt=3)
 
     async def run():
@@ -336,8 +435,127 @@ def test_shed_body_leaves_the_connection_in_step(shed_by, size):
             writer.close()
             assert s.handler.bodies == []
             assert ingest.seen == ([size, 10] if shed_by == "ingest" else [])
-            route = "direct" if size >= DIRECT_BODY_MIN else "stream"
-            assert s.read_bytes(route) >= size
+            assert s.read_bytes(carrier) >= size
+
+    asyncio.run(run())
+
+
+def _handlers() -> list[asyncio.Task]:
+    return [t for t in asyncio.all_tasks() if t.get_coro().__name__ == "_handle_conn"]
+
+
+def test_a_cancelled_request_takes_its_read_with_it(direct):
+    """The handler of a connection in mid-body is cancelled: its carrier lets
+    the socket go at once (no thread, registration or descriptor is held for
+    ``read_timeout``), nothing is dispatched, and the server serves on."""
+    size = 4 * MB
+    body = _payload(size, salt=5)
+
+    async def post(s):
+        reader, writer = await s.connect()
+        writer.write(_head(size) + body)
+        await writer.drain()
+        status, _, _ = await asyncio.wait_for(_response(reader), 10.0)
+        writer.close()
+        await writer.wait_closed()
+        return status
+
+    async def run():
+        async with _Served(read_timeout=120.0) as s:
+            assert await post(s) == 200  # the carrier's own descriptors are open from here on
+            assert await asyncio.to_thread(_wait_until, lambda: not s.server._writers)
+            descriptors = _open_descriptors()
+            reader, writer = await s.connect()
+            writer.write(_head(size) + body[: size // 2])
+            await writer.drain()
+            assert await asyncio.to_thread(_wait_until, lambda: _reading(s.server) == 1)
+            (handler,) = _handlers()
+            handler.cancel()
+            assert await asyncio.wait_for(reader.read(), 5.0) == b""
+            writer.close()
+            await writer.wait_closed()
+            assert await asyncio.to_thread(
+                _wait_until, lambda: not _reading(s.server) and _open_descriptors() <= descriptors)
+            assert await post(s) == 200
+            assert s.received() == [_digest(body)] * 2 and s.read_bytes(direct) == 2 * size
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("readers", [0, 16])
+def test_48_bodies_at_once_arrive_whole_and_none_waits_for_another(readers, monkeypatch):
+    """48 connections send a large body at one instant, 47 of them whole and
+    one a piece at a time: the 47 are answered while the slow one is still in
+    mid-body, on the one ``rest-overflow`` thread (with sixteen ``rest-body``
+    readers beside it: they take the first sixteen), and the slow one is
+    answered when its last byte is in."""
+    monkeypatch.setattr(rest, "BODY_READERS", readers)
+    monkeypatch.setattr(rest, "OVERFLOW_TURN_BYTES", 64 * 1024)  # a body is many turns
+    size, n = 2 * MB + 11, 48
+    bodies = [_payload(size, salt=10 + i) for i in range(n)]
+
+    async def run():
+        async with _Served() as s:
+            peers = [await s.connect() for _ in range(n)]
+            slow_reader, slow = peers[-1]  # the last to ask: it finds no reader
+            for (_, writer), body in zip(peers[:-1], bodies):
+                writer.write(_head(size) + body[: size // 3])
+            await asyncio.gather(*(writer.drain() for _, writer in peers[:-1]))
+            assert await asyncio.to_thread(_wait_until, lambda: _reading(s.server) == n - 1)
+            slow.write(_head(size) + bodies[-1][:1000])
+            assert await asyncio.to_thread(_wait_until, lambda: _reading(s.server) == n)
+            assert len(s.server._direct_reads) == readers
+            assert s.registry.sample_value("xaynet_rest_overflow_bodies") == n - readers
+            assert len(_reader_threads("rest-overflow")) == 1
+            for (_, writer), body in zip(peers[:-1], bodies):
+                writer.write(body[size // 3:])
+            statuses = await asyncio.wait_for(
+                asyncio.gather(*(_response(reader) for reader, _ in peers[:-1])), 30.0)
+            assert [status for status, _, _ in statuses] == [200] * (n - 1)
+            assert sorted(s.received()) == sorted(_digest(b) for b in bodies[:-1])
+            assert len(s.server._overflow_reads) == 1  # the slow one, still waited for
+            for at in range(1000, size, 300_000):
+                slow.write(bodies[-1][at: at + 300_000])
+                await slow.drain()
+                await asyncio.sleep(0.01)
+            assert (await asyncio.wait_for(_response(slow_reader), 10.0))[0] == 200
+            assert s.received()[-1] == _digest(bodies[-1])
+            assert (s.read_bytes("direct"), s.read_bytes("overflow"), s.read_bytes("stream")) \
+                == (readers * size, (n - readers) * size, 0)
+            for _, writer in peers:
+                writer.close()
+
+    asyncio.run(run())
+
+
+def test_the_overflow_thread_is_made_once_and_stop_ends_it(monkeypatch):
+    """No thread until a body needs it; the same one for every later body,
+    together or one after another; ``stop()`` ends it."""
+    monkeypatch.setattr(rest, "BODY_READERS", 0)
+    size = DIRECT_BODY_MIN + 5
+    body = _payload(size, salt=6)
+
+    async def post(s):
+        reader, writer = await s.connect()
+        writer.write(_head(size) + body)
+        await writer.drain()
+        status, _, _ = await asyncio.wait_for(_response(reader), 10.0)
+        writer.close()
+        return status, {t.ident for t in _reader_threads("rest-overflow")}
+
+    async def run():
+        async with _Served() as s:
+            # an earlier test's readers may still be on their way out
+            assert await asyncio.to_thread(_wait_until, lambda: not _reader_threads())
+            assert s.server._overflow is None
+            seen = [await post(s), await post(s), *await asyncio.gather(*(post(s) for _ in range(8)))]
+            assert [status for status, _ in seen] == [200] * 10
+            (thread,) = {frozenset(threads) for _, threads in seen}  # one, and the same
+            assert len(thread) == 1 and s.read_bytes("overflow") == 10 * size
+            health = await s.server._dispatch(
+                "GET", "/healthz", "", b"", {}, rest.TenantRoutes(_Fetcher(), s.handler))
+            assert health[0] == 200 and json.loads(health[1])["overflow_bodies"] == 0
+        assert _wait_until(lambda: not _reader_threads())
 
     asyncio.run(run())
 

@@ -1,18 +1,37 @@
-"""Host benchmark of the REST body read: StreamReader against direct.
+"""Host benchmark of the REST body read, carrier by carrier.
 
 One process serves ``RestServer`` with a handler that drops every message;
-a second process sends ``--conns`` bodies of ``--size`` bytes at once, each
-over its own connection, exactly as ``sdk/client.py::_exchange`` does
-(``writer.write(head + body); await writer.drain()``). Printed per case:
-seconds from headers parsed to body in memory (what ``rest.read_body``
-brackets), mean and max over the bodies, and the sender's wall. ``stream``
-raises the threshold above every body so that the StreamReader carries
-them; ``direct`` leaves ``rest.py`` as it ships. ``--gil N`` runs N threads
-of pure Python beside the server's loop, which is what the served round's
-workers do to it. No chip, no jax: a CPU number, and quoted as one.
+a second process sends ``--conns`` bodies of ``--size`` bytes at one
+instant, each over its own connection, exactly as
+``sdk/client.py::_exchange`` does (``writer.write(head + body); await
+writer.drain()``). A case is one carrier for every body:
 
-Run:  python tools/bench_body_read.py [--size 178899224] [--conns 1,8]
-          [--routes stream,direct] [--gil 0,3] [--repeat 1]
+- ``stream``: the threshold raised above every body, so that the loop's
+  StreamReader gathers them (what a large body that found no reader took
+  until PR 43);
+- ``thread``: a ``rest-body`` thread each (``BODY_READERS`` raised to the
+  connections);
+- ``overflow``: all on the one ``rest-overflow`` thread (``BODY_READERS``
+  0), at each ``--turn`` (``rest.OVERFLOW_TURN_BYTES``);
+- ``coroutine``: the carrier that was weighed and not taken (ISSUE 43): the
+  same receive as a coroutine on the loop, ``loop.sock_recv_into`` into the
+  one buffer, ``--turn`` bytes and then the loop given back; it lives here
+  alone;
+- ``direct``: ``rest.py`` as it ships (sixteen threads, the rest overflow).
+
+Printed a case: seconds from headers parsed to body in memory (what
+``rest.read_body`` brackets), mean and longest over the bodies; the loop
+thread's CPU a body (``time.thread_time()`` on the loop, sender started ->
+last body in memory); the sealed GB/s (all bodies over first headers parsed
+-> last body in memory); how late a 10 ms sleep on the loop woke, mean and
+longest; the sender's wall. ``--gil N`` runs N threads of pure Python beside
+the server's loop, which is what a served round's own Python does to a
+thread that takes the interpreter lock back after every receive. No chip,
+no jax: a host number, and quoted as one (PERF.md section 6, PR 27, PR 43).
+
+Run:  python tools/bench_body_read.py [--size 39622260] [--conns 16,48,64]
+          [--carriers stream,thread,overflow,coroutine,direct]
+          [--turn 262144,1048576,4194304] [--gil 0,1] [--repeat 1]
       python tools/bench_body_read.py --sweep     # where the thread hop pays
 """
 
@@ -31,6 +50,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from xaynet_tpu.server import rest  # noqa: E402
 from xaynet_tpu.telemetry.registry import MetricsRegistry  # noqa: E402
+from xaynet_tpu.utils import native  # noqa: E402
+
+
+TAKES_TURNS = ("overflow", "coroutine", "direct")  # the carriers --turn applies to
 
 
 class _Drop:
@@ -39,15 +62,47 @@ class _Drop:
 
 
 class _TimedServer(rest.RestServer):
-    def __init__(self):
+    def __init__(self, coroutine: bool):
         super().__init__(fetcher=None, handler=_Drop(), registry=MetricsRegistry())
-        self.reads: list[float] = []
+        self.coroutine = coroutine
+        self.reads: list[tuple[float, float]] = []  # (headers parsed, body in memory)
 
     async def _read_body(self, reader, writer, length):
         t0 = time.monotonic()
-        body = await super()._read_body(reader, writer, length)
-        self.reads.append(time.monotonic() - t0)
+        carrier = self._read_on_the_loop if self.coroutine else super()._read_body
+        body = await carrier(reader, writer, length)
+        self.reads.append((t0, time.monotonic()))
         return body
+
+    async def _read_on_the_loop(self, reader, writer, length):
+        """``_read_body``'s preparation, then the receive as a coroutine."""
+        sock, _ = self._direct_socket(reader, writer, length)
+        assert sock is not None
+        with sock:
+            loop, transport = asyncio.get_running_loop(), writer.transport
+            transport.pause_reading()
+            body = native.uninitialised_bytearray(None, length)
+            got = len(reader._buffer)
+            if got:
+                body[:got] = await reader.read(got)
+                transport.pause_reading()
+            view = memoryview(body)
+            while got < length:
+                n = await loop.sock_recv_into(sock, view[got:got + rest.OVERFLOW_TURN_BYTES])
+                if n == 0:
+                    raise asyncio.IncompleteReadError(b"", length)
+                got += n
+                await asyncio.sleep(0)  # a readable socket never suspends sock_recv_into
+        transport.resume_reading()
+        return body
+
+
+async def _watch_lag(late: list[float], period: float = 0.01) -> None:
+    loop = asyncio.get_running_loop()
+    while True:
+        due = loop.time() + period
+        await asyncio.sleep(period)
+        late.append(max(0.0, loop.time() - due))
 
 
 async def _send(port: int, size: int, conns: int, rounds: int) -> None:
@@ -75,44 +130,60 @@ def _spin(stop: threading.Event) -> None:
             x += i * i
 
 
-async def _case(route: str, size: int, conns: int, rounds: int, gil: int) -> dict:
-    shipped = rest.DIRECT_BODY_MIN
-    rest.DIRECT_BODY_MIN = rest.MAX_BODY + 1 if route == "stream" else shipped
-    server = _TimedServer()
+async def _case(carrier: str, size: int, conns: int, rounds: int, gil: int, turn: int) -> dict:
+    shipped = rest.DIRECT_BODY_MIN, rest.BODY_READERS, rest.OVERFLOW_TURN_BYTES
+    if carrier == "stream":
+        rest.DIRECT_BODY_MIN = rest.MAX_BODY + 1
+    rest.BODY_READERS = {"thread": conns, "overflow": 0}.get(carrier, rest.BODY_READERS)
+    rest.OVERFLOW_TURN_BYTES = turn
+    server = _TimedServer(coroutine=carrier == "coroutine")
     _, port = await server.start("127.0.0.1", 0)
-    stop = threading.Event()
+    stop, late = threading.Event(), []
     spinners = [threading.Thread(target=_spin, args=(stop,), daemon=True) for _ in range(gil)]
     for t in spinners:
         t.start()
+    lag = asyncio.create_task(_watch_lag(late))
     try:
+        cpu = time.thread_time()
         sender = await asyncio.create_subprocess_exec(
             sys.executable, os.path.abspath(__file__), "--send", str(port),
             "--size", str(size), "--conns", str(conns), "--rounds", str(rounds),
             stdout=subprocess.PIPE,
         )
         out, _ = await sender.communicate()
+        cpu = time.thread_time() - cpu
         assert sender.returncode == 0, sender.returncode
     finally:
         stop.set()
+        lag.cancel()
         await server.stop()
-        rest.DIRECT_BODY_MIN = shipped
-    reads = server.reads
-    direct = server.registry.sample_value("xaynet_rest_body_bytes_total", {"route": "direct"}) or 0
+        rest.DIRECT_BODY_MIN, rest.BODY_READERS, rest.OVERFLOW_TURN_BYTES = shipped
+    reads = [end - start for start, end in server.reads]
+    wall = max(end for _, end in server.reads) - min(start for start, _ in server.reads)
+    by_route = {
+        route: int(server.registry.sample_value("xaynet_rest_body_bytes_total", {"route": route}) or 0)
+        for route in ("direct", "overflow", "stream")
+    }
     return {
-        "route": route, "size": size, "conns": conns, "rounds": rounds, "gil_threads": gil,
+        "carrier": carrier, "size": size, "conns": conns, "rounds": rounds, "gil_threads": gil,
+        "turn_bytes": turn if carrier in TAKES_TURNS else None,
         "bodies": len(reads), "read_mean_s": sum(reads) / len(reads), "read_max_s": max(reads),
+        "loop_cpu_ms_per_body": 1e3 * cpu / len(reads), "sealed_gbps": len(reads) * size / wall / 1e9,
+        "loop_lag_mean_ms": 1e3 * sum(late) / max(1, len(late)), "loop_lag_max_ms": 1e3 * max(late, default=0.0),
         "sender_s": json.loads(out.decode().strip().splitlines()[-1])["sender_s"],
-        "direct_bytes": int(direct),
+        "bytes_by_route": by_route,
     }
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--send", type=int, help=argparse.SUPPRESS)  # the sender child: a port
-    ap.add_argument("--size", type=int, default=178_899_224)
-    ap.add_argument("--conns", default="1,8")
+    ap.add_argument("--size", type=int, default=39_622_260)
+    ap.add_argument("--conns", default="16,48,64")
     ap.add_argument("--rounds", type=int, default=1, help="bodies a connection sends in turn")
-    ap.add_argument("--routes", default="stream,direct")
+    ap.add_argument("--carriers", default="stream,thread,overflow,coroutine,direct")
+    ap.add_argument("--turn", default=str(rest.OVERFLOW_TURN_BYTES),
+                    help="bytes a body takes in one turn (overflow, coroutine, direct)")
     ap.add_argument("--gil", default="0")
     ap.add_argument("--repeat", type=int, default=1)
     ap.add_argument("--sweep", action="store_true",
@@ -122,13 +193,16 @@ def main() -> None:
         asyncio.run(_send(args.send, args.size, int(args.conns), args.rounds))
         return
     if args.sweep:
-        cases = [(r, 1 << p, 1, 40, 0) for p in range(16, 25) for r in ("stream", "direct")]
+        cases = [(r, 1 << p, 1, 40, 0, rest.OVERFLOW_TURN_BYTES)
+                 for p in range(16, 25) for r in ("stream", "direct")]
         rest.DIRECT_BODY_MIN = 1  # every size of the sweep may go direct
     else:
+        turns = [int(t) for t in args.turn.split(",")]
         cases = [
-            (r, args.size, int(c), args.rounds, int(g))
+            (r, args.size, int(c), args.rounds, int(g), t)
             for g in args.gil.split(",") for c in args.conns.split(",")
-            for r in args.routes.split(",")
+            for r in args.carriers.split(",")
+            for t in (turns if r in TAKES_TURNS else turns[:1])
         ]
     for case in cases:
         for _ in range(args.repeat):

@@ -23,14 +23,18 @@ from __future__ import annotations
 
 import asyncio
 import ctypes
+import heapq
 import json
 import logging
 import math
 import os
 import select
+import selectors
 import socket
 import ssl
+import threading
 import time
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Optional
@@ -73,9 +77,17 @@ MAX_BODY = 1 << 32  # u32 length field ceiling, as in the reference
 # StreamReader's copies (PERF.md §6, PR 27: where the two cross).
 DIRECT_BODY_MIN = 1 << 20
 # Readers that may block on a socket at once. A large body that finds them
-# all busy is read through the StreamReader: a connection never waits for a
-# reader while its peer is sending.
+# all busy is received by the one event-driven `rest-overflow` thread, beside
+# any number of others: a connection never waits for a reader while its peer
+# is sending, and a slow peer there costs a registration and no thread.
 BODY_READERS = 16
+# The most bytes the `rest-overflow` thread takes from one body before it
+# turns to the next readable one, so that bodies registered together advance
+# together. 48 bodies of 39.6 MB at once on the idle chip host: 0.56 GB/s at
+# 64 KiB, 0.80 at 256 KiB, 0.93-0.98 at 1 MiB, 1.03 at 4 MiB, 1.01 at 16 MiB
+# (one thread's first touch of fresh pages is the bound from here on), where
+# a longer turn only keeps the others waiting (PERF.md §6, PR 43).
+OVERFLOW_TURN_BYTES = 1 << 20
 
 SPAN_REQUEST = trace.declare_span("rest.request")
 
@@ -146,6 +158,131 @@ def _abort_read(sock: socket.socket) -> None:
         sock.shutdown(socket.SHUT_RDWR)
     except OSError:
         pass  # the reader finished and closed it first
+
+
+class _OverflowBody:
+    """One body the ``rest-overflow`` thread is receiving: ``view`` is filled
+    as far as ``got``; ``sock`` is ``None`` once the thread has let it go."""
+
+    __slots__ = ("sock", "view", "got", "deadline", "loop", "done")
+
+    def __init__(self, sock, body: bytearray, start: int, deadline: float, loop, done):
+        self.sock, self.view, self.got = sock, memoryview(body), start
+        self.deadline, self.loop, self.done = deadline, loop, done
+
+
+class _OverflowReader:
+    """The ``rest-overflow`` thread: any number of bodies at once, each
+    received from its own socket (non-blocking: it shares its transport's
+    open file) straight into its one buffer. The thread owns a selector and
+    every socket handed to it, and closes each where that body ends: whole,
+    the peer closed or reset, the socket shut down under it (``_abort_read``)
+    or its deadline passed. Each ends by resolving its future, on its loop,
+    with how far the buffer is filled, as ``_recv_exactly`` returns it.
+    ``recv_into`` releases the interpreter lock for the copy and for the
+    first touch of the buffer's pages, so both land here and not on the loop.
+    ``holds`` is the gauge of the bodies the thread holds."""
+
+    def __init__(self, holds):
+        self._holds = holds
+        self._selector = selectors.DefaultSelector()
+        # registrations and close() reach a thread asleep in select() here
+        self._wake_recv, self._wake_send = socket.socketpair()
+        self._wake_recv.setblocking(False)
+        self._wake_send.setblocking(False)
+        self._selector.register(self._wake_recv, selectors.EVENT_READ)
+        self._arrivals: deque[_OverflowBody] = deque()
+        self._closing = False
+        threading.Thread(target=self._run, name="rest-overflow", daemon=True).start()
+
+    def receive(
+        self, sock: socket.socket, body: bytearray, start: int, deadline: float
+    ) -> "asyncio.Future[int]":
+        """Hand ``sock`` to the thread, to fill ``body[start:]`` by
+        ``deadline`` (``time.monotonic()``). Called on the request's loop."""
+        loop = asyncio.get_running_loop()
+        done = loop.create_future()
+        self._holds.inc()
+        self._arrivals.append(_OverflowBody(sock, body, start, deadline, loop, done))
+        self._wake()
+        return done
+
+    def _resolve(self, done: asyncio.Future, got: int) -> None:
+        """On the request's loop: the thread has let the body go."""
+        self._holds.dec()
+        if not done.done():  # a cancelled request waits no longer
+            done.set_result(got)
+
+    def close(self) -> None:
+        """The thread ends once it holds no body (``stop()`` has shut their
+        sockets down: each returns short at once)."""
+        self._closing = True
+        self._wake()
+
+    def _wake(self) -> None:
+        try:
+            self._wake_send.send(b"\0")
+        except BlockingIOError:
+            pass  # as many wake-ups unread as the socket holds: it will wake
+
+    def _run(self) -> None:
+        deadlines, order = [], 0  # a heap: (deadline, order of arrival, body)
+        while True:
+            timeout = None
+            if deadlines:  # the nearest one; a body that ended sooner wakes it for nothing
+                timeout = max(0.0, deadlines[0][0] - time.monotonic())
+            for key, _ in self._selector.select(timeout):
+                if key.data is not None:
+                    self._advance(key.data)
+                else:
+                    try:
+                        self._wake_recv.recv(4096)
+                    except BlockingIOError:
+                        pass
+            while self._arrivals:
+                body = self._arrivals.popleft()
+                order += 1
+                heapq.heappush(deadlines, (body.deadline, order, body))
+                try:
+                    self._selector.register(body.sock, selectors.EVENT_READ, body)
+                except OSError:
+                    self._finish(body, registered=False)
+            now = time.monotonic()
+            while deadlines and (deadlines[0][2].sock is None or deadlines[0][0] <= now):
+                body = heapq.heappop(deadlines)[2]
+                if body.sock is not None:
+                    self._finish(body)  # out of time: short
+            if self._closing and not deadlines and not self._arrivals:
+                break
+        self._selector.close()
+        self._wake_recv.close()
+        self._wake_send.close()
+
+    def _advance(self, body: _OverflowBody) -> None:
+        """``body``'s socket is readable: one ``recv_into`` of what is there,
+        never past the body's end nor over a turn's bytes. A short count says
+        the socket is drained; the selector says when it no longer is."""
+        end = min(len(body.view), body.got + OVERFLOW_TURN_BYTES)
+        try:
+            n = body.sock.recv_into(body.view[body.got:end])
+        except (BlockingIOError, InterruptedError):
+            return
+        except OSError:
+            n = 0  # reset by the peer
+        body.got += n
+        if n == 0 or body.got == len(body.view):
+            self._finish(body)
+
+    def _finish(self, body: _OverflowBody, registered: bool = True) -> None:
+        sock, body.sock = body.sock, None
+        if registered:
+            self._selector.unregister(sock)
+        sock.close()
+        body.view.release()
+        try:
+            body.loop.call_soon_threadsafe(self._resolve, body.done, body.got)
+        except RuntimeError:
+            pass  # the loop is closed: nobody waits
 
 
 class RestServer:
@@ -229,8 +366,9 @@ class RestServer:
             "xaynet_rest_body_bytes_total",
             "Request body bytes read in full, by route: direct = received by "
             "a rest-body thread into one buffer of the Content-Length, "
-            "stream = gathered by the event loop's StreamReader (small "
-            "bodies, TLS, no free reader).",
+            "overflow = received into such a buffer by the rest-overflow "
+            "thread (every rest-body thread was busy), stream = gathered by "
+            "the event loop's StreamReader (small bodies, TLS, no socket).",
             ("route",),
         )
         self._loop_cpu = self.registry.counter(
@@ -257,10 +395,13 @@ class RestServer:
         self._phase_seen: dict[str, object] = {}
         self._lag_task: Optional[asyncio.Task] = None
         self._server: Optional[asyncio.AbstractServer] = None
-        # the bounded rest-body pool (made when a body first needs it) and
-        # the sockets its threads are reading: stop() shuts those down
+        # the bounded rest-body pool and the rest-overflow thread (each made
+        # when a body first needs it), and the sockets each is reading:
+        # stop() shuts those down
         self._body_pool: Optional[ThreadPoolExecutor] = None
         self._direct_reads: set[socket.socket] = set()
+        self._overflow: Optional[_OverflowReader] = None
+        self._overflow_reads: set[socket.socket] = set()
         # live connections: stop() closes them — an idle keep-alive peer
         # would otherwise hold the process for read_timeout seconds
         self._writers: set[asyncio.StreamWriter] = set()
@@ -287,14 +428,17 @@ class RestServer:
             await asyncio.gather(self._lag_task, return_exceptions=True)
             self._lag_task = None
         self._server.close()
-        for sock in list(self._direct_reads):
-            _abort_read(sock)  # a body in mid-read: its thread returns short
+        for sock in [*self._direct_reads, *self._overflow_reads]:
+            _abort_read(sock)  # a body in mid-read: its carrier returns short
         for writer in list(self._writers):
             writer.close()
         await self._server.wait_closed()
         if self._body_pool is not None:
             self._body_pool.shutdown(wait=False)
             self._body_pool = None
+        if self._overflow is not None:
+            self._overflow.close()
+            self._overflow = None
 
     async def _watch_loop_lag(self, period: float = 0.1) -> None:
         """Observe, every ``period`` seconds, how late this loop ran a task
@@ -364,9 +508,10 @@ class RestServer:
         """The request's body, whole within ``read_timeout`` or an exception
         that drops the connection unanswered. One algorithm (receive
         ``length`` bytes) on the carrier the request calls for: a large body
-        on plain TCP with a reader free goes straight from the socket into
-        one buffer on a ``rest-body`` thread, everything else through the
-        StreamReader on the loop. Never reads past the body."""
+        on plain TCP goes straight from the socket into one buffer, on a
+        ``rest-body`` thread while one is free and on the ``rest-overflow``
+        thread beside the others there while none is; everything else
+        through the StreamReader on the loop. Never reads past the body."""
         if not length:
             return b""
         sock, reason = self._direct_socket(reader, writer, length)
@@ -377,7 +522,7 @@ class RestServer:
             return body
         deadline = time.monotonic() + self.read_timeout
         transport = writer.transport
-        # nothing below suspends until the thread has the socket, so no byte
+        # nothing below suspends until a thread has the socket, so no byte
         # reaches the StreamReader between here and resume_reading()
         transport.pause_reading()
         body = native.uninitialised_bytearray(None, length)
@@ -388,47 +533,57 @@ class RestServer:
             # the StreamReader had paused itself: pause again
             body[:buffered] = await reader.read(buffered)  # lint: wirecopy-ok (a store)
             transport.pause_reading()
-        if self._body_pool is None:
-            self._body_pool = ThreadPoolExecutor(BODY_READERS, thread_name_prefix="rest-body")
-        self._direct_reads.add(sock)
-        try:
-            got = await asyncio.get_running_loop().run_in_executor(
+        if reason == "large":
+            if self._body_pool is None:
+                self._body_pool = ThreadPoolExecutor(BODY_READERS, thread_name_prefix="rest-body")
+            route, reads = "direct", self._direct_reads
+            whole = asyncio.get_running_loop().run_in_executor(
                 self._body_pool, _recv_exactly, sock, body, buffered, deadline
             )
+        else:
+            if self._overflow is None:
+                self._overflow = _OverflowReader(self._intake.overflow_bodies)
+            route, reads = "overflow", self._overflow_reads
+            whole = self._overflow.receive(sock, body, buffered, deadline)
+        reads.add(sock)
+        try:
+            got = await whole
         except BaseException:
-            _abort_read(sock)  # cancelled: the thread must not outlive the request
+            _abort_read(sock)  # cancelled: the read must not outlive the request
             raise
         finally:
-            self._direct_reads.discard(sock)
+            reads.discard(sock)
         if got < length:  # closed, reset, aborted or out of time: all drop the connection
             raise asyncio.IncompleteReadError(b"", length)
         transport.resume_reading()
-        self._body_bytes.labels(route="direct").inc(length)
-        self._intake.read("direct", reason)
+        self._body_bytes.labels(route=route).inc(length)
+        self._intake.read(route, reason)
         return body
 
     def _direct_socket(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int
     ) -> tuple[Optional[socket.socket], str]:
         """A duplicate of the connection's descriptor if this body is to be
-        read directly, else ``None``, and the reason either way (``large``,
-        ``small``, or one of ``intake.TURNED_AWAY``). A duplicate, so that a transport closed
-        in mid-body (``stop()``) cannot hand the reader thread a recycled
-        descriptor; it shares the transport's non-blocking mode."""
+        received straight from the socket, else ``None``, and the reason
+        either way: ``large`` (for a ``rest-body`` thread: one is free) or
+        ``no_reader`` (for the ``rest-overflow`` thread: none is) with a
+        socket, ``small``, ``tls`` or ``no_socket`` without. A duplicate, so
+        that a transport closed in mid-body (``stop()``) cannot hand the
+        reading thread a recycled descriptor; it shares the transport's
+        non-blocking mode."""
         if length < DIRECT_BODY_MIN:
             return None, "small"
         if writer.get_extra_info("ssl_object") is not None:
             return None, "tls"
-        if len(self._direct_reads) >= BODY_READERS:
-            return None, "no_reader"
         trsock = writer.get_extra_info("socket")
         buffer = getattr(reader, "_buffer", None)
         if trsock is None or not isinstance(buffer, bytearray) or len(buffer) >= length:
             return None, "no_socket"
         try:
-            return socket.socket(fileno=os.dup(trsock.fileno())), "large"
+            sock = socket.socket(fileno=os.dup(trsock.fileno()))
         except OSError:
             return None, "no_socket"  # the transport is already closed: the stream path says how
+        return sock, "large" if len(self._direct_reads) < BODY_READERS else "no_reader"
 
     def _resolve_tenant(self, path: str):
         """Split a ``/t/<tenant>/<sub>`` target into (tenant id, sub path,
@@ -579,12 +734,13 @@ class RestServer:
             self._intake.new_window()
         elif entered.event is PhaseName.SUM2:
             self._sum2_first_arrival.observe(time.monotonic() - entered.at)
-            direct, turned, high = self._intake.since_last()
-            if direct or turned:
+            direct, overflow, turned, high = self._intake.since_last()
+            if direct or overflow or turned:
                 logger.info(
-                    "large bodies since the last Sum2: %d read by rest-body threads, %d through "
-                    "the StreamReader (%s); at most %d message bodies held sealed at once",
-                    direct, sum(turned.values()),
+                    "large bodies since the last Sum2: %d read by rest-body threads, %d by the "
+                    "rest-overflow thread, %d through the StreamReader (%s); at most %d message "
+                    "bodies held sealed at once",
+                    direct, overflow, sum(turned.values()),
                     ", ".join(f"{reason} {n}" for reason, n in turned.items()) or "none", high,
                 )
 
@@ -655,6 +811,7 @@ class RestServer:
                 payload = self._health_payload(routes)
                 payload["status"] = "ok"
                 payload["uptime_seconds"] = round(time.monotonic() - self._started_at, 3)
+                payload["overflow_bodies"] = int(self._intake.overflow_bodies.value)
                 if routes.pipeline is not None:
                     ingest = routes.pipeline.health()
                     # the ingress boundary gets its own top-level section

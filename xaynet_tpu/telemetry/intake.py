@@ -2,17 +2,23 @@
 intake under a fan-in").
 
 With more connections than ``rest-body`` readers a round's large bodies are
-read on two carriers at once, every connection holds a body while its
-message waits for a worker, and the API's loop, which runs the serial part
-of every message, becomes the bound on intake. Three things are counted,
-each where it happens:
+received on two carriers at once (a ``rest-body`` thread each while one is
+free, the one event-driven ``rest-overflow`` thread for all the others),
+every connection holds a body while its message waits for a worker, and the
+API's loop, which runs the serial part of every message, becomes the bound
+on intake. Four things are counted, each where it happens:
 
 - ``xaynet_rest_body_reads_total{route, reason}``: one a request body read
-  in full, by the carrier that read it and why it was that one.
-  ``route="direct"`` has the one reason ``large``; ``route="stream"`` says
-  ``small`` (under ``rest.DIRECT_BODY_MIN``), ``tls``, ``no_reader`` (a
-  large body on a plain connection that found every reader busy) or
-  ``no_socket`` (the transport gave no descriptor to read from).
+  in full, by the carrier that read it and why it was that one. Of the three
+  carriers ``route="direct"`` (a ``rest-body`` thread) has the one reason
+  ``large`` and ``route="overflow"`` (the ``rest-overflow`` thread) the one
+  reason ``no_reader``: a large body on a plain connection that found every
+  reader busy. ``route="stream"`` (the loop's StreamReader) says ``small``
+  (under ``rest.DIRECT_BODY_MIN``), ``tls`` or ``no_socket`` (the transport
+  gave no descriptor to read from); ``stream``/``no_reader`` is declared and
+  stays 0: no large plain body that had a socket is gathered on the loop.
+- ``xaynet_rest_overflow_bodies``: the bodies the ``rest-overflow`` thread
+  holds at this instant (also ``/healthz`` ``overflow_bodies``).
 - ``xaynet_rest_bodies_resident`` and ``..._resident_max``: POSTed message
   bodies held sealed (being read, or read and waiting for a ``pet-msg``
   worker to open them), now and at the most since the Update phase's first
@@ -30,8 +36,10 @@ import threading
 
 from .registry import MetricsRegistry
 
-# why a large body went through the StreamReader, in the order a round's log names them
-TURNED_AWAY = ("no_reader", "tls", "no_socket")
+# every (route, reason) a large body is counted under: the two direct carriers,
+# then why one went through the StreamReader, in the order a round's log names them
+LARGE = (("direct", "large"), ("overflow", "no_reader"),
+         ("stream", "no_reader"), ("stream", "tls"), ("stream", "no_socket"))
 
 
 class Held:
@@ -57,11 +65,19 @@ class BodyIntake:
         self._reads = registry.counter(
             "xaynet_rest_body_reads_total",
             "Request bodies read in full, by route and why: direct/large = a "
-            "rest-body thread; stream/small, stream/tls, stream/no_reader (a "
-            "large plain-TCP body that found all readers busy), "
-            "stream/no_socket = the event loop's StreamReader "
-            "(telemetry/intake.py).",
+            "rest-body thread; overflow/no_reader = the rest-overflow thread "
+            "(a large plain-TCP body that found all readers busy); "
+            "stream/small, stream/tls, stream/no_socket = the event loop's "
+            "StreamReader; stream/no_reader stays 0 (telemetry/intake.py).",
             ("route", "reason"),
+        )
+        # declared (each reads 0, not absent), and a round's log counts from
+        # here: the registry may have served another server before this one
+        self._logged = self._large_reads()
+        self.overflow_bodies = registry.gauge(
+            "xaynet_rest_overflow_bodies",
+            "Large request bodies the rest-overflow thread is receiving at "
+            "this instant (one registration each, no thread).",
         )
         self._resident = registry.gauge(
             "xaynet_rest_bodies_resident",
@@ -76,7 +92,6 @@ class BodyIntake:
         self._lock = threading.Lock()
         self._n = 0
         self._high = 0
-        self._logged: dict[str, float] = {}
 
     def read(self, route: str, reason: str) -> None:
         self._reads.labels(route=route, reason=reason).inc()
@@ -102,14 +117,16 @@ class BodyIntake:
             self._high = self._n
             self._resident_max.set(self._high)
 
-    def since_last(self) -> tuple[int, dict[str, int], int]:
-        """(large bodies read directly, {reason: large bodies through the
+    def _large_reads(self) -> dict[tuple[str, str], float]:
+        return {key: self._reads.labels(route=key[0], reason=key[1]).value for key in LARGE}
+
+    def since_last(self) -> tuple[int, int, dict[str, int], int]:
+        """(large bodies read by ``rest-body`` threads, by the
+        ``rest-overflow`` thread, {reason: large bodies through the
         StreamReader, where any}, the high-water mark) since the previous
         call: what a round's log line says."""
-        now = {"large": self._reads.labels(route="direct", reason="large").value}
-        for reason in TURNED_AWAY:
-            now[reason] = self._reads.labels(route="stream", reason=reason).value
-        grown = {k: int(v - self._logged.get(k, 0.0)) for k, v in now.items()}
+        now = self._large_reads()
+        grown = {key: int(v - self._logged[key]) for key, v in now.items()}
         self._logged = now
-        direct = grown.pop("large")
-        return direct, {reason: n for reason, n in grown.items() if n}, self._high
+        direct, overflow = grown.pop(LARGE[0]), grown.pop(LARGE[1])
+        return direct, overflow, {reason: n for (_, reason), n in grown.items() if n}, self._high
