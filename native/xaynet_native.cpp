@@ -1126,7 +1126,7 @@ XN_EXPORT uint64_t xn_count_ge(const uint32_t* limbs, uint64_t count, uint32_t n
   return bad;
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 11; }
+XN_EXPORT uint32_t xn_abi_version(void) { return 12; }
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST
@@ -1159,39 +1159,61 @@ XN_EXPORT uint64_t xn_recv_exactly(int fd, uint8_t* buf, uint64_t start, uint64_
   return got;
 }
 
+// Copy n bytes on fold_threads() threads (ABI 12). A vector-sized
+// serialisation into a fresh buffer is bound by the first touch of the
+// destination's pages, not by the copy: every thread touches its own slice
+// (the decoded model's one serialisation, utils/native.py::tobytes).
+XN_EXPORT void xn_copy_bytes(const uint8_t* src, uint8_t* dst, uint64_t n) {
+  run_sliced(n, 4096, [=](uint64_t s0, uint64_t s1) {
+    std::memcpy(dst + s0, src + s0, (size_t)(s1 - s0));
+  });
+}
+
 // Fixed-point decode: out[i] = ((value_i - C) ) * inv, computed in
-// double-double, where value_i is the unmasked group element (wire-layout
-// uint32 limbs, n_limbs <= 4 so values fit __int128), C = nb_models *
-// add_shift * exp_shift (integer, little-endian bytes), and (inv_hi,
-// inv_lo) is the double-double reciprocal of exp_shift * scalar_sum.
+// double-double, where value_i is the unmasked group element (uint32 limbs,
+// n_limbs <= 4 so values fit __int128), C = nb_models * add_shift *
+// exp_shift (integer, little-endian bytes), and (inv_hi, inv_lo) is the
+// double-double reciprocal of exp_shift * scalar_sum. Element i's limb j is
+// read at limbs[j * plane_stride + i]: the planar layout the device arms
+// fetch, plane_stride being the padded length; plane_stride == 0 reads the
+// wire layout, limbs[i * n_limbs + j]. The element axis runs through
+// run_sliced on fold_threads() threads, each writing its own slice of
+// `out` (whose fresh pages are first touched there). The arithmetic per
+// element is the same on every thread count and in both layouts.
 // This is the unmask decode hot loop (python fallback: double-double
 // numpy in xaynet_tpu/core/mask/encode.py).
 XN_EXPORT int xn_decode_f64(const uint32_t* limbs, uint64_t n, uint32_t n_limbs,
-                            const uint8_t* c_le, uint32_t c_len, double inv_hi,
-                            double inv_lo, double* out) {
+                            uint64_t plane_stride, const uint8_t* c_le,
+                            uint32_t c_len, double inv_hi, double inv_lo,
+                            double* out) {
   if (n_limbs == 0 || n_limbs > 4 || c_len > 15) return 1;
+  if (plane_stride != 0 && plane_stride < n) return 1;
   __int128 c = 0;
   for (int i = (int)c_len - 1; i >= 0; i--) c = (c << 8) | c_le[i];
+  const uint64_t elem_step = plane_stride ? 1 : n_limbs;
+  const uint64_t limb_step = plane_stride ? plane_stride : 1;
 
-  for (uint64_t i = 0; i < n; i++) {
-    const uint32_t* v = limbs + i * n_limbs;
-    unsigned __int128 val = 0;
-    for (int j = (int)n_limbs - 1; j >= 0; j--) val = (val << 32) | v[j];
-    __int128 diff = (__int128)val - c;
-    // exact double-double of diff (|diff| < 2^127)
-    double d_hi = (double)diff;
-    double d_lo = (double)(diff - (__int128)d_hi);
-    // dd multiply (d_hi, d_lo) * (inv_hi, inv_lo), Dekker two_prod
-    double p = d_hi * inv_hi;
-    const double split = 134217729.0;  // 2^27 + 1
-    double ah = split * d_hi, bh = split * inv_hi;
-    ah = ah - (ah - d_hi);
-    bh = bh - (bh - inv_hi);
-    double al = d_hi - ah, bl = inv_hi - bh;
-    double err = ((ah * bh - p) + ah * bl + al * bh) + al * bl;
-    err += d_hi * inv_lo + d_lo * inv_hi;
-    out[i] = p + err;
-  }
+  run_sliced(n, 4096, [=](uint64_t s0, uint64_t s1) {
+    for (uint64_t i = s0; i < s1; i++) {
+      const uint32_t* v = limbs + i * elem_step;
+      unsigned __int128 val = 0;
+      for (int j = (int)n_limbs - 1; j >= 0; j--) val = (val << 32) | v[j * limb_step];
+      __int128 diff = (__int128)val - c;
+      // exact double-double of diff (|diff| < 2^127)
+      double d_hi = (double)diff;
+      double d_lo = (double)(diff - (__int128)d_hi);
+      // dd multiply (d_hi, d_lo) * (inv_hi, inv_lo), Dekker two_prod
+      double p = d_hi * inv_hi;
+      const double split = 134217729.0;  // 2^27 + 1
+      double ah = split * d_hi, bh = split * inv_hi;
+      ah = ah - (ah - d_hi);
+      bh = bh - (bh - inv_hi);
+      double al = d_hi - ah, bl = inv_hi - bh;
+      double err = ((ah * bh - p) + ah * bl + al * bh) + al * bl;
+      err += d_hi * inv_lo + d_lo * inv_hi;
+      out[i] = p + err;
+    }
+  });
   return 0;
 }
 

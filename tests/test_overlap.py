@@ -248,7 +248,9 @@ def test_eager_unmask_byte_identical_sharded(kernel):
     stream.drain()
     out = stream.finish_unmask(job)
     assert out is not None, "no shard error -> the eager result must land"
-    np.testing.assert_array_equal(out, expected)
+    # the eager arm hands over the planes it fetched; a wire caller asks
+    assert out.planes.shape == (expected.shape[1], LEN) and out.length == LEN
+    np.testing.assert_array_equal(out.wire(), expected)
     stream.close()
 
 
@@ -318,7 +320,8 @@ def test_two_tenant_pipelined_eager_unmask_byte_identical():
             stream.submit_batch(np.stack(stacks[i : i + 4]))
         job = stream.stage_unmask(agg.mask_planar(mask_vect))
         stream.drain()
-        return stream.finish_unmask(job) if job is not None else None
+        out = stream.finish_unmask(job) if job is not None else None
+        return out.wire() if out is not None else None
 
     results = {}
     errs = []

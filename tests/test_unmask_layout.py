@@ -2,7 +2,12 @@
 (docs/DESIGN.md §22, "Ask before the relayout"): ``mask_planar`` writes the
 transposition into the padded array in one pass, the eager unmask asks the
 pipeline whether it can stage before it relays the mask out, and a pipeline
-that cannot (one device) leaves the one relayout to ``unmask_limbs``."""
+that cannot (one device) leaves the one relayout to ``unmask_planar``.
+
+And the model's side (docs/DESIGN.md §16, "One layout to the decode, one
+buffer to the store"): every device arm hands the decode the planes it
+fetched, the float64 is written once and serialised once, and the store,
+the trust anchor and the broadcast hold what the decoder returned."""
 
 import asyncio
 from fractions import Fraction
@@ -64,10 +69,10 @@ def test_mask_planar_is_one_pass_and_equals_the_padded_transposition(n_limbs, le
     assert planar.tobytes() == expected.tobytes()
 
 
-def _staged_round(config, n: int, mesh, rng):
-    """``k`` masked updates staged on the device and the sum of their masks."""
+def _staged_round(config, n: int, mesh, rng, device: bool = True):
+    """``k`` masked updates staged (on the device) and the sum of their masks."""
     k = 3
-    agg = StagedAggregator(config.pair(), n, device=True, batch_size=2, kernel="xla", mesh=mesh)
+    agg = StagedAggregator(config.pair(), n, device=device, batch_size=2, kernel="xla", mesh=mesh)
     masks = Aggregation(config.pair(), n)
     weights = rng.uniform(-1, 1, size=(k, n)).astype(np.float32)
     for row in weights:
@@ -251,3 +256,168 @@ def test_a_served_round_on_one_device_observes_mask_put_once(
     assert relayouts == [(n, n_limbs)] and staged_jobs == []
     assert MASK_VOTES.labels(route="kept").value - kept == n_sum
     np.testing.assert_allclose(model, mean, atol=1e-9)
+
+
+# --------------------------------------------------------------------------
+# one layout from every device arm to the decode, one buffer from the decode
+# to the store, the anchor and the broadcast (docs/DESIGN.md §16)
+# --------------------------------------------------------------------------
+
+# arm -> (device, devices of the mesh, the pipeline rides into Unmask open)
+ARMS = {
+    "host": (False, 0, False),
+    "one_device": (True, 1, True),
+    "mesh_drain_time": (True, None, False),
+    "mesh_eager": (True, None, True),
+}
+
+
+def _pass_bytes() -> dict:
+    return {key[0]: child.value for key, child in unmask_stages.MODEL_BYTES.children()}
+
+
+def _run_unmask_phase(arm: str, n_limbs: int, n: int, sound: bool = True):
+    """The Unmask phase itself over one arm's aggregation of the same three
+    updates: returns the phase, what the store and the anchor were handed,
+    the broadcast model and the bytes each host pass wrote."""
+    from test_resilience import _settings
+
+    from xaynet_tpu.server.coordinator import CoordinatorState
+    from xaynet_tpu.server.events import EventPublisher, PhaseName
+    from xaynet_tpu.server.phases.base import Shared
+    from xaynet_tpu.server.phases.unmask import Unmask
+    from xaynet_tpu.server.requests import RequestReceiver
+    from xaynet_tpu.storage.memory import (
+        InMemoryCoordinatorStorage, InMemoryModelStorage, NoOpTrustAnchor)
+    from xaynet_tpu.storage.traits import Store
+
+    device, n_devices, open_stream = ARMS[arm]
+    config = _config(n_limbs)
+    mesh = make_mesh(jax.devices()[:n_devices]) if device else None
+    agg, mask, mean = _staged_round(config, n, mesh, np.random.default_rng(11), device=device)
+    view = agg.finalize_inplace(defer_drain=open_stream)
+    assert isinstance(view, DeviceAggregation) == device
+
+    handed = {"store": [], "anchor": []}
+
+    class Models(InMemoryModelStorage):
+        async def set_global_model(self, round_id, round_seed, model_data):
+            handed["store"].append(model_data)
+            return await super().set_global_model(round_id, round_seed, model_data)
+
+    class Anchor(NoOpTrustAnchor):
+        async def publish_proof(self, model_data):
+            handed["anchor"].append(model_data)
+
+    settings = _settings(model_len=n)
+    settings.mask.group_type = config.group_type
+    settings.mask.bound_type = config.bound_type
+    settings.mask.model_type = config.model_type
+    state = CoordinatorState.from_settings(settings)
+    assert state.round_params.mask_config == config.pair()
+
+    async def run():
+        coord = InMemoryCoordinatorStorage()
+        pk = b"\x01" * 32
+        assert await coord.add_sum_participant(pk, b"e" * 32) is None
+        assert await coord.incr_mask_score(pk, mask) is None
+        models = Models()
+        events = EventPublisher(
+            round_id=0, keys=state.keys, params=state.round_params, phase=PhaseName.IDLE)
+        shared = Shared(
+            state=state, request_rx=RequestReceiver(), events=events,
+            store=Store(coord, models, Anchor()), settings=settings, metrics=None)
+        phase = Unmask(shared, view)
+        before = _pass_bytes()
+        await phase.process()
+        phase.broadcast()
+        after = _pass_bytes()
+        stored = await models.global_model(await coord.latest_global_model_id())
+        return phase, stored, events.model.get_latest().event.model, {
+            name: after.get(name, 0) - before.get(name, 0)
+            for name in ("transpose", "decode", "serialise")}
+
+    phase, stored, broadcast, passes = asyncio.run(run())
+    if sound:
+        np.testing.assert_allclose(phase.global_model, mean, atol=1e-9)
+    return phase, stored, handed, broadcast, passes
+
+
+@pytest.mark.parametrize("n_limbs,length", SHAPES)
+def test_every_arm_publishes_the_same_model_to_the_bit(n_limbs, length, monkeypatch):
+    n = LENGTHS[length]
+    taken = []
+    for owner, name in ((ShardedAggregator, "unmask_planar"), (ShardedAggregator, "_unmask_plan"),
+                        (StreamingAggregator, "finish_unmask")):
+        def spy(self, *args, _real=getattr(owner, name), _name=name):
+            taken.append(_name)
+            return _real(self, *args)
+
+        monkeypatch.setattr(owner, name, spy)
+    routes = {"host": [], "one_device": ["unmask_planar"],
+              "mesh_drain_time": ["unmask_planar", "_unmask_plan"], "mesh_eager": ["finish_unmask"]}
+    models = {}
+    for arm in ARMS:
+        del taken[:]
+        phase, stored, _handed, broadcast, _passes = _run_unmask_phase(arm, n_limbs, n)
+        assert taken == routes[arm], arm
+        assert phase.global_model.dtype == np.float64 and phase.global_model.shape == (n,)
+        assert broadcast is phase.global_model
+        models[arm] = stored
+    assert len(models["host"]) == 8 * n
+    for arm in ARMS:
+        assert models[arm] == models["host"], arm
+
+
+@pytest.mark.parametrize("arm", list(ARMS))
+@pytest.mark.parametrize("n_limbs", list(BOUNDS))
+def test_the_model_is_decoded_once_and_serialised_once(arm, n_limbs):
+    """Between the kernel's result and the end of the phase: one decode, no
+    transposition, one serialisation, and the store and the trust anchor are
+    handed the same ``bytes`` (``xaynet_unmask_model_bytes_total``)."""
+    n = LENGTHS["padded"]
+    phase, stored, handed, _broadcast, passes = _run_unmask_phase(arm, n_limbs, n)
+    assert passes == {"transpose": 0, "decode": 8 * n, "serialise": 8 * n}
+    assert len(handed["store"]) == len(handed["anchor"]) == 1
+    assert type(handed["store"][0]) is bytes and handed["anchor"][0] is handed["store"][0]
+    # a `bytes` is kept, not copied again, and it is the model's float64
+    assert stored is handed["store"][0]
+    assert stored == phase.global_model.tobytes()
+
+
+@pytest.mark.parametrize("arm", list(ARMS))
+def test_an_element_altered_where_it_is_decoded_is_stored_and_broadcast(arm, monkeypatch):
+    """What ``benchmark/tests/serve_broken.py`` does: the decoder is looked
+    up in its module when the phase runs, and the array it returns is the
+    one whose values are stored, proved and broadcast."""
+    from xaynet_tpu.core.mask import encode
+
+    n = LENGTHS["whole"]
+    sound_bytes = _run_unmask_phase(arm, 2, n)[1]
+    real_decode = encode.decode_vect_fast
+
+    def decode_altered(*args, **kwargs):
+        out = real_decode(*args, **kwargs)
+        out[len(out) // 2] += 2.0 ** -20
+        return out
+
+    monkeypatch.setattr(encode, "decode_vect_fast", decode_altered)
+    phase, stored, handed, broadcast, _passes = _run_unmask_phase(arm, 2, n, sound=False)
+    altered = np.frombuffer(stored, dtype=np.float64)
+    differing = np.flatnonzero(altered != np.frombuffer(sound_bytes, dtype=np.float64))
+    assert differing.tolist() == [n // 2]
+    assert handed["anchor"][0] is handed["store"][0] is stored
+    assert np.array_equal(np.asarray(broadcast), altered)
+
+
+@pytest.mark.parametrize("n", [0, 1, 1003, (1 << 19) - 1, 1 << 19, (1 << 19) + 4097, 3 * (1 << 19) + 7])
+def test_the_threaded_serialisation_is_numpys(n):
+    """``utils/native.py::tobytes``: under, at and over the size from which
+    the library's threads make the copy (4 MiB = 2^19 float64)."""
+    from xaynet_tpu.utils import native
+
+    model = np.random.default_rng(n).standard_normal(n)
+    data = native.tobytes(model)
+    assert type(data) is bytes and data == model.tobytes() and hash(data) == hash(model.tobytes())
+    # a view that is not contiguous takes numpy's copy
+    assert native.tobytes(model[::2]) == model[::2].tobytes()

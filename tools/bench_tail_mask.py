@@ -1,10 +1,12 @@
 """Host benchmark of what the round's tail does to the one aggregated mask
-between a ``pet-msg`` worker's parse and the subtract kernel.
+between a ``pet-msg`` worker's parse and the subtract kernel, and to the
+unmasked model between the kernel's result and the store.
 
-Beside ``tools/bench_compose.py``. One mask of ``--elements`` group elements
-at ``--bytes`` wire bytes each (25,557,032 at 7 or 10: the benchmark's 2 and
-3 limbs, 204 and 307 MB of limbs), and every vector-sized pass the tail made
-or makes over it, each timed alone on an idle host:
+Beside ``tools/bench_compose.py``. One mask of each of ``--shapes`` (group
+elements x wire bytes each; 25,557,032 at 7 and 10 and 6,603,710 at 6: the
+benchmark's vectors at 2 and 3 limbs, 204, 307 and 53 MB of limbs), and
+every vector-sized pass the tail made or makes over it, each timed alone on
+an idle host:
 
 - ``serialise_and_hash``: ``serialize_mask_object`` and the bytes hashed as a
   dictionary key: what ``incr_mask_score`` of the in-memory store cost a vote
@@ -26,10 +28,26 @@ or makes over it, each timed alone on an idle host:
   CPU devices, which 25,557,032 elements do not divide: one column of padding,
   the two-pass case.
 
-A width is run in a child process of its own, on the CPU backend. No chip: a
-host number, and quoted as one (PERF.md section 6, PR 36).
+Then the model's side, on the limbs as the subtract kernel leaves them
+(planes ``uint32[L, n]``), since PR 44:
 
-Run:  python tools/bench_tail_mask.py [--bytes 7,10] [--elements 25557032]
+- ``transpose_to_wire``: planes to wire rows, the strided pass the phase's
+  ``fetch`` made for the decode until PR 44 (``PlanarLimbs.wire`` now, and
+  only for a caller that asks);
+- ``decode_wire_one_thread``: ``decode_vect_fast`` over wire rows in a child
+  with ``XAYNET_NATIVE_THREADS=1``: the decode as it stood until PR 44;
+- ``decode_planes``: ``decode_vect_fast`` over the planes, on the library's
+  threads (``cores``: how many the process may run on);
+- ``tobytes``: ``model.tobytes()``, the serialisation the phase made twice
+  until PR 44;
+- ``tobytes_threads``: ``utils/native.py::tobytes``, the same bytes copied
+  on the library's threads into an uninitialised ``bytes``: the one
+  serialisation the phase makes since.
+
+A shape is run in a child process of its own, on the CPU backend. No chip: a
+host number, and quoted as one (PERF.md section 6, PR 36 and PR 44).
+
+Run:  python tools/bench_tail_mask.py [--shapes 25557032x7,25557032x10,6603710x6]
           [--repeat 3] [--root /path/to/another/checkout]
 """
 
@@ -52,7 +70,8 @@ def _mask(elements: int, bpn: int):
     from xaynet_tpu.core.mask.object import MaskObject, MaskUnit, MaskVect
     from xaynet_tpu.ops import limbs as limb_ops
 
-    # the first f32 mask of the catalogue at that width: B0/M6 at 7, B0/M12 at 10
+    # the first f32 mask of the catalogue at that width: B0/M3 at 6, B0/M6 at
+    # 7, B0/M12 at 10
     config = next(
         c
         for c in (
@@ -96,6 +115,34 @@ def _store_votes(memory, mask, twin) -> dict[str, float]:
     return asyncio.run(run())
 
 
+def _model_passes(mask, one_thread: bool) -> dict[str, float]:
+    """The passes over the unmasked model: the mask's own limbs stand in for
+    the kernel's result (uniform elements under the order)."""
+    from fractions import Fraction
+
+    import numpy as np
+
+    from xaynet_tpu.core.mask.encode import decode_vect_fast
+
+    config, wire = mask.vect.config, mask.vect.data
+    if one_thread:
+        seconds, _ = _timed(lambda: decode_vect_fast(wire, config, 12, Fraction(3, 4)))
+        return {"decode_wire_one_thread": seconds}
+    steps: dict[str, float] = {}
+    planes = np.ascontiguousarray(wire.T)
+    steps["transpose_to_wire"], _ = _timed(lambda: np.ascontiguousarray(planes.T))
+    try:
+        from xaynet_tpu.ops.limbs import PlanarLimbs
+        from xaynet_tpu.utils import native
+    except ImportError:  # a checkout from before PR 44 (--root): rows only
+        return steps
+    steps["decode_planes"], model = _timed(
+        lambda: decode_vect_fast(PlanarLimbs(planes, len(wire)), config, 12, Fraction(3, 4)))
+    steps["tobytes"], _ = _timed(model.tobytes)
+    steps["tobytes_threads"], _ = _timed(lambda: native.tobytes(model))
+    return steps
+
+
 def _case(elements: int, bpn: int, repeat: int) -> dict:
     import jax
     import numpy as np
@@ -109,6 +156,16 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
 
     native.load()  # built on first use: not the mask's cost
     mask = _mask(elements, bpn)
+    result = {
+        "bytes_per_number": bpn, "n_limbs": int(mask.vect.data.shape[1]), "elements": elements,
+        "limb_bytes": int(mask.vect.data.nbytes), "unit": "ms",
+        "cores": len(os.sched_getaffinity(0)),
+    }
+    in_ms = lambda steps: {k: round(v * 1000.0, 1) for k, v in steps.items()}  # noqa: E731
+    if os.environ.get("XAYNET_NATIVE_THREADS") == "1":
+        # the child that times the decode as it stood, on one library thread
+        result["runs"] = [in_ms(_model_passes(mask, True)) for _ in range(repeat)]
+        return result
     twin = MaskObject(
         MaskVect(mask.vect.config, mask.vect.data.copy()),
         MaskUnit(mask.unit.config, mask.unit.data.copy()),
@@ -138,11 +195,10 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
             steps["planar" + suffix], one = _timed(lambda: agg.mask_planar(mask.vect.data))
             assert np.array_equal(one, two)
             del one, two
-        runs.append({k: round(v * 1000.0, 1) for k, v in steps.items()})
-    return {
-        "bytes_per_number": bpn, "n_limbs": int(mask.vect.data.shape[1]), "elements": elements,
-        "limb_bytes": int(mask.vect.data.nbytes), "unit": "ms", "runs": runs,
-    }
+        steps.update(_model_passes(mask, False))
+        runs.append(in_ms(steps))
+    result["runs"] = runs
+    return result
 
 
 def _planar_parent(agg, mask_vect):
@@ -168,30 +224,40 @@ def _sha256(mask) -> bytes:
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--bytes", default="7,10", help="wire bytes an element, comma-separated")
-    ap.add_argument("--elements", type=int, default=25_557_032)
+    ap.add_argument("--shapes", default="25557032x7,25557032x10,6603710x6",
+                    help="elements x wire bytes an element, comma-separated")
     ap.add_argument("--repeat", type=int, default=3)
     ap.add_argument("--root", default=None, help="another checkout to import xaynet_tpu from")
-    ap.add_argument("--case", default=None, help=argparse.SUPPRESS)  # a child's one width
+    ap.add_argument("--case", default=None, help=argparse.SUPPRESS)  # a child's one shape
     args = ap.parse_args()
     root = os.path.abspath(args.root or os.path.join(os.path.dirname(__file__), ".."))
     if args.case:
         sys.path.insert(0, root)
-        print(json.dumps(_case(args.elements, int(args.case), args.repeat)))
+        elements, bpn = args.case.split("x")
+        print(json.dumps(_case(int(elements), int(bpn), args.repeat)))
         return
-    for bpn in args.bytes.split(","):
-        out = subprocess.run(
-            [sys.executable, os.path.abspath(__file__), "--case", bpn,
-             "--elements", str(args.elements), "--repeat", str(args.repeat), "--root", root],
-            capture_output=True, text=True,
-            env={**os.environ, "JAX_PLATFORMS": "cpu",
-                 "XLA_FLAGS": "--xla_force_host_platform_device_count=3"},
-        )
-        line = out.stdout.strip().splitlines()[-1] if out.stdout.strip() else ""
-        if out.returncode != 0 or not line.startswith("{"):
-            print(json.dumps({"bytes_per_number": int(bpn), "error": out.stderr[-800:]}), flush=True)
-            continue
-        result = json.loads(line)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=3"}
+    env.pop("XAYNET_NATIVE_THREADS", None)
+    for shape in args.shapes.split(","):
+        result = None
+        # the shape with the library's threads, then its decode with one
+        for threads in ({}, {"XAYNET_NATIVE_THREADS": "1"}):
+            out = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--case", shape,
+                 "--repeat", str(args.repeat), "--root", root],
+                capture_output=True, text=True, env={**env, **threads},
+            )
+            line = out.stdout.strip().splitlines()[-1] if out.stdout.strip() else ""
+            if out.returncode != 0 or not line.startswith("{"):
+                result = {"shape": shape, "error": out.stderr[-800:]}
+                break
+            child = json.loads(line)
+            if result is None:
+                result = child
+            else:
+                for run, one in zip(result["runs"], child["runs"]):
+                    run.update(one)
         result["root"] = root
         print(json.dumps(result), flush=True)
 

@@ -36,6 +36,7 @@ import numpy as np
 from ...ops import dd
 from ...ops import limbs as limb_ops
 from ...telemetry import codec
+from ...telemetry import unmask as unmask_stages
 from .config import BoundType, DataType, MaskConfig
 
 # ---------------------------------------------------------------------------
@@ -130,12 +131,17 @@ def decode_vect_exact(
     return [(Fraction(v, e) - shift) / scalar_sum for v in values]
 
 
-def _decode_native(limbs: np.ndarray, c_int: int, recip: Fraction):
-    """Native double-double decode; None when unavailable/out of range."""
+def _decode_native(limbs, c_int: int, recip: Fraction):
+    """Native double-double decode of wire rows or of fetched planes, read
+    in place; None when unavailable/out of range."""
     from ...utils import native
 
     lib = native.load()
-    n, n_limb = limbs.shape
+    if isinstance(limbs, limb_ops.PlanarLimbs):
+        n, (n_limb, plane_stride) = limbs.length, limbs.planes.shape
+        limbs = limbs.planes
+    else:
+        (n, n_limb), plane_stride = limbs.shape, 0
     if (
         lib is None
         or not hasattr(lib, "xn_decode_f64")
@@ -154,6 +160,7 @@ def _decode_native(limbs: np.ndarray, c_int: int, recip: Fraction):
         native.np_u32p(arr),
         n,
         n_limb,
+        plane_stride,
         native.as_u8p(c_le),
         len(c_le),
         ctypes.c_double(inv_hi),
@@ -177,7 +184,10 @@ def decode_vect_any(
     protocol tolerance and the float64 output rounding that follows
     (reference: rust/xaynet-core/src/mask/masking.rs:190-231).
     """
+    if isinstance(limbs, limb_ops.PlanarLimbs):
+        limbs = limbs.wire()  # no cell: this family's kernels read wire rows
     n, n_limb = limbs.shape
+    unmask_stages.count_pass("decode", 8 * n)
     c_int = nb_models * int(config.add_shift) * config.exp_shift
     recip = Fraction(1, 1) / (config.exp_shift * scalar_sum)
     c_nlimbs = max(1, (c_int.bit_length() + 31) // 32)
@@ -248,9 +258,15 @@ def decode_vect_any(
 
 
 def decode_vect_fast(
-    limbs: np.ndarray, config: MaskConfig, nb_models: int, scalar_sum: Fraction
+    limbs, config: MaskConfig, nb_models: int, scalar_sum: Fraction
 ) -> np.ndarray:
     """Vectorized double-double decode -> float64 array (f32-accurate+).
+
+    ``limbs`` is wire ``uint32[n, L]`` (the host arm) or the
+    :class:`~xaynet_tpu.ops.limbs.PlanarLimbs` a device arm fetched, whose
+    planes the native kernel reads where they lie. The array returned is
+    freshly written and the caller's: the served round stores and
+    broadcasts it as it is.
 
     Structured for memory-bandwidth: scaling by 2^32 is exact on both dd
     components (no renormalization pass), constants broadcast as scalars,
@@ -258,20 +274,24 @@ def decode_vect_fast(
     precomputed dd reciprocal (~1e-32 relative, far below tolerance).
     """
     assert has_fast_path(config)
-    n, n_limb = limbs.shape
+    planar = isinstance(limbs, limb_ops.PlanarLimbs)
+    n = limbs.length if planar else limbs.shape[0]
     c_int = nb_models * int(config.add_shift) * config.exp_shift
     recip = Fraction(1, 1) / (config.exp_shift * scalar_sum)
     native_out = _decode_native(limbs, c_int, recip)
     codec.count("decode", native_out is not None, n)
+    unmask_stages.count_pass("decode", 8 * n)
     if native_out is not None:
         return native_out
+    # limb j of every element, in either layout
+    cols = [plane[:n] for plane in limbs.planes] if planar else list(limbs.T)
     # limbs -> double-double value (high to low; power-of-two scaling exact)
-    hi = limbs[:, n_limb - 1].astype(np.float64)
+    hi = cols[-1].astype(np.float64)
     lo = np.zeros(n)
-    for j in range(n_limb - 2, -1, -1):
+    for col in cols[-2::-1]:
         hi = hi * 4294967296.0
         lo = lo * 4294967296.0
-        hi, lo = dd.add_f(hi, lo, limbs[:, j].astype(np.float64))
+        hi, lo = dd.add_f(hi, lo, col.astype(np.float64))
     # subtract nb_models * A * E (exact integer; scalar dd constant)
     c_hi, c_lo = dd.from_fraction(nb_models * int(config.add_shift) * config.exp_shift)
     hi, lo = dd.add(hi, lo, -c_hi, -c_lo)

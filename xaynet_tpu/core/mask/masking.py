@@ -287,9 +287,11 @@ class Aggregation:
 
     # --- unmasking (reference: masking.rs:190-231) ------------------------
 
-    def _unmasked_vect(self, mask_obj: MaskObject) -> np.ndarray:
+    def _unmasked_vect(self, mask_obj: MaskObject) -> "np.ndarray | limb_ops.PlanarLimbs":
         # the host arm's `subtract` stage of the Unmask phase
-        # (telemetry/unmask.py); the device arm brackets its own three
+        # (telemetry/unmask.py), on wire rows; the device arms
+        # (server/aggregation.py) bracket their own three and hand over the
+        # planes they fetched: the decoders are told which by the type
         with unmask_stages.stage("subtract", bytes=mask_obj.vect.data.nbytes):
             return limb_ops.mod_sub(
                 self.object.vect.data, mask_obj.vect.data, _order_limbs(self.config.vect)
@@ -315,6 +317,8 @@ class Aggregation:
         config = self.config
         n_vect, n_unit = self._unmasked_limbs(mask_obj)
         scalar_sum = decode_scalar_sum(n_unit, config.unit, self.nb_models)
+        if isinstance(n_vect, limb_ops.PlanarLimbs):
+            n_vect = n_vect.wire()
         values = limb_ops.limbs_to_ints(n_vect)
         return Model(decode_vect_exact(values, config.vect, self.nb_models, scalar_sum))
 
@@ -329,6 +333,9 @@ class Aggregation:
             # the vector's decoders are looked up in their module when they
             # run, as the device arm always did: a launcher may stand in for
             # one (benchmark/tests/serve_broken.py alters the answer there)
-            if has_fast_path(config.vect):
-                return _encode.decode_vect_fast(n_vect, config.vect, self.nb_models, scalar_sum)
-            return _encode.decode_vect_any(n_vect, config.vect, self.nb_models, scalar_sum)
+            fast = has_fast_path(config.vect)
+            decode = _encode.decode_vect_fast if fast else _encode.decode_vect_any
+            model = decode(n_vect, config.vect, self.nb_models, scalar_sum)
+            # the limbs go back inside the stage that read them
+            del n_vect
+        return model

@@ -18,6 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..telemetry import codec
+from ..telemetry import unmask as unmask_stages
 
 _U32 = np.uint32
 _U64 = np.uint64
@@ -128,6 +129,33 @@ def ints_to_limbs(values, n_limbs: int) -> np.ndarray:
         if v >> (32 * n_limbs):
             raise OverflowError("value does not fit in the limb width")
     return out
+
+
+class PlanarLimbs:
+    """Unmasked group elements as a device arm fetched them: ``planes`` is
+    ``uint32[L, stride]``, limb ``j`` of element ``i`` at ``planes[j, i]``,
+    and the first ``length`` columns are the model (``stride`` is the padded
+    length on one device). The unmask decode reads the planes in place
+    (``core/mask/encode.py``); :meth:`wire` is for a caller that wants the
+    wire layout and pays the transposition for it. A type of its own and
+    not a bare array: a vector of 1-4 elements has planes and wire rows of
+    the same shape."""
+
+    __slots__ = ("planes", "length")
+
+    def __init__(self, planes: np.ndarray, length: int):
+        assert planes.ndim == 2 and planes.dtype == _U32 and planes.shape[1] >= length
+        self.planes = planes
+        self.length = length
+
+    @property
+    def nbytes(self) -> int:
+        return self.planes.shape[0] * self.length * 4
+
+    def wire(self) -> np.ndarray:
+        """The wire layout ``uint32[length, L]``: one strided pass."""
+        unmask_stages.count_pass("transpose", self.nbytes)
+        return np.ascontiguousarray(self.planes[:, : self.length].T)
 
 
 def limbs_to_ints(arr: np.ndarray) -> list[int]:

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.mask.config import MaskConfig
 from ..ops import limbs as host_limbs
+from ..ops.limbs import PlanarLimbs
 from ..ops.fold_jax import (
     MAX_LAZY_BATCH,
     fold_packed_batch,
@@ -829,7 +830,14 @@ class ShardedAggregator:
         return planar
 
     def unmask_limbs(self, mask_vect) -> np.ndarray:
-        """Subtract the aggregated mask; returns host wire ``uint32[model_len, L]``."""
+        """Subtract the aggregated mask; returns host wire ``uint32[model_len, L]``
+        (:meth:`unmask_planar` and the transposition a wire caller asks for)."""
+        return self.unmask_planar(mask_vect).wire()
+
+    def unmask_planar(self, mask_vect) -> PlanarLimbs:
+        """Subtract the aggregated mask and fetch the result as it lies on
+        the device: host planes ``uint32[L, stride]``, the layout every
+        device arm hands to the decode (docs/DESIGN.md §16)."""
         if self._live_plan is not None:
             # reduce-scatter unmask: each shard subtracts ITS slice of the
             # mask against its own accumulator buffer — the aggregate is
@@ -857,16 +865,24 @@ class ShardedAggregator:
                     lambda: _unmask_kernel(self.acc, mask_dev, self.order),
                 )
             )
+        # device to host, the copy alone: the padded planes as they are
         with unmask_stages.stage("fetch", bytes=self.model_length * self.n_limbs * 4):
-            return np.ascontiguousarray(np.asarray(out)[:, : self.model_length].T)
+            return PlanarLimbs(np.asarray(out), self.model_length)
 
-    def unmask_shard(self, plan, d: int, mask_planar: np.ndarray, out: np.ndarray) -> None:
+    def unmask_out(self) -> PlanarLimbs:
+        """The host planes the mesh arms assemble their shards' unmasked
+        slices in (a contiguous copy a limb plane each)."""
+        return PlanarLimbs(
+            np.empty((self.n_limbs, self.model_length), dtype=np.uint32), self.model_length
+        )
+
+    def unmask_shard(self, plan, d: int, mask_planar: np.ndarray, out: PlanarLimbs) -> None:
         """One shard's leg of the reduce-scatter unmask: subtract shard
         ``d``'s slice of the aggregated mask against its own accumulator
-        buffer and write the unmasked wire slice into ``out``. Shared by
+        buffer and write the unmasked planar slice into ``out``. Shared by
         the drain-time ``_unmask_plan`` pass and the eager per-shard
         unmask tail jobs (docs/DESIGN.md §22), which run it concurrently
-        from the shard workers — distinct ``out`` row ranges per shard,
+        from the shard workers — distinct ``out`` column ranges per shard,
         no synchronization needed."""
         lo, hi = plan.slices[d]
         real_hi = min(hi, self.model_length)
@@ -879,15 +895,15 @@ class ShardedAggregator:
         # deliberate barrier: the unmasked slice is this shard's FINAL device
         # read of the round — the eager tail job (or the drain pass) fetches
         # it here so Unmask never touches the device again  # lint: sync-ok
-        out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T  # lint: sync-ok
+        out.planes[:, lo:real_hi] = np.asarray(res)[:, : real_hi - lo]  # lint: sync-ok
 
-    def _unmask_plan(self, plan, mask_vect) -> np.ndarray:
+    def _unmask_plan(self, plan, mask_vect) -> PlanarLimbs:
         """Per-shard in-place unmask against a live reduce-scatter plan:
         one subtract per device (all in flight before the first fetch) —
-        only the UNMASKED per-shard slices move, once, into the host wire
-        result."""
-        out = np.empty((self.model_length, self.n_limbs), dtype=np.uint32)
-        # the stages of the one-device arm (``unmask_limbs``), a shard each:
+        only the UNMASKED per-shard slices move, once, into the host
+        planes."""
+        out = self.unmask_out()
+        # the stages of the one-device arm (``unmask_planar``), a shard each:
         # every slice is put before any subtract is dispatched, and every
         # subtract dispatched before any result is fetched, so the
         # per-device transfers and kernels overlap within their stage
@@ -906,9 +922,9 @@ class ShardedAggregator:
             for (lo, hi), res in zip(plan.slices, results):
                 real_hi = min(hi, self.model_length)
                 if lo < real_hi:
-                    out[lo:real_hi] = np.asarray(res)[:, : real_hi - lo].T
+                    out.planes[:, lo:real_hi] = np.asarray(res)[:, : real_hi - lo]
             BYTES_REDUCED.labels(path="gather").inc(out.nbytes)
-            return np.ascontiguousarray(out)
+            return out
 
     def snapshot(self) -> np.ndarray:
         """Host wire-layout copy of the aggregate (checkpoints / tests)."""

@@ -82,6 +82,7 @@ from contextlib import contextmanager, nullcontext
 import numpy as np
 
 from ..ops.fold_jax import MAX_LAZY_BATCH
+from ..ops.limbs import PlanarLimbs
 from ..resilience.faults import maybe_fail
 from ..telemetry import profiling
 from ..telemetry import tracing as trace
@@ -295,8 +296,8 @@ class _UnmaskJob:
     (docs/DESIGN.md §22): each shard worker subtracts ITS mask slice
     against its own accumulator buffer as soon as the shard's last queued
     fold commits (queue FIFO is the ordering guarantee — the unmask item
-    sits behind every fold item of the round). Workers write disjoint row
-    ranges of ``out``; ``error`` is first-failure sticky and the caller
+    sits behind every fold item of the round). Workers write disjoint column
+    ranges of ``out``'s planes; ``error`` is first-failure sticky and the caller
     falls back to the drain-time unmask pass (the subtract is functional —
     a failed shard leaves its accumulator untouched)."""
 
@@ -1859,9 +1860,7 @@ class StreamingAggregator:
         settles in :meth:`finish_unmask`."""
         if not self.can_stage_unmask():
             return None
-        agg = self.agg
-        out = np.empty((agg.model_length, agg.n_limbs), dtype=np.uint32)
-        job = _UnmaskJob(mask_planar, out, self._n_shards)
+        job = _UnmaskJob(mask_planar, self.agg.unmask_out(), self._n_shards)
         self._ensure_shard_workers()
         for d, q in enumerate(self._shard_queues):
             q.put((job, d))
@@ -1909,10 +1908,10 @@ class StreamingAggregator:
             if last:
                 job.done.set()
 
-    def finish_unmask(self, job: "_UnmaskJob") -> np.ndarray | None:
+    def finish_unmask(self, job: "_UnmaskJob") -> PlanarLimbs | None:
         """Settle an eager unmask: wait for every shard's tail job (most
         of the work has already run, hidden behind the fold/drain wall),
-        then hand back the assembled host wire result — or ``None`` if any
+        then hand back the assembled host planes — or ``None`` if any
         shard failed (caller falls back to the drain-time pass). Records
         the same ``unmask`` kernel op and gather accounting as the
         drain-time pass — what shrinks is the measured wall, which is
@@ -1930,6 +1929,6 @@ class StreamingAggregator:
                 )
                 return None
             BYTES_REDUCED.labels(path="gather").inc(job.out.nbytes)
-            return np.ascontiguousarray(job.out)
+            return job.out
 
         return profiling.timed_kernel("unmask", self.agg.padded_length, settle)

@@ -30,6 +30,7 @@ from ..core.mask.config import MaskConfigPair
 from ..core.mask.masking import Aggregation, AggregationError, UnmaskingError
 from ..core.mask.object import LazyWireMaskVect, MaskObject, MaskUnit, MaskVect
 from ..ops import limbs as limb_ops
+from ..ops.limbs import PlanarLimbs
 from ..resilience.checkpoint import AggSnapshot
 from ..telemetry import profiling
 from ..telemetry import tracing as trace
@@ -66,7 +67,7 @@ class DeviceAggregation(Aggregation):
     whole mesh accumulator into one wire-layout host array before the
     Unmask phase has even subtracted the mask. This view keeps the
     accumulator where it is: ``unmask_array``/``unmask`` subtract the
-    elected mask per-shard in place (``ShardedAggregator.unmask_limbs`` —
+    elected mask per-shard in place (``ShardedAggregator.unmask_planar`` —
     each mesh device subtracts its own model-axis slice), and only the
     *unmasked* result crosses to the host for the fixed-point decode.
     Validation and the tiny unit channel need no accumulator read at all;
@@ -134,7 +135,7 @@ class DeviceAggregation(Aggregation):
             stream.close()
             self._nb_models = self._device.nb_models
 
-    def _eager_unmask(self, mask_obj: MaskObject) -> np.ndarray | None:
+    def _eager_unmask(self, mask_obj: MaskObject) -> PlanarLimbs | None:
         """Eager per-shard unmask (docs/DESIGN.md §22): the mask subtract
         is staged as per-shard tail jobs BEHIND the round's last fold
         batches, so each shard unmasks the moment its own last fold
@@ -168,15 +169,16 @@ class DeviceAggregation(Aggregation):
     # ``unmask`` and ``unmask_array`` are the base's: they read the carried
     # config pair (``self.config``) and these two, never ``self.object``
 
-    def _unmasked_vect(self, mask_obj: MaskObject) -> np.ndarray:
+    def _unmasked_vect(self, mask_obj: MaskObject) -> PlanarLimbs:
         # mask_put, subtract and fetch are bracketed where they run
-        # (ShardedAggregator.unmask_limbs, or the eager arm above).
+        # (ShardedAggregator.unmask_planar, or the eager arm above).
         # per-shard in-place subtract: the mask planes upload with the
         # accumulator's sharding and each device subtracts its own slice;
-        # the gather happens AFTER the subtraction, on the unmasked result
+        # the gather happens AFTER the subtraction, on the unmasked result,
+        # which every arm hands over as the planes it fetched
         n_vect = self._eager_unmask(mask_obj) if self._stream is not None else None
         if n_vect is None:
-            n_vect = self._device.unmask_limbs(mask_obj.vect.data)
+            n_vect = self._device.unmask_planar(mask_obj.vect.data)
         return n_vect
 
     def _unmasked_unit(self, mask_obj: MaskObject) -> int:

@@ -11,9 +11,12 @@ The unmask subtract runs on the vectorized limb kernels. Device rounds
 arrive as a ``DeviceAggregation`` view (``aggregation.finalize_inplace``):
 the subtract runs per-shard against the still-sharded accumulator — each
 mesh device unmasks its own model-axis slice, the aggregate is never
-gathered before subtraction, and the host ``mod_sub`` only runs when a
-native fold left the accumulator host-resident. The fixed-point decode
-uses the double-double fast path for f32 configs (core/mask/encode.py).
+gathered before subtraction, and the host ``mod_sub`` runs for a host
+round alone. The fixed-point decode uses the double-double fast path for
+f32 configs (core/mask/encode.py): it reads the planes a device arm
+fetched where they lie, on the native library's threads, and the float64
+it returns is ``global_model``: serialised once, and those bytes handed to
+the store and to the trust anchor (docs/DESIGN.md §16).
 """
 
 from __future__ import annotations
@@ -29,6 +32,7 @@ from ...resilience.chaos import maybe_kill
 from ...telemetry import profiling
 from ...telemetry import unmask as unmask_stages
 from ...telemetry.registry import get_registry
+from ...utils import native
 from ..events import ModelUpdate, PhaseName
 from .base import PhaseError, PhaseState
 
@@ -48,6 +52,9 @@ class Unmask(PhaseState):
         super().__init__(shared)
         self.model_agg = model_agg
         self.global_model: np.ndarray | None = None
+        # the model's float64 bytes: made once, in `save`, and handed to the
+        # store and to the trust anchor as the same object
+        self._model_bytes: bytes | None = None
 
     async def process(self) -> None:
         # the phase, stage by stage (telemetry/unmask.py): the brackets here,
@@ -126,13 +133,20 @@ class Unmask(PhaseState):
             raise PhaseError("AmbiguousMasks", "top masks share the same score")
         return winner
 
+    def _serialised_model(self) -> bytes:
+        """The phase's one serialisation of the decoded model (a ``bytes``,
+        which a store that keeps it does not copy again)."""
+        if self._model_bytes is None:
+            assert self.global_model is not None
+            self._model_bytes = native.tobytes(np.asarray(self.global_model, dtype=np.float64))
+            unmask_stages.count_pass("serialise", len(self._model_bytes))
+        return self._model_bytes
+
     async def _save_global_model(self) -> None:
-        assert self.global_model is not None
-        data = np.asarray(self.global_model, dtype=np.float64).tobytes()
         model_id = await self.shared.store.models.set_global_model(
             self.shared.state.round_id,
             self.shared.state.round_params.seed.as_bytes(),
-            data,
+            self._serialised_model(),
         )
         # best-effort per the reference (unmask.rs:191-198) — the retry
         # itself lives in the ResilientStore layer every storage call flows
@@ -151,6 +165,4 @@ class Unmask(PhaseState):
             logger.warning("failed to update latest global model id: %s", err)
 
     async def _publish_proof(self) -> None:
-        assert self.global_model is not None
-        data = np.asarray(self.global_model, dtype=np.float64).tobytes()
-        await self.shared.store.trust_anchor.publish_proof(data)
+        await self.shared.store.trust_anchor.publish_proof(self._serialised_model())

@@ -14,16 +14,28 @@ as a span under ``phase.unmask`` and as one observation on
   its ``device_put`` until the array is ready (``parallel/aggregator.py``);
 - ``subtract``: the subtract kernel until its result is ready (on the host
   arm ``mod_sub`` over the vector, ``core/mask/masking.py``);
-- ``fetch``: device to host and the transposition to the wire layout;
-- ``decode``: the unit's ``mod_sub``, ``decode_scalar_sum``, ``decode_vect_*``;
-- ``save``: the float64 bytes, the model store, the latest-model pointer;
-- ``proof`` and ``retire``: the trust anchor's proof and the journal's
-  retire, where they run.
+- ``fetch``: device to host, the copy alone: every device arm hands
+  ``decode`` the planar array it fetched (``ops/limbs.py::PlanarLimbs``);
+- ``decode``: the unit's ``mod_sub``, ``decode_scalar_sum``, ``decode_vect_*``
+  (planes read in place, the element axis on the native library's threads);
+- ``save``: the float64 bytes (the phase's one serialisation), the model
+  store, the latest-model pointer;
+- ``proof`` and ``retire``: the trust anchor's proof (the bytes ``save``
+  made) and the journal's retire, where they run.
 
 The eager per-shard unmask (docs/DESIGN.md §22) does the device's part on
 the shard workers (``overlap.eager_unmask``); what the phase's task does
 meanwhile carries the same names: ``mask_put`` is the relayout, ``subtract``
 the wait for the shards' tail jobs, ``fetch`` the assembled result.
+
+Between the kernel's result and the end of the phase the model should be
+walked once and serialised at most once. ``xaynet_unmask_model_bytes_total
+{pass}`` counts the bytes each vector-sized host pass writes, where the
+pass runs: ``transpose`` (planar to wire, ``PlanarLimbs.wire``: only a
+caller that asks for wire pays it), ``decode`` (``decode_vect_*``: the
+float64 written) and ``serialise`` (the phase's ``tobytes()``). All passes
+over ``decode`` is how many times the model was written: 2.0 for a served
+round (PERF.md section 3, ``unmask.host_passes``).
 """
 
 from __future__ import annotations
@@ -42,6 +54,15 @@ SECONDS = get_registry().histogram(
              0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
 )
 
+MODEL_BYTES = get_registry().counter(
+    "xaynet_unmask_model_bytes_total",
+    "Bytes written by each vector-sized host pass between the subtract "
+    "kernel's result and the end of the Unmask phase, by pass: transpose "
+    "(planar to wire), decode (the float64), serialise (its bytes) "
+    "(telemetry/unmask.py).",
+    ("pass",),
+)
+
 # stage label -> span name; spelled out (not built in a loop) so the
 # analysis `span` pass reads the literal set against the DESIGN §16 table.
 # Every stage starts and ends on the state machine's thread: all mirrored.
@@ -56,6 +77,11 @@ _SPANS: dict[str, str] = {
     "proof": trace.declare_span("unmask.proof", mirror=True),
     "retire": trace.declare_span("unmask.retire", mirror=True),
 }
+
+
+def count_pass(name: str, nbytes: int) -> None:
+    """One vector-sized host pass wrote ``nbytes`` (counted where it runs)."""
+    MODEL_BYTES.labels(**{"pass": name}).inc(nbytes)
 
 
 def stage(label: str, **attrs):
