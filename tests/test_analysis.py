@@ -983,8 +983,8 @@ def test_tenant_pass_lease_site_whitelist(tmp_path):
     # the whitelist covers the real sites (file + qualname exact)
     graph = _graph(
         tmp_path,
-        {"xaynet_tpu/parallel/shards.py":
-         "class ShardPlan:\n    def _alloc(self, pool):\n"
+        {"xaynet_tpu/parallel/streaming.py":
+         "class _StagingRing:\n    def _grow(self, pool):\n"
          "        return pool.lease_host(self.tenant, (4, 4), 'uint32')\n"},
     )
     assert tenantscope.run(graph) == []

@@ -394,13 +394,3 @@ def test_sharded_aggregator_wire_ingest_fused(monkeypatch):
         np.stack([m.vect.data for _, m in raws[:3]]), np.zeros((3, unit_l), dtype=np.uint32)
     )
     assert np.array_equal(dev.snapshot(), host.object.vect.data)
-
-
-def test_multihost_initialize_noop_and_mesh():
-    """Single-process: initialize is a no-op and the global mesh spans all
-    devices (the 2-process path is covered by tests/test_multihost.py)."""
-    from xaynet_tpu.parallel import multihost
-
-    multihost.initialize()  # no-op without num_processes
-    mesh = multihost.global_mesh()
-    assert mesh.devices.size >= 1

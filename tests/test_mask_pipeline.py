@@ -257,12 +257,14 @@ def test_finalize_inplace_device_view_unmasks_per_shard():
         empty.validate_unmasking(mask)
 
 
-def test_unmask_phase_uses_inplace_view_without_double_timing(monkeypatch):
-    """Sum2Phase hands Unmask the in-place view, and the phase does not
-    wrap the view's unmask in a second `unmask` kernel timer."""
+def test_sum2_hands_unmask_the_open_pipeline_and_the_unmask_settles_it():
+    """The shipped hand-off: ``Sum2Phase.next`` gives Unmask the in-place
+    view with the streaming pipeline still open (the drain barrier is the
+    unmask's), the view's unmask is exact, and it leaves the pipeline
+    drained, closed and the model count pinned."""
     import asyncio
 
-    from xaynet_tpu.server.aggregation import StagedAggregator
+    from xaynet_tpu.server.aggregation import DeviceAggregation, StagedAggregator
     from xaynet_tpu.server.phases.sum2 import Sum2Phase
 
     cfg = CONFIGS[0]
@@ -270,8 +272,10 @@ def test_unmask_phase_uses_inplace_view_without_double_timing(monkeypatch):
     dev = StagedAggregator(cfg.pair(), n, device=True, batch_size=2)
     mask_agg = Aggregation(cfg.pair(), n)
     rng = np.random.default_rng(3)
+    expected = np.zeros(n)
     for _ in range(k):
         w = rng.uniform(-1, 1, n).astype(np.float32)
+        expected += w.astype(np.float64) / k
         seed, masked = Masker(cfg.pair()).mask(Scalar(1, k), w)
         mask_agg.aggregate(MaskSeed(seed.as_bytes()).derive_mask(n, cfg.pair()))
         dev.aggregate(masked)
@@ -280,27 +284,14 @@ def test_unmask_phase_uses_inplace_view_without_double_timing(monkeypatch):
     phase.aggregator = dev
     phase._base = None  # no round journal: next() must skip the unmask entry
     phase._votes = []
+    phase.shared = object()  # next() reads no setting
 
-    class _Shared:
-        pass
-
-    phase.shared = _Shared()
-    # next() consults [overlap]: pin the serial path — this test asserts
-    # the drain-time in-place view contract, not the §22 eager engine
-    from xaynet_tpu.server.settings import OverlapSettings
-
-    class _SettingsStub:
-        overlap = OverlapSettings(enabled=False)
-
-    phase.shared.settings = _SettingsStub()
-
-    async def drive():
-        from xaynet_tpu.server.aggregation import DeviceAggregation
-
-        nxt = await Sum2Phase.next(phase)
-        assert isinstance(nxt.model_agg, DeviceAggregation)
-        return nxt.model_agg
-
-    view = asyncio.run(drive())
+    view = asyncio.run(Sum2Phase.next(phase)).model_agg
+    assert isinstance(view, DeviceAggregation)
+    # k = 3 at a batch of 2: one update is still staged when Sum2 ends, so
+    # the hand-off has to submit it and leave the barrier to the unmask
+    assert view._stream is dev._stream and not dev._stream._closed
     got = view.unmask_array(mask_agg.object)
-    assert got.shape == (n,)
+    np.testing.assert_allclose(got, expected, atol=1e-9)
+    assert view._stream is None and dev._stream._closed
+    assert view.nb_models == k

@@ -201,7 +201,7 @@ async def _served_round(settings: Settings, weights: list[np.ndarray], order: li
                 sent = sent or sm.phase is PhaseKind.UPDATE
         gap = ACCEPT_GAP_MAX.value
         # to the model's publication: the phase's last flush is drained under
-        # Sum2's window ([overlap] sum2_drain, as shipped)
+        # Sum2's window (docs/DESIGN.md §22)
         await sum_task
         moved = {key: value - before[key] for key, value in _pipeline_counters().items()}
         return {"model": np.asarray(fetcher.model(), dtype=np.float64), "moved": moved,

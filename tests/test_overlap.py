@@ -1,21 +1,19 @@
-"""Phase overlap & speculation (docs/DESIGN.md §22, ISSUE 18).
+"""The round's one shape (docs/DESIGN.md §22, ISSUE 18, ISSUE 45).
 
-The overlap engines shrink the round wall below the serial sum of phase
-walls; everything rests on **byte-identity with the serial path**. These
-tests pin:
+The Update phase's fold tail rides into Sum2 and each shard's subtract
+rides behind its own last fold, so the round wall comes in below the
+serial sum of phase walls; everything rests on **byte-identity with the
+drain-time pass**, and no setting chooses an arm. These tests pin:
 
-- speculative sum2 mask derivation (`ops.speculation`): hit / miss /
-  discard reconciliation byte-identical to `sum_masks`, including
-  mis-speculation (a speculated participant dropping before sum2),
-  across mesh={1,8} and the host/device derive routes;
 - eager per-shard unmask (`parallel.streaming._UnmaskJob`): identical to
   the drain-then-subtract serial pass on the native and XLA shard
   routes, correct fallback on a single-device mesh, and two tenants
   pipelined through the shared scheduler concurrently;
-- `TenantScheduler.try_acquire_idle`: never blocks, never starves a
-  real waiter, never distorts the fairness split;
-- the `[overlap]` settings surface (defaults, env override, master
-  gate);
+- what the phases observe: without a journal the drain ends inside
+  Sum2's window, with one it has ended before the window opens; a fold
+  error the drain raises fails the round at Sum2's exit;
+- a settings file (or environment) that still names the retired
+  `[overlap]` section and `[tenancy] device_pages` loads as one without;
 - persisted calibration verdicts (`utils.calibcache`): cold→warm
   round-trip, fingerprint invalidation, corrupt-file fail-soft, and a
   warm verdict short-circuiting the mask probe race;
@@ -28,7 +26,6 @@ import json
 import os
 import sys
 import threading
-import time
 from pathlib import Path
 
 import numpy as np
@@ -52,11 +49,10 @@ from xaynet_tpu.core.mask import (
 )
 from xaynet_tpu.ops import limbs as host_limbs
 from xaynet_tpu.ops import masking_jax
-from xaynet_tpu.ops.speculation import SpeculativeMaskSession
 from xaynet_tpu.parallel.aggregator import ShardedAggregator
 from xaynet_tpu.parallel.mesh import make_mesh
 from xaynet_tpu.parallel.streaming import StreamingAggregator
-from xaynet_tpu.server.settings import OverlapSettings, Settings
+from xaynet_tpu.server.settings import Settings
 from xaynet_tpu.tenancy.scheduler import TenantScheduler
 from xaynet_tpu.utils import calibcache
 
@@ -67,145 +63,6 @@ LEN = 257  # odd on purpose: uneven shard slices + a padded tail
 
 def _seeds(n, tag=0):
     return [bytes([i & 0xFF, i >> 8, tag]) + b"\x5a" * 29 for i in range(n)]
-
-
-def _settle_all(spec, n, deadline_s=60.0):
-    """Wait until the background worker folded all n offered seeds (the
-    deterministic all-hit setup; compile time makes a fixed sleep flaky)."""
-    t0 = time.monotonic()
-    while spec.speculated() < n:
-        if time.monotonic() - t0 > deadline_s:
-            pytest.fail(f"speculation folded {spec.speculated()}/{n} seeds")
-        time.sleep(0.01)
-
-
-# --- speculative mask derivation ------------------------------------------
-
-
-def test_speculation_all_hits_byte_identical():
-    seeds = _seeds(6)
-    unit_ref, vect_ref = masking_jax.sum_masks(seeds, LEN, CFG.pair())
-    spec = SpeculativeMaskSession(LEN, CFG.pair())
-    spec.offer(seeds)
-    _settle_all(spec, len(seeds))
-    unit, vect = spec.settle(seeds)
-    np.testing.assert_array_equal(np.asarray(vect), np.asarray(vect_ref))
-    np.testing.assert_array_equal(np.asarray(unit), np.asarray(unit_ref))
-
-
-def test_speculation_settle_without_worker_progress_is_serial():
-    # settle may run before the worker derives anything (or after it only
-    # got part way): every un-folded seed is a miss = the serial path
-    seeds = _seeds(5, tag=1)
-    unit_ref, vect_ref = masking_jax.sum_masks(seeds, LEN, CFG.pair())
-    spec = SpeculativeMaskSession(LEN, CFG.pair())
-    spec.offer(seeds)
-    unit, vect = spec.settle(seeds)  # immediately: any mix of hit/miss
-    np.testing.assert_array_equal(np.asarray(vect), np.asarray(vect_ref))
-    np.testing.assert_array_equal(np.asarray(unit), np.asarray(unit_ref))
-
-
-@pytest.mark.parametrize("kernel", ["host-threaded", "batch"])
-@pytest.mark.parametrize("mesh_devices", [1, 8])
-def test_misspeculation_discard_byte_identical(kernel, mesh_devices):
-    """PR-5 churn as mis-speculation: a speculated sum participant drops
-    before sum2 — its folded mask must be subtracted back out exactly, on
-    host and device derive routes, single-device and 8-device meshes."""
-    mesh = make_mesh(jax.devices()[:mesh_devices]) if mesh_devices > 1 else None
-    offered = _seeds(5, tag=2)
-    dropped = offered[2]
-    actual = [s for s in offered if s != dropped]  # + one never-offered miss
-    actual.append(_seeds(1, tag=3)[0])
-    unit_ref, vect_ref = masking_jax.sum_masks(
-        actual, LEN, CFG.pair(), kernel=kernel, mesh=mesh
-    )
-    spec = SpeculativeMaskSession(LEN, CFG.pair(), kernel=kernel, mesh=mesh)
-    spec.offer(offered)
-    _settle_all(spec, len(offered))  # the dropped seed IS folded -> discard
-    unit, vect = spec.settle(actual)
-    np.testing.assert_array_equal(np.asarray(vect), np.asarray(vect_ref))
-    np.testing.assert_array_equal(np.asarray(unit), np.asarray(unit_ref))
-
-
-def test_speculation_records_outcomes(monkeypatch):
-    from xaynet_tpu.telemetry import timeline
-
-    recorded = []
-    monkeypatch.setattr(
-        "xaynet_tpu.ops.speculation.record_spec_outcomes",
-        lambda hits=0, misses=0, discards=0: recorded.append(
-            (hits, misses, discards)
-        ),
-    )
-    offered = _seeds(4, tag=4)
-    actual = offered[:3] + _seeds(1, tag=5)
-    spec = SpeculativeMaskSession(LEN, CFG.pair())
-    spec.offer(offered)
-    _settle_all(spec, len(offered))
-    spec.settle(actual)
-    assert recorded == [(3, 1, 1)]
-    # and the real counter exists with the registered outcome labels
-    assert timeline.SPEC_DERIVE is not None
-
-
-def test_speculation_idle_slots_only():
-    """A busy scheduler (waiter pending) denies the worker; every seed
-    becomes a miss and settle still returns the exact aggregate."""
-    sched = TenantScheduler(max_inflight=1)
-    blocker = sched.new_owner()
-    sched.acquire("real", blocker)  # the mesh is busy for the whole test
-    try:
-        seeds = _seeds(4, tag=6)
-        unit_ref, vect_ref = masking_jax.sum_masks(seeds, LEN, CFG.pair())
-        spec = SpeculativeMaskSession(
-            LEN, CFG.pair(), tenant="spec", scheduler=sched
-        )
-        spec.offer(seeds)
-        time.sleep(0.2)  # give the worker a chance to (wrongly) grab a slot
-        assert spec.speculated() == 0
-        unit, vect = spec.settle(seeds)
-        np.testing.assert_array_equal(np.asarray(vect), np.asarray(vect_ref))
-        np.testing.assert_array_equal(np.asarray(unit), np.asarray(unit_ref))
-        assert "spec" not in sched.split()  # idle grants never charge fairness
-    finally:
-        sched.release_owner(blocker)
-
-
-# --- scheduler idle slots --------------------------------------------------
-
-
-def test_try_acquire_idle_semantics():
-    sched = TenantScheduler(max_inflight=2)
-    a, b, c = sched.new_owner(), sched.new_owner(), sched.new_owner()
-    # idle mesh: granted, but NOT charged to the fairness split
-    assert sched.try_acquire_idle("bg", a)
-    assert sched.split() == {}
-    # at capacity: denied
-    sched.acquire("fg", b)
-    assert not sched.try_acquire_idle("bg", a)
-    sched.release(a)
-    # capacity free but a regular waiter pending: denied (never starve)
-    waited = threading.Event()
-
-    def waiter():
-        sched.acquire("fg", c)
-        waited.set()
-
-    t = threading.Thread(target=waiter, daemon=True)
-    t.start()
-    deadline = time.monotonic() + 5.0
-    while not sched._waiting and not waited.is_set():
-        if time.monotonic() > deadline:
-            pytest.fail("waiter never queued")
-        time.sleep(0.005)
-    if not waited.is_set():
-        assert not sched.try_acquire_idle("bg", a)
-    sched.release(b)
-    t.join(timeout=5.0)
-    assert waited.is_set()
-    assert sched.split() == {"fg": 2}
-    sched.release_owner(c)
-    sched.release_owner(a)
 
 
 # --- eager per-shard unmask ------------------------------------------------
@@ -351,35 +208,26 @@ def test_two_tenant_pipelined_eager_unmask_byte_identical():
     assert split.get("a", 0) > 0 and split.get("b", 0) > 0
 
 
-# --- [overlap] settings ----------------------------------------------------
+# --- retired settings still load -------------------------------------------
 
 
-def test_overlap_settings_defaults_and_master_gate():
-    o = OverlapSettings()
-    assert o.enabled and o.spec_group == 8
-    for f in ("speculative_derive", "eager_unmask", "sum2_drain"):
-        assert o.feature(f)
-    o.enabled = False
-    for f in ("speculative_derive", "eager_unmask", "sum2_drain"):
-        assert not o.feature(f)
-    with pytest.raises(Exception):
-        OverlapSettings(spec_group=0).validate()
-
-
-def test_overlap_settings_config_and_env():
-    s = Settings.load(str(REPO / "configs" / "config.toml"))
-    assert s.overlap.enabled and s.overlap.eager_unmask
-    s2 = Settings.load(
-        str(REPO / "configs" / "config.toml"),
-        env={"XAYNET__OVERLAP__EAGER_UNMASK": "false"},
+def test_retired_overlap_and_device_pages_keys_load_as_if_absent(tmp_path):
+    """An operator's file written for an earlier release keeps loading:
+    `[overlap]` and `[tenancy] device_pages` are ignored, as every unknown
+    table and key is, and the round it configures is the shipped one."""
+    shipped = (REPO / "configs" / "config.toml").read_text()
+    assert "[overlap]" not in shipped and "device_pages" not in shipped
+    old = tmp_path / "old.toml"
+    old.write_text(
+        shipped.replace("[tenancy]\n", "[tenancy]\ndevice_pages = 8\n", 1)
+        + "\n[overlap]\nenabled = false\neager_unmask = false\nspec_group = 0\n"
     )
-    assert not s2.overlap.feature("eager_unmask")
-    assert s2.overlap.feature("sum2_drain")
-    s3 = Settings.load(
-        str(REPO / "configs" / "config.toml"),
-        env={"XAYNET__OVERLAP__ENABLED": "false"},
-    )
-    assert not s3.overlap.feature("sum2_drain")
+    assert "device_pages = 8" in old.read_text()
+    new = Settings.load(str(REPO / "configs" / "config.toml"), env={})
+    assert Settings.load(str(old), env={}) == new
+    env = {"XAYNET__OVERLAP__ENABLED": "false", "XAYNET__TENANCY__DEVICE_PAGES": "8"}
+    assert Settings.load(str(REPO / "configs" / "config.toml"), env=env) == new
+    assert not hasattr(new, "overlap") and not hasattr(new.tenancy, "device_pages")
 
 
 # --- persisted calibration verdicts ---------------------------------------
@@ -583,16 +431,20 @@ def test_trace_report_overlap_cli_on_exported_trace(tmp_path):
     assert trace_report.main(["--overlap", str(path)]) == 0
 
 
-# --- server round: the phase machine engages the overlap engines ----------
+# --- server round: what the phases observe decides where the drain ends ---
 
 
-@pytest.mark.parametrize("enabled", [True, False])
-def test_server_round_overlap_engines(enabled, monkeypatch):
-    """A real device-aggregation PET round end to end. With `[overlap]`
-    enabled (the default) the unmask phase must go through the eager
-    per-shard path (stage_unmask on the still-live stream) and the update
-    phase must exit via flush (the drain rides into sum2); disabled, the
-    round is fully serial — and both produce the exact mean."""
+@pytest.mark.parametrize("checkpoint_enabled", [False, True])
+def test_server_round_overlap_engines(checkpoint_enabled, monkeypatch):
+    """A real device-aggregation PET round end to end, and what selects
+    where its drain ends. The Update phase always leaves through flush
+    and Sum2 always starts the drain off the loop. Without a journal the
+    drain ends INSIDE Sum2's window: held open here until a vote is
+    acknowledged, it can only return if the window opened beside it. With
+    a journal it has ended before the window opens: held open the same
+    way, it sees no vote. Unmask goes through the eager per-shard path
+    (stage_unmask on the still-live stream), and both publish the exact
+    mean."""
     import asyncio
     from fractions import Fraction
 
@@ -604,6 +456,7 @@ def test_server_round_overlap_engines(enabled, monkeypatch):
     )
     from xaynet_tpu.sdk.traits import ModelStore
     from xaynet_tpu.server.aggregation import StagedAggregator
+    from xaynet_tpu.server.phases.sum2 import Sum2Phase
     from xaynet_tpu.server.services import Fetcher, PetMessageHandler
     from xaynet_tpu.server.settings import (
         CountSettings,
@@ -621,21 +474,46 @@ def test_server_round_overlap_engines(enabled, monkeypatch):
     )
     from xaynet_tpu.storage.traits import Store
 
-    staged, drained = [], []
+    staged, order, vote_seen_by_drain = [], [], []
+    first_vote = threading.Event()
     real_stage = StreamingAggregator.stage_unmask
+    real_flush = StagedAggregator.flush
     real_drain = StagedAggregator.drain
+    real_sum2_drain = Sum2Phase._drain_overlapped
+    real_vote = Sum2Phase.handle_request
 
     def stage_spy(self, mask_planar):
         job = real_stage(self, mask_planar)
         staged.append(job is not None)
         return job
 
+    def flush_spy(self):
+        order.append("flush")
+        return real_flush(self)
+
     def drain_spy(self):
-        drained.append(threading.current_thread().name)
+        order.append("drain")
         return real_drain(self)
 
+    def sum2_drain_spy(self):
+        order.append(("sum2_drain", threading.current_thread().name))
+        # hold the barrier open until a vote is acknowledged: at once where
+        # the window is open beside the drain, never where the phase waits
+        # for the drain first
+        vote_seen_by_drain.append(
+            first_vote.wait(timeout=1.0 if checkpoint_enabled else 60.0)
+        )
+        return real_sum2_drain(self)
+
+    async def vote_spy(self, req):
+        await real_vote(self, req)
+        first_vote.set()
+
     monkeypatch.setattr(StreamingAggregator, "stage_unmask", stage_spy)
+    monkeypatch.setattr(StagedAggregator, "flush", flush_spy)
     monkeypatch.setattr(StagedAggregator, "drain", drain_spy)
+    monkeypatch.setattr(Sum2Phase, "_drain_overlapped", sum2_drain_spy)
+    monkeypatch.setattr(Sum2Phase, "handle_request", vote_spy)
 
     class ArrayModelStore(ModelStore):
         def __init__(self, model):
@@ -669,7 +547,7 @@ def test_server_round_overlap_engines(enabled, monkeypatch):
         settings.aggregation.device = True
         settings.aggregation.batch_size = 2
         settings.aggregation.kernel = "xla"
-        settings.overlap.enabled = enabled
+        settings.resilience.checkpoint_enabled = checkpoint_enabled
         settings.validate()
         store = Store(
             InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor()
@@ -735,12 +613,54 @@ def test_server_round_overlap_engines(enabled, monkeypatch):
 
     got, expected = asyncio.run(asyncio.wait_for(run(), timeout=180))
     np.testing.assert_allclose(got, expected, atol=1e-9)
-    if enabled:
-        assert staged and staged[-1], "unmask did not take the eager path"
-        # the sum2-window drain ran OFF the event loop (executor thread)
-        assert any(name != "MainThread" for name in drained)
+    assert staged and staged[-1], "unmask did not take the eager path"
+    sum2_drains = [e for e in order if isinstance(e, tuple)]
+    # one drain a round, started by Sum2 OFF the event loop
+    assert len(sum2_drains) == 1 and sum2_drains[0][1] != "MainThread"
+    before_sum2 = order[: order.index(sum2_drains[0])]
+    assert "flush" in before_sum2, "the Update phase did not leave through flush"
+    if checkpoint_enabled:
+        # the barrier ended before the window opened: no vote while it held
+        assert vote_seen_by_drain == [False]
     else:
-        assert not staged, "disabled overlap must stay fully serial"
+        # nothing drained before Sum2 did, and the window was open beside it
+        assert "drain" not in before_sum2
+        assert vote_seen_by_drain == [True]
+
+
+def test_fold_error_in_the_drain_fails_the_round_at_sum2_exit():
+    """The drain rides into Sum2; a fold error it raises must fail the
+    round as Sum2 exits (a Failure for phase sum2), after the window, and
+    the hand-off to Unmask (`finalize_inplace`) must never run."""
+    import asyncio
+
+    from test_round_journal import _failure_shared, _mem_store, _settings
+
+    from xaynet_tpu.server.events import PhaseName
+    from xaynet_tpu.server.phases.failure import Failure
+    from xaynet_tpu.server.phases.sum2 import Sum2Phase
+    from xaynet_tpu.server.settings import CountSettings, TimeSettings
+
+    calls = []
+
+    class FailingFold:
+        def drain(self):
+            calls.append("drain")
+            raise RuntimeError("injected fold fault")
+
+        def finalize_inplace(self, defer_drain=False):
+            calls.append("finalize_inplace")
+            raise AssertionError("Unmask was reached past a failed drain")
+
+    settings = _settings()
+    # an empty window: the phase ends as soon as it has opened
+    settings.pet.sum2.count = CountSettings(min=0, max=0)
+    settings.pet.sum2.time = TimeSettings(min=0.0, max=0.2)
+    phase = Sum2Phase(_failure_shared(settings, _mem_store()), FailingFold())
+    nxt = asyncio.run(asyncio.wait_for(phase.run_phase(), timeout=30))
+    assert isinstance(nxt, Failure) and nxt.failed_phase is PhaseName.SUM2
+    assert "injected fold fault" in str(nxt.error)
+    assert calls == ["drain"]
 
 
 # --- negative slack through the in-process timeline fold -------------------

@@ -164,7 +164,7 @@ def test_a_served_round_on_one_device_observes_mask_put_once(
     n_limbs, length, relayouts, staged_jobs, monkeypatch
 ):
     """A whole PET round through the state machine, device aggregation on one
-    CPU device, the overlap engines on (the shipped default): the Unmask phase
+    CPU device, the pipeline riding into Unmask (docs/DESIGN.md §22): the Unmask phase
     observes ``mask_put`` once on ``xaynet_unmask_seconds`` and relays the
     elected mask out once; the model is the mean."""
     from xaynet_tpu.sdk.client import InProcessClient
@@ -269,7 +269,23 @@ ARMS = {
     "one_device": (True, 1, True),
     "mesh_drain_time": (True, None, False),
     "mesh_eager": (True, None, True),
+    # the two drain-time arms that no caller's argument chooses (docs/DESIGN.md
+    # §22): a shard's tail job fails under the open pipeline, and a journal
+    # resume re-enters Unmask (phases/resume.py) with no pipeline to ride
+    "mesh_failed_shard": (True, None, True),
+    "mesh_resumed": (True, None, False),
 }
+
+
+def _failing_shard(monkeypatch, shard: int = 1):
+    real = ShardedAggregator.unmask_shard
+
+    def failing(self, plan, d, mask_planar, out):
+        if d == shard:
+            raise RuntimeError("injected shard fault")
+        return real(self, plan, d, mask_planar, out)
+
+    monkeypatch.setattr(ShardedAggregator, "unmask_shard", failing)
 
 
 def _pass_bytes() -> dict:
@@ -284,7 +300,9 @@ def _run_unmask_phase(arm: str, n_limbs: int, n: int, sound: bool = True):
 
     from xaynet_tpu.server.coordinator import CoordinatorState
     from xaynet_tpu.server.events import EventPublisher, PhaseName
+    from xaynet_tpu.resilience.checkpoint import entry
     from xaynet_tpu.server.phases.base import Shared
+    from xaynet_tpu.server.phases.resume import resume_phase
     from xaynet_tpu.server.phases.unmask import Unmask
     from xaynet_tpu.server.requests import RequestReceiver
     from xaynet_tpu.storage.memory import (
@@ -295,8 +313,8 @@ def _run_unmask_phase(arm: str, n_limbs: int, n: int, sound: bool = True):
     config = _config(n_limbs)
     mesh = make_mesh(jax.devices()[:n_devices]) if device else None
     agg, mask, mean = _staged_round(config, n, mesh, np.random.default_rng(11), device=device)
-    view = agg.finalize_inplace(defer_drain=open_stream)
-    assert isinstance(view, DeviceAggregation) == device
+    resumed = arm == "mesh_resumed"
+    view = None if resumed else agg.finalize_inplace(defer_drain=open_stream)
 
     handed = {"store": [], "anchor": []}
 
@@ -310,6 +328,8 @@ def _run_unmask_phase(arm: str, n_limbs: int, n: int, sound: bool = True):
             handed["anchor"].append(model_data)
 
     settings = _settings(model_len=n)
+    settings.aggregation.device = device
+    settings.aggregation.kernel = "xla"
     settings.mask.group_type = config.group_type
     settings.mask.bound_type = config.bound_type
     settings.mask.model_type = config.model_type
@@ -327,9 +347,19 @@ def _run_unmask_phase(arm: str, n_limbs: int, n: int, sound: bool = True):
         shared = Shared(
             state=state, request_rx=RequestReceiver(), events=events,
             store=Store(coord, models, Anchor()), settings=settings, metrics=None)
-        phase = Unmask(shared, view)
+        if resumed:
+            # the journal's way back in: the aggregate restored into a new
+            # aggregator, which hands over with no pipeline opened
+            phase = resume_phase(shared, entry(shared, "unmask", agg.snapshot_journal()))
+        else:
+            phase = Unmask(shared, view)
+        assert isinstance(phase.model_agg, DeviceAggregation) == device
+        assert (getattr(phase.model_agg, "_stream", None) is not None) == open_stream
         before = _pass_bytes()
-        await phase.process()
+        with pytest.MonkeyPatch.context() as patch:
+            if arm == "mesh_failed_shard":
+                _failing_shard(patch)
+            await phase.process()
         phase.broadcast()
         after = _pass_bytes()
         stored = await models.global_model(await coord.latest_global_model_id())
@@ -354,8 +384,12 @@ def test_every_arm_publishes_the_same_model_to_the_bit(n_limbs, length, monkeypa
             return _real(self, *args)
 
         monkeypatch.setattr(owner, name, spy)
+    drain_time = ["unmask_planar", "_unmask_plan"]
     routes = {"host": [], "one_device": ["unmask_planar"],
-              "mesh_drain_time": ["unmask_planar", "_unmask_plan"], "mesh_eager": ["finish_unmask"]}
+              "mesh_drain_time": drain_time, "mesh_eager": ["finish_unmask"],
+              "mesh_failed_shard": ["finish_unmask", *drain_time],
+              # restored into the mesh array: no shard plan to subtract by
+              "mesh_resumed": ["unmask_planar"]}
     models = {}
     for arm in ARMS:
         del taken[:]

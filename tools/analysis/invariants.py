@@ -38,8 +38,6 @@ NB_MODELS_SITES: dict[tuple[str, str], str] = {
     # the device aggregator: same contract, device accumulator
     ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator.__init__"): "fresh accumulator",
     ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator.add_batch"): "pre-validated batch credit",
-    ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator.add_planar_batch"):
-        "pre-validated planar batch credit",
     ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator._ingest_staged_bytes"):
         "wire batch credit from the synced acceptance vector",
     ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator.restore"):

@@ -23,8 +23,8 @@ Two legs:
    thread) carry ``# lint: tenant-ok: <rationale>`` — the rationale is
    the review record.
 
-2. **sanctioned lease sites** — every ``lease_host``/``lease_device``
-   call outside ``xaynet_tpu/tenancy/`` must appear in
+2. **sanctioned lease sites** — every ``lease_host`` call outside
+   ``xaynet_tpu/tenancy/`` must appear in
    :data:`LEASE_SITES` with a rationale naming its paired release. This
    is the static half of the *leases == releases at round end* invariant:
    the whitelist below is the closed set of places pages enter
@@ -61,7 +61,7 @@ from .core import Finding, suppressed, suppression_pending_rationale
 _SCOPED_ATTRS = frozenset({"edge_watermarks", "resume_attempts"})
 _SCOPED_POOL_CALLS = frozenset({"page_table", "balanced", "reclaim"})
 
-_LEASE_CALLS = frozenset({"lease_host", "lease_device"})
+_LEASE_CALLS = frozenset({"lease_host"})
 
 # (file, function qualname) -> rationale naming the paired release.
 LEASE_SITES: dict[tuple[str, str], str] = {
@@ -69,13 +69,6 @@ LEASE_SITES: dict[tuple[str, str], str] = {
         "staging ring buffers, leased as acquire() needs them up to the "
         "ring's size; released by ring.close() from the pipeline's "
         "close(), GC finalizer as the crash backstop",
-    ("xaynet_tpu/parallel/shards.py", "ShardPlan._alloc"):
-        "per-shard accumulator/spare buffers; released by "
-        "release_pages() from the round's unmask tail, GC finalizer + "
-        "Idle reclaim as crash backstops",
-    ("xaynet_tpu/parallel/shards.py", "ShardPlan.__init__"):
-        "device-ledger lease for the plan's HBM footprint; released with "
-        "release_pages() exactly like the host buffers",
 }
 
 _PREFIXES = ("xaynet_tpu/server/", "xaynet_tpu/parallel/")

@@ -23,15 +23,12 @@ Legs (one JSON result each, combined into one line on stdout):
   and replays N updates, to pin the bytes-per-accepted-update comparison:
   the packed path must move STRICTLY fewer bytes.
 
-``--append-history`` appends the records to BENCH_HISTORY.jsonl (family:
-``ingress accepted updates``); nothing reads that file any more (ROADMAP D4).
-
 Usage (CI smoke):
   python tools/loadgen_soak.py --participants 2000 --drivers 2 --tenants 2 \
-      --identity --legacy-control 400 --append-history
+      --identity --legacy-control 400
 Headline (the 100k+ run):
   python tools/loadgen_soak.py --participants 100000 --drivers 2 \
-      --model-len 64 --legacy-control 2000 --append-history
+      --model-len 64 --legacy-control 2000
 """
 
 from __future__ import annotations
@@ -49,10 +46,6 @@ from fractions import Fraction
 from urllib.request import urlopen
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-HISTORY = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "BENCH_HISTORY.jsonl"
-)
 
 CONFIG = """
 [api]
@@ -550,45 +543,6 @@ def leg_legacy_control(tmp: str, args) -> dict:
         coord.stop()
 
 
-def append_history(result: dict, args) -> None:
-    records = []
-    head = result["headline"]
-    records.append({
-        "ts": round(time.time(), 3),
-        "source": "loadgen_soak",
-        "metric": "ingress accepted updates",
-        "value": head["accepted_per_s"],
-        "unit": "updates/s",
-        "platform": "cpu",
-        "cpus": os.cpu_count(),
-        "participants": head["participants"],
-        "drivers": head["drivers"],
-        "tenants": head["tenants"],
-        "edges": head["edges"],
-        "wire": head["wire"],
-        "model_len": head["model_len"],
-        "replay_wall_s": head["replay_wall_s"],
-        "bytes_per_accepted": head["bytes_per_accepted"],
-        "shed": head["shed"],
-    })
-    if result.get("legacy_control"):
-        records.append({
-            "ts": round(time.time(), 3),
-            "source": "loadgen_soak",
-            "metric": "ingress staging bytes per accepted update",
-            "value": head["bytes_per_accepted"],
-            "unit": "bytes/update",
-            "platform": "cpu",
-            "wire": head["wire"],
-            "model_len": head["model_len"],
-            "legacy_bytes_per_accepted":
-                result["legacy_control"]["bytes_per_accepted"],
-        })
-    with open(HISTORY, "a") as f:
-        for r in records:
-            f.write(json.dumps(r) + "\n")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--participants", type=int, default=2000)
@@ -605,7 +559,6 @@ def main() -> None:
     ap.add_argument("--identity-n", type=int, default=12)
     ap.add_argument("--legacy-control", type=int, default=0, metavar="N")
     ap.add_argument("--close-timeout", type=float, default=7200.0)
-    ap.add_argument("--append-history", action="store_true")
     args = ap.parse_args()
     if args.tenants and args.edges:
         ap.error("--tenants and --edges are separate topologies")
@@ -633,8 +586,6 @@ def main() -> None:
                 "strictly_fewer": packed_bpa < legacy_bpa,
             }
     result["wall_s"] = round(time.perf_counter() - t0, 2)
-    if args.append_history:
-        append_history(result, args)
     print(json.dumps(result))
 
 

@@ -443,8 +443,6 @@ def _metric_sum(port: int, family: str) -> float:
 # the model save but BEFORE the journal retires (the idempotent-republish
 # window, the nastiest restart point).
 KILL_MATRIX = ("sum:1", "update:2", "sum2:1", "unmask:publish:1")
-RECOVERY_METRIC = "restart recovery wall"
-RECOVERY_UNIT = "s/recovery"
 
 
 def _kill_config(port: int, model_len: int, state_dir: str) -> str:
@@ -454,8 +452,7 @@ def _kill_config(port: int, model_len: int, state_dir: str) -> str:
 
     ``checkpoint_every_batches = 1`` with ``batch_size = 1`` puts a journal
     write BEFORE every update acknowledgement, so any accepted message
-    survives any kill point. Overlap is pinned off: the matrix measures the
-    journal, not the journal x speculation interplay (tests cover that)."""
+    survives any kill point."""
     base = CONFIG.format(
         port=port,
         model_len=model_len,
@@ -482,7 +479,6 @@ def _kill_config(port: int, model_len: int, state_dir: str) -> str:
         "checkpoint_every_batches = 1\n"
         "checkpoint_every_s = 1.0\n"
         "max_resume_attempts = 3\n"
-        "\n[overlap]\nenabled = false\n"
     )
 
 
@@ -575,8 +571,7 @@ def run_kill_matrix_soak(args) -> None:
     - the published global model is byte-identical to an unkilled control;
     - zero pool pages stay leased after the round (no leak across a kill);
     - the restart-to-serving wall (``xaynet_recovery_seconds``) is
-      recorded — with ``--append-history`` it lands in BENCH_HISTORY.jsonl
-      as the lower-is-better "restart recovery wall" family.
+      recorded, in the JSON line the soak prints.
     """
     import signal
     import socket
@@ -712,28 +707,6 @@ def run_kill_matrix_soak(args) -> None:
                     "pool_pages_leaked": leaked,
                 }
             )
-    if args.append_history:
-        history = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_HISTORY.jsonl",
-        )
-        ts = time.time()
-        with open(history, "a") as f:
-            for rec in results:
-                f.write(
-                    json.dumps(
-                        {
-                            "ts": ts,
-                            "cpus": os.cpu_count(),
-                            "metric": f"{RECOVERY_METRIC} ({rec['kill_point']})",
-                            "value": rec["recovery_s"],
-                            "unit": RECOVERY_UNIT,
-                            "restart_to_serving_s": rec["restart_to_serving_s"],
-                            "model_len": args.model_len,
-                        }
-                    )
-                    + "\n"
-                )
     print(
         json.dumps(
             {
@@ -1366,13 +1339,6 @@ def main() -> None:
         "CI smoke runs a one-per-phase-family subset",
     )
     ap.add_argument(
-        "--append-history",
-        action="store_true",
-        help="with --kill-matrix: append one 'restart recovery wall' record "
-        "per kill coordinate to BENCH_HISTORY.jsonl (lower is better; "
-        "nothing reads that file any more)",
-    )
-    ap.add_argument(
         "--faults",
         type=int,
         default=None,
@@ -1404,8 +1370,8 @@ def main() -> None:
                      "process lifecycle and durable tree)")
         run_kill_matrix_soak(args)
         return
-    if args.kill_points or args.append_history:
-        ap.error("--kill-points/--append-history require --kill-matrix")
+    if args.kill_points:
+        ap.error("--kill-points requires --kill-matrix")
     if args.tenant_churn:
         if (
             args.tenants is not None

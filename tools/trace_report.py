@@ -291,8 +291,8 @@ def overlap_report(events: list[dict]) -> tuple[str, list[str]]:
     attribute naming its HOME phase (whose work it is); merging it into
     that phase's interval set makes phases genuinely intersect, and the
     identity ``sum(phase walls) − overlap + gap == wall`` must still
-    balance — with the overlap engines on, wall < sum of phase walls
-    (negative slack) is the measured win, not an accounting error."""
+    balance — wall < sum of phase walls (negative slack) is the measured
+    win, not an accounting error."""
     problems: list[str] = []
     lines: list[str] = []
     phase_iv: dict[str, list[tuple[float, float]]] = {}
@@ -308,7 +308,7 @@ def overlap_report(events: list[dict]) -> tuple[str, list[str]]:
     plain_walls = {p: _length(_merge(iv)) for p, iv in phase_iv.items()}
     lines.append("cross-phase concurrency lanes:")
     if not ov_spans:
-        lines.append("  (no overlap.* spans — overlap engines off or idle)")
+        lines.append("  (no overlap.* spans — no drain and no shard subtract ran beside a phase)")
     for e in sorted(ov_spans, key=lambda e: e["ts"]):
         home = str((e.get("args") or {}).get("phase") or "")
         lo, hi = e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6

@@ -3,12 +3,11 @@
 The TPU answer to the reference's scaling story (reference:
 rust/xaynet-server's single-threaded in-memory `Aggregation`): HBM-resident
 accumulators sharded over the model axis of a `jax.sharding.Mesh`, with
-zero-collective elementwise kernels and multi-host extensions.
+zero-collective elementwise kernels.
 """
 
 from .aggregator import ShardedAggregator
 from .mesh import MODEL_AXIS, make_mesh, shard_slices
-from .multihost import MultiHostAggregator
 from .shards import ShardPlan
 from .streaming import StreamingAggregator
 
@@ -19,5 +18,4 @@ __all__ = [
     "MODEL_AXIS",
     "make_mesh",
     "shard_slices",
-    "MultiHostAggregator",
 ]

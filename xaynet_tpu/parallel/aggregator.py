@@ -279,11 +279,6 @@ class ShardedAggregator:
         self.acc = self._fold(self.acc, staged)
         self.nb_models += stack.shape[0]
 
-    def add_planar_batch(self, stack_planar: jax.Array) -> None:
-        """Fold an already device-resident planar ``[K, L, padded_len]`` batch."""
-        self.acc = self._fold(self.acc, stack_planar)
-        self.nb_models += stack_planar.shape[0]
-
     def _stage_raw_bytes(self, raw: np.ndarray):
         """Shared guard + pad + upload for raw wire element blocks: validate
         dtype/shape, zero-pad to the padded length (zero bytes decode to
@@ -461,8 +456,7 @@ class ShardedAggregator:
 
     def _ingest_staged_bytes(self, staged) -> np.ndarray:
         """Unpack + validity + fold an already device/mesh-resident raw-byte
-        batch (``add_wire_batch`` after device_put; the multihost path after
-        ``make_array_from_process_local_data``) with an immediate
+        batch (``add_wire_batch`` after device_put) with an immediate
         acceptance sync."""
         ok_host = np.asarray(self.dispatch_staged_bytes(staged))
         self.nb_models += int(ok_host.sum())

@@ -101,7 +101,7 @@ from .shards import H2D_GATE
 logger = logging.getLogger(__name__)
 
 # mirror=True: also written into the profiler's trace when the runner has
-# installed its sink. stream.commit and overlap.eager_unmask are recorded
+# installed its sink. stream.commit and SPAN_EAGER_UNMASK are recorded
 # after the fact (record_span), which no mirror can carry: a commit barrier
 # begins on the thread of the first shard to fold and ends on the last's.
 SPAN_STAGE = trace.declare_span("stream.stage", mirror=True)
@@ -1869,7 +1869,7 @@ class StreamingAggregator:
     def _process_unmask(self, item: tuple) -> None:
         """One shard worker's eager unmask leg: runs after the shard's
         last fold (queue FIFO), subtracts that shard's mask slice, and
-        records the hidden seconds as an ``overlap.eager_unmask`` span
+        records the hidden seconds as a ``SPAN_EAGER_UNMASK`` span
         (home phase ``unmask``) so the timeline fold measures them as
         negative slack."""
         job, d = item

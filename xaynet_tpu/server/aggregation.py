@@ -136,13 +136,17 @@ class DeviceAggregation(Aggregation):
             self._nb_models = self._device.nb_models
 
     def _eager_unmask(self, mask_obj: MaskObject) -> PlanarLimbs | None:
-        """Eager per-shard unmask (docs/DESIGN.md §22): the mask subtract
-        is staged as per-shard tail jobs BEHIND the round's last fold
-        batches, so each shard unmasks the moment its own last fold
-        commits — instead of global drain barrier, then a separate unmask
-        pass. Returns ``None`` when the pipeline couldn't run it (caller
-        falls back to the drain-time subtract, byte-identical either way:
-        a failed shard's accumulator is untouched)."""
+        """The unmask of a pipeline that rode into Unmask still open
+        (docs/DESIGN.md §22). Where the pipeline can take the mask now
+        (``can_stage_unmask``: it folds on more than one shard and is
+        neither degraded, poisoned nor closed) the subtract is staged as
+        per-shard tail jobs BEHIND the round's last fold batches, so each
+        shard unmasks the moment its own last fold commits, and the
+        planes those jobs fetched are returned. Otherwise (one device,
+        above all), or where a shard's job failed, the pipeline is
+        drained and settled and ``None`` returned: the caller runs the
+        drain-time subtract, byte-identical either way, since a failed
+        shard's accumulator is untouched."""
         stream = self._stream
         job = None
         if stream.can_stage_unmask():
@@ -151,9 +155,8 @@ class DeviceAggregation(Aggregation):
             with unmask_stages.stage("mask_put", bytes=mask_obj.vect.data.nbytes):
                 job = stream.stage_unmask(self._device.mask_planar(mask_obj.vect.data))
         try:
-            # the deferred acceptance sync + completion barrier; fold
-            # errors surface here exactly as they would have at the
-            # sum2 finalize in the serial flow
+            # the completion barrier: the shard jobs staged above end
+            # under it, and a fold error still pending fails the round here
             with unmask_stages.stage("subtract"):
                 stream.drain()
         except Exception:
@@ -667,12 +670,16 @@ class StagedAggregator:
         ``finalize()`` (kept for snapshot/test callers) gathers first and
         subtracts after, a full extra accumulator round-trip at 25M params.
 
-        With ``defer_drain`` (``[overlap] eager_unmask``, docs/DESIGN.md
-        §22) the device pipeline rides into Unmask still OPEN: the staged
-        remainder is submitted but the drain barrier moves into the eager
-        unmask, where each shard subtracts its mask slice the moment its
-        own last fold commits instead of after a global drain plus a
-        separate unmask pass.
+        ``defer_drain`` is the caller saying that a pipeline is open
+        (docs/DESIGN.md §22). Sum2 hands over with it set: the device
+        pipeline rides into Unmask still OPEN, the staged remainder
+        submitted, and the drain barrier moves into the unmask, which on
+        a mesh stages the subtract behind each shard's own last fold
+        (``DeviceAggregation._eager_unmask``). A journal resume into
+        Unmask restored its aggregate into an aggregator that never
+        opened a pipeline and leaves it unset: drain, close, and the
+        view's drain-time subtract (``ShardedAggregator.unmask_planar``),
+        which is also what one device and a failed shard job come to.
         """
         if defer_drain and self._device is not None:
             self.flush()

@@ -114,7 +114,8 @@ def _tenant_rows(server) -> str:
         )
         # cross-phase overlap of the last folded round (docs/DESIGN.md
         # §22): negative slack — the round wall came in under the serial
-        # sum of phase walls — is the overlap engine's visible win
+        # sum of phase walls — is what the riding drain and the shards'
+        # subtracts hid
         if last:
             ov = last.get("overlap_s", 0.0)
             slack = last.get("wall_s", 0.0) - sum(
@@ -218,7 +219,6 @@ def _pool_section(server) -> str:
             "slabs",
             "host_pages_in_use",
             "host_pages_free",
-            "device_pages_in_use",
             "fragmentation",
         )
         if k in stats

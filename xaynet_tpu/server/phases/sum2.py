@@ -5,24 +5,23 @@ each accepted ``Sum2Request`` increments the score of the submitted mask
 (sum membership and single submission enforced by the store); the model
 aggregation is carried forward to Unmask.
 
-Phase overlap (docs/DESIGN.md §22): with ``[overlap] sum2_drain`` the
-update phase hands its streaming pipeline over still in flight and this
-phase runs the drain barrier in a background executor thread while it
-collects sum2 masks — the fold tail that used to serialize behind the
-update wall is hidden under this phase's collection wall, recorded as an
-``overlap.drain`` span (home phase ``update``) so the round timeline
-measures the hidden seconds as negative slack. The drain future is
-awaited before the phase exits, so fold errors still fail the round
-before Unmask reads the accumulator.
+The round's shape (docs/DESIGN.md §22): the Update phase hands its
+streaming pipeline over still in flight, and this phase runs the drain
+barrier on an executor thread. What the phase observes decides when it
+waits for it: without a journal the drain runs beside the collection of
+sum2 masks and is awaited as the phase exits, so the fold tail is hidden
+under this phase's wall (an ``overlap.drain`` span, home phase
+``update``, which the round timeline reads as negative slack); with
+``[resilience] checkpoint_enabled`` the drain is awaited before the vote
+window opens, because the journal's base entry needs the exact aggregate.
+Either way a fold error fails the round here, before Unmask reads the
+accumulator.
 
 Resilience (docs/DESIGN.md §9): with ``[resilience] checkpoint_enabled``
 the phase writes a sum2-tagged journal entry (finished aggregate + sealed
 dictionaries) BEFORE acknowledging its first vote, then rewrites it per
 accepted vote; ``next`` advances the entry to ``unmask`` before the
-finalize barrier so the publish window is covered too. Journal-before-ack
-takes precedence over the drain overlap: when both are on, the drain is
-awaited before the vote window opens (the base entry needs the exact
-aggregate; the overlap win is forfeited for the round's durability).
+finalize barrier so the publish window is covered too.
 """
 
 from __future__ import annotations
@@ -63,7 +62,6 @@ class Sum2Phase(PhaseState):
     ):
         super().__init__(shared)
         self.aggregator = aggregator
-        self._drain_task: asyncio.Future | None = None
         self._resume_from = resume_from
         self._journal = shared.settings.resilience.checkpoint_enabled
         # accepted votes in journal form [(sum_pk, serialized mask bytes)];
@@ -92,17 +90,12 @@ class Sum2Phase(PhaseState):
 
     async def process(self) -> None:
         params = self.shared.settings.pet.sum2
-        if self.shared.settings.overlap.feature("sum2_drain"):
-            self._drain_task = asyncio.get_running_loop().run_in_executor(
-                None, self._drain_overlapped
-            )
-        if self._journal and self._drain_task is not None:
+        drain = asyncio.get_running_loop().run_in_executor(None, self._drain_overlapped)
+        if self._journal:
             # journal-ready-before-first-vote-ack: the base entry snapshots
             # the finished aggregate, so the drain must complete BEFORE the
-            # window opens — durability outranks the overlap win here
-            task, self._drain_task = self._drain_task, None
-            await task
-        if self._journal:
+            # window opens
+            await drain
             if self._resume_from is not None:
                 await self._rebroadcast_dicts()
                 self.arrivals_offset = len(self._votes)
@@ -118,12 +111,10 @@ class Sum2Phase(PhaseState):
         try:
             await self.process_requests(params)
         finally:
-            if self._drain_task is not None:
-                # the overlap window closes with the phase: fold errors
-                # surface HERE (failing the round exactly where the
-                # serial flow's drain would have), never past sum2
-                task, self._drain_task = self._drain_task, None
-                await task
+            # the drain's window closes with the phase: fold errors
+            # surface HERE, never past sum2 (a journalled phase awaited it
+            # above, and awaits a finished future here)
+            await drain
 
     async def _rebroadcast_dicts(self) -> None:
         """Participants contacting a restarted coordinator need the round
@@ -173,12 +164,10 @@ class Sum2Phase(PhaseState):
             self._base.mask_votes = list(self._votes)
             await write_entry(self.shared, self._base)
         # finalize WITHOUT gathering: device rounds hand Unmask a sharded
-        # view so the elected mask is subtracted per-shard in place (host
-        # rounds get the host Aggregation exactly as before); with
-        # [overlap] eager_unmask the pipeline stays open so each shard
-        # subtracts at its own last-fold commit (docs/DESIGN.md §22)
-        eager = self.shared.settings.overlap.feature("eager_unmask")
-        return Unmask(self.shared, self.aggregator.finalize_inplace(defer_drain=eager))
+        # view over the pipeline, still open, so that on a mesh each shard
+        # subtracts its slice of the elected mask behind its own last fold
+        # (docs/DESIGN.md §22); host rounds get the host Aggregation
+        return Unmask(self.shared, self.aggregator.finalize_inplace(defer_drain=True))
 
     async def handle_request(self, req: StateMachineRequest) -> None:
         if not isinstance(req, Sum2Request):

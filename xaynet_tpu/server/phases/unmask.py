@@ -7,15 +7,19 @@ scores are ambiguous -> round failure); validate and unmask the aggregate;
 persist the global model under ``{round_id}_{hex(seed)}`` with the latest-id
 pointer; publish proof to the trust anchor; broadcast the new model.
 
-The unmask subtract runs on the vectorized limb kernels. Device rounds
-arrive as a ``DeviceAggregation`` view (``aggregation.finalize_inplace``):
-the subtract runs per-shard against the still-sharded accumulator — each
-mesh device unmasks its own model-axis slice, the aggregate is never
-gathered before subtraction, and the host ``mod_sub`` runs for a host
-round alone. The fixed-point decode uses the double-double fast path for
-f32 configs (core/mask/encode.py): it reads the planes a device arm
-fetched where they lie, on the native library's threads, and the float64
-it returns is ``global_model``: serialised once, and those bytes handed to
+The unmask subtract runs on the vectorized limb kernels, and what the
+phase is handed selects where (docs/DESIGN.md §22; no setting does). A
+host round hands over the host ``Aggregation`` and its ``mod_sub``. A
+device round hands over a ``DeviceAggregation`` view
+(``aggregation.finalize_inplace``) over the still-sharded accumulator,
+which is never gathered before the subtraction: where the view's pipeline
+is open and folds on a mesh, each shard subtracts its slice of the mask
+behind its own last fold; on one device, after a journal resume (no
+pipeline) and where a shard's job failed, one drain-time subtract runs
+over the whole accumulator. The fixed-point decode uses the double-double
+fast path for f32 configs (core/mask/encode.py): it reads the planes a
+device arm fetched where they lie, on the native library's threads, and
+the float64 it returns is ``global_model``: serialised once, and those bytes handed to
 the store and to the trust anchor (docs/DESIGN.md §16).
 """
 

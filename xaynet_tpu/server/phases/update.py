@@ -118,18 +118,11 @@ class UpdatePhase(PhaseState):
             # aggregate; a SIGTERM mid-phase forces one final save (runner)
             self.shared.flush_hook = self._ckpt.save_now
         await self.process_requests(params)
-        if self.shared.settings.overlap.feature("sum2_drain"):
-            # phase overlap (docs/DESIGN.md §22): SUBMIT the staged
-            # remainder but leave the drain barrier to the sum2 phase,
-            # which runs it in the background under its own collection
-            # wall — the fold tail that used to extend the update wall is
-            # hidden, and fold errors still fail the round before Unmask
-            await asyncio.get_running_loop().run_in_executor(None, self.aggregator.flush)
-        else:
-            # phase transition: drain the streaming pipeline — every
-            # submitted fold completes and the deferred acceptance sync
-            # runs, off the event loop (the one blocking sync point)
-            await asyncio.get_running_loop().run_in_executor(None, self.aggregator.drain)
+        # the phase ends by SUBMITTING the staged remainder; the drain
+        # barrier is Sum2's, which runs it beside its own collection (or
+        # before its window, where a journal is kept) and fails the round
+        # there, before Unmask, if a fold failed (docs/DESIGN.md §22)
+        await asyncio.get_running_loop().run_in_executor(None, self.aggregator.flush)
         self._seed_dict = await self.shared.store.coordinator.seed_dict()
         if not self._seed_dict:
             raise PhaseError("NoSeedDict", "seed dictionary missing after update phase")

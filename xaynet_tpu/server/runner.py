@@ -422,7 +422,7 @@ async def serve_tenants(settings: Settings) -> None:
     startup.mark("imports")
     init_logging(settings)
     ten = settings.tenancy
-    configure_pool(ten.page_kib, ten.slab_pages, ten.host_pages, ten.device_pages)
+    configure_pool(ten.page_kib, ten.slab_pages, ten.host_pages)
     configure_scheduler(ten.max_inflight_folds)
     budget = TenantAdmissionBudget(ten.ingest_capacity, ten.max_share)
     if settings.resilience.fault_plan:

@@ -485,8 +485,8 @@ class TenancySettings:
     FIRST id doubles as the default tenant serving the bare legacy routes;
     every tenant is also reachable under ``/t/<tenant>/...``.
 
-    Pool knobs size the shared arena (pages of ``page_kib`` KiB; 0 caps =
-    uncapped, the host arena grows by ``slab_pages``-page slabs);
+    Pool knobs size the shared host arena (pages of ``page_kib`` KiB,
+    grown by ``slab_pages``-page slabs; ``host_pages = 0`` is uncapped);
     ``max_inflight_folds`` bounds fold batches in flight across ALL
     tenants (the scheduler's backpressure); ``ingest_capacity`` and
     ``max_share`` shape the per-tenant admission budget layered on each
@@ -499,7 +499,6 @@ class TenancySettings:
     page_kib: int = 1024  # pool page size (multiple of 4 KiB)
     slab_pages: int = 64  # host-arena growth granularity
     host_pages: int = 0  # 0 = uncapped
-    device_pages: int = 0  # 0 = uncapped
     max_inflight_folds: int = 8  # cross-tenant fold-batch bound
     ingest_capacity: int = 4096  # process-wide admission budget (messages)
     max_share: float = 0.6  # one tenant's ceiling of that budget
@@ -542,8 +541,8 @@ class TenancySettings:
             raise SettingsError("tenancy.page_kib must be a multiple of 4 (>= 4)")
         if self.slab_pages < 1:
             raise SettingsError("tenancy.slab_pages must be >= 1")
-        if self.host_pages < 0 or self.device_pages < 0:
-            raise SettingsError("tenancy.host_pages/device_pages must be >= 0")
+        if self.host_pages < 0:
+            raise SettingsError("tenancy.host_pages must be >= 0")
         if self.max_inflight_folds < 1:
             raise SettingsError("tenancy.max_inflight_folds must be >= 1")
         if self.ingest_capacity < 1:
@@ -652,45 +651,6 @@ class SloSettings:
 
 
 @dataclass
-class OverlapSettings:
-    """``[overlap]`` — round-phase overlap & speculation (docs/DESIGN.md §22).
-
-    The PET phase chain is serial by protocol, not by data dependency:
-    the sum2 mask derivation needs only the sealed sum dict, the fold
-    drain needs only staged updates, and each shard's unmask slice needs
-    only that shard's folds. Each flag opts one overlap out independently
-    (the ``[liveness]`` idiom — mechanisms are orthogonal); ``enabled =
-    false`` forces the fully serial pre-overlap behaviour regardless of
-    the per-feature flags. Every overlap is byte-identity preserving: a
-    disabled or mis-speculated fast path falls back to the on-demand
-    serial path.
-    """
-
-    enabled: bool = True
-    # derive sum2 masks speculatively during the update phase (bench/sim
-    # rounds where the sum participant is in-process); mis-speculated
-    # seeds are discarded by an exact modular subtract
-    speculative_derive: bool = True
-    # subtract each shard's mask slice as soon as ITS last fold commits
-    # at the drain barrier (instead of global drain + a separate pass)
-    eager_unmask: bool = True
-    # let the update-phase fold drain ride into the sum2 request window
-    # instead of blocking the phase transition on it
-    sum2_drain: bool = True
-    # seeds per speculative derive group (bounds resident mask memory to
-    # one accumulator + one group of per-seed derivations)
-    spec_group: int = 8
-
-    def feature(self, name: str) -> bool:
-        """Effective per-feature switch (master ``enabled`` gates all)."""
-        return self.enabled and bool(getattr(self, name))
-
-    def validate(self) -> None:
-        if self.spec_group < 1:
-            raise SettingsError("overlap.spec_group must be >= 1")
-
-
-@dataclass
 class Settings:
     pet: PetSettings
     mask: MaskSettings = field(default_factory=MaskSettings)
@@ -708,14 +668,12 @@ class Settings:
     tenancy: TenancySettings = field(default_factory=TenancySettings)
     slo: SloSettings = field(default_factory=SloSettings)
     loadgen: LoadgenSettings = field(default_factory=LoadgenSettings)
-    overlap: OverlapSettings = field(default_factory=OverlapSettings)
 
     def validate(self) -> None:
         self.pet.validate()
         self.api.validate()
         self.tenancy.validate()
         self.slo.validate()
-        self.overlap.validate()
         try:
             self.mask.to_config()  # quant level vs data/bound-type ceiling
         except ValueError as e:
@@ -832,8 +790,6 @@ class Settings:
         slo_base = base.slo
         lg_raw = raw.get("loadgen", {})
         lg_base = base.loadgen
-        ov_raw = raw.get("overlap", {})
-        ov_base = base.overlap
 
         return cls(
             pet=PetSettings(
@@ -997,7 +953,6 @@ class Settings:
                 page_kib=int(ten_raw.get("page_kib", ten_base.page_kib)),
                 slab_pages=int(ten_raw.get("slab_pages", ten_base.slab_pages)),
                 host_pages=int(ten_raw.get("host_pages", ten_base.host_pages)),
-                device_pages=int(ten_raw.get("device_pages", ten_base.device_pages)),
                 max_inflight_folds=int(
                     ten_raw.get("max_inflight_folds", ten_base.max_inflight_folds)
                 ),
@@ -1062,15 +1017,6 @@ class Settings:
                 ),
                 concurrency=int(lg_raw.get("concurrency", lg_base.concurrency)),
                 seed=int(lg_raw.get("seed", lg_base.seed)),
-            ),
-            overlap=OverlapSettings(
-                enabled=bool(ov_raw.get("enabled", ov_base.enabled)),
-                speculative_derive=bool(
-                    ov_raw.get("speculative_derive", ov_base.speculative_derive)
-                ),
-                eager_unmask=bool(ov_raw.get("eager_unmask", ov_base.eager_unmask)),
-                sum2_drain=bool(ov_raw.get("sum2_drain", ov_base.sum2_drain)),
-                spec_group=int(ov_raw.get("spec_group", ov_base.spec_group)),
             ),
         )
 

@@ -249,10 +249,9 @@ class RoundReporter:
         overlap = drain_overlap_window()
         if overlap:
             # phase-overlap work that landed during this round
-            # (docs/DESIGN.md §22): hidden seconds by kind (spec_derive |
-            # drain | eager_unmask) with the speculation reconciliation
-            # counts — the round-report view of why the round wall came in
-            # under the serial sum of phase walls
+            # (docs/DESIGN.md §22): hidden seconds by kind (drain |
+            # eager_unmask) — the round-report view of why the round wall
+            # came in under the serial sum of phase walls
             report["overlap"] = overlap
         calibrations = drain_mask_calibrations()
         if calibrations:

@@ -61,17 +61,9 @@ ROUND_WALL = get_registry().histogram(
 OVERLAP_SECONDS = get_registry().counter(
     "xaynet_overlap_seconds_total",
     "Seconds of cross-phase work hidden inside another phase's wall, by "
-    "overlap kind (spec_derive | eager_unmask | drain; docs/DESIGN.md §22).",
+    "overlap kind (eager_unmask | drain; docs/DESIGN.md §22).",
     ("kind",),
 )
-SPEC_DERIVE = get_registry().counter(
-    "xaynet_spec_derive_total",
-    "Speculatively derived sum2 mask seeds by outcome: hit (speculated and "
-    "folded), miss (derived on demand at sum2), discard (mis-speculated, "
-    "subtracted back out; docs/DESIGN.md §22).",
-    ("outcome",),
-)
-
 # per-round overlap window: entries recorded by the overlap features and
 # drained into the round report's `overlap` section (the
 # `record_mask_calibration` idiom — bounded, fail-soft)
@@ -88,16 +80,6 @@ def record_overlap(kind: str, seconds: float, tenant: str = "default", **extra) 
     with _overlap_window_lock:
         if len(_overlap_window) < _MAX_OVERLAP_ENTRIES:
             _overlap_window.append(entry)
-
-
-def record_spec_outcomes(hits: int = 0, misses: int = 0, discards: int = 0) -> None:
-    """Count speculative-derive seed outcomes (hit | miss | discard)."""
-    if hits:
-        SPEC_DERIVE.labels(outcome="hit").inc(hits)
-    if misses:
-        SPEC_DERIVE.labels(outcome="miss").inc(misses)
-    if discards:
-        SPEC_DERIVE.labels(outcome="discard").inc(discards)
 
 
 def drain_overlap_window() -> list[dict]:
@@ -179,14 +161,14 @@ def fold_spans(round_id: int, spans: list) -> Optional[dict]:
                 heapq.heapreplace(heap, (span.duration, seq, name))
         if name.startswith("overlap."):
             # an overlap span is WORK BELONGING TO ITS HOME PHASE (the
-            # `phase` attr) that ran outside the phase's own span — a
-            # speculative derive inside update, update's drain riding the
-            # sum2 window, an eager per-shard unmask inside the drain.
+            # `phase` attr) that ran outside the phase's own span:
+            # update's drain riding the sum2 window, a shard's unmask
+            # inside the drain.
             # Merging it into the home phase's interval set makes the
             # identity's overlap term measure the hidden work: phase
             # intervals now genuinely intersect, so ``sum(phase walls) -
             # overlap + gap == wall`` reports negative slack (wall < sum
-            # of walls) exactly when the overlap engine saved wall time.
+            # of walls) exactly when that work hid under another phase.
             home = str(span.attrs.get("phase") or "")
             if home in _WORK_PHASES and span.duration > 0:
                 phase_iv.setdefault(home, []).append(

@@ -24,8 +24,8 @@ as a span under ``phase.unmask`` and as one observation on
   made) and the journal's retire, where they run.
 
 The eager per-shard unmask (docs/DESIGN.md §22) does the device's part on
-the shard workers (``overlap.eager_unmask``); what the phase's task does
-meanwhile carries the same names: ``mask_put`` is the relayout, ``subtract``
+the shard workers (``streaming.SPAN_EAGER_UNMASK``); what the phase's task
+does meanwhile carries the same names: ``mask_put`` is the relayout, ``subtract``
 the wait for the shards' tail jobs, ``fetch`` the assembled result.
 
 Between the kernel's result and the end of the phase the model should be

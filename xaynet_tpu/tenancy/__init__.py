@@ -1,8 +1,8 @@
 """Multi-tenant coordinator plumbing (docs/DESIGN.md §19, §23).
 
 - :mod:`pool` — the paged accumulator pool: fixed-size pages, host slab
-  arena + device capacity ledger, per-tenant page tables, lease/release
-  accounting with the round-end leases == releases invariant, and
+  arena, per-tenant page tables, lease/release accounting with the
+  round-end leases == releases invariant, and
   between-round compaction of fragmented slabs.
 - :mod:`scheduler` — the tenant fold-batch scheduler: bounded in-flight
   slots across tenants, weighted deficit-round-robin fairness with

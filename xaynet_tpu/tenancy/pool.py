@@ -8,24 +8,18 @@ reservations and without allocator fragmentation across rounds — a
 released run coalesces back into the free list and the next tenant's
 lease reuses the same physical pages.
 
-Two arenas, one accounting discipline:
-
-- **host arena** — real paging: a set of page-aligned uint8 slabs; a
-  lease carves a *contiguous page run* out of a slab and hands back a
-  typed numpy view. Contiguity per lease is the design point: every
-  existing fold kernel (native strided C++, XLA, pallas) reads plain
-  C-contiguous buffers, so paging lives at the allocator layer and the
-  hot path is byte-identical to owning a private buffer. Leased memory is
-  ZEROED before handoff — a page run previously owned by another tenant
-  must never leak that tenant's masked bytes (the PR-14 secret-hygiene
-  posture extended to memory reuse).
-- **device arena** — a capacity ledger: device fold kernels donate their
-  accumulators (`donate_argnums`), so a device buffer's identity is
-  ephemeral by design and literal page views cannot survive a fold. What
-  multi-tenant admission needs from HBM is the *budget*: the ledger
-  tracks pages leased per tenant against the configured capacity and
-  fails fast when a lease would not fit. Nothing charges it yet: shard
-  plans lease no pages (docs/DESIGN.md §19).
+The arena is host memory, and the paging is real: a set of page-aligned
+uint8 slabs; a lease carves a *contiguous page run* out of a slab and
+hands back a typed numpy view. Contiguity per lease is the design point:
+every existing fold kernel (native strided C++, XLA, pallas) reads plain
+C-contiguous buffers, so paging lives at the allocator layer and the hot
+path is byte-identical to owning a private buffer. Leased memory is
+ZEROED before handoff — a page run previously owned by another tenant
+must never leak that tenant's masked bytes (the PR-14 secret-hygiene
+posture extended to memory reuse). Device memory is not leased here:
+device fold kernels donate their accumulators (`donate_argnums`), so a
+device buffer's identity is ephemeral and no page view could survive a
+fold (docs/DESIGN.md §19).
 
 Accounting invariant (checked at round boundaries and by the
 ``tenant-scope`` analysis pass's sanctioned-site whitelist): **leases ==
@@ -49,9 +43,12 @@ from ..telemetry.registry import get_registry
 logger = logging.getLogger("xaynet.tenancy")
 
 _registry = get_registry()
+# the one value of the pool metrics' ``arena`` label, kept because readers
+# select on it (tenancy/lifecycle.py, tools/soak.py)
+_ARENA = "host"
 POOL_PAGES = _registry.gauge(
     "xaynet_pool_pages",
-    "Pool pages currently leased, by arena (host | device) and tenant.",
+    "Pool pages currently leased, by arena (host) and tenant.",
     ("arena", "tenant"),
 )
 POOL_LEASES = _registry.counter(
@@ -96,8 +93,8 @@ class PoolExhausted(RuntimeError):
 
 @dataclass
 class PageLease:
-    """One granted page run. ``array`` is the typed view for host leases
-    (None for device-ledger leases). Release is idempotent.
+    """One granted page run and ``array``, its typed view. Release is
+    idempotent.
 
     ``migrator`` opts the lease into compaction: when set, ``compact()``
     may move the run to a lower offset and calls ``migrator(new_view)``
@@ -107,11 +104,10 @@ class PageLease:
     migrator are immovable barriers."""
 
     tenant: str
-    arena: str  # "host" | "device"
     lease_id: int
     pages: int
-    slab: int = -1  # host: owning slab index
-    offset: int = -1  # host: first page within the slab
+    slab: int = -1  # owning slab index
+    offset: int = -1  # first page within the slab
     array: Optional[np.ndarray] = None
     released: bool = field(default=False, repr=False)
     migrator: Optional[object] = field(default=None, repr=False)
@@ -156,15 +152,14 @@ class _Slab:
 
 
 class PagePool:
-    """Host-slab page allocator + device capacity ledger with per-tenant
-    page tables and lease/release accounting (docs/DESIGN.md §19)."""
+    """Host-slab page allocator with per-tenant page tables and
+    lease/release accounting (docs/DESIGN.md §19)."""
 
     def __init__(
         self,
         page_bytes: int = DEFAULT_PAGE_BYTES,
         slab_pages: int = DEFAULT_SLAB_PAGES,
         host_pages: int = 0,
-        device_pages: int = 0,
     ):
         if page_bytes < 4096 or page_bytes % 4096:
             raise ValueError("page_bytes must be a positive multiple of 4096")
@@ -175,12 +170,11 @@ class PagePool:
         # 0 = uncapped (the arena grows by slabs on demand); a cap makes
         # lease() raise PoolExhausted instead of over-committing
         self.host_pages = host_pages
-        self.device_pages = device_pages
         self._lock = threading.Lock()
         self._slabs: list[_Slab] = []  # guarded-by: _lock
         self._leases: dict[int, PageLease] = {}  # guarded-by: _lock
         self._next_id = 0  # guarded-by: _lock
-        self._in_use = {"host": 0, "device": 0}  # pages  # guarded-by: _lock
+        self._in_use = 0  # pages  # guarded-by: _lock
 
     # -- leasing ------------------------------------------------------------
 
@@ -195,10 +189,10 @@ class PagePool:
         nbytes = int(np.prod(shape)) * dtype.itemsize
         pages = self.pages_for(nbytes)
         with self._lock:
-            if self.host_pages and self._in_use["host"] + pages > self.host_pages:
+            if self.host_pages and self._in_use + pages > self.host_pages:
                 raise PoolExhausted(
                     f"host arena: {pages} pages requested, "
-                    f"{self.host_pages - self._in_use['host']} of "
+                    f"{self.host_pages - self._in_use} of "
                     f"{self.host_pages} available"
                 )
             slab_idx, start, fresh = -1, None, False
@@ -216,7 +210,7 @@ class PagePool:
                 slab_idx = len(self._slabs) - 1
                 start = slab.take(pages)
                 fresh = True
-            lease = self._grant_locked(tenant, "host", pages, slab_idx, start)
+            lease = self._grant_locked(tenant, pages, slab_idx, start)
             slab_buf = self._slabs[slab_idx].buf
         raw = slab_buf[start * self.page_bytes : start * self.page_bytes + nbytes]
         view = raw.view(dtype).reshape(shape)
@@ -232,36 +226,19 @@ class PagePool:
         lease.array = view
         return lease
 
-    def lease_device(self, tenant: str, nbytes: int) -> PageLease:
-        """Ledger-only device lease: accounts ``nbytes`` of HBM as pages
-        against the device capacity (device kernels donate buffers, so
-        literal page views cannot survive a fold — DESIGN §19)."""
-        pages = self.pages_for(nbytes)
-        with self._lock:
-            if self.device_pages and self._in_use["device"] + pages > self.device_pages:
-                raise PoolExhausted(
-                    f"device arena: {pages} pages requested, "
-                    f"{self.device_pages - self._in_use['device']} of "
-                    f"{self.device_pages} available"
-                )
-            return self._grant_locked(tenant, "device", pages, -1, -1)
-
-    def _grant_locked(
-        self, tenant: str, arena: str, pages: int, slab: int, offset: int
-    ) -> PageLease:
+    def _grant_locked(self, tenant: str, pages: int, slab: int, offset: int) -> PageLease:
         self._next_id += 1  # lint: guarded-ok: _locked suffix — every caller holds _lock
         lease = PageLease(
             tenant=tenant,
-            arena=arena,
             lease_id=self._next_id,  # lint: guarded-ok: _locked suffix
             pages=pages,
             slab=slab,
             offset=offset if offset is not None else -1,
         )
         self._leases[lease.lease_id] = lease  # lint: guarded-ok: _locked suffix
-        self._in_use[arena] += pages  # lint: guarded-ok: _locked suffix
-        POOL_PAGES.labels(arena=arena, tenant=tenant).inc(pages)
-        POOL_LEASES.labels(arena=arena, tenant=tenant).inc()
+        self._in_use += pages  # lint: guarded-ok: _locked suffix
+        POOL_PAGES.labels(arena=_ARENA, tenant=tenant).inc(pages)
+        POOL_LEASES.labels(arena=_ARENA, tenant=tenant).inc()
         return lease
 
     def release(self, lease: PageLease) -> bool:
@@ -275,13 +252,13 @@ class PagePool:
                 return False
             lease.released = True
             del self._leases[lease.lease_id]
-            self._in_use[lease.arena] -= lease.pages
-            if lease.arena == "host" and 0 <= lease.slab < len(self._slabs):
+            self._in_use -= lease.pages
+            if 0 <= lease.slab < len(self._slabs):
                 self._slabs[lease.slab].give(lease.offset, lease.pages)
         lease.array = None
         lease.migrator = None
-        POOL_PAGES.labels(arena=lease.arena, tenant=lease.tenant).dec(lease.pages)
-        POOL_RELEASES.labels(arena=lease.arena, tenant=lease.tenant).inc()
+        POOL_PAGES.labels(arena=_ARENA, tenant=lease.tenant).dec(lease.pages)
+        POOL_RELEASES.labels(arena=_ARENA, tenant=lease.tenant).inc()
         return True
 
     def set_migrator(self, lease: PageLease, migrator) -> None:
@@ -333,12 +310,11 @@ class PagePool:
         return len(won)
 
     def page_table(self, tenant: str) -> dict[int, dict]:
-        """The tenant's logical->physical mapping: lease id -> arena, slab,
-        page offset, run length (host leases; device leases carry -1)."""
+        """The tenant's logical->physical mapping: lease id -> slab, page
+        offset, run length."""
         with self._lock:
             return {
                 l.lease_id: {
-                    "arena": l.arena,
                     "slab": l.slab,
                     "offset": l.offset,
                     "pages": l.pages,
@@ -387,7 +363,7 @@ class PagePool:
         with self._lock:
             by_slab: dict[int, list[PageLease]] = {}
             for lease in self._leases.values():
-                if lease.arena == "host" and 0 <= lease.slab < len(self._slabs):
+                if 0 <= lease.slab < len(self._slabs):
                     by_slab.setdefault(lease.slab, []).append(lease)
             for slab_idx, leases in by_slab.items():
                 slab = self._slabs[slab_idx]
@@ -419,7 +395,7 @@ class PagePool:
                 occupied = sorted(
                     (l.offset, l.pages)
                     for l in self._leases.values()
-                    if l.arena == "host" and l.slab == slab_idx
+                    if l.slab == slab_idx
                 )
                 free: list[tuple[int, int]] = []
                 edge = 0
@@ -450,9 +426,8 @@ class PagePool:
             return {
                 "page_bytes": self.page_bytes,
                 "slabs": len(self._slabs),
-                "host_pages_in_use": self._in_use["host"],
+                "host_pages_in_use": self._in_use,
                 "host_pages_free": sum(s.free_pages for s in self._slabs),
-                "device_pages_in_use": self._in_use["device"],
                 "leases": len(self._leases),
                 "tenant_leases": tenant_leases,
                 "fragmentation": self._fragmentation_locked(),
@@ -473,9 +448,7 @@ def get_pool() -> PagePool:
         return _pool
 
 
-def configure_pool(
-    page_kib: int, slab_pages: int, host_pages: int, device_pages: int
-) -> PagePool:
+def configure_pool(page_kib: int, slab_pages: int, host_pages: int) -> PagePool:
     """Install the configured process pool (runner startup). Replaces the
     default instance; existing leases on the old pool keep their slabs
     alive through their own references."""
@@ -484,7 +457,6 @@ def configure_pool(
         page_bytes=page_kib * 1024,
         slab_pages=slab_pages,
         host_pages=host_pages,
-        device_pages=device_pages,
     )
     with _pool_lock:
         _pool = pool

@@ -186,24 +186,6 @@ class TenantScheduler:
         if waited > 0:
             TENANT_SCHED_WAIT.labels(tenant=tenant).inc(waited)
 
-    def try_acquire_idle(self, tenant: str, owner: int) -> bool:
-        """Grant a slot ONLY if the mesh is idle enough to give one away:
-        capacity free AND no regular ``acquire`` waiter pending. Never
-        blocks, never starves a real fold batch — the speculation/overlap
-        engines (docs/DESIGN.md §22) use this to soak up scheduler slack
-        between a round's fold batches. An idle grant is a normal owned
-        slot (same ``release``/``release_owner``), but it is NOT charged
-        to the fairness split: background speculation must not distort
-        the deficit-round-robin ordering of real fold grants."""
-        with self._cond:
-            if self._inflight >= self.max_inflight or self._waiting:
-                return False
-            self._inflight += 1
-            self._owners[owner] = self._owners.get(owner, 0) + 1
-        SCHED_INFLIGHT.inc()
-        TENANT_BATCHES.labels(tenant=tenant).inc()
-        return True
-
     def release(self, owner: int) -> None:
         """Return one slot held by ``owner``."""
         with self._cond:
