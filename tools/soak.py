@@ -443,121 +443,223 @@ def _metric_sum(port: int, family: str) -> float:
 # the model save but BEFORE the journal retires (the idempotent-republish
 # window, the nastiest restart point).
 KILL_MATRIX = ("sum:1", "update:2", "sum2:1", "unmask:publish:1")
+KILL_SEED = 46  # the participants' weights and mask seeds (benchmark/harness/reference.py)
+KILL_CONCURRENCY = 8  # uploads in flight, as the benchmark's flood8
+KILL_SAMPLE = (1_000_000, 1024)  # positions compared with the plain reference, and each edge
 
 
-def _kill_config(port: int, model_len: int, state_dir: str) -> str:
+def _kill_spec(args) -> dict:
+    """The deployment the matrix kills: vector, mask, fold batch, round."""
+    if args.mask:
+        names = args.mask.lower().split("/")
+        if len(names) != 4:
+            raise SystemExit("--mask is GROUP/DATA/BOUND/MODEL, e.g. integer/f32/b0/m6")
+        mask = dict(zip(("group_type", "data_type", "bound_type", "model_type"), names))
+    else:
+        from xaynet_tpu.server.settings import MaskSettings
+
+        shipped = MaskSettings()
+        mask = {key: getattr(shipped, key).name.lower()
+                for key in ("group_type", "data_type", "bound_type", "model_type")}
+    n = args.updates
+    if n % args.batch_size:
+        raise SystemExit(f"--updates {n} is not whole fold batches of {args.batch_size}")
+    den = 1
+    while den < n:
+        den *= 2  # a dyadic scalar: exact in the SDK's encode and in the reference
+    at_size = args.model_len > 1_000_000
+    return {"model_len": args.model_len, "mask": mask, "batch_size": args.batch_size,
+            "updates": n, "scalar_den": den,
+            # a phase window no upload of the size can outlast; a start; a round
+            "time_max": 900.0 if at_size else 20.0,
+            "boot_s": 600 if at_size else 90,
+            "round_s": 1500.0 if at_size else 300.0}
+
+
+def _healthz(url: str) -> dict:
+    from urllib.request import urlopen
+
+    with urlopen(url + "/healthz", timeout=30) as resp:
+        return json.loads(resp.read())
+
+
+def _kill_config(port: int, spec: dict, state_dir: str) -> str:
     """A checkpoint-enabled coordinator config whose durable state (file
     coordinator + model archive + round journal) all lives under
     ``state_dir`` — the restart boots on the SAME tree the kill orphaned.
 
-    ``checkpoint_every_batches = 1`` with ``batch_size = 1`` puts a journal
-    write BEFORE every update acknowledgement, so any accepted message
-    survives any kill point."""
+    ``checkpoint_every_batches = 1`` puts a journal write after every fold
+    batch, before the acknowledgement of the upload that filled it (with the
+    default ``--batch-size 1``: before every acknowledgement); what the
+    benchmark's ``resnet50-f32m6-durable`` sets under ``toml`` is set here."""
     base = CONFIG.format(
         port=port,
-        model_len=model_len,
+        model_len=spec["model_len"],
         model_dir=state_dir,
         agg_device="true",
         agg_wire_ingest="false",
-        agg_batch=1,
+        agg_batch=spec["batch_size"],
         agg_kernel="auto",
-        update_min=3,
-        update_max=3,
+        update_min=spec["updates"],
+        update_max=spec["updates"],
         update_quorum_line="",
         stall_grace=5.0,
         edge_enabled_line="",
-    )
+    ).replace("max = 20.0", f"max = {spec['time_max']}")
     # the template's [storage] table already exists — inject the coordinator
     # backend into it (tomllib rejects a duplicate [storage] section)
     base = base.replace(
         'backend = "filesystem"', 'backend = "filesystem"\ncoordinator = "file"'
     )
+    mask = "".join(f'{key} = "{value}"\n' for key, value in spec["mask"].items())
     return base + (
+        f"\n[mask]\n{mask}"
         "\n[restore]\nenable = true\n"
         "\n[resilience]\n"
         "checkpoint_enabled = true\n"
         "checkpoint_every_batches = 1\n"
-        "checkpoint_every_s = 1.0\n"
         "max_resume_attempts = 3\n"
     )
 
 
-def _drive_crash_round(
-    url: str, model_len: int, expected: bytes | None, label: str,
-    timeout_s: float = 300.0,
-) -> bytes:
-    """Drive ONE deterministic PET round, tolerating a coordinator death
-    and restart mid-round: every fetch retries through the dead-socket
-    window, and ``Participant.tick`` already swallows transport errors into
-    a PENDING transition (the resilient client bridges short gaps on its
-    own). Returns the published global model bytes, byte-compared against
-    ``expected`` when given."""
-    from fractions import Fraction
+def _journal_update_pks(state_dir: str) -> set:
+    """The update participants the journal on disk holds (its header alone
+    is read: the aggregate behind it can be of the model's size)."""
+    import struct
 
+    try:
+        with open(os.path.join(state_dir, "coordinator_state.json.ckpt"), "rb") as f:
+            f.read(7)  # magic
+            (hlen,) = struct.unpack("<I", f.read(4))
+            header = json.loads(f.read(hlen))
+    except (OSError, ValueError, struct.error):
+        return set()
+    return {bytes.fromhex(pk) for pk in header.get("seed_dicts") or {}}
+
+
+def _drive_crash_round(url: str, spec: dict, state_dir: str, label: str,
+                       timeout_s: float) -> tuple[bytes, dict]:
+    """Drive ONE deterministic PET round from the clients' side, through a
+    coordinator's death and restart: the SDK's sum participant, and
+    ``spec["updates"]`` uploads masked and sealed by the benchmark's forge
+    (weights and mask seeds from ``KILL_SEED``, so every round of the matrix
+    sends the same weights and must publish the same bytes) over
+    ``KILL_CONCURRENCY`` connections. Every fetch retries through the
+    dead-socket window and ``Participant.tick`` swallows transport errors.
+    A silo sends again whatever the journal on disk does not hold once the
+    coordinator serves again: the upload that died with its request, and
+    the ones answered 200 since the last entry. Returns the published model's
+    bytes and its comparison with the plain reference."""
     import numpy as np
 
+    from benchmark.harness import forge as forge_mod, reference
     from xaynet_tpu.sdk.client import HttpClient
     from xaynet_tpu.sdk.participant import Participant
     from xaynet_tpu.sdk.simulation import keys_for_task
 
-    def fetch_params():
-        return asyncio.run(HttpClient(url, keep_alive=False).get_round_params())
-
-    def fetch_model() -> bytes:
-        model = asyncio.run(HttpClient(url, keep_alive=False).get_model())
-        return np.asarray(model, dtype=np.float64).tobytes()
-
+    n, model_len, den = spec["updates"], spec["model_len"], spec["scalar_den"]
     deadline = time.time() + timeout_s
-    params = None
-    while params is None:
-        if time.time() > deadline:
-            raise RuntimeError(f"{label}: no round parameters before timeout")
-        try:
-            params = fetch_params()
-        except Exception:
+
+    def retry(call, what: str):
+        while True:
+            if time.time() > deadline:
+                raise RuntimeError(f"{label}: {what} not before timeout")
+            try:
+                value = call()
+                if value is not None:
+                    return value
+            except Exception:
+                pass  # coordinator dead or restarting
             time.sleep(0.2)
-    seed = params.seed.as_bytes()
-    summer = keys_for_task(seed, params.sum, params.update, "sum")
-    upd, start = [], 0
-    while len(upd) < 3:
-        k = keys_for_task(seed, params.sum, params.update, "update", start=start)
-        start += 100000
-        if all(k.public != u.public for u in upd) and k.public != summer.public:
-            upd.append(k)
-    parts = [Participant(url, keys=summer, scalar=Fraction(1, 3))]
-    for i, k in enumerate(upd):
-        p = Participant(url, keys=k, scalar=Fraction(1, 3))
-        p.set_model(np.full(model_len, 0.25 * (i + 1), dtype=np.float32))
-        parts.append(p)
+
+    def probe(call):
+        client = HttpClient(url, keep_alive=False, timeout=600.0)
+        try:
+            return asyncio.run(call(client))
+        finally:
+            client.close()
+
+    def health() -> dict:
+        return _healthz(url)
+
+    forge = forge_mod.Forge(
+        seed=KILL_SEED, order=list(range(n)), scalar_den=den, model_length=model_len,
+        mask=spec["mask"], workers=forge_mod.default_workers())
+    summer = None
     try:
-        closed = False
-        while time.time() < deadline:
-            for p in parts:
-                p.tick()
+        params = retry(lambda: probe(lambda c: c.get_round_params()), "round parameters")
+        seed = params.seed.as_bytes()
+        round_id = retry(lambda: health()["round_id"], "/healthz")
+        summer = Participant(
+            HttpClient(url, timeout=600.0),
+            keys=keys_for_task(seed, params.sum, params.update, "sum"),
+            device_sum2=False, max_message_size=None)
+
+        def settled(done) -> bool:
             try:
-                if fetch_params().seed.as_bytes() != seed:
-                    closed = True
-                    break
-            except Exception:
-                # coordinator dead or restarting: keep the participants'
-                # resend state warm and poll again
+                return bool(done())
+            except Exception:  # coordinator dead or restarting
                 time.sleep(0.2)
-        if not closed:
-            raise RuntimeError(f"{label}: round did not complete")
-        model_bytes = None
-        while model_bytes is None:
-            if time.time() > deadline + 30:
-                raise RuntimeError(f"{label}: model not fetchable after round close")
+                return False
+
+        def tick_until(done, what: str) -> None:
+            while not settled(done):
+                if time.time() > deadline:
+                    raise RuntimeError(f"{label}: {what} not before timeout")
+                summer.tick()
+                if not summer.made_progress():
+                    time.sleep(0.05)
+
+        tick_until(lambda: health()["phase"] == "update", "sum message accepted")
+        sums = retry(lambda: probe(lambda c: c.get_sums()), "sum dictionary")
+        sealed, pks = forge.seal(params.to_dict(), sums, list(range(n)))
+
+        async def send(indices: list) -> set:
+            client = HttpClient(url, timeout=600.0, max_idle=KILL_CONCURRENCY)
+            gate, ok = asyncio.Semaphore(KILL_CONCURRENCY), set()
+
+            async def one(i: int) -> None:
+                async with gate:
+                    try:
+                        await client.send_message(sealed[i])
+                        ok.add(i)
+                    except Exception:
+                        pass  # the coordinator died under it
+
             try:
-                model_bytes = fetch_model()
-            except Exception:
-                time.sleep(0.2)
-        if expected is not None and model_bytes != expected:
-            raise RuntimeError(f"{label}: model NOT byte-identical to the unkilled control")
-        return model_bytes
+                await asyncio.gather(*(one(i) for i in indices))
+            finally:
+                client.close()
+            return ok
+
+        pending, resent = list(range(n)), 0
+        while pending:
+            ok = asyncio.run(send(pending))
+            if len(ok) == len(pending):
+                break
+            if time.time() > deadline:
+                raise RuntimeError(f"{label}: uploads not answered before timeout")
+            retry(lambda: health()["phase"], "coordinator serving again")
+            held = _journal_update_pks(state_dir)
+            pending = [i for i in range(n) if pks[i] not in held]
+            resent += len(pending)
+        tick_until(lambda: health()["round_id"] > round_id, "global model published")
+        model = retry(lambda: probe(lambda c: c.get_model()), "global model")
     finally:
-        for p in parts:
-            p.close()
+        if summer is not None:
+            summer.close()
+        forge.close()
+    model = np.ascontiguousarray(model, dtype=np.float64)
+    pair = forge_mod._mask_config(spec["mask"])
+    add_shift, exp_shift = int(pair.vect.add_shift), int(pair.vect.exp_shift)
+    positions = reference.sample_positions(KILL_SEED, model_len, *KILL_SAMPLE)
+    ref, mean = reference.reference_model(
+        KILL_SEED, list(range(n)), model_len, den, add_shift, exp_shift, positions)
+    cmp = reference.compare(model, ref, positions, mean, den, exp_shift)
+    cmp["resent"] = resent
+    if cmp["mismatched_positions"] != 0:
+        raise RuntimeError(f"{label}: model differs from the plain reference: {cmp}")
+    return model.tobytes(), cmp
 
 
 def run_kill_matrix_soak(args) -> None:
@@ -568,28 +670,39 @@ def run_kill_matrix_soak(args) -> None:
 
     - the restarted coordinator RESUMED the killed phase from the round
       journal (``xaynet_resume_total{phase,outcome="resumed"}`` >= 1);
-    - the published global model is byte-identical to an unkilled control;
-    - zero pool pages stay leased after the round (no leak across a kill);
-    - the restart-to-serving wall (``xaynet_recovery_seconds``) is
-      recorded, in the JSON line the soak prints.
+    - the published global model is byte-identical to an unkilled control
+      and equal to the plain reference bit for bit;
+    - zero pool pages and zero staging ring buffers stay leased after the
+      round (no leak across a kill);
+    - the restart-to-serving wall (``xaynet_recovery_seconds``) and the
+      restart's ``/healthz`` ``startup`` timeline are recorded, in the JSON
+      line the soak prints.
+
+    The coordinator runs on the caller's platform (``JAX_PLATFORMS`` as the
+    caller set it: unset on a chip host, ``cpu`` here), at the size the
+    arguments give; this process and its forge workers stay on the CPU.
     """
     import signal
     import socket
     import threading
 
+    spec = _kill_spec(args)
     coords = [
         c.strip()
         for c in (args.kill_points or ",".join(KILL_MATRIX)).split(",")
         if c.strip()
     ]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ)
     env.pop("XAYNET_KILL_POINT", None)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
+    if env.get("JAX_PLATFORMS", "").strip() == "cpu":
+        flags = env.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # this process, its forge workers, its sum participant
+    boot_s, round_s = spec["boot_s"], spec["round_s"]
 
     def wait_listening(port: int, proc) -> None:
-        deadline = time.time() + 90
+        deadline = time.time() + boot_s
         while time.time() < deadline:
             try:
                 with socket.create_connection(("127.0.0.1", port), timeout=1):
@@ -598,12 +711,12 @@ def run_kill_matrix_soak(args) -> None:
                 if proc.poll() is not None:
                     raise RuntimeError("coordinator exited during startup")
                 time.sleep(0.25)
-        raise RuntimeError("coordinator did not start listening in 90s")
+        raise RuntimeError(f"coordinator did not start listening in {boot_s}s")
 
     def stop(proc) -> None:
         proc.terminate()
         try:
-            proc.wait(timeout=10)
+            proc.wait(timeout=30)
         except subprocess.TimeoutExpired:
             proc.kill()
             proc.wait(timeout=5)
@@ -611,12 +724,12 @@ def run_kill_matrix_soak(args) -> None:
     url = f"http://127.0.0.1:{args.port}"
     t0 = time.perf_counter()
     results = []
-    with tempfile.TemporaryDirectory() as tmp:
+    with tempfile.TemporaryDirectory(dir=args.state_dir) as tmp:
         def boot(state_dir: str, tag: str, extra_env: dict | None = None):
             cfg = os.path.join(state_dir, "coordinator.toml")
             if not os.path.exists(cfg):
                 with open(cfg, "w") as f:
-                    f.write(_kill_config(args.port, args.model_len, state_dir))
+                    f.write(_kill_config(args.port, spec, state_dir))
             log = open(os.path.join(state_dir, f"{tag}.log"), "w")
             proc = subprocess.Popen(
                 [sys.executable, "-m", "xaynet_tpu.server.runner", "-c", cfg],
@@ -631,11 +744,11 @@ def run_kill_matrix_soak(args) -> None:
         proc, log = boot(control_dir, "control")
         try:
             wait_listening(args.port, proc)
-            control = _drive_crash_round(url, args.model_len, None, "control")
+            control, cmp = _drive_crash_round(url, spec, control_dir, "control", round_s)
         finally:
             stop(proc)
             log.close()
-        print(f"control: model {len(control)} bytes", file=sys.stderr)
+        print(f"control: model {len(control)} bytes, {cmp}", file=sys.stderr)
 
         # --- the matrix ---------------------------------------------------
         for coord in coords:
@@ -647,8 +760,8 @@ def run_kill_matrix_soak(args) -> None:
 
             def drive() -> None:
                 try:
-                    box["model"] = _drive_crash_round(
-                        url, args.model_len, control, f"kill {coord}"
+                    box["model"], box["cmp"] = _drive_crash_round(
+                        url, spec, state_dir, f"kill {coord}", 2 * round_s
                     )
                 except BaseException as err:
                     box["error"] = err
@@ -659,22 +772,27 @@ def run_kill_matrix_soak(args) -> None:
                 th.start()
                 # the seeded kill MUST fire: anything else (clean exit,
                 # crash-on-boot, survived round) fails the matrix
-                rc = proc.wait(timeout=240)
+                rc = proc.wait(timeout=round_s)
                 if rc != -signal.SIGKILL:
                     raise RuntimeError(f"{coord}: coordinator exited {rc}, expected SIGKILL")
             finally:
                 log.close()
-            print(f"{coord}: killed (pid {proc.pid})", file=sys.stderr)
+            held = len(_journal_update_pks(state_dir))
+            print(f"{coord}: killed (pid {proc.pid}), {held} updates in the journal",
+                  file=sys.stderr)
             t_restart = time.perf_counter()
             proc, log = boot(state_dir, "restarted")
             try:
                 wait_listening(args.port, proc)
                 restart_wall = time.perf_counter() - t_restart
-                th.join(timeout=300)
+                startup = _healthz(url).get("startup")
+                th.join(timeout=2 * round_s)
                 if th.is_alive():
                     raise RuntimeError(f"{coord}: round did not complete after restart")
                 if "error" in box:
                     raise box["error"]
+                if box["model"] != control:
+                    raise RuntimeError(f"{coord}: model NOT byte-identical to the unkilled control")
                 resumed = _metric_value(
                     args.port, "xaynet_resume_total",
                     {"phase": phase, "outcome": "resumed"},
@@ -688,6 +806,11 @@ def run_kill_matrix_soak(args) -> None:
                 leaked = _metric_sum(args.port, "xaynet_pool_pages")
                 if leaked:
                     raise RuntimeError(f"{coord}: {leaked:g} pool pages leaked")
+                ring = _metric_sum(args.port, "xaynet_streaming_staging_depth") + _metric_sum(
+                    args.port, "xaynet_streaming_shard_staging_depth")
+                if ring:
+                    raise RuntimeError(f"{coord}: {ring:g} staging ring buffers still leased")
+                journal = _healthz(url).get("journal")
             finally:
                 stop(proc)
                 log.close()
@@ -700,18 +823,29 @@ def run_kill_matrix_soak(args) -> None:
                 {
                     "kill_point": coord,
                     "phase": phase,
+                    "journalled_at_kill": held,
+                    "resent": box["cmp"]["resent"],
                     "resumed": resumed,
                     "recovery_s": recovery_s,
                     "restart_to_serving_s": round(restart_wall, 3),
+                    "startup": startup,
                     "byte_identical": True,
+                    "mismatched_positions": box["cmp"]["mismatched_positions"],
+                    "positions_compared": box["cmp"]["positions_compared"],
                     "pool_pages_leaked": leaked,
+                    "ring_buffers_leased": ring,
+                    "journal": journal,
                 }
             )
     print(
         json.dumps(
             {
                 "kill_matrix": results,
-                "model_len": args.model_len,
+                "model_len": spec["model_len"],
+                "mask": "/".join(spec["mask"].values()),
+                "batch_size": spec["batch_size"],
+                "updates": spec["updates"],
+                "platform": env.get("JAX_PLATFORMS") or "default",
                 "byte_identical": True,
                 "wall_s": round(time.perf_counter() - t0, 2),
             }
@@ -1326,9 +1460,14 @@ def main() -> None:
         help="SIGKILL-matrix chaos soak: kill the coordinator at seeded "
         "(phase, message-index) coordinates, restart it on the same durable "
         "tree and drive the surviving participants to completion — the "
-        "global model must be byte-identical to an unkilled control, the "
-        "killed phase must RESUME from the round journal, and zero pool "
-        "pages may leak (docs/DESIGN.md §9)",
+        "global model must be byte-identical to an unkilled control and "
+        "equal to the plain reference bit for bit, the killed phase must "
+        "RESUME from the round journal, and zero pool pages or ring buffers "
+        "may stay leased (docs/DESIGN.md §9). The coordinator runs on the "
+        "caller's platform (JAX_PLATFORMS as set: cpu here, unset on a chip "
+        "host) and at the size given by --model-len, --mask, --batch-size "
+        "and --updates; sites: sum, update, sum2:base, sum2, unmask:start, "
+        "unmask:publish",
     )
     ap.add_argument(
         "--kill-points",
@@ -1337,6 +1476,36 @@ def main() -> None:
         help="with --kill-matrix: comma-separated kill coordinates "
         "(default: the full matrix sum:1,update:2,sum2:1,unmask:publish:1); "
         "CI smoke runs a one-per-phase-family subset",
+    )
+    ap.add_argument(
+        "--mask",
+        default=None,
+        metavar="GROUP/DATA/BOUND/MODEL",
+        help="with --kill-matrix: the mask configuration, e.g. integer/f32/b0/m6 "
+        "(default: the shipped one)",
+    )
+    ap.add_argument(
+        "--batch-size",
+        type=int,
+        default=1,
+        metavar="K",
+        help="with --kill-matrix: the fold batch; a journal entry is written "
+        "after each (default 1: before every acknowledgement)",
+    )
+    ap.add_argument(
+        "--updates",
+        type=int,
+        default=3,
+        metavar="N",
+        help="with --kill-matrix: uploads a round, whole fold batches (default 3)",
+    )
+    ap.add_argument(
+        "--state-dir",
+        default=None,
+        metavar="DIR",
+        help="with --kill-matrix: where the coordinators' durable trees are "
+        "made, one a coordinate, and removed at the end (default: the "
+        "system's temporary directory)",
     )
     ap.add_argument(
         "--faults",

@@ -8,6 +8,10 @@ power-loss the journal must survive. Sites:
 
 - ``sum`` / ``update`` / ``sum2``: after the n-th accepted (and
   journaled) message of that phase;
+- ``sum2:base``: after the Sum2 phase's base entry (the finished aggregate)
+  is written, BEFORE its vote window opens;
+- ``unmask:start``: at the entry of the Unmask phase, the ``unmask``-tagged
+  entry written and nothing of the model computed or stored;
 - ``unmask:publish``: after the global model is persisted but BEFORE the
   journal entry is deleted — the publish window.
 
@@ -47,5 +51,10 @@ def maybe_kill(site: str) -> None:
         return
     if _visits[site] >= n:
         logger.warning("kill point %s reached (visit %d): SIGKILL", spec, _visits[site])
-        logging.shutdown()
-        os.kill(os.getpid(), signal.SIGKILL)
+        _die()
+
+
+def _die() -> None:
+    """The kill itself (tests of the resume path put their own in its place)."""
+    logging.shutdown()
+    os.kill(os.getpid(), signal.SIGKILL)

@@ -48,6 +48,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..telemetry import journal
 from ..telemetry.registry import get_registry
 
 logger = logging.getLogger("xaynet.resilience")
@@ -413,22 +414,30 @@ def entry(
     )
 
 
-async def write_entry(shared, ckpt: RoundCheckpoint) -> bool:
+async def write_entry(shared, ckpt: RoundCheckpoint, write=None) -> bool:
     """Serialize + persist one journal entry, fail-soft.
 
     The store call rides the ResilientStore retry policy (runner wraps
     every storage method); exhaustion lands on
     ``xaynet_checkpoint_save_failures_total`` and the round CONTINUES — a
     journal write must never fail the phase it exists to protect.
+
+    ``write`` is the ``telemetry.journal.Write`` of a caller that has
+    already bracketed stages of its own (a drain, a fetch); without one the
+    entry is a write of its own.
     """
+    if write is None:
+        with journal.write(ckpt.phase, nb_models=ckpt.nb_models) as own:
+            return await write_entry(shared, ckpt, own)
     import asyncio
 
     try:
         loop = asyncio.get_running_loop()
         # serialization sha256-hashes the model-sized aggregate — CPU work
         # that must not stall the loop serving the API
-        blob = await loop.run_in_executor(None, ckpt.to_bytes)
-        await shared.store.coordinator.set_round_checkpoint(blob)
+        blob = await loop.run_in_executor(None, _serialise, ckpt, write)
+        with write.stage("store", bytes=len(blob)):
+            await shared.store.coordinator.set_round_checkpoint(blob)
     except asyncio.CancelledError:
         raise
     except Exception as e:
@@ -438,8 +447,49 @@ async def write_entry(shared, ckpt: RoundCheckpoint) -> bool:
         CHECKPOINTS.labels(outcome="failed").inc()
         SAVE_FAILURES.inc()
         return False
+    write.saved(len(blob))
     CHECKPOINTS.labels(outcome="saved").inc()
     return True
+
+
+def _serialise(ckpt: RoundCheckpoint, write) -> bytes:
+    """``to_bytes`` under its stage, on the executor thread that runs it."""
+    with write.stage("serialise", nb_models=ckpt.nb_models) as span:
+        blob = ckpt.to_bytes()
+        span.set(bytes=len(blob))
+    return blob
+
+
+def snapshot(aggregator, write) -> AggSnapshot:
+    """The aggregate for a journal entry, on an executor thread: the
+    pipeline's barrier and the device-to-host copy, each under its stage."""
+    with write.stage("drain"):
+        aggregator.drain()
+    return fetch(aggregator, write)
+
+
+def fetch(aggregator, write) -> AggSnapshot:
+    """The device-to-host copy of a drained aggregate under its stage
+    (``snapshot_journal`` runs the barrier again before it reads, and finds
+    the pipeline settled)."""
+    with write.stage("fetch") as span:
+        snap = aggregator.snapshot_journal()
+        if snap.planes is not None:
+            nbytes = sum(int(plane.nbytes) for _, _, plane in snap.planes)
+        else:
+            nbytes = int(snap.vect.nbytes)
+        span.set(bytes=nbytes, nb_models=snap.nb_models)
+    return snap
+
+
+async def round_dicts(shared, write) -> tuple[dict, dict]:
+    """The store's sum dictionary and its seed dictionaries in the
+    journal's replay form, under their stage."""
+    with write.stage("dicts"):
+        coord = shared.store.coordinator
+        seed_dict = await coord.seed_dict()
+        sum_dict = await coord.sum_dict() or {}
+        return sum_dict, invert_seed_dict(seed_dict)
 
 
 async def validate(
@@ -569,33 +619,33 @@ class CheckpointManager:
 
         self._batches_since = 0
         self._last_save = now
-        try:
-            with CHECKPOINT_SECONDS.time():
-                loop = asyncio.get_running_loop()
-                # drain + snapshot off the event loop: the drain blocks on
-                # in-flight device folds
-                snap = await loop.run_in_executor(
-                    None, self.aggregator.snapshot_journal
-                )
-                coord = self.shared.store.coordinator
-                seed_dict = await coord.seed_dict()
-                sum_dict = await coord.sum_dict()
-                ckpt = entry(
-                    self.shared,
-                    "update",
-                    snap,
-                    sum_dict=sum_dict,
-                    seed_dicts=invert_seed_dict(seed_dict),
-                )
-                if not await write_entry(self.shared, ckpt):
-                    return False
-        except asyncio.CancelledError:
-            raise
-        except Exception as e:
-            logger.warning("round %d: checkpoint save failed: %s", self.shared.round_id, e)
-            CHECKPOINTS.labels(outcome="failed").inc()
-            SAVE_FAILURES.inc()
-            return False
+        # the failure paths share one count: the write's outcome
+        with journal.write("update") as write:
+            try:
+                with CHECKPOINT_SECONDS.time():
+                    loop = asyncio.get_running_loop()
+                    # drain + snapshot off the event loop: the drain blocks on
+                    # in-flight device folds
+                    snap = await loop.run_in_executor(
+                        None, snapshot, self.aggregator, write
+                    )
+                    sum_dict, seed_dicts = await round_dicts(self.shared, write)
+                    ckpt = entry(
+                        self.shared,
+                        "update",
+                        snap,
+                        sum_dict=sum_dict,
+                        seed_dicts=seed_dicts,
+                    )
+                    if not await write_entry(self.shared, ckpt, write):
+                        return False
+            except asyncio.CancelledError:
+                raise
+            except Exception as e:
+                logger.warning("round %d: checkpoint save failed: %s", self.shared.round_id, e)
+                CHECKPOINTS.labels(outcome="failed").inc()
+                SAVE_FAILURES.inc()
+                return False
         self.saves += 1
         logger.info(
             "round %d: journaled update aggregate (%d models, watermark %d)",

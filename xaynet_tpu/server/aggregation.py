@@ -32,7 +32,7 @@ from ..core.mask.object import LazyWireMaskVect, MaskObject, MaskUnit, MaskVect
 from ..ops import limbs as limb_ops
 from ..ops.limbs import PlanarLimbs
 from ..resilience.checkpoint import AggSnapshot
-from ..telemetry import profiling
+from ..telemetry import journal, profiling
 from ..telemetry import tracing as trace
 from ..telemetry import unmask as unmask_stages
 from ..utils.tracing import current_request_id
@@ -622,13 +622,14 @@ class StagedAggregator:
         already built."""
         if ckpt.nb_models == 0:
             return
-        if self._device is not None and ckpt.planes:
-            if self._count or self.nb_models:
-                raise RuntimeError("restore_journal requires an empty aggregator")
-            self._device.restore_shards(ckpt.planes, ckpt.nb_models)
-            self._unit_acc = np.ascontiguousarray(ckpt.unit, dtype=np.uint32)
-            return
-        self.restore_state(ckpt.wire_vect(), ckpt.unit, ckpt.nb_models)
+        with journal.resume_stage("restore", phase=ckpt.phase, nb_models=ckpt.nb_models):
+            if self._device is not None and ckpt.planes:
+                if self._count or self.nb_models:
+                    raise RuntimeError("restore_journal requires an empty aggregator")
+                self._device.restore_shards(ckpt.planes, ckpt.nb_models)
+                self._unit_acc = np.ascontiguousarray(ckpt.unit, dtype=np.uint32)
+                return
+            self.restore_state(ckpt.wire_vect(), ckpt.unit, ckpt.nb_models)
 
     def restore_state(self, vect: np.ndarray, unit: np.ndarray, nb_models: int) -> None:
         """Restore a checkpoint snapshot into an EMPTY aggregator (resume)."""

@@ -20,6 +20,7 @@ import logging
 
 from ...resilience.chaos import maybe_kill
 from ...resilience.checkpoint import RoundCheckpoint, entry, write_entry
+from ...telemetry import journal
 from ..events import DictionaryUpdate, PhaseName
 from ..requests import RequestError, StateMachineRequest, SumRequest
 from .base import PhaseError, PhaseState, reduce_count_window
@@ -76,6 +77,8 @@ class SumPhase(PhaseState):
             # journal-before-ack: the accepted participant is durable before
             # the acknowledgement leaves (one rewrite per accept; the sum
             # dictionary is tiny relative to the update-phase aggregate)
-            sum_dict = await self.shared.store.coordinator.sum_dict() or {}
-            await write_entry(self.shared, entry(self.shared, "sum", sum_dict=sum_dict))
+            with journal.write("sum") as write:
+                with write.stage("dicts"):
+                    sum_dict = await self.shared.store.coordinator.sum_dict() or {}
+                await write_entry(self.shared, entry(self.shared, "sum", sum_dict=sum_dict), write)
         maybe_kill("sum")

@@ -35,9 +35,11 @@ from ...resilience.chaos import maybe_kill
 from ...resilience.checkpoint import (
     RoundCheckpoint,
     entry,
-    invert_seed_dict,
+    fetch,
+    round_dicts,
     write_entry,
 )
+from ...telemetry import journal
 from ...telemetry import tracing as trace
 from ...telemetry.timeline import record_overlap
 from .. import stages
@@ -90,31 +92,34 @@ class Sum2Phase(PhaseState):
 
     async def process(self) -> None:
         params = self.shared.settings.pet.sum2
-        drain = asyncio.get_running_loop().run_in_executor(None, self._drain_overlapped)
-        if self._journal:
+        loop = asyncio.get_running_loop()
+        drain = None
+        if self._journal and self._resume_from is None:
             # journal-ready-before-first-vote-ack: the base entry snapshots
-            # the finished aggregate, so the drain must complete BEFORE the
-            # window opens
+            # the finished aggregate, so the drain completes BEFORE the
+            # window opens, as that entry's first stage
+            await self._build_base()
+        else:
+            drain = loop.run_in_executor(None, self._drain_overlapped)
+        if self._resume_from is not None:
             await drain
-            if self._resume_from is not None:
-                await self._rebroadcast_dicts()
-                self.arrivals_offset = len(self._votes)
-                params = reduce_count_window(params, len(self._votes))
-                self._base = self._resume_from
-                logger.info(
-                    "round %d: sum2 phase RESUMED from journal (%d votes restored)",
-                    self.shared.round_id,
-                    len(self._votes),
-                )
-            else:
-                await self._build_base()
+            await self._rebroadcast_dicts()
+            self.arrivals_offset = len(self._votes)
+            params = reduce_count_window(params, len(self._votes))
+            self._base = self._resume_from
+            logger.info(
+                "round %d: sum2 phase RESUMED from journal (%d votes restored)",
+                self.shared.round_id,
+                len(self._votes),
+            )
         try:
             await self.process_requests(params)
         finally:
             # the drain's window closes with the phase: fold errors
-            # surface HERE, never past sum2 (a journalled phase awaited it
-            # above, and awaits a finished future here)
-            await drain
+            # surface HERE, never past sum2 (a journalled phase has
+            # awaited it above)
+            if drain is not None:
+                await drain
 
     async def _rebroadcast_dicts(self) -> None:
         """Participants contacting a restarted coordinator need the round
@@ -130,22 +135,28 @@ class Sum2Phase(PhaseState):
 
     async def _build_base(self) -> None:
         """Journal the Update -> Sum2 transition: the finished aggregate +
-        the sealed dictionaries, written before the first vote is acked."""
+        the sealed dictionaries, written before the first vote is acked.
+        The phase's drain is this entry's first stage: without a journal it
+        is hidden under the vote window."""
         loop = asyncio.get_running_loop()
-        # drain + snapshot off the event loop (blocks on in-flight folds)
-        snap = await loop.run_in_executor(None, self.aggregator.snapshot_journal)
-        coord = self.shared.store.coordinator
-        sum_dict = await coord.sum_dict() or {}
-        seed_dicts = invert_seed_dict(await coord.seed_dict())
-        self._base = entry(
-            self.shared,
-            "sum2",
-            snap,
-            sum_dict=sum_dict,
-            seed_dicts=seed_dicts,
-            mask_votes=self._votes,
-        )
-        await write_entry(self.shared, self._base)
+        with journal.write("sum2") as write:
+            # drain + snapshot off the event loop (blocks on in-flight folds)
+            with write.stage("drain"):
+                await loop.run_in_executor(None, self._drain_overlapped)
+            snap = await loop.run_in_executor(None, fetch, self.aggregator, write)
+            sum_dict, seed_dicts = await round_dicts(self.shared, write)
+            self._base = entry(
+                self.shared,
+                "sum2",
+                snap,
+                sum_dict=sum_dict,
+                seed_dicts=seed_dicts,
+                mask_votes=self._votes,
+            )
+            await write_entry(self.shared, self._base, write)
+        # chaos hook (kill-matrix harness): the finished aggregate is
+        # journalled and no vote has been asked for yet
+        maybe_kill("sum2:base")
 
     def broadcast(self) -> None:
         # the round's dictionaries are spent once the masks are in

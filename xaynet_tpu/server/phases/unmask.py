@@ -61,6 +61,9 @@ class Unmask(PhaseState):
         self._model_bytes: bytes | None = None
 
     async def process(self) -> None:
+        # chaos hook (kill-matrix harness): the journal says `unmask`, and
+        # nothing of the model has been computed or stored
+        maybe_kill("unmask:start")
         # the phase, stage by stage (telemetry/unmask.py): the brackets here,
         # in the aggregation's ``unmask_array`` (mask_put, subtract, fetch,
         # decode) and nothing between them

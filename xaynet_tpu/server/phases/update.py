@@ -30,6 +30,7 @@ import logging
 from ...core.mask.masking import AggregationError
 from ...resilience.chaos import maybe_kill
 from ...resilience.checkpoint import CheckpointManager, RoundCheckpoint, entry, write_entry
+from ...telemetry import journal
 from ...telemetry.registry import get_registry
 from .. import stages
 from ..aggregation import StagedAggregator, build_staged_aggregator
@@ -111,8 +112,12 @@ class UpdatePhase(PhaseState):
             # seal the Sum -> Update transition: a crash before the first
             # accepted update must resume into Update with the frozen sum
             # dictionary, not restart the round from Idle
-            sum_dict = await self.shared.store.coordinator.sum_dict() or {}
-            await write_entry(self.shared, entry(self.shared, "update", sum_dict=sum_dict))
+            with journal.write("update") as write:
+                with write.stage("dicts"):
+                    sum_dict = await self.shared.store.coordinator.sum_dict() or {}
+                await write_entry(
+                    self.shared, entry(self.shared, "update", sum_dict=sum_dict), write
+                )
         if self._ckpt is not None:
             # graceful-signal flush: the journal cadence may lag the live
             # aggregate; a SIGTERM mid-phase forces one final save (runner)

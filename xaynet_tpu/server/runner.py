@@ -118,12 +118,19 @@ def init_logging(settings: Settings) -> None:
             handler.addFilter(tracing.RequestIdFilter())
 
 
-def _health_sections(handler: PetMessageHandler, device_report):
+def _health_sections(handler: PetMessageHandler, device_report, resilience):
     """The runner's own sections of ``/healthz``: the size of the process's
-    ``pet-msg`` pool, and the device's report where one aggregates."""
+    ``pet-msg`` pool, the round journal's writes, and the device's report
+    where one aggregates."""
+    from ..telemetry import journal
 
     def report() -> dict:
-        out = {"message_workers": handler.workers.size}
+        out = {
+            "message_workers": handler.workers.size,
+            "journal": journal.report(
+                resilience.checkpoint_enabled, resilience.checkpoint_every_batches
+            ),
+        }
         if device_report is not None:
             out.update(device_report())
         return out
@@ -229,7 +236,7 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
         registry=metrics.registry,
         pipeline=pipeline,
         edge_api=edge_api,
-        health_extra=_health_sections(handler, device_report),
+        health_extra=_health_sections(handler, device_report, settings.resilience),
     )
     host, _, port = settings.api.bind_address.partition(":")
     tls = None
@@ -387,7 +394,7 @@ async def _build_tenant_context(settings: Settings, tenant: str, budget, registr
         handler=handler,
         pipeline=pipeline,
         edge_api=edge_api,
-        health_extra=_health_sections(handler, device_report),
+        health_extra=_health_sections(handler, device_report, settings.resilience),
     )
     logger.info(
         "tenant %s: model_len=%d group=%s (round pipeline up)",

@@ -14,6 +14,7 @@ import numpy as np
 
 from ..resilience import checkpoint as ckpt_mod
 from ..storage.traits import Store
+from ..telemetry import journal
 from ..telemetry.bridge import BridgedMetrics
 from .coordinator import CoordinatorState
 from .events import EventPublisher, EventSubscriber, ModelUpdate, PhaseName
@@ -119,13 +120,15 @@ class StateMachineInitializer:
         can retry. Returns a phase factory or None."""
         if not self.settings.resilience.checkpoint_enabled:
             return None
-        ckpt = await ckpt_mod.load(self.store)
+        with journal.resume_stage("load"):
+            ckpt = await ckpt_mod.load(self.store)
         if ckpt is None:
             return None
-        try:
-            reason = await ckpt_mod.validate(ckpt, state, self.store, reseed=True)
-        except Exception as err:
-            reason = f"validation failed: {err}"
+        with journal.resume_stage("validate", phase=ckpt.phase, nb_models=ckpt.nb_models):
+            try:
+                reason = await ckpt_mod.validate(ckpt, state, self.store, reseed=True)
+            except Exception as err:
+                reason = f"validation failed: {err}"
         if reason is not None:
             logger.warning(  # lint: taint-ok: reason carries counts/names only, never key bytes
                 "round journal not resumable (%s); starting at Idle", reason
