@@ -18,7 +18,9 @@ round resumes into the killed phase and publishes the reference's model over
 every counted update; (c) one round writes the expected entries by phase and
 ``xaynet_journal_*`` and ``/healthz`` say so; (d) a write that exhausts its
 retries is counted and the round goes on; (e) what an earlier process left
-in the directory never stops a start.
+in the directory never stops a start; (f) the sections a round's entries
+carry are counted by what became of them, and each is hashed once; (g) the
+single-file journal a process of the program before left is resumed.
 """
 
 import asyncio
@@ -54,7 +56,11 @@ from xaynet_tpu.server.settings import (
     TimeSettings,
 )
 from xaynet_tpu.server.state_machine import StateMachineInitializer
+from xaynet_tpu.storage.memory import FileCoordinatorStorage
+from xaynet_tpu.storage.traits import join_entry
 from xaynet_tpu.telemetry import journal
+
+from journal_reference import CountingHashlib, reference_to_bytes
 
 K, MODEL_LEN, N_UPDATE = 3, 257, 6
 SUM_PROB, UPDATE_PROB = 0.4, 0.5
@@ -287,6 +293,19 @@ async def _round(settings: Settings, weights: list[np.ndarray]) -> dict:
         await coord.stop()
 
 
+def _journal_on_disk(model_dir):
+    """The entry the directory holds, as a restart's store reads it."""
+    blob = FileCoordinatorStorage(
+        os.path.join(model_dir, "coordinator_state.json"))._read_ckpt()
+    return None if blob is None else ckpt_mod.RoundCheckpoint.from_bytes(blob)
+
+
+def _beside_the_head(model_dir) -> list[str]:
+    """The section files of the journal, and whatever else lies beside its head."""
+    return sorted(f for f in os.listdir(model_dir)
+                  if f.startswith("coordinator_state.json.ckpt."))
+
+
 def _run(coro, timeout: float = 150.0):
     return asyncio.run(asyncio.wait_for(coro, timeout))
 
@@ -315,6 +334,7 @@ def test_journalled_round_equals_the_reference_and_the_unjournalled_round(
     with open(tmp_path / "kept" / stored[0], "rb") as f:
         assert f.read() == kept["model"].tobytes()
     assert not os.path.exists(tmp_path / "kept" / "coordinator_state.json.ckpt")
+    assert _beside_the_head(tmp_path / "kept") == []  # nor a section of it
 
 
 # --- (b) killed and restarted on the same directory --------------------------
@@ -325,15 +345,25 @@ KILLS = {
     "sum2:base:1": ("sum2", N_UPDATE),
     "sum2:1": ("sum2", N_UPDATE),
     "unmask:start:1": ("unmask", N_UPDATE),
+    # the file store's own point, sections written and the head not renamed,
+    # at its second visit (the second fold batch's entry: the first batch's
+    # is what the restart finds) and its fourth (the vote's: Sum2's base)
+    "journal:sections:2": ("update", K),
+    "journal:sections:4": ("sum2", N_UPDATE),
 }
 
 
-@pytest.mark.parametrize("point", list(KILLS))
-def test_killed_and_restarted_round_publishes_the_reference(point, one_device, kill_at, tmp_path):
+def _killed_then_restarted(point, kill_at, tmp_path, between=None):
+    """One round killed at ``point`` and restarted on the same directory
+    (``between`` may touch the directory while no process lives): asserts
+    what the journal holds at the death and that the restart resumes into
+    the killed phase; returns the published model, the reference's, and the
+    ring buffers still leased."""
     mask = "int-b0m6"
     phase, journalled = KILLS[point]
     weights = _weights(1.0)
     settings = _settings(mask, tmp_path)
+    failed0 = sum(journal.report(True, 1)["failed"].values())
 
     async def killed_run():
         coord = await Coordinator(settings).start()
@@ -400,33 +430,72 @@ def test_killed_and_restarted_round_publishes_the_reference(point, one_device, k
             await coord.stop()
 
     seed, summer_blob, staged = _run(killed_run())
-    if phase == "update":
+    if point.startswith("update:"):
         # the first batch is journalled; two rows of the second were written
         # into their slots as they arrived (the first of them was answered)
         assert staged == K + 2
-        with open(tmp_path / "coordinator_state.json.ckpt", "rb") as f:
-            entry = ckpt_mod.RoundCheckpoint.from_bytes(f.read())
-        assert (entry.phase, entry.nb_models, entry.seed_watermark) == ("update", K, K)
+    entry = _journal_on_disk(tmp_path)
+    assert (entry.phase, entry.nb_models, entry.seed_watermark) == (
+        phase, journalled, journalled)
+    named = {f"coordinator_state.json.ckpt.{s.digest}" for s in entry.sections() if s.nbytes}
+    if point.startswith("journal:sections:"):
+        # the entry that died left its sections beside the live entry's, and
+        # no vote: the head that names them was never renamed into place
+        assert named < set(_beside_the_head(tmp_path)) and not entry.mask_votes
+    else:
+        assert named == set(_beside_the_head(tmp_path))
+    if between is not None:
+        between(entry)
     model, leased, health = _run(restarted_run(seed, summer_blob))
+    assert _beside_the_head(tmp_path) == []  # the retire took every section
+    # no write failed but the one that died in the store's hands (which a
+    # process that dies does not live to count)
+    failed = sum(health["journal"]["failed"].values()) - failed0
+    assert failed == (1 if point.startswith("journal:sections:") else 0)
+    assert health["journal"]["enabled"] is True
+    return model, _want(mask, weights), leased
+
+
+@pytest.mark.parametrize("point", list(KILLS))
+def test_killed_and_restarted_round_publishes_the_reference(point, one_device, kill_at, tmp_path):
+    model, want, leased = _killed_then_restarted(point, kill_at, tmp_path)
     # every update is in the model once: the journalled ones from the
     # journal, the others from their second sending
-    assert _bits_equal(model, _want(mask, weights))
+    assert _bits_equal(model, want)
     assert leased == 0  # no ring buffer stays leased
-    assert health["journal"]["enabled"] is True and not health["journal"]["failed"]
+
+
+# --- (g) the journal a process of the program before left --------------------
+
+
+@pytest.mark.parametrize("point", [f"update:{K + 2}", "sum2:1"])
+def test_a_single_file_journal_written_by_the_reference_serialiser_resumes(
+        point, one_device, kill_at, tmp_path):
+    journal_path = tmp_path / "coordinator_state.json.ckpt"
+
+    def as_the_parent_left_it(entry):
+        for name in _beside_the_head(tmp_path):
+            os.remove(tmp_path / name)
+        journal_path.write_bytes(reference_to_bytes(entry))
+
+    model, want, leased = _killed_then_restarted(
+        point, kill_at, tmp_path, between=as_the_parent_left_it)
+    assert _bits_equal(model, want)
+    assert leased == 0 and not journal_path.exists()
 
 
 # --- (c) what one round writes ----------------------------------------------
 
 
 def test_one_round_writes_the_expected_entries_and_counts_them(one_device, tmp_path, monkeypatch):
-    from xaynet_tpu.storage.memory import FileCoordinatorStorage
-
-    blobs: list[bytes] = []
+    blobs: list[bytes] = []  # each entry as `round_checkpoint()` would return it
+    handed: list[int] = []  # and the bytes its write handed the store
     real = FileCoordinatorStorage._write_ckpt
 
-    def write_ckpt(self, data):
-        blobs.append(bytes(data))
-        real(self, data)
+    def write_ckpt(self, head, sections=()):
+        blobs.append(join_entry(head, sections))
+        handed.append(len(head) + sum(s.nbytes for s in sections if not s.stored))
+        real(self, head, sections)
 
     monkeypatch.setattr(FileCoordinatorStorage, "_write_ckpt", write_ckpt)
 
@@ -456,7 +525,7 @@ def test_one_round_writes_the_expected_entries_and_counts_them(one_device, tmp_p
     for p in PHASES:
         assert moved["writes", p, "saved"] == want_writes[p]
         assert moved["writes", p, "failed"] == 0
-        assert moved["bytes", p] == sum(len(b) for b, t in zip(blobs, tags) if t.phase == p)
+        assert moved["bytes", p] == sum(n for n, t in zip(handed, tags) if t.phase == p)
         # every stage a write has is observed once a write
         assert moved["stage", "total", p] == want_writes[p]
         assert moved["stage", "serialise", p] == moved["stage", "store", p] == want_writes[p]
@@ -471,6 +540,15 @@ def test_one_round_writes_the_expected_entries_and_counts_them(one_device, tmp_p
     limbs = 2
     assert all(len(b) > 4 * limbs * MODEL_LEN for b, t in zip(blobs, tags) if t.nb_models)
     assert config.bytes_per_number == 7
+    # a write hands the store what no earlier entry handed it: the entries
+    # of the fold batches and Sum2's base their aggregate, the vote's entry
+    # the vote, the `unmask` entry a head
+    assert [n == len(b) for n, b in zip(handed, blobs)] == [True] * 5 + [False] * 2
+    planes = sum(plane.nbytes for _, _, plane in tags[6].planes)
+    vote = len(tags[6].mask_votes[0][1])
+    assert handed[5] == len(blobs[5]) - planes - tags[5].unit.nbytes
+    assert handed[6] == len(blobs[6]) - planes - tags[6].unit.nbytes - vote
+    assert blobs[6] == reference_to_bytes(tags[6])
 
     section = out["health"]["journal"]
     assert (section["enabled"], section["every_batches"]) == (True, 1)
@@ -478,10 +556,71 @@ def test_one_round_writes_the_expected_entries_and_counts_them(one_device, tmp_p
         assert section["writes"][p] - health0["writes"].get(p, 0) == want_writes[p]
     assert section["failed"] == health0["failed"]
     assert section["last"]["phase"] == "unmask" and section["last"]["outcome"] == "saved"
-    assert section["last"]["bytes"] == len(blobs[-1]) and section["last"]["seconds"] > 0.0
+    assert section["last"]["bytes"] == handed[-1] and section["last"]["seconds"] > 0.0
+    # the last entry carried the aggregate, the unit and the vote, and wrote none of them
+    assert section["last"]["written"] == 0
+    assert section["last"]["reused"] == len(blobs[-1]) - handed[-1]
     mirrored = out["health"]["trace"]["mirrored_spans"]
     assert {f"journal.{stage}" for stage in STAGES if stage != "total"} <= set(mirrored)
     assert "journal.total" not in mirrored  # it would cover the five and say nothing
+
+
+# --- (f) what became of each section, and how often it was hashed ------------
+
+
+def test_one_rounds_sections_are_counted_by_route_and_each_is_hashed_once(
+        devices, tmp_path, monkeypatch):
+    counting = CountingHashlib()
+    monkeypatch.setattr(ckpt_mod, "hashlib", counting)
+    entries: list = []
+    real = FileCoordinatorStorage._write_ckpt
+
+    def write_ckpt(self, head, sections=()):
+        entries.append({s.name: (s.nbytes, s.stored, s.digest) for s in sections})
+        real(self, head, sections)
+
+    monkeypatch.setattr(FileCoordinatorStorage, "_write_ckpt", write_ckpt)
+
+    def counters():
+        return {(name, route): journal.SECTION_BYTES.labels(section=name, route=route).value
+                for name in ("vect", "unit", "votes", "planes")
+                for route in ("written", "reused")}
+
+    mask = "int-b0m6"
+    weights = _weights(1.0)
+    before = counters()
+    out = _run(_round(_settings(mask, tmp_path), weights))
+    moved = {key: value - before[key] for key, value in counters().items()}
+    assert _bits_equal(out["model"], _want(mask, weights))
+
+    # sum, the seal | batch, batch | Sum2's base, the vote, `unmask`
+    assert len(entries) == 7
+    agg = entries[2]["planes"][0]  # the accumulator as the journal holds it
+    unit = entries[2]["unit"][0]
+    vote = entries[5]["votes"][0]
+    assert agg >= 4 * 2 * MODEL_LEN and unit == 4 * 2 and vote > 7 * MODEL_LEN
+    # written: an aggregate twice in Update and once in Sum2, the vote once;
+    # reused: the finished aggregate twice and the vote once
+    assert moved == {
+        ("planes", "written"): 3 * agg, ("planes", "reused"): 2 * agg,
+        ("unit", "written"): 3 * unit, ("unit", "reused"): 2 * unit,
+        ("votes", "written"): vote, ("votes", "reused"): vote,
+        ("vect", "written"): 0, ("vect", "reused"): 0,  # a device round journals planes
+    }
+    assert [e["planes"][1] for e in entries[2:]] == [False, False, False, True, True]
+    assert [e["votes"][1] for e in entries[5:]] == [False, True]
+    # Sum2's three entries carry one aggregate; so does the last batch's
+    # (the drain found nothing left to fold), whose file the base entry's
+    # write finds there
+    assert len({e["planes"][2] for e in entries[3:]}) == 1
+    assert entries[2]["planes"][2] != entries[3]["planes"][2]
+    # each distinct section met one hash object and was fed to it once: the
+    # three snapshots' planes and units, the vote (what is empty feeds none)
+    assert counting.sizes() == sorted([agg] * 3 + [unit] * 3 + [vote])
+    last = out["health"]["journal"]["last"]
+    assert (last["phase"], last["written"], last["reused"]) == ("unmask", 0, agg + unit + vote)
+    fold = aggregator_mod.fold_kernel_report()
+    assert fold["shards"] == devices  # four planes a snapshot on the mesh
 
 
 # --- (d) a write that fails is counted, and the round goes on ----------------
@@ -517,15 +656,15 @@ def test_a_write_that_exhausts_its_retries_is_counted_and_the_round_publishes(
 
 
 def _rewrite_journal(path, **changes) -> None:
-    with open(path, "rb") as f:
-        entry = ckpt_mod.RoundCheckpoint.from_bytes(f.read())
+    entry = _journal_on_disk(os.path.dirname(path))
     for key, value in changes.items():
         setattr(entry, key, value)
     with open(path, "wb") as f:
-        f.write(entry.to_bytes())
+        f.write(entry.to_bytes())  # whole, in the one file
 
 
-LEFTOVERS = ("finished-run", "other-seed", "other-mask", "other-length", "torn-tmp")
+LEFTOVERS = ("finished-run", "other-seed", "other-mask", "other-length", "torn-tmp",
+             "short-section", "no-section")
 
 
 @pytest.mark.parametrize("left", LEFTOVERS)
@@ -572,6 +711,13 @@ def test_boot_on_what_an_earlier_run_left_starts_at_idle_and_serves_a_round(
             blob = journal_path.read_bytes()
             journal_path.write_bytes(blob[: len(blob) // 2])  # a torn entry
             (tmp_path / "coordinator_state.json.ckpt.tmp").write_bytes(blob[:100])
+        elif left == "short-section":
+            largest = max(_beside_the_head(tmp_path), key=lambda f: os.path.getsize(tmp_path / f))
+            raw = (tmp_path / largest).read_bytes()
+            (tmp_path / largest).write_bytes(raw[: len(raw) // 2])
+        elif left == "no-section":
+            for name in _beside_the_head(tmp_path):
+                os.remove(tmp_path / name)
 
     resumed0 = sum(ckpt_mod.RESUME_TOTAL.labels(phase=p, outcome="resumed").value
                    for p in PHASES)
@@ -596,3 +742,4 @@ def test_boot_on_what_an_earlier_run_left_starts_at_idle_and_serves_a_round(
     # never parsed, and a finished run leaves none
     assert (refused >= 1) == (left in ("other-seed", "other-mask", "other-length"))
     assert not journal_path.exists()  # the served round retired its own
+    assert _beside_the_head(tmp_path) == []  # and took what the dead one left

@@ -607,3 +607,292 @@ def test_boot_restore_resumes_sum2_phase_and_finishes_round():
     model = asyncio.run(asyncio.wait_for(run(), timeout=120))
     # all three updates survived the kill inside the restored aggregate
     np.testing.assert_allclose(model, expected, atol=1e-9)
+
+
+# --------------------------------------------------------------------------
+# An entry is a head and its sections (PR 47): what a store returns for it,
+# how often a section is hashed and written, what a torn write leaves
+# --------------------------------------------------------------------------
+
+
+def _planes(n: int, width: int = 12, seed: int = 5) -> list:
+    rng = np.random.default_rng(seed)
+    step = width // n
+    return [
+        (i * step, (i + 1) * step, rng.integers(0, 2**32, size=(2, step), dtype=np.uint32))
+        for i in range(n)
+    ]
+
+
+def _votes(n: int = 2) -> list:
+    return [(_pk(40 + i), bytes([i + 1]) * (9 + i)) for i in range(n)]
+
+
+_EMPTY = dict(
+    vect=np.zeros((0, 0), dtype=np.uint32), unit=np.zeros((0,), dtype=np.uint32),
+    nb_models=0, seed_watermark=0,
+)
+# kind -> what the entry carries beside the identity of its round
+ENTRIES = {
+    "wire-vect": lambda: dict(seed_dicts={_pk(20): {_pk(1): _seed(7)}, _pk(21): {_pk(1): _seed(8)}}),
+    "one-plane": lambda: dict(vect=_EMPTY["vect"], planes=_planes(1), model_length=11),
+    "four-planes": lambda: dict(vect=_EMPTY["vect"], planes=_planes(4), model_length=11,
+                                sum_dict={_pk(1): _pk(2)}),
+    "votes": lambda: dict(phase="sum2", vect=_EMPTY["vect"], planes=_planes(2),
+                          model_length=11, mask_votes=_votes()),
+    "all-empty": lambda: dict(phase="sum", sum_dict={_pk(1): _pk(2)}, **_EMPTY),
+}
+
+
+class _DefaultStore:
+    """Nothing but the trait's own three methods."""
+
+    from xaynet_tpu.storage.traits import CoordinatorStorage as _T
+
+    set_round_checkpoint = _T.set_round_checkpoint
+    round_checkpoint = _T.round_checkpoint
+    delete_round_checkpoint = _T.delete_round_checkpoint
+
+
+def _journal_store(kind: str, tmp_path):
+    if kind == "file":
+        from xaynet_tpu.storage.memory import FileCoordinatorStorage
+
+        return FileCoordinatorStorage(str(tmp_path / "state.json"))
+    if kind == "memory":
+        return InMemoryCoordinatorStorage()
+    if kind == "redis":
+        from xaynet_tpu.storage.redis import RedisCoordinatorStorage
+
+        class _Resp:
+            def __init__(self):
+                self.kv = {}
+
+            async def command(self, verb, key, *rest):
+                if verb == b"SET":
+                    self.kv[key] = bytes(rest[0])
+                elif verb == b"DEL":
+                    self.kv.pop(key, None)
+                return self.kv.get(key)
+
+        store = RedisCoordinatorStorage(key_prefix="t:x:")
+        store.client = _Resp()
+        return store
+    return _DefaultStore()
+
+
+async def _put(store, ck) -> tuple:
+    sections = ck.sections()
+    head = ck.head(sections)
+    await store.set_round_checkpoint(head, sections)
+    return head, sections
+
+
+def _beside(tmp_path) -> list:
+    """The section files beside the head, and whatever else lies there."""
+    import os
+
+    return sorted(f for f in os.listdir(tmp_path) if f.startswith("state.json.ckpt."))
+
+
+@pytest.mark.parametrize("store_kind", ["file", "memory", "default", "redis"])
+@pytest.mark.parametrize("kind", list(ENTRIES))
+def test_a_store_returns_for_an_entry_what_the_reference_serialiser_makes(
+        kind, store_kind, tmp_path):
+    from journal_reference import reference_to_bytes
+
+    ck = _ckpt(**ENTRIES[kind]())
+    want = reference_to_bytes(ck)  # before anything of the program has touched the entry
+    store = _journal_store(store_kind, tmp_path)
+
+    async def run():
+        head, sections = await _put(store, ck)
+        first = await store.round_checkpoint()
+        # written again with one header field changed, as Sum2 -> Unmask does
+        ck.phase = "unmask"
+        await _put(store, ck)
+        second = await store.round_checkpoint()
+        await store.delete_round_checkpoint()
+        return head, sections, first, second, await store.round_checkpoint()
+
+    head, sections, first, second, gone = asyncio.run(run())
+    assert first == want == ck_bytes_of(first)
+    assert second == reference_to_bytes(ck) != want
+    assert gone is None
+    assert [s.name for s in sections] == ["vect", "unit", "votes", "planes"]
+    assert want == head + b"".join(bytes(v) for s in sections for v in s.views())
+    parsed = ckpt_mod.RoundCheckpoint.from_bytes(first)
+    assert (parsed.phase, parsed.nb_models, parsed.mask_votes) == (
+        ENTRIES[kind]().get("phase", "update"), ck.nb_models, ck.mask_votes)
+    np.testing.assert_array_equal(parsed.wire_vect(), ck.wire_vect())
+    if store_kind == "file":
+        assert _beside(tmp_path) == []  # the retire leaves nothing beside the state
+
+
+def ck_bytes_of(blob: bytes) -> bytes:
+    """A blob parsed and serialised again by the program: the same bytes."""
+    return ckpt_mod.RoundCheckpoint.from_bytes(blob).to_bytes()
+
+
+@pytest.mark.parametrize("planes", [1, 4], ids=["one-plane", "four-planes"])
+def test_a_section_is_hashed_once_written_once_and_read_only_after(planes, tmp_path, monkeypatch):
+    """Sum2's three entries over one aggregate, as the phase writes them."""
+    from journal_reference import CountingHashlib, reference_to_bytes
+
+    counting = CountingHashlib()
+    monkeypatch.setattr(ckpt_mod, "hashlib", counting)
+    store = _journal_store("file", tmp_path)
+    agg = _planes(planes)
+    agg_bytes = sum(p.nbytes for _, _, p in agg)
+    base = _ckpt(phase="sum2", vect=_EMPTY["vect"], planes=agg, model_length=11)
+    vote = _votes(1)
+    wants = []
+
+    async def run():
+        written = []
+        for phase, votes in (("sum2", []), ("sum2", vote), ("unmask", vote)):
+            base.phase, base.mask_votes = phase, list(votes)
+            wants.append(reference_to_bytes(base))
+            before = {f: (tmp_path / f).stat().st_mtime_ns for f in _beside(tmp_path)}
+            _, sections = await _put(store, base)
+            after = {f: (tmp_path / f).stat().st_mtime_ns for f in _beside(tmp_path)}
+            written.append(sorted(f for f in after if after[f] != before.get(f)))
+            assert await store.round_checkpoint() == wants[-1]
+        return written, sections
+
+    written, sections = asyncio.run(run())
+    by_name = {s.name: s for s in sections}
+    # each distinct section met one hash object: the aggregate, the unit, the vote
+    assert counting.sizes() == sorted([agg_bytes, base.unit.nbytes, len(vote[0][1])])
+    # and was written once: the base entry its two, the vote's entry the vote, `unmask` none
+    assert [len(w) for w in written] == [2, 1, 0]
+    assert written[1] == [f"state.json.ckpt.{by_name['votes'].digest}"]
+    assert _beside(tmp_path) == sorted(
+        f"state.json.ckpt.{by_name[n].digest}" for n in ("unit", "votes", "planes"))
+    # the arrays an entry has hashed are read-only: a write raises
+    for _, _, plane in agg:
+        assert not plane.flags.writeable
+        with pytest.raises(ValueError, match="read-only"):
+            plane[0, 0] = 1
+    with pytest.raises(ValueError, match="read-only"):
+        base.unit[0] = 1
+    # other buffers are another section: hashed afresh, never the kept digest
+    base.planes = [(lo, hi, plane.copy()) for lo, hi, plane in agg]
+    base.planes[0][2][0, 0] ^= 1
+    assert base.sections()[3] is not by_name["planes"]
+    assert base.to_bytes() == reference_to_bytes(base) != wants[-1]
+
+
+def test_a_wire_vect_is_read_only_after_its_first_entry():
+    ck = _ckpt()
+    ck.vect[0, 0] = 5  # before: an aggregate like any other
+    blob = ck.to_bytes()
+    with pytest.raises(ValueError, match="read-only"):
+        ck.vect[0, 0] = 6
+    assert ck.to_bytes() == blob
+
+
+def _torn_write(store, ck) -> None:
+    """The state a process leaves that dies with the sections of ``ck``
+    written and the head not yet renamed: ``_write_ckpt`` run up to its
+    kill point."""
+    from xaynet_tpu.resilience import chaos
+
+    class Died(Exception):
+        pass
+
+    def die():
+        raise Died()
+
+    import os
+
+    real, chaos._die = chaos._die, die
+    os.environ[chaos.ENV] = "journal:sections:1"
+    chaos._visits.clear()
+    try:
+        sections = ck.sections()
+        with pytest.raises(Died):
+            store._write_ckpt(ck.head(sections), sections)
+    finally:
+        chaos._die = real
+        del os.environ[chaos.ENV]
+        chaos._visits.clear()
+
+
+def test_killed_between_the_sections_and_the_head_the_previous_entry_is_what_loads(tmp_path):
+    from journal_reference import reference_to_bytes
+
+    store = Store(_journal_store("file", tmp_path), InMemoryModelStorage(), NoOpTrustAnchor())
+    first = _ckpt(vect=_EMPTY["vect"], planes=_planes(2, seed=1), model_length=11)
+    second = _ckpt(vect=_EMPTY["vect"], planes=_planes(2, seed=2), model_length=11,
+                   nb_models=4, seed_watermark=4)
+    third = _ckpt(phase="sum2", vect=_EMPTY["vect"], planes=_planes(2, seed=3),
+                  model_length=11, nb_models=6, seed_watermark=6)
+    asyncio.run(_put(store.coordinator, first))
+    held = _beside(tmp_path)
+    _torn_write(store.coordinator, second)
+    # the dead write's sections lie beside the live entry's, which is whole
+    assert set(held) < set(_beside(tmp_path))
+    loaded = asyncio.run(ckpt_mod.load(store))
+    assert loaded is not None and loaded.nb_models == first.nb_models
+    assert asyncio.run(store.coordinator.round_checkpoint()) == reference_to_bytes(first)
+    # the next entry takes what no head names away with it
+    _, sections = asyncio.run(_put(store.coordinator, third))
+    assert _beside(tmp_path) == sorted(
+        f"state.json.ckpt.{s.digest}" for s in sections if s.nbytes)
+    assert asyncio.run(ckpt_mod.load(store)).phase == "sum2"
+    # and so does the retire, a dead write's leavings included
+    _torn_write(store.coordinator, second)
+    asyncio.run(store.coordinator.delete_round_checkpoint())
+    assert _beside(tmp_path) == [] and asyncio.run(ckpt_mod.load(store)) is None
+
+
+@pytest.mark.parametrize("harm", ["removed", "truncated", "flipped-byte", "torn-head"])
+def test_a_harmed_section_file_never_loads(harm, tmp_path):
+    store = Store(_journal_store("file", tmp_path), InMemoryModelStorage(), NoOpTrustAnchor())
+    ck = _ckpt(phase="sum2", vect=_EMPTY["vect"], planes=_planes(2), model_length=11,
+               mask_votes=_votes())
+    _, sections = asyncio.run(_put(store.coordinator, ck))
+    assert asyncio.run(ckpt_mod.load(store)) is not None
+    path = tmp_path / f"state.json.ckpt.{sections[3].digest}"
+    if harm == "removed":
+        path.unlink()
+    elif harm == "truncated":
+        path.write_bytes(path.read_bytes()[:-4])
+    elif harm == "flipped-byte":
+        raw = bytearray(path.read_bytes())
+        raw[len(raw) // 2] ^= 0x10
+        path.write_bytes(bytes(raw))
+    else:
+        head = tmp_path / "state.json.ckpt"
+        head.write_bytes(head.read_bytes()[:40])
+    assert asyncio.run(ckpt_mod.load(store)) is None
+    # a whole entry written over it loads again
+    asyncio.run(_put(store.coordinator, _ckpt()))
+    assert asyncio.run(ckpt_mod.load(store)).phase == "update"
+
+
+@pytest.mark.parametrize("kind", ["wire-vect", "four-planes", "votes"])
+def test_a_single_file_journal_of_the_program_before_is_read_by_the_file_store(kind, tmp_path):
+    from journal_reference import CountingHashlib, reference_to_bytes
+
+    ck = _ckpt(**ENTRIES[kind]())
+    blob = reference_to_bytes(ck)
+    (tmp_path / "state.json.ckpt").write_bytes(blob)
+    store = Store(_journal_store("file", tmp_path), InMemoryModelStorage(), NoOpTrustAnchor())
+    assert asyncio.run(store.coordinator.round_checkpoint()) == blob
+    loaded = asyncio.run(ckpt_mod.load(store))
+    assert (loaded.phase, loaded.nb_models, loaded.mask_votes) == (
+        ck.phase, ck.nb_models, ck.mask_votes)
+    np.testing.assert_array_equal(loaded.wire_vect(), ck.wire_vect())
+    # what was read keeps its digests: the next entry over it hashes nothing
+    counting = CountingHashlib()
+    ckpt_mod.hashlib, real = counting, ckpt_mod.hashlib
+    try:
+        loaded.phase = "unmask"
+        asyncio.run(_put(store.coordinator, loaded))
+    finally:
+        ckpt_mod.hashlib = real
+    assert counting.sizes() == []
+    ck.phase = "unmask"
+    assert asyncio.run(store.coordinator.round_checkpoint()) == reference_to_bytes(ck)

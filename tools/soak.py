@@ -441,8 +441,12 @@ def _metric_sum(port: int, family: str) -> float:
 # publish window. <site>:<n> dies on the n-th visit to the site — "update:2"
 # is mid-window (2 of 3 updates journaled), "unmask:publish:1" lands AFTER
 # the model save but BEFORE the journal retires (the idempotent-republish
-# window, the nastiest restart point).
-KILL_MATRIX = ("sum:1", "update:2", "sum2:1", "unmask:publish:1")
+# window, the nastiest restart point). "journal:sections:2" is the file
+# store's own point, inside a journal write: the second entry that carries an
+# aggregate has its sections on disk and its head not yet renamed into place,
+# so the restart must find the entry before it (the phase it resumes into is
+# read from that entry).
+KILL_MATRIX = ("sum:1", "update:2", "journal:sections:2", "sum2:1", "unmask:publish:1")
 KILL_SEED = 46  # the participants' weights and mask seeds (benchmark/harness/reference.py)
 KILL_CONCURRENCY = 8  # uploads in flight, as the benchmark's flood8
 KILL_SAMPLE = (1_000_000, 1024)  # positions compared with the plain reference, and each edge
@@ -522,19 +526,27 @@ def _kill_config(port: int, spec: dict, state_dir: str) -> str:
     )
 
 
-def _journal_update_pks(state_dir: str) -> set:
-    """The update participants the journal on disk holds (its header alone
-    is read: the aggregate behind it can be of the model's size)."""
+def _journal_header(state_dir: str) -> dict:
+    """The header of the journal entry on disk, {} if there is none (the
+    head file alone is read: the sections beside it can be of the model's
+    size)."""
     import struct
 
+    from xaynet_tpu.storage.memory import FileCoordinatorStorage
+
     try:
-        with open(os.path.join(state_dir, "coordinator_state.json.ckpt"), "rb") as f:
-            f.read(7)  # magic
-            (hlen,) = struct.unpack("<I", f.read(4))
-            header = json.loads(f.read(hlen))
-    except (OSError, ValueError, struct.error):
-        return set()
-    return {bytes.fromhex(pk) for pk in header.get("seed_dicts") or {}}
+        found = FileCoordinatorStorage(
+            os.path.join(state_dir, "coordinator_state.json"))._read_head()
+        head = found[0]
+        (hlen,) = struct.unpack_from("<I", head, 7)  # behind the magic
+        return json.loads(head[11 : 11 + hlen])
+    except (OSError, TypeError, ValueError, struct.error):
+        return {}
+
+
+def _journal_update_pks(state_dir: str) -> set:
+    """The update participants the journal on disk holds."""
+    return {bytes.fromhex(pk) for pk in _journal_header(state_dir).get("seed_dicts") or {}}
 
 
 def _drive_crash_round(url: str, spec: dict, state_dir: str, label: str,
@@ -778,6 +790,10 @@ def run_kill_matrix_soak(args) -> None:
             finally:
                 log.close()
             held = len(_journal_update_pks(state_dir))
+            if phase == "journal":
+                # killed inside a write: the entry before it is what the
+                # restart finds, and its tag the phase it resumes into
+                phase = _journal_header(state_dir).get("phase", "?")
             print(f"{coord}: killed (pid {proc.pid}), {held} updates in the journal",
                   file=sys.stderr)
             t_restart = time.perf_counter()
@@ -1467,14 +1483,15 @@ def main() -> None:
         "caller's platform (JAX_PLATFORMS as set: cpu here, unset on a chip "
         "host) and at the size given by --model-len, --mask, --batch-size "
         "and --updates; sites: sum, update, sum2:base, sum2, unmask:start, "
-        "unmask:publish",
+        "unmask:publish, journal:sections",
     )
     ap.add_argument(
         "--kill-points",
         default=None,
         metavar="SITE:N,...",
         help="with --kill-matrix: comma-separated kill coordinates "
-        "(default: the full matrix sum:1,update:2,sum2:1,unmask:publish:1); "
+        "(default: the full matrix sum:1,update:2,journal:sections:2,sum2:1,"
+        "unmask:publish:1); "
         "CI smoke runs a one-per-phase-family subset",
     )
     ap.add_argument(

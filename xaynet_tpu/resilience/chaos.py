@@ -13,7 +13,11 @@ power-loss the journal must survive. Sites:
 - ``unmask:start``: at the entry of the Unmask phase, the ``unmask``-tagged
   entry written and nothing of the model computed or stored;
 - ``unmask:publish``: after the global model is persisted but BEFORE the
-  journal entry is deleted — the publish window.
+  journal entry is deleted — the publish window;
+- ``journal:sections``: inside the file store's journal write
+  (``storage/memory.py::_write_ckpt``), an entry's sections written and its
+  head not yet renamed into place — visited by every entry that carries a
+  section; the restart must find the entry before it.
 
 Without the environment variable every call is a no-op (one dict lookup
 on the accept path). The counter is per-site and process-local: a

@@ -35,6 +35,15 @@ wire ``[model_len, L]`` or packed per-shard planar planes), unit
 accumulator, concatenated serialized mask votes. Every section's sha256 is
 in the header — a torn write must fail validation, never resume. ``XNCKPT1``
 blobs (update-only snapshots from older coordinators) still read.
+
+An entry in flight is not that blob: it is a **head** (magic, length, JSON
+header) and its four **sections held by reference** (:class:`Section`), the
+aggregate's arrays and the votes' bytes where they already lie. A section is
+hashed in place, once: its digest stays with it, so an entry that carries the
+buffers an earlier entry of the round carried (Sum2's base entry, its rewrite
+for a vote and the ``unmask`` entry share the finished aggregate) hashes
+nothing again, and a store that keeps sections apart (the file store) writes
+nothing again. The blob is what ``round_checkpoint()`` gives back.
 """
 
 from __future__ import annotations
@@ -48,6 +57,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..storage.traits import join_entry
 from ..telemetry import journal
 from ..telemetry.registry import get_registry
 
@@ -95,6 +105,56 @@ class CheckpointError(ValueError):
     """Corrupt or inconsistent checkpoint blob."""
 
 
+class Section:
+    """One payload section of a journal entry, held by reference: the
+    buffers it is made of, in order (contiguous ``uint32`` arrays, a vote's
+    ``bytes``), and, once computed, the SHA-256 of their concatenation.
+
+    Nothing here copies: ``views`` are memoryviews of the buffers in place,
+    which the digest reads and a store writes. The first digest makes the
+    arrays read-only, so a later write to a journalled aggregate raises
+    instead of leaving a journal whose digest no longer fits its bytes.
+    ``stored`` is set once a store has taken the section: an entry that
+    carries it again hands over bytes the store already has."""
+
+    __slots__ = ("name", "sources", "parts", "nbytes", "stored", "_digest")
+
+    def __init__(self, name: str, sources: list):
+        self.name = name
+        self.sources = sources  # what the entry holds: identity is what `holds` compares
+        self.parts = [
+            bytes(src) if isinstance(src, (bytes, bytearray, memoryview))
+            else np.ascontiguousarray(src, dtype=np.uint32)
+            for src in sources
+        ]
+        self.nbytes = sum(memoryview(part).nbytes for part in self.parts)
+        self.stored = False
+        self._digest: Optional[str] = None
+
+    def holds(self, sources: list) -> bool:
+        """Whether these are the very buffers this section was made of."""
+        return len(sources) == len(self.sources) and all(
+            a is b for a, b in zip(sources, self.sources)
+        )
+
+    def views(self) -> list:
+        """The section's bytes, in order, where they lie (empty parts left
+        out: a memoryview of no elements cannot be cast)."""
+        return [view.cast("B") for view in map(memoryview, self.parts) if view.nbytes]
+
+    @property
+    def digest(self) -> str:
+        if self._digest is None:
+            for buf in (*self.sources, *self.parts):
+                if isinstance(buf, np.ndarray):
+                    buf.flags.writeable = False
+            sha = hashlib.sha256()
+            for view in self.views():
+                sha.update(view)
+            self._digest = sha.hexdigest()
+        return self._digest
+
+
 @dataclass
 class AggSnapshot:
     """One exact host copy of the aggregate, as the journal stores it:
@@ -132,6 +192,9 @@ class RoundCheckpoint:
     # packed per-shard planar planes [(lo, hi, uint32[L, hi-lo])]; when set,
     # ``vect`` is empty and ``wire_vect()`` reassembles on demand
     planes: Optional[list] = None
+    # the sections the entry was last taken apart into, by name: a section
+    # whose buffers the entry still holds keeps its digest (``sections``)
+    _sections: dict = field(default_factory=dict, repr=False, compare=False)
 
     # -- derived -----------------------------------------------------------
 
@@ -151,24 +214,34 @@ class RoundCheckpoint:
 
     # -- serialization -----------------------------------------------------
 
-    def to_bytes(self) -> bytes:
-        if self.version < 2:
-            return self._to_bytes_v1()
-        vect = np.ascontiguousarray(self.vect, dtype=np.uint32)
-        unit = np.ascontiguousarray(self.unit, dtype=np.uint32)
-        vect_raw = vect.tobytes()
-        unit_raw = unit.tobytes()
-        votes_raw = b"".join(bytes(mask) for _, mask in self.mask_votes)
+    def sections(self) -> list[Section]:
+        """The entry's payload sections in wire order (vector accumulator,
+        unit accumulator, votes, shard planes), by reference. A section made
+        of the very buffers an earlier call found is that call's object, its
+        digest and its ``stored`` with it."""
+        sources = {
+            "vect": [self.vect],
+            "unit": [self.unit],
+            "votes": [mask for _, mask in self.mask_votes],
+            "planes": [plane for _, _, plane in self.planes or ()],
+        }
+        for name, held in sources.items():
+            kept = self._sections.get(name)
+            if kept is None or not kept.holds(held):
+                self._sections[name] = Section(name, held)
+        return [self._sections[name] for name in sources]
+
+    def head(self, sections: list[Section]) -> bytes:
+        """Magic, header length and the JSON header over ``sections``:
+        everything of the entry that is not payload. Hashes the sections
+        that carry no digest yet; allocates nothing of their size."""
+        vect, unit, votes, planes = sections
         planes_meta = None
-        planes_raw = b""
         if self.planes is not None:
-            planes_meta = []
-            chunks = []
-            for lo, hi, plane in self.planes:
-                plane = np.ascontiguousarray(plane, dtype=np.uint32)
-                planes_meta.append([int(lo), int(hi), *map(int, plane.shape)])
-                chunks.append(plane.tobytes())
-            planes_raw = b"".join(chunks)
+            planes_meta = [
+                [int(lo), int(hi), *map(int, plane.shape)]
+                for (lo, hi, _), plane in zip(self.planes, planes.parts)
+            ]
         header = json.dumps(  # lint: taint-ok: durable journal; seeds stay sealed, round seed is the identity check
             {
                 "version": 2,
@@ -179,10 +252,10 @@ class RoundCheckpoint:
                 "model_length": self.model_length,
                 "nb_models": self.nb_models,
                 "seed_watermark": self.seed_watermark,
-                "vect_shape": list(vect.shape),
-                "unit_shape": list(unit.shape),
-                "vect_sha256": hashlib.sha256(vect_raw).hexdigest(),
-                "unit_sha256": hashlib.sha256(unit_raw).hexdigest(),
+                "vect_shape": list(vect.parts[0].shape),
+                "unit_shape": list(unit.parts[0].shape),
+                "vect_sha256": vect.digest,
+                "unit_sha256": unit.digest,
                 "sum_dict": {
                     pk.hex(): ephm.hex() for pk, ephm in self.sum_dict.items()
                 },
@@ -190,21 +263,25 @@ class RoundCheckpoint:
                     pk.hex(): {spk.hex(): bytes(seed).hex() for spk, seed in local.items()}
                     for pk, local in self.seed_dicts.items()
                 },
-                "votes": [[pk.hex(), len(bytes(mask))] for pk, mask in self.mask_votes],
-                "votes_sha256": hashlib.sha256(votes_raw).hexdigest(),
+                "votes": [
+                    [pk.hex(), len(mask)]
+                    for (pk, _), mask in zip(self.mask_votes, votes.parts)
+                ],
+                "votes_sha256": votes.digest,
                 "planes": planes_meta,
-                "planes_sha256": hashlib.sha256(planes_raw).hexdigest(),
+                "planes_sha256": planes.digest,
             }
         ).encode()
-        return (
-            MAGIC2
-            + struct.pack("<I", len(header))
-            + header
-            + vect_raw
-            + unit_raw
-            + votes_raw
-            + planes_raw
-        )
+        return MAGIC2 + struct.pack("<I", len(header)) + header
+
+    def to_bytes(self) -> bytes:
+        """The entry as one blob: what ``round_checkpoint()`` returns for
+        it, and what a store that holds one value keeps (one copy, the
+        join's; the journal's own writes go by ``head`` and ``sections``)."""
+        if self.version < 2:
+            return self._to_bytes_v1()
+        sections = self.sections()
+        return join_entry(self.head(sections), sections)
 
     def _to_bytes_v1(self) -> bytes:
         """The update-only XNCKPT1 snapshot (kept writable for the
@@ -311,7 +388,7 @@ class RoundCheckpoint:
                 )
                 pos += n
         empty2 = np.zeros((0, 0), dtype=np.uint32)
-        return cls(
+        ckpt = cls(
             round_id=int(header["round_id"]),
             phase=str(header["phase"]),
             round_seed=bytes.fromhex(header["round_seed"]),
@@ -344,6 +421,11 @@ class RoundCheckpoint:
             mask_votes=mask_votes,
             planes=planes,
         )
+        if magic == MAGIC2:
+            # the digests were checked above: the sections keep them
+            for section in ckpt.sections():
+                section._digest = header[f"{section.name}_sha256"]
+        return ckpt
 
 
 def mask_config_names(config_pair) -> list:
@@ -433,11 +515,15 @@ async def write_entry(shared, ckpt: RoundCheckpoint, write=None) -> bool:
 
     try:
         loop = asyncio.get_running_loop()
-        # serialization sha256-hashes the model-sized aggregate — CPU work
-        # that must not stall the loop serving the API
-        blob = await loop.run_in_executor(None, _serialise, ckpt, write)
-        with write.stage("store", bytes=len(blob)):
-            await shared.store.coordinator.set_round_checkpoint(blob)
+        # serialisation sha256-hashes what the entry has not hashed yet (a
+        # model-sized aggregate, a vote) — CPU work that must not stall the
+        # loop serving the API
+        head, sections = await loop.run_in_executor(None, _serialise, ckpt, write)
+        # what this write hands the store: the head, and the sections no
+        # earlier entry of the round handed it
+        nbytes = len(head) + sum(s.nbytes for s in sections if not s.stored)
+        with write.stage("store", bytes=nbytes):
+            await shared.store.coordinator.set_round_checkpoint(head, sections)
     except asyncio.CancelledError:
         raise
     except Exception as e:
@@ -447,17 +533,25 @@ async def write_entry(shared, ckpt: RoundCheckpoint, write=None) -> bool:
         CHECKPOINTS.labels(outcome="failed").inc()
         SAVE_FAILURES.inc()
         return False
-    write.saved(len(blob))
+    write.saved(
+        nbytes,
+        [(s.name, "reused" if s.stored else "written", s.nbytes) for s in sections],
+    )
+    for section in sections:
+        section.stored = True
     CHECKPOINTS.labels(outcome="saved").inc()
     return True
 
 
-def _serialise(ckpt: RoundCheckpoint, write) -> bytes:
-    """``to_bytes`` under its stage, on the executor thread that runs it."""
+def _serialise(ckpt: RoundCheckpoint, write) -> tuple[bytes, list[Section]]:
+    """The entry's head and sections under their stage, on the executor
+    thread that runs it: the SHA-256 of each section that has none yet, in
+    place, and the JSON header. Nothing of a section's size is allocated."""
     with write.stage("serialise", nb_models=ckpt.nb_models) as span:
-        blob = ckpt.to_bytes()
-        span.set(bytes=len(blob))
-    return blob
+        sections = ckpt.sections()
+        head = ckpt.head(sections)
+        span.set(bytes=len(head) + sum(s.nbytes for s in sections))
+    return head, sections
 
 
 def snapshot(aggregator, write) -> AggSnapshot:
@@ -565,10 +659,15 @@ async def load(store) -> Optional["RoundCheckpoint"]:
     if blob is None:
         return None
     try:
-        return RoundCheckpoint.from_bytes(blob)
+        ckpt = RoundCheckpoint.from_bytes(blob)
     except CheckpointError as e:
         logger.warning("discarding corrupt round checkpoint: %s", e)
         return None
+    # what was read is what the store holds: a resumed phase that carries
+    # these sections into its next entry hands over nothing new
+    for section in ckpt.sections():
+        section.stored = True
+    return ckpt
 
 
 class CheckpointManager:

@@ -21,7 +21,11 @@ Resilience (docs/DESIGN.md §9): with ``[resilience] checkpoint_enabled``
 the phase writes a sum2-tagged journal entry (finished aggregate + sealed
 dictionaries) BEFORE acknowledging its first vote, then rewrites it per
 accepted vote; ``next`` advances the entry to ``unmask`` before the
-finalize barrier so the publish window is covered too.
+finalize barrier so the publish window is covered too. The three are one
+``RoundCheckpoint`` (``_base``) written again: the aggregate does not
+change after the drain, so its section keeps the digest the base entry
+computed and the bytes the store already holds, a vote is hashed and
+written once, and the ``unmask`` entry is a head alone.
 """
 
 from __future__ import annotations
@@ -171,6 +175,7 @@ class Sum2Phase(PhaseState):
             # advance the journal into the publish window BEFORE the
             # finalize barrier: a crash anywhere from here to the journal
             # retire in Unmask resumes into Unmask with the final votes
+            # (the sections are the vote entries' own: a new head, no more)
             self._base.phase = "unmask"
             self._base.mask_votes = list(self._votes)
             await write_entry(self.shared, self._base)
@@ -193,7 +198,8 @@ class Sum2Phase(PhaseState):
                 raise RequestError(RequestError.Kind.MESSAGE_REJECTED, err.value)
             if self._base is not None:
                 # journal-before-ack: the accepted vote is durable before the
-                # acknowledgement leaves (rewrite; votes are mask-sized)
+                # acknowledgement leaves (the votes' section is mask-sized and
+                # new; the aggregate's is the base entry's, digest and file)
                 self._votes.append(
                     (req.participant_pk, serialize_mask_object(req.model_mask))
                 )

@@ -38,6 +38,11 @@ from .traits import (
 )
 
 
+# what a journal head file starts with when its sections lie in files of
+# their own (FileCoordinatorStorage): this, a u32-le length, a JSON listing
+# [[sha256 hex, bytes], ...] in wire order, then the head itself
+_SECTIONED = b"XNCKSEC1"
+
 # elements of the vector that go into a vote's prefilter: what an honest
 # mask of other seeds differs in with all but one chance in the group's order
 _PREFILTER_ELEMENTS = 16
@@ -273,7 +278,8 @@ class FileCoordinatorStorage(InMemoryCoordinatorStorage):
     the latest-global-model pointer — exactly what restore reads,
     reference: initializer.rs:162-271) persists to a JSON file. Round
     dictionaries live in memory only — but the round JOURNAL (the binary
-    ``.ckpt`` sibling) carries its own copy of them, and a boot restore
+    ``.ckpt`` sibling and the section files beside it) carries its own copy
+    of them, and a boot restore
     replays them back through ``restore_round_dicts``, so a crash
     anywhere in the round resumes instead of restarting it.
     """
@@ -318,46 +324,130 @@ class FileCoordinatorStorage(InMemoryCoordinatorStorage):
         await super().delete_coordinator_data()
         self._persist()
 
-    # --- mid-round checkpoint: binary sibling file (the aggregate snapshot
-    # can be model-sized; it does not belong hex-encoded inside the JSON) --
+    # --- round journal: a head file and one file a section -----------------
+    # An entry's sections can be of the model's size, and most of what the
+    # round's tail journals (the finished aggregate, a vote) does not change
+    # from one entry to the next. So `<path>.ckpt` holds the head alone,
+    # behind a listing of the sections it goes with, and each non-empty
+    # section lies in `<path>.ckpt.<sha256>`, written once: an entry that
+    # carries a section whose file is there writes the head and nothing else.
 
     def _ckpt_path(self) -> str:
         return self.path + ".ckpt"
 
-    async def set_round_checkpoint(self, data: bytes) -> None:
+    async def set_round_checkpoint(self, head: bytes, sections=()) -> None:
         import asyncio
 
-        # model-sized blob: the file write goes through the executor so the
-        # event loop keeps serving the API during a checkpoint
+        # model-sized sections: the file writes go through the executor so
+        # the event loop keeps serving the API during a checkpoint
         await asyncio.get_running_loop().run_in_executor(
-            None, self._write_ckpt, data
+            None, self._write_ckpt, head, sections
         )
 
-    def _write_ckpt(self, data: bytes) -> None:
+    def _write_ckpt(self, head: bytes, sections=()) -> None:
+        """Sections first, each under a temporary name until it is whole;
+        then the head, whose rename is the commit: until it, the previous
+        entry and its sections are what ``round_checkpoint()`` returns.
+        Sections the new head does not name go only after it."""
+        import json
+        import os
+        import struct
+
+        from ..resilience.chaos import maybe_kill
+
+        base = self._ckpt_path()
+        listing = []
+        for section in sections:
+            if not section.nbytes:
+                continue
+            listing.append([section.digest, section.nbytes])
+            path = f"{base}.{section.digest}"
+            try:
+                if os.path.getsize(path) == section.nbytes:
+                    continue  # an earlier entry of the round wrote it
+            except OSError:
+                pass
+            with open(path + ".tmp", "wb") as f:
+                for view in section.views():
+                    f.write(view)
+            os.replace(path + ".tmp", path)
+        if listing:
+            # chaos hook (kill-matrix harness): the sections are on disk
+            # and the head still says the previous entry
+            maybe_kill("journal:sections")
+            index = json.dumps(listing).encode()
+            head = _SECTIONED + struct.pack("<I", len(index)) + index + head
+        with open(base + ".tmp", "wb") as f:
+            f.write(head)
+        os.replace(base + ".tmp", base)
+        self._sweep_ckpt(keep={digest for digest, _ in listing})
+
+    def _sweep_ckpt(self, keep=()) -> None:
+        """Remove what lies beside the head and no head names: the sections
+        of entries gone by, and what a process that died left half written."""
         import os
 
-        tmp = self._ckpt_path() + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(data)
-        os.replace(tmp, self._ckpt_path())
+        folder, name = os.path.split(self._ckpt_path())
+        for found in os.listdir(folder or "."):
+            if found.startswith(name + ".") and found[len(name) + 1 :] not in keep:
+                try:
+                    os.remove(os.path.join(folder, found))
+                except FileNotFoundError:
+                    pass
 
     async def round_checkpoint(self) -> Optional[bytes]:
         import asyncio
 
         return await asyncio.get_running_loop().run_in_executor(None, self._read_ckpt)
 
-    def _read_ckpt(self) -> Optional[bytes]:
-        import os
+    def _read_head(self) -> Optional[tuple[bytes, list]]:
+        """The head file alone: the head, and the listing of the sections
+        that follow it in files of their own. A file without a listing is
+        whole as it lies (an entry with no payload, a journal an older
+        program left, or a torn head, which no magic the journal knows
+        starts): it comes back as it is, with nothing to follow."""
+        import json
+        import struct
 
-        if not os.path.exists(self._ckpt_path()):
+        try:
+            with open(self._ckpt_path(), "rb") as f:
+                data = f.read()
+        except FileNotFoundError:
             return None
-        with open(self._ckpt_path(), "rb") as f:
-            return f.read()
+        if not data.startswith(_SECTIONED):
+            return data, []
+        try:
+            (n,) = struct.unpack_from("<I", data, len(_SECTIONED))
+            at = len(_SECTIONED) + 4
+            listing = [(str(digest), int(nbytes)) for digest, nbytes in json.loads(data[at : at + n])]
+        except (struct.error, ValueError, TypeError):
+            return data, []
+        return data[at + n :], listing
+
+    def _read_ckpt(self) -> Optional[bytes]:
+        """The entry as one blob: the head and the sections it lists, in
+        order. A section file that is missing adds nothing, and one that is
+        short or altered adds what it holds: the blob then fails its length
+        or digest check."""
+        found = self._read_head()
+        if found is None:
+            return None
+        head, listing = found
+        parts = [head]
+        for digest, _nbytes in listing:
+            try:
+                with open(f"{self._ckpt_path()}.{digest}", "rb") as f:
+                    parts.append(f.read())
+            except FileNotFoundError:
+                pass
+        return b"".join(parts)
 
     async def delete_round_checkpoint(self) -> None:
         import os
 
+        # the head first: from here on there is no entry, whatever is left
         try:
             os.remove(self._ckpt_path())
         except FileNotFoundError:
             pass
+        self._sweep_ckpt()

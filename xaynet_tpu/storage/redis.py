@@ -30,6 +30,7 @@ from .traits import (
     StorageError,
     SumPartAddError,
     TransientStorageError,
+    join_entry,
 )
 
 # --- RESP2 client ----------------------------------------------------------
@@ -389,8 +390,8 @@ class RedisCoordinatorStorage(CoordinatorStorage):
         v = await self.client.command(b"GET", self._k(_K_LATEST_MODEL))
         return v.decode() if v is not None else None
 
-    async def set_round_checkpoint(self, data: bytes) -> None:
-        await self.client.command(b"SET", self._k(_K_ROUND_CKPT), data)
+    async def set_round_checkpoint(self, head: bytes, sections=()) -> None:
+        await self.client.command(b"SET", self._k(_K_ROUND_CKPT), join_entry(head, sections))
 
     async def round_checkpoint(self):
         return await self.client.command(b"GET", self._k(_K_ROUND_CKPT))

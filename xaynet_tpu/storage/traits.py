@@ -33,6 +33,14 @@ MASK_VOTES = get_registry().counter(
 )
 
 
+def join_entry(head: bytes, sections=()) -> bytes:
+    """A round-journal entry's head and its sections (each gives ``views()``:
+    its bytes where they lie, ``resilience/checkpoint.py``) as the one
+    ``XNCKPT2`` value ``round_checkpoint()`` returns: for a store that holds
+    the entry whole."""
+    return b"".join([head, *(view for section in sections for view in section.views())])
+
+
 class StorageError(RuntimeError):
     """Infrastructure failure (connection lost, serialization bug, ...).
 
@@ -176,9 +184,11 @@ class CoordinatorStorage(ABC):
     # correct for every backend; durable backends (file, redis) override
     # to persist it alongside the coordinator state.
 
-    async def set_round_checkpoint(self, data: bytes) -> None:
-        """Persist the serialized mid-round aggregate checkpoint."""
-        self._round_checkpoint_mem = bytes(data)
+    async def set_round_checkpoint(self, head: bytes, sections=()) -> None:
+        """Persist one round-journal entry: its head (a whole blob, from a
+        caller that has one) and the payload sections that follow it, held
+        by reference. ``round_checkpoint()`` returns them joined."""
+        self._round_checkpoint_mem = join_entry(head, sections)
 
     async def round_checkpoint(self) -> Optional[bytes]:
         """The last persisted checkpoint, or None."""

@@ -18,8 +18,12 @@ phase}``, ``phase`` the entry's tag (``sum``, ``update``, ``sum2``,
   accumulator device to host, shard by shard (the copy alone);
 - ``dicts``: the store's sum and seed dictionaries read and inverted into
   the journal's replay form;
-- ``serialise``: ``RoundCheckpoint.to_bytes`` (sections, SHA-256, header);
-- ``store``: ``set_round_checkpoint`` to its return, retries included;
+- ``serialise``: the SHA-256 of each section no earlier entry of the round
+  hashed, over the buffer in place, and the JSON header
+  (``resilience/checkpoint.py::_serialise``): nothing of a section's size is
+  allocated in it;
+- ``store``: ``set_round_checkpoint(head, sections)`` to its return, retries
+  included (the file store: the sections it has no file of, then the head);
 - ``total``: one observation a write, around all of them.
 
 The five stages are mirrored into the profiler's trace (``total`` is not:
@@ -30,7 +34,13 @@ chip run, PR 46). An entry runs the stages it
 has: a ``sum`` entry, the seal at Sum -> Update and a rewrite for a vote
 have no aggregate to drain or fetch.
 
-``xaynet_journal_bytes_total{phase}`` is the blobs' length and
+``xaynet_journal_bytes_total{phase}`` is what the writes handed the store
+(the head, and the sections no earlier entry of the round handed it),
+``xaynet_journal_section_bytes_total{section, route}`` every section an
+entry carried, by name (``vect``, ``unit``, ``votes``, ``planes``) and by
+what became of it: ``written`` (hashed and handed to the store by this
+entry) or ``reused`` (the digest and the stored bytes of an earlier entry of
+the round kept), counted where ``write_entry`` makes the choice; and
 ``xaynet_journal_writes_total{phase, outcome}`` the writes, ``saved`` or
 ``failed``: a write that fails is skipped, not raised, so a journal that
 broke reads as a faster round unless someone looks here. ``/healthz``
@@ -68,6 +78,15 @@ BYTES = _registry.counter(
     "phase tag (telemetry/journal.py).",
     ("phase",),
 )
+SECTION_BYTES = _registry.counter(
+    "xaynet_journal_section_bytes_total",
+    "Bytes of the payload sections that saved round-journal entries "
+    "carried, by section (vect | unit | votes | planes) and route: written "
+    "= hashed and handed to the store by this entry; reused = the digest "
+    "and the stored bytes kept from an earlier entry of the round "
+    "(telemetry/journal.py).",
+    ("section", "route"),
+)
 WRITES = _registry.counter(
     "xaynet_journal_writes_total",
     "Round-journal writes, by the entry's phase tag and outcome (saved | "
@@ -104,13 +123,14 @@ class Write:
     ``journal.total`` span, which the stages on executor threads (whose
     ambient context is empty) parent to."""
 
-    __slots__ = ("phase", "ctx", "bytes", "outcome")
+    __slots__ = ("phase", "ctx", "bytes", "routes", "outcome")
 
     def __init__(self, phase: str, ctx):
         self.phase = phase
         self.ctx = ctx
         self.bytes = 0
-        self.outcome = "failed"  # until the store has the blob
+        self.routes = {"written": 0, "reused": 0}  # section bytes, by route
+        self.outcome = "failed"  # until the store has the entry
 
     def stage(self, label: str, **attrs):
         """Bracket one stage of this write where it runs."""
@@ -119,10 +139,16 @@ class Write:
             ctx=self.ctx, phase=self.phase, **attrs,
         )
 
-    def saved(self, nbytes: int) -> None:
-        """The store returned: ``nbytes`` is the blob's length."""
+    def saved(self, nbytes: int, sections=()) -> None:
+        """The store returned: ``nbytes`` is what it was handed (the head
+        and the sections written), ``sections`` every section the entry
+        carried as ``(name, route, bytes)``."""
         self.bytes = int(nbytes)
         self.outcome = "saved"
+        for name, route, size in sections:
+            if size:
+                SECTION_BYTES.labels(section=name, route=route).inc(size)
+                self.routes[route] += int(size)
 
 
 @contextmanager
@@ -148,7 +174,7 @@ def write(phase: str, **attrs):
                 tally = _writes if w.outcome == "saved" else _failed
                 tally[phase] = tally.get(phase, 0) + 1
                 _last = {"phase": phase, "outcome": w.outcome, "bytes": w.bytes,
-                         "seconds": round(time.monotonic() - t0, 6)}
+                         **w.routes, "seconds": round(time.monotonic() - t0, 6)}
 
 
 def report(enabled: bool, every_batches: int) -> dict:
