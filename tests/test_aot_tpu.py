@@ -157,3 +157,32 @@ def test_wire_v1_ingest_compiles_for_v5e_at_25m(v5e, n_dev):
     acc, _planar, _packed, wire = _specs(devices)
     _compile(agg._make_unpack_fn(), wire)
     _compile(agg._make_ingest_fn(), acc, wire)
+
+
+@pytest.mark.parametrize("n_dev,k,rows,width", [
+    pytest.param(1, 12, BPN, N, id="1-k12-7B"),  # resnet50-f32m6's batch
+    pytest.param(1, 8, 10, N, id="1-k8-10B"),  # resnet50-f32b6m6's
+    pytest.param(1, 48, BPN, N // 4, id="shard-k48-7B"),  # a shard's slice of -x4's
+    pytest.param(1, 64, 6, 6_603_710, id="1-k64-6B"),  # femnist-cnn-prime-f32m3's
+    pytest.param(4, 12, BPN, N, id="4-k12-7B"),  # the one-worker pipeline on a mesh
+])
+def test_row_placement_compiles_in_place_for_v5e(v5e, n_dev, k, rows, width):
+    """A batch staged at arrival is assembled on the device row by row
+    (``shards.place_row``, the batch donated): the compiler has to alias the
+    whole batch to its result, or every row would cost a copy of 2.1 GB. A
+    row whose home is one device arrives flat and is relaid out into the
+    batch's tiling plane by plane, through a temporary of at most four rows
+    (where the chip keeps the batch with K on the sublanes, K a multiple of
+    eight, a tile of 4 x 128 around the one row); over a mesh it arrives as
+    ``[planes, width]``, model axis sharded."""
+    from xaynet_tpu.parallel.shards import place_row
+
+    devices = v5e[:n_dev]
+    acc, _planar, packed, _wire = _specs(devices, L, rows, k)
+    batch = jax.ShapeDtypeStruct((k, rows, width), jnp.uint8, sharding=packed.sharding)
+    row = jax.ShapeDtypeStruct((rows * width,) if n_dev == 1 else (rows, width), jnp.uint8,
+                               sharding=acc.sharding)
+    slot = jax.ShapeDtypeStruct((), jnp.int32)
+    mem = place_row.lower(batch, row, slot).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes > 0
+    assert mem.temp_size_in_bytes <= 4.2 * rows * width / n_dev

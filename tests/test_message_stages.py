@@ -373,7 +373,8 @@ def test_loop_lag_is_observed_while_the_server_runs(served_round):
 
 def test_h2d_bytes_equal_the_staged_batch_and_to_planar_is_observed():
     """Device aggregation on the CPU backend: one fold batch staged, copied
-    and folded; the copy's counter moves by the staged batch's bytes."""
+    row by row and folded; the copies' counter moves by the staged batch's
+    bytes."""
     pytest.importorskip("jax")
     import jax
 
@@ -403,10 +404,11 @@ def test_h2d_bytes_equal_the_staged_batch_and_to_planar_is_observed():
     staged = sum(child.value for _, child in BYTES_STAGED.children()) - staged0
     assert agg.nb_models == k and staged > 0
     assert streaming.H2D_BYTES.value - h2d0 == staged
-    assert streaming.H2D_SECONDS.count - n0 == 1
+    assert streaming.H2D_SECONDS.count - n0 == k  # staged at arrival: a copy a row
     assert _counts("-").get("to_planar", 0) - planar0 == k
-    h2d = [s for s in tracing.get_tracer().ring_spans() if s.name == "stream.h2d"][-1]
-    assert h2d.attrs["bytes"] == staged
+    h2d = [s for s in tracing.get_tracer().ring_spans() if s.name == "stream.h2d"][-k:]
+    assert [s.attrs["route"] for s in h2d] == ["row"] * k
+    assert sum(s.attrs["bytes"] for s in h2d) == staged
 
 
 def test_unmask_stages_of_the_device_arm_are_observed_once_and_carry_bytes():

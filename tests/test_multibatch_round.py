@@ -149,13 +149,28 @@ def _pipeline_counters() -> dict:
     out["commits"] = streaming.COMMIT_SECONDS.count
     out["staged_bytes"] = sum(aggregator_mod.BYTES_STAGED.labels(layout=layout).value
                               for layout in ("packed", "unpacked", "wire"))
+    out["h2d_bytes"] = streaming.H2D_BYTES.value
+    out["h2d_early_bytes"] = streaming.H2D_EARLY_BYTES.value
     return out
 
 
-async def _served_round(settings: Settings, weights: list[np.ndarray], order: list[int]) -> dict:
+async def _copied(target: float) -> None:
+    """The pipeline's copier has put ``target`` bytes of rows on the devices
+    (the counter a copy moves when it ends; there is nothing else to wait on
+    from outside the coordinator)."""
+    deadline = asyncio.get_running_loop().time() + 30
+    while streaming.H2D_BYTES.value < target:
+        assert asyncio.get_running_loop().time() < deadline
+        await asyncio.sleep(0.002)
+
+
+async def _served_round(settings: Settings, weights: list[np.ndarray], order: list[int],
+                        row_bytes: int = 0) -> dict:
     """One PET round over the REST API on localhost; the updaters send one
-    after another in ``order``. Returns the published model and how far the
-    pipeline's counters moved over the Update phase."""
+    after another in ``order``, and where ``row_bytes`` is given the next one
+    only once the answered ones' rows are on the devices. Returns the
+    published model and how far the pipeline's counters moved over the
+    Update phase."""
     store = Store(InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor())
     machine, request_tx, events = await StateMachineInitializer(settings, store).init()
     fetcher = Fetcher(events)
@@ -194,11 +209,13 @@ async def _served_round(settings: Settings, weights: list[np.ndarray], order: li
         while fetcher.phase().value != "update":
             await asyncio.sleep(0.005)
         before = _pipeline_counters()
-        for i in order:  # one upload at a time: the arrival order is `order`
+        for done, i in enumerate(order, 1):  # one upload at a time, in `order`
             sm, sent = updaters[i], False
             while not (sent and sm.phase is PhaseKind.AWAITING):
                 await sm.transition()
                 sent = sent or sm.phase is PhaseKind.UPDATE
+            if row_bytes:
+                await _copied(before["h2d_bytes"] + done * row_bytes)
         gap = ACCEPT_GAP_MAX.value
         # to the model's publication: the phase's last flush is drained under
         # Sum2's window (docs/DESIGN.md §22)
@@ -246,6 +263,10 @@ def test_served_multibatch_round_equals_the_plain_reference(
     assert moved["commits"] == batches  # one a batch on one shard too
     # every row was written into its slot as it arrived, none at a flush
     assert (moved["rows", "arrival"], moved["rows", "flush"]) == (n_update, 0)
+    # and went to the device from there, row by row, each byte once
+    assert aggregator_mod.fold_kernel_report()["h2d_route"] == "row"
+    assert moved["h2d_bytes"] == moved["staged_bytes"]
+    assert 0 <= moved["h2d_early_bytes"] <= moved["h2d_bytes"]
     # one acquisition a batch; a ring of `staging_buffers` leases no more
     # than that, so a round of more batches took a buffer again
     ring = {how: moved["ring", how] for how in HOWS}
@@ -256,33 +277,60 @@ def test_served_multibatch_round_equals_the_plain_reference(
     assert out["gap"] > 0.0  # the longest gap between two accepted updates
 
 
+def _compile_the_mesh_pipeline(config: MaskConfig) -> None:
+    """One batch of ``K`` and one of one through a pipeline of the served
+    round's shapes on the four devices, so that the round compiles nothing:
+    its uploads, which wait for each other's copies here, then stay well
+    inside ``stall_grace_s``, which closes the remainder round."""
+    agg = aggregator_mod.ShardedAggregator(
+        config, MODEL_LEN, mesh=make_mesh(jax.devices()[:4]), kernel="xla")
+    stream = streaming.StreamingAggregator(agg, staging_buffers=2, max_batch=K)
+    row = np.zeros((MODEL_LEN, agg.n_limbs), dtype=np.uint32)
+    for k in (K, 1):
+        bufs = stream.open_batch()
+        for i in range(k):
+            stream.stage_row(bufs, i, row)
+        stream.submit_staged(bufs, k)
+    stream.close()
+
+
 @pytest.mark.parametrize("shape", ["whole", "remainder"])
 @pytest.mark.parametrize("width", list(BOUNDS))
 def test_served_round_on_a_mesh_stages_every_update_at_arrival(width, shape, four_devices):
     """Two batches (and a remainder batch of one) on four shards: the model
     is the plain reference's bit for bit, every accepted update was staged
-    at arrival and once (its packed bytes, no planar row beside them), each
-    shard's ring was asked once a batch, and each batch committed once."""
+    at arrival and once (its packed bytes, no planar row beside them) and
+    copied to the shards' devices from its slot, row by row (some of it
+    before its batch's flush: the counters are read once the model is
+    published, so nothing here waits for a copy), each shard's ring was asked
+    once a batch, and each batch committed once."""
     config = _config(width)
     n_update = 2 * K + (shape == "remainder")
     batches = 2 + (shape == "remainder")
     count_min = n_update if shape == "whole" else 3 * K
     weights = _weights(n_update, float(config.add_shift))
     order = [int(i) for i in np.random.default_rng(11).permutation(n_update)]
+    padded = -(-MODEL_LEN // 4) * 4
+    row_bytes = config.bytes_per_number * padded
+    _compile_the_mesh_pipeline(config)
     depth0 = streaming.STAGING_DEPTH.value
     out = asyncio.run(asyncio.wait_for(
-        _served_round(_settings(width, 2, n_update, count_min), weights, order), 180))
+        _served_round(_settings(width, 2, n_update, count_min), weights, order, row_bytes), 180))
 
     want = reference_model(weights, int(config.add_shift), config.exp_shift)
     assert np.array_equal(out["model"].view(np.uint64), want.view(np.uint64))
     fold = aggregator_mod.fold_kernel_report()
-    padded = -(-MODEL_LEN // 4) * 4
     assert (fold["shards"], fold["shard_length"]) == (4, padded // 4)
+    assert fold["h2d_route"] == "row"
     moved = out["moved"]
     assert moved["batches", "folded"] == moved["batches", "staged"] == batches
     assert moved["batches", "failed"] == 0
     assert (moved["rows", "arrival"], moved["rows", "flush"]) == (n_update, 0)
     assert moved["staged_bytes"] == n_update * config.bytes_per_number * padded
+    assert moved["h2d_bytes"] == moved["staged_bytes"]
+    # every upload was sent once its predecessors' rows were on the devices:
+    # a flush can have found at most its own, last row's copy outstanding
+    assert (n_update - batches) * row_bytes <= moved["h2d_early_bytes"] <= moved["h2d_bytes"]
     assert moved["commits"] == batches
     ring = {how: moved["ring", how] for how in HOWS}
     assert sum(ring.values()) == 4 * batches, ring

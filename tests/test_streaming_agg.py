@@ -393,9 +393,13 @@ def _rows_staged():
 
 
 def _await_writes(dev):
+    """Every slot write of the open batches has ended, and every row's copy
+    to the device behind it."""
     for batch in dev._open:
         for write in batch.writes:
             write.exception(timeout=30)
+        if batch.bufs is not None:
+            dev._stream.wait_rows(batch.bufs)
 
 
 @WIDTHS
@@ -687,35 +691,48 @@ def test_mesh_round_of_three_batches_takes_each_shards_buffer_again(cfg):
     assert got.object == want.object
 
 
-def test_mesh_shards_copy_host_to_device_one_at_a_time(monkeypatch):
-    """The shards' fold workers start together; their copies of one batch do
-    not overlap (``shards.H2D_GATE``), and each shard's span says whose."""
+@pytest.mark.parametrize("route", ["row", "batch"])
+def test_mesh_shards_copy_host_to_device_one_at_a_time(monkeypatch, route):
+    """No two host-to-device copies overlap (``shards.H2D_GATE``), and each
+    span says whose shard's and by which route. row: a batch staged at
+    arrival, every row's slice of every shard copied by the one copier as
+    its slot is written. batch: a batch staged inside one call, where the
+    shards' fold workers start together and each copies its slice whole."""
     from xaynet_tpu.telemetry import tracing
 
     n, k, n_dev = 103, 4, 4
     objs = _masked_updates(n, k, seed=44)
     dev = _staged(n, batch_size=k, mesh=_mesh(n_dev), shard_parallel=True)
     real = jax.device_put
+    copiers = {"row": "xn-h2d", "batch": "xn-stream-fold-"}
 
     def slow_put(x, device=None, **kw):
-        if threading.current_thread().name.startswith("xn-stream-fold-"):
+        if threading.current_thread().name.startswith(copiers[route]):
             time.sleep(0.05)  # a copy long enough to overlap another
         return real(x, device, **kw)
 
     monkeypatch.setattr(jax, "device_put", slow_put)
     t0 = time.monotonic()
-    for obj in objs:
-        dev.validate_aggregation(obj)
-        dev.aggregate(obj)
+    if route == "row":
+        for obj in objs:
+            dev.validate_aggregation(obj)
+            dev.aggregate(obj)
+    else:
+        dev._stream.submit_batch(np.stack([obj.vect.data for obj in objs]))
     dev.drain()
     spans = [s for s in tracing.get_tracer().ring_spans()
              if s.name == "stream.h2d" and s.start >= t0]
-    assert sorted(s.attrs["shard"] for s in spans) == list(range(n_dev))
+    assert {s.attrs["route"] for s in spans} == {route}
+    copies = k if route == "row" else 1  # of each shard's slice
+    assert sorted(s.attrs["shard"] for s in spans) == sorted(list(range(n_dev)) * copies)
+    if route == "row":
+        assert sorted(s.attrs["slot"] for s in spans) == sorted(list(range(k)) * n_dev)
     spans.sort(key=lambda s: s.start)
     for earlier, later in zip(spans, spans[1:]):
         assert earlier.duration >= 0.05
         assert later.start >= earlier.start + earlier.duration
-    assert dev.finalize().object == _oracle(n, objs).object
+    # the vector alone: the pipeline's own entry point stages no unit
+    assert dev.finalize().object.vect == _oracle(n, objs).object.vect
 
 
 @pytest.mark.parametrize("failing_slot", [0, 2], ids=["first-write", "last-write"])
@@ -825,3 +842,211 @@ def test_staging_ring_stays_within_size_under_concurrent_acquires():
     assert STAGING_DEPTH.value == depth0
     ring.close()
     assert pool.balanced(tenant)
+
+
+# -- a batch staged at arrival goes to the device row by row (ISSUE 48) ------
+#
+# As a slot write ends the pipeline's copier puts that row on the device and
+# places it in the batch's device batch, so the flush folds rows that are
+# resident and waits for the copies still outstanding. The aggregate has to
+# be the one-copy route's and the host reference's, bit for bit, and the host
+# ring buffer stays the source of truth until the fold has returned.
+
+
+def _h2d_counters():
+    from xaynet_tpu.parallel.streaming import H2D_BYTES, H2D_EARLY_BYTES
+
+    return H2D_EARLY_BYTES.value, H2D_BYTES.value
+
+
+def _pipeline(n, cfg, n_dev, packed=True, max_batch=4, **kw):
+    agg = ShardedAggregator(cfg, n, mesh=make_mesh(jax.devices()[:n_dev]), kernel="xla")
+    return agg, StreamingAggregator(agg, max_batch=max_batch, packed=packed, **kw)
+
+
+def _stage_rows(stream, stacks, slots):
+    """Open a batch and write ``stacks[i]`` into slot ``i`` for ``i`` in
+    ``slots``, in that order; the buffers."""
+    bufs = stream.open_batch()
+    for i in slots:
+        stream.stage_row(bufs, i, stacks[i])
+    return bufs
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4, 8])
+@pytest.mark.parametrize("k", [4, 3], ids=["full", "partial"])
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
+@WIDTHS
+def test_rows_copied_at_arrival_fold_bit_equal_to_the_one_copy_route(cfg, packed, k, n_dev):
+    """One batch through ``stage_row`` (rows finishing out of slot order; the
+    row route), the same rows through ``submit_batch`` (one copy at the
+    fold) and the host reference: one aggregate."""
+    from xaynet_tpu.parallel.aggregator import fold_kernel_report
+
+    n = 103
+    stacks, _, host = _updates(n, k, seed=48, cfg=cfg)
+    agg, stream = _pipeline(n, cfg, n_dev, packed)
+    early0, all0 = _h2d_counters()
+    bufs = _stage_rows(stream, stacks, [i for i in (2, 0, 3, 1) if i < k])
+    assert stream.wait_rows(bufs) == k
+    stream.submit_staged(bufs, k)
+    stream.drain()
+    assert fold_kernel_report()["h2d_route"] == "row"
+    early, copied = (now - was for now, was in zip(_h2d_counters(), (early0, all0)))
+    assert early == copied == sum(buf[:k].nbytes for buf in bufs)
+
+    ref, one_copy = _pipeline(n, cfg, n_dev, packed)
+    one_copy.submit_batch(np.stack(stacks))
+    one_copy.drain()
+    assert fold_kernel_report()["h2d_route"] == "batch"
+    assert agg.nb_models == ref.nb_models == k
+    assert np.array_equal(agg.snapshot(), ref.snapshot())
+    assert np.array_equal(agg.snapshot(), host.object.vect.data)
+    stream.close()
+    one_copy.close()
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
+def test_rows_of_a_batch_are_not_read_after_its_buffer_is_lent_again(packed, n_dev):
+    """Five batches through a ring of the shipped size, rows finishing out of
+    slot order and no drain between the batches, so that every buffer is
+    written again while earlier batches are still in flight. On the CPU
+    backend a ``device_put`` may alias the slot's memory: a row read after
+    its buffer went to a later batch would show as a wrong sum."""
+    n, k, batches = 103, 4, 5
+    stacks, _, host = _updates(n, k * batches, seed=49)
+    agg, stream = _pipeline(n, CFG, n_dev, packed, staging_buffers=3)
+    orders = [(3, 1, 0, 2), (0, 2, 1, 3), (2, 3, 1, 0), (1, 0, 3, 2), (3, 2, 1, 0)]
+    leased = set()
+    for b, order in enumerate(orders):
+        bufs = _stage_rows(stream, stacks[b * k:(b + 1) * k], order)
+        leased.add(id(bufs[0]))
+        stream.submit_staged(bufs, k)  # its last copies may still be running
+    stream.drain()
+    assert len(leased) <= 3  # buffers were taken again
+    assert agg.nb_models == k * batches
+    assert np.array_equal(agg.snapshot(), host.object.vect.data)
+    stream.close()
+
+
+@pytest.mark.parametrize("second", ["degrades", "poisons"])
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+def test_failed_row_copy_falls_back_to_one_copy_of_the_batch(n_dev, second):
+    """A row's copy raises (fault site ``streaming.h2d_row``): the batch is
+    folded once, from the ring buffer, by the ladder's synchronous retry;
+    the count is exact, the pipeline reads degraded and not poisoned, and
+    what comes after is copied a batch at a time. Where the retry fails too
+    the pipeline is poisoned and names the batch."""
+    from xaynet_tpu.parallel.aggregator import fold_kernel_report
+    from xaynet_tpu.parallel.shards import ShardPlan
+    from xaynet_tpu.parallel.streaming import DEGRADATIONS
+    from xaynet_tpu.resilience import FaultPlan, clear_plan, install_plan
+
+    n, k = 103, 4
+    stacks, _, host = _updates(n, 2 * k, seed=50)
+    agg, stream = _pipeline(n, CFG, n_dev)
+    degraded0 = DEGRADATIONS.value
+    real_packed = ShardPlan.fold_shard_packed
+
+    def boom(*_a):
+        raise RuntimeError("fold died (stand-in)")
+
+    install_plan(FaultPlan.parse("streaming.h2d_row:error,nth=2"))
+    try:
+        bufs = _stage_rows(stream, stacks, range(k))
+        assert stream.wait_rows(bufs) == 1  # the second copy failed, the rest were skipped
+        if second == "poisons":
+            agg.kernel_used = "xla"
+            agg._packed_fold_fn = boom
+            ShardPlan.fold_shard_packed = boom
+        stream.submit_staged(bufs, k)
+        if second == "poisons":
+            with pytest.raises(StreamingError, match=r"batch 1.*fold died"):
+                stream.drain()
+            assert stream.in_flight_models == 0
+            stream.close()
+            return
+        stream.drain()
+    finally:
+        clear_plan()
+        ShardPlan.fold_shard_packed = real_packed
+    assert stream.degraded and stream._poisoned() is None
+    assert DEGRADATIONS.value == degraded0 + 1
+    assert agg.nb_models == k and fold_kernel_report()["h2d_route"] == "batch"
+    # degraded: rows are no longer copied as they arrive
+    early0, all0 = _h2d_counters()
+    bufs = _stage_rows(stream, stacks[k:], range(k))
+    assert stream.wait_rows(bufs) == 0
+    stream.submit_staged(bufs, k)
+    stream.drain()
+    early, copied = (now - was for now, was in zip(_h2d_counters(), (early0, all0)))
+    assert (early, copied) == (0, sum(buf.nbytes for buf in bufs))
+    assert agg.nb_models == 2 * k
+    assert np.array_equal(agg.snapshot(), host.object.vect.data)
+    stream.close()
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+def test_released_batch_leaves_no_row_on_the_device(n_dev):
+    """``release_batch`` (a failed slot write) drops the rows already copied:
+    the device's live arrays are what they were, the ring is whole, and the
+    next batch folds."""
+    import gc
+
+    n, k = 103, 4
+    stacks, _, host = _updates(n, k, seed=51)
+    agg, stream = _pipeline(n, CFG, n_dev)
+    # a first batch through, so that every executable and constant exists
+    bufs = _stage_rows(stream, stacks, range(k))
+    stream.submit_staged(bufs, k)
+    stream.drain()
+    gc.collect()
+    live0, depth0 = len(jax.live_arrays()), STAGING_DEPTH.value
+    bufs = _stage_rows(stream, stacks, range(3))
+    assert stream.wait_rows(bufs) == 3
+    assert len(jax.live_arrays()) > live0  # a device batch a shard
+    stream.release_batch(bufs)
+    del bufs
+    gc.collect()
+    assert len(jax.live_arrays()) <= live0  # nothing of the batch is left
+    assert STAGING_DEPTH.value == depth0
+    assert not any(ring._inflight for ring in stream._rings.values())
+    bufs = _stage_rows(stream, stacks, range(k))
+    stream.submit_staged(bufs, k)
+    stream.drain()
+    assert agg.nb_models == 2 * k
+    twice = Aggregation(CFG.pair(), n)
+    for _ in range(2):
+        twice.aggregate(host.object)
+    assert np.array_equal(agg.snapshot(), twice.object.vect.data)
+    stream.close()
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["one-device", "mesh4"])
+def test_h2d_counters_tell_rows_copied_before_the_submit_from_the_rest(n_dev):
+    """``h2d_early_bytes_total`` moves by the rows whose copy had ended when
+    their batch was submitted, ``h2d_bytes_total`` by all of them; a batch
+    staged inside one call (``submit_batch``) moves the second alone."""
+    from xaynet_tpu.parallel.shards import H2D_GATE
+
+    n, k = 103, 4
+    stacks, _, host = _updates(n, 2 * k, seed=52)
+    agg, stream = _pipeline(n, CFG, n_dev)
+    early0, all0 = _h2d_counters()
+    bufs = _stage_rows(stream, stacks, (1, 0))
+    assert stream.wait_rows(bufs) == 2
+    row = sum(buf[0].nbytes for buf in bufs)
+    with H2D_GATE:  # the link is busy: the next two rows' copies wait
+        for i in (3, 2):
+            stream.stage_row(bufs, i, stacks[i])
+        stream.submit_staged(bufs, k)
+        assert _h2d_counters() == (early0 + 2 * row, all0 + 2 * row)
+    stream.drain()
+    assert _h2d_counters() == (early0 + 2 * row, all0 + 4 * row)
+    stream.submit_batch(np.stack(stacks[k:]))
+    stream.drain()
+    assert _h2d_counters() == (early0 + 2 * row, all0 + 8 * row)
+    assert agg.nb_models == 2 * k
+    assert np.array_equal(agg.snapshot(), host.object.vect.data)
+    stream.close()

@@ -87,6 +87,11 @@ _AUTO_KERNEL_CACHE: dict[tuple, str] = {}
 # for the coordinator's start-up report and /healthz (fold_kernel_report)
 _LAST_RESOLUTION: dict = {}
 
+# how the last host batch folded in this process reached the device: "row"
+# (copied row by row while it filled) or "batch" (one copy at its fold: a
+# batch staged inside one call, or the fall-back after a failed row copy)
+_LAST_H2D_ROUTE: str | None = None
+
 _RACE_DRAWS = 3  # timed folds per race candidate; the fastest counts
 
 # compiled fold callables, process-wide. jit caches by FUNCTION IDENTITY, so
@@ -104,8 +109,19 @@ def fold_kernel_report() -> dict:
     ``shards`` of them, each ``shard_length`` columns with the padding),
     and for a race ``race`` (per candidate ``status`` = ``ok`` or
     ``failed: <ExceptionType>``, first-call and steady ``seconds``) plus
-    ``results_equal``. Empty before the first fold."""
-    return dict(_LAST_RESOLUTION)
+    ``results_equal``; and ``h2d_route``, the route the last folded host
+    batch's bytes took to the device (``row`` | ``batch``, None before the
+    streaming pipeline has folded one). Empty before the first fold."""
+    if not _LAST_RESOLUTION:
+        return {}
+    return {**_LAST_RESOLUTION, "h2d_route": _LAST_H2D_ROUTE}
+
+
+def note_h2d_route(route: str) -> None:
+    """The streaming pipeline's fold workers say which route the batch they
+    are about to fold took (``fold_kernel_report``)."""
+    global _LAST_H2D_ROUTE
+    _LAST_H2D_ROUTE = route
 
 
 def _mesh_key(mesh) -> tuple:

@@ -39,19 +39,51 @@ cross-shard barrier.
 from __future__ import annotations
 
 import threading
+from functools import partial
 
 import jax
 
 from .mesh import shard_slices
 
 
-# one host->device copy of a staged batch at a time, whatever the shard and
-# whichever plan: the copies share the host's path to the chips. Four 2.15 GB
-# copies started together on a four-chip v5e host ran two at 5.3 GB/s and two
-# at 0.3 GB/s, 5.9-10.2 s for the batch where four in a row need under 2
-# (PERF.md section 6, PR 40); a shard's fold still runs beside the next
-# shard's copy.
+# one host->device copy of staged rows at a time, whatever the shard and
+# whichever pipeline: the copies share the host's path to the chips. Four
+# 2.15 GB copies started together on a four-chip v5e host ran two at 5.3 GB/s
+# and two at 0.3 GB/s, 5.9-10.2 s for the batch where four in a row need
+# under 2 (PERF.md section 6, PR 40). Held by a pipeline's row copier for one
+# row's slice (``streaming._copy_row``: a batch staged at arrival goes up row
+# by row while it fills) and by a shard's fold worker for its slice of a
+# whole batch (the batch route); a shard's fold still runs beside the next
+# copy.
 H2D_GATE = threading.Lock()
+
+
+def settle(out):
+    """What a donating device dispatch returned, as its caller may publish
+    it while it still holds the dispatch lock. jax's dispatch/execution path
+    is not reliably thread-safe for concurrent donating jit calls on the
+    virtual-device CPU backend (~1 in 40k folds lands a torn shard slice
+    under scheduler contention — reproduced with no fault injection), so
+    there the lock is held through COMPLETION: the virtual devices share the
+    physical cores, and serialized executions lose no real parallelism. On
+    real accelerators only the host-side dispatch serializes; per-device
+    execution stays concurrent."""
+    if jax.default_backend() == "cpu":
+        out = jax.block_until_ready(out)  # lint: sync-ok
+    return out
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def place_row(batch, row, i):
+    """One staged row into slot ``i`` of a device-resident batch of rows, in
+    place (the batch is donated): how a batch staged at arrival is assembled
+    on its device while it fills, so that the fold finds its ``[K, ...]``
+    operand resident (``streaming._put_row``). ``row`` has a slot's shape or
+    is the slot flat, as it crossed the link. One executable a batch shape,
+    whatever the slot; no fold, and not named like one."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        batch, row.reshape((1,) + batch.shape[1:]), i, axis=0
+    )
 
 
 class ShardPlan:
@@ -60,25 +92,17 @@ class ShardPlan:
     Built against a resolved kernel (``agg.kernel_used``).
     """
 
-    def __init__(self, agg):
+    def __init__(self, agg, dispatch_lock: threading.Lock | None = None):
         if agg.kernel_used is None:
             raise ValueError("kernel must be resolved before building a shard plan")
         self.agg = agg
         self.slices = shard_slices(agg.padded_length, agg.mesh.devices.size)
         self.devices = list(agg.mesh.devices.flat)
-        # serializes device folds issued from the D worker threads: jax's
-        # dispatch/execution path is not reliably thread-safe for
-        # concurrent donating jit calls on the virtual-device CPU backend
-        # (~1 in 40k folds lands a torn shard slice under scheduler
-        # contention — reproduced with no fault injection). On CPU the
-        # lock is held through COMPLETION: the virtual devices share the
-        # physical cores, so serialized folds lose no real parallelism
-        # (XLA's intra-op pool still spans the cores, and staging copies
-        # keep overlapping). On real accelerators only the host-side
-        # dispatch serializes — per-device execution stays concurrent,
-        # which is the point of the shard fan-out.
-        self._device_dispatch_lock = threading.Lock()
-        self._serialize_device_folds = jax.default_backend() == "cpu"
+        # serializes the donating device dispatches issued from the D worker
+        # threads (see ``settle``). A streaming pipeline hands in
+        # its own lock, which its row copier takes too: a row's placement
+        # into the device batch donates as a fold does
+        self._device_dispatch_lock = dispatch_lock or threading.Lock()
         # zero-copy decomposition: the addressable shards of the
         # mesh-sharded accumulator ARE the per-device slices; the first
         # donated fold invalidates the global array, which is exactly the
@@ -159,13 +183,10 @@ class ShardPlan:
         note). The shard accumulator is reassigned only after ``call``
         returns — an exception leaves the shard consistent."""
         with self._device_dispatch_lock:
-            new_acc = call(self.accs[d])
-            if self._serialize_device_folds:
-                new_acc = jax.block_until_ready(new_acc)  # lint: sync-ok
             # reassign INSIDE the lock: the slot write itself must not
             # interleave with another shard's donating dispatch (the PR-7
             # torn-slice hazard this lock exists for)
-            self.accs[d] = new_acc
+            self.accs[d] = settle(call(self.accs[d]))
 
     # -- barrier / reassembly ---------------------------------------------
 

@@ -16,6 +16,20 @@ the device take turns idling. This module turns that into a pipeline:
   slots as they arrive (``stage_row``) and submitting the batch
   (``submit_staged``) relays nothing out. There is one ring a shard: a
   single-device pipeline is the case of one shard as wide as the model.
+- **rows go to the device as their slots are written** — a batch that fills
+  through ``open_batch``/``stage_row`` has an arrival interval to copy in:
+  when a slot write ends the row is queued to the pipeline's one copier
+  thread (``xn-h2d``), which ``device_put``s each shard's slice of it to
+  that shard's device, one copy at a time, and places it in a device batch
+  of the ring buffer's shape (``shards.place_row``, in place). The fold at
+  the flush waits for the copies still outstanding (after the last write:
+  one row's) and runs once, over the resident batch; slots no row went to
+  are zeros, which add nothing. The host ring buffer stays the batch's
+  source of truth until that fold has returned: a failed row copy is the
+  ladder's first failure, and the retry copies the batch whole from the
+  ring buffer, which is also how a batch staged inside one call
+  (``submit_batch``, raw wire batches) and every batch of a degraded
+  pipeline reach the device.
 - **dispatch-ahead depth** — up to ``dispatch_ahead`` batches are queued to
   a single fold worker thread, so XLA's asynchronous dispatch keeps
   multiple folds in flight behind one another while the producer stages
@@ -94,9 +108,9 @@ from ..tenancy.scheduler import get_scheduler
 # BYTES_STAGED: one module owns the xaynet_bytes_staged_total family —
 # aggregator.py registers it (wire-ingest staging accounts there too) and
 # the streaming rings account through the shared symbol
-from .aggregator import BYTES_REDUCED, BYTES_STAGED, ShardedAggregator
+from .aggregator import BYTES_REDUCED, BYTES_STAGED, ShardedAggregator, note_h2d_route
 from .mesh import shard_slices
-from .shards import H2D_GATE
+from .shards import H2D_GATE, place_row, settle
 
 logger = logging.getLogger(__name__)
 
@@ -164,15 +178,24 @@ SHARD_OVERLAP = _registry.gauge(
 )
 H2D_SECONDS = _registry.histogram(
     "xaynet_streaming_h2d_seconds",
-    "One staged batch's host-to-device copy: device_put until the staged "
-    "array is ready, the fold's dispatch in between (and, while "
-    "XAYNET_KERNEL_PROFILE keeps its per-fold sync, that fold's device time).",
+    "One host-to-device copy of staged rows, device_put until the array is "
+    "ready: one row (a shard's slice of it) of a batch staged at arrival, "
+    "copied by the pipeline's copier while the batch fills; or a whole batch "
+    "(a shard's slice of it) copied by a fold worker, where on one shard the "
+    "fold's dispatch (and, while XAYNET_KERNEL_PROFILE keeps its per-fold "
+    "sync, that fold's device time) lies in between.",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
              1.0, 2.5, 5.0, 10.0, 30.0),
 )
 H2D_BYTES = _registry.counter(
     "xaynet_streaming_h2d_bytes_total",
-    "Bytes of staged batches copied host to device by the fold worker.",
+    "Bytes of staged rows copied host to device, row by row as their batch "
+    "filled or a batch at a time by a fold worker.",
+)
+H2D_EARLY_BYTES = _registry.counter(
+    "xaynet_streaming_h2d_early_bytes_total",
+    "Bytes of staged rows whose host-to-device copy was complete before "
+    "their batch was submitted (the flush had no copy of them to wait for).",
 )
 ROWS_STAGED = _registry.counter(
     "xaynet_streaming_rows_staged_total",
@@ -203,15 +226,18 @@ _SHUTDOWN = object()
 
 
 @contextmanager
-def _h2d(kind: str, nbytes: int, **shard):
-    """One staged batch's host-to-device copy: from ``device_put`` to the
-    wait that frees the ring buffer. In ``_fold_payload`` the fold's
-    dispatch lies between the two (the order is the hot path's, and this
-    adds no sync), so the time is an upper bound of the copy alone; a
-    shard's slice of a batch (``_fold_shard_item``, ``shard=d``) is copied
-    before its fold is dispatched, and the time is the copy's."""
+def _h2d(kind: str, nbytes: int, route: str, **where):
+    """One host-to-device copy of staged rows, from ``device_put`` to the
+    array ready. ``route="row"``: one row of a batch staged at arrival
+    (``_put_row``; ``slot=i``, and ``shard=d`` for a shard's slice of it),
+    and the time is the copy's. ``route="batch"``: a whole batch copied by
+    a fold worker. There a shard's slice (``_fold_shard_item``,
+    ``shard=d``) is copied before its fold is dispatched and the time is
+    the copy's; in ``_fold_payload`` the fold's dispatch lies between the
+    two (the order is the hot path's, and this adds no sync), so the time
+    is an upper bound of the copy alone."""
     t0 = time.monotonic()
-    with trace.get_tracer().span(SPAN_H2D, kind=kind, bytes=nbytes, **shard):
+    with trace.get_tracer().span(SPAN_H2D, kind=kind, bytes=nbytes, route=route, **where):
         yield
     H2D_SECONDS.observe(time.monotonic() - t0)
     H2D_BYTES.inc(nbytes)
@@ -272,9 +298,9 @@ class _BatchJob:
     """
 
     __slots__ = ("kind", "k", "ticket", "seq", "remaining", "failed", "retried",
-                 "first_done", "staged", "global_release")
+                 "first_done", "staged", "global_release", "rows")
 
-    def __init__(self, kind: str, k: int, ticket, seq: int, n_shards: int):
+    def __init__(self, kind: str, k: int, ticket, seq: int, n_shards: int, rows=None):
         self.kind = kind
         self.k = k
         self.ticket = ticket
@@ -283,6 +309,10 @@ class _BatchJob:
         self.failed = False  # guarded-by: _lock
         self.retried = False  # guarded-by: _lock
         self.first_done = None  # when the first shard settled  # guarded-by: _lock
+        # the batch's rows on the devices (_RowCopies), None where it was
+        # staged inside one call, the pipeline has degraded, or a shard's
+        # retry sent the rest to the ring buffer  # guarded-by: _lock
+        self.rows = rows
         # staged/global_release are NOT lock-guarded: after `remaining`
         # hits zero under the lock, exactly ONE worker (the last shard)
         # reaches the commit tail that touches them — ownership handoff
@@ -309,6 +339,94 @@ class _UnmaskJob:
         self.remaining = n_shards  # guarded-by: _lock (the owning pipeline's)
         self.error = None  # guarded-by: _lock
         self.done = threading.Event()
+
+
+class _RowCopies:
+    """The device half of one batch staged at arrival. While the batch
+    fills, the pipeline's copier puts every row on the device as its slot
+    write ends (``_copy_row``), a shard's slice of the row into slot ``i``
+    of that shard's device batch ``dev[d]`` (the ring buffer's shape, zeros
+    where no row went), so the flush finds the fold's operand resident and
+    waits for the copies still outstanding: after the last write, one row's.
+    The ring buffers stay the source of truth until the fold has returned: a
+    copy that failed (``error``, raised to the fold worker, whose ladder
+    copies the batch whole), rows that do not cover the submitted slots, or
+    a batch given back unfolded leave the device rows dropped and nothing
+    else to undo."""
+
+    __slots__ = ("bufs", "dev", "slots", "queued", "ended", "nbytes", "error",
+                 "dropped", "_cond")
+
+    def __init__(self, bufs: list[np.ndarray]):
+        self.bufs = bufs
+        self._cond = threading.Condition()
+        # a device batch a shard. NOT lock-guarded: the copier's alone while
+        # a copy is outstanding; take() and drop() touch it once every
+        # queued copy has ended — ownership handoff through the counters
+        self.dev: list | None = None
+        self.slots: set[int] = set()  # slots whose row is resident  # guarded-by: _cond
+        self.queued = 0  # copies handed to the copier  # guarded-by: _cond
+        self.ended = 0  # of those, ended (well, badly or skipped)  # guarded-by: _cond
+        self.nbytes = 0  # bytes of the resident rows  # guarded-by: _cond
+        self.error: BaseException | None = None  # first failed copy  # guarded-by: _cond
+        self.dropped = False  # guarded-by: _cond
+
+    def queue(self) -> None:
+        with self._cond:
+            self.queued += 1
+
+    def wanted(self) -> bool:
+        """Whether a queued copy should still run."""
+        with self._cond:
+            return not self.dropped and self.error is None
+
+    def end(self, slot: int, nbytes: int, error: BaseException | None) -> None:
+        """One queued copy has ended: ``nbytes`` of slot ``slot`` resident,
+        or nothing (skipped), or ``error``."""
+        with self._cond:
+            self.ended += 1
+            if error is not None:
+                self.error = self.error or error
+            elif nbytes:
+                self.slots.add(slot)
+                self.nbytes += nbytes
+            self._cond.notify_all()
+
+    def wait(self) -> int:
+        """Block until every queued copy has ended; the rows resident."""
+        with self._cond:
+            while self.ended < self.queued:
+                self._cond.wait()
+            return len(self.slots)
+
+    def resident_bytes(self) -> int:
+        with self._cond:
+            return self.nbytes
+
+    def take(self, d: int, k: int):
+        """Shard ``d``'s device batch for the fold of the first ``k`` slots,
+        handed over (the fold's reference is the last): waits for the
+        outstanding copies, raises the first one's failure, and returns None
+        where the resident rows are not exactly those slots."""
+        with self._cond:
+            while self.ended < self.queued:
+                self._cond.wait()
+            if self.error is not None:
+                raise self.error
+            if self.dropped or self.dev is None or self.slots != set(range(k)):
+                self.dev = None
+                return None
+            batch, self.dev[d] = self.dev[d], None
+            return batch
+
+    def drop(self) -> None:
+        """Let the device rows go (after the copy in flight, if any, so
+        that no copy reads a ring buffer that has gone back)."""
+        with self._cond:
+            self.dropped = True
+            while self.ended < self.queued:
+                self._cond.wait()
+            self.dev = None
 
 
 def _release_ring(pool, leases: list, inflight: dict, gauge) -> None:
@@ -531,6 +649,27 @@ class StreamingAggregator:
         self._shard_queues: list[queue_mod.Queue] | None = None
         self._shard_workers: list[threading.Thread | None] = []
         self._queue: queue_mod.Queue = queue_mod.Queue(maxsize=dispatch_ahead)
+        # the row copier (``xn-h2d``): one thread, one copy in flight, the
+        # rows of batches staged at arrival in the order their writes ended
+        self._h2d_queue: queue_mod.Queue = queue_mod.Queue()
+        self._copier: threading.Thread | None = None  # guarded-by: _lock
+        self._row_copies: dict[int, _RowCopies] = {}  # id(open buffer) -> its rows  # guarded-by: _lock
+        # where a shard's slice of a row, and its device batch, live: the
+        # shard's device, or on one worker the mesh. A row whose home is one
+        # device travels flat: as ``[planes, width]`` the chip would pad its
+        # planes to a tile of eight (7 -> 8, 10 -> 16) and the link would
+        # carry the padding (3.7-4.4 GB/s against 6.4-6.6 flat, PERF.md
+        # section 6, PR 48); over a mesh it keeps its model axis, sharded
+        devices = list(agg.mesh.devices.flat)
+        self._flat_rows = self._n_shards == n_dev  # every shard is one device
+        self._row_at = devices if self._flat_rows else [agg._acc_sharding]
+        self._batch_at = devices if self._sharded else [
+            agg._batch_packed_sharding if self._packed else agg._batch_sharding
+        ]
+        # every donating device dispatch of this pipeline's threads (a fold,
+        # a row's placement into its device batch) runs under it, see
+        # ``shards.settle``; the shard plan folds under the same lock
+        self._device_lock = threading.Lock()
         # lazy: "wire", or a shard's index (its host batches)  # guarded-by: _lock
         self._rings: dict[str | int, _StagingRing] = {}
         self._pending: list[StreamTicket] = []  # awaiting ok sync  # guarded-by: _lock
@@ -567,6 +706,22 @@ class StreamingAggregator:
             # wake the worker if this pipeline is dropped without close()
             weakref.finalize(self, self._queue.put, _SHUTDOWN)
 
+    def _ensure_copier(self) -> None:
+        with self._lock:
+            if self._copier is not None and self._copier.is_alive():
+                return
+            fresh = self._copier is None
+            self._copier = threading.Thread(
+                target=_worker_main,
+                args=(weakref.ref(self), self._h2d_queue),
+                name="xn-h2d",
+                daemon=True,
+            )
+            self._copier.start()
+        if fresh:
+            # wake the copier if this pipeline is dropped without close()
+            weakref.finalize(self, self._h2d_queue.put, _SHUTDOWN)
+
     def close(self) -> None:
         """Drain, then stop the fold worker. Idempotent. A poisoned
         pipeline (worker failure) still shuts down — the error has already
@@ -590,6 +745,14 @@ class StreamingAggregator:
             for w in self._shard_workers:
                 if w is not None and w.is_alive():
                     w.join(timeout=60.0)
+        with self._lock:
+            copier, unsubmitted = self._copier, list(self._row_copies.values())
+            self._row_copies.clear()
+        for rows in unsubmitted:  # open batches never submitted
+            rows.drop()
+        if copier is not None and copier.is_alive():
+            self._h2d_queue.put(_SHUTDOWN)
+            copier.join(timeout=60.0)
         # the per-shard buffers stay ADOPTED by the aggregator
         # (reduce-scatter) so finalize/unmask/snapshot after close still
         # read the accumulator — on a poisoned pipeline they surface the
@@ -717,7 +880,7 @@ class StreamingAggregator:
     def _dispatch(self, item: tuple) -> None:
         """Queue to the fold worker — or, once degraded, fold synchronously
         on the caller's thread (same math, no overlap)."""
-        buf, payload, kind, k, ticket, seq = item
+        buf, payload, kind, k, ticket, seq, rows = item
         self._slot_acquire()  # released when the fold settles (_process)
         with self._lock:
             self._in_flight_models += k
@@ -729,6 +892,8 @@ class StreamingAggregator:
             self._queue.put(item)
             return
         t0 = time.monotonic()
+        if rows is not None:
+            rows.drop()  # the degraded path copies the batch whole
         try:
             # serialize with the worker: batches queued BEFORE degradation
             # (including the retry that flipped the flag) must finish before
@@ -795,6 +960,7 @@ class StreamingAggregator:
             raise
         with self._lock:
             self._lent_since[id(bufs[0])] = time.monotonic()
+            self._row_copies[id(bufs[0])] = _RowCopies(bufs)
         return bufs
 
     def _relay_wire_rows(self, view: np.ndarray, stack: np.ndarray, lo: int, hi: int) -> None:
@@ -827,12 +993,38 @@ class StreamingAggregator:
         for buf, (lo, hi) in zip(bufs, self._slices):
             self._relay_wire_rows(buf[i : i + 1], wire[None], lo, hi)
         ROWS_STAGED.labels(route="arrival").inc()
+        # the slot is written: its copy to the device starts now, behind
+        # the copies queued before it, and not on this thread. A degraded
+        # pipeline copies a batch whole at its fold, as before
+        with self._lock:
+            rows = None if self._degraded else self._row_copies.get(id(bufs[0]))
+        if rows is not None:
+            rows.queue()
+            self._ensure_copier()
+            self._h2d_queue.put((rows, i))
+
+    def wait_rows(self, bufs: list[np.ndarray]) -> int:
+        """Block until every row copy queued so far for an open batch has
+        ended (what its fold waits for); the number of rows resident."""
+        with self._lock:
+            rows = self._row_copies.get(id(bufs[0]))
+        return rows.wait() if rows is not None else 0
+
+    def _close_open(self, bufs: list[np.ndarray]) -> tuple:
+        """An open batch stops being open: when its buffers were lent, and
+        its device rows (either may be None)."""
+        if not bufs:
+            return None, None
+        with self._lock:
+            return (self._lent_since.pop(id(bufs[0]), None),
+                    self._row_copies.pop(id(bufs[0]), None))
 
     def release_batch(self, bufs: list[np.ndarray]) -> None:
-        """Return an open batch's buffers unfolded (a failed slot write)."""
-        if bufs:
-            with self._lock:
-                self._lent_since.pop(id(bufs[0]), None)
+        """Return an open batch's buffers unfolded (a failed slot write);
+        the rows already copied are dropped first."""
+        _lent, rows = self._close_open(bufs)
+        if rows is not None:
+            rows.drop()
         for d, buf in enumerate(bufs):
             self._ring(self._host_kind, d).release(buf)
 
@@ -856,16 +1048,19 @@ class StreamingAggregator:
             self.release_batch(bufs)
             raise
         self._batch_seq += 1
-        with self._lock:
-            lent = self._lent_since.pop(id(bufs[0]), None)
+        lent, rows = self._close_open(bufs)
         if lent is not None:
             # the buffers in hand -> handed over, every shard's leg alike
             shards = range(self._n_shards) if self._sharded else ()
             self._leg(lent, "stage", *(("stage", d) for d in shards))
+        if rows is not None:
+            # what the flush finds on the device already; its fold waits for
+            # the rest (after the last slot write: one row's copy)
+            H2D_EARLY_BYTES.inc(rows.resident_bytes())
         if plan is None:
-            self._dispatch((bufs[0], views[0], kind, k, ticket, self._batch_seq))
+            self._dispatch((bufs[0], views[0], kind, k, ticket, self._batch_seq, rows))
             return ticket
-        job = _BatchJob(kind, k, ticket, self._batch_seq, self._n_shards)
+        job = _BatchJob(kind, k, ticket, self._batch_seq, self._n_shards, rows=rows)
         self._dispatch_sharded(job, [
             (job, d, view, self._ring(kind, d), buf)
             for d, (view, buf) in enumerate(zip(views, bufs))
@@ -1073,7 +1268,7 @@ class StreamingAggregator:
         if self._sharded:
             return self._dispatch_sharded_wire(ring, buf, view, k, ticket)
         self._batch_seq += 1
-        self._dispatch((buf, view, "wire", k, ticket, self._batch_seq))
+        self._dispatch((buf, view, "wire", k, ticket, self._batch_seq, None))
         return ticket
 
     # -- fold worker -------------------------------------------------------
@@ -1086,16 +1281,24 @@ class StreamingAggregator:
         times."""
         agg = self.agg
         fold = agg._fold_packed if packed else agg._fold
-        new_acc = fold(agg.acc, staged)
+        with self._device_lock:  # the copier's placements donate too
+            new_acc = settle(fold(agg.acc, staged))
         with self._lock:
             agg.acc = new_acc
             agg.nb_models += k
             self._in_flight_models -= k
 
-    def _fold_payload(self, payload, kind: str, k: int, ticket, defer_ok: bool) -> None:
+    def _fold_payload(self, payload, kind: str, k: int, ticket, defer_ok: bool,
+                      rows: _RowCopies | None = None) -> None:
         """Fold one staged batch. ``defer_ok=True`` (worker path) leaves a
         wire batch's acceptance vector in flight for drain's single sync;
         ``defer_ok=False`` (degraded sync path) resolves it immediately.
+
+        A host batch whose rows went to the device as it filled (``rows``)
+        is folded from there once the copies still outstanding have ended;
+        a failed copy is raised before anything is folded, so the ladder's
+        retry (which passes no rows) copies the batch whole from the ring
+        buffer, as a batch staged inside one call always is.
 
         Failure classes matter here: the accumulator is reassigned only
         when a fold call RETURNS, so an exception raised before/inside the
@@ -1150,13 +1353,16 @@ class StreamingAggregator:
                 np.asarray(payload), agg.n_limbs  # host ring view  # lint: sync-ok
             )
             agg._resolve_kernel(jax.device_put(planar, agg._batch_sharding))
-        with _h2d(kind, payload.nbytes):
-            staged = jax.device_put(
-                payload, agg._batch_packed_sharding if packed else agg._batch_sharding
-            )
+        staged = rows.take(0, k) if rows is not None else None
+        note_h2d_route("batch" if staged is None else "row")
+        with _h2d(kind, payload.nbytes, "batch") if staged is None else nullcontext():
+            if staged is None:
+                staged = jax.device_put(payload, self._batch_at[0])
             self._credit(staged, k, packed=packed)
             try:
-                jax.block_until_ready(staged)  # host buffer free to reuse  # lint: sync-ok
+                # host buffer free to reuse: the batch's copy, or its rows'
+                # placements into the device batch, have read it
+                jax.block_until_ready(staged)  # lint: sync-ok
             except BaseException as e:
                 # _credit already handed the count off: settled
                 raise _UnsafeFoldError(settled=True) from e
@@ -1205,14 +1411,16 @@ class StreamingAggregator:
             return self._process_unmask(item)
         if isinstance(item[0], _BatchJob):  # shard-parallel item
             return self._process_shard(item)
-        buf, payload, kind, k, ticket, seq = item
+        if isinstance(item[0], _RowCopies):  # the copier's item
+            return self._copy_row(item)
+        buf, payload, kind, k, ticket, seq, rows = item
         agg_t0 = time.monotonic()
         outcome = "folded"
         with trace.get_tracer().span(SPAN_FOLD, batch=seq, kind=kind, k=k) as fold_span:
             try:
                 try:
                     maybe_fail("streaming.fold")
-                    self._fold_payload(payload, kind, k, ticket, defer_ok=True)
+                    self._fold_payload(payload, kind, k, ticket, defer_ok=True, rows=rows)
                 except BaseException as first:
                     if isinstance(first, _UnsafeFoldError):
                         # acc may already reference the batch: retrying would
@@ -1250,6 +1458,53 @@ class StreamingAggregator:
                 cause, pseq = self._error, self._poison_seq
             if cause is not None:
                 self._flight_poison(cause, pseq)
+
+    # -- row copier --------------------------------------------------------
+
+    def _copy_row(self, item: tuple) -> None:
+        """The copier's item: slot ``i`` of an open batch has been written
+        and goes to the device, every shard's slice of it. Nothing raises
+        here: a failure is the batch's (``_RowCopies.error``), found by its
+        fold worker, and the rows queued behind it are skipped."""
+        rows, i = item
+        nbytes, error = 0, None
+        try:
+            if rows.wanted():
+                maybe_fail("streaming.h2d_row")
+                nbytes = self._put_row(rows, i)
+        except BaseException as e:
+            error = e
+            logger.warning("row copy of slot %d failed (%s: %s)", i, type(e).__name__, e)
+        finally:
+            rows.end(i, nbytes, error)
+
+    def _put_row(self, rows: _RowCopies, i: int) -> int:
+        """Copy slot ``i`` of every shard's ring buffer to that shard's
+        device, one copy at a time (``shards.H2D_GATE``), and place it in
+        the shard's device batch (made, zeroed, with the batch's first row).
+        Returns the bytes copied."""
+        import jax
+        import jax.numpy as jnp
+
+        if rows.dev is None:
+            rows.dev = [
+                jnp.zeros(buf.shape, buf.dtype, device=at)
+                for buf, at in zip(rows.bufs, self._batch_at)
+            ]
+        nbytes = 0
+        for d, buf in enumerate(rows.bufs):
+            row = buf[i].reshape(-1) if self._flat_rows else buf[i]  # a view
+            where = {"shard": d} if self._sharded else {}
+            with H2D_GATE, _h2d(self._host_kind, row.nbytes, "row", slot=i, **where):
+                # enqueue only; on a mesh under the dispatch lock, as a
+                # shard's whole-batch copy is (_fold_shard_item)
+                with self._device_lock if self._sharded else nullcontext():
+                    staged = jax.device_put(row, self._row_at[d])
+                jax.block_until_ready(staged)  # lint: sync-ok
+            with self._device_lock:
+                rows.dev[d] = settle(place_row(rows.dev[d], staged, i))
+            nbytes += row.nbytes
+        return nbytes
 
     # -- drain -------------------------------------------------------------
 
@@ -1400,7 +1655,7 @@ class StreamingAggregator:
             # persists across drain windows as the authoritative
             # accumulator, so the per-drain reassemble+decompose round
             # trip is gone — the only gathers left are explicit acc reads
-            plan = ShardPlan(agg)
+            plan = ShardPlan(agg, dispatch_lock=self._device_lock)
             agg.adopt_plan(plan)
             with self._lock:
                 self._plan = plan
@@ -1456,6 +1711,10 @@ class StreamingAggregator:
             return
         t0 = time.monotonic()
         released = [False] * len(items)
+        with self._lock:
+            rows, job.rows = job.rows, None
+        if rows is not None:
+            rows.drop()  # the degraded path copies the batch whole
         try:
             # serialize with the shard workers: batches queued BEFORE the
             # degradation must land before caller-thread folds touch the
@@ -1578,30 +1837,39 @@ class StreamingAggregator:
         return ticket
 
     def _fold_shard_item(self, job: _BatchJob, d: int, payload) -> None:
-        """Fold one shard's slice of one batch: its host-to-device copy, one
-        shard's at a time (``shards.H2D_GATE``), then its fold. The copy is
-        complete before the fold is dispatched and the shard's accumulator
-        is reassigned only after the fold returns, so an exception here
-        leaves it consistent (the per-shard retry relies on that)."""
+        """Fold one shard's slice of one batch: the slice resident on the
+        shard's device, then its fold. A batch staged at arrival went up row
+        by row as it filled (``job.rows``) and the wait is for the copies
+        still outstanding; otherwise, and on the retry after a failure, the
+        slice is copied whole here, one shard's at a time
+        (``shards.H2D_GATE``). The copy is complete before the fold is
+        dispatched and the shard's accumulator is reassigned only after the
+        fold returns, so an exception here leaves it consistent (the
+        per-shard retry relies on that)."""
         with self._lock:
             plan = self._plan
+            rows = job.rows
         if job.kind == "wire":
             plan.fold_shard(d, payload)
             return
         packed = job.kind == "packed"
         import jax
 
-        with H2D_GATE, _h2d(job.kind, payload.nbytes, shard=d):
-            with plan._device_dispatch_lock:
-                # host-side transfer enqueue only — the copy itself proceeds
-                # async and the barrier below stays outside the lock (packed
-                # staging: only bpn-byte planes cross here, the unpack runs
-                # in-graph on the shard's device)
-                staged = jax.device_put(payload, plan.devices[d])
-            # the transfer out of the ring buffer completes before the next
-            # shard's begins (the gate) and before this shard's fold is
-            # dispatched, so a failure here leaves the accumulator untouched
-            jax.block_until_ready(staged)  # lint: sync-ok
+        staged = rows.take(d, job.k) if rows is not None else None
+        note_h2d_route("batch" if staged is None else "row")
+        if staged is None:
+            with H2D_GATE, _h2d(job.kind, payload.nbytes, "batch", shard=d):
+                with plan._device_dispatch_lock:
+                    # host-side transfer enqueue only — the copy itself
+                    # proceeds async and the barrier below stays outside the
+                    # lock (packed staging: only bpn-byte planes cross here,
+                    # the unpack runs in-graph on the shard's device)
+                    staged = jax.device_put(payload, plan.devices[d])
+                # the transfer out of the ring buffer completes before the
+                # next shard's begins (the gate) and before this shard's
+                # fold is dispatched, so a failure here leaves the
+                # accumulator untouched
+                jax.block_until_ready(staged)  # lint: sync-ok
         if packed:
             plan.fold_shard_packed(d, staged)
         else:
@@ -1623,10 +1891,12 @@ class StreamingAggregator:
             first,
         )
         with self._lock:
-            self._degraded = True
+            fresh, self._degraded = not self._degraded, True
             job.retried = True
+            job.rows = None  # what is left of the batch is copied whole
         DEGRADED.set(1)
-        DEGRADATIONS.inc()
+        if fresh:  # a failed row copy is met by every shard: one degradation
+            DEGRADATIONS.inc()
         try:
             self._fold_shard_item(job, d, payload)
             return True
