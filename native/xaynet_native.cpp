@@ -14,6 +14,7 @@
 // Built as a plain shared library; loaded via ctypes (no pybind11).
 
 #include <atomic>
+#include <chrono>
 #include <cerrno>
 #include <cstdint>
 #include <cstdlib>
@@ -493,11 +494,14 @@ void fold_wire_u64_slice(const uint32_t* acc, const uint32_t* stack, uint32_t* o
 // the OUTPUT index of an accepted candidate (how many were accepted before it)
 // depends on the stream. A seed's candidates are therefore cut into segments
 // of `seg_cand` candidates. Any thread samples any segment into a small
-// compacted buffer, then commits it in segment order: reads the seed's running
-// count `pos`, publishes `pos + cnt` for the next segment, and only then adds
-// its values into `acc[pos : pos + cnt]`. The ranges of one seed are disjoint,
-// so the threads of a group share one accumulator with no lock, and the
-// serial part of a segment is two loads and two stores. The walk of a seed
+// compacted buffer and publishes the count it accepted; segments are placed in
+// segment order (the seed's running count `pos` is a segment's position,
+// `pos + cnt` the next one's) by whichever thread finds the due one sampled,
+// and the owner then adds its values into `acc[pos : pos + cnt]`. A thread
+// whose segment is not placed yet samples on (DS_RUN_AHEAD), so one thread
+// taken off its core does not stop the others. The ranges of one seed are
+// disjoint, so the threads of a group share one accumulator with no lock, and
+// the serial part of a segment is a few loads and stores. The walk of a seed
 // stops at the segment in which the n-th acceptance falls; the end cursor is
 // the byte after that attempt, as in xn_sample_uniform.
 //
@@ -527,14 +531,40 @@ inline void spin_wait(unsigned& spins) {
   }
 }
 
+// A sampled segment on its way into the sum: its owner publishes the count it
+// accepted (`sampled`), whoever places it gives it the position its values go
+// to (`placed`), and the owner takes that (`taken`) and adds them there. A
+// slot serves ticket j, then j + ring, ...: the three words hold the ticket
+// plus one, and a ticket is published only once the slot's last is taken.
+struct DeriveSlot {
+  std::atomic<uint64_t> sampled{0};
+  std::atomic<uint64_t> placed{0};
+  std::atomic<uint64_t> taken{0};
+  uint64_t cnt = 0, pos = 0, take = 0;
+};
+
 struct alignas(64) DeriveSeed {
   std::atomic<uint64_t> next{0};   // next segment to sample (a ticket)
-  std::atomic<uint64_t> turn{0};   // the segment whose commit is due
-  std::atomic<uint64_t> added{0};  // committed segments whose adds are complete
-  std::atomic<bool> done{false};   // the segment holding the n-th accept has committed
-  uint64_t pos = 0;                // accepted so far; owned by the committer in turn
-  uint64_t total = 0;              // committed segments; valid once `done`
+  std::atomic<uint64_t> turn{0};   // the segment whose place is due
+  std::atomic<uint64_t> added{0};  // placed segments whose adds are complete
+  std::atomic<bool> done{false};   // the segment holding the n-th accept is placed
+  std::atomic<bool> placing{false};  // one thread at a time walks `turn` on
+  std::atomic<uint64_t> pos{0};    // accepted so far; written by the placing thread
+  uint64_t total = 0;              // placed segments; valid once `done`
+  std::unique_ptr<DeriveSlot[]> ring;
 };
+
+// A thread whose segment cannot be placed yet samples on: up to this many
+// segments held, sampled and not added. Positions are handed out in ticket
+// order, so a thread that the scheduler (or, on a shared host, the
+// hypervisor) takes off its core in the middle of a segment holds up the
+// place of every later one; the others then lose that time after
+// DS_RUN_AHEAD segments of further work each, and not at once. Buffers past
+// the first are allocated and touched only where such a wait happened.
+constexpr uint32_t DS_RUN_AHEAD = 4;
+// ... once it has waited this long: placing is a handful of stores, so in
+// step the wait is microseconds and a thread holds one segment.
+constexpr auto DS_AHEAD_AFTER = std::chrono::microseconds(100);
 
 // Compact the accepted candidates of `c` attempts at `p` into `vals`.
 // Orders of up to 8 bytes: one masked 8-byte load an attempt, and a store
@@ -708,48 +738,128 @@ int derive_sum_run(const DeriveSumArgs& a) {
     accs[g] = extra.back().get();
   }
   std::unique_ptr<DeriveSeed[]> seeds(new DeriveSeed[a.k]);
+  // a seed's tickets in flight: DS_RUN_AHEAD for each thread of its group
+  const uint64_t ring = (uint64_t)((nt + ng - 1) / ng) * DS_RUN_AHEAD;
+  for (uint64_t s = 0; s < a.k; s++) seeds[s].ring.reset(new DeriveSlot[ring]);
+
+  // Give the sampled segments from `turn` on their positions, as far as they
+  // are sampled; the one that holds the n-th accept ends the seed. Any thread
+  // of the group does this for all of them, one at a time.
+  auto place = [&](uint64_t s, DeriveSeed& st, const uint32_t key[8], uint8_t* ks) {
+    if (st.placing.exchange(true, std::memory_order_acquire)) return;
+    while (!st.done.load(std::memory_order_relaxed)) {
+      const uint64_t j = st.turn.load(std::memory_order_relaxed);
+      DeriveSlot& sl = st.ring[j % ring];
+      if (sl.sampled.load(std::memory_order_acquire) != j + 1) break;
+      const uint64_t pos = st.pos.load(std::memory_order_relaxed);
+      sl.pos = pos;
+      if (pos + sl.cnt >= a.n) {
+        sl.take = a.n - pos;
+        const uint64_t b0 = a.offsets[s] + j * seg_bytes;
+        a.ends[s] = b0 + (nth_accept(key, b0, a.bpn, a.order, sl.take, ks) + 1) * a.bpn;
+        st.total = j + 1;
+        sl.placed.store(j + 1, std::memory_order_release);
+        st.done.store(true, std::memory_order_release);
+      } else {
+        sl.take = sl.cnt;
+        st.pos.store(pos + sl.cnt, std::memory_order_relaxed);
+        sl.placed.store(j + 1, std::memory_order_release);
+        st.turn.store(j + 1, std::memory_order_release);
+      }
+    }
+    st.placing.store(false, std::memory_order_release);
+  };
 
   auto worker = [&](uint32_t t) {
     const uint32_t g = t % ng;
     uint8_t* acc = accs[g];
     std::vector<uint8_t> ks(DS_CHUNK_BYTES + 64 + 64 + 16);
-    std::unique_ptr<V[]> vals(new V[a.seg_cand]);  // touched as far as it fills
+    // this thread's tickets, sampled and not added yet, oldest first; only
+    // the newest can be waiting for its slot
+    struct Held {
+      uint64_t j, cnt;
+      uint32_t buf;
+    };
+    Held held[DS_RUN_AHEAD];
+    std::unique_ptr<V[]> bufs[DS_RUN_AHEAD];  // each touched as far as it fills
     for (uint64_t s = g; s < a.k; s += ng) {
       DeriveSeed& st = seeds[s];
       DeriveSeed* prev = s >= ng ? &seeds[s - ng] : nullptr;
       uint32_t key[8];
       std::memcpy(key, a.seeds + 32 * s, 32);
-      while (!st.done.load(std::memory_order_acquire)) {
-        const uint64_t j = st.next.fetch_add(1, std::memory_order_relaxed);
-        const uint64_t b0 = a.offsets[s] + j * seg_bytes;
-        const uint64_t cnt =
-            sample_range(key, b0, a.seg_cand, a.bpn, a.order, ks.data(), vals.get());
-
-        // the last segment's commit sets `done` and leaves `turn` where it is
-        unsigned spins = 0;
-        while (st.turn.load(std::memory_order_acquire) != j &&
-               !st.done.load(std::memory_order_acquire))
+      uint32_t n_held = 0;
+      uint32_t free_bufs = (1u << DS_RUN_AHEAD) - 1;
+      bool unpublished = false;
+      unsigned spins = 0;
+      std::chrono::steady_clock::time_point waiting_since;
+      for (;;) {
+        if (unpublished) {
+          const Held& h = held[n_held - 1];
+          DeriveSlot& sl = st.ring[h.j % ring];
+          if (h.j < ring || sl.taken.load(std::memory_order_acquire) == h.j - ring + 1) {
+            sl.cnt = h.cnt;
+            sl.sampled.store(h.j + 1, std::memory_order_release);
+            unpublished = false;
+            spins = 0;
+            continue;
+          }
+        }
+        if (n_held > (unpublished ? 1u : 0u)) {
+          DeriveSlot& sl = st.ring[held[0].j % ring];
+          if (sl.placed.load(std::memory_order_acquire) == held[0].j + 1) {
+            const Held h = held[0];
+            const uint64_t pos = sl.pos, take = sl.take;
+            sl.taken.store(h.j + 1, std::memory_order_release);
+            for (uint32_t i = 1; i < n_held; i++) held[i - 1] = held[i];
+            n_held--;
+            // this group's previous seed may still be adding into the same slots
+            if (prev != nullptr) {
+              spins = 0;
+              while (prev->added.load(std::memory_order_acquire) != prev->total)
+                spin_wait(spins);
+            }
+            acc_add<V, S>(acc, pos, bufs[h.buf].get(), take, a.eager, a.order);
+            st.added.fetch_add(1, std::memory_order_release);
+            free_bufs |= 1u << h.buf;
+            spins = 0;
+            continue;
+          }
+        }
+        if (st.done.load(std::memory_order_acquire)) {
+          // what is placed is placed by now: the rest are tickets past the end
+          while (n_held && held[n_held - 1].j >= st.total) n_held--;
+          unpublished = false;
+          if (n_held == 0) break;
+          continue;
+        }
+        const uint64_t due = st.turn.load(std::memory_order_acquire);
+        if (st.ring[due % ring].sampled.load(std::memory_order_acquire) == due + 1) {
+          place(s, st, key, ks.data());
+          continue;
+        }
+        bool sample = n_held == 0;
+        if (!sample && !unpublished && n_held < DS_RUN_AHEAD && spins >= 64) {
+          // ahead only of a wait that lasts, and not past the seed's end as
+          // far as this thread's own acceptance rate foretells it
+          const uint64_t in_flight = st.next.load(std::memory_order_relaxed) - due;
+          sample = std::chrono::steady_clock::now() - waiting_since >= DS_AHEAD_AFTER &&
+                   in_flight * held[0].cnt < a.n - st.pos.load(std::memory_order_relaxed);
+        }
+        if (!sample) {
+          if (spins == 0) waiting_since = std::chrono::steady_clock::now();
           spin_wait(spins);
-        if (st.turn.load(std::memory_order_acquire) != j) break;  // a ticket past it
-        const uint64_t pos = st.pos;
-        uint64_t take = cnt;
-        if (pos + cnt >= a.n) {
-          take = a.n - pos;
-          a.ends[s] =
-              b0 + (nth_accept(key, b0, a.bpn, a.order, take, ks.data()) + 1) * a.bpn;
-          st.total = j + 1;
-          st.done.store(true, std::memory_order_release);
-        } else {
-          st.pos = pos + cnt;
-          st.turn.store(j + 1, std::memory_order_release);
+          continue;
         }
-        // this group's previous seed may still be adding into the same slots
-        if (prev != nullptr) {
-          spins = 0;
-          while (prev->added.load(std::memory_order_acquire) != prev->total) spin_wait(spins);
-        }
-        acc_add<V, S>(acc, pos, vals.get(), take, a.eager, a.order);
-        st.added.fetch_add(1, std::memory_order_release);
+        const uint32_t b = (uint32_t)__builtin_ctz(free_bufs);
+        free_bufs &= ~(1u << b);
+        if (!bufs[b]) bufs[b].reset(new V[a.seg_cand]);
+        Held& h = held[n_held++];
+        h.j = st.next.fetch_add(1, std::memory_order_relaxed);
+        h.buf = b;
+        h.cnt = sample_range(key, a.offsets[s] + h.j * seg_bytes, a.seg_cand, a.bpn, a.order,
+                             ks.data(), bufs[b].get());
+        unpublished = true;
+        spins = 0;
       }
     }
   };
@@ -1126,7 +1236,55 @@ XN_EXPORT uint64_t xn_count_ge(const uint32_t* limbs, uint64_t count, uint32_t n
   return bad;
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 12; }
+// The same count over a byte-planar block (ABI 13; a wire v2 vector as it
+// arrives, ops/limbs.py::planes_lt_order): plane b, at planes + b *
+// plane_stride, holds byte b of each of the n elements, and `order_le` is
+// the order in bpn little-endian bytes (callers handle an order of
+// 2^(8*bpn), which admits every element the planes can hold). An element is
+// compared from its top byte down and decided at the first byte that
+// differs, so all but the elements whose top byte ties the order's are
+// decided by a read of the top plane alone. The element axis runs on
+// fold_threads() threads.
+XN_EXPORT uint64_t xn_count_ge_planes(const uint8_t* planes, uint64_t n, uint64_t plane_stride,
+                                      uint32_t bpn, const uint8_t* order_le) {
+  if (n == 0 || bpn == 0) return 0;
+  std::atomic<uint64_t> bad{0};
+  const uint8_t* top = planes + (uint64_t)(bpn - 1) * plane_stride;
+  const uint8_t top_order = order_le[bpn - 1];
+  run_sliced(
+      n, 4096,
+      [&, top, top_order](uint64_t s0, uint64_t s1) {
+        uint64_t mine = 0;
+        for (uint64_t i = s0; i < s1; i++) {
+          if (top[i] < top_order) continue;
+          int ge = 1;  // equal down to the last byte counts as >=
+          for (int b = (int)bpn - 1; b >= 0; b--) {
+            const uint8_t v = planes[(uint64_t)b * plane_stride + i];
+            if (v > order_le[b]) { ge = 1; break; }
+            if (v < order_le[b]) { ge = 0; break; }
+          }
+          mine += (uint64_t)ge;
+        }
+        if (mine) bad.fetch_add(mine, std::memory_order_relaxed);
+      });
+  return bad.load();
+}
+
+// Copy `width` bytes of each of `bpn` planes, src plane b at src + b *
+// src_plane_stride into dst + b * dst_plane_stride (ABI 13): a column range
+// of a wire v2 body into a shard's staging slot, whose planes are as wide as
+// the shard's padded range (ops/limbs.py::copy_planes). The column axis runs
+// on fold_threads() threads, each copying its slice of every plane.
+XN_EXPORT void xn_copy_planes(const uint8_t* src, uint64_t src_plane_stride, uint8_t* dst,
+                              uint64_t dst_plane_stride, uint32_t bpn, uint64_t width) {
+  run_sliced(width, 4096, [=](uint64_t s0, uint64_t s1) {
+    for (uint32_t b = 0; b < bpn; b++)
+      std::memcpy(dst + (uint64_t)b * dst_plane_stride + s0,
+                  src + (uint64_t)b * src_plane_stride + s0, (size_t)(s1 - s0));
+  });
+}
+
+XN_EXPORT uint32_t xn_abi_version(void) { return 13; }
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST

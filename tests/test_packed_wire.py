@@ -173,3 +173,115 @@ def test_round_parameters_negotiate_wire_format():
     legacy = params.to_dict()
     legacy.pop("wire_format")
     assert RoundParameters.from_dict(legacy).wire_format == 1
+
+
+# --- the eager parse of a v2 block (ISSUE 50) --------------------------------
+
+EAGER_MASKS = {
+    "integer-b0m6": CFG,
+    "integer-b6m6": MaskConfig(GroupType.INTEGER, DataType.F32, BoundType.B6, ModelType.M6),
+    "prime-b0m3": MaskConfig(GroupType.PRIME, DataType.F32, BoundType.B0, ModelType.M3),
+}
+
+
+@pytest.mark.parametrize("streamed", [False, True], ids=["buffer", "stream"])
+@pytest.mark.parametrize("mask", list(EAGER_MASKS))
+def test_eager_v2_parse_equals_the_v1_parse_and_the_masked_limbs(mask, streamed, monkeypatch):
+    """v2 eager == v1 eager == the limbs that were masked; the v2 vector is a
+    checked view of the body (no limb row until someone asks for ``.data``,
+    and then through the counted fallback), and re-emits byte for byte."""
+    from xaynet_tpu.core.mask import serialization
+    from xaynet_tpu.core.mask.object import LazyWireMaskVect, wire_route
+    from xaynet_tpu.core.message.encoder import ChunkReader
+    from xaynet_tpu.telemetry.registry import get_registry
+
+    cfg = EAGER_MASKS[mask]
+    w = np.random.default_rng(23).uniform(-1, 1, N).astype(np.float32)
+    _, masked = Masker(cfg.pair()).mask(Scalar(1, 4), w)
+
+    def parse(blob):
+        if streamed:
+            return serialization.parse_mask_vect_stream(ChunkReader([blob[:11], blob[11:]]))
+        return parse_mask_vect(blob)[0]
+
+    v1 = parse(serialize_mask_vect(masked.vect, planar=False))
+    blob2 = serialize_mask_vect(masked.vect, planar=True)
+    calls = []
+    real = serialization.planar_to_interleaved
+    monkeypatch.setattr(serialization, "planar_to_interleaved",
+                        lambda *a: calls.append(a[1]) or real(*a))
+    v2 = parse(blob2)
+    assert type(v1).__name__ == "MaskVect" and wire_route(v1) == ("legacy", "relayout")
+    assert isinstance(v2, LazyWireMaskVect) and v2.planar and v2.checked and not v2.materialized
+    assert wire_route(v2) == ("packed", "copy")
+    assert v2.is_valid() and not calls and not v2.materialized  # the parse's verdict, no scan
+    assert v2.planar_block.shape == (cfg.bytes_per_number, N)
+    assert serialize_mask_vect(v2, planar=True) == blob2 and not v2.materialized
+    generic = lambda: get_registry().sample_value(  # noqa: E731
+        "xaynet_codec_elements_total", {"op": "parse", "route": "generic"}) or 0.0
+    before = generic()
+    assert v2 == v1 == masked.vect  # materialises: the fallback, counted
+    assert calls == [N] and generic() - before >= N
+    assert wire_route(v2) == ("packed", "relayout")
+    assert np.array_equal(v2.data, masked.vect.data) and v2.is_valid()
+
+
+@pytest.mark.parametrize("mask", list(EAGER_MASKS))
+def test_eager_v2_parse_rejects_an_element_out_of_the_group_as_the_v1_parse_does(mask):
+    cfg = EAGER_MASKS[mask]
+    w = np.random.default_rng(29).uniform(-1, 1, N).astype(np.float32)
+    _, masked = Masker(cfg.pair()).mask(Scalar(1, 4), w)
+    from xaynet_tpu.ops import limbs as limb_ops
+
+    masked.vect.data[N // 2] = limb_ops.int_to_limbs(cfg.order, masked.vect.data.shape[1])
+    errors = []
+    for planar in (False, True):
+        with pytest.raises(DecodeError) as err:
+            parse_mask_vect(serialize_mask_vect(masked.vect, planar=planar))
+        errors.append(str(err.value))
+    assert errors[0] == errors[1] == "mask vector element >= group order"
+    # the lazy parse (wire ingest) still defers the verdict to the device
+    vect, _ = parse_mask_vect(serialize_mask_vect(masked.vect, planar=True), lazy=True)
+    assert not vect.checked
+
+
+@pytest.mark.parametrize("library", ["native", "numpy"])
+def test_copy_planes_copies_a_column_range_through_both_strides(library, monkeypatch):
+    from xaynet_tpu.ops import limbs as limb_ops
+    from xaynet_tpu.utils import native
+
+    if library == "numpy":
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", True)
+    rng = np.random.default_rng(31)
+    n = 5_000_011  # more than one thread's slice
+    planes = np.frombuffer(rng.bytes(3 * n), dtype=np.uint8).reshape(3, n)
+    out = np.full((3, n + 64), 7, dtype=np.uint8)
+    limb_ops.copy_planes(planes[:, 5 : n - 9], out[:, : n - 14])
+    assert np.array_equal(out[:, : n - 14], planes[:, 5 : n - 9])
+    assert (out[:, n - 14 :] == 7).all()
+    with pytest.raises(ValueError):
+        limb_ops.copy_planes(planes, out)
+
+
+@pytest.mark.parametrize("mask", list(EAGER_MASKS))
+def test_the_planar_serialiser_writes_the_same_bytes_with_and_without_the_library(
+        mask, monkeypatch):
+    """The sender's side: the planes of a v2 body come from the library's
+    plane pack where it loads, from numpy's strided copy otherwise; plane b
+    holds byte b of every element either way."""
+    from xaynet_tpu.utils import native
+
+    cfg = EAGER_MASKS[mask]
+    n = 600_011  # more than one thread's slice
+    w = np.random.default_rng(37).uniform(-1, 1, n).astype(np.float32)
+    _, masked = Masker(cfg.pair()).mask(Scalar(1, 2), w)
+    assert native.load() is not None
+    fast = serialize_mask_vect(masked.vect, planar=True)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", True)
+    assert serialize_mask_vect(masked.vect, planar=True) == fast
+    bpn = cfg.bytes_per_number
+    planes = np.frombuffer(fast, dtype=np.uint8)[-bpn * n:].reshape(bpn, n)
+    wire = np.frombuffer(serialize_mask_vect(masked.vect, planar=False), dtype=np.uint8)
+    assert np.array_equal(planes.T, wire[-bpn * n:].reshape(n, bpn))

@@ -152,6 +152,29 @@ def test_result_does_not_depend_on_threads_or_groups(bpn, threads, groups):
     assert np.array_equal(got[0], base[0]) and got[1] == base[1]
 
 
+@needs_native
+@pytest.mark.parametrize("threads,groups", [(32, 1), (64, 1), (32, 5), (48, 16)])
+@pytest.mark.parametrize("bpn", [7, 10, 16])
+def test_more_threads_than_cores_sample_ahead_and_change_nothing(bpn, threads, groups):
+    """Many more threads than cores, on hundreds of 16-candidate segments a
+    seed: threads are taken off their cores with a ticket in hand, the
+    others sample ahead of the segment that cannot be placed yet, the ring of
+    slots (four a thread of the group) is gone round many times, and every
+    thread but the first finds tickets past the seed's end. Same arrays, same
+    cursors, and it ends."""
+    order = _config(WIDTHS[bpn]).order
+    seeds = _seeds(7, 0x70 + bpn)
+    offsets = _unit_offsets(seeds, order)
+    # about 400 segments a seed at this width's acceptance rate
+    n = max(64, int(400 * SEGMENT * order / 2 ** (8 * bpn)))
+    base = derive_sum.derive_sum_vect(seeds, offsets, n, order, threads=1, groups=1, segment=1 << 20)
+    for _ in range(3):
+        got = derive_sum.derive_sum_vect(
+            seeds, offsets, n, order, threads=threads, groups=groups, segment=SEGMENT
+        )
+        assert np.array_equal(got[0], base[0]) and got[1] == base[1]
+
+
 @pytest.mark.parametrize("k", [1, 2, 9])
 @pytest.mark.parametrize("bpn", STREAMED + [WIDER])
 def test_entry_equals_aggregation_over_derive_mask(bpn, k):

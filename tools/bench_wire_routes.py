@@ -1,0 +1,118 @@
+"""Host benchmark of an Update vector's road from the opened message to its
+staging slot, by wire: what ``[ingest] wire_format = "packed"`` saves the
+coordinator a message (docs/DESIGN.md §21; PERF.md section 6, PR 50).
+
+One vector of ``elements`` group elements at ``bytes`` wire bytes each
+(25,557,032 at 7 or 10: the benchmark's 179 and 256 MB; 6,603,710 at 6: the
+fan-in cell's), each pass timed alone on an idle host, best of ``--repeat``:
+
+- ``v1``: the interleaved body as every cell but the packed one sends it:
+  ``parse`` (``bytes_le_to_limbs``: wire bytes -> ``uint32[n, L]``), ``scan``
+  (``all_lt_order`` on the limb rows; the served path runs it twice, in the
+  parse and in ``validate_aggregation``), ``slot`` (``pack_wire_slice``: limb
+  rows -> the slot's byte planes);
+- ``v2``: the byte-planar body: ``scan`` (``planes_lt_order``: the planes
+  against the order, top plane down), ``slot`` (``copy_planes``: the planes
+  into the slot);
+- ``fallback``: what a v2 body cost before PR 50 and still costs where limb
+  rows are asked for: ``planar_to_interleaved`` (numpy's transpose), then all
+  of ``v1``.
+
+Each shape runs twice in children of its own: on one thread of the native
+library (``XAYNET_NATIVE_THREADS=1``) and on ``fold_threads()``. GB/s are the
+element block's bytes over the pass's time. No chip, no jax: host numbers,
+and quoted as such.
+
+Run:  python tools/bench_wire_routes.py [--shapes 25557032x7,...] [--repeat 3]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+import time
+
+
+def _best(fn, repeat: int) -> float:
+    best = float("inf")
+    for _ in range(repeat):
+        t0 = time.perf_counter()
+        fn()
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def _case(elements: int, bpn: int, repeat: int) -> dict:
+    import numpy as np
+
+    from xaynet_tpu.core.mask.serialization import planar_to_interleaved
+    from xaynet_tpu.ops import limbs as limb_ops
+
+    rng = np.random.default_rng(50)
+    order = (1 << (8 * bpn - 1)) + 12345  # its top byte is 0x80
+    rows = np.frombuffer(rng.bytes(elements * bpn), dtype=np.uint8).reshape(elements, bpn).copy()
+    rows[:, -1] &= 0x7F  # every element under the order; 1 in 128 ties its top byte
+    v1 = rows.reshape(-1)
+    v2 = np.ascontiguousarray(rows.T)
+    n_limb = limb_ops.n_limbs_for_bytes(bpn)
+    limbs = limb_ops.bytes_le_to_limbs(v1, elements, bpn, op=None)
+    slot = np.zeros((1, bpn, elements + 128), dtype=np.uint8)  # touched: a ring buffer is reused
+    assert limb_ops.all_lt_order(limbs, order) and limb_ops.planes_lt_order(v2, order)
+    passes = {
+        "v1.parse": lambda: limb_ops.bytes_le_to_limbs(v1, elements, bpn, op=None),
+        "v1.scan": lambda: limb_ops.all_lt_order(limbs, order),
+        "v1.slot": lambda: limb_ops.pack_wire_slice(limbs[None], 0, elements, bpn, slot),
+        "v2.scan": lambda: limb_ops.planes_lt_order(v2, order),
+        "v2.slot": lambda: limb_ops.copy_planes(v2, slot[0, :, :elements]),
+        "fallback.transpose": lambda: planar_to_interleaved(v2.reshape(-1), elements, bpn),
+    }
+    ms = {name: 1e3 * _best(fn, repeat) for name, fn in passes.items()}
+    limb_ops.copy_planes(v2, slot[0, :, :elements])
+    assert np.array_equal(slot[0, :, :elements], v2)
+    block = elements * bpn
+    return {
+        "elements": elements, "bytes": bpn, "limbs": n_limb, "block_mb": block / 1e6,
+        "threads": os.environ.get("XAYNET_NATIVE_THREADS", "fold_threads()"),
+        "ms": {k: round(v, 2) for k, v in ms.items()},
+        "gb_per_s": {k: round(block / 1e6 / v, 2) for k, v in ms.items()},
+        "a_message_ms": {
+            "v1": round(ms["v1.parse"] + 2 * ms["v1.scan"] + ms["v1.slot"], 1),
+            "v2": round(ms["v2.scan"] + ms["v2.slot"], 1),
+            "fallback": round(ms["fallback.transpose"] + ms["v1.parse"] + 2 * ms["v1.scan"]
+                              + ms["v1.slot"], 1),
+        },
+    }
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="25557032x7,25557032x10,6603710x6",
+                    help="elements x wire bytes, comma-separated")
+    ap.add_argument("--repeat", type=int, default=3)
+    ap.add_argument("--case", default=None, help=argparse.SUPPRESS)  # a child's one shape
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    if args.case:
+        elements, bpn = (int(x) for x in args.case.split("x"))
+        print(json.dumps(_case(elements, bpn, args.repeat)))
+        return
+    for shape in args.shapes.split(","):
+        for threads in ("1", None):
+            env = {k: v for k, v in os.environ.items() if k != "XAYNET_NATIVE_THREADS"}
+            env["JAX_PLATFORMS"] = "cpu"
+            if threads:
+                env["XAYNET_NATIVE_THREADS"] = threads
+            done = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--case", shape,
+                 "--repeat", str(args.repeat)], env=env, capture_output=True, text=True)
+            if done.returncode != 0:
+                print(json.dumps({"shape": shape, "error": done.stderr[-400:]}))
+                continue
+            print(done.stdout.strip().splitlines()[-1], flush=True)
+
+
+if __name__ == "__main__":
+    main()

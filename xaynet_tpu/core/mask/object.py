@@ -74,14 +74,25 @@ class LazyWireMaskVect(MaskVect):
     """A ``MaskVect`` parsed from wire with limb materialization DEFERRED.
 
     Carries the raw fixed-width element block (``wire_block``, a zero-copy
-    uint8 view) so a device-ingest coordinator can unpack + validity-check
-    + fold on the accelerator without ever running the host element parse
-    (the second hot loop after the fold). Any host access to ``data``
-    materializes the limbs exactly like the eager parse would have;
-    ``is_valid()`` then applies the same element rule. The eager parse
-    rejects invalid elements with ``DecodeError`` at parse time; the lazy
-    path defers that rejection to ``validate_aggregation`` (device) or the
-    first host materialization — same update rejected, one stage later.
+    uint8 view) and takes one of two roads:
+
+    - **wire ingest** (``parse_mask_vect(lazy=True)``, v1 or v2): a
+      device-ingest coordinator unpacks + validity-checks + folds on the
+      accelerator without ever running the host element parse. The eager
+      parse rejects invalid elements with ``DecodeError`` at parse time;
+      this road defers that rejection to ``validate_aggregation`` (device)
+      or the first host materialization — same update rejected, one stage
+      later.
+    - **the packed wire, eager** (a v2 block under ``lazy=False``): the
+      parse scans the planes against the order (:meth:`check_planes`) and
+      rejects with ``DecodeError`` as for a v1 body; the verdict rides on
+      the object (``checked``), so ``is_valid()`` scans nothing again, and
+      a packed-staging coordinator copies ``planar_block`` into the staging
+      slot: no interleaved block and no limb row is ever made.
+
+    Any host access to ``data`` materializes the limbs exactly like the v1
+    eager parse would have (a v2 block through the counted transposing
+    fallback ``planar_to_interleaved``).
     """
 
     def __init__(
@@ -100,10 +111,30 @@ class LazyWireMaskVect(MaskVect):
         # on it without another device round-trip)
         self._staged_planar = None
         self._wire_invalid = False
+        # the host's verdict on the planes (check_planes), None = not scanned
+        self._planes_valid: bool | None = None
 
     @property
     def materialized(self) -> bool:
         return self._data is not None
+
+    @property
+    def checked(self) -> bool:
+        """Whether the host has scanned this block's planes against the
+        order (the eager v2 parse): ``is_valid()`` then repeats the verdict."""
+        return self._planes_valid is not None
+
+    def check_planes(self) -> bool:
+        """Element validity of a v2 block on its byte planes (no limb row
+        made), once: the verdict is kept for ``is_valid()``."""
+        if self._planes_valid is None:
+            self._planes_valid = limb_ops.planes_lt_order(self.planar_block, self.config.order)
+        return self._planes_valid
+
+    def is_valid(self) -> bool:
+        if self._planes_valid is not None:
+            return self._planes_valid
+        return super().is_valid()
 
     @property
     def planar_block(self) -> np.ndarray:
@@ -134,9 +165,25 @@ class LazyWireMaskVect(MaskVect):
     @data.setter
     def data(self, value) -> None:  # dataclass-compat (never used in practice)
         self._data = value
+        self._planes_valid = None  # a verdict on other bytes
 
     def __len__(self) -> int:
         return self._count
+
+
+def wire_route(vect: MaskVect) -> tuple[str, str]:
+    """``(wire, route)`` of a parsed Update vector, the labels of
+    ``xaynet_update_wire_bytes_total`` and of the ``parse`` / ``validate`` /
+    ``to_planar`` spans. ``wire``: ``packed`` = the message carried the v2
+    flag, ``legacy`` = v1. ``route``: ``copy`` = a plane view the host has
+    checked (nothing relaid; a packed-staging slot takes it by copy),
+    ``device`` = an unchecked wire block for the device's unpack (wire
+    ingest), ``relayout`` = limb rows (the v1 parse, or a view since
+    materialized)."""
+    wire = "packed" if getattr(vect, "planar", False) else "legacy"
+    if isinstance(vect, LazyWireMaskVect) and not vect.materialized:
+        return wire, "copy" if vect.checked else "device"
+    return wire, "relayout"
 
 
 @dataclass

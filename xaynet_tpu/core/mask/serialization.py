@@ -16,8 +16,10 @@ count word (``WIRE_PLANAR_FLAG``) marks the element block as BYTE-PLANAR —
 ``bytes_per_number`` contiguous planes of ``count`` bytes each, plane ``b``
 holding byte ``b`` of every element — instead of the v1 interleaved
 per-element layout. Same byte budget, but the planar block is already the
-PR-13 packed staging layout, so a device-ingest coordinator uploads it
-without the byte-gather relayout and never materializes uint32 limbs.
+packed staging layout: the eager parse keeps it as a view, checks every
+element against the order on the planes, and a packed-staging coordinator
+copies it into the staging slot; a device-ingest coordinator uploads it
+without the byte-gather relayout. Neither materializes uint32 limbs.
 Element counts are bounded far below 2^31 (``MAX_BODY`` caps the message),
 so the flag bit can never collide with a real count.
 """
@@ -29,6 +31,7 @@ import struct
 import numpy as np
 
 from ...ops import limbs as limb_ops
+from ...telemetry import codec
 from ...utils import native
 from .config import MASK_CONFIG_LENGTH, MaskConfig
 from .object import MaskObject, MaskUnit, MaskVect
@@ -52,8 +55,12 @@ def _split_count_word(word: int) -> tuple[int, bool]:
 
 def planar_to_interleaved(block: np.ndarray, count: int, bpn: int) -> np.ndarray:
     """Byte-planar element block ``uint8[bpn * count]`` -> the v1 interleaved
-    layout (one materializing transpose — the lazy path's host FALLBACK; the
-    device path consumes the planar block directly)."""
+    layout: one materializing numpy transpose, counted ``op="parse",
+    route="generic"``. The FALLBACK of a v2 vector whose limb rows someone
+    asks for (``LazyWireMaskVect.data``: the host aggregator, unpacked
+    staging, the exact path, tests); no parse calls it, and a packed-staging
+    device coordinator never does: it copies the planes (docs/DESIGN.md §21)."""
+    codec.count("parse", False, count)
     return np.ascontiguousarray(
         np.asarray(block).reshape(bpn, count).T
     ).reshape(-1)
@@ -140,6 +147,12 @@ def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[M
     validity then happens on device in ``validate_aggregation`` (or on
     first host materialization), one stage later than the eager parse's
     ``DecodeError``.
+
+    A v2 (byte-planar) block under ``lazy=False`` is also returned as a
+    ``LazyWireMaskVect`` over the body's bytes, but a CHECKED one: its
+    planes are scanned against the order here, an element out of the group
+    raises the same ``DecodeError`` a v1 body's would, and no interleaved
+    block or limb row is made unless a caller asks for ``.data``.
     """
     if len(data) - offset < MASK_CONFIG_LENGTH + 4:
         raise DecodeError("mask vector buffer too short")
@@ -155,17 +168,24 @@ def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[M
     if len(data) < end:
         raise DecodeError("mask vector data truncated")
     raw = np.frombuffer(data, dtype=np.uint8, count=count * bpn, offset=start)
-    if lazy:
-        from .object import LazyWireMaskVect
-
-        return LazyWireMaskVect(config, raw, count, planar=planar), end - offset
-    if planar:
-        raw = planar_to_interleaved(raw, count, bpn)
+    if lazy or planar:
+        return _wire_vect(config, raw, count, planar, checked=not lazy), end - offset
     limbs = limb_ops.bytes_le_to_limbs(raw, count, bpn)
     vect = MaskVect(config, limbs)
     if not vect.is_valid():
         raise DecodeError("mask vector element >= group order")
     return vect, end - offset
+
+
+def _wire_vect(config: MaskConfig, raw: np.ndarray, count: int, planar: bool, checked: bool):
+    """The vector as a view of its wire block. ``checked`` (the eager parse
+    of a v2 block): the planes are scanned against the order first."""
+    from .object import LazyWireMaskVect
+
+    vect = LazyWireMaskVect(config, raw, count, planar=planar)
+    if checked and not vect.check_planes():
+        raise DecodeError("mask vector element >= group order")
+    return vect
 
 
 def write_mask_unit(unit: MaskUnit, buf, offset: int) -> int:
@@ -236,17 +256,7 @@ def parse_mask_vect_stream(reader, lazy: bool = False) -> MaskVect:
         # plane-major block cannot feed without a full-block staging anyway
         raw = np.empty(nbytes, dtype=np.uint8)
         reader.read_into(raw)
-        if lazy:
-            from .object import LazyWireMaskVect
-
-            return LazyWireMaskVect(config, raw, count, planar=planar)
-        limbs = limb_ops.bytes_le_to_limbs(
-            planar_to_interleaved(raw, count, bpn), count, bpn
-        )
-        vect = MaskVect(config, limbs)
-        if not vect.is_valid():
-            raise DecodeError("mask vector element >= group order")
-        return vect
+        return _wire_vect(config, raw, count, planar, checked=not lazy)
     # segmented convert: fixed-size wire segments go straight into the limb
     # tensor, so the transient staging is bounded (never O(payload))
     n_limb = limb_ops.n_limbs_for_bytes(bpn)

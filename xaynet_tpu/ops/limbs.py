@@ -95,6 +95,61 @@ def all_lt_order(data: np.ndarray, order: int) -> bool:
     return bool(np.all(lt_const(flat, int_to_limbs(order, n_limb))))
 
 
+def planes_lt_order(planes: np.ndarray, order: int) -> bool:
+    """:func:`all_lt_order` of a byte-planar block ``uint8[bpn, n]`` (a wire
+    v2 vector as it arrives: plane ``b`` holds byte ``b`` of every element)
+    with no limb row made: elements are compared from the top plane down
+    against the order's bytes, so all but the few whose top byte ties the
+    order's are decided by the top plane alone. Native kernel on the
+    library's threads; numpy plane compares otherwise (``generic``)."""
+    bpn, n = planes.shape
+    if order >> (8 * bpn):
+        return True  # every value the planes can hold is a group element
+    if planes.dtype != np.uint8 or planes.strides[1] != 1:
+        planes = np.ascontiguousarray(planes, dtype=np.uint8)
+    order_le = np.frombuffer(order.to_bytes(bpn, "little"), dtype=np.uint8)
+    from ..utils import native
+
+    lib = native.load()
+    codec.count("validate", lib is not None, n)
+    if lib is not None:
+        return 0 == lib.xn_count_ge_planes(
+            native.np_u8p(planes), n, planes.strides[0], bpn, native.np_u8p(order_le)
+        )
+    # tied[i]: element i equals the order in every plane above the current one
+    tied = np.ones(n, dtype=bool)
+    for b in range(bpn - 1, -1, -1):
+        if np.any(tied & (planes[b] > order_le[b])):
+            return False
+        tied &= planes[b] == order_le[b]
+        if not tied.any():
+            return True
+    return False  # an element equal to the order
+
+
+def copy_planes(planes: np.ndarray, out: np.ndarray) -> None:
+    """Copy byte planes ``uint8[bpn, w]`` into ``out[bpn, w]`` through both
+    arrays' plane strides (a column range of a wire v2 body into a staging
+    slot, whose planes are wider): the slot write of the packed wire, a copy
+    and no relayout. The library's threads share the column axis (a fresh
+    slot's pages are first touched there); numpy's copy otherwise."""
+    bpn, width = planes.shape
+    if out.shape != planes.shape or out.dtype != np.uint8 or planes.dtype != np.uint8:
+        raise ValueError("expected uint8[bpn, width] planes and a destination of their shape")
+    from ..utils import native
+
+    lib = native.load()
+    fast = lib is not None and width > 0 and planes.strides[1] == 1 and out.strides[1] == 1
+    codec.count("stage", fast, width)
+    if fast:
+        lib.xn_copy_planes(
+            native.np_u8p(planes), planes.strides[0], native.np_u8p(out), out.strides[0],
+            bpn, width,
+        )
+    else:
+        out[...] = planes
+
+
 def elements_lt_order(data: np.ndarray, order: int) -> np.ndarray:
     """Per-row validity ``element < order`` handling the 2^(32L) boundary."""
     n_limb = n_limbs_for_order(order)
@@ -215,22 +270,33 @@ def limbs_into_wire(
         out.flags.c_contiguous and out.flags.writeable
     ):
         raise ValueError("destination is not a writable contiguous uint8[n * bytes_per_number]")
+    from ..utils import native
+
+    lib = native.load()
+    # native codecs assume the wire width and limb count agree (L == ceil(bpn/4))
+    fast = lib is not None and n > 0 and arr.shape[1] == n_limbs_for_bytes(bytes_per_number)
+    if fast and planar:
+        # the staging ring's plane pack, with the message as its destination:
+        # plane-major unit-stride writes. On one thread, as the v1 kernel
+        # below: a forge seals a message a process on every core at once, and
+        # sixteen threads each (the first try) made its seal of 24 messages
+        # 2.5 s longer than numpy's strided copy had (PERF.md section 6, PR 50)
+        lib.xn_pack_wire_planes(
+            native.np_u32p(arr), n, arr.shape[1], bytes_per_number, native.np_u8p(out), n, 1
+        )
+        return
+    if fast:
+        lib.xn_limbs_to_wire(
+            native.np_u32p(arr), n, bytes_per_number, arr.shape[1], native.np_u8p(out)
+        )
+        return
     rows = arr.astype("<u4", copy=False).view(np.uint8).reshape(n, 4 * arr.shape[1])
     rows = rows[:, :bytes_per_number]
     if planar:
         # one strided pass from the limbs' own bytes: no interleaved block
         out.reshape(bytes_per_number, n)[...] = rows.T
-        return
-    from ..utils import native
-
-    lib = native.load()
-    # native codec assumes the wire width and limb count agree (L == ceil(bpn/4))
-    if lib is not None and n > 0 and arr.shape[1] == n_limbs_for_bytes(bytes_per_number):
-        lib.xn_limbs_to_wire(
-            native.np_u32p(arr), n, bytes_per_number, arr.shape[1], native.np_u8p(out)
-        )
-        return
-    out.reshape(n, bytes_per_number)[...] = rows
+    else:
+        out.reshape(n, bytes_per_number)[...] = rows
 
 
 def limbs_to_bytes_le(arr: np.ndarray, bytes_per_number: int) -> bytes:

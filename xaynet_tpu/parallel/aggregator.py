@@ -31,6 +31,7 @@ from ..ops.fold_jax import (
 )
 from ..telemetry import profiling
 from ..telemetry import unmask as unmask_stages
+from ..telemetry import wire as wire_stats
 from ..telemetry.registry import get_registry
 from ..utils.kernels import FOLD_KERNELS
 from .mesh import MODEL_AXIS, make_mesh, pad_to_multiple
@@ -111,10 +112,13 @@ def fold_kernel_report() -> dict:
     ``failed: <ExceptionType>``, first-call and steady ``seconds``) plus
     ``results_equal``; and ``h2d_route``, the route the last folded host
     batch's bytes took to the device (``row`` | ``batch``, None before the
-    streaming pipeline has folded one). Empty before the first fold."""
+    streaming pipeline has folded one); and ``wire``, how many update vectors
+    of the fold batch closed last came ``packed`` (wire v2) and ``legacy``
+    (v1), and how many were ``copied`` into their slots as planes
+    (``telemetry/wire.py``). Empty before the first fold."""
     if not _LAST_RESOLUTION:
         return {}
-    return {**_LAST_RESOLUTION, "h2d_route": _LAST_H2D_ROUTE}
+    return {**_LAST_RESOLUTION, "h2d_route": _LAST_H2D_ROUTE, "wire": wire_stats.last_batch()}
 
 
 def note_h2d_route(route: str) -> None:

@@ -992,6 +992,37 @@ class StreamingAggregator:
             raise ValueError("expected uint32[model_len, L]")
         for buf, (lo, hi) in zip(bufs, self._slices):
             self._relay_wire_rows(buf[i : i + 1], wire[None], lo, hi)
+        self._row_written(bufs, i)
+
+    @property
+    def takes_planes(self) -> bool:
+        """Whether a slot of this pipeline's rings is byte planes, so that a
+        wire v2 body's planes go in by copy (:meth:`stage_planes`)."""
+        return self._packed
+
+    def stage_planes(self, bufs: list[np.ndarray], i: int, planes: np.ndarray) -> None:
+        """:meth:`stage_row` for an update that arrived as byte planes
+        ``uint8[bpn, model_len]`` (wire v2, every element checked against the
+        order by its parse): each shard's column range of every plane is
+        copied into slot ``i`` of that shard's buffer and the pad columns
+        zeroed, as the plane pack leaves them. No relayout: the body's
+        layout is the slot's."""
+        if not self._packed or planes.dtype != np.uint8 or planes.shape != (
+            self.agg.packed_width, self.agg.model_length
+        ):
+            raise ValueError("expected uint8[bpn, model_len] planes and packed staging")
+        from ..ops import limbs as host_limbs
+
+        for buf, (lo, hi) in zip(bufs, self._slices):
+            real_hi = min(hi, self.agg.model_length)
+            if lo < real_hi:
+                host_limbs.copy_planes(planes[:, lo:real_hi], buf[i, :, : real_hi - lo])
+            if real_hi < hi:
+                buf[i, :, max(0, real_hi - lo):] = 0
+        self._row_written(bufs, i)
+
+    def _row_written(self, bufs: list[np.ndarray], i: int) -> None:
+        """Slot ``i`` of an open batch holds its update, by either road."""
         ROWS_STAGED.labels(route="arrival").inc()
         # the slot is written: its copy to the device starts now, behind
         # the copies queued before it, and not on this thread. A degraded

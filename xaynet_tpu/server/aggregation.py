@@ -28,13 +28,14 @@ import numpy as np
 
 from ..core.mask.config import MaskConfigPair
 from ..core.mask.masking import Aggregation, AggregationError, UnmaskingError
-from ..core.mask.object import LazyWireMaskVect, MaskObject, MaskUnit, MaskVect
+from ..core.mask.object import LazyWireMaskVect, MaskObject, MaskUnit, MaskVect, wire_route
 from ..ops import limbs as limb_ops
 from ..ops.limbs import PlanarLimbs
 from ..resilience.checkpoint import AggSnapshot
 from ..telemetry import journal, profiling
 from ..telemetry import tracing as trace
 from ..telemetry import unmask as unmask_stages
+from ..telemetry import wire as wire_stats
 from ..utils.tracing import current_request_id
 from . import stages
 
@@ -312,6 +313,7 @@ class StagedAggregator:
             self._device is not None
             and isinstance(vect, LazyWireMaskVect)
             and not vect.materialized
+            and not vect.checked
         ):
             # device wire ingest: unpack + element validity run on the
             # accelerator, and the resulting planar is cached on the object
@@ -332,6 +334,8 @@ class StagedAggregator:
                 raise AggregationError("InvalidObject")
             vect._staged_planar = planar
         elif not obj.is_valid():
+            # a wire v2 vector the parse has checked on its planes repeats
+            # that verdict here and scans nothing (LazyWireMaskVect.is_valid)
             raise AggregationError("InvalidObject")
 
     def prevalidate_wire_batch(self, objs) -> None:
@@ -357,6 +361,7 @@ class StagedAggregator:
             for obj in objs
             if isinstance(obj.vect, LazyWireMaskVect)
             and not obj.vect.materialized
+            and not obj.vect.checked
             and obj.vect._staged_planar is None
             and not obj.vect._wire_invalid
             and obj.vect.config == self.config.vect
@@ -440,16 +445,31 @@ class StagedAggregator:
         """Updates staged but not yet folded."""
         return self._count
 
+    def wire_route(self, vect: MaskVect) -> tuple[str, str]:
+        """``(wire, route)`` of a validated Update vector on this aggregator
+        (``telemetry/wire.py``): what ``core.mask.object.wire_route`` says of
+        the object, but that a checked plane view goes by ``copy`` only into
+        byte-planar slots (the device path under packed staging) and by
+        ``relayout`` everywhere else, and that a vector the device has
+        unpacked is ``device`` whatever it was."""
+        wire, route = wire_route(vect)
+        if isinstance(vect, LazyWireMaskVect) and vect._staged_planar is not None:
+            return wire, "device"
+        if route == "copy" and self._stream is not None and self._stream.takes_planes:
+            return wire, "copy"
+        return wire, "relayout"
+
     def stage(self, obj: MaskObject) -> None:
         """Stage an update without folding (caller controls flush timing).
 
         Never waits: on the device path the relayout (and, for the first
         row of a batch, the wait for a free ring buffer) runs on the
         ``xn-ingest`` pool."""
+        vect = obj.vect
+        wire, route = self.wire_route(vect)
+        wire_stats.staged(wire, route, len(vect) * vect.config.bytes_per_number)
         if self._ingest_pool is not None:
-            planar_dev = (
-                obj.vect._staged_planar if isinstance(obj.vect, LazyWireMaskVect) else None
-            )
+            planar_dev = vect._staged_planar if isinstance(vect, LazyWireMaskVect) else None
             # the relayout outlives this call (and may outlive the request
             # that staged it), so its span LINKS the caller's span instead
             # of parenting to it
@@ -469,16 +489,23 @@ class StagedAggregator:
                     self._open.append(batch)
                 stream, slot = self._stream, len(batch.writes)
 
-                def write_slot(data=obj.vect.data):
+                if route == "copy":
+                    # wire v2: the body's planes are the slot's layout
+                    data, write = vect.planar_block, stream.stage_planes
+                else:
+                    data, write = vect.data, stream.stage_row
+
+                def write_slot():
                     bufs = batch.buffers(stream)
                     with stages.stage(
-                        "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes
+                        "to_planar", link=caller, rid=rid, phase=arrived, bytes=data.nbytes,
+                        wire=wire, route=route,
                     ):
-                        stream.stage_row(bufs, slot, data)
+                        write(bufs, slot, data)
 
                 batch.writes.append(self._ingest_pool.submit(write_slot))
         else:
-            self._staged_vect.append(obj.vect.data)
+            self._staged_vect.append(vect.data)
         self._staged_unit.append(obj.unit.data)
         self._count += 1
 
@@ -497,6 +524,7 @@ class StagedAggregator:
         """
         if self._count == 0:
             return
+        wire_stats.batch_closed()
         stack = None if self._ingest_pool is not None else np.stack(self._staged_vect)
         units = np.stack(self._staged_unit)
         if self._device is not None:

@@ -28,6 +28,7 @@ import asyncio
 import logging
 
 from ...core.mask.masking import AggregationError
+from ...core.mask.object import wire_route
 from ...resilience.chaos import maybe_kill
 from ...resilience.checkpoint import CheckpointManager, RoundCheckpoint, entry, write_entry
 from ...telemetry import journal
@@ -154,7 +155,8 @@ class UpdatePhase(PhaseState):
             # kernel + sync — neither may stall the loop serving the API
             # (ordering is preserved: the await completes before the
             # seed-dict insert below)
-            with stages.stage("validate"):
+            wire, route = wire_route(req.masked_model.vect)
+            with stages.stage("validate", wire=wire, route=route):
                 await asyncio.get_running_loop().run_in_executor(
                     None, self.aggregator.validate_aggregation, req.masked_model
                 )

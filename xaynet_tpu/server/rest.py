@@ -43,6 +43,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from ..telemetry import tracing as trace
+from ..telemetry import wire as wire_stats
 from ..telemetry.intake import BodyIntake
 from ..telemetry.registry import MetricsRegistry, get_registry
 from ..telemetry.startup import get_timeline
@@ -742,6 +743,13 @@ class RestServer:
                     "bodies held sealed at once",
                     direct, overflow, sum(turned.values()),
                     ", ".join(f"{reason} {n}" for reason, n in turned.items()) or "none", high,
+                )
+            staged = wire_stats.since_last()
+            if staged["packed"] or staged["legacy"]:
+                logger.info(
+                    "update vectors staged since the last Sum2: %d on the packed wire (v2), %d "
+                    "of them copied into their slots as planes; %d on the legacy wire (v1)",
+                    staged["packed"], staged["copied"], staged["legacy"],
                 )
 
     async def _dispatch(self, method: str, path: str, query: str, body: bytes,

@@ -30,6 +30,7 @@ from ..core.common import RoundParameters
 from ..core.crypto import unlocked
 from ..core.crypto.encrypt import DecryptError, EncryptKeyPair
 from ..core.crypto.sign import is_eligible, verify_detached
+from ..core.mask.object import wire_route
 from ..core.mask.serialization import DecodeError
 from ..core.message import Chunk, Message, Sum, Sum2, Tag, Update, peek_header
 from ..core.message.encoder import MessageBuilder
@@ -251,10 +252,16 @@ class PetMessageHandler:
         put, and a thread's start at its first use) and awaited after."""
         verdict = None
         try:
-            with stages.stage("parse", **at):
+            with stages.stage("parse", **at) as span:
                 if beside:
                     verdict = self.workers.verdicts.submit(self._verify_beside, raw, at)
-                return Message.from_bytes(raw, verify=False, lazy_update_vect=self.wire_ingest)
+                message = Message.from_bytes(raw, verify=False, lazy_update_vect=self.wire_ingest)
+                if isinstance(message.payload, Update):
+                    # which wire the vector came on and what the parse made
+                    # of it: limb rows, or a view of the body's bytes
+                    wire, route = wire_route(message.payload.masked_model.vect)
+                    span.set(wire=wire, route=route)
+                return message
         finally:
             # nothing of the parse, an error included, leaves before the
             # verdict is in: a bad signature raises here, over whatever the

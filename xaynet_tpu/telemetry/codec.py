@@ -10,10 +10,14 @@ loops cost 3-23% of a kernel and nothing end to end (PERF.md section 6, PR 26),
 where numpy costs a multiple. One counter says, in elements, which ran,
 counted where the choice is made:
 
-- ``parse``: ``ops/limbs.py::bytes_le_to_limbs`` (wire bytes -> limbs);
-- ``validate``: ``ops/limbs.py::all_lt_order`` (element < order);
+- ``parse``: ``ops/limbs.py::bytes_le_to_limbs`` (wire bytes -> limbs), and
+  ``core/mask/serialization.py::planar_to_interleaved``, always ``generic``:
+  numpy's transpose of a wire v2 vector whose limb rows someone asks for;
+- ``validate``: ``ops/limbs.py::all_lt_order`` (element < order, on limb
+  rows) and ``planes_lt_order`` (the same on a wire v2 vector's byte planes);
 - ``stage``: ``ops/limbs.py::pack_wire_slice``, ``pack_wire``, ``pack_planar``
-  (limbs -> byte planes);
+  (limbs -> byte planes) and ``copy_planes`` (a v2 vector's planes copied
+  into the slot);
 - ``derive``: ``core/crypto/prng.py::StreamSampler.draw_limbs`` (seed -> mask
   elements), in the process that derives: the sum participant's, not the
   coordinator's. A third route, ``fused``, counts the elements that

@@ -18,7 +18,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 12
+_ABI_VERSION = 13
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -207,6 +207,25 @@ def load() -> Optional[ctypes.CDLL]:
         lib.xn_limbs_to_wire.restype = None
         lib.xn_count_ge.argtypes = [u32p, ctypes.c_uint64, ctypes.c_uint32, u32p]
         lib.xn_count_ge.restype = ctypes.c_uint64
+        # ABI 13: a wire v2 (byte-planar) vector scanned against the order
+        # and copied into its staging slot as planes
+        lib.xn_count_ge_planes.argtypes = [
+            u8p,
+            ctypes.c_uint64,  # n elements
+            ctypes.c_uint64,  # plane stride (bytes)
+            ctypes.c_uint32,  # bpn
+            u8p,  # the order, bpn little-endian bytes
+        ]
+        lib.xn_count_ge_planes.restype = ctypes.c_uint64
+        lib.xn_copy_planes.argtypes = [
+            u8p,
+            ctypes.c_uint64,  # source plane stride (bytes)
+            u8p,
+            ctypes.c_uint64,  # destination plane stride (bytes)
+            ctypes.c_uint32,  # bpn
+            ctypes.c_uint64,  # bytes of each plane to copy
+        ]
+        lib.xn_copy_planes.restype = None
         lib.xn_fold_wire_nlimb.argtypes = list(lib.xn_fold_wire_u64.argtypes)
         lib.xn_fold_wire_nlimb.restype = ctypes.c_int
         # the REST server's direct body read (ABI 9): poll + recv in C, one
