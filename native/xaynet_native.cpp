@@ -1284,7 +1284,158 @@ XN_EXPORT void xn_copy_planes(const uint8_t* src, uint64_t src_plane_stride, uin
   });
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 13; }
+namespace {
+
+// 1 if the bpn-byte little-endian element at p is >= the order, compared
+// from the top byte down (equal down to the last byte counts as >=).
+inline uint64_t element_ge(const uint8_t* p, uint32_t bpn, const uint8_t* order_le) {
+  for (int b = (int)bpn - 1; b >= 0; b--) {
+    if (p[b] > order_le[b]) return 1;
+    if (p[b] < order_le[b]) return 0;
+  }
+  return 1;
+}
+
+// Elements [s, e) of the interleaved block into their plane columns and
+// their count >= order, a byte at a time: the tail of a slice, and every
+// element where the library was built without AVX2.
+inline uint64_t wire_to_planes_bytewise(const uint8_t* wire, uint32_t bpn, uint8_t* planes,
+                                        uint64_t plane_stride, const uint8_t* order_le,
+                                        uint64_t s, uint64_t e) {
+  constexpr uint64_t BLOCK = 4096;  // as the plane packs: a block stays in L1 for its bpn passes
+  uint64_t bad = 0;
+  for (; s < e; s += BLOCK) {
+    const uint64_t bn = (e - s) < BLOCK ? (e - s) : BLOCK;
+    const uint8_t* src = wire + s * bpn;
+    for (uint32_t b = 0; b < bpn; b++) {
+      uint8_t* dst = planes + (uint64_t)b * plane_stride + s;
+      for (uint64_t i = 0; i < bn; i++) dst[i] = src[i * bpn + b];
+    }
+    if (order_le) {
+      const uint8_t* top = planes + (uint64_t)(bpn - 1) * plane_stride + s;
+      for (uint64_t i = 0; i < bn; i++)
+        if (top[i] >= order_le[bpn - 1]) bad += element_ge(src + i * bpn, bpn, order_le);
+    }
+  }
+  return bad;
+}
+
+#ifdef __AVX2__
+// Eight registers of byte pairs (16-bit lane k of a[i], in each 128-bit
+// half: byte k of elements 2i and 2i+1 of that half's sixteen) -> eight
+// registers of whole plane runs: p[k] holds byte k of the sixteen elements
+// of each half, in element order.
+inline void byte_pairs_to_planes(const __m256i a[8], __m256i p[8]) {
+  __m256i q[8];
+  for (int j = 0; j < 4; j++) {
+    q[j] = _mm256_unpacklo_epi16(a[2 * j], a[2 * j + 1]);      // bytes 0..3 of four elements
+    q[4 + j] = _mm256_unpackhi_epi16(a[2 * j], a[2 * j + 1]);  // bytes 4..7
+  }
+  for (int h = 0; h < 2; h++) {
+    const __m256i* b = q + 4 * h;
+    const __m256i c0 = _mm256_unpacklo_epi32(b[0], b[1]), c1 = _mm256_unpacklo_epi32(b[2], b[3]);
+    const __m256i c2 = _mm256_unpackhi_epi32(b[0], b[1]), c3 = _mm256_unpackhi_epi32(b[2], b[3]);
+    p[4 * h + 0] = _mm256_unpacklo_epi64(c0, c1);
+    p[4 * h + 1] = _mm256_unpackhi_epi64(c0, c1);
+    p[4 * h + 2] = _mm256_unpacklo_epi64(c2, c3);
+    p[4 * h + 3] = _mm256_unpackhi_epi64(c2, c3);
+  }
+}
+
+// One slice of xn_wire_to_planes at a width the compiler knows: 32 elements
+// a turn, each loaded whole (8 or 16 bytes from its first, the excess
+// belonging to its successors), transposed in registers by the unpack
+// network and stored as a 32-byte run of each of the BPN planes; the run of
+// the top plane is compared with the order's top byte before it leaves its
+// register. The planes beyond BPN are never computed: BPN is a constant.
+template <int BPN>
+uint64_t wire_to_planes_slice(const uint8_t* wire, uint64_t count, uint8_t* planes,
+                              uint64_t plane_stride, const uint8_t* order_le, uint64_t s0,
+                              uint64_t s1) {
+  constexpr int LOAD = BPN <= 8 ? 8 : 16;
+  // the last load of a turn, at element s + 31, ends inside the block
+  const uint64_t total = count * BPN;
+  const __m256i top_order = _mm256_set1_epi8(order_le ? (char)order_le[BPN - 1] : 0);
+  uint64_t bad = 0, s = s0;
+  for (; s + 32 <= s1 && (s + 31) * BPN + LOAD <= total; s += 32) {
+    const uint8_t* src = wire + s * BPN;
+    __m256i r[16], a[8], p[16];
+    for (int i = 0; i < 16; i++) {
+      const uint8_t *lo = src + i * BPN, *hi = src + (16 + i) * BPN;
+      if (LOAD == 8)
+        r[i] = _mm256_set_m128i(_mm_loadl_epi64((const __m128i*)hi),
+                                _mm_loadl_epi64((const __m128i*)lo));
+      else
+        r[i] = _mm256_loadu2_m128i((const __m128i*)hi, (const __m128i*)lo);
+    }
+    for (int i = 0; i < 8; i++) a[i] = _mm256_unpacklo_epi8(r[2 * i], r[2 * i + 1]);
+    byte_pairs_to_planes(a, p);
+    if (BPN > 8) {
+      for (int i = 0; i < 8; i++) a[i] = _mm256_unpackhi_epi8(r[2 * i], r[2 * i + 1]);
+      byte_pairs_to_planes(a, p + 8);
+    }
+    for (int b = 0; b < BPN; b++)
+      _mm256_storeu_si256((__m256i*)(planes + (uint64_t)b * plane_stride + s), p[b]);
+    if (order_le) {
+      const __m256i top = p[BPN - 1];
+      uint32_t tied = (uint32_t)_mm256_movemask_epi8(
+          _mm256_cmpeq_epi8(_mm256_max_epu8(top, top_order), top));  // top byte >= the order's
+      for (; tied; tied &= tied - 1)
+        bad += element_ge(src + (uint64_t)__builtin_ctz(tied) * BPN, BPN, order_le);
+    }
+  }
+  return bad + wire_to_planes_bytewise(wire, BPN, planes, plane_stride, order_le, s, s1);
+}
+#endif  // __AVX2__
+
+typedef uint64_t (*WireToPlanesSlice)(const uint8_t*, uint64_t, uint8_t*, uint64_t,
+                                      const uint8_t*, uint64_t, uint64_t);
+
+}  // namespace
+
+// Interleaved wire bytes -> checked byte planes, in one pass (ABI 14; the
+// eager parse of a v1 Update vector on a coordinator whose staging slots are
+// byte planes, ops/limbs.py::wire_to_planes): `count` elements of `bpn`
+// little-endian bytes each at `wire`; byte b of element i goes to planes + b
+// * plane_stride + i, the layout of a wire v2 body and of a staging slot
+// (xn_pack_wire_planes writes the same bytes from limb rows). Returns the
+// number of elements >= the order, `order_le` being its bpn little-endian
+// bytes, compared from the top byte down as xn_count_ge_planes compares; a
+// null `order_le` (an order of 2^(8*bpn)) admits all and compares nothing.
+// One read of the wire bytes, one write of the planes, the comparison on
+// bytes still in registers. Any bpn from 1 to 16 by the same loop (wider
+// elements, and a build without AVX2, a byte at a time). The element axis
+// runs on `n_threads` threads (0 = fold_threads()).
+XN_EXPORT uint64_t xn_wire_to_planes(const uint8_t* wire, uint64_t count, uint32_t bpn,
+                                     uint8_t* planes, uint64_t plane_stride,
+                                     const uint8_t* order_le, uint32_t n_threads) {
+  if (count == 0 || bpn == 0) return 0;
+  std::atomic<uint64_t> bad{0};
+#ifdef __AVX2__
+  static const WireToPlanesSlice by_width[16] = {
+      wire_to_planes_slice<1>,  wire_to_planes_slice<2>,  wire_to_planes_slice<3>,
+      wire_to_planes_slice<4>,  wire_to_planes_slice<5>,  wire_to_planes_slice<6>,
+      wire_to_planes_slice<7>,  wire_to_planes_slice<8>,  wire_to_planes_slice<9>,
+      wire_to_planes_slice<10>, wire_to_planes_slice<11>, wire_to_planes_slice<12>,
+      wire_to_planes_slice<13>, wire_to_planes_slice<14>, wire_to_planes_slice<15>,
+      wire_to_planes_slice<16>};
+  const WireToPlanesSlice slice = bpn <= 16 ? by_width[bpn - 1] : nullptr;
+#else
+  const WireToPlanesSlice slice = nullptr;
+#endif
+  run_sliced(
+      count, 4096,
+      [&, slice](uint64_t s0, uint64_t s1) {
+        const uint64_t mine =
+            slice ? slice(wire, count, planes, plane_stride, order_le, s0, s1)
+                  : wire_to_planes_bytewise(wire, bpn, planes, plane_stride, order_le, s0, s1);
+        if (mine) bad.fetch_add(mine, std::memory_order_relaxed);
+      },
+      n_threads);
+  return bad.load();
+}
+
+XN_EXPORT uint32_t xn_abi_version(void) { return 14; }
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST

@@ -174,18 +174,21 @@ def _forged(config: MaskConfig, params, sums: dict, index: int, planar: bool,
     return PublicEncryptKey(params.pk).encrypt(message.to_bytes(keys.secret))
 
 
-async def _served_round(settings: Settings, senders: list[str], forged=()) -> dict:
+async def _served_round(settings: Settings, senders: list[str], forged=(), **pipeline) -> dict:
     """One PET round over the REST API on localhost. ``senders[i]`` says what
     participant ``i`` runs: ``"sdk"`` (the SDK as shipped: it follows the
     round's ``wire_format``) or ``"legacy"`` (an SDK that sends v1 whatever the
     round says). ``forged(config, params, sums)`` may give sealed messages to
     POST after the first sender: ``[(sealed, expected error stage or None)]``.
+    ``pipeline``: what the runner would tell the message handler of these
+    settings (``wire_ingest``, ``update_planes``); nothing, as these tests ran
+    before the handler could be told of its consumer's slots.
     Returns the model, what the counters moved by over the round, and what
     the handler said of each forged message."""
     store = Store(InMemoryCoordinatorStorage(), InMemoryModelStorage(), NoOpTrustAnchor())
     machine, request_tx, events = await StateMachineInitializer(settings, store).init()
     fetcher = Fetcher(events)
-    handler = PetMessageHandler(events, request_tx)
+    handler = PetMessageHandler(events, request_tx, **pipeline)
     rest = RestServer(fetcher, handler)
     host, port = await rest.start("127.0.0.1", 0)
     url = f"http://{host}:{port}"
@@ -252,8 +255,9 @@ async def _served_round(settings: Settings, senders: list[str], forged=()) -> di
         await asyncio.gather(machine_task, return_exceptions=True)
 
 
-def _run(settings: Settings, senders: list[str], forged=()) -> dict:
-    return asyncio.run(asyncio.wait_for(_served_round(settings, senders, forged), 150))
+def _run(settings: Settings, senders: list[str], forged=(), **pipeline) -> dict:
+    return asyncio.run(
+        asyncio.wait_for(_served_round(settings, senders, forged, **pipeline), 150))
 
 
 def _reference(config: MaskConfig, accepted: list[int]) -> np.ndarray:
@@ -323,7 +327,8 @@ def test_served_packed_round_equals_the_reference_and_the_legacy_round(
     assert last["packed"] + last["legacy"] == K and last["copied"] == last["packed"]
     line = [r.getMessage() for r in caplog.records
             if r.getMessage().startswith("update vectors staged since the last Sum2")]
-    assert line and f"{n_v2} on the packed wire (v2), {n_v2} of them copied" in line[0]
+    assert line and (f"{n_v2} on the packed wire (v2), {n_update - n_v2} on the legacy wire (v1); "
+                     f"{n_v2} of them copied") in line[0]
 
 
 def test_unpacked_staging_and_the_host_aggregator_take_a_v2_body_through_the_counted_fallback(

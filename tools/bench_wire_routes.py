@@ -11,6 +11,11 @@ fan-in cell's), each pass timed alone on an idle host, best of ``--repeat``:
   (``all_lt_order`` on the limb rows; the served path runs it twice, in the
   parse and in ``validate_aggregation``), ``slot`` (``pack_wire_slice``: limb
   rows -> the slot's byte planes);
+- ``v1-planes``: the same body on a coordinator whose slots are byte planes
+  (PR 51): ``planes`` (``wire_to_planes``: wire bytes -> checked byte planes in
+  one pass, on pages kept from the last message as the parse takes them from
+  its ``PlaneBuffers``; ``planes_fresh``: the same into a new array, every page
+  of it touched for the first time), then ``v2``'s ``slot``;
 - ``v2``: the byte-planar body: ``scan`` (``planes_lt_order``: the planes
   against the order, top plane down), ``slot`` (``copy_planes``: the planes
   into the slot);
@@ -20,8 +25,11 @@ fan-in cell's), each pass timed alone on an idle host, best of ``--repeat``:
 
 Each shape runs twice in children of its own: on one thread of the native
 library (``XAYNET_NATIVE_THREADS=1``) and on ``fold_threads()``. GB/s are the
-element block's bytes over the pass's time. No chip, no jax: host numbers,
-and quoted as such.
+element block's bytes over the pass's time. ``x8`` rows are the pass called
+by eight Python threads at once, each on a vector of its own (the ``pet-msg``
+workers of a 13-core host under a flood), timed until the last returns: what
+a kernel that threads by default costs where many callers meet. No chip, no
+jax: host numbers, and quoted as such.
 
 Run:  python tools/bench_wire_routes.py [--shapes 25557032x7,...] [--repeat 3]
 """
@@ -45,6 +53,26 @@ def _best(fn, repeat: int) -> float:
     return best
 
 
+def _at_once(fn, callers: int) -> float:
+    """Seconds until the last of ``callers`` threads has run ``fn(i)`` once."""
+    import threading
+
+    gate = threading.Barrier(callers + 1)
+
+    def run(i):
+        gate.wait()
+        fn(i)
+
+    threads = [threading.Thread(target=run, args=(i,)) for i in range(callers)]
+    for t in threads:
+        t.start()
+    gate.wait()
+    t0 = time.perf_counter()
+    for t in threads:
+        t.join()
+    return time.perf_counter() - t0
+
+
 def _case(elements: int, bpn: int, repeat: int) -> dict:
     import numpy as np
 
@@ -61,10 +89,14 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
     limbs = limb_ops.bytes_le_to_limbs(v1, elements, bpn, op=None)
     slot = np.zeros((1, bpn, elements + 128), dtype=np.uint8)  # touched: a ring buffer is reused
     assert limb_ops.all_lt_order(limbs, order) and limb_ops.planes_lt_order(v2, order)
+    kept = limb_ops.PlaneBuffers(keep=8)
     passes = {
         "v1.parse": lambda: limb_ops.bytes_le_to_limbs(v1, elements, bpn, op=None),
         "v1.scan": lambda: limb_ops.all_lt_order(limbs, order),
         "v1.slot": lambda: limb_ops.pack_wire_slice(limbs[None], 0, elements, bpn, slot),
+        "v1p.planes": lambda: limb_ops.wire_to_planes(
+            v1, elements, bpn, order, out=kept.take(bpn, elements), n_threads=0),
+        "v1p.planes_fresh": lambda: limb_ops.wire_to_planes(v1, elements, bpn, order, n_threads=0),
         "v2.scan": lambda: limb_ops.planes_lt_order(v2, order),
         "v2.slot": lambda: limb_ops.copy_planes(v2, slot[0, :, :elements]),
         "fallback.transpose": lambda: planar_to_interleaved(v2.reshape(-1), elements, bpn),
@@ -72,6 +104,19 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
     ms = {name: 1e3 * _best(fn, repeat) for name, fn in passes.items()}
     limb_ops.copy_planes(v2, slot[0, :, :elements])
     assert np.array_equal(slot[0, :, :elements], v2)
+    planes, bad = limb_ops.wire_to_planes(v1, elements, bpn, order)
+    assert bad == 0 and np.array_equal(planes, v2)
+    del planes
+    # eight callers at once, a body each (a copy: no two read the same pages)
+    bodies = [v1.copy() for _ in range(8)]
+    ms["v1.parse_x8"] = 1e3 * _best(lambda: _at_once(
+        lambda i: limb_ops.bytes_le_to_limbs(bodies[i], elements, bpn, op=None), 8), repeat)
+    ms["v1p.planes_x8"] = 1e3 * _best(lambda: _at_once(
+        lambda i: limb_ops.wire_to_planes(
+            bodies[i], elements, bpn, order, out=kept.take(bpn, elements), n_threads=0), 8),
+        repeat + 1)  # the first turn touches the kept pages
+    ms["v1p.planes_fresh_x8"] = 1e3 * _best(lambda: _at_once(
+        lambda i: limb_ops.wire_to_planes(bodies[i], elements, bpn, order, n_threads=0), 8), repeat)
     block = elements * bpn
     return {
         "elements": elements, "bytes": bpn, "limbs": n_limb, "block_mb": block / 1e6,
@@ -80,6 +125,7 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
         "gb_per_s": {k: round(block / 1e6 / v, 2) for k, v in ms.items()},
         "a_message_ms": {
             "v1": round(ms["v1.parse"] + 2 * ms["v1.scan"] + ms["v1.slot"], 1),
+            "v1-planes": round(ms["v1p.planes"] + ms["v2.slot"], 1),
             "v2": round(ms["v2.scan"] + ms["v2.slot"], 1),
             "fallback": round(ms["fallback.transpose"] + ms["v1.parse"] + 2 * ms["v1.scan"]
                               + ms["v1.slot"], 1),

@@ -73,8 +73,11 @@ class MaskVect:
 class LazyWireMaskVect(MaskVect):
     """A ``MaskVect`` parsed from wire with limb materialization DEFERRED.
 
-    Carries the raw fixed-width element block (``wire_block``, a zero-copy
-    uint8 view) and takes one of two roads:
+    Carries the fixed-width element block as bytes (``wire_block``, uint8)
+    and says two things of it apart: ``packed_wire``, which wire the
+    MESSAGE came on (it carried the v2 flag, or it is a v1 message), and
+    ``planar``, which LAYOUT the block has (byte planes, or interleaved as
+    on the v1 wire). It takes one of three roads:
 
     - **wire ingest** (``parse_mask_vect(lazy=True)``, v1 or v2): a
       device-ingest coordinator unpacks + validity-checks + folds on the
@@ -82,28 +85,44 @@ class LazyWireMaskVect(MaskVect):
       parse rejects invalid elements with ``DecodeError`` at parse time;
       this road defers that rejection to ``validate_aggregation`` (device)
       or the first host materialization — same update rejected, one stage
-      later.
+      later. The block is a zero-copy view of the body, in its layout.
     - **the packed wire, eager** (a v2 block under ``lazy=False``): the
       parse scans the planes against the order (:meth:`check_planes`) and
       rejects with ``DecodeError`` as for a v1 body; the verdict rides on
       the object (``checked``), so ``is_valid()`` scans nothing again, and
       a packed-staging coordinator copies ``planar_block`` into the staging
       slot: no interleaved block and no limb row is ever made.
+    - **the legacy wire, relaid in the parse** (a v1 block under
+      ``parse_mask_vect(planes=True)``: the consumer's slots are byte
+      planes): one native pass wrote the planes from the interleaved bytes
+      and compared every element with the order (``ops/limbs.py::
+      wire_to_planes``), so the object is born ``checked`` over planes of
+      its own (not a view: the body is not kept), ``packed_wire`` false,
+      and everything after it is the packed wire's road.
 
     Any host access to ``data`` materializes the limbs exactly like the v1
-    eager parse would have (a v2 block through the counted transposing
+    eager parse would have (a planar block through the counted transposing
     fallback ``planar_to_interleaved``).
     """
 
     def __init__(
-        self, config: MaskConfig, wire_block: np.ndarray, count: int, planar: bool = False
+        self,
+        config: MaskConfig,
+        wire_block: np.ndarray,
+        count: int,
+        planar: bool = False,
+        packed_wire: bool | None = None,
+        checked: bool = False,
     ):
         self.config = config
         self.wire_block = wire_block  # uint8[count * bytes_per_number]
         self._count = count
-        # wire format v2: the block is byte-planar (bpn planes of count
-        # bytes) instead of interleaved — already the packed staging layout
+        # the block is byte-planar (bpn planes of count bytes: the wire v2
+        # layout, and the packed staging layout) instead of interleaved
         self.planar = planar
+        # the message carried the v2 flag; a block's layout follows its wire
+        # unless the maker says otherwise (the v1 parse that writes planes)
+        self.packed_wire = planar if packed_wire is None else packed_wire
         self._data: np.ndarray | None = None
         # device planar cached by StagedAggregator.validate_aggregation so
         # stage() never re-uploads; _wire_invalid is the cached REJECTED
@@ -111,8 +130,9 @@ class LazyWireMaskVect(MaskVect):
         # on it without another device round-trip)
         self._staged_planar = None
         self._wire_invalid = False
-        # the host's verdict on the planes (check_planes), None = not scanned
-        self._planes_valid: bool | None = None
+        # the host's verdict on the planes (check_planes, or the maker's own
+        # pass: ``checked``), None = not scanned
+        self._planes_valid: bool | None = True if checked else None
 
     @property
     def materialized(self) -> bool:
@@ -120,13 +140,14 @@ class LazyWireMaskVect(MaskVect):
 
     @property
     def checked(self) -> bool:
-        """Whether the host has scanned this block's planes against the
-        order (the eager v2 parse): ``is_valid()`` then repeats the verdict."""
+        """Whether the host has compared this block's planes with the order
+        (the eager v2 parse, or the v1 parse that wrote them):
+        ``is_valid()`` then repeats the verdict."""
         return self._planes_valid is not None
 
     def check_planes(self) -> bool:
-        """Element validity of a v2 block on its byte planes (no limb row
-        made), once: the verdict is kept for ``is_valid()``."""
+        """Element validity of a planar block on its byte planes (no limb
+        row made), once: the verdict is kept for ``is_valid()``."""
         if self._planes_valid is None:
             self._planes_valid = limb_ops.planes_lt_order(self.planar_block, self.config.order)
         return self._planes_valid
@@ -138,11 +159,11 @@ class LazyWireMaskVect(MaskVect):
 
     @property
     def planar_block(self) -> np.ndarray:
-        """Zero-copy ``uint8[bpn, count]`` view of a v2 planar element block
+        """Zero-copy ``uint8[bpn, count]`` view of a planar element block
         (the shape the packed staging rings and the device planar-unpack
         consume directly)."""
         if not self.planar:
-            raise ValueError("planar_block on an interleaved (v1) wire vect")
+            raise ValueError("planar_block on an interleaved element block")
         return np.asarray(self.wire_block).reshape(
             self.config.bytes_per_number, self._count
         )
@@ -175,12 +196,13 @@ def wire_route(vect: MaskVect) -> tuple[str, str]:
     """``(wire, route)`` of a parsed Update vector, the labels of
     ``xaynet_update_wire_bytes_total`` and of the ``parse`` / ``validate`` /
     ``to_planar`` spans. ``wire``: ``packed`` = the message carried the v2
-    flag, ``legacy`` = v1. ``route``: ``copy`` = a plane view the host has
-    checked (nothing relaid; a packed-staging slot takes it by copy),
-    ``device`` = an unchecked wire block for the device's unpack (wire
-    ingest), ``relayout`` = limb rows (the v1 parse, or a view since
-    materialized)."""
-    wire = "packed" if getattr(vect, "planar", False) else "legacy"
+    flag, ``legacy`` = v1, whatever layout the parse gave the block.
+    ``route``: ``copy`` = byte planes the host has checked (a v2 body's own,
+    or the ones the v1 parse wrote; a packed-staging slot takes them by
+    copy), ``device`` = an unchecked wire block for the device's unpack
+    (wire ingest), ``relayout`` = limb rows (the v1 parse for a consumer
+    that wants them, or a block since materialized)."""
+    wire = "packed" if getattr(vect, "packed_wire", False) else "legacy"
     if isinstance(vect, LazyWireMaskVect) and not vect.materialized:
         return wire, "copy" if vect.checked else "device"
     return wire, "relayout"

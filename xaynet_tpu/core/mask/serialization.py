@@ -22,6 +22,13 @@ copies it into the staging slot; a device-ingest coordinator uploads it
 without the byte-gather relayout. Neither materializes uint32 limbs.
 Element counts are bounded far below 2^31 (``MAX_BODY`` caps the message),
 so the flag bit can never collide with a real count.
+
+A v1 block whose consumer's slots are byte planes (``planes``, the buffers
+to write them in: a packed-staging device coordinator's Update vectors) is
+relaid ONCE, here:
+one native pass writes the planes from the interleaved bytes and compares
+every element with the order, and the vector is the checked plane object a
+v2 body gives, flagged as having come on the legacy wire.
 """
 
 from __future__ import annotations
@@ -124,7 +131,8 @@ def write_mask_vect(vect: MaskVect, buf, offset: int, planar: bool = False) -> i
     )
     block = np.frombuffer(buf, dtype=np.uint8, count=count * bpn, offset=start)
     if planar and getattr(vect, "planar", False) and not vect.materialized:
-        # parsed-from-planar-wire and never touched: re-emit the block
+        # a planar block never touched (a v2 body's, or the planes the v1
+        # parse wrote) asked for as planes: re-emit the block
         block[...] = np.asarray(vect.wire_block)
     else:
         limb_ops.limbs_into_wire(vect.data, bpn, block, planar=planar)
@@ -138,7 +146,12 @@ def serialize_mask_vect(vect: MaskVect, planar: bool = False) -> bytes:
     )
 
 
-def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[MaskVect, int]:
+def parse_mask_vect(
+    data: bytes,
+    offset: int = 0,
+    lazy: bool = False,
+    planes: "limb_ops.PlaneBuffers | None" = None,
+) -> tuple[MaskVect, int]:
     """Parse a MaskVect at ``offset``; returns (vect, bytes consumed).
 
     ``lazy=True`` (device-ingest coordinators) skips the host limb
@@ -153,6 +166,12 @@ def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[M
     planes are scanned against the order here, an element out of the group
     raises the same ``DecodeError`` a v1 body's would, and no interleaved
     block or limb row is made unless a caller asks for ``.data``.
+
+    ``planes`` (the consumer's slots are byte planes: a packed-staging
+    device coordinator's Update vectors; the ``PlaneBuffers`` to take the
+    pages from) gives a v1 block the same shape in one pass: checked planes
+    of the object's own, ``packed_wire`` false, the same ``DecodeError`` for
+    the same bodies. ``lazy`` goes first.
     """
     if len(data) - offset < MASK_CONFIG_LENGTH + 4:
         raise DecodeError("mask vector buffer too short")
@@ -170,6 +189,11 @@ def parse_mask_vect(data: bytes, offset: int = 0, lazy: bool = False) -> tuple[M
     raw = np.frombuffer(data, dtype=np.uint8, count=count * bpn, offset=start)
     if lazy or planar:
         return _wire_vect(config, raw, count, planar, checked=not lazy), end - offset
+    if planes is not None:
+        block, bad = limb_ops.wire_to_planes(
+            raw, count, bpn, config.order, out=planes.take(bpn, count)
+        )
+        return _relaid_vect(config, block, count, bad), end - offset
     limbs = limb_ops.bytes_le_to_limbs(raw, count, bpn)
     vect = MaskVect(config, limbs)
     if not vect.is_valid():
@@ -186,6 +210,18 @@ def _wire_vect(config: MaskConfig, raw: np.ndarray, count: int, planar: bool, ch
     if checked and not vect.check_planes():
         raise DecodeError("mask vector element >= group order")
     return vect
+
+
+def _relaid_vect(config: MaskConfig, block: np.ndarray, count: int, bad: int):
+    """The vector of a v1 block over the planes ``wire_to_planes`` wrote
+    from it, ``bad`` being that pass's count of elements out of the group."""
+    from .object import LazyWireMaskVect
+
+    if bad:
+        raise DecodeError("mask vector element >= group order")
+    return LazyWireMaskVect(
+        config, block.reshape(-1), count, planar=True, packed_wire=False, checked=True
+    )
 
 
 def write_mask_unit(unit: MaskUnit, buf, offset: int) -> int:
@@ -225,7 +261,9 @@ def parse_mask_unit(data: bytes, offset: int = 0) -> tuple[MaskUnit, int]:
     return unit, MASK_CONFIG_LENGTH + bpn
 
 
-def parse_mask_vect_stream(reader, lazy: bool = False) -> MaskVect:
+def parse_mask_vect_stream(
+    reader, lazy: bool = False, planes: "limb_ops.PlaneBuffers | None" = None
+) -> MaskVect:
     """Streaming MaskVect parse from a ``ChunkReader``.
 
     The element block is copied chunk-by-chunk into one staging array
@@ -238,6 +276,9 @@ def parse_mask_vect_stream(reader, lazy: bool = False) -> MaskVect:
     byte copy (no limb conversion, no host validity — a plain memcpy
     instead of the parse hot loop) into a ``LazyWireMaskVect`` for the
     device-ingest coordinator; see ``parse_mask_vect``.
+
+    ``planes``: each segment of a v1 block fills its columns of every plane
+    (``wire_to_planes`` a segment at a time), as ``parse_mask_vect``.
     """
     head = reader.read(MASK_CONFIG_LENGTH + 4)
     try:
@@ -258,19 +299,31 @@ def parse_mask_vect_stream(reader, lazy: bool = False) -> MaskVect:
         reader.read_into(raw)
         return _wire_vect(config, raw, count, planar, checked=not lazy)
     # segmented convert: fixed-size wire segments go straight into the limb
-    # tensor, so the transient staging is bounded (never O(payload))
-    n_limb = limb_ops.n_limbs_for_bytes(bpn)
-    limbs = np.empty((count, n_limb), dtype=np.uint32)
-    seg_elems = max(1, (2 << 20) // max(bpn, 1))
-    for s in range(0, count, seg_elems):
-        k = min(seg_elems, count - s)
-        staging = np.empty(k * bpn, dtype=np.uint8)
-        reader.read_into(staging)
+    # tensor (or the planes), so the transient staging is bounded (never
+    # O(payload))
+    if planes is not None:
+        block, bad = planes.take(bpn, count), 0
+        for s, k, staging in _wire_segments(reader, count, bpn):
+            bad += limb_ops.wire_to_planes(staging, k, bpn, config.order, out=block, column=s)[1]
+        return _relaid_vect(config, block, count, bad)
+    limbs = np.empty((count, limb_ops.n_limbs_for_bytes(bpn)), dtype=np.uint32)
+    for s, k, staging in _wire_segments(reader, count, bpn):
         limbs[s : s + k] = limb_ops.bytes_le_to_limbs(staging, k, bpn)
     vect = MaskVect(config, limbs)
     if not vect.is_valid():
         raise DecodeError("mask vector element >= group order")
     return vect
+
+
+def _wire_segments(reader, count: int, bpn: int):
+    """``(first element, elements, their wire bytes)`` of an interleaved
+    block read from ``reader`` in segments of about 2 MiB."""
+    seg_elems = max(1, (2 << 20) // max(bpn, 1))
+    for s in range(0, count, seg_elems):
+        k = min(seg_elems, count - s)
+        staging = np.empty(k * bpn, dtype=np.uint8)
+        reader.read_into(staging)
+        yield s, k, staging
 
 
 def parse_mask_unit_stream(reader) -> MaskUnit:
@@ -306,9 +359,9 @@ def serialize_mask_object(obj: MaskObject, planar_vect: bool = False) -> bytes:
 
 
 def parse_mask_object(
-    data: bytes, offset: int = 0, lazy_vect: bool = False
+    data: bytes, offset: int = 0, lazy_vect: bool = False, planes_vect=None
 ) -> tuple[MaskObject, int]:
-    vect, n1 = parse_mask_vect(data, offset, lazy=lazy_vect)
+    vect, n1 = parse_mask_vect(data, offset, lazy=lazy_vect, planes=planes_vect)
     unit, n2 = parse_mask_unit(data, offset + n1)
     return MaskObject(vect, unit), n1 + n2
 

@@ -101,12 +101,22 @@ class Message:
         return bytes(buf)
 
     @classmethod
-    def from_bytes(cls, data: bytes, verify: bool = True, lazy_update_vect: bool = False) -> "Message":
+    def from_bytes(
+        cls,
+        data: bytes,
+        verify: bool = True,
+        lazy_update_vect: bool = False,
+        planes_update_vect=None,
+    ) -> "Message":
         """Parse and (by default) verify the signature.
 
         ``lazy_update_vect``: device-ingest coordinators defer the Update
         payload's element parse/validity to the accelerator (see
-        ``parse_mask_vect``); all other payloads parse eagerly."""
+        ``parse_mask_vect``); all other payloads parse eagerly.
+        ``planes_update_vect`` (a ``PlaneBuffers``): the coordinator's
+        staging slots are byte planes, so the Update payload's vector is
+        parsed into checked planes taken from it
+        (``parse_mask_vect(planes=)``); no other payload's is."""
         length = cls._declared_length(data)
         # ``data`` may be a view of the buffer a sealed box was opened into:
         # the header's fields are copied out, the payload is sliced as a view
@@ -127,7 +137,11 @@ class Message:
         if verify:
             cls.verify_bytes(data)
         payload = parse_payload(
-            tag, is_multipart, data[HEADER_LENGTH:length], lazy_update_vect=lazy_update_vect
+            tag,
+            is_multipart,
+            data[HEADER_LENGTH:length],
+            lazy_update_vect=lazy_update_vect,
+            planes_update_vect=planes_update_vect,
         )
         return cls(
             participant_pk=participant_pk,

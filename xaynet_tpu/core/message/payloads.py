@@ -182,23 +182,27 @@ class Update:
         return compose_bytes(self.serialized_length(), self.write_into)
 
     @classmethod
-    def from_bytes(cls, data: bytes, lazy_vect: bool = False) -> "Update":
+    def from_bytes(
+        cls, data: bytes, lazy_vect: bool = False, planes_vect=None
+    ) -> "Update":
         if len(data) < 2 * SIGNATURE_LENGTH:
             raise DecodeError("update payload too short")
-        masked, consumed = parse_mask_object(data, 2 * SIGNATURE_LENGTH, lazy_vect=lazy_vect)
+        masked, consumed = parse_mask_object(
+            data, 2 * SIGNATURE_LENGTH, lazy_vect=lazy_vect, planes_vect=planes_vect
+        )
         seed_dict, _ = parse_local_seed_dict(data, 2 * SIGNATURE_LENGTH + consumed)
         return cls(
             sum_signature=bytes(data[:SIGNATURE_LENGTH]),
             update_signature=bytes(data[SIGNATURE_LENGTH : 2 * SIGNATURE_LENGTH]),
             masked_model=masked,
             local_seed_dict=seed_dict,
-            wire_planar=bool(getattr(masked.vect, "planar", False)),
+            wire_planar=bool(getattr(masked.vect, "packed_wire", False)),
         )
 
     @classmethod
-    def from_stream(cls, reader, lazy_vect: bool = False) -> "Update":
+    def from_stream(cls, reader, lazy_vect: bool = False, planes_vect=None) -> "Update":
         sigs = reader.read(2 * SIGNATURE_LENGTH)
-        vect = parse_mask_vect_stream(reader, lazy=lazy_vect)
+        vect = parse_mask_vect_stream(reader, lazy=lazy_vect, planes=planes_vect)
         unit = parse_mask_unit_stream(reader)
         seed_dict = parse_local_seed_dict_stream(reader)
         return cls(
@@ -206,7 +210,7 @@ class Update:
             update_signature=sigs[SIGNATURE_LENGTH:],
             masked_model=MaskObject(vect, unit),
             local_seed_dict=seed_dict,
-            wire_planar=bool(getattr(vect, "planar", False)),
+            wire_planar=bool(getattr(vect, "packed_wire", False)),
         )
 
 
@@ -284,7 +288,11 @@ Payload = Union[Sum, Update, Sum2, Chunk]
 
 
 def parse_payload(
-    tag, is_multipart: bool, data: bytes, lazy_update_vect: bool = False
+    tag,
+    is_multipart: bool,
+    data: bytes,
+    lazy_update_vect: bool = False,
+    planes_update_vect=None,
 ) -> Payload:
     if is_multipart:
         return Chunk.from_bytes(data, tag=tag)
@@ -293,13 +301,17 @@ def parse_payload(
     if tag == Tag.SUM:
         return Sum.from_bytes(data)
     if tag == Tag.UPDATE:
-        return Update.from_bytes(data, lazy_vect=lazy_update_vect)
+        return Update.from_bytes(
+            data, lazy_vect=lazy_update_vect, planes_vect=planes_update_vect
+        )
     if tag == Tag.SUM2:
         return Sum2.from_bytes(data)
     raise DecodeError(f"unknown tag {tag}")
 
 
-def parse_payload_stream(tag, reader, lazy_update_vect: bool = False) -> Payload:
+def parse_payload_stream(
+    tag, reader, lazy_update_vect: bool = False, planes_update_vect=None
+) -> Payload:
     """Streaming payload parse from a ``ChunkReader`` (multipart reassembly).
 
     Reference analogue: the stream variants of ``FromBytes``
@@ -311,7 +323,9 @@ def parse_payload_stream(tag, reader, lazy_update_vect: bool = False) -> Payload
         if tag == Tag.SUM:
             return Sum.from_bytes(reader.read(reader.remaining))
         if tag == Tag.UPDATE:
-            return Update.from_stream(reader, lazy_vect=lazy_update_vect)
+            return Update.from_stream(
+                reader, lazy_vect=lazy_update_vect, planes_vect=planes_update_vect
+            )
         if tag == Tag.SUM2:
             return Sum2.from_stream(reader)
     except ValueError as e:

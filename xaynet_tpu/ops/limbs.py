@@ -15,6 +15,9 @@ oracle for the JAX/Pallas device kernels in ``xaynet_tpu.ops.limbs_jax``.
 
 from __future__ import annotations
 
+import sys
+import threading
+
 import numpy as np
 
 from ..telemetry import codec
@@ -58,6 +61,13 @@ def n_limbs_for_order(order: int) -> int:
     rounded up to whole limbs.
     """
     return n_limbs_for_bytes(wire_width_for(order))
+
+
+def packed_staging_usable(order: int) -> bool:
+    """Whether packed byte-planar staging shrinks anything for this group:
+    the wire width must be narrower than the limb width (at the
+    ``order == 2^(32L)`` boundary bpn == 4L and packing is a no-op)."""
+    return wire_width_for(order) < 4 * n_limbs_for_order(order)
 
 
 def order_limbs_for(order: int) -> np.ndarray:
@@ -116,15 +126,125 @@ def planes_lt_order(planes: np.ndarray, order: int) -> bool:
         return 0 == lib.xn_count_ge_planes(
             native.np_u8p(planes), n, planes.strides[0], bpn, native.np_u8p(order_le)
         )
+    return _count_ge_planes(planes, order_le) == 0
+
+
+def _count_ge_planes(planes: np.ndarray, order_le: np.ndarray) -> int:
+    """numpy's count of the elements of byte planes that are ``>=`` the
+    order, from the top plane down (the ``generic`` twin of
+    ``xn_count_ge_planes``)."""
+    bpn, n = planes.shape
+    bad = 0
     # tied[i]: element i equals the order in every plane above the current one
     tied = np.ones(n, dtype=bool)
     for b in range(bpn - 1, -1, -1):
-        if np.any(tied & (planes[b] > order_le[b])):
-            return False
+        bad += int(np.count_nonzero(tied & (planes[b] > order_le[b])))
         tied &= planes[b] == order_le[b]
         if not tied.any():
-            return True
-    return False  # an element equal to the order
+            return bad
+    return bad + int(np.count_nonzero(tied))  # the elements equal to the order
+
+
+class PlaneBuffers:
+    """The ``uint8[bpn, count]`` planes :func:`wire_to_planes` writes for a
+    parse, on pages kept from earlier messages.
+
+    A vector-sized array of fresh pages costs more to touch than the pass
+    that fills it (on the chip's host 4 us a page: 190 of the 192 ms of one
+    178.9 MB relayout, and eight workers' faults queue behind each other;
+    PERF.md section 6, PR 51), so up to ``keep`` buffers are kept and handed
+    out again. Nobody gives a buffer back: every array that reaches a
+    buffer's memory is a numpy view of it and holds a reference to it, so a
+    kept buffer that nothing but this object refers to is free, and is found
+    so by its reference count. A buffer still referred to (a vector waiting
+    for its slot copy, one kept by a test) is left alone, and where all are,
+    or the size asked for is not the kept buffers', fresh pages are handed
+    out as ``np.empty`` would. ``keep = 0`` keeps nothing."""
+
+    def __init__(self, keep: int = 0):
+        self._keep = max(0, int(keep))
+        self._lock = threading.Lock()
+        # flat uint8 arrays that own their memory  # guarded-by: _lock
+        self._kept: list[np.ndarray] = [np.empty(0, dtype=np.uint8)]
+        # what the scan below reads of a buffer nothing else refers to
+        self._unshared = self._shared_by()[0]
+        self._kept.clear()
+
+    def _shared_by(self) -> list[int]:
+        return [sys.getrefcount(buf) for buf in self._kept]
+
+    def take(self, bpn: int, count: int) -> np.ndarray:
+        """``uint8[bpn, count]``, contents undefined, the caller's until its
+        last view of them is gone."""
+        nbytes = bpn * count
+        with self._lock:
+            shared_by = self._shared_by()
+            if self._kept and self._kept[0].size != nbytes:
+                if any(n != self._unshared for n in shared_by):
+                    return np.empty((bpn, count), dtype=np.uint8)
+                self._kept.clear()  # another round's vectors: start anew
+            for buf, n in zip(self._kept, shared_by):
+                if n == self._unshared:
+                    return buf.reshape(bpn, count)
+            buf = np.empty(nbytes, dtype=np.uint8)
+            if len(self._kept) < self._keep:
+                self._kept.append(buf)
+            return buf.reshape(bpn, count)
+
+
+def wire_to_planes(
+    wire: np.ndarray,
+    count: int,
+    bpn: int,
+    order: int,
+    out: np.ndarray | None = None,
+    column: int = 0,
+    n_threads: int = 1,
+) -> tuple[np.ndarray, int]:
+    """``count`` interleaved ``bpn``-byte little-endian elements (a wire v1
+    element block, or a segment of one) -> byte planes, plane ``b`` holding
+    byte ``b`` of every element, and the number of elements ``>=`` the
+    order: ``bytes_le_to_limbs`` + ``all_lt_order`` + ``pack_wire`` in one
+    pass and no limb row (``xn_wire_to_planes``; numpy's transpose and plane
+    compares otherwise, ``generic``). The planes are the bytes a wire v2 body
+    carries and a packed staging slot holds.
+
+    ``out`` (``uint8[bpn, >= column + count]``, unit column stride) receives
+    the elements at columns ``[column, column + count)``; a fresh
+    ``uint8[bpn, count]`` otherwise (a parse brings :class:`PlaneBuffers`'). Returns ``(planes, bad)``. On one thread
+    of the caller's unless told otherwise: the parse runs on every ``pet-msg``
+    worker at once (PERF.md section 6, PR 51)."""
+    raw = np.frombuffer(wire, dtype=np.uint8, count=count * bpn)
+    if out is None:
+        out = np.empty((bpn, count), dtype=np.uint8)
+    if (
+        out.dtype != np.uint8 or out.ndim != 2 or out.shape[0] != bpn
+        or out.shape[1] < column + count or (out.shape[1] > 1 and out.strides[1] != 1)
+    ):
+        raise ValueError("expected uint8[bpn, >= column + count] planes of unit column stride")
+    # None: every value bpn bytes can hold is a group element, nothing to compare
+    order_le = (
+        None if order >> (8 * bpn)
+        else np.frombuffer(order.to_bytes(bpn, "little"), dtype=np.uint8)
+    )
+    from ..utils import native
+
+    lib = native.load()
+    fast = lib is not None
+    codec.count("parse", fast, count)
+    if order_le is not None:
+        codec.count("validate", fast, count)
+    if count == 0:
+        return out, 0
+    if fast:
+        bad = lib.xn_wire_to_planes(
+            native.np_u8p(raw), count, bpn, native.np_u8p_at(out, column), out.strides[0],
+            None if order_le is None else native.np_u8p(order_le), max(0, int(n_threads)),
+        )
+        return out, int(bad)
+    view = out[:, column : column + count]
+    view[...] = raw.reshape(count, bpn).T
+    return out, 0 if order_le is None else _count_ge_planes(view, order_le)
 
 
 def copy_planes(planes: np.ndarray, out: np.ndarray) -> None:

@@ -537,10 +537,9 @@ class ShardedAggregator:
         return fn
 
     def packed_staging_usable(self) -> bool:
-        """Whether packed byte-planar staging actually shrinks anything:
-        the wire width must be narrower than the limb width (at the
-        ``order == 2^(32L)`` boundary bpn == 4L and packing is a no-op)."""
-        return self.packed_width < 4 * self.n_limbs
+        """Whether packed byte-planar staging actually shrinks anything
+        (``ops/limbs.py::packed_staging_usable``)."""
+        return host_limbs.packed_staging_usable(self.order)
 
     def _make_packed_fold_fn(self, kernel: str):
         """The packed-batch fold callable for ``kernel`` (byte-planar

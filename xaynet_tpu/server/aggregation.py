@@ -61,6 +61,22 @@ def build_staged_aggregator(shared) -> "StagedAggregator":
     )
 
 
+def slots_take_planes(settings) -> bool:
+    """Whether the staging slots of the aggregator these settings build
+    (:func:`build_staged_aggregator`) are byte planes
+    (``StreamingAggregator.takes_planes``: the device path under packed
+    staging, where the mask's wire width is under its limb width). What the
+    message pipeline needs to know of its consumer before a round has one:
+    it then parses an Update's v1 vector into the planes its slot will hold
+    (``PetMessageHandler.update_planes``)."""
+    aggregation = settings.aggregation
+    return bool(
+        aggregation.device
+        and aggregation.packed_staging
+        and limb_ops.packed_staging_usable(settings.mask.to_config().order)
+    )
+
+
 class DeviceAggregation(Aggregation):
     """Aggregation view over the still-sharded device accumulator.
 

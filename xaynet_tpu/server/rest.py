@@ -748,8 +748,8 @@ class RestServer:
             if staged["packed"] or staged["legacy"]:
                 logger.info(
                     "update vectors staged since the last Sum2: %d on the packed wire (v2), %d "
-                    "of them copied into their slots as planes; %d on the legacy wire (v1)",
-                    staged["packed"], staged["copied"], staged["legacy"],
+                    "on the legacy wire (v1); %d of them copied into their slots as planes",
+                    staged["packed"], staged["legacy"], staged["copied"],
                 )
 
     async def _dispatch(self, method: str, path: str, query: str, body: bytes,

@@ -28,6 +28,7 @@ from ..storage.traits import Store
 from ..telemetry import BridgedMetrics, RoundReporter
 from ..telemetry.startup import get_timeline
 from ..utils import tracing
+from .aggregation import slots_take_planes
 from .metrics import InfluxHttpMetrics, InfluxLineMetrics, JsonlMetrics, LogMetrics
 from .rest import RestServer
 from .services import Fetcher, PetMessageHandler
@@ -215,7 +216,10 @@ async def serve(settings: Settings, store: Optional[Store] = None) -> None:
     startup.mark("machine")
 
     handler = PetMessageHandler(
-        events, request_tx, wire_ingest=settings.aggregation.wire_ingest
+        events,
+        request_tx,
+        wire_ingest=settings.aggregation.wire_ingest,
+        update_planes=slots_take_planes(settings),
     )
     fetcher = Fetcher(events)
     pipeline = None
@@ -359,7 +363,10 @@ async def _build_tenant_context(settings: Settings, tenant: str, budget, registr
     initializer = StateMachineInitializer(tset, store, metrics, tenant=tenant)
     machine, request_tx, events = await initializer.init()
     handler = PetMessageHandler(
-        events, request_tx, wire_ingest=tset.aggregation.wire_ingest
+        events,
+        request_tx,
+        wire_ingest=tset.aggregation.wire_ingest,
+        update_planes=slots_take_planes(tset),
     )
     fetcher = Fetcher(events)
     pipeline = None

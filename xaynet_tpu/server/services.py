@@ -34,6 +34,7 @@ from ..core.mask.object import wire_route
 from ..core.mask.serialization import DecodeError
 from ..core.message import Chunk, Message, Sum, Sum2, Tag, Update, peek_header
 from ..core.message.encoder import MessageBuilder
+from ..ops.limbs import PlaneBuffers
 from ..telemetry import tracing as trace
 from ..telemetry.registry import get_registry
 from ..utils import tracing
@@ -141,6 +142,7 @@ class PetMessageHandler:
         request_tx: RequestSender,
         wire_ingest: bool = False,
         workers: Optional[MessageWorkers] = None,
+        update_planes: bool = False,
     ):
         self.events = events
         self.request_tx = request_tx
@@ -149,6 +151,15 @@ class PetMessageHandler:
         # validate_aggregation, before the seed-dict insert)
         self.wire_ingest = wire_ingest
         self.workers = workers if workers is not None else shared_workers()
+        # the staged aggregator's slots are byte planes
+        # (aggregation.slots_take_planes): an Update's v1 vector is parsed
+        # into checked planes, which its slot takes by copy, and not into
+        # limb rows that nothing but the plane pack would read. The planes
+        # lie on pages kept from earlier messages: one vector a worker and
+        # the few that wait for their slot copy on the xn-ingest pool
+        self.update_planes = (
+            PlaneBuffers(keep=self.workers.size + 4) if update_planes else None
+        )
         # multipart reassembly buffers keyed by (participant_pk, message_id);
         # bounded: abandoned reassemblies are evicted oldest-first so a
         # client cannot grow coordinator memory without completing messages
@@ -255,10 +266,16 @@ class PetMessageHandler:
             with stages.stage("parse", **at) as span:
                 if beside:
                     verdict = self.workers.verdicts.submit(self._verify_beside, raw, at)
-                message = Message.from_bytes(raw, verify=False, lazy_update_vect=self.wire_ingest)
+                message = Message.from_bytes(
+                    raw,
+                    verify=False,
+                    lazy_update_vect=self.wire_ingest,
+                    planes_update_vect=self.update_planes,
+                )
                 if isinstance(message.payload, Update):
                     # which wire the vector came on and what the parse made
-                    # of it: limb rows, or a view of the body's bytes
+                    # of it: limb rows, checked planes, or a view of the
+                    # body's bytes
                     wire, route = wire_route(message.payload.masked_model.vect)
                     span.set(wire=wire, route=route)
                 return message
@@ -364,7 +381,10 @@ class PetMessageHandler:
 
         try:
             payload = parse_payload_stream(
-                message.tag, builder.take_reader(), lazy_update_vect=self.wire_ingest
+                message.tag,
+                builder.take_reader(),
+                lazy_update_vect=self.wire_ingest,
+                planes_update_vect=self.update_planes,
             )
         except DecodeError as e:
             raise ServiceError("multipart", str(e)) from e

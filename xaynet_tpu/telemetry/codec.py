@@ -10,11 +10,14 @@ loops cost 3-23% of a kernel and nothing end to end (PERF.md section 6, PR 26),
 where numpy costs a multiple. One counter says, in elements, which ran,
 counted where the choice is made:
 
-- ``parse``: ``ops/limbs.py::bytes_le_to_limbs`` (wire bytes -> limbs), and
+- ``parse``: ``ops/limbs.py::bytes_le_to_limbs`` (wire bytes -> limbs),
+  ``wire_to_planes`` (v1 wire bytes -> checked byte planes in one pass), and
   ``core/mask/serialization.py::planar_to_interleaved``, always ``generic``:
-  numpy's transpose of a wire v2 vector whose limb rows someone asks for;
+  numpy's transpose of a planar block whose limb rows someone asks for;
 - ``validate``: ``ops/limbs.py::all_lt_order`` (element < order, on limb
-  rows) and ``planes_lt_order`` (the same on a wire v2 vector's byte planes);
+  rows), ``planes_lt_order`` (the same on a wire v2 vector's byte planes) and
+  ``wire_to_planes`` (the comparison inside its pass: its elements count
+  under ``parse`` and here);
 - ``stage``: ``ops/limbs.py::pack_wire_slice``, ``pack_wire``, ``pack_planar``
   (limbs -> byte planes) and ``copy_planes`` (a v2 vector's planes copied
   into the slot);

@@ -9,10 +9,11 @@ a time, so a round may mix both. Counted once an update, where
 
 - ``xaynet_update_wire_bytes_total{wire, route}``: the element-block bytes
   of each staged Update. ``wire`` = ``packed`` (the v2 flag) | ``legacy``.
-  ``route`` = ``copy`` (the planes copied into the slot, no intermediate) |
-  ``relayout`` (through uint32 limb rows and the plane pack; for a v2 body
-  the transposing fallback before them) | ``device`` (wire ingest: unpacked
-  and checked on the accelerator).
+  ``route`` = ``copy`` (checked planes copied into the slot, no limb row: a
+  v2 body's own, or the ones the parse wrote from a v1 body where the slots
+  are byte planes) | ``relayout`` (through uint32 limb rows and the plane
+  pack; for a v2 body the transposing fallback before them) | ``device``
+  (wire ingest: unpacked and checked on the accelerator).
 - the same by bodies, for the log line a round (``since_last``, printed where
   the first Sum2 message arrives) and for ``/healthz`` ``device.fold.wire``
   (``last_batch``: the fold batch closed last).
@@ -28,7 +29,7 @@ BYTES = get_registry().counter(
     "xaynet_update_wire_bytes_total",
     "Element-block bytes of staged Update vectors, by the wire they came on "
     "(packed = v2 byte-planar, legacy = v1 interleaved) and the route to the "
-    "staging slot: copy = planes copied in, no intermediate; relayout = through "
+    "staging slot: copy = checked planes copied in, no limb row; relayout = through "
     "limb rows and the plane pack; device = wire ingest (telemetry/wire.py).",
     ("wire", "route"),
 )

@@ -18,7 +18,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 13
+_ABI_VERSION = 14
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -226,6 +226,18 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint64,  # bytes of each plane to copy
         ]
         lib.xn_copy_planes.restype = None
+        # ABI 14: a wire v1 (interleaved) vector relaid to checked byte
+        # planes in one pass
+        lib.xn_wire_to_planes.argtypes = [
+            u8p,
+            ctypes.c_uint64,  # count elements
+            ctypes.c_uint32,  # bpn
+            u8p,
+            ctypes.c_uint64,  # plane stride (bytes)
+            u8p,  # the order, bpn little-endian bytes; NULL admits all
+            ctypes.c_uint32,  # n_threads (0 = process default)
+        ]
+        lib.xn_wire_to_planes.restype = ctypes.c_uint64
         lib.xn_fold_wire_nlimb.argtypes = list(lib.xn_fold_wire_u64.argtypes)
         lib.xn_fold_wire_nlimb.restype = ctypes.c_int
         # the REST server's direct body read (ABI 9): poll + recv in C, one
