@@ -25,6 +25,7 @@
 #include <vector>
 
 #include <poll.h>
+#include <sys/resource.h>
 #include <sys/socket.h>
 
 #ifdef __AVX2__
@@ -390,6 +391,40 @@ unsigned fold_threads() {
   return cached;
 }
 
+// What the worker threads started and joined by one thread have spent, in
+// total since that thread began (ABI 15): user and system microseconds, minor
+// and major page faults, voluntary and involuntary context switches. A worker
+// is a fresh thread, so the kernel's count for it at its end is what it spent,
+// its own stack's first touch included; the thread that joins it adds that to
+// its own tally, which a caller that times a stage reads beside its own
+// getrusage (telemetry/tracing.py: the calling thread's reading alone would
+// say that a copy on sixteen workers cost nothing and faulted nothing in).
+thread_local uint64_t tl_workers_spent[6] = {0, 0, 0, 0, 0, 0};
+
+struct WorkerTally {
+  std::atomic<uint64_t> v[6];
+  WorkerTally() {
+    for (auto& x : v) x.store(0, std::memory_order_relaxed);
+  }
+  // on a worker, at its end
+  void add_this_thread() {
+#ifdef RUSAGE_THREAD
+    struct rusage ru;
+    if (getrusage(RUSAGE_THREAD, &ru) != 0) return;
+    const uint64_t now[6] = {
+        (uint64_t)ru.ru_utime.tv_sec * 1000000ull + (uint64_t)ru.ru_utime.tv_usec,
+        (uint64_t)ru.ru_stime.tv_sec * 1000000ull + (uint64_t)ru.ru_stime.tv_usec,
+        (uint64_t)ru.ru_minflt, (uint64_t)ru.ru_majflt,
+        (uint64_t)ru.ru_nvcsw,  (uint64_t)ru.ru_nivcsw};
+    for (int i = 0; i < 6; i++) v[i].fetch_add(now[i], std::memory_order_relaxed);
+#endif
+  }
+  // on the thread that started them, once they are joined
+  void settle() {
+    for (int i = 0; i < 6; i++) tl_workers_spent[i] += v[i].load(std::memory_order_relaxed);
+  }
+};
+
 // Run fn(s0, s1) over contiguous slices of [0, n): the fold's element axis
 // is embarrassingly parallel, so each thread owns a disjoint slice and no
 // merge step exists. Slices align to `align` (the fold's BLOCK size) and a
@@ -413,13 +448,18 @@ void run_sliced(uint64_t n, uint64_t align, F&& fn, unsigned nt_override = 0) {
   chunk = (chunk + align - 1) / align * align;
   std::vector<std::thread> threads;
   threads.reserve(nt);
+  WorkerTally tally;
   for (unsigned t = 0; t < nt; t++) {
     const uint64_t s0 = (uint64_t)t * chunk;
     if (s0 >= n) break;
     const uint64_t s1 = s0 + chunk < n ? s0 + chunk : n;
-    threads.emplace_back([&fn, s0, s1] { fn(s0, s1); });
+    threads.emplace_back([&fn, &tally, s0, s1] {
+      fn(s0, s1);
+      tally.add_this_thread();
+    });
   }
   for (auto& th : threads) th.join();
+  tally.settle();
 }
 
 // The single-pass u64 batch fold over one element slice [s0, s1) of the
@@ -870,9 +910,13 @@ int derive_sum_run(const DeriveSumArgs& a) {
     std::vector<std::thread> threads;
     std::vector<uint32_t> unspawned;
     threads.reserve(nt - 1);
+    WorkerTally tally;
     for (uint32_t t = 1; t < nt; t++) {
       try {
-        threads.emplace_back(worker, t);
+        threads.emplace_back([&worker, &tally, t] {
+          worker(t);
+          tally.add_this_thread();
+        });
       } catch (...) {
         unspawned.push_back(t);  // a worker is complete alone: run it here
       }
@@ -880,6 +924,7 @@ int derive_sum_run(const DeriveSumArgs& a) {
     worker(0);
     for (uint32_t t : unspawned) worker(t);
     for (auto& th : threads) th.join();
+    tally.settle();
   }
 
   // merge the groups, reduce, write uint32[n, L]; `out` may be `acc` itself
@@ -1435,7 +1480,14 @@ XN_EXPORT uint64_t xn_wire_to_planes(const uint8_t* wire, uint64_t count, uint32
   return bad.load();
 }
 
-XN_EXPORT uint32_t xn_abi_version(void) { return 14; }
+XN_EXPORT uint32_t xn_abi_version(void) { return 15; }
+
+// The calling thread's tally of what the workers it started and joined have
+// spent so far (ABI 15; `tl_workers_spent` above): out[0..6) = user us,
+// system us, minor faults, major faults, voluntary and involuntary switches.
+XN_EXPORT void xn_workers_spent(uint64_t* out) {
+  for (int i = 0; i < 6; i++) out[i] = tl_workers_spent[i];
+}
 
 // Fill buf[start, len) from the non-blocking stream socket `fd` within
 // `timeout_s` seconds and return how far buf is filled (ABI 9; the REST
@@ -1622,13 +1674,18 @@ XN_EXPORT int xn_decode_exact(const uint32_t* limbs, uint64_t n, uint32_t n_limb
     return 0;
   }
   std::vector<std::thread> pool;
+  WorkerTally tally;
   uint64_t per = (n + nthreads - 1) / nthreads;
   for (unsigned ti = 0; ti < nthreads; ti++) {
     uint64_t lo = ti * per, hi = lo + per < n ? lo + per : n;
     if (lo >= hi) break;
-    pool.emplace_back(decode_range, lo, hi);
+    pool.emplace_back([&decode_range, &tally, lo, hi] {
+      decode_range(lo, hi);
+      tally.add_this_thread();
+    });
   }
   for (auto& th : pool) th.join();
+  tally.settle();
   return 0;
 }
 

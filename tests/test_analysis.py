@@ -14,6 +14,8 @@ import json
 import sys
 from pathlib import Path
 
+import pytest
+
 REPO = Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
@@ -488,6 +490,41 @@ def test_span_brace_shorthand_rows(tmp_path):
     )
     rows = "| `mod.{one,two}` | mod.py |"
     assert _spans_fixture(tmp_path, code, rows) == []
+
+
+_USAGE_CODE = (
+    "from ..telemetry import tracing as trace\n"
+    "A = trace.declare_span('mod.one', usage='thread')\n"
+    "B = trace.declare_span('mod.two', mirror=True, usage='crew')\n"
+    "C = trace.declare_span('mod.three')\n"
+    "D = trace.declare_span('mod.lone', usage='carrier')\n"
+)
+
+
+@pytest.mark.parametrize("rows,found", [
+    # a group whose names differ says which word is whose; one word is every name's
+    ("| `mod.{one,two,three}` | mod.py | `thread`: one; `crew`: two | work |\n"
+     "| `mod.lone` | mod.py | `carrier` | a wait |", []),
+    # a declared word the row does not give
+    ("| `mod.{one,two,three}` | mod.py | `thread`: one | work |\n"
+     "| `mod.lone` | mod.py | `carrier` | a wait |",
+     ["span 'mod.two' is declared usage='crew' and its row of the DESIGN.md §16 span table "
+      "says '-'"]),
+    # another word than the declared one
+    ("| `mod.{one,two,three}` | mod.py | `thread`: one; `crew`: two | work |\n"
+     "| `mod.lone` | mod.py | `process` | a wait |",
+     ["span 'mod.lone' is declared usage='carrier' and its row of the DESIGN.md §16 span "
+      "table says 'process'"]),
+    # a word on a span that declares none
+    ("| `mod.{one,two,three}` | mod.py | `thread`: one, three; `crew`: two | work |\n"
+     "| `mod.lone` | mod.py | `carrier` | a wait |",
+     ["the span table gives 'mod.three' the usage 'thread' and its declaration has none"]),
+])
+def test_span_usage_words_match_the_table(tmp_path, rows, found):
+    msgs = [f.message for f in _spans_fixture(tmp_path, _USAGE_CODE, rows)]
+    assert len(msgs) == len(found)
+    for want, got in zip(found, msgs):
+        assert want in got
 
 
 # --- secret-flow taint pass (ISSUE 14) ---------------------------------------

@@ -17,6 +17,13 @@ machine-checkable legs, mirrored here as rule ``span``:
 3. **DESIGN-table parity** — the declared name set matches the §16 span
    table between ``<!-- span-table:begin -->`` / ``<!-- span-table:end -->``
    markers, both directions (the metrics-table cross-check idiom).
+4. **usage parity** — a span declared ``declare_span(..., usage="word")``
+   (whose CPU seconds, page faults and context switches its brackets read)
+   carries that word in its row's ``Usage`` cell, and a row names no usage
+   that the declaration does not have. The cell is ``-``, one word in
+   backticks for every name of the row, or ``word: member, member; word:
+   member`` where the names of a ``{...}`` group differ (a member is the
+   part of the name after its last dot).
 
 The pass is lexical + single-module-resolution only: a span-name argument
 may be a literal (checked against the declared set) or a reference to a
@@ -46,23 +53,46 @@ def _expand(token: str) -> list[str]:
     return [name for part in group.split(",") for name in _expand(before + part + after)]
 
 
-def documented(design_text: str) -> dict[str, int]:
-    """span name -> first documenting line, from marked table rows."""
-    out: dict[str, int] = {}
+def _table_rows(design_text: str):
+    """``(line number, line)`` of the rows between the span-table markers."""
     active = False
     for i, line in enumerate(design_text.splitlines(), 1):
         if _BEGIN in line:
             active = True
-            continue
-        if _END in line:
+        elif _END in line:
             active = False
-            continue
-        if not active or not line.lstrip().startswith("|"):
-            continue
+        elif active and line.lstrip().startswith("|"):
+            yield i, line
+
+
+def documented(design_text: str) -> dict[str, int]:
+    """span name -> first documenting line, from marked table rows."""
+    out: dict[str, int] = {}
+    for i, line in _table_rows(design_text):
         for token in _TOKEN_RE.findall(line):
             for name in _expand(token):
                 if "." in name or name == "round":  # span names, not prose
                     out.setdefault(name, i)
+    return out
+
+
+_USAGE_RE = re.compile(r"`([a-z]+)`(?:\s*:\s*([a-z0-9_, ]+))?")
+
+
+def documented_usage(design_text: str) -> dict[str, str]:
+    """span name -> the usage word its row gives it (rows whose third cell
+    is a usage cell; names with ``-`` or no word are left out)."""
+    out: dict[str, str] = {}
+    for _, line in _table_rows(design_text):
+        cells = line.split("|")
+        if len(cells) < 5:
+            continue
+        names = [n for token in _TOKEN_RE.findall(cells[1]) for n in _expand(token)]
+        for word, members in _USAGE_RE.findall(cells[3]):
+            chosen = {m.strip() for m in members.split(",") if m.strip()}
+            for name in names:
+                if not chosen or name.rsplit(".", 1)[-1] in chosen:
+                    out[name] = word
     return out
 
 
@@ -87,6 +117,7 @@ class _ModuleScan(ast.NodeVisitor):
 
     def __init__(self):
         self.declares: list[tuple[str | None, int]] = []  # (literal name | None, line)
+        self.usages: dict[str, tuple[str, int]] = {}  # name -> (usage word, line)
         self.span_calls: list[ast.Call] = []
         self.with_items: set[int] = set()  # id() of context expressions
         self._tracer_names: set[str] = set()
@@ -116,6 +147,11 @@ class _ModuleScan(ast.NodeVisitor):
             ):
                 name = node.args[0].value
             self.declares.append((name, node.lineno))
+            for kw in node.keywords:
+                if kw.arg == "usage" and isinstance(kw.value, ast.Constant) and isinstance(
+                    kw.value.value, str
+                ) and name is not None:
+                    self.usages[name] = (kw.value.value, node.lineno)
         func = node.func
         if isinstance(func, ast.Attribute) and func.attr == "span":
             value = func.value
@@ -129,6 +165,7 @@ class _ModuleScan(ast.NodeVisitor):
 def run(files: list[FileInfo], design_path) -> list[Finding]:
     findings: list[Finding] = []
     declares: dict[str, list[tuple[str, int]]] = {}  # name -> [(rel, line)]
+    usages: dict[str, tuple[str, str, int]] = {}  # name -> (usage word, rel, line)
     scans: list[tuple[FileInfo, _ModuleScan]] = []
     for info in files:
         if info.tree is None or not info.rel.startswith("xaynet_tpu/"):
@@ -136,6 +173,8 @@ def run(files: list[FileInfo], design_path) -> list[Finding]:
         scan = _ModuleScan()
         scan.visit(info.tree)
         scans.append((info, scan))
+        for name, (word, line) in scan.usages.items():
+            usages[name] = (word, info.rel, line)
         for name, line in scan.declares:
             if name is None:
                 if not suppressed("span", info.line(line)):
@@ -232,6 +271,30 @@ def run(files: list[FileInfo], design_path) -> list[Finding]:
                     line,
                     f"documented span '{name}' is not declared anywhere "
                     "under xaynet_tpu/ (stale table row?)",
+                )
+            )
+    told = documented_usage(design_text)
+    for name, (word, rel, line) in sorted(usages.items()):
+        if name in docs and told.get(name) != word:
+            findings.append(
+                Finding(
+                    "span",
+                    rel,
+                    line,
+                    f"span '{name}' is declared usage='{word}' and its row of the "
+                    f"DESIGN.md §16 span table says '{told.get(name, '-')}' in the "
+                    "Usage cell",
+                )
+            )
+    for name, word in sorted(told.items()):
+        if name in declares and name not in usages:
+            findings.append(
+                Finding(
+                    "span",
+                    "docs/DESIGN.md",
+                    docs.get(name, 1),
+                    f"the span table gives '{name}' the usage '{word}' and its "
+                    "declaration has none",
                 )
             )
     return findings

@@ -21,7 +21,12 @@ Prints one JSON line: {updates_per_s_on, updates_per_s_off, overhead_pct,
 span_cost_us, span_cost_mirrored_us (the mirror sink set, no profiler
 session), unmask_bracket_on_us / unmask_bracket_off_us (one stage bracket of
 ``telemetry/unmask.py``: span + histogram observation, and the observation
-alone with the tracer off), timeline_fold_us, timeline_fold_pct_of_window, ...}.
+alone with the tracer off), usage_bracket_us (what ``usage="thread"`` adds to
+one such bracket: two ``getrusage`` calls, the differences onto the four
+``xaynet_span_*`` counters and into the span), usage_bracket_crew_us (the
+same for ``usage="crew"``: with the native workers' tally read twice),
+getrusage_us (one ``getrusage(RUSAGE_THREAD)`` on this box: a bracket makes two),
+timeline_fold_us, timeline_fold_pct_of_window, ...}.
 """
 
 from __future__ import annotations
@@ -180,6 +185,42 @@ def main() -> None:
     finally:
         tracer.configure(mode="on")
 
+    # what reading the thread's usage adds to such a bracket: the same
+    # `timed_span` on a name declared with `usage` against one declared
+    # without, interleaved, the least of several passes of each (the box's
+    # drift is larger than the difference)
+    from xaynet_tpu.utils import native
+
+    native.load()  # a crew's tally is the library's
+    usage_probes = {"none": "trace.overhead_probe_usage_none",
+                    "thread": "trace.overhead_probe_usage_thread",
+                    "crew": "trace.overhead_probe_usage_crew"}
+    for word, probe_name in usage_probes.items():
+        if probe_name not in name:
+            tracing.declare_span(probe_name, usage=None if word == "none" else word)
+    seconds = unmask_stages.SECONDS.labels(stage="retire")
+    passes: dict[str, list[float]] = {word: [] for word in usage_probes}
+    for _ in range(9):
+        for word, probe_name in usage_probes.items():
+            t0 = time.perf_counter()
+            for _ in range(n_probe // 4):
+                with tracing.timed_span(probe_name, seconds):
+                    pass
+            passes[word].append((time.perf_counter() - t0) / (n_probe // 4) * 1e6)
+    usage_bracket_us = min(passes["thread"]) - min(passes["none"])
+    # the floor under it on this box: the system call itself, twice a bracket
+    import resource
+
+    who = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+    calls = []
+    for _ in range(9):
+        t0 = time.perf_counter()
+        for _ in range(n_probe // 4):
+            resource.getrusage(who)
+        calls.append((time.perf_counter() - t0) / (n_probe // 4) * 1e6)
+    getrusage_us = min(calls)
+    usage_bracket_crew_us = min(passes["crew"]) - min(passes["none"])
+
     # the always-on timeline fold (DESIGN §20): one O(n) pass per round
     # over the span buffer. Time it on a synthetic buffer shaped like a
     # real round (phase spans + streaming children, half the 8192 cap) and
@@ -234,6 +275,9 @@ def main() -> None:
                 "span_cost_mirrored_us": round(span_cost_mirrored_us, 2),
                 "unmask_bracket_on_us": round(bracket_on_us, 2),
                 "unmask_bracket_off_us": round(bracket_off_us, 2),
+                "usage_bracket_us": round(usage_bracket_us, 2),
+                "usage_bracket_crew_us": round(usage_bracket_crew_us, 2),
+                "getrusage_us": round(getrusage_us, 2),
                 "timeline_fold_us": round(fold_cost_us, 2),
                 "timeline_fold_spans": len(buffer),
                 "timeline_fold_pct_of_window": round(fold_pct_of_window, 4),

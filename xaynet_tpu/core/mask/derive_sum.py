@@ -97,6 +97,14 @@ def _grain(
     return threads, groups, max(_MIN_SEGMENT, min(segment, _MAX_SEGMENT))
 
 
+def derive_threads(k: int, length: int, order: int) -> int:
+    """The threads ``derive_sum_vect`` gives the native pass for ``k`` seeds
+    of ``length`` draws below ``order``, left to itself (what a reader of the
+    derive's CPU seconds sets them against)."""
+    bpn = limb_ops.draw_width_for(order)
+    return _grain(length, k, bpn, order, accumulator_plan(order, k)[0], host_threads())[0]
+
+
 def derive_sum_vect(
     seeds: list[bytes],
     offsets: list[int],

@@ -28,8 +28,8 @@ from .message import HEADER_LENGTH, Message
 from .payloads import CHUNK_HEADER_LENGTH, Chunk
 
 # the two passes of composing a part, under the sender's ``message.compose``
-SPAN_SERIALISE = trace.declare_span("message.serialise")
-SPAN_SIGN = trace.declare_span("message.sign")
+SPAN_SERIALISE = trace.declare_span("message.serialise", usage="thread")
+SPAN_SIGN = trace.declare_span("message.sign", usage="thread")
 
 # minimum sensible ceiling: header + chunk header + 1 byte of progress
 # (reference: rust/xaynet-sdk/src/settings/max_message_size.rs:4-80)

@@ -34,11 +34,12 @@ from ..core.crypto.encrypt import (
     PublicEncryptKey,
 )
 from ..core.crypto.sign import SigningKeyPair, is_eligible
-from ..core.mask.derive_sum import derive_and_sum
+from ..core.mask.derive_sum import derive_and_sum, derive_threads
 from ..core.mask.masking import Masker, check_nb_models
 from ..core.mask.model import Scalar
 from ..core.mask.object import MaskObject, MaskUnit, MaskVect
 from ..core.message import Message, Sum, Sum2, Update
+from ..core.message.message import Tag
 from ..core.message.encoder import DEFAULT_MAX_MESSAGE_SIZE, MIN_MESSAGE_SIZE, MessageEncoder
 from ..telemetry import codec, tracing as trace
 from ..utils import native
@@ -49,14 +50,21 @@ logger = logging.getLogger("xaynet.participant")
 # one part of a message composed for sending (serialise and sign are the
 # encoder's spans, under it); the seal is its last step
 SPAN_COMPOSE = trace.declare_span("message.compose")
-SPAN_SEAL = trace.declare_span("message.seal")
-# the sum participant's two steps before it composes its Sum2 message: the
-# seed dictionary fetched and every seed box opened (recorded when it ends:
-# the polls before the dictionary is served leave no span), then the masks derived
-# and summed (attrs: masks = the seeds opened, elements, route; an attribute
-# with "seed" in its name would leave the process redacted)
+SPAN_SEAL = trace.declare_span("message.seal", usage="thread")
+# the sum participant's leg of the round's tail, step by step. Its polls for
+# the seed dictionary (recorded when they end: the first one asked -> the
+# one that was answered asked; a poll that found nothing leaves no span of
+# its own), the dictionary fetched and every seed box opened (recorded when
+# it ends), the masks derived and summed (attrs: masks = the seeds opened,
+# elements, route, threads = what the native derive ran on; an attribute
+# with "seed" in its name would leave the process redacted; its usage is the
+# process's: the derive's threads are its own, and a participant does
+# nothing beside it), then `message.compose`, and the POST of the Sum2
+# message from its first byte to the coordinator's answer
+SPAN_AWAIT_PHASE = trace.declare_span("sum2.await_phase")
 SPAN_OPEN_SEEDS = trace.declare_span("sum2.open_seeds")
-SPAN_DERIVE = trace.declare_span("sum2.derive")
+SPAN_DERIVE = trace.declare_span("sum2.derive", usage="process")
+SPAN_SEND = trace.declare_span("sum2.send")
 
 
 def _derived_by_route() -> dict[str, float]:
@@ -260,6 +268,8 @@ class StateMachine:
         # Delivered parts are never re-sent.
         self._pending: Optional[_PendingSend] = None
         self._after_send_phase: Optional[PhaseKind] = None
+        # when this round's first poll for the seed dictionary was made
+        self._seeds_asked_since: Optional[float] = None
 
     # --- driving ----------------------------------------------------------
 
@@ -326,6 +336,7 @@ class StateMachine:
             raise
 
     def _reset_round_state(self) -> None:
+        self._seeds_asked_since = None
         self.task = Task.NONE
         self.sum_signature = None
         self.update_signature = None
@@ -429,9 +440,15 @@ class StateMachine:
         assert self.round_params is not None and self.ephm_keys is not None
         tracer = trace.get_tracer()
         asked = time.monotonic()
+        # getattr: tests build bare machines with __new__
+        if getattr(self, "_seeds_asked_since", None) is None:
+            self._seeds_asked_since = asked
         seeds = await self.client.get_seeds(self.keys.public)
         if not seeds:
             return TransitionOutcome.PENDING  # a poll that found nothing leaves no span
+        tracer.record_span(SPAN_AWAIT_PHASE, start=self._seeds_asked_since,
+                           duration=asked - self._seeds_asked_since)
+        self._seeds_asked_since = None
         mask_seeds = [
             encrypted.decrypt(self.ephm_keys.secret, self.ephm_keys.public)
             for encrypted in seeds.values()
@@ -448,7 +465,13 @@ class StateMachine:
             # `fused`, `fast` or `generic` on the host (the unit's one
             # element always goes `fast`); none of them moves on a device
             moved = {r: n - before.get(r, 0) for r, n in _derived_by_route().items()}
-            span.set(route=max(moved, key=moved.get) if any(moved.values()) else "device")
+            route = max(moved, key=moved.get) if any(moved.values()) else "device"
+            span.set(route=route)
+            if route == "fused":
+                # what the span's CPU seconds are to be set against: the
+                # threads the native derive ran on (every other route runs a
+                # pool of its own making, and says nothing here)
+                span.set(threads=derive_threads(len(mask_seeds), length, config.vect.order))
 
         payload = Sum2(sum_signature=self.sum_signature, model_mask=mask_obj)
         return await self._send(payload, PhaseKind.AWAITING)
@@ -530,7 +553,14 @@ class StateMachine:
         while pending.next_index < pending.encoder.n_parts:
             sealed = pending.sealed_part()
             try:
-                await self.client.send_message(sealed)
+                if pending.encoder.message.tag == Tag.SUM2:
+                    # the tail of a round waits for this POST: first byte
+                    # to the coordinator's answer
+                    with trace.get_tracer().span(SPAN_SEND, part=pending.next_index,
+                                                 bytes=len(sealed)):
+                        await self.client.send_message(sealed)
+                else:
+                    await self.client.send_message(sealed)
             except asyncio.CancelledError:
                 raise
             except Exception as e:
@@ -637,8 +667,6 @@ class StateMachine:
             machine.round_params = RoundParameters.from_dict(d["round_params"])
         ps = d.get("pending_send")
         if ps and machine.round_params is not None:
-            from ..core.message.message import Tag
-
             message = Message(
                 participant_pk=machine.keys.public,
                 coordinator_pk=machine.round_params.pk,

@@ -156,10 +156,13 @@ class UpdatePhase(PhaseState):
             # (ordering is preserved: the await completes before the
             # seed-dict insert below)
             wire, route = wire_route(req.masked_model.vect)
-            with stages.stage("validate", wire=wire, route=route):
-                await asyncio.get_running_loop().run_in_executor(
-                    None, self.aggregator.validate_aggregation, req.masked_model
-                )
+            # (the scan's CPU and faults are read where it runs, on the
+            # executor's thread, and handed back for the span)
+            with stages.stage("validate", wire=wire, route=route) as span:
+                span.set(**await asyncio.get_running_loop().run_in_executor(
+                    None, stages.carried, "validate",
+                    self.aggregator.validate_aggregation, req.masked_model,
+                ))
         except AggregationError as err:
             raise RequestError(RequestError.Kind.MESSAGE_REJECTED, err.kind) from err
         with stages.stage("seed_dict"):
@@ -174,8 +177,10 @@ class UpdatePhase(PhaseState):
             # fold off the event loop so the API stays responsive during
             # large folds; handle_request awaits it, so folds serialize.
             # The message that fills the batch pays for its flush.
-            with stages.stage("flush", k=self.aggregator.pending):
-                await asyncio.get_running_loop().run_in_executor(None, self.aggregator.flush)
+            with stages.stage("flush", k=self.aggregator.pending) as span:
+                span.set(**await asyncio.get_running_loop().run_in_executor(
+                    None, stages.carried, "flush", self.aggregator.flush
+                ))
             if self._ckpt is not None:
                 await self._ckpt.maybe_save()
         # chaos hook (kill-matrix harness): dies BEFORE the ack leaves, so

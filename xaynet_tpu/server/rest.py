@@ -113,7 +113,8 @@ _KNOWN_PATHS = {"/message", "/params", "/sums", "/seeds", "/model",
 _KNOWN_METHODS = {"GET", "POST", "HEAD", "PUT", "DELETE", "OPTIONS", "PATCH"}
 
 
-def _recv_exactly(sock: socket.socket, body: bytearray, start: int, deadline: float) -> int:
+def _recv_exactly(sock: socket.socket, body: bytearray, start: int, deadline: float,
+                  spent: dict | None = None) -> int:
     """Fill ``body[start:]`` from ``sock`` (non-blocking: it shares the
     transport's open file) and return how far ``body`` is filled: short
     when the peer closed or reset, when ``deadline`` (``time.monotonic()``)
@@ -121,33 +122,39 @@ def _recv_exactly(sock: socket.socket, body: bytearray, start: int, deadline: fl
     Runs on a ``rest-body`` thread, which owns ``sock`` and closes it. The
     native library loops ``recv`` and ``poll`` with the interpreter lock
     released once for the whole body; without it the same loop runs here and
-    takes the lock back after each of a body's several hundred calls."""
+    takes the lock back after each of a body's several hundred calls.
+
+    This thread does the work of the message's ``read_body`` stage, which the
+    loop opened around its wait for it, so what the stage spent is read here
+    and left in ``spent``: CPU is ``recv``'s copy and the first touch of the
+    buffer's pages, wall less CPU the wait in ``poll`` for the sender's bytes."""
     try:
-        lib = native.load()
-        if lib is not None:
-            first = ctypes.c_uint8.from_buffer(body)  # pins the buffer for the call
-            return lib.xn_recv_exactly(
-                sock.fileno(), ctypes.byref(first), start, len(body),
-                deadline - time.monotonic(),
-            )
-        view = memoryview(body)
-        poller = select.poll()
-        poller.register(sock, select.POLLIN)
-        got = start
-        while got < len(body):
-            try:
-                n = sock.recv_into(view[got:])
-            except (BlockingIOError, InterruptedError):
-                left = deadline - time.monotonic()
-                if left <= 0 or not poller.poll(left * 1000.0):
+        with stages.usage("read_body", spent=spent):
+            lib = native.load()
+            if lib is not None:
+                first = ctypes.c_uint8.from_buffer(body)  # pins the buffer for the call
+                return lib.xn_recv_exactly(
+                    sock.fileno(), ctypes.byref(first), start, len(body),
+                    deadline - time.monotonic(),
+                )
+            view = memoryview(body)
+            poller = select.poll()
+            poller.register(sock, select.POLLIN)
+            got = start
+            while got < len(body):
+                try:
+                    n = sock.recv_into(view[got:])
+                except (BlockingIOError, InterruptedError):
+                    left = deadline - time.monotonic()
+                    if left <= 0 or not poller.poll(left * 1000.0):
+                        break
+                    continue
+                except OSError:
+                    break  # reset by the peer
+                if n == 0:
                     break
-                continue
-            except OSError:
-                break  # reset by the peer
-            if n == 0:
-                break
-            got += n
-        return got
+                got += n
+            return got
     finally:
         sock.close()
 
@@ -163,13 +170,15 @@ def _abort_read(sock: socket.socket) -> None:
 
 class _OverflowBody:
     """One body the ``rest-overflow`` thread is receiving: ``view`` is filled
-    as far as ``got``; ``sock`` is ``None`` once the thread has let it go."""
+    as far as ``got``; ``sock`` is ``None`` once the thread has let it go;
+    ``spent`` is what the thread's turns at this body have spent so far."""
 
-    __slots__ = ("sock", "view", "got", "deadline", "loop", "done")
+    __slots__ = ("sock", "view", "got", "deadline", "loop", "done", "spent")
 
-    def __init__(self, sock, body: bytearray, start: int, deadline: float, loop, done):
+    def __init__(self, sock, body: bytearray, start: int, deadline: float, loop, done, spent):
         self.sock, self.view, self.got = sock, memoryview(body), start
         self.deadline, self.loop, self.done = deadline, loop, done
+        self.spent = {} if spent is None else spent
 
 
 class _OverflowReader:
@@ -197,14 +206,16 @@ class _OverflowReader:
         threading.Thread(target=self._run, name="rest-overflow", daemon=True).start()
 
     def receive(
-        self, sock: socket.socket, body: bytearray, start: int, deadline: float
+        self, sock: socket.socket, body: bytearray, start: int, deadline: float,
+        spent: dict | None = None,
     ) -> "asyncio.Future[int]":
         """Hand ``sock`` to the thread, to fill ``body[start:]`` by
-        ``deadline`` (``time.monotonic()``). Called on the request's loop."""
+        ``deadline`` (``time.monotonic()``). Called on the request's loop.
+        ``spent`` is filled as ``_recv_exactly`` fills it."""
         loop = asyncio.get_running_loop()
         done = loop.create_future()
         self._holds.inc()
-        self._arrivals.append(_OverflowBody(sock, body, start, deadline, loop, done))
+        self._arrivals.append(_OverflowBody(sock, body, start, deadline, loop, done, spent))
         self._wake()
         return done
 
@@ -262,15 +273,24 @@ class _OverflowReader:
     def _advance(self, body: _OverflowBody) -> None:
         """``body``'s socket is readable: one ``recv_into`` of what is there,
         never past the body's end nor over a turn's bytes. A short count says
-        the socket is drained; the selector says when it no longer is."""
+        the socket is drained; the selector says when it no longer is.
+
+        The thread serves many bodies, so what the ``read_body`` stage spent
+        is read a turn at a time, around the ``recv_into`` alone, under the
+        stage's name; the message counts once, when its body is whole. CPU,
+        faults and switches are a body's own; the select between its turns
+        is nobody's, so wall less CPU holds the other bodies' turns too."""
         end = min(len(body.view), body.got + OVERFLOW_TURN_BYTES)
         try:
-            n = body.sock.recv_into(body.view[body.got:end])
+            with stages.usage("read_body", count=False, spent=body.spent):
+                n = body.sock.recv_into(body.view[body.got:end])
         except (BlockingIOError, InterruptedError):
             return
         except OSError:
             n = 0  # reset by the peer
         body.got += n
+        if body.got == len(body.view):
+            stages.counted("read_body")
         if n == 0 or body.got == len(body.view):
             self._finish(body)
 
@@ -483,8 +503,8 @@ class RestServer:
                     await self._respond(writer, 413, b"body too large")
                     break
 
-                async def read_body(length=length) -> bytes | bytearray:
-                    return await self._read_body(reader, writer, length)
+                async def read_body(span=None, length=length) -> bytes | bytearray:
+                    return await self._read_body(reader, writer, length, span)
 
                 keep_alive = headers.get("connection", "keep-alive").lower() != "close"
                 status, payload, ctype, extra = await self._route(
@@ -504,7 +524,8 @@ class RestServer:
                 pass
 
     async def _read_body(
-        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int
+        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter, length: int,
+        span=None,
     ) -> bytes | bytearray:
         """The request's body, whole within ``read_timeout`` or an exception
         that drops the connection unanswered. One algorithm (receive
@@ -512,7 +533,9 @@ class RestServer:
         on plain TCP goes straight from the socket into one buffer, on a
         ``rest-body`` thread while one is free and on the ``rest-overflow``
         thread beside the others there while none is; everything else
-        through the StreamReader on the loop. Never reads past the body."""
+        through the StreamReader on the loop. Never reads past the body.
+        ``span`` is the message's ``read_body`` stage, which gets what the
+        thread that received the body spent on it."""
         if not length:
             return b""
         sock, reason = self._direct_socket(reader, writer, length)
@@ -527,6 +550,7 @@ class RestServer:
         # reaches the StreamReader between here and resume_reading()
         transport.pause_reading()
         body = native.uninitialised_bytearray(None, length)
+        spent: dict = {}
         buffered = len(reader._buffer)
         if buffered:
             # the segment that carried the headers carried these; the buffer
@@ -539,13 +563,13 @@ class RestServer:
                 self._body_pool = ThreadPoolExecutor(BODY_READERS, thread_name_prefix="rest-body")
             route, reads = "direct", self._direct_reads
             whole = asyncio.get_running_loop().run_in_executor(
-                self._body_pool, _recv_exactly, sock, body, buffered, deadline
+                self._body_pool, _recv_exactly, sock, body, buffered, deadline, spent
             )
         else:
             if self._overflow is None:
                 self._overflow = _OverflowReader(self._intake.overflow_bodies)
             route, reads = "overflow", self._overflow_reads
-            whole = self._overflow.receive(sock, body, buffered, deadline)
+            whole = self._overflow.receive(sock, body, buffered, deadline, spent)
         reads.add(sock)
         try:
             got = await whole
@@ -557,6 +581,8 @@ class RestServer:
         if got < length:  # closed, reset, aborted or out of time: all drop the connection
             raise asyncio.IncompleteReadError(b"", length)
         transport.resume_reading()
+        if span is not None:
+            span.set(**spent)
         self._body_bytes.labels(route=route).inc(length)
         self._intake.read(route, reason)
         return body
@@ -695,8 +721,8 @@ class RestServer:
                     if read_body is not None:
                         with stages.stage(
                             "read_body", bytes=int(headers.get("content-length", "0"))
-                        ):
-                            body = await read_body()
+                        ) as reading:
+                            body = await read_body(reading)
                     with stages.use_held(held):
                         result = await self._dispatch(
                             method, path, url.query, body, headers, routes

@@ -74,21 +74,29 @@ SECONDS = get_registry().histogram(
 
 # stage label -> span name; spelled out (not built in a loop) so the
 # analysis `span` pass reads the literal set against the DESIGN §16 table
+# (`usage`: whose CPU seconds, page faults and context switches the stage's
+# brackets read, `telemetry/tracing.py`. `thread` = the work runs on the
+# opening thread from entry to exit; `crew` = on it and on the workers the
+# native library starts for it over the element axis (a v1 body's relayout
+# and a v2 body's check in the parse, the slot's plane copy); `carrier` =
+# the stage is opened on the loop around an `await` while another thread
+# works, which reads itself and its crew under the stage's name: `carried`,
+# `usage`)
 _SPANS: dict[str, str] = {
-    "read_body": trace.declare_span("rest.read_body", mirror=True),
+    "read_body": trace.declare_span("rest.read_body", mirror=True, usage="carrier"),
     "pool_wait": trace.declare_span("pipeline.pool_wait"),
-    "open": trace.declare_span("pipeline.open", mirror=True),
-    "verify": trace.declare_span("pipeline.verify", mirror=True),
-    "verify_beside": trace.declare_span("pipeline.verify_beside", mirror=True),
-    "parse": trace.declare_span("pipeline.parse", mirror=True),
+    "open": trace.declare_span("pipeline.open", mirror=True, usage="thread"),
+    "verify": trace.declare_span("pipeline.verify", mirror=True, usage="thread"),
+    "verify_beside": trace.declare_span("pipeline.verify_beside", mirror=True, usage="thread"),
+    "parse": trace.declare_span("pipeline.parse", mirror=True, usage="crew"),
     "resume_wait": trace.declare_span("pipeline.resume_wait"),
     "request_wait": trace.declare_span("update.request_wait"),
-    "validate": trace.declare_span("update.validate", mirror=True),
+    "validate": trace.declare_span("update.validate", mirror=True, usage="carrier"),
     "seed_dict": trace.declare_span("update.seed_dict", mirror=True),
-    "stage": trace.declare_span("update.stage", mirror=True),
-    "to_planar": trace.declare_span("update.to_planar", mirror=True),
-    "flush": trace.declare_span("update.flush", mirror=True),
-    "score": trace.declare_span("sum2.score", mirror=True),
+    "stage": trace.declare_span("update.stage", mirror=True, usage="thread"),
+    "to_planar": trace.declare_span("update.to_planar", mirror=True, usage="crew"),
+    "flush": trace.declare_span("update.flush", mirror=True, usage="carrier"),
+    "score": trace.declare_span("sum2.score", mirror=True, usage="thread"),
     "verdict_wait": trace.declare_span("update.verdict_wait"),
 }
 # the state machine with nothing to do: waiting for the next request
@@ -148,6 +156,29 @@ def stage(label: str, ctx: Optional[trace.TraceContext] = None,
     phase = attrs.setdefault("phase", _phase.get())
     return trace.timed_span(_SPANS[label], SECONDS.labels(stage=label, phase=phase),
                             ctx=ctx, link=link, **attrs)
+
+
+def usage(label: str, count: bool = True, spent: Optional[dict] = None):
+    """What the calling thread spends inside the block, credited to stage
+    ``label`` (``tracing.usage_of``): for the thread that does the work of a
+    stage opened elsewhere. ``count=False`` where one message's stage takes
+    several blocks; :func:`counted` then counts the message, once."""
+    return trace.usage_of(_SPANS[label], count, spent)
+
+
+def counted(label: str) -> None:
+    """One message's stage ``label`` has its usage on the counters."""
+    trace.count_usage(_SPANS[label])
+
+
+def carried(label: str, work, *args) -> dict:
+    """Run ``work(*args)`` on this thread, an executor's, for a stage
+    ``label`` that the loop opened around the ``await``: what the thread
+    spent is counted under the stage's name and returned, for the span's
+    attributes."""
+    with usage(label) as spent:
+        work(*args)
+    return spent
 
 
 def waited(label: str, since: float, ctx: Optional[trace.TraceContext] = None,

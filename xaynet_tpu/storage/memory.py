@@ -341,8 +341,16 @@ class FileCoordinatorStorage(InMemoryCoordinatorStorage):
         # model-sized sections: the file writes go through the executor so
         # the event loop keeps serving the API during a checkpoint
         await asyncio.get_running_loop().run_in_executor(
-            None, self._write_ckpt, head, sections
+            None, self._write_ckpt_counted, head, sections
         )
+
+    def _write_ckpt_counted(self, head: bytes, sections=()) -> None:
+        """``_write_ckpt`` on its executor thread, which says what it spent
+        under the ``store`` stage that the loop opened around the wait."""
+        from ..telemetry import journal
+
+        with journal.writing():
+            self._write_ckpt(head, sections)
 
     def _write_ckpt(self, head: bytes, sections=()) -> None:
         """Sections first, each under a temporary name until it is whole;

@@ -97,12 +97,17 @@ WRITES = _registry.counter(
 
 # stage label -> span name; spelled out (not built in a loop) so the
 # analysis `span` pass reads the literal set against the DESIGN §16 table
+# (`usage`, telemetry/tracing.py: `dicts` is the loop thread's own work and
+# `serialise` its executor thread's, entry to exit: the SHA-256 runs on the
+# thread that calls it. `store` is opened on the loop around the store's
+# `await`; the file store writes on an executor thread, which reads itself
+# under the stage's name: `writing`)
 _SPANS: dict[str, str] = {
     "drain": trace.declare_span("journal.drain", mirror=True),
     "fetch": trace.declare_span("journal.fetch", mirror=True),
-    "dicts": trace.declare_span("journal.dicts", mirror=True),
-    "serialise": trace.declare_span("journal.serialise", mirror=True),
-    "store": trace.declare_span("journal.store", mirror=True),
+    "dicts": trace.declare_span("journal.dicts", mirror=True, usage="thread"),
+    "serialise": trace.declare_span("journal.serialise", mirror=True, usage="thread"),
+    "store": trace.declare_span("journal.store", mirror=True, usage="carrier"),
     "total": trace.declare_span("journal.total"),
 }
 _RESUME_SPANS: dict[str, str] = {
@@ -175,6 +180,13 @@ def write(phase: str, **attrs):
                 tally[phase] = tally.get(phase, 0) + 1
                 _last = {"phase": phase, "outcome": w.outcome, "bytes": w.bytes,
                          **w.routes, "seconds": round(time.monotonic() - t0, 6)}
+
+
+def writing():
+    """What the calling thread spends inside the block, credited to the
+    ``store`` stage (``tracing.usage_of``): for the thread that writes an
+    entry's files while the loop, which opened the stage, waits for it."""
+    return trace.usage_of(_SPANS["store"])
 
 
 def report(enabled: bool, every_batches: int) -> dict:

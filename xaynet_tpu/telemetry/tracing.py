@@ -41,6 +41,29 @@ Spans land in two bounded places:
 ``failure`` (ring only: spans exist for the flight recorder, no per-round
 export), ``off`` (spans are no-ops). Failed/degraded rounds are always
 covered by the ring regardless of sampling — the ring never samples.
+
+What a span's thread spent
+--------------------------
+
+A wall clock cannot tell a stage that worked from one that waited, faulted
+pages in or stood without a core. The kernel keeps all of that for every
+thread; a span declared ``declare_span(name, usage=...)`` reads it at entry
+and exit (``getrusage``) and writes the differences down twice, as the
+histograms are: on four counters of the registry (``xaynet_span_*``, by
+span name) and, at its end, in its attributes (``cpu_s``, ``sys_s``,
+``minflt``, ``majflt``, ``nvcsw``, ``nivcsw``). ``"thread"``: the work runs
+on the opening thread from entry to exit. ``"crew"``: on the opening thread
+and on worker threads that the native library starts and joins for it
+inside the span (a copy or a relayout over the element axis), whose tally
+the library keeps for the thread that joined them (``set_workers_reader``).
+``"process"``: it fans out over threads nobody tallies and the process has
+nothing else of that size in flight. ``"carrier"``: the span is opened
+around an ``await`` while another thread works; the span itself reads
+nothing and the thread that works brackets itself with :func:`usage_of`
+under the span's name, its crew included. A bracket closed on another
+thread than it was opened on, and a platform without the call, count
+nothing and raise nothing (docs/DESIGN.md §16, "What a stage's thread
+spent").
 """
 
 from __future__ import annotations
@@ -57,6 +80,11 @@ from contextlib import contextmanager
 from typing import Iterable, Optional
 
 from .registry import get_registry
+
+try:
+    import resource
+except ImportError:  # not on this platform: usage spans count nothing
+    resource = None
 
 logger = logging.getLogger("xaynet.telemetry")
 
@@ -79,6 +107,36 @@ TRACE_EXPORTS = _registry.counter(
     "Per-round Chrome-trace exports, by outcome (written | failed).",
     ("outcome",),
 )
+SPAN_CPU = _registry.counter(
+    "xaynet_span_cpu_seconds_total",
+    "CPU seconds the kernel charged between the entry and the exit of the "
+    "spans declared with usage, by span name and mode (user | system): the "
+    "opening thread's, with its native workers' for a span declared `crew`, "
+    "the whole process's for one declared `process` (telemetry/tracing.py; "
+    "docs/DESIGN.md §16).",
+    ("span", "mode"),
+)
+SPAN_FAULTS = _registry.counter(
+    "xaynet_span_page_faults_total",
+    "Page faults inside the spans declared with usage, by span name and "
+    "kind: minor (a page mapped without I/O: the first touch of fresh "
+    "memory) | major.",
+    ("span", "kind"),
+)
+SPAN_SWITCHES = _registry.counter(
+    "xaynet_span_context_switches_total",
+    "Context switches inside the spans declared with usage, by span name "
+    "and kind: voluntary (the thread slept or waited) | involuntary (it was "
+    "taken off its core).",
+    ("span", "kind"),
+)
+SPAN_USAGE = _registry.counter(
+    "xaynet_span_usage_total",
+    "Spans that recorded their usage, by span name: what the three "
+    "counters beside it are divided by. A stage read on its carrier counts "
+    "one a message, however many brackets its body took.",
+    ("span",),
+)
 
 
 class SpanNameError(ValueError):
@@ -93,18 +151,54 @@ _SPAN_NAMES: dict[str, str] = {}
 # the declaration, never at a call site. Spans that would cover every idle
 # gap whole (``round``, ``phase.*``, ``rest.request``) stay out of it.
 _MIRRORED: set[str] = set()
+# name -> whose usage its brackets read (``thread`` | ``crew`` | ``process``
+# | ``carrier``), and for the names the span itself reads where it opens and
+# closes, how (``_how``: resolved once, a bracket looks nothing else up)
+_USAGE: dict[str, str] = {}
+_USAGE_ON_SPAN: dict[str, tuple] = {}
 _names_lock = threading.Lock()
 
+# whose usage -> the `who` of getrusage; None where the platform has no such call
+_RUSAGE_WHO: dict[str, Optional[int]] = {
+    "thread": getattr(resource, "RUSAGE_THREAD", None),
+    "process": getattr(resource, "RUSAGE_SELF", None),
+}
+_RUSAGE_WHO["crew"] = _RUSAGE_WHO["carrier"] = _RUSAGE_WHO["thread"]
+# the words whose reading takes in the native workers of the thread
+_WITH_WORKERS = frozenset({"crew", "carrier"})
+# () -> what the native workers that the calling thread started and joined
+# have spent so far, as a reading's six numbers; None until the library is
+# loaded (utils/native.py), and a crew is then its thread alone
+_workers = None
 
-def declare_span(name: str, mirror: bool = False) -> str:
+
+def set_workers_reader(reader) -> None:
+    """Install the tally of native worker threads (``xn_workers_spent``).
+    This module stays stdlib-only: the loader of the library hands it in."""
+    global _workers
+    _workers = reader
+
+
+def _how(usage: str) -> tuple:
+    """``(who, with_workers)`` of a usage word: what ``getrusage`` is asked
+    (None where the platform has no such call) and whether the reading
+    takes in the native workers of the thread."""
+    return _RUSAGE_WHO[usage], usage in _WITH_WORKERS
+
+
+def declare_span(name: str, mirror: bool = False, usage: Optional[str] = None) -> str:
     """Register one span name exactly once (module import time).
 
     Returns the name so modules can bind it: ``SPAN_X = declare_span("x.y")``.
     ``mirror=True`` also hands the span, when opened with ``with``, to the
     tracer's mirror sink (the profiler's clock; docs/DESIGN.md §16).
+    ``usage`` says whose CPU seconds, page faults and context switches its
+    brackets read (the module docstring has the four words).
     """
     if not name or any(c.isspace() for c in name):
         raise SpanNameError(f"bad span name {name!r}")
+    if usage is not None and usage not in _RUSAGE_WHO:
+        raise SpanNameError(f"span {name!r}: usage is one of {sorted(_RUSAGE_WHO)}, not {usage!r}")
     import inspect
 
     frame = inspect.currentframe()
@@ -121,6 +215,10 @@ def declare_span(name: str, mirror: bool = False) -> str:
         _SPAN_NAMES[name] = module
         if mirror:
             _MIRRORED.add(name)
+        if usage is not None:
+            _USAGE[name] = usage
+            if usage != "carrier":
+                _USAGE_ON_SPAN[name] = _how(usage)
     return name
 
 
@@ -138,9 +236,130 @@ def mirrored_span_names() -> list[str]:
         return sorted(_MIRRORED)
 
 
+def usage_span_names() -> dict[str, str]:
+    """The names declared with ``usage`` and the word each was given."""
+    with _names_lock:
+        return dict(_USAGE)
+
+
 # the root span every phase span parents to; declared here because the
 # tracer itself records it at round end
 SPAN_ROUND = declare_span("round")
+
+# a usage reading as it is written on a span, from struct_rusage's fields
+# 0, 1, 6, 7, 14 and 15 (ru_utime, ru_stime, ru_minflt, ru_majflt, ru_nvcsw,
+# ru_nivcsw)
+_USAGE_KEYS = ("cpu_s", "sys_s", "minflt", "majflt", "nvcsw", "nivcsw")
+_usage_children: dict[str, tuple] = {}  # name -> its children of the four counters
+
+
+def _children_of(name: str) -> tuple:
+    """The counter children of one span name, in ``_USAGE_KEYS``' order and
+    the usage count last (looked up once a name: a bracket costs no label
+    lookups)."""
+    children = _usage_children.get(name)
+    if children is None:
+        children = _usage_children[name] = (
+            SPAN_CPU.labels(span=name, mode="user"),
+            SPAN_CPU.labels(span=name, mode="system"),
+            SPAN_FAULTS.labels(span=name, kind="minor"),
+            SPAN_FAULTS.labels(span=name, kind="major"),
+            SPAN_SWITCHES.labels(span=name, kind="voluntary"),
+            SPAN_SWITCHES.labels(span=name, kind="involuntary"),
+            SPAN_USAGE.labels(span=name),
+        )
+    return children
+
+
+def count_usage(name: str) -> None:
+    """One more span of ``name`` has its usage on the counters: for a stage
+    whose brackets were opened with ``count=False`` (the ``rest-overflow``
+    thread takes a body in many turns, and the body counts once, whole)."""
+    _children_of(name)[6].inc()
+
+
+class usage_of:
+    """What the calling thread (its crew, for a name declared ``crew`` or
+    ``carrier``; the process, for one declared so) spends inside the block,
+    added to the counters of span ``name`` and to ``spent``, which the block
+    gets: ``cpu_s``, ``sys_s``, ``minflt``, ``majflt``, ``nvcsw``,
+    ``nivcsw``, there once the block has ended. A span declared ``thread``,
+    ``crew`` or ``process`` is read by its own handle; this is for the
+    thread that works under a span opened elsewhere (``carrier``). Given a
+    ``spent`` of its own, the block adds to what that holds: one dict over
+    several blocks sums them. Left on another thread than it was entered
+    on, or where the platform has no reading, it adds nothing anywhere."""
+
+    __slots__ = ("spent", "_name", "_how", "_count", "_adds", "_start")
+
+    def __init__(self, name: str, count: bool = True, spent: Optional[dict] = None):
+        try:
+            self._how = _how(_USAGE[name])
+        except KeyError:
+            raise SpanNameError(f"span name {name!r} was never declared with usage") from None
+        self._name = name
+        self._count = count
+        self._adds = spent is not None
+        self.spent = spent if self._adds else {}
+        self._start = None
+
+    def __enter__(self) -> dict:
+        self._start = _usage_start(*self._how)
+        return self.spent
+
+    def __exit__(self, exc_type=None, exc=None, tb=None) -> None:
+        if self._start is not None:
+            _spend(self._name, self._start, self.spent, self._count, self._adds)
+
+
+def _usage_start(who: Optional[int], with_workers: bool) -> Optional[tuple]:
+    """The start of a bracket: whose usage, the thread, its reading and its
+    workers' tally; None where the platform has no reading."""
+    if who is None:
+        return None
+    crew = _workers() if with_workers and _workers is not None else None
+    return who, threading.get_ident(), resource.getrusage(who), crew
+
+
+def _spend(name: str, start: tuple, spent: dict, count: bool = True,
+           adds: bool = False) -> None:
+    """The end of the usage bracket of ``name`` that began with ``start``:
+    nothing if this is another thread, else the differences onto the
+    counters and into ``spent`` (added to what it holds, with ``adds``)."""
+    who, thread, at, crew = start
+    if threading.get_ident() != thread:
+        return
+    now = resource.getrusage(who)
+    # spelled out, not looped: a bracket costs two readings and this
+    cpu, sys_, minflt = now[0] - at[0], now[1] - at[1], now[6] - at[6]
+    majflt, nvcsw, nivcsw = now[7] - at[7], now[14] - at[14], now[15] - at[15]
+    if crew is not None:
+        joined = _workers()
+        cpu, sys_, minflt = cpu + joined[0] - crew[0], sys_ + joined[1] - crew[1], \
+            minflt + joined[2] - crew[2]
+        majflt, nvcsw, nivcsw = majflt + joined[3] - crew[3], nvcsw + joined[4] - crew[4], \
+            nivcsw + joined[5] - crew[5]
+    to = _usage_children.get(name) or _children_of(name)
+    if cpu > 0:
+        to[0].inc(cpu)
+    if sys_ > 0:
+        to[1].inc(sys_)
+    if minflt:
+        to[2].inc(minflt)
+    if majflt:
+        to[3].inc(majflt)
+    if nvcsw:
+        to[4].inc(nvcsw)
+    if nivcsw:
+        to[5].inc(nivcsw)
+    if count:
+        to[6].inc()
+    if adds and spent:
+        for key, d in zip(_USAGE_KEYS, (cpu, sys_, minflt, majflt, nvcsw, nivcsw)):
+            spent[key] += d
+    else:
+        spent["cpu_s"], spent["sys_s"], spent["minflt"] = cpu, sys_, minflt
+        spent["majflt"], spent["nvcsw"], spent["nivcsw"] = majflt, nvcsw, nivcsw
 
 
 # span ids are correlation handles, not secrets: a module-level PRNG
@@ -281,13 +500,14 @@ class _SpanHandle:
     exception path by construction (the analysis ``span`` pass rejects
     non-``with`` uses)."""
 
-    __slots__ = ("_tracer", "_span", "_token", "_mirror")
+    __slots__ = ("_tracer", "_span", "_token", "_mirror", "_usage")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
         self._token = None
         self._mirror = None
+        self._usage = None
 
     @property
     def ctx(self) -> TraceContext:
@@ -307,9 +527,17 @@ class _SpanHandle:
             except Exception:  # a telemetry consumer must never fail the work
                 self._mirror = None
                 logger.exception("trace mirror failed to open %s", self._span.name)
+        how = _USAGE_ON_SPAN.get(self._span.name)
+        if how is not None:
+            # last in, first out: the reading brackets the work alone
+            self._usage = _usage_start(how[0], how[1])
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._usage is not None:
+            # set at the end: in the tracer's export, not in the mirror,
+            # which took the attributes at the start
+            _spend(self._span.name, self._usage, self._span.attrs)
         if self._mirror is not None:
             try:
                 self._mirror.__exit__(exc_type, exc, tb)
@@ -703,12 +931,18 @@ def timed_span(name: str, seconds, ctx: Optional[TraceContext] = None,
     as one observation on ``seconds`` (a histogram child of the registry).
     The spans say where one message's or one phase's seconds went; the
     histogram says it for a window of ``/metrics``. With the tracer ``off``
-    no ``Span`` is made and the histogram is still observed. The stage
-    tables built on it: ``server/stages.py`` (a message's chain) and
-    ``telemetry/unmask.py`` (the Unmask phase)."""
+    no ``Span`` is made and the histogram is still observed, and so are the
+    usage counters of a name declared ``thread`` or ``process``. The stage
+    tables built on it: ``server/stages.py`` (a message's chain),
+    ``telemetry/unmask.py`` (the Unmask phase) and ``telemetry/journal.py``
+    (a journal write)."""
     t0 = time.monotonic()
     try:
         with get_tracer().span(name, ctx=ctx, link=link, **attrs) as span:
-            yield span
+            if span is _NULL_SPAN and name in _USAGE_ON_SPAN:
+                with usage_of(name):
+                    yield span
+            else:
+                yield span
     finally:
         seconds.observe(time.monotonic() - t0)

@@ -66,14 +66,20 @@ MODEL_BYTES = get_registry().counter(
 # stage label -> span name; spelled out (not built in a loop) so the
 # analysis `span` pass reads the literal set against the DESIGN §16 table.
 # Every stage starts and ends on the state machine's thread: all mirrored.
+# The three whose work is that thread's own say what it spent (`usage`,
+# telemetry/tracing.py; `mask_put` with the crew that packs its planes);
+# `decode` and `save` fan out over the native library's threads and an
+# executor's while the tail has nothing else of the vector's size in flight,
+# so they read the whole process; `subtract` and `fetch` wait for the
+# device, `proof` and `retire` for a store.
 _SPANS: dict[str, str] = {
-    "elect": trace.declare_span("unmask.elect", mirror=True),
-    "validate": trace.declare_span("unmask.validate", mirror=True),
-    "mask_put": trace.declare_span("unmask.mask_put", mirror=True),
+    "elect": trace.declare_span("unmask.elect", mirror=True, usage="thread"),
+    "validate": trace.declare_span("unmask.validate", mirror=True, usage="thread"),
+    "mask_put": trace.declare_span("unmask.mask_put", mirror=True, usage="crew"),
     "subtract": trace.declare_span("unmask.subtract", mirror=True),
     "fetch": trace.declare_span("unmask.fetch", mirror=True),
-    "decode": trace.declare_span("unmask.decode", mirror=True),
-    "save": trace.declare_span("unmask.save", mirror=True),
+    "decode": trace.declare_span("unmask.decode", mirror=True, usage="process"),
+    "save": trace.declare_span("unmask.save", mirror=True, usage="process"),
     "proof": trace.declare_span("unmask.proof", mirror=True),
     "retire": trace.declare_span("unmask.retire", mirror=True),
 }

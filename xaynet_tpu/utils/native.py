@@ -11,6 +11,7 @@ import ctypes
 import logging
 import os
 import subprocess
+import threading
 from typing import Optional
 
 logger = logging.getLogger("xaynet.native")
@@ -18,7 +19,7 @@ logger = logging.getLogger("xaynet.native")
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libxaynet_native.so")
 
-_ABI_VERSION = 14
+_ABI_VERSION = 15
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -250,13 +251,35 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_double,  # seconds allowed
         ]
         lib.xn_recv_exactly.restype = ctypes.c_uint64
+        # ABI 15: what the worker threads that the calling thread started
+        # and joined have spent, for the spans that read their usage
+        lib.xn_workers_spent.argtypes = [ctypes.POINTER(ctypes.c_uint64)]
+        lib.xn_workers_spent.restype = None
         _lib = lib
+        from ..telemetry import tracing
+
+        tracing.set_workers_reader(_workers_spent)
     except (OSError, AttributeError) as e:
         # AttributeError: a stale prebuilt .so missing newer symbols when the
         # rebuild could not run — degrade to the python fallback, not a crash
         logger.warning("native library load failed; using python fallback: %s", e)
         _lib = None
     return _lib
+
+
+_workers_buf = threading.local()
+
+
+def _workers_spent() -> tuple:
+    """What the library's worker threads started and joined by the calling
+    thread have spent so far, as ``telemetry/tracing.py`` reads a usage:
+    user and system seconds, minor and major faults, voluntary and
+    involuntary switches."""
+    buf = getattr(_workers_buf, "buf", None)
+    if buf is None:
+        buf = _workers_buf.buf = (ctypes.c_uint64 * 6)()
+    _lib.xn_workers_spent(buf)
+    return (1e-6 * buf[0], 1e-6 * buf[1], buf[2], buf[3], buf[4], buf[5])
 
 
 # ``bytearray(n)`` zero-fills its n bytes under the interpreter lock: 110 ms
