@@ -32,6 +32,7 @@ import select
 import selectors
 import socket
 import ssl
+import sys
 import threading
 import time
 from collections import deque
@@ -89,6 +90,16 @@ BODY_READERS = 16
 # (one thread's first touch of fresh pages is the bound from here on), where
 # a longer turn only keeps the others waiting (PERF.md §6, PR 43).
 OVERFLOW_TURN_BYTES = 1 << 20
+# The most bytes of received bodies' buffers a server keeps for the bodies to
+# come (`_BodyBuffers`). It keeps only what its intake held at once (8 bodies
+# of 179-256 MB in the benchmark's `flood8` cells and one Sum2 body, 1.6-2.3
+# GB; 64 of 39.6 MB in the fan-in cell, 2.5 GB), so the cap binds only where
+# lengths change from round to round by more than a buffer's slack.
+BODY_BUFFERS_MAX_BYTES = 4 << 30
+# A fresh body buffer is allocated this much over its length (untouched pages
+# cost nothing), so that a later body a little longer fits it too: an Update
+# body grows by 112 bytes a sum participant in the round's seed dictionary.
+BODY_BUFFER_SLACK = 1 << 16
 
 SPAN_REQUEST = trace.declare_span("rest.request")
 
@@ -166,6 +177,74 @@ def _abort_read(sock: socket.socket) -> None:
         sock.shutdown(socket.SHUT_RDWR)
     except OSError:
         pass  # the reader finished and closed it first
+
+
+# ``PyByteArray_Resize``: within the allocation and over half of it, a new
+# length and no byte moved or written (``del body[n:]`` only shrinks)
+_resize_bytearray = ctypes.PYFUNCTYPE(ctypes.c_int, ctypes.py_object, ctypes.c_ssize_t)(
+    ("PyByteArray_Resize", ctypes.pythonapi)
+)
+
+
+class _BodyBuffers:
+    """The buffers large bodies are received into, on pages kept from
+    earlier bodies (docs/DESIGN.md §16, "How a body is read").
+
+    A body of ``DIRECT_BODY_MIN`` bytes or more gets one ``bytearray`` of its
+    length. A fresh one is an ``mmap`` of untouched pages which ``free`` gives
+    back, and ``recv`` into it spends several times the copy on page faults
+    (PERF.md §6, PR 53), so the buffers handed out are kept, up to ``cap``
+    bytes of them, and handed out again. Nobody gives one back: whatever
+    reads a body's bytes (the request's frames, the ``memoryview`` the open
+    returns, a ``np.frombuffer`` view of a vector that waits for its slot
+    copy, the ``ctypes`` pin of a ``recv`` in flight) holds a reference to the
+    ``bytearray``, so a kept one that nothing but this object refers to is
+    free, and is found so by its reference count. One still referred to is
+    left alone, and where none that is free fits, fresh pages are handed out
+    as before. A kept buffer serves another length where ``bytearray`` need
+    not move it to resize: within its allocation and at least half of it.
+    Its old contents (the last message's opened bytes, as the heap held them
+    after ``free``) are the next body's to overwrite; nothing reads them."""
+
+    def __init__(self, cap: int = BODY_BUFFERS_MAX_BYTES):
+        self._cap = cap
+        self._lock = threading.Lock()
+        # least recently taken first  # guarded-by: _lock
+        self._kept: list[bytearray] = [bytearray()]
+        # what the scan below reads of a buffer nothing else refers to
+        self._unshared = self._shared_by()[0]
+        self._kept.clear()
+
+    def _shared_by(self) -> list[int]:
+        return [sys.getrefcount(buf) for buf in self._kept]
+
+    def take(self, length: int) -> tuple[bytearray, bool]:
+        """A ``bytearray`` of ``length`` bytes, contents undefined, the
+        caller's until its last reference to it and view of it is gone, and
+        whether its pages are kept ones (mapped already) or fresh."""
+        with self._lock:
+            free = [n == self._unshared for n in self._shared_by()]
+            rooms = [buf.__alloc__() for buf in self._kept]
+            # of the free ones it fits unmoved, the smallest, least recently taken
+            fits = [
+                (room, at) for at, room in enumerate(rooms)
+                if free[at] and room // 2 <= length < room
+            ]
+            if fits:
+                body = self._kept.pop(min(fits)[1])
+                self._kept.append(body)
+                if len(body) != length:
+                    _resize_bytearray(body, length)  # no export, and in place: see above
+                return body, True
+            body = native.uninitialised_bytearray(None, length + BODY_BUFFER_SLACK)
+            _resize_bytearray(body, length)
+            room = body.__alloc__()
+            if room <= self._cap:
+                self._kept.append(body)
+                held = sum(rooms) + room
+                while held > self._cap:
+                    held -= self._kept.pop(0).__alloc__()
+            return body, False
 
 
 class _OverflowBody:
@@ -423,6 +502,9 @@ class RestServer:
         self._direct_reads: set[socket.socket] = set()
         self._overflow: Optional[_OverflowReader] = None
         self._overflow_reads: set[socket.socket] = set()
+        # what both carriers receive into: one pool a server, its tenants'
+        # too (a buffer nothing refers to carries no tenant's state)
+        self._body_buffers = _BodyBuffers()
         # live connections: stop() closes them — an idle keep-alive peer
         # would otherwise hold the process for read_timeout seconds
         self._writers: set[asyncio.StreamWriter] = set()
@@ -549,7 +631,7 @@ class RestServer:
         # nothing below suspends until a thread has the socket, so no byte
         # reaches the StreamReader between here and resume_reading()
         transport.pause_reading()
-        body = native.uninitialised_bytearray(None, length)
+        body, kept = self._body_buffers.take(length)
         spent: dict = {}
         buffered = len(reader._buffer)
         if buffered:
@@ -584,7 +666,7 @@ class RestServer:
         if span is not None:
             span.set(**spent)
         self._body_bytes.labels(route=route).inc(length)
-        self._intake.read(route, reason)
+        self._intake.read(route, reason, pages="kept" if kept else "fresh")
         return body
 
     def _direct_socket(
@@ -761,14 +843,16 @@ class RestServer:
             self._intake.new_window()
         elif entered.event is PhaseName.SUM2:
             self._sum2_first_arrival.observe(time.monotonic() - entered.at)
-            direct, overflow, turned, high = self._intake.since_last()
+            direct, overflow, turned, high, kept = self._intake.since_last()
             if direct or overflow or turned:
                 logger.info(
                     "large bodies since the last Sum2: %d read by rest-body threads, %d by the "
                     "rest-overflow thread, %d through the StreamReader (%s); at most %d message "
-                    "bodies held sealed at once",
+                    "bodies held sealed at once; %d of the two carriers' bodies were received "
+                    "into pages kept from earlier bodies",
                     direct, overflow, sum(turned.values()),
                     ", ".join(f"{reason} {n}" for reason, n in turned.items()) or "none", high,
+                    kept,
                 )
             staged = wire_stats.since_last()
             if staged["packed"] or staged["legacy"]:

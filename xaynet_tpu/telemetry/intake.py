@@ -6,7 +6,7 @@ received on two carriers at once (a ``rest-body`` thread each while one is
 free, the one event-driven ``rest-overflow`` thread for all the others),
 every connection holds a body while its message waits for a worker, and the
 API's loop, which runs the serial part of every message, becomes the bound
-on intake. Four things are counted, each where it happens:
+on intake. Five things are counted, each where it happens:
 
 - ``xaynet_rest_body_reads_total{route, reason}``: one a request body read
   in full, by the carrier that read it and why it was that one. Of the three
@@ -17,6 +17,14 @@ on intake. Four things are counted, each where it happens:
   (under ``rest.DIRECT_BODY_MIN``), ``tls`` or ``no_socket`` (the transport
   gave no descriptor to read from); ``stream``/``no_reader`` is declared and
   stays 0: no large plain body that had a socket is gathered on the loop.
+- ``xaynet_rest_body_buffers_total{pages}``: one a body read in full on
+  ``direct`` or ``overflow`` (the StreamReader's bodies have no buffer of
+  their own), by what it was received into: ``kept`` = a buffer kept from an
+  earlier body that nothing referred to any more, its pages mapped already;
+  ``fresh`` = a new allocation, whose pages ``recv`` touched first (none that
+  was free fitted: a first round, more bodies alive at once than ever
+  before, another length). ``rest.py::_BodyBuffers`` chooses; ``kept`` over
+  the two is the benchmark's ``rest.body_kept_share``.
 - ``xaynet_rest_overflow_bodies``: the bodies the ``rest-overflow`` thread
   holds at this instant (also ``/healthz`` ``overflow_bodies``).
 - ``xaynet_rest_bodies_resident`` and ``..._resident_max``: POSTed message
@@ -71,9 +79,19 @@ class BodyIntake:
             "StreamReader; stream/no_reader stays 0 (telemetry/intake.py).",
             ("route", "reason"),
         )
+        self._buffers = registry.counter(
+            "xaynet_rest_body_buffers_total",
+            "Large bodies read in full by a rest-body thread or the "
+            "rest-overflow thread, by the pages they were received into: "
+            "kept = a buffer kept from an earlier body that nothing referred "
+            "to any more, fresh = a new allocation (telemetry/intake.py).",
+            ("pages",),
+        )
         # declared (each reads 0, not absent), and a round's log counts from
         # here: the registry may have served another server before this one
         self._logged = self._large_reads()
+        self._buffers.labels(pages="fresh")
+        self._kept_logged = self._buffers.labels(pages="kept").value
         self.overflow_bodies = registry.gauge(
             "xaynet_rest_overflow_bodies",
             "Large request bodies the rest-overflow thread is receiving at "
@@ -93,8 +111,12 @@ class BodyIntake:
         self._n = 0
         self._high = 0
 
-    def read(self, route: str, reason: str) -> None:
+    def read(self, route: str, reason: str, pages: str | None = None) -> None:
+        """One body read in full; ``pages`` where it was received into a
+        buffer of its own (``direct``, ``overflow``): ``kept`` | ``fresh``."""
         self._reads.labels(route=route, reason=reason).inc()
+        if pages is not None:
+            self._buffers.labels(pages=pages).inc()
 
     def hold(self) -> Held:
         with self._lock:
@@ -120,13 +142,16 @@ class BodyIntake:
     def _large_reads(self) -> dict[tuple[str, str], float]:
         return {key: self._reads.labels(route=key[0], reason=key[1]).value for key in LARGE}
 
-    def since_last(self) -> tuple[int, int, dict[str, int], int]:
+    def since_last(self) -> tuple[int, int, dict[str, int], int, int]:
         """(large bodies read by ``rest-body`` threads, by the
         ``rest-overflow`` thread, {reason: large bodies through the
-        StreamReader, where any}, the high-water mark) since the previous
-        call: what a round's log line says."""
+        StreamReader, where any}, the high-water mark, how many of the first
+        two were received into kept pages) since the previous call: what a
+        round's log line says."""
         now = self._large_reads()
         grown = {key: int(v - self._logged[key]) for key, v in now.items()}
         self._logged = now
         direct, overflow = grown.pop(LARGE[0]), grown.pop(LARGE[1])
-        return direct, overflow, {reason: n for (_, reason), n in grown.items() if n}, self._high
+        kept, self._kept_logged = self._kept_logged, self._buffers.labels(pages="kept").value
+        turned = {reason: n for (_, reason), n in grown.items() if n}
+        return direct, overflow, turned, self._high, int(self._kept_logged - kept)
