@@ -186,3 +186,57 @@ def test_row_placement_compiles_in_place_for_v5e(v5e, n_dev, k, rows, width):
     mem = place_row.lower(batch, row, slot).compile().memory_analysis()
     assert mem.alias_size_in_bytes == mem.output_size_in_bytes > 0
     assert mem.temp_size_in_bytes <= 4.2 * rows * width / n_dev
+
+
+# --- the per-update road of [aggregation] wire_ingest (ISSUE 54) --------------
+#
+# The cell resnet50-f32m6-wireingest.flood at its own size: one update's
+# element block on the device as its message held it (no batch axis), the
+# de-interleave and order check over it, the stack of a chunk of resident
+# rows and the fold of the chunk (12 rows a flush = chunks of 8 and 4).
+
+N_RESNET = 25_557_032
+
+
+def _one_update_specs(devices, n=N_RESNET):
+    """(v1 raw block, v2 planes, resident planar row) of ONE update."""
+    if len(devices) == 1:
+        flat = rows = SingleDeviceSharding(devices[0])
+    else:
+        mesh = Mesh(np.asarray(devices), (MODEL_AXIS,))
+        flat, rows = NamedSharding(mesh, P(MODEL_AXIS)), NamedSharding(mesh, P(None, MODEL_AXIS))
+    return (
+        jax.ShapeDtypeStruct((n * BPN,), jnp.uint8, sharding=flat),
+        jax.ShapeDtypeStruct((BPN, n), jnp.uint8, sharding=rows),
+        jax.ShapeDtypeStruct((L, n), jnp.uint32, sharding=rows),
+    )
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_one_update_unpack_and_check_compile_for_v5e_at_the_cells_size(v5e, n_dev):
+    devices = v5e[:n_dev]
+    agg = _builder(devices)
+    raw, planes, _row = _one_update_specs(devices)
+    mem = _compile(agg._make_unpack_fn(one=True), raw)
+    # what stays is the row alone: [L, n] uint32 and the verdict
+    assert mem.output_size_in_bytes >= 4 * L * N_RESNET // n_dev
+    assert mem.output_size_in_bytes < 4 * L * N_RESNET // n_dev + 4096
+    _compile(agg._make_planar_ok_fn(one=True), planes)
+
+
+@pytest.mark.parametrize("k", [8, 4])
+def test_a_chunk_of_resident_rows_stacks_and_folds_for_v5e_at_the_cells_size(v5e, k):
+    devices = v5e[:1]
+    agg = _builder(devices)
+    agg._batch_sharding = SingleDeviceSharding(devices[0])
+    _raw, _planes, row = _one_update_specs(devices)
+    stack = agg._make_stack_fn()
+    mem = stack.lower(*[row] * k).compile().memory_analysis()
+    chunk = k * 4 * L * N_RESNET
+    # one copy of the rows into the chunk, no second one beside it
+    assert chunk <= mem.output_size_in_bytes < 1.001 * chunk  # (the tiles pad the last columns)
+    assert mem.temp_size_in_bytes <= 0.05 * chunk
+    acc = jax.ShapeDtypeStruct((L, N_RESNET), jnp.uint32, sharding=row.sharding)
+    batch = jax.ShapeDtypeStruct((k, L, N_RESNET), jnp.uint32, sharding=row.sharding)
+    _compile(agg._make_fold_fn("xla"), acc, batch)
+    _compile(agg._make_fold_fn("pallas"), acc, batch)

@@ -313,7 +313,7 @@ def test_the_benchmarks_metric_reads_the_counter():
     # a program without the counter (the parent): nothing, and no 0.0
     bare = parse_metrics(MetricsRegistry().render())
     assert read({"metrics": {"open": bare, "end": bare}}, **spec["args"]) is None
-    entry = data.load_benchmark()["per_layer"][-1]
+    entry = next(m for m in data.load_benchmark()["per_layer"] if m["name"] == "rest.body_kept_share")
     assert entry == {
         "name": "rest.body_kept_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "message pipeline", "moves": "updates_per_s",

@@ -261,14 +261,14 @@ def test_round_with_wire_ingest(monkeypatch):
     from xaynet_tpu.parallel.aggregator import ShardedAggregator
 
     validated = []
-    real_validate = ShardedAggregator.validate_wire_update
+    real_validate = ShardedAggregator.unpack_put_update
 
-    def spy(self, raw):
-        out = real_validate(self, raw)
+    def spy(self, staged):
+        out = real_validate(self, staged)
         validated.append(out is not None)
         return out
 
-    monkeypatch.setattr(ShardedAggregator, "validate_wire_update", spy)
+    monkeypatch.setattr(ShardedAggregator, "unpack_put_update", spy)
 
     async def run():
         settings = _settings()

@@ -427,7 +427,7 @@ def test_sharded_fold_planar_rows_now_device_resident(kernel):
     stream = StreamingAggregator(agg, staging_buffers=2, dispatch_ahead=2, max_batch=4)
     planars = agg.validate_wire_updates([np.asarray(r) for r in raws])
     assert all(p is not None for p in planars)
-    stream.fold_planar_rows_now(planars)
+    stream.fold_resident_rows_now(planars)
     stream.drain()
 
     assert np.array_equal(agg.snapshot(), seq.snapshot())

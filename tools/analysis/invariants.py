@@ -47,10 +47,8 @@ NB_MODELS_SITES: dict[tuple[str, str], str] = {
     ("xaynet_tpu/parallel/aggregator.py", "ShardedAggregator.reset"): "round reset",
     # the streaming pipeline: every credit sits under the pipeline lock,
     # paired with the in-flight decrement (counted_models() atomicity)
-    ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator.fold_planar_rows_now"):
-        "caller-thread fold credit",
-    ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator.fold_packed_rows_now"):
-        "caller-thread fold credit (pre-packed byte-planar rows, §21 wire ingest)",
+    ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator.fold_resident_rows_now"):
+        "caller-thread fold credit (device-resident rows of either layout, wire ingest)",
     ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator.fold_planar_stack_now"):
         "caller-thread fold credit (stacked device batch, fused mask pipeline)",
     ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator._fold_pinned_stack"):
@@ -68,8 +66,6 @@ NB_MODELS_SITES: dict[tuple[str, str], str] = {
         "degraded shard-parallel wire credit",
     ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator._shard_job_done"):
         "cross-shard commit barrier: last shard credits the batch",
-    ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator._fold_planar_rows_now_sharded"):
-        "caller-thread shard-parallel fold credit",
     ("xaynet_tpu/parallel/streaming.py", "StreamingAggregator._drain_sharded"):
         "deferred wire credit at the cross-shard barrier",
     # the server-side aggregation facade

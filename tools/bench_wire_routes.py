@@ -23,6 +23,16 @@ fan-in cell's), each pass timed alone on an idle host, best of ``--repeat``:
   rows are asked for: ``planar_to_interleaved`` (numpy's transpose), then all
   of ``v1``.
 
+- ``device`` (``--device``; a child that holds the accelerator, the default
+  JAX platform as the caller's ``JAX_PLATFORMS`` leaves it: the chip on a chip
+  host, and it says which): the road of ``[aggregation] wire_ingest``
+  (docs/DESIGN.md §3) for one vector of either wire, each leg until it is
+  done: ``h2d`` (the view of the body, at an offset as a message holds it,
+  ``device_put`` until the transfer is complete), ``unpack`` (the device's
+  de-interleave and order check, or for a v2 body the check alone, dispatch to
+  the verdict on the host), and both in a row. The kernel alone, to set
+  against the served turn (``ingest.h2d_ms``, ``ingest.unpack_wait_ms``).
+
 Each shape runs twice in children of its own: on one thread of the native
 library (``XAYNET_NATIVE_THREADS=1``) and on ``fold_threads()``. GB/s are the
 element block's bytes over the pass's time. ``x8`` rows are the pass called
@@ -31,7 +41,7 @@ workers of a 13-core host under a flood), timed until the last returns: what
 a kernel that threads by default costs where many callers meet. No chip, no
 jax: host numbers, and quoted as such.
 
-Run:  python tools/bench_wire_routes.py [--shapes 25557032x7,...] [--repeat 3]
+Run:  python tools/bench_wire_routes.py [--shapes 25557032x7,...] [--repeat 3] [--device]
 """
 
 from __future__ import annotations
@@ -133,19 +143,76 @@ def _case(elements: int, bpn: int, repeat: int) -> dict:
     }
 
 
+_CATALOGUE = {6: ("PRIME", "B0", "M3"), 7: ("INTEGER", "B0", "M6"), 10: ("INTEGER", "B6", "M6")}
+
+
+def _device_case(elements: int, bpn: int, repeat: int) -> dict:
+    """One vector through the device road: the catalogue's mask of ``bpn``
+    wire bytes (its own order: the device's program is built for one)."""
+    import jax
+    import numpy as np
+
+    from xaynet_tpu.core.mask import config as mask_config
+    from xaynet_tpu.parallel.aggregator import ShardedAggregator
+
+    group, bound, model = _CATALOGUE[bpn]
+    config = mask_config.MaskConfig(
+        mask_config.GroupType[group], mask_config.DataType.F32,
+        mask_config.BoundType[bound], mask_config.ModelType[model])
+    rng = np.random.default_rng(54)
+    rows = np.frombuffer(rng.bytes(elements * bpn), dtype=np.uint8).reshape(elements, bpn).copy()
+    rows[:, -1] %= config.order.to_bytes(bpn, "little")[-1]  # every element under the order
+    agg = ShardedAggregator(config, elements, kernel="xla")
+    ms = {}
+    for wire, block in (("v1", rows.reshape(-1)), ("v2", np.ascontiguousarray(rows.T).reshape(-1))):
+        body = bytearray(64) + bytearray(block.tobytes())  # the block lies at an offset
+        view = np.frombuffer(body, dtype=np.uint8, count=elements * bpn, offset=64)
+        if wire == "v1":
+            put, check = agg.put_wire_update, agg.unpack_put_update
+        else:
+            view, put, check = view.reshape(bpn, elements), agg.put_planar_update, agg.check_put_update
+        assert check(put(view)) is not None  # compiled, and the verdict is "accepted"
+        staged = put(view)
+        ms[f"{wire}.h2d"] = 1e3 * _best(lambda: put(view), repeat)
+        ms[f"{wire}.unpack"] = 1e3 * _best(lambda: check(staged), repeat)
+        ms[f"{wire}.total"] = 1e3 * _best(lambda: check(put(view)), repeat)
+        del staged
+    block_bytes = elements * bpn
+    device = jax.devices()[0]
+    return {
+        "route": "device", "elements": elements, "bytes": bpn, "limbs": agg.n_limbs,
+        "block_mb": block_bytes / 1e6, "platform": device.platform, "device_kind": device.device_kind,
+        "devices": agg.mesh.devices.size,
+        "ms": {k: round(v, 2) for k, v in ms.items()},
+        "gb_per_s": {k: round(block_bytes / 1e6 / v, 2) for k, v in ms.items()},
+    }
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shapes", default="25557032x7,25557032x10,6603710x6",
                     help="elements x wire bytes, comma-separated")
     ap.add_argument("--repeat", type=int, default=3)
+    ap.add_argument("--device", action="store_true",
+                    help="also the device road of wire ingest, on the default JAX platform")
     ap.add_argument("--case", default=None, help=argparse.SUPPRESS)  # a child's one shape
+    ap.add_argument("--device-case", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if args.case:
-        elements, bpn = (int(x) for x in args.case.split("x"))
-        print(json.dumps(_case(elements, bpn, args.repeat)))
+    if args.case or args.device_case:
+        elements, bpn = (int(x) for x in (args.case or args.device_case).split("x"))
+        case = _case if args.case else _device_case
+        print(json.dumps(case(elements, bpn, args.repeat)))
         return
     for shape in args.shapes.split(","):
+        if args.device:
+            # one child a shape: the accelerator has one owner at a time
+            done = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--device-case", shape,
+                 "--repeat", str(args.repeat)], capture_output=True, text=True)
+            print(done.stdout.strip().splitlines()[-1] if done.returncode == 0
+                  else json.dumps({"shape": shape, "route": "device", "error": done.stderr[-400:]}),
+                  flush=True)
         for threads in ("1", None):
             env = {k: v for k, v in os.environ.items() if k != "XAYNET_NATIVE_THREADS"}
             env["JAX_PLATFORMS"] = "cpu"

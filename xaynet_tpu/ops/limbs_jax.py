@@ -145,6 +145,55 @@ def _assemble_limbs(byte_plane, bpn: int, n_limbs: int):
     return jnp.stack(limbs, axis=-2)
 
 
+_LANES = 128  # elements a row of the de-interleave holds: the TPU's lane width
+_BLOCK_ROWS = 4096  # rows one pass of the de-interleave takes (3.7 MB of wire bytes at bpn = 7)
+
+
+def _deinterleave_weights(bpn: int) -> np.ndarray:
+    """The ``[128 * bpn, 128 * H]`` matrix (``H = ceil(bpn / 2)`` 16-bit
+    halves an element) that takes a row of 128 interleaved elements to their
+    halves, plane by plane: column ``h * 128 + e`` has 1 at byte ``2h`` of
+    element ``e`` (row ``e * bpn + 2h``) and 256 at its byte ``2h + 1``. A
+    trace-time constant."""
+    halves = (bpn + 1) // 2
+    w = np.zeros((_LANES * bpn, _LANES * halves), dtype=np.float32)
+    e = np.arange(_LANES)
+    for h in range(halves):
+        w[e * bpn + 2 * h, h * _LANES + e] = 1.0
+        if 2 * h + 1 < bpn:
+            w[e * bpn + 2 * h + 1, h * _LANES + e] = 256.0
+    return w
+
+
+def _deinterleave_rows(rows: jax.Array, bpn: int, n_limbs: int) -> jax.Array:
+    """``uint8[..., r, 128 * bpn]`` (each row 128 interleaved elements) ->
+    planar ``uint32[..., L, r * 128]``, by ONE matrix product: the bytes of
+    an element lie ``bpn`` apart along the lanes, and a lane-strided gather
+    is the slowest thing the chip does (0.43 s a byte plane at 25.5M
+    elements on a v5e: PERF.md section 6, PR 54), while a product with a 0/1/256
+    matrix is what it does best. Exact: a byte and the weights 1 and 256 are
+    bfloat16 numbers, a product of two is a float32 number, and a column
+    sums two of them to less than 2**16."""
+    halves = (bpn + 1) // 2
+    w = jnp.asarray(_deinterleave_weights(bpn), dtype=jnp.bfloat16)
+    out = jax.lax.dot_general(
+        rows.astype(jnp.bfloat16), w, (((rows.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(_U32)  # [..., r, H * 128]
+    out = out.reshape(*out.shape[:-1], halves, _LANES)
+    limbs = []
+    for j in range(n_limbs):
+        if 2 * j >= halves:
+            limbs.append(jnp.zeros(out.shape[:-2] + (_LANES,), dtype=_U32))
+            continue
+        word = out[..., 2 * j, :]
+        if 2 * j + 1 < halves:
+            word = word | (out[..., 2 * j + 1, :] << _U32(16))
+        limbs.append(word)
+    planar = jnp.stack(limbs, axis=-3)  # [..., L, r, 128]
+    return planar.reshape(*planar.shape[:-2], -1)
+
+
 def wire_bytes_to_planar(data: jax.Array, count: int, bpn: int) -> jax.Array:
     """Wire element block ``uint8[..., count*bpn]`` -> planar ``uint32[..., L, count]``.
 
@@ -153,19 +202,51 @@ def wire_bytes_to_planar(data: jax.Array, count: int, bpn: int) -> jax.Array:
     byte shuffling, so the coordinator can ship RAW wire bytes to the
     device (``bpn/(4L)`` of the limb-tensor size, e.g. 6/8 for the
     f32/B0/M3 configs, 7/8 for M6) and never pay a host-side parse.
-    Byte i of every element is the stride-``bpn`` slice ``data[..., i::bpn]``
-    — a reshape to ``[..., count, bpn]`` would put a minor dimension of
-    ``bpn`` under the TPU's 128-lane tiling (18x padding at bpn = 7; the
-    v5e compiler refuses it at n = 25M). Designed to run inside a jitted
-    caller.
+    The block is read as rows of 128 elements (``128 * bpn`` bytes: whole
+    lanes, where a reshape to ``[..., count, bpn]`` would put a minor
+    dimension of ``bpn`` under the TPU's 128-lane tiling, 18x padding at
+    bpn = 7, which the v5e compiler refuses at n = 25M) and de-interleaved
+    a row at a time by a matrix product (:func:`_deinterleave_rows`),
+    ``_BLOCK_ROWS`` rows a pass of a loop that writes each pass's planes
+    where they belong in the result: what the product holds beside its
+    input and output (the bytes widened, the halves as float32) is then a
+    pass's and not the vector's, 4.6x the block for a group of four at 25M
+    otherwise. The rows past the last whole pass go through the same
+    product once; the elements past the last whole row, under 128 of them,
+    are the stride-``bpn`` slices ``data[..., i::bpn]``, which is what the
+    whole block used to be. Designed to run inside a jitted caller.
     """
     from . import limbs as host_limbs
 
     if data.shape[-1] != count * bpn:
         raise ValueError("wire block length must be count * bytes_per_number")
-    return _assemble_limbs(
-        lambda i: data[..., i::bpn], bpn, host_limbs.n_limbs_for_bytes(bpn)
-    )
+    n_limbs = host_limbs.n_limbs_for_bytes(bpn)
+    lead = data.shape[:-1]
+    row_bytes = _LANES * bpn
+    whole = count // _LANES
+    passes, rest = divmod(whole, _BLOCK_ROWS)
+    out = jnp.zeros((*lead, n_limbs, count), dtype=_U32)
+
+    def place(out, block, n_rows, first_row):
+        planes = _deinterleave_rows(block.reshape(*lead, n_rows, row_bytes), bpn, n_limbs)
+        return jax.lax.dynamic_update_slice_in_dim(out, planes, first_row * _LANES, axis=-1)
+
+    if passes:
+        pass_bytes = _BLOCK_ROWS * row_bytes
+
+        def one_pass(i, out):
+            block = jax.lax.dynamic_slice_in_dim(data, i * pass_bytes, pass_bytes, axis=-1)
+            return place(out, block, _BLOCK_ROWS, i * _BLOCK_ROWS)
+
+        out = jax.lax.fori_loop(0, passes, one_pass, out)
+    if rest:
+        block = data[..., passes * _BLOCK_ROWS * row_bytes : whole * row_bytes]
+        out = place(out, block, rest, passes * _BLOCK_ROWS)
+    if count % _LANES:
+        tail = data[..., whole * row_bytes :]
+        planes = _assemble_limbs(lambda i: tail[..., i::bpn], bpn, n_limbs)
+        out = jax.lax.dynamic_update_slice_in_dim(out, planes, whole * _LANES, axis=-1)
+    return out
 
 
 def packed_planar_to_limbs(packed: jax.Array, n_limbs: int) -> jax.Array:

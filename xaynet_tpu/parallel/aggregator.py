@@ -147,6 +147,11 @@ def _build_wire_unpack(bpn: int, order: int, multi_device: bool):
 
     Runs inside jit (and, when ``multi_device``, inside shard_map, where the
     psum makes an update invalid on ANY shard excluded on every shard).
+    ``raw`` is a batch ``uint8[K, bytes]`` (``ok`` is ``bool[K]``) or one
+    update ``uint8[bytes]`` as its message held it (``ok`` is a scalar and
+    the planar ``[L, n]``: the per-update road puts no batch axis on it).
+    The function's name is the executable's (``jit_unpack_mask``), which a
+    trace reader matches.
     """
     from ..ops import limbs_jax
 
@@ -157,7 +162,7 @@ def _build_wire_unpack(bpn: int, order: int, multi_device: bool):
         if multi_device:
             bad = jax.lax.psum((~ok).astype(jnp.uint32), MODEL_AXIS)
             ok = bad == jnp.uint32(0)
-        planar = jnp.where(ok[:, None, None], planar, jnp.uint32(0))
+        planar = jnp.where(ok[..., None, None], planar, jnp.uint32(0))
         return planar, ok
 
     return unpack_mask
@@ -171,11 +176,12 @@ def _build_planar_ok(n_limbs: int, order: int, multi_device: bool):
     *transiently*, inside this jit. The caller keeps the packed bytes as
     the staged representation; no resident uint32 planar exists on the v2
     path until the fused packed fold. Same per-update validity + psum
-    exclusion semantics as v1.
+    exclusion semantics as v1, and as there one update ``uint8[bpn, n]``
+    gives a scalar. The executable is ``jit_planar_order_check``.
     """
     from ..ops import limbs_jax
 
-    def check(raw):
+    def planar_order_check(raw):
         planar = limbs_jax.packed_planar_to_limbs(raw, n_limbs)
         ok = limbs_jax.planar_all_lt_const(planar, order)  # per update
         if multi_device:
@@ -183,7 +189,42 @@ def _build_planar_ok(n_limbs: int, order: int, multi_device: bool):
             ok = bad == jnp.uint32(0)
         return ok
 
-    return check
+    return planar_order_check
+
+
+# rows a resident fold stacks and folds at once (``StreamingAggregator.
+# fold_resident_rows_now``): what a flush holds beside the rows themselves
+RESIDENT_CHUNK = 8
+
+
+def resident_footprint(rows: int, n_limbs: int, bpn: int, shard_len: int) -> int:
+    """Device bytes, on one device, that a flush of ``rows`` updates accepted
+    under wire ingest may hold at once: the rows, resident since each was
+    accepted (``4 * n_limbs`` bytes an element: a v1 row, and a v1 body may
+    come in any round; a v2 row is ``bpn``), one stacked chunk of them, the
+    fold over that chunk at its worst (the start-up race of the fold kernels:
+    the accumulator, a scratch, two kept results and temporaries up to 1.1x
+    the arguments, as ``benchmark/harness/sizing.py::footprint`` reckons them
+    from what the v5e compiler reports), and one raw body on its way in."""
+    row = 4 * n_limbs * shard_len
+    chunk = min(rows, RESIDENT_CHUNK) * row
+    return rows * row + chunk + 4 * row + int(1.1 * (chunk + row)) + bpn * shard_len
+
+
+def resident_rows_that_fit(limit: int, n_limbs: int, bpn: int, shard_len: int) -> int:
+    """The largest flush, of those one fold may take, that
+    ``resident_footprint`` puts within ``limit`` bytes."""
+    rows = 0
+    while rows < MAX_LAZY_BATCH and resident_footprint(rows + 1, n_limbs, bpn, shard_len) <= limit:
+        rows += 1
+    return rows
+
+
+def device_memory_limit(mesh) -> int | None:
+    """The least ``memory_stats()["bytes_limit"]`` of the mesh's devices;
+    ``None`` where the backend reports none (the CPU backend)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in mesh.devices.flat]
+    return None if not limits or any(not n for n in limits) else int(min(limits))
 
 
 class ShardedAggregator:
@@ -334,19 +375,99 @@ class ShardedAggregator:
         """
         return self._ingest_staged_bytes(self._stage_raw_bytes(raw))
 
+    # -- one update, as its message holds it (the K = 1 road) ---------------
+    #
+    # What ``StagedAggregator.validate_aggregation`` runs for every update
+    # under ``[aggregation] wire_ingest``: the element block goes to the
+    # device as the view of the body that the lazy parse made (no stack, no
+    # copy, no host pad), the device de-interleaves it and compares every
+    # element with the order, and the verdict comes back before the caller's
+    # seed-dict insert. Two steps, so that the caller can time the link and
+    # the kernel apart (``ingest.h2d`` / ``ingest.unpack``).
+
+    def _put_update(self, block: np.ndarray, bpe: int):
+        """``block`` (a host view whose LAST axis is the model axis, ``bpe``
+        bytes an element along it) on the mesh, padded to the padded length,
+        the transfer complete when this returns: the body's pages may be
+        taken for another body the moment its message is answered. One
+        device takes the view whole. On a mesh every device takes its own
+        columns of the view, and the one that holds the end of the model
+        pads its piece with zeros itself (zero bytes decode to zero
+        elements, valid and fold-neutral)."""
+        lead = block.shape[:-1]
+        sharding = NamedSharding(self.mesh, P(*([None] * len(lead)), MODEL_AXIS))
+        if self.mesh.devices.size == 1:
+            return jax.block_until_ready(jax.device_put(block, sharding))  # lint: sync-ok
+        shape = (*lead, self.padded_length * bpe)
+        pieces = []
+        for device, index in sharding.addressable_devices_indices_map(shape).items():
+            lo, hi = index[-1].start or 0, index[-1].stop or shape[-1]
+            piece = jax.device_put(block[..., lo : min(hi, block.shape[-1])], device)
+            if piece.shape[-1] < hi - lo:
+                pad = [(0, 0)] * len(lead) + [(0, hi - lo - piece.shape[-1])]
+                piece = jnp.pad(piece, pad)
+            pieces.append(piece)
+        return jax.block_until_ready(  # lint: sync-ok
+            jax.make_array_from_single_device_arrays(shape, sharding, pieces)
+        )
+
+    def put_wire_update(self, raw: np.ndarray):
+        """ONE raw v1 element block ``uint8[model_len * bpn]`` on the device
+        as it lies: ``uint8[padded_len * bpn]``."""
+        bpn = self.config.bytes_per_number
+        raw = np.asarray(raw)  # an array as it is: no copy  # lint: sync-ok
+        if raw.dtype != np.uint8 or raw.shape != (self.model_length * bpn,):
+            raise ValueError("expected uint8[model_len * bytes_per_number]")
+        BYTES_STAGED.labels(layout="wire").inc(raw.nbytes)
+        return self._put_update(raw, bpn)
+
+    def unpack_put_update(self, staged):
+        """De-interleave + validity-check the update ``put_wire_update`` put.
+        Returns the device-resident planar ``[L, padded_len]`` (already
+        validity-masked) for later staging, or ``None`` if any element is
+        >= the group order."""
+        planar, ok = profiling.timed_kernel(
+            "wire_unpack", self.padded_length, lambda: self._make_unpack_fn(one=True)(staged)
+        )
+        return planar if bool(ok) else None  # the verdict's sync  # lint: sync-ok
+
     def validate_wire_update(self, raw: np.ndarray):
         """Unpack + validity-check ONE raw wire update on device.
 
         The coordinator's per-update validation step when wire ingest is on
         (reference ordering: validate BEFORE the seed-dict insert,
-        update.rs:119-152). Returns the device-resident planar
-        ``[L, padded_len]`` (already validity-masked) for later staging, or
-        ``None`` if any element is >= the group order.
+        update.rs:119-152): ``put_wire_update`` then ``unpack_put_update``.
         """
-        raw = np.asarray(raw)
-        if raw.ndim != 1:
-            raise ValueError("expected uint8[model_len * bytes_per_number]")
-        return self.validate_wire_updates([raw])[0]
+        return self.unpack_put_update(self.put_wire_update(raw))
+
+    def put_planar_update(self, planes: np.ndarray):
+        """Wire-v2: ONE byte-planar element block ``uint8[bpn, model_len]``
+        (the body's planes viewed 2-D) on the device as it lies:
+        ``uint8[bpn, padded_len]``, which is also the row that stays."""
+        planes = np.asarray(planes)  # an array as it is: no copy  # lint: sync-ok
+        if planes.dtype != np.uint8 or planes.shape != (
+            self.config.bytes_per_number,
+            self.model_length,
+        ):
+            raise ValueError("expected uint8[bytes_per_number, model_len]")
+        BYTES_STAGED.labels(layout="wire-planar").inc(planes.nbytes)
+        return self._put_update(planes, 1)
+
+    def check_put_update(self, staged):
+        """Validity-check the update ``put_planar_update`` put. Returns it, or
+        ``None`` if any element is >= the group order. The accepted row stays
+        PACKED (``uint8[bpn, padded_len]``): the uint32 limb expansion only
+        ever happens transiently inside the validity/fold jits, never as a
+        resident buffer."""
+        ok = profiling.timed_kernel(
+            "wire_unpack", self.padded_length, lambda: self._make_planar_ok_fn(one=True)(staged)
+        )
+        return staged if bool(ok) else None  # the verdict's sync  # lint: sync-ok
+
+    def validate_planar_update(self, raw: np.ndarray):
+        """Wire-v2 twin of ``validate_wire_update``: ``put_planar_update``
+        then ``check_put_update``."""
+        return self.check_put_update(self.put_planar_update(raw))
 
     def validate_wire_updates(self, raws) -> list:
         """Unpack + validity-check a GROUP of raw wire updates in ONE device
@@ -381,18 +502,6 @@ class ShardedAggregator:
         )
         ok_host = np.asarray(ok)
         return [planar[i] if ok_host[i] else None for i in range(k)]
-
-    def validate_planar_update(self, raw: np.ndarray):
-        """Wire-v2: validity-check ONE byte-planar update
-        (``uint8[bpn, model_len]``, the serialized planar element block
-        viewed 2-D) on device. Same contract as ``validate_wire_update``,
-        except the accepted row stays PACKED (``uint8[bpn, padded_len]``) —
-        the uint32 limb expansion only ever happens transiently inside the
-        validity/fold jits, never as a resident buffer."""
-        raw = np.asarray(raw)
-        if raw.ndim != 2:
-            raise ValueError("expected uint8[bytes_per_number, model_len]")
-        return self.validate_planar_updates([raw])[0]
 
     def validate_planar_updates(self, raws) -> list:
         """Wire-v2 twin of ``validate_wire_updates``: one staged upload +
@@ -592,23 +701,25 @@ class ShardedAggregator:
             lambda: self._packed_fold_fn(acc, staged_packed),
         )
 
-    def _make_unpack_fn(self):
+    def _make_unpack_fn(self, one: bool = False):
         """Device wire-unpack + validity callable, memoized process-wide
-        (same identity-caching rationale as the fold fns)."""
+        (same identity-caching rationale as the fold fns). ``one``: for one
+        update with no batch axis (the per-update road)."""
         bpn = self.config.bytes_per_number
-        key = ("unpack", _mesh_key(self.mesh), bpn, self.order)
+        key = ("unpack", _mesh_key(self.mesh), bpn, self.order, one)
         fn = _FOLD_FN_CACHE.get(key)
         if fn is not None:
             return fn
         multi = self.mesh.devices.size > 1
         unpack_mask = _build_wire_unpack(bpn, self.order, multi)
         if multi:
+            batch = () if one else (None,)
             fn = jax.jit(
                 _shard_map(
                     unpack_mask,
                     mesh=self.mesh,
-                    in_specs=(P(None, MODEL_AXIS),),
-                    out_specs=(P(None, None, MODEL_AXIS), P()),
+                    in_specs=(P(*batch, MODEL_AXIS),),
+                    out_specs=(P(*batch, None, MODEL_AXIS), P()),
                 )
             )
         else:
@@ -616,28 +727,48 @@ class ShardedAggregator:
         _FOLD_FN_CACHE[key] = fn
         return fn
 
-    def _make_planar_ok_fn(self):
+    def _make_planar_ok_fn(self, one: bool = False):
         """Device planar (wire-v2) validity callable, memoized process-wide
-        (same identity-caching rationale as ``_make_unpack_fn``). Output is
-        only ``ok[K]`` — the staged packed bytes themselves are the result."""
-        key = ("planar-ok", _mesh_key(self.mesh), self.n_limbs, self.order)
+        (same identity-caching rationale as ``_make_unpack_fn``, and its
+        ``one``). Output is only ``ok[K]`` — the staged packed bytes
+        themselves are the result."""
+        key = ("planar-ok", _mesh_key(self.mesh), self.n_limbs, self.order, one)
         fn = _FOLD_FN_CACHE.get(key)
         if fn is not None:
             return fn
         multi = self.mesh.devices.size > 1
         check = _build_planar_ok(self.n_limbs, self.order, multi)
         if multi:
+            batch = () if one else (None,)
             fn = jax.jit(
                 _shard_map(
                     check,
                     mesh=self.mesh,
-                    in_specs=(P(None, None, MODEL_AXIS),),
+                    in_specs=(P(*batch, None, MODEL_AXIS),),
                     out_specs=P(),
                 )
             )
         else:
             fn = jax.jit(check)
         _FOLD_FN_CACHE[key] = fn
+        return fn
+
+    def _make_stack_fn(self):
+        """``jnp.stack`` of resident rows into the chunk ``[k, ...]`` as the
+        fold takes it (the model axis over the mesh; planar and packed chunks
+        are both ``[k, planes, padded]``): one device copy, under a name a
+        trace reader can match (``jit_stack_resident_rows``). Memoized
+        process-wide, as the fold fns are."""
+        key = ("stack", _mesh_key(self.mesh))
+        fn = _FOLD_FN_CACHE.get(key)
+        if fn is None:
+
+            def stack_resident_rows(*rows):
+                return jnp.stack(rows)
+
+            fn = _FOLD_FN_CACHE[key] = jax.jit(
+                stack_resident_rows, out_shardings=self._batch_sharding
+            )
         return fn
 
     def _make_ingest_fn(self):

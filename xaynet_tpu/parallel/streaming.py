@@ -108,7 +108,13 @@ from ..tenancy.scheduler import get_scheduler
 # BYTES_STAGED: one module owns the xaynet_bytes_staged_total family —
 # aggregator.py registers it (wire-ingest staging accounts there too) and
 # the streaming rings account through the shared symbol
-from .aggregator import BYTES_REDUCED, BYTES_STAGED, ShardedAggregator, note_h2d_route
+from .aggregator import (
+    BYTES_REDUCED,
+    BYTES_STAGED,
+    RESIDENT_CHUNK,
+    ShardedAggregator,
+    note_h2d_route,
+)
 from .mesh import shard_slices
 from .shards import H2D_GATE, place_row, settle
 
@@ -1134,10 +1140,40 @@ class StreamingAggregator:
         ROWS_STAGED.labels(route="flush").inc(k)
         return self.submit_staged(bufs, k)
 
-    def fold_planar_rows_now(self, rows: list) -> None:
-        """Fold already device-resident, validity-checked planar
-        ``[L, padded_len]`` updates on the CALLER's thread (the wire-ingest
-        server path: validated planars cached by ``validate_wire_update(s)``).
+    # -- resident rows: folded where they lie, on the caller's thread --------
+
+    @contextmanager
+    def _resident_batch(self, k: int, result):
+        """One flush's ``k`` rows, on the device since each was accepted
+        (wire ingest), folded on the caller's thread inside: the batch it is
+        on ``xaynet_streaming_batches_total``, as a queued one is. ``staged``
+        when the flush hands the rows over; ``folded`` when ``result()``, the
+        accumulator that the last chunk's fold returned, is ready on the
+        device (a fold's dispatch returns before the device has run it, and
+        whoever reads the counter takes ``folded`` to mean the fold
+        completed); ``failed`` if anything inside raised. One count a flush,
+        however many chunks it is folded in, under one ``stream.fold`` span."""
+        import jax
+
+        self._batch_seq += 1
+        BATCHES_TOTAL.labels(stage="staged").inc()
+        try:
+            with trace.get_tracer().span(SPAN_FOLD, batch=self._batch_seq, how="resident", k=k):
+                yield
+                jax.block_until_ready(result())  # lint: sync-ok
+        except BaseException:
+            BATCHES_TOTAL.labels(stage="failed").inc()
+            raise
+        BATCHES_TOTAL.labels(stage="folded").inc()
+
+    def fold_resident_rows_now(self, rows: list) -> None:
+        """Fold already device-resident, validity-checked updates on the
+        CALLER's thread (the wire-ingest server path: the rows that
+        ``ShardedAggregator.validate_wire_update(s)`` /
+        ``validate_planar_update(s)`` left on the device): planar
+        ``uint32[L, padded_len]`` from the v1 wire, PACKED byte-planar
+        ``uint8[bpn, padded_len]`` from v2 (``bpn`` bytes an element instead
+        of the ``4L`` a resident uint32 planar pins), in any mix.
 
         Deliberately NOT queued: these rows already occupy device memory,
         so parking them behind ``dispatch_ahead`` would pin up to
@@ -1145,86 +1181,85 @@ class StreamingAggregator:
         25M/batch 64) — and XLA's own asynchronous dispatch already
         overlaps device-side folds without our queue. Waits out queued
         work first (``agg.acc`` has exactly one mutator at a time), then
-        stacks + folds in chunks, dropping consumed references, so peak
-        device memory stays at the staged rows + one chunk-sized copy —
-        the same bound as the pre-streaming flush."""
+        stacks + folds in chunks of ``RESIDENT_CHUNK``, a layout at a time,
+        so peak device memory stays at the staged rows + one chunk-sized
+        copy. A packed chunk folds through the fused packed kernel
+        (``agg._fold_packed``: the uint32 expansion only ever exists
+        transiently inside the jit); where no fold kernel is resolved yet, or
+        the pipeline is shard-parallel, it is unpacked on the device, a chunk
+        at a time, and folded as a planar one is: the start-up race of the
+        kernels times a planar batch, and the shard plan folds planar ones.
+        The whole flush is ONE batch of the pipeline's counters
+        (:meth:`_resident_batch`)."""
         if not rows:
             return
         if self._sharded:
-            return self._fold_planar_rows_now_sharded(rows)
-        self._queue.join()
+            self._join_shard_queues()
+        else:
+            self._queue.join()
         err = self._poisoned()
         if err is not None:
             raise self._poison_error() from err
         if self._closed:
             raise StreamingError("pipeline is closed")
-        import jax
-        import jax.numpy as jnp
+        from ..ops.limbs_jax import packed_planar_to_limbs_jit
 
         agg = self.agg
-        rows = list(rows)
-        while rows:
-            piece, rows = rows[:8], rows[8:]
-            staged = jax.device_put(jnp.stack(piece), agg._batch_sharding)
-            n_piece = len(piece)
-            del piece
-            # caller-thread folds hold a scheduler slot per chunk too, so
-            # the device-resident fast path cannot starve other tenants
-            self._slot_acquire()
-            try:
-                agg.acc = agg._fold(agg.acc, staged)
-            finally:
-                self._slot_release()
-            with self._lock:
-                agg.nb_models += n_piece
+        packed = [r for r in rows if r.dtype == np.uint8]
+        chunks = [
+            group[i : i + RESIDENT_CHUNK]
+            for group in (packed, [r for r in rows if r.dtype != np.uint8])
+            for i in range(0, len(group), RESIDENT_CHUNK)
+        ]
+        n_packed = -(-len(packed) // RESIDENT_CHUNK)
+        del rows, packed
 
-    def fold_packed_rows_now(self, rows: list) -> None:
-        """Fold already device-resident, validity-checked PACKED byte-planar
-        ``uint8[bpn, padded_len]`` updates on the CALLER's thread — the
-        wire-v2 ingest path (``validate_planar_update(s)`` keeps accepted
-        rows in their staged packed layout, ``bpn`` bytes/element instead
-        of the ``4L`` a resident uint32 planar would pin). Same
-        no-queueing rationale and accounting as
-        :meth:`fold_planar_rows_now`; the fold itself is the fused packed
-        kernel (``agg._fold_packed``), so the uint32 expansion only ever
-        exists transiently inside the jit. In shard-parallel mode the rows
-        are unpacked on device (still no host materialization) and folded
-        through the per-shard planar fan-out."""
-        if not rows:
-            return
-        if self._sharded:
-            from ..ops.limbs_jax import packed_planar_to_limbs_jit
+        def planar_of(piece, is_packed):
+            staged = agg._make_stack_fn()(*piece)
+            if not is_packed:
+                return staged
+            import jax
 
-            n_limbs = self.agg.n_limbs
-            return self._fold_planar_rows_now_sharded(
-                [packed_planar_to_limbs_jit(r, n_limbs) for r in rows]
+            # pinned: the shard plan reads addressable shards by column start
+            return jax.device_put(
+                packed_planar_to_limbs_jit(staged, agg.n_limbs), agg._batch_sharding
             )
-        self._queue.join()
-        err = self._poisoned()
-        if err is not None:
-            raise self._poison_error() from err
-        if self._closed:
-            raise StreamingError("pipeline is closed")
-        import jax
-        import jax.numpy as jnp
 
-        agg = self.agg
-        rows = list(rows)
-        while rows:
-            piece, rows = rows[:8], rows[8:]
-            staged = jax.device_put(jnp.stack(piece), agg._batch_packed_sharding)
-            n_piece = len(piece)
-            del piece
-            # the packed fold never drives kernel auto-calibration (see
-            # agg._fold_packed) — resolve on the cheap path first
-            agg._resolve_kernel_cheap(n_piece)
-            self._slot_acquire()
-            try:
-                agg.acc = agg._fold_packed(agg.acc, staged)
-            finally:
-                self._slot_release()
-            with self._lock:
-                agg.nb_models += n_piece
+        if self._sharded:
+            raced = []  # the first chunk, where the start-up race stacked it
+
+            def first_chunk():
+                raced.append(planar_of(chunks[0], n_packed > 0))
+                return raced[0]
+
+            plan = self._ensure_plan(len(chunks[0]), first_chunk)
+            with self._resident_batch(sum(map(len, chunks)), lambda: plan.accs):
+                for i in range(len(chunks)):
+                    piece, chunks[i] = chunks[i], None  # consumed: free as we fold
+                    stacked = raced.pop() if raced else planar_of(piece, i < n_packed)
+                    self._fold_pinned_stack(plan, stacked, len(piece))
+            return
+        with self._resident_batch(sum(map(len, chunks)), lambda: agg.acc):
+            for i in range(len(chunks)):
+                piece, chunks[i] = chunks[i], None  # consumed: free as we fold
+                n_piece = len(piece)
+                is_packed = i < n_packed
+                if is_packed:
+                    agg._resolve_kernel_cheap(n_piece)
+                if is_packed and agg.kernel_used is not None:
+                    staged, fold = agg._make_stack_fn()(*piece), agg._fold_packed
+                else:
+                    staged, fold = planar_of(piece, is_packed), agg._fold
+                del piece
+                # caller-thread folds hold a scheduler slot per chunk too, so
+                # the device-resident fast path cannot starve other tenants
+                self._slot_acquire()
+                try:
+                    agg.acc = fold(agg.acc, staged)
+                finally:
+                    self._slot_release()
+                with self._lock:
+                    agg.nb_models += n_piece
 
     def fold_planar_stack_now(self, stacked) -> None:
         """Fold an already device-resident planar ``[K, L, padded_len]``
@@ -1232,7 +1267,7 @@ class StreamingAggregator:
         (``ops.masking_jax``): a whole seed group's mask planes come out of
         one jitted derive as a single stacked array, so re-slicing it into
         rows only to re-stack them would buy two copies. Same rationale and
-        accounting as :meth:`fold_planar_rows_now` (device-resident batches
+        accounting as :meth:`fold_resident_rows_now` (device-resident batches
         are never queued; ``agg.acc`` has one mutator at a time); in
         shard-parallel mode each shard folds its addressable slice."""
         if stacked.shape[0] == 0:
@@ -2051,37 +2086,6 @@ class StreamingAggregator:
             self._slot_release()
         with self._lock:
             self.agg.nb_models += k
-
-    def _fold_planar_rows_now_sharded(self, rows: list) -> None:
-        """Shard-parallel variant of :meth:`fold_planar_rows_now`: the rows
-        are already device-resident mesh-sharded planars, so each shard
-        folds its addressable piece of the stacked chunk on the CALLER's
-        thread (deliberately synchronous, same rationale as the
-        single-worker path: these rows already occupy device memory)."""
-        self._join_shard_queues()
-        err = self._poisoned()
-        if err is not None:
-            raise self._poison_error() from err
-        if self._closed:
-            raise StreamingError("pipeline is closed")
-        import jax
-        import jax.numpy as jnp
-
-        agg = self.agg
-        rows = list(rows)
-        plan = self._ensure_plan(
-            min(8, len(rows)), lambda: jnp.stack(rows[: min(8, len(rows))])
-        )
-        while rows:
-            piece, rows = rows[:8], rows[8:]
-            # pin the stacked chunk to the batch sharding: jnp.stack of
-            # sharded rows does not guarantee the model-axis layout, and
-            # the per-shard fan-out below reads addressable shards by their
-            # column start
-            stacked = jax.device_put(jnp.stack(piece), agg._batch_sharding)
-            n_piece = len(piece)
-            del piece
-            self._fold_pinned_stack(plan, stacked, n_piece)
 
     def _drain_sharded(self) -> int:
         """The cross-shard barrier: every shard queue drains, the one

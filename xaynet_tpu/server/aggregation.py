@@ -57,6 +57,7 @@ def build_staged_aggregator(shared) -> "StagedAggregator":
         staging_buffers=settings.aggregation.staging_buffers,
         shard_parallel=settings.aggregation.shard_parallel,
         packed_staging=settings.aggregation.packed_staging,
+        wire_ingest=settings.aggregation.wire_ingest,
         tenant=shared.tenant,
     )
 
@@ -246,6 +247,7 @@ class StagedAggregator:
         staging_buffers: int = 3,
         shard_parallel: bool = True,
         packed_staging: bool = True,
+        wire_ingest: bool = False,
         tenant: str = "default",
     ):
         self.config = config
@@ -255,6 +257,7 @@ class StagedAggregator:
         # device: device-resident planars (wire ingest) only; a host update
         # lives in its slot of an open batch
         self._staged_vect: list = []
+        self._resident_max = 0  # the most of them a flush of this round took
         self._open: list[_OpenBatch] = []  # device: batches filling in the ring
         self._staged_unit: list[np.ndarray] = []
         self._count = 0
@@ -270,6 +273,8 @@ class StagedAggregator:
             from ..parallel.streaming import StreamingAggregator
 
             self._device = ShardedAggregator(config.vect, object_size, mesh=mesh, kernel=kernel)
+            if wire_ingest:
+                self._check_resident_fits()
             # flush() submits micro-batches here; drain()/finalize() sync.
             # On a multi-device mesh the pipeline runs shard-parallel (one
             # fold worker per device, per-shard staging rings + donated
@@ -293,6 +298,56 @@ class StagedAggregator:
             self._ingest_pool = ThreadPoolExecutor(
                 max_workers=max(1, ingest_workers), thread_name_prefix="xn-ingest"
             )
+
+    def _check_resident_fits(self) -> None:
+        """Under wire ingest every accepted update stays in device memory
+        until its batch's flush, so ``batch_size`` bounds that memory: refuse
+        a round whose flush cannot fit the device before its first update,
+        not at its fortieth. Where the backend reports no limit (the CPU
+        backend) nothing is checked."""
+        from ..parallel import aggregator as device_agg
+        from .settings import SettingsError
+
+        device = self._device
+        limit = device_agg.device_memory_limit(device.mesh)
+        if limit is None:
+            return
+        sizes = (
+            device.n_limbs,
+            device.config.bytes_per_number,
+            device.padded_length // device.mesh.devices.size,
+        )
+        need = device_agg.resident_footprint(self.batch_size, *sizes)
+        if need > limit:
+            fits = device_agg.resident_rows_that_fit(limit, *sizes)
+            raise SettingsError(
+                f"aggregation.wire_ingest keeps every accepted update in device memory until "
+                f"its batch is folded: batch_size = {self.batch_size} at model length "
+                f"{self.object_size} may hold {need} bytes on a device of {limit}; the largest "
+                f"aggregation.batch_size that fits is {fits}"
+            )
+
+    def _validate_on_device(self, vect: LazyWireMaskVect):
+        """One update's element block to the device as the view of the body
+        that the lazy parse made, and the device's verdict on it: the row that
+        stays there (``ShardedAggregator.put_*_update`` and its check), or
+        ``None`` for a vector with an element at or over the order. The link
+        and the kernel are stages of the message apart (``ingest_h2d``: put
+        to transfer done; ``ingest_unpack``: dispatch to the verdict on the
+        host), inside ``validate``."""
+        device = self._device
+        if vect.planar:
+            # wire v2: the body is already the packed byte-planar layout
+            block, put, check = vect.planar_block, device.put_planar_update, device.check_put_update
+        else:
+            block, put, check = vect.wire_block, device.put_wire_update, device.unpack_put_update
+        where = {"wire": "packed" if vect.packed_wire else "legacy", "route": "device"}
+        with stages.stage("ingest_h2d", bytes=block.nbytes, **where):
+            staged = put(block)
+        with stages.stage("ingest_unpack", **where):
+            row = check(staged)
+        wire_stats.device_verdict(block.nbytes, row is not None)
+        return row
 
     @property
     def kernel_used(self) -> str:
@@ -340,12 +395,7 @@ class StagedAggregator:
             # only un-prevalidated updates pay the per-update sync here.
             planar = vect._staged_planar
             if planar is None and not vect._wire_invalid:
-                if vect.planar:
-                    # wire v2: the body is already the packed byte-planar
-                    # layout — uploaded as-is, no byte gather either side
-                    planar = self._device.validate_planar_update(vect.planar_block)
-                else:
-                    planar = self._device.validate_wire_update(np.asarray(vect.wire_block))
+                planar = self._validate_on_device(vect)
             if planar is None or not obj.unit.is_valid():
                 raise AggregationError("InvalidObject")
             vect._staged_planar = planar
@@ -399,6 +449,7 @@ class StagedAggregator:
                         [np.asarray(v.wire_block) for v in chunk]
                     )
                 for vect, planar in zip(chunk, planars):
+                    wire_stats.device_verdict(vect.wire_block.nbytes, planar is not None)
                     if planar is None:
                         vect._wire_invalid = True
                     else:
@@ -548,25 +599,20 @@ class StagedAggregator:
 
             self._submit_open_batches()
             parts, self._staged_vect = self._staged_vect, []  # consumed: free as we fold
-            # wire-v2 members stay PACKED uint8[bpn, padded] through staging
-            # (bpn bytes/element vs the 4L a uint32 planar pins) and fold
-            # through the fused packed kernel; a mixed round therefore
-            # splits one flush by staged layout
-            packed_rows = [p for p in parts if p.dtype == "uint8"]
-            parts = [p for p in parts if p.dtype != "uint8"]
-            if packed_rows:
-                self._stream.fold_packed_rows_now(packed_rows)
-                packed_rows.clear()
             if parts:
-                # wire ingest: every planar is already device-resident and
-                # validity-checked — folded INLINE (not queued: parking
-                # device-resident batches behind dispatch_ahead would pin
-                # several full batches in HBM at once, ~13 GB each at
-                # 25M/batch 64, where XLA's async dispatch already overlaps
-                # device folds). Chunked stack+fold keeps peak HBM at the
-                # staged planars + one chunk-sized copy, the pre-streaming
-                # bound.
-                self._stream.fold_planar_rows_now(parts)
+                # wire ingest: every row is already device-resident and
+                # validity-checked (a v1 update as a uint32 planar, a v2 one
+                # still PACKED uint8[bpn, padded]: bpn bytes an element
+                # against the 4L a planar pins) — folded INLINE (not queued:
+                # parking device-resident batches behind dispatch_ahead
+                # would pin several full batches in HBM at once, ~13 GB each
+                # at 25M/batch 64, where XLA's async dispatch already
+                # overlaps device folds). Chunked stack+fold keeps peak HBM
+                # at the staged rows + one chunk-sized copy; the flush is one
+                # batch of the pipeline's counters whatever its layouts.
+                self._resident_max = max(self._resident_max, len(parts))
+                wire_stats.RESIDENT_ROWS_MAX.set(self._resident_max)
+                self._stream.fold_resident_rows_now(parts)
                 parts.clear()
             order_limbs = limb_ops.order_limbs_for(self.config.unit.order)
             batch_unit = limb_ops.batch_mod_sum(units[:, None, :], order_limbs)[0]

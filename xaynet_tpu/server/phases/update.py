@@ -25,6 +25,7 @@ restored updates, so an already-satisfied round drains straight through.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
 
 from ...core.mask.masking import AggregationError
@@ -158,9 +159,11 @@ class UpdatePhase(PhaseState):
             wire, route = wire_route(req.masked_model.vect)
             # (the scan's CPU and faults are read where it runs, on the
             # executor's thread, and handed back for the span)
+            # (the message's context goes with it: under wire ingest the
+            # device's stages open there, as children of this one)
             with stages.stage("validate", wire=wire, route=route) as span:
                 span.set(**await asyncio.get_running_loop().run_in_executor(
-                    None, stages.carried, "validate",
+                    None, contextvars.copy_context().run, stages.carried, "validate",
                     self.aggregator.validate_aggregation, req.masked_model,
                 ))
         except AggregationError as err:

@@ -222,7 +222,10 @@ class AggregationSettings:
     # never executes the host element parse. Rejection semantics: an
     # invalid element fails validate_aggregation (message rejected before
     # its seed-dict insert) instead of the eager parse's DecodeError — the
-    # same update rejected, one pipeline stage later.
+    # same update rejected, one pipeline stage later. Every accepted update
+    # stays in device memory until its batch's flush, so batch_size bounds
+    # that memory: StagedAggregator checks it against the device's limit
+    # when it is built (docs/DESIGN.md §3 "Coordinator integration").
     wire_ingest: bool = False
 
 

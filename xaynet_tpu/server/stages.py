@@ -33,6 +33,14 @@ What a phase does with the message is a stage of the chain too:
 ``validate``, ``seed_dict``, ``stage`` and ``flush`` are the Update
 phase's, ``score`` the Sum2 phase's.
 
+Under ``[aggregation] wire_ingest`` two more lie INSIDE ``validate`` and are
+no part of the chain's sum either: ``ingest_h2d`` (the element block, a view
+of the body, put to the device until the transfer is done) and
+``ingest_unpack`` (the device's de-interleave and order check, dispatch to
+the verdict on the host), bracketed where they run
+(``StagedAggregator._validate_on_device``, on the executor's thread that
+``validate`` carries its context to).
+
 Two stages run beside the chain and are no part of its sum: ``to_planar``
 (the slot write, on the ``xn-ingest`` pool) and ``verify_beside`` (a long
 message's whole signature pass, on a ``pet-verify`` thread while the worker
@@ -63,7 +71,8 @@ SECONDS = get_registry().histogram(
     "xaynet_message_pipeline_seconds",
     "Wall time of one stage of a message's handling, by stage: read_body, "
     "pool_wait, open, verify, parse, resume_wait, request_wait, validate, "
-    "seed_dict, stage, flush, score, verdict_wait; beside the chain to_planar, "
+    "seed_dict, stage, flush, score, verdict_wait; inside validate under wire "
+    "ingest ingest_h2d, ingest_unpack; beside the chain to_planar, "
     "verify_beside (server/stages.py); decrypt_parse[_batch] = the pool hop (pool_wait to "
     "resume_wait); total = body read to the state machine's verdict. phase = "
     "the phase the message's coordinator was in when the message arrived.",
@@ -92,6 +101,8 @@ _SPANS: dict[str, str] = {
     "resume_wait": trace.declare_span("pipeline.resume_wait"),
     "request_wait": trace.declare_span("update.request_wait"),
     "validate": trace.declare_span("update.validate", mirror=True, usage="carrier"),
+    "ingest_h2d": trace.declare_span("ingest.h2d", mirror=True),
+    "ingest_unpack": trace.declare_span("ingest.unpack", mirror=True),
     "seed_dict": trace.declare_span("update.seed_dict", mirror=True),
     "stage": trace.declare_span("update.stage", mirror=True, usage="thread"),
     "to_planar": trace.declare_span("update.to_planar", mirror=True, usage="crew"),

@@ -14,6 +14,14 @@ a time, so a round may mix both. Counted once an update, where
   are byte planes) | ``relayout`` (through uint32 limb rows and the plane
   pack; for a v2 body the transposing fallback before them) | ``device``
   (wire ingest: unpacked and checked on the accelerator).
+- wire ingest, where the accelerator parses and checks (``route="device"``;
+  docs/DESIGN.md §3): ``xaynet_ingest_device_bytes_total``, the element-block
+  bytes put to the device as they lay in their message (over the seconds of
+  the ``ingest_h2d`` stage: the link's rate); ``xaynet_ingest_verdicts_total
+  {outcome}``, the device's verdicts (``accepted`` | ``rejected``: an element
+  at or over the order, found before the seed-dict insert); and
+  ``xaynet_ingest_resident_rows_max``, the most accepted rows a flush of the
+  round found resident in device memory (what ``batch_size`` bounds there).
 - the same by bodies, for the log line a round (``since_last``, printed where
   the first Sum2 message arrives) and for ``/healthz`` ``device.fold.wire``
   (``last_batch``: the fold batch closed last).
@@ -34,6 +42,25 @@ BYTES = get_registry().counter(
     ("wire", "route"),
 )
 
+DEVICE_BYTES = get_registry().counter(
+    "xaynet_ingest_device_bytes_total",
+    "Element-block bytes of Update vectors put to the accelerator as they lay "
+    "in their message (wire ingest: a view of the body, one transfer, no host "
+    "parse, scan or copy).",
+)
+VERDICTS = get_registry().counter(
+    "xaynet_ingest_verdicts_total",
+    "Verdicts of the accelerator's element check under wire ingest, one an "
+    "update, before its seed-dict insert: accepted | rejected (an element at "
+    "or over the group order).",
+    ("outcome",),
+)
+RESIDENT_ROWS_MAX = get_registry().gauge(
+    "xaynet_ingest_resident_rows_max",
+    "The most accepted update rows that a flush of the current round found "
+    "resident in device memory (wire ingest; batch_size bounds it).",
+)
+
 _lock = threading.Lock()
 _ZERO = {"packed": 0, "legacy": 0, "copied": 0}
 _batch = dict(_ZERO)  # the fold batch filling now  # guarded-by: _lock
@@ -49,6 +76,13 @@ def staged(wire: str, route: str, nbytes: int) -> None:
             tally[wire] += 1
             if route == "copy":
                 tally["copied"] += 1
+
+
+def device_verdict(nbytes: int, accepted: bool) -> None:
+    """The accelerator has checked one Update whose ``nbytes`` of element
+    block were put to it."""
+    DEVICE_BYTES.inc(nbytes)
+    VERDICTS.labels(outcome="accepted" if accepted else "rejected").inc()
 
 
 def batch_closed() -> None:
